@@ -37,9 +37,11 @@ import optax
 from torchft_tpu import DiLoCo, DistributedSampler, LocalSGD, Manager, TcpCommContext
 from torchft_tpu.comm.store import StoreServer
 from torchft_tpu.models import CONFIGS, init_params, make_train_step
+from torchft_tpu.utils.device import place_compile_cache
 
 
 def main() -> None:
+    place_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", "0"))
     num_groups = int(os.environ.get("NUM_REPLICA_GROUPS", "2"))
     total_syncs = int(os.environ.get("TOTAL_SYNCS", "10"))

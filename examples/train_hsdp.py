@@ -44,9 +44,11 @@ from torchft_tpu.ddp import DistributedDataParallel
 from torchft_tpu.models import CONFIGS, init_params, make_grad_step
 from torchft_tpu.optim import OptimizerWrapper
 from torchft_tpu.parallel import ft_mesh, shard_pytree, tp_rules_gpt
+from torchft_tpu.utils.device import place_compile_cache
 
 
 def main() -> None:
+    place_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", "0"))
     total_steps = int(os.environ.get("TOTAL_STEPS", "30"))
     cfg = CONFIGS[os.environ.get("MODEL", "tiny")]

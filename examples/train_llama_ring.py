@@ -48,9 +48,11 @@ from torchft_tpu.models.llama import (
 )
 from torchft_tpu.optim import OptimizerWrapper
 from torchft_tpu.parallel import ft_mesh, make_ring_attention
+from torchft_tpu.utils.device import place_compile_cache
 
 
 def main() -> None:
+    place_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", "0"))
     total_steps = int(os.environ.get("TOTAL_STEPS", "20"))
     seq_len = int(os.environ.get("SEQ_LEN", "256"))

@@ -42,9 +42,11 @@ from torchft_tpu.models import MOE_CONFIGS, moe_transformer_loss_fn, moe_init_pa
 from torchft_tpu.optim import OptimizerWrapper
 from torchft_tpu.parallel import ft_mesh, shard_pytree
 from torchft_tpu.parallel.moe import moe_rules
+from torchft_tpu.utils.device import place_compile_cache
 
 
 def main() -> None:
+    place_compile_cache()
     replica_group = int(os.environ.get("REPLICA_GROUP_ID", "0"))
     total_steps = int(os.environ.get("TOTAL_STEPS", "30"))
     cfg = MOE_CONFIGS[os.environ.get("MODEL", "moe-tiny")]

@@ -1,29 +1,20 @@
 #!/usr/bin/env python
-"""Bench metric-surface smoke: run bench.py one short window and assert
-the streamed-pipeline gauges are present and finite; also run one tiny
-in-process heal round (heal_* gauges), one short streaming-DiLoCo
-round (outer_* gauges — outer_wire_ms / outer_overlap — plus the
-t1_outer_overlap payload key), one xla-backend allreduce round
-under a forced host device count (backend-tagged comm_* gauges +
-comm_backend label, comm/xla_backend.py), and one flight-recorder
-round (a solo manager's lifecycle events dumped and converted with
-to_chrome_trace — fails on invalid Chrome-trace JSON or missing
-quorum/step_commit events; bench payload must carry a positive
-t1_events_recorded).
+"""Metric-surface smoke: in-process rounds, each asserting that one
+plane's gauges and events are present and finite — one tiny heal round
+(heal_* gauges), one short streaming-DiLoCo round (outer_* gauges), one
+xla-backend allreduce round under a forced host device count
+(backend-tagged comm_* gauges + comm_backend label,
+comm/xla_backend.py), one flight-recorder round (a solo manager's
+lifecycle events dumped and converted with to_chrome_trace — fails on
+invalid Chrome-trace JSON or missing quorum/step_commit events), and the
+sharded, redistribution, fused, fleet, pipeline, fastpath, multi-job and
+serve planes' own rounds.
 
 Driven by ``BENCH_SMOKE=1 scripts/test.sh``. The point is that a metric
 regression (a renamed key, a gauge that silently stopped being computed,
 a pipeline that stopped recording stage timers) fails tier-1-adjacent
-tooling loudly instead of vanishing from the next graded artifact.
-
-The run is the smallest configuration that still exercises the real
-streamed DDP pipeline: tiny model, 2 replicas (the CPU child heals and
-trains in lockstep, so the classic DDP path actually runs), a small
-BENCH_BUCKET_KB so the grad tree splits into >= 2 buckets (the overlap
-gauge needs at least two), chaos/sync/overhead phases off. If the
-2-replica bring-up fails (bench falls back to solo — no DDP steps), the
-pipeline gauges are legitimately null: the smoke then only asserts the
-keys exist, and says so.
+tooling loudly. These are counts and presence checks on the CPU, never
+times; bench.py itself runs on a TPU only and is not driven from here.
 """
 
 import json
@@ -36,7 +27,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)  # run as a script: the repo root is not on
 # sys.path (heal_smoke imports torchft_tpu in-process)
 
-_STAGES = ("d2h", "wire", "h2d")  # ef only runs under a lossy codec
 
 
 def heal_smoke() -> "list[str]":
@@ -1321,45 +1311,6 @@ def serve_smoke() -> "list[str]":
 
 
 def main() -> int:
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("PYTHONPATH", "XLA_FLAGS")
-    }
-    env.update(
-        JAX_PLATFORMS="cpu",
-        BENCH_NO_FALLBACK="1",
-        BENCH_MODEL="tiny",
-        BENCH_STEPS=env.get("BENCH_SMOKE_STEPS", "5"),
-        BENCH_WARMUP="1",
-        BENCH_REPLICAS="2",
-        BENCH_BUCKET_KB="64",   # tiny's ~0.8MB float tree -> >= 2 buckets
-        BENCH_CHAOS="0",
-        BENCH_SYNC="0",
-        BENCH_OVERHEAD="0",
-    )
-    out = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "bench.py")],
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-        timeout=float(os.environ.get("BENCH_SMOKE_TIMEOUT", "420")),
-    )
-    lines = [l for l in out.stdout.splitlines() if l.strip()]
-    if not lines:
-        print("bench smoke: bench produced no output", file=sys.stderr)
-        return 1
-    try:
-        payload = json.loads(lines[-1])
-    except json.JSONDecodeError:
-        print("bench smoke: tail is not JSON:\n" + "\n".join(lines[-15:]),
-              file=sys.stderr)
-        return 1
-    if payload.get("metric") == "bench_error":
-        print(f"bench smoke: bench errored: {payload.get('error')}",
-              file=sys.stderr)
-        return 1
-
     failures = heal_smoke()
     failures += diloco_smoke()
     failures += xla_smoke()
@@ -1374,61 +1325,12 @@ def main() -> int:
     failures += fastpath_smoke()
     failures += multijob_smoke()
     failures += serve_smoke()
-    for key in ("t1_pipeline_overlap", "t1_pipeline_ms", "t1_ddp_streamed",
-                "t1_overhead_ms", "t1_outer_overlap", "t1_outer_wire_ms",
-                "comm_backend", "t1_events_recorded",
-                "t1_opt_update_ms", "t1_opt_state_bytes"):
-        if key not in payload:
-            failures.append(f"missing key {key!r}")
-    sharded = payload.get("sharded") or {}
-    if sharded.get("error"):
-        failures.append(f"bench sharded phase errored: {sharded['error']}")
-    elif sharded and sharded.get("bitwise") is not True:
-        failures.append(
-            "bench sharded phase: sharded arm not bitwise with the "
-            "replicated arm"
-        )
-    recorded = payload.get("t1_events_recorded")
-    if recorded is not None and int(recorded or 0) <= 0:
-        failures.append(
-            "bench recorded zero lifecycle events "
-            f"(t1_events_recorded={recorded!r}) — recorder disabled or "
-            "emit paths regressed"
-        )
-    classic = payload.get("t1_classic_steps") or 0
-    if classic > 0 and not failures:
-        # The DDP path ran: the gauges must be real finite numbers.
-        overlap = payload["t1_pipeline_overlap"]
-        if overlap is None or not (0.0 <= float(overlap) <= 1.0):
-            failures.append(
-                f"t1_pipeline_overlap not a finite ratio: {overlap!r}"
-            )
-        pipe = payload["t1_pipeline_ms"]
-        for stage in _STAGES:
-            k = f"ddp_{stage}_avg_ms"
-            v = pipe.get(k)
-            if v is None or not (float(v) >= 0.0):  # NaN fails this too
-                failures.append(f"t1_pipeline_ms[{k!r}] not finite: {v!r}")
-    elif classic == 0:
-        print(
-            "bench smoke: WARNING — no classic DDP step ran (2-replica "
-            "bring-up fell back to solo); pipeline gauges verified for "
-            "presence only", file=sys.stderr,
-        )
-
     if failures:
         print("bench smoke FAILED:\n  " + "\n  ".join(failures),
               file=sys.stderr)
-        print(json.dumps(payload)[:2000], file=sys.stderr)
         return 1
     print(
         "bench smoke OK: "
-        f"overlap={payload['t1_pipeline_overlap']} "
-        f"classic_steps={classic} "
-        f"stages={sorted(payload['t1_pipeline_ms'])} "
-        f"comm_backend={payload.get('comm_backend')} "
-        f"events_recorded={payload.get('t1_events_recorded')} "
-        f"opt_state_ratio={(payload.get('sharded') or {}).get('state_bytes_ratio')} "
         "heal_gauges=ok outer_gauges=ok xla_gauges=ok qpsum_gauges=ok "
         "hier_gauges=ok chrome_trace=ok sharded_gauges=ok "
         "redist_gauges=ok fused_gauges=ok fleet_gauges=ok "

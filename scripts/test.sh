@@ -12,7 +12,7 @@
 #                                # lint finding or data race; see
 #                                # docs/operations.md "Static analysis
 #                                # & sanitizers"
-#   BENCH_SMOKE=1 scripts/test.sh  # one short bench.py window + one tiny
+#   BENCH_SMOKE=1 scripts/test.sh  # in-process metric smokes: one tiny
 #                                  # heal round + one streaming-DiLoCo round
 #                                  # + one xla allreduce round + one
 #                                  # flight-recorder round + one w2→w3

@@ -1,13 +1,13 @@
-"""Regression tests for the graded bench artifact.
+"""bench.py measures a TPU and nothing else.
 
-Rounds 1 and 2 each lost one graded artifact to packaging: the bench
-printed valid JSON and then teardown noise (a manager traceback from an
-in-flight quorum failed by lighthouse shutdown) landed after it, so the
-driver's tail was unparseable. These tests run bench.py exactly the way
-the driver does — a subprocess whose combined stdout+stderr tail must end
-with one parseable JSON line — covering the chaos/teardown path (the one
-that broke), the solo path, and a flagship-config smoke so the 125m model
-runs in the graded loop every round even without a TPU.
+Every artifact the driver holds from the earlier rounds is a CPU run under
+a chip metric's name: with no accelerator the bench used to re-run itself
+on the CPU. These tests pin the contract that replaced that: without a TPU
+the bench runs no phase, exits non-zero, and still ends its combined
+stdout+stderr with ONE parseable JSON line (``bench_error``, naming the
+missing TPU) — the way the driver reads it. What the bench computes on a
+chip is checked on a chip (chip_smoke.py runs the same training loop there;
+ROADMAP S1 gives the benchmark its own cells).
 """
 
 import json
@@ -22,17 +22,14 @@ _BENCH = os.path.join(_REPO, "bench.py")
 
 
 def _run_bench(extra_env, timeout):
-    """Run bench.py as the driver does, on CPU, merging stdout+stderr."""
+    """Run bench.py as the driver does, merging stdout+stderr, on a
+    machine whose only platform is the CPU."""
     env = {
         k: v for k, v in os.environ.items()
         if k not in ("PYTHONPATH", "XLA_FLAGS")
     }
-    env.update(
-        JAX_PLATFORMS="cpu",
-        BENCH_NO_FALLBACK="1",
-        **extra_env,
-    )
-    out = subprocess.run(
+    env.update(JAX_PLATFORMS="cpu", **extra_env)
+    return subprocess.run(
         [sys.executable, _BENCH],
         env=env,
         stdout=subprocess.PIPE,
@@ -40,7 +37,6 @@ def _run_bench(extra_env, timeout):
         text=True,
         timeout=timeout,
     )
-    return out
 
 
 def _last_line_json(out):
@@ -50,96 +46,20 @@ def _last_line_json(out):
         return json.loads(lines[-1])
     except json.JSONDecodeError:
         pytest.fail(
-            "bench tail is not JSON — the graded artifact would be lost. "
+            "bench tail is not JSON — the artifact would be lost. "
             f"Tail:\n{chr(10).join(lines[-15:])}"
         )
 
 
-@pytest.mark.slow  # tier-1 budget: >=25s on a 2-core host (see pytest.ini)
-def test_bench_tail_is_json_through_chaos_teardown():
-    """The full 2-replica chaos path — child SIGKILL, warm-standby rejoin,
-    heal, multi-server teardown — must still end with one JSON line."""
-    out = _run_bench(
-        {
-            "BENCH_MODEL": "tiny",
-            "BENCH_STEPS": "2",
-            "BENCH_REPLICAS": "2",
-            "BENCH_CHAOS_SECONDS": "12",
-            "BENCH_SYNC": "0",
-        },
-        timeout=420,
-    )
+def test_bench_without_tpu_runs_nothing_and_says_why():
+    out = _run_bench({"BENCH_MODEL": "tiny", "BENCH_STEPS": "2"}, timeout=120)
     payload = _last_line_json(out)
-    assert out.returncode == 0
-    # driver contract fields
-    assert payload["metric"].startswith("ft_tokens_per_sec")
-    assert payload["value"] > 0
-    assert payload["unit"] == "tokens/s/chip"
-    assert 0 < payload["vs_baseline"]
-    # the chaos kill must actually have landed in this configuration
-    assert payload["chaos_tokens_per_sec"] is not None
-    assert payload["replicas"] == 2
-    # on CPU the child heals into the cohort: T1 must have measured REAL
-    # 2-participant averaging, not an idle echo
-    assert payload["t1_participants_max"] == 2
-    # ...and the path counters must prove it: a 2-member wire rides the
-    # classic grad/transport/update path, not the solo fused program
-    assert payload["t1_classic_steps"] >= 1
-    # the chaos window spans both: classic while the peer lives, fused
-    # after the kill leaves the survivor solo (the 2.5s dead time past
-    # the 800ms heartbeat guarantees solo steps)
-    assert payload["chaos_classic_steps"] >= 1
-    assert payload["chaos_fused_steps"] >= 1
-    # 2 trainers on a (usually 1-core) CPU sandbox: the chaos headline
-    # must self-qualify instead of reporting a contended-host ratio as
-    # product fault-tolerance (VERDICT r4 weak #4)
-    if payload["host_cores"] < 2:
-        assert payload["chaos_regime"] == "contended_host"
-        assert payload["chaos_efficiency"] is None
-        assert payload["chaos_efficiency_raw"] > 0
-    # the classic path dominates a 2-member wire: its phase breakdown
-    # must be populated (VERDICT r4 weak #3)
-    assert payload["t1_phase_ms"], payload
-    assert "barrier" in payload["t1_phase_ms"]
-    assert "dispatch" in payload["t1_phase_ms"]
-    # percentile split for tail attribution (VERDICT r4 weak #6)
-    assert any(k.endswith("_p95_ms") for k in payload["t1_overhead_ms"])
-
-
-def test_bench_solo_tail_is_json():
-    out = _run_bench(
-        {
-            "BENCH_MODEL": "tiny",
-            "BENCH_STEPS": "2",
-            "BENCH_REPLICAS": "1",
-            "BENCH_CHAOS": "0",
-            "BENCH_SYNC": "0",
-        },
-        timeout=180,
-    )
-    payload = _last_line_json(out)
-    assert out.returncode == 0
-    assert payload["value"] > 0
-    assert payload["chaos_tokens_per_sec"] is None
-    # the classic-path overhead phase rode the artifact (VERDICT r4 #2):
-    # a fixed ms residue and its projection onto the measured T0 step
-    ovh = payload["classic_overhead"]
-    assert "error" not in ovh, ovh
-    # falsifiable checks: both loops really ran (nonzero windows), all
-    # four phases were recorded with a real barrier residue, and the
-    # headline is either a valid >= 1.0 projection or EXPLICITLY nulled
-    # with the inverted flag — never a silently clean 0.0/1.0
-    assert ovh["bare_s"] > 0 and ovh["ft_s"] > 0
-    for phase in ("prologue", "dispatch", "barrier", "fence"):
-        assert phase in ovh["phase_ms"], ovh
-    assert ovh["phase_ms"]["barrier"] > 0
-    if ovh["inverted_measurement"]:
-        assert ovh["overhead_ms_per_step"] is None
-        assert ovh["projected_ratio"] is None
-        assert ovh["overhead_ms_per_step_raw"] < 0
-    else:
-        assert ovh["overhead_ms_per_step"] >= 0
-        assert ovh["projected_ratio"] >= 1.0
+    assert out.returncode != 0
+    assert payload["metric"] == "bench_error"
+    assert "tpu" in payload["error"].lower()
+    # no phase ran: nothing measured rides the error line
+    assert payload["value"] == 0.0
+    assert not any(k.startswith(("t0_", "t1_", "chaos_")) for k in payload)
 
 
 def test_bench_error_path_still_emits_json():
@@ -150,129 +70,21 @@ def test_bench_error_path_still_emits_json():
         timeout=120,
     )
     payload = _last_line_json(out)
+    assert out.returncode != 0
     assert payload["metric"] == "bench_error"
     assert "value" in payload and "vs_baseline" in payload
 
 
-@pytest.mark.slow  # tier-1 budget: >=25s on a 2-core host (see pytest.ini)
-def test_bench_wedged_probe_fallback_survives_watchdog():
-    """r3's graded artifact was destroyed by the watchdog firing while the
-    parent legitimately waited on the probe / CPU-fallback child
-    (bench.py `_devices_or_fallback`) — no progress touch on that path, so
-    at BENCH_WATCHDOG_S the parent emitted bench_error and os._exit(2)'d,
-    killing the child doing the work. This reproduces the exact geometry:
-    a probe that hangs LONGER than the watchdog limit (so the old code is
-    guaranteed to fire mid-wait), then a CPU fallback run. The driver-style
-    tail must parse to a THROUGHPUT metric, not bench_error."""
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("PYTHONPATH", "XLA_FLAGS")
-    }
-    env.update(
-        JAX_PLATFORMS="cpu",
-        BENCH_TEST_PROBE_HANG="1",     # probe wedges (never finishes)
-        BENCH_INIT_TIMEOUT="25",       # probe wait outlives the watchdog…
-        BENCH_WATCHDOG_S="20",         # …so the old code fired right here
-        BENCH_FALLBACK_WATCHDOG_S="300",  # child gets a sane budget
-        BENCH_MODEL="tiny",
-        BENCH_STEPS="2",
-        BENCH_REPLICAS="1",
-        BENCH_CHAOS="0",
-        BENCH_SYNC="0",
-    )
-    out = subprocess.run(
-        [sys.executable, _BENCH],
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-        timeout=420,
-    )
-    payload = _last_line_json(out)
-    assert payload["metric"] != "bench_error", payload
-    assert payload["metric"].startswith("ft_tokens_per_sec")
-    assert payload["value"] > 0
-    assert out.returncode == 0
+def test_peak_flops_refuses_a_device_kind_it_does_not_know():
+    sys.path.insert(0, _REPO)
+    import bench
 
+    class _Device:
+        def __init__(self, kind):
+            self.device_kind = kind
 
-@pytest.mark.slow  # tier-1 budget: >=25s on a 2-core host (see pytest.ini)
-def test_bench_flagship_cpu_smoke():
-    """The 125m flagship config must run in the graded loop (full param
-    set, real vocab, real bucketing shapes) even when only a CPU is
-    available — no silent downgrade to tiny (VERDICT r02 weak #7). Short
-    sequence keeps the FLOPs tractable; params/buckets stay flagship."""
-    out = _run_bench(
-        {
-            "BENCH_MODEL": "125m",
-            "BENCH_BATCH": "1",
-            "BENCH_SEQ": "64",
-            "BENCH_STEPS": "1",
-            "BENCH_WARMUP": "1",
-            "BENCH_REPLICAS": "1",
-            "BENCH_CHAOS": "0",
-            "BENCH_SYNC": "0",
-        },
-        timeout=600,
-    )
-    payload = _last_line_json(out)
-    assert out.returncode == 0
-    assert payload["model"] == "125m"
-    assert payload["params_m"] > 100
-    assert payload["value"] > 0
-
-
-@pytest.mark.slow  # tier-1 budget: >=25s on a 2-core host (see pytest.ini)
-def test_bench_localsgd_diloco_fields():
-    """BASELINE configs 3-4 ride the graded artifact: LocalSGD with a
-    real injected transport fault (discarded sync + recovery through the
-    coordinated comm-epoch reconfigure) and DiLoCo outer-optimizer
-    cadence, each with the cross-group consistency oracle. BENCH_SYNC_FAST
-    shrinks group counts for suite time; the graded defaults are 4 and 8
-    groups (BASELINE.json configs[2:4])."""
-    out = _run_bench(
-        {
-            "BENCH_MODEL": "tiny",
-            "BENCH_STEPS": "2",
-            "BENCH_REPLICAS": "1",
-            "BENCH_CHAOS": "0",
-            "BENCH_SYNC_FAST": "1",
-        },
-        timeout=540,
-    )
-    payload = _last_line_json(out)
-    assert out.returncode == 0
-    ls = payload["localsgd"]
-    assert ls["sync_every"] == 8
-    assert ls["fault_injected"] and ls["fault_sync_discarded"], ls
-    assert ls["recovered"] and ls["consistent"], ls
-    assert ls["syncs_committed"] >= 2 and ls["inner_steps_per_sec"] > 0
-    dl = payload["diloco"]
-    assert dl["consistent"] and dl["syncs_committed"] >= 2, dl
-    # >= 0.5, not == 1.0: a transport timeout at a sync point under host
-    # contention latches (no exception) and discards that sync — the
-    # documented straggler path; what matters is recovery + consistency
-    assert dl["commit_rate"] >= 0.5, dl
-
-
-def test_bench_max_runtime_bound_emits_parseable_error():
-    """A degraded-but-progressing run (every phase still touching the
-    watchdog) must still be bounded: BENCH_MAX_RUNTIME_S fires from
-    INSIDE the process (claim-safe self-exit) with a parseable tail
-    carrying whatever was already measured."""
-    out = _run_bench(
-        {
-            "BENCH_MODEL": "125m",      # slow enough to outlive the bound
-            "BENCH_BATCH": "1",
-            "BENCH_SEQ": "64",
-            "BENCH_REPLICAS": "1",
-            "BENCH_CHAOS": "0",
-            "BENCH_SYNC": "0",
-            "BENCH_WATCHDOG_S": "0",    # isolate the total-runtime bound
-            "BENCH_MAX_RUNTIME_S": "5",
-        },
-        timeout=300,
-    )
-    payload = _last_line_json(out)
-    assert payload["metric"] == "bench_error"
-    assert "total runtime" in payload["error"]
-    assert out.returncode == 2
+    assert bench._peak_flops(_Device("TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        bench._peak_flops(_Device("TPU v9 imaginary"))
+    with pytest.raises(ValueError, match="cpu"):
+        bench._peak_flops(_Device("cpu"))

@@ -44,6 +44,32 @@ def _run_ranks(store, world_size, fn, prefix="q0", timeout=20.0):
     return results
 
 
+def test_idle_lane_lets_go_of_its_last_op(store) -> None:
+    # A lane thread blocked on its queue must not pin the op it ran last:
+    # the op's future carries the caller's continuations, which reach the
+    # caller's buffers (for DDP, that step's gradients on the device).
+    import gc
+    import weakref
+
+    class Payload:
+        pass
+
+    def _fn(ctx, rank):
+        payload = Payload()
+        alive = weakref.ref(payload)
+        fut = ctx.allreduce([np.ones(4, np.float32)]).future()
+        fut.add_done_callback(lambda _f, _p=payload: None)
+        fut.result(timeout=10)
+        del fut, payload
+        # the lane is idle again once this rank has its result; give its
+        # loop the moment it needs to come back around to its queue
+        time.sleep(0.2)
+        gc.collect()
+        return alive() is None
+
+    assert _run_ranks(store, 2, _fn) == [True, True]
+
+
 @pytest.mark.parametrize("world_size", [1, 2, 4])
 def test_allreduce_sum(store, world_size) -> None:
     def _fn(ctx, rank):

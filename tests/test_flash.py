@@ -160,3 +160,32 @@ def test_streamed_backward_matches() -> None:
             )
     finally:
         flash_mod._RESIDENT_KV_BYTES = old
+
+
+def test_causal_attention_propagates_a_kernel_error(monkeypatch) -> None:
+    # On a TPU the Mosaic kernel is THE path: a kernel that raises must
+    # reach the caller, never be swapped for the XLA reference behind
+    # their back (the device-hiding fallback this dispatch used to have).
+    import torchft_tpu.ops.attention as attention
+    import torchft_tpu.ops.flash as flash
+
+    def refused(*_args, **_kwargs):
+        raise ValueError("mosaic refused this kernel")
+
+    q = _rand((1, 128, 2, 32), 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash, "flash_attention", refused)
+    with pytest.raises(ValueError, match="mosaic refused this kernel"):
+        attention.causal_attention(q, q, q)
+    # and off the TPU the reference is chosen without touching the kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    out = attention.causal_attention(q, q, q)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(reference_attention(q, q, q)),
+    )
+
+
+def test_flash_shape_error_names_the_shape() -> None:
+    q = _rand((1, 192, 2, 32), 0)  # 192 is not a multiple of 128
+    with pytest.raises(ValueError, match=r"seq len 192 of q\(1, 192, 2, 32\)"):
+        flash_attention(q, q, q, interpret=True)

@@ -48,6 +48,30 @@ def test_future_timeout_late_completion_ignored() -> None:
         wrapped.result(timeout=1.0)
 
 
+def test_finished_timeout_releases_what_its_future_reaches() -> None:
+    # A finished op's deadline entry sits in the timer heap until the
+    # deadline passes; it must not keep the op's future — and through its
+    # continuations a whole step's device buffers — alive that long. (At
+    # 125m this held ~1 GB of gradients per classic step for `timeout`
+    # seconds: RESOURCE_EXHAUSTED on the chip after a few steps.)
+    import gc
+    import weakref
+
+    class Payload:
+        pass
+
+    fut: Future = Future()
+    wrapped = future_timeout(fut, 600.0)
+    payload = Payload()
+    wrapped.add_done_callback(lambda _f, _p=payload: None)
+    alive = weakref.ref(payload)
+    fut.set_result(1)
+    assert wrapped.result(timeout=1.0) == 1
+    del fut, wrapped, payload
+    gc.collect()
+    assert alive() is None
+
+
 def test_future_wait() -> None:
     fut: Future = Future()
 

@@ -182,13 +182,10 @@ def test_ring_attention_matches_reference(causal) -> None:
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_flash_blocks_matches_reference(
-    causal, monkeypatch
-) -> None:
+def test_ring_attention_flash_blocks_matches_reference(causal) -> None:
     # flash-block ring (pallas local blocks + logaddexp stream merge,
     # future blocks skipped at block granularity) must be EXACT vs dense
     # attention, like the einsum ring. Interpret mode: no TPU in tests.
-    monkeypatch.setenv("TORCHFT_TPU_PALLAS_INTERPRET", "1")
     mesh = ft_mesh({"seq": 4}, devices=jax.devices()[:4])
     B, S, H, D = 2, 64, 2, 16
     rng = np.random.default_rng(7)
@@ -200,7 +197,7 @@ def test_ring_attention_flash_blocks_matches_reference(
 
     ring = jax.jit(make_ring_attention(
         mesh, "seq", causal=causal, block_impl="flash",
-        block_q=8, block_k=8,
+        block_q=8, block_k=8, interpret=True,
     ))
     out = ring(qs, ks, vs)
     expected = _reference_attention(q, k, v, causal)
@@ -210,11 +207,8 @@ def test_ring_attention_flash_blocks_matches_reference(
     assert out.sharding.spec == P(None, "seq", None, None)
 
 
-def test_ring_attention_flash_blocks_match_einsum_blocks(
-    monkeypatch,
-) -> None:
+def test_ring_attention_flash_blocks_match_einsum_blocks() -> None:
     # the two block implementations are interchangeable numerically
-    monkeypatch.setenv("TORCHFT_TPU_PALLAS_INTERPRET", "1")
     mesh = ft_mesh({"seq": 8})
     B, S, H, D = 1, 64, 2, 8
     rng = np.random.default_rng(8)
@@ -228,6 +222,7 @@ def test_ring_attention_flash_blocks_match_einsum_blocks(
     )
     out_f = jax.jit(make_ring_attention(
         mesh, "seq", causal=True, block_impl="flash", block_q=8, block_k=8,
+        interpret=True,
     ))(qs, ks, vs)
     np.testing.assert_allclose(
         np.asarray(out_e), np.asarray(out_f), atol=2e-5, rtol=2e-5
@@ -262,11 +257,10 @@ def test_ring_attention_long_context_grad() -> None:
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_ring_attention_flash_blocks_grad(causal, monkeypatch) -> None:
+def test_ring_attention_flash_blocks_grad(causal) -> None:
     # the flash ring's custom VJP (ring-structured FlashAttention-2
     # backward: global lse/delta, dk/dv accumulators rotating with their
     # kv blocks) must produce EXACT gradients vs dense attention
-    monkeypatch.setenv("TORCHFT_TPU_PALLAS_INTERPRET", "1")
     mesh = ft_mesh({"seq": 4}, devices=jax.devices()[:4])
     B, S, H, D = 2, 64, 2, 16
     rng = np.random.default_rng(11)
@@ -278,7 +272,7 @@ def test_ring_attention_flash_blocks_grad(causal, monkeypatch) -> None:
 
     ring = make_ring_attention(
         mesh, "seq", causal=causal, block_impl="flash",
-        block_q=8, block_k=8,
+        block_q=8, block_k=8, interpret=True,
     )
 
     def loss_ring(q, k, v):
@@ -297,12 +291,9 @@ def test_ring_attention_flash_blocks_grad(causal, monkeypatch) -> None:
         )
 
 
-def test_ring_attention_flash_grad_matches_einsum_grad(
-    monkeypatch,
-) -> None:
+def test_ring_attention_flash_grad_matches_einsum_grad() -> None:
     # flash and einsum ring backwards are interchangeable (training can
     # switch block_impl without a trajectory break)
-    monkeypatch.setenv("TORCHFT_TPU_PALLAS_INTERPRET", "1")
     mesh = ft_mesh({"seq": 8})
     B, S, H, D = 1, 64, 2, 8
     rng = np.random.default_rng(12)
@@ -315,6 +306,7 @@ def test_ring_attention_flash_grad_matches_einsum_grad(
     ring_e = make_ring_attention(mesh, "seq", causal=True)
     ring_f = make_ring_attention(
         mesh, "seq", causal=True, block_impl="flash", block_q=8, block_k=8,
+        interpret=True,
     )
 
     ge = jax.jit(jax.grad(

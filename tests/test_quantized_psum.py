@@ -50,7 +50,6 @@ from torchft_tpu.comm.xla_backend import (
     MeshManager,
     XlaCommContext,
     device_codec_roundtrip,
-    pallas_block_quant,
 )
 
 CHUNK = 1 << 12  # small grid: multiple chunks + per-chunk int8 scales
@@ -515,76 +514,3 @@ def test_sharded_update_over_quantized_psum_scatter(mesh_mgr) -> None:
         # difference vs the replicated fp32 arm is the int8 wire
         envelope = 0.1 * 2 * float(np.abs(grads0[k]).max()) / 100
         assert float(np.abs(quant[0][k] - full[0][k]).max()) <= envelope
-
-
-# -------------------------------------------------- pallas fallback
-
-
-def test_pallas_block_quant_matches_host_quantizer() -> None:
-    # The fallback kernel (f32 scale math) is NUMERIC parity with the
-    # host codec: scale within 1 ulp, q within +-1 count, tail block
-    # handled via zero padding (zeros never raise an absmax).
-    import jax
-    from torchft_tpu.comm.transport import _Int8Codec
-
-    rng = np.random.default_rng(4)
-    x = rng.standard_normal(5000).astype(np.float32)  # 4 full + 1 tail
-    step = 1024
-    q, s = jax.jit(lambda v: pallas_block_quant(v, step))(x)
-    q, s = np.asarray(q), np.asarray(s)
-    assert q.shape == (5000,) and s.shape == (5,)
-    for ci in range(5):
-        blk = x[ci * step: (ci + 1) * step]
-        sc_h, q_h = _Int8Codec._quantize(blk)
-        assert np.isclose(s[ci], sc_h, rtol=1e-6)
-        assert np.abs(
-            q[ci * step: ci * step + blk.size].astype(np.int32)
-            - q_h.astype(np.int32)
-        ).max() <= 1
-    # nonfinite block poisons its OWN scale only
-    bad = x.copy()
-    bad[0] = np.nan
-    q2, s2 = jax.jit(lambda v: pallas_block_quant(v, step))(bad)
-    s2 = np.asarray(s2)
-    assert np.isnan(s2[0]) and np.isfinite(s2[1:]).all()
-    assert (np.asarray(q2)[:step] == 0).all()
-
-
-def test_pallas_fallback_end_to_end(monkeypatch) -> None:
-    # TORCHFT_TPU_QPSUM_PALLAS=1 swaps the phase-1 quantizer for the
-    # pallas kernel; the impl is part of the cache key (a flip compiles
-    # a new executable, never serves the stale one) and the numeric
-    # envelope is unchanged.
-    monkeypatch.setenv("TORCHFT_TPU_QPSUM_PALLAS", "1")
-    mm = MeshManager()
-    world = 2
-    inputs = _inputs(world, seed=23, size=3000)
-    ctxs = _qpsum_ctxs(mm, world, "int8")
-
-    def body(ctx, rank):
-        out = []
-        for _ in range(2):
-            w = ctx.allreduce([inputs[rank].copy()])
-            out.append(w.future().result(timeout=120)[0])
-        return out
-
-    results = _run_cohort(ctxs, "qpallas", world, body, timeout=300)
-    assert mm.compile_count == 1 and mm.trace_count == 1
-    exact = np.sum(inputs, axis=0, dtype=np.float64)
-    absmax = max(float(np.abs(a).max()) for a in inputs)
-    assert float(np.abs(results[0][0] - exact).max()) < (
-        (world + 1) * absmax / 100
-    )
-    # flipping the impl back is a NEW cache key (one more compile, not
-    # a silent stale hit)
-    monkeypatch.setenv("TORCHFT_TPU_QPSUM_PALLAS", "0")
-    _run_cohort(
-        [c for c in ctxs], "qpallas2", world,
-        lambda ctx, rank: ctx.allreduce(
-            [inputs[rank].copy()]
-        ).future().result(timeout=120),
-        timeout=300,
-    )
-    assert mm.compile_count == 2
-    for c in ctxs:
-        c.shutdown()

@@ -896,6 +896,10 @@ class _Lane:
                     pending.fut.set_exception(e)
                 except Exception:
                     pass
+            # Let go of the op before blocking on the queue: its future's
+            # continuations reach the caller's buffers (DDP: that step's
+            # gradients on the device), which an idle lane must not pin.
+            pending = result = None
 
     def _execute(self, p: _PendingOp):
         self._seq += 1

@@ -117,7 +117,6 @@ __all__ = [
     "MeshManager",
     "default_mesh_manager",
     "device_codec_roundtrip",
-    "pallas_block_quant",
 ]
 
 _AXIS = "replica"
@@ -417,7 +416,7 @@ def _build_allreduce(mesh_mgr: MeshManager, world_size: int,
     reduced value. ``layouts`` is [(flat_size, dtype), ...]."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -510,7 +509,7 @@ def _build_allreduce(mesh_mgr: MeshManager, world_size: int,
             local, mesh=mesh,
             in_specs=(P(),) + tuple(P(axis) for _ in stacked),
             out_specs=tuple(P(axis) for _ in stacked),
-            check_rep=False,
+            check_vma=False,
         )(z, *stacked)
 
     rep = NamedSharding(mesh, P())
@@ -526,9 +525,9 @@ def _build_allreduce(mesh_mgr: MeshManager, world_size: int,
 def _x64_trace():
     """x64 enabled for TRACE/LOWER time only (the int8 scale's f64
     divide); runtime execution is config-independent."""
-    from jax.experimental import enable_x64
+    import jax
 
-    return enable_x64(True)
+    return jax.enable_x64(True)
 
 
 def _build_psum_scatter(mesh_mgr: MeshManager, world_size: int, op: str,
@@ -541,7 +540,7 @@ def _build_psum_scatter(mesh_mgr: MeshManager, world_size: int, op: str,
     the payload and no rank ever materializes the full reduction."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -562,7 +561,7 @@ def _build_psum_scatter(mesh_mgr: MeshManager, world_size: int, op: str,
         mesh_mgr._note_trace()
         return shard_map(
             local, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )(stacked)
 
     row = NamedSharding(mesh, P(axis))
@@ -571,72 +570,6 @@ def _build_psum_scatter(mesh_mgr: MeshManager, world_size: int, op: str,
 
 
 # ------------------------------------------------- quantized psum builders
-
-
-def _quant_impl() -> str:
-    """Which block-quantizer the quantized-psum builders trace:
-    ``"xla"`` (default — the per-chunk jnp loop XLA fuses into the
-    exchange) or ``"pallas"`` (TORCHFT_TPU_QPSUM_PALLAS=1 — one
-    hand-written kernel per payload, the fallback for block-scale
-    patterns XLA's fusion gives up on: very large chunk counts or
-    odd chunk/tile interactions on real TPUs). Part of the executable
-    cache key, so flipping the env mid-run compiles a new executable
-    instead of silently serving the old one."""
-    import os
-
-    return "pallas" if os.environ.get(
-        "TORCHFT_TPU_QPSUM_PALLAS", "0"
-    ) == "1" else "xla"
-
-
-def _pallas_quant_kernel(x_ref, q_ref, s_ref):
-    """One grid step = one block: absmax scale + int8 payload. Scale
-    math is f32 (pallas has no f64 path), so this quantizer is NUMERIC
-    parity with the host codec (scale can differ by 1 ulp, q by ±1),
-    not bitwise — the xla impl remains the bit-matched default."""
-    import jax.numpy as jnp
-
-    x = x_ref[...]
-    absmax = jnp.max(jnp.abs(x))
-    scale = jnp.where(
-        absmax > 0, absmax / np.float32(127.0), np.float32(1.0)
-    ).astype(jnp.float32)
-    scale = jnp.where(jnp.isfinite(absmax), scale, jnp.float32(np.nan))
-    q = jnp.clip(jnp.rint(x / scale), -127.0, 127.0).astype(jnp.int8)
-    q = jnp.where(jnp.isfinite(absmax), q, jnp.int8(0))
-    q_ref[...] = q
-    s_ref[...] = jnp.full((1, 1), scale, jnp.float32)
-
-
-def pallas_block_quant(x, step: int):
-    """Block-wise absmax int8 quantization of a flat f32 array as ONE
-    pallas kernel (grid = blocks of ``step`` elements — the PR 2 chunk
-    grid). Returns ``(q int8 (size,), scales f32 (n_blocks,))``.
-    Interpreted off-TPU (the CPU sandbox), compiled on real hardware.
-    The tail block is zero-padded for the kernel; zeros never raise an
-    absmax, so tail scales match the unpadded chunk's."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    size = x.shape[0]
-    blocks = max(1, -(-size // step))
-    padded = jnp.pad(x, (0, blocks * step - size))
-    q2, s2 = pl.pallas_call(
-        _pallas_quant_kernel,
-        grid=(blocks,),
-        in_specs=[pl.BlockSpec((1, step), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, step), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((blocks, step), jnp.int8),
-            jax.ShapeDtypeStruct((blocks, 1), jnp.float32),
-        ],
-        interpret=jax.default_backend() != "tpu",
-    )(padded.reshape(blocks, step))
-    return q2.reshape(-1)[:size], s2.reshape(-1)
 
 
 def _grid_bounds(size: int, chunk_bytes: int,
@@ -653,17 +586,15 @@ def _grid_bounds(size: int, chunk_bytes: int,
     return [(s, min(size, s + step)) for s in range(0, size, step)]
 
 
-def _quantize_chunks(x, z, bounds, quant_impl: str):
+def _quantize_chunks(x, z, bounds):
     """``(q int8 (size,), scales f32 (len(bounds),))`` over a non-empty
-    chunk-bound list — the ONE phase-1 quantizer dispatch shared by
-    :func:`_build_quantized_psum` and
-    :func:`_build_quantized_psum_scatter` (a fix to either impl lands
-    on both wires)."""
+    chunk-bound list — the ONE phase-1 quantizer shared by
+    :func:`_build_quantized_psum`,
+    :func:`_build_quantized_psum_scatter` and the fused step (a fix
+    lands on every wire): the per-chunk jnp loop XLA fuses into the
+    exchange."""
     import jax.numpy as jnp
 
-    if quant_impl == "pallas":
-        step = bounds[0][1] - bounds[0][0]
-        return pallas_block_quant(x, step)
     qs, scs = [], []
     for s, e in bounds:
         q, sc = _dev_quant_int8(x[s:e], z)
@@ -676,8 +607,7 @@ def _quantize_chunks(x, z, bounds, quant_impl: str):
 
 def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
                           codec_name: str, chunk_bytes: int, op: str,
-                          layouts: Sequence[Tuple[int, np.dtype]],
-                          quant_impl: str = "xla"):
+                          layouts: Sequence[Tuple[int, np.dtype]]):
     """Compile ONE quantized allreduce on the hardware-native exchange
     path (EQuARX-style, ROADMAP item 2): for each f32 payload —
 
@@ -694,7 +624,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
        SAME reduced values).
 
     One executable, cached per ``(world, codec, chunk grid, op,
-    layouts, quant impl)`` like every PR 6 collective — a kill/reform
+    layouts)`` like every PR 6 collective — a kill/reform
     at a seen world size is a cache lookup, never a retrace. Like raw
     ``psum``, XLA owns scheduling, so this path is NUMERIC (outside the
     bitwise A/B); the phase-1 encode is bit-matched to the host codec
@@ -705,7 +635,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -719,7 +649,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
     def reduce_int8(x, z, size, L, padn, d):
         bounds = _grid_bounds(size, chunk_bytes)
         lens = np.array([e - s for s, e in bounds])
-        q_full, scales = _quantize_chunks(x, z, bounds, quant_impl)
+        q_full, scales = _quantize_chunks(x, z, bounds)
         qt = lax.all_to_all(
             jnp.pad(q_full, (0, padn)).reshape(n, L), axis, 0, 0
         )
@@ -744,8 +674,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
         # phase 2: re-encode the reduced shard (shard-local grid) and
         # broadcast it encoded — every rank decodes identical bytes
         shard_bounds = _grid_bounds(L, chunk_bytes)
-        q_shard, sc_shard = _quantize_chunks(acc, z, shard_bounds,
-                                             quant_impl)
+        q_shard, sc_shard = _quantize_chunks(acc, z, shard_bounds)
         qg = lax.all_gather(q_shard, axis)
         sg = lax.all_gather(sc_shard, axis)
         parts = [
@@ -803,7 +732,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
             local, mesh=mesh,
             in_specs=(P(),) + tuple(P(axis) for _ in stacked),
             out_specs=tuple(P(axis) for _ in stacked),
-            check_rep=False,
+            check_vma=False,
         )(z, *stacked)
 
     rep = NamedSharding(mesh, P())
@@ -818,8 +747,7 @@ def _build_quantized_psum(mesh_mgr: MeshManager, world_size: int,
 
 def _build_quantized_psum_scatter(mesh_mgr: MeshManager, world_size: int,
                                   codec_name: str, chunk_bytes: int,
-                                  op: str, sizes: Sequence[int],
-                                  quant_impl: str = "xla"):
+                                  op: str, sizes: Sequence[int]):
     """Quantized reduce_scatter on the native path: phase 1 of
     :func:`_build_quantized_psum` alone — each rank quantizes its
     contribution to every destination array (per-chunk scales on each
@@ -829,11 +757,11 @@ def _build_quantized_psum_scatter(mesh_mgr: MeshManager, world_size: int,
     allgathers PARAMS after the optimizer step, not gradients. Input
     layout matches :func:`_build_psum_scatter` ((world, world*L)
     stacked f32, one slot per destination rank); cached per (world,
-    codec, chunk grid, op, sizes, quant impl)."""
+    codec, chunk grid, op, sizes)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -850,8 +778,7 @@ def _build_quantized_psum_scatter(mesh_mgr: MeshManager, world_size: int,
             if codec_name == "int8":
                 q_rows, s_rows = [], []
                 for j in range(n):
-                    q_j, s_j = _quantize_chunks(x[j], z, bounds,
-                                                quant_impl)
+                    q_j, s_j = _quantize_chunks(x[j], z, bounds)
                     q_rows.append(q_j)
                     s_rows.append(s_j)
                 qt = lax.all_to_all(jnp.stack(q_rows), axis, 0, 0)
@@ -881,7 +808,7 @@ def _build_quantized_psum_scatter(mesh_mgr: MeshManager, world_size: int,
         mesh_mgr._note_trace()
         return shard_map(
             local, mesh=mesh, in_specs=(P(), P(axis)),
-            out_specs=P(axis), check_rep=False,
+            out_specs=P(axis), check_vma=False,
         )(z, stacked)
 
     rep = NamedSharding(mesh, P())
@@ -921,14 +848,14 @@ class _FusedSpec:
 
     __slots__ = (
         "replicas", "model_shards", "param_size", "batch_size",
-        "codec_name", "chunk_bytes", "quant_impl", "error_feedback",
+        "codec_name", "chunk_bytes", "error_feedback",
         "loss_fn", "tx", "opt_treedef", "opt_leaf_shapes",
         "opt_leaf_dtypes", "fn_key", "q_len", "p_len", "s_len",
     )
 
     def __init__(self, replicas: int, model_shards: int, param_size: int,
                  batch_size: int, codec_name: str, chunk_bytes: int,
-                 quant_impl: str, error_feedback: bool, loss_fn, tx,
+                 error_feedback: bool, loss_fn, tx,
                  opt_treedef, opt_leaf_shapes, opt_leaf_dtypes,
                  fn_key: str) -> None:
         self.replicas = int(replicas)
@@ -937,7 +864,6 @@ class _FusedSpec:
         self.batch_size = int(batch_size)
         self.codec_name = codec_name
         self.chunk_bytes = int(chunk_bytes)
-        self.quant_impl = quant_impl
         self.error_feedback = bool(error_feedback)
         self.loss_fn = loss_fn
         self.tx = tx
@@ -954,11 +880,11 @@ class _FusedSpec:
     def exec_key(self, kind: str) -> Tuple:
         """MeshManager executable-cache key for one program of the
         family (``kind``: "fused" or a stage name): pins mesh shape,
-        codec, chunk grid, quantizer impl, EF arm, layouts and the
+        codec, chunk grid, EF arm, layouts and the
         caller-supplied (loss_fn, tx) identity."""
         return (
             "fused_step", kind, self.replicas, self.model_shards,
-            self.codec_name, self.chunk_bytes, self.quant_impl,
+            self.codec_name, self.chunk_bytes,
             self.error_feedback, self.param_size, self.batch_size,
             self.opt_leaf_shapes,
             tuple(str(d) for d in self.opt_leaf_dtypes), self.fn_key,
@@ -1043,9 +969,7 @@ def _fused_local_fns(mesh_mgr: MeshManager, spec: "_FusedSpec"):
         rows = gq.reshape(R, q_len)
         q_rows, s_rows, w_rows = [], [], []
         for j in range(R):
-            q_j, s_j = _quantize_chunks(
-                rows[j], z, bounds, spec.quant_impl
-            )
+            q_j, s_j = _quantize_chunks(rows[j], z, bounds)
             q_rows.append(q_j)
             s_rows.append(s_j)
             if ef:
@@ -1147,7 +1071,7 @@ def _build_fused_step(mesh_mgr: MeshManager, spec: "_FusedSpec"):
     copies back), never partially mutated mid-flight."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axes = _fused_axes(mesh_mgr, spec)
@@ -1178,7 +1102,7 @@ def _build_fused_step(mesh_mgr: MeshManager, spec: "_FusedSpec"):
             local, mesh=mesh,
             in_specs=(P(),) + (P(axes),) * n,
             out_specs=(P(axes),) * n,
-            check_rep=False,
+            check_vma=False,
         )(z, p, b, e, *opt_leaves)
 
     rep, row, avals = _fused_avals(mesh_mgr, spec)
@@ -1198,7 +1122,7 @@ def _build_step_stage(mesh_mgr: MeshManager, spec: "_FusedSpec",
     ``gather``   ``fn(new_sub) -> (new_p,)``"""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, axes = _fused_axes(mesh_mgr, spec)
@@ -1218,7 +1142,7 @@ def _build_step_stage(mesh_mgr: MeshManager, spec: "_FusedSpec",
             return shard_map(
                 local, mesh=mesh,
                 in_specs=(P(), P(axes), P(axes)),
-                out_specs=(P(axes), P(axes)), check_rep=False,
+                out_specs=(P(axes), P(axes)), check_vma=False,
             )(z, p, b)
 
         args = [avals["z"], avals["p"], avals["b"]]
@@ -1232,7 +1156,7 @@ def _build_step_stage(mesh_mgr: MeshManager, spec: "_FusedSpec",
             return shard_map(
                 local, mesh=mesh,
                 in_specs=(P(), P(axes), P(axes)),
-                out_specs=(P(axes), P(axes)), check_rep=False,
+                out_specs=(P(axes), P(axes)), check_vma=False,
             )(z, gm, e)
 
         args = [avals["z"], avals["p"], avals["e"]]
@@ -1258,7 +1182,7 @@ def _build_step_stage(mesh_mgr: MeshManager, spec: "_FusedSpec",
                 local, mesh=mesh,
                 in_specs=(P(),) + (P(axes),) * n,
                 out_specs=(P(axes),) * (1 + len(opt_leaves)),
-                check_rep=False,
+                check_vma=False,
             )(z, h, p, *opt_leaves)
 
         args = [avals["z"], avals["h"], avals["p"]] + avals["opt"]
@@ -1270,7 +1194,7 @@ def _build_step_stage(mesh_mgr: MeshManager, spec: "_FusedSpec",
             mesh_mgr._note_trace()
             return shard_map(
                 local, mesh=mesh, in_specs=(P(axes),),
-                out_specs=(P(axes),), check_rep=False,
+                out_specs=(P(axes),), check_vma=False,
             )(new_sub)
 
         args = [avals["ns"]]
@@ -1310,7 +1234,7 @@ def _build_hier_allreduce(mesh_mgr: MeshManager, world_size: int,
     PR 6 collective."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -1374,7 +1298,7 @@ def _build_hier_allreduce(mesh_mgr: MeshManager, world_size: int,
             local, mesh=mesh,
             in_specs=(P(),) + tuple(P(axis) for _ in stacked),
             out_specs=tuple(P(axis) for _ in stacked),
-            check_rep=False,
+            check_vma=False,
         )(z, *stacked)
 
     rep = NamedSharding(mesh, P())
@@ -1408,7 +1332,7 @@ def _build_hier_psum(mesh_mgr: MeshManager, world_size: int,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = world_size
@@ -1472,7 +1396,7 @@ def _build_hier_psum(mesh_mgr: MeshManager, world_size: int,
             local, mesh=mesh,
             in_specs=(P(),) + tuple(P(axis) for _ in stacked),
             out_specs=tuple(P(axis) for _ in stacked),
-            check_rep=False,
+            check_vma=False,
         )(z, *stacked)
 
     rep = NamedSharding(mesh, P())
@@ -2054,14 +1978,11 @@ class _XlaGroup:
                 # the quantized native exchange (EQuARX): encode →
                 # all_to_all/all_gather of encoded payloads → decode-
                 # accumulate, one executable cached per (world, codec,
-                # grid, op, layouts, quant impl) like every collective
-                quant_impl = _quant_impl()
-                key = (n, "psum_q", codec_name, chunk_bytes, op,
-                       layouts, quant_impl)
+                # grid, op, layouts) like every collective
+                key = (n, "psum_q", codec_name, chunk_bytes, op, layouts)
                 build = lambda: _build_quantized_psum(  # noqa: E731
                     mm, n, codec_name, chunk_bytes, op,
                     [(s, np.dtype(d)) for (s, d) in layouts],
-                    quant_impl,
                 )
             else:
                 key = (n, algorithm, codec_name, chunk_bytes, op, layouts)
@@ -2252,12 +2173,11 @@ class _XlaGroup:
             # quantized native reduce_scatter: phase 1 of the quantized
             # psum alone — encoded all_to_all, owner-side decode-
             # accumulate (the sharded weight update's gradient hop)
-            quant_impl = _quant_impl()
             key = (n, "psum_scatter_q", codec_name, chunk_bytes, op,
-                   sizes, quant_impl)
+                   sizes)
             compiled, (rep, row) = mm.executable(
                 key, lambda: _build_quantized_psum_scatter(
-                    mm, n, codec_name, chunk_bytes, op, sizes, quant_impl
+                    mm, n, codec_name, chunk_bytes, op, sizes
                 )
             )
         else:

@@ -1,77 +1,114 @@
 """ctypes loader for the native control plane (libtorchft_tpu_native.so).
 
-Builds the library from native/ on first use if missing or stale (make is
-part of the baked toolchain). The C ABI is defined in native/capi.cc; the
-reference achieves the same Python↔native embedding with pyo3
-(/root/reference/src/lib.rs) — pybind11 is unavailable here, so the ABI is
-plain C consumed via ctypes, which also conveniently releases the GIL for
-every native call (parity with py.allow_threads at ref lib.rs:54,98).
+Builds the library from native/ on first use if it is missing or was not
+built from the sources at hand (make is part of the baked toolchain). The
+C ABI is defined in native/capi.cc; the reference achieves the same
+Python↔native embedding with pyo3 (/root/reference/src/lib.rs) — pybind11
+is unavailable here, so the ABI is plain C consumed via ctypes, which also
+conveniently releases the GIL for every native call (parity with
+py.allow_threads at ref lib.rs:54,98).
+
+Staleness is decided by content, not by time: a sha256 over the
+Makefile, its ``SRCS`` and every header is kept in a stamp beside the
+library, and the library is rebuilt exactly when the stamp differs. File
+times say nothing here — a checkout, an archive or a copy of the tree
+sets them arbitrarily, and the objects are git-ignored, so a copied tree
+can pair fresh-looking objects with other sources. The build is
+serialised across processes by a lock file, so several workers started
+at once on a fresh checkout run one ``make``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
+import re
 import subprocess
 import threading
-from typing import Optional
+from typing import List, Optional
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libtorchft_tpu_native.so")
+_LIB_NAME = "libtorchft_tpu_native.so"
+_LIB_PATH = os.path.join(_NATIVE_DIR, _LIB_NAME)
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _lib_cc_sources() -> "Optional[set]":
-    """The .cc files that are actually inputs to the .so, read from the
-    Makefile's SRCS line (the single source of truth). Sanitizer-plane
-    sources (churn_stress.cc, the tsan compat shim) are NOT in SRCS:
-    `make` never relinks the lib for them, so counting them in the
-    staleness scan would make _needs_build() permanently true — a
-    no-op make on every import, and a hard build failure on
-    toolchain-less machines with a perfectly good prebuilt .so.
-    Returns None (scan every .cc) if the Makefile cannot be parsed."""
+def _build_inputs(native_dir: str) -> List[str]:
+    """Every file the library is a function of: the Makefile, the .cc
+    files on its ``SRCS`` line (the single source of truth —
+    sanitizer-plane sources such as churn_stress.cc are NOT inputs and
+    must not force rebuilds) and every header. If the Makefile cannot be
+    parsed, every .cc counts."""
+    with open(os.path.join(native_dir, "Makefile")) as f:
+        m = re.search(r"^SRCS\s*=\s*(.+)$", f.read(), re.MULTILINE)
+    lib_srcs = set(m.group(1).split()) if m else None
+    names = ["Makefile"]
+    for name in os.listdir(native_dir):
+        if name.endswith(".h") or (
+            name.endswith(".cc") and (lib_srcs is None or name in lib_srcs)
+        ):
+            names.append(name)
+    return sorted(names)
+
+
+def source_digest(native_dir: str = _NATIVE_DIR) -> str:
+    """sha256 over the names and bytes of :func:`_build_inputs`."""
+    h = hashlib.sha256()
+    for name in _build_inputs(native_dir):
+        with open(os.path.join(native_dir, name), "rb") as f:
+            data = f.read()
+        h.update(f"{name}:{len(data)}:".encode())
+        h.update(data)
+    return h.hexdigest()
+
+
+def built_digest(native_dir: str = _NATIVE_DIR) -> Optional[str]:
+    """The source digest the library on disk was built from (its stamp),
+    or None if there is no library or no stamp."""
+    lib = os.path.join(native_dir, _LIB_NAME)
     try:
-        with open(os.path.join(_NATIVE_DIR, "Makefile")) as f:
-            text = f.read()
+        with open(lib + ".stamp") as f:
+            stamp = f.read().strip()
     except OSError:
         return None
-    import re
-
-    m = re.search(r"^SRCS\s*=\s*(.+)$", text, re.MULTILINE)
-    if not m:
-        return None
-    return set(m.group(1).split())
+    return stamp if os.path.exists(lib) else None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    lib_srcs = _lib_cc_sources()
-    for name in os.listdir(_NATIVE_DIR):
-        is_input = name.endswith(".h") or (
-            name.endswith(".cc") and (lib_srcs is None or name in lib_srcs)
+def ensure_built(native_dir: str = _NATIVE_DIR) -> bool:
+    """Make the library on disk match the sources; True if this call ran
+    the build. Safe to call from many processes at once: the check is
+    repeated under an exclusive lock, so whoever loses the race finds the
+    winner's stamp and builds nothing."""
+    digest = source_digest(native_dir)
+    if built_digest(native_dir) == digest:
+        return False
+    lib = os.path.join(native_dir, _LIB_NAME)
+    with open(os.path.join(native_dir, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if built_digest(native_dir) == digest:
+            return False
+        # -B: the objects on disk may come from other sources with newer
+        # times (see module docstring), so make's own check is not trusted.
+        result = subprocess.run(
+            ["make", "-B", "-j", "-C", native_dir],
+            capture_output=True,
+            text=True,
         )
-        if is_input:
-            if os.path.getmtime(os.path.join(_NATIVE_DIR, name)) > lib_mtime:
-                return True
-    return False
-
-
-def _build() -> None:
-    result = subprocess.run(
-        ["make", "-j", "-C", _NATIVE_DIR],
-        capture_output=True,
-        text=True,
-    )
-    if result.returncode != 0:
-        raise RuntimeError(
-            "failed to build native control plane:\n"
-            f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
-        )
+        if result.returncode != 0:
+            raise RuntimeError(
+                "failed to build native control plane:\n"
+                f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
+            )
+        tmp = f"{lib}.stamp.{os.getpid()}"
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, lib + ".stamp")
+    return True
 
 
 def _configure(lib: ctypes.CDLL) -> None:
@@ -193,8 +230,7 @@ def get_lib() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is None:
-            if _needs_build():
-                _build()
+            ensure_built()
             lib = ctypes.CDLL(_LIB_PATH)
             _configure(lib)
             _lib = lib

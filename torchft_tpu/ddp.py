@@ -25,9 +25,9 @@ caller's thread —
           OFF the submit path, so bucket k+1's pack/submit never stalls
           behind bucket k's quantizer)
     wire  the transport round trip (lanes; chunk-striped)
-    h2d   unpack + the ``jnp.array`` copy back to device, per bucket AS
-          ITS WIRE FUTURE COMPLETES (continuation → bounded worker),
-          out of order — not after a global drain
+    h2d   unpack + the copy back to each gradient leaf's own device(s),
+          per bucket AS ITS WIRE FUTURE COMPLETES (continuation →
+          bounded worker), out of order — not after a global drain
 
 The step future resolves when the last bucket has landed AND every EF
 task has finished, so ``.result()`` still means "arena quiescent,
@@ -91,6 +91,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from torchft_tpu.futures import FutureGroup, future_all, future_chain
+from torchft_tpu.utils.device import land_like
 from torchft_tpu.utils.profiling import timed_span
 
 __all__ = [
@@ -161,6 +162,15 @@ def _ef_gate(manager, error_feedback: "bool | str") -> bool:
                 return False
     is_part = getattr(manager, "is_participating", None)
     return (not callable(is_part)) or bool(is_part())
+
+
+def _land_leaf(view: np.ndarray, like: Any) -> Any:
+    """The averaged gradient for leaf ``like``, as a device array with
+    ``like``'s dtype and sharding (a numpy gradient's lands on the default
+    device). Always a COPY of ``view``: the views point into a reusable
+    staging arena, and a result that aliased it would be silently
+    overwritten by the arena's next pack."""
+    return land_like(view, like) if hasattr(like, "dtype") else view
 
 
 class _BucketPlan:
@@ -540,19 +550,13 @@ class DistributedDataParallel:
                      in_leaves: List[Any], out_leaves: List[Any],
                      metrics) -> None:
         """Stage h2d: unpack bucket k's reduced flat array into its
-        leaves and copy them back to device. jnp.array (copy=True), NOT
-        jnp.asarray: on the CPU backend asarray aliases the numpy buffer
-        — these views point into the reusable arena, and an aliased
-        result would be silently overwritten by the arena's NEXT pack."""
-        import jax.numpy as jnp
-
+        leaves and copy each back to the device(s) of the gradient leaf
+        it replaces (:func:`_land_leaf`). This runs on a pool thread, so
+        the placement must come from the leaf, not from any thread-local
+        default device of the caller."""
         with timed_span(metrics, "ddp_h2d", span=f"ddp_unpack_bucket{k}"):
             for i, view in plan.unpack_bucket(k, reduced):
-                l = in_leaves[i]
-                out_leaves[i] = (
-                    jnp.array(view, dtype=l.dtype)
-                    if hasattr(l, "dtype") else view
-                )
+                out_leaves[i] = _land_leaf(view, in_leaves[i])
 
     # ----------------------------------------------------------- code paths
 
@@ -1018,7 +1022,6 @@ class PureDistributedDataParallel:
 
     def average_gradients(self, grads: Any) -> Any:
         import jax
-        import jax.numpy as jnp
 
         try:
             self._manager.wait_quorum()
@@ -1033,7 +1036,7 @@ class PureDistributedDataParallel:
         host = [np.asarray(jax.device_get(l)) for l in leaves]
         works = [self._manager.allreduce_arrays([h]) for h in host]
         out = [
-            jnp.asarray(w.future().result()[0], dtype=l.dtype)
+            _land_leaf(w.future().result()[0], l)
             for w, l in zip(works, leaves)
         ]
         return jax.tree_util.tree_unflatten(treedef, out)

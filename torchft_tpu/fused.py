@@ -46,7 +46,6 @@ from torchft_tpu.comm.xla_backend import (
     _build_fused_step,
     _build_step_stage,
     _fused_avals,
-    _quant_impl,
 )
 from torchft_tpu.utils.metrics import Metrics
 
@@ -114,7 +113,6 @@ class FusedStepEngine:
             batch_size=int(batch_size),
             codec_name=codec,
             chunk_bytes=int(chunk_bytes),
-            quant_impl=_quant_impl(),
             error_feedback=bool(error_feedback),
             loss_fn=loss_fn,
             tx=tx,
@@ -339,7 +337,6 @@ class FusedStepEngine:
             batch_size=old.batch_size,
             codec_name=old.codec_name,
             chunk_bytes=old.chunk_bytes,
-            quant_impl=old.quant_impl,
             error_feedback=old.error_feedback,
             loss_fn=old.loss_fn,
             tx=old.tx,

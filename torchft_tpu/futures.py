@@ -39,24 +39,37 @@ __all__ = [
 
 
 class TimerHandle:
-    """Cancellable handle to a pending deadline (ref futures.py:12-29)."""
+    """Cancellable handle to a pending deadline (ref futures.py:12-29).
+    The handle owns the callback and lets go of it when cancelled: the
+    timer heap keeps a cancelled entry until its deadline, and whatever
+    the callback closes over — a whole step's futures, and through their
+    continuations the gradients on the device — must not wait that long
+    to be freed."""
 
-    def __init__(self) -> None:
+    def __init__(self, fn: "Optional[Callable[[], None]]" = None) -> None:
         self._lock = threading.Lock()
         self._cancelled = False
+        self._fn = fn
 
     def cancel(self) -> None:
         with self._lock:
             self._cancelled = True
+            self._fn = None
 
     @property
     def cancelled(self) -> bool:
         with self._lock:
             return self._cancelled
 
+    def take(self) -> "Optional[Callable[[], None]]":
+        """The callback, once: None if cancelled or already taken."""
+        with self._lock:
+            fn, self._fn = self._fn, None
+            return fn
+
 
 class _TimerManager:
-    """Singleton deadline thread: min-heap of (deadline, seq, handle, fn).
+    """Singleton deadline thread: min-heap of (deadline, seq, handle).
 
     Replaces the reference's asyncio ``call_later`` loop
     (ref futures.py:32-117) with a plain condition-variable heap, which is
@@ -78,9 +91,9 @@ class _TimerManager:
             self._thread.start()
 
     def call_at(self, deadline: float, fn: Callable[[], None]) -> TimerHandle:
-        handle = TimerHandle()
+        handle = TimerHandle(fn)
         with self._lock:
-            heapq.heappush(self._heap, (deadline, next(self._seq), handle, fn))
+            heapq.heappush(self._heap, (deadline, next(self._seq), handle))
             self._ensure_thread()
             self._lock.notify()
         return handle
@@ -92,13 +105,14 @@ class _TimerManager:
             with self._lock:
                 while not self._heap:
                     self._lock.wait()
-                deadline, _, handle, fn = self._heap[0]
+                deadline, _, handle = self._heap[0]
                 now = time.monotonic()
                 if deadline > now:
                     self._lock.wait(timeout=deadline - now)
                     continue
                 heapq.heappop(self._heap)
-            if not handle.cancelled:
+            fn = handle.take()
+            if fn is not None:
                 try:
                     fn()
                 except Exception:  # timer callbacks must never kill the thread

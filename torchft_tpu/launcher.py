@@ -19,6 +19,15 @@ Env contract per worker (consumed by torchft_tpu.Manager):
                                 it; other ranks connect)
     TORCHFT_TPU_MANAGER_PORT    the group's manager server port (29600+i,
                                 mirroring the reference's convention)
+    TPU_VISIBLE_CHIPS / TPU_CHIPS_PER_PROCESS_BOUNDS / TPU_PROCESS_BOUNDS
+                            the worker's own chip (:func:`chip_env`)
+
+One process per chip: a TPU chip belongs to one process at a time, and a
+process that initialises jax with no restriction claims every chip of the
+host, so N unrestricted workers on one host collide (libtpu aborts all but
+one on its lock file). Every spec therefore names its worker's chip. The
+launching process itself must stay off jax backends — it would hold the
+chips its workers need; nothing in this module initialises one.
 """
 
 from __future__ import annotations
@@ -31,7 +40,25 @@ from typing import Dict, List, Optional
 
 from torchft_tpu.manager import LIGHTHOUSE_ENV, MANAGER_PORT_ENV
 
-__all__ = ["ReplicaGroupSpec", "hsdp_spec", "launch_local", "LIGHTHOUSE_ENV"]
+__all__ = ["ReplicaGroupSpec", "chip_env", "hsdp_spec", "launch_local",
+           "LIGHTHOUSE_ENV"]
+
+
+def chip_env(worker_index: int) -> Dict[str, str]:
+    """libtpu environment that gives one worker process exactly one chip
+    of its host: chip ``worker_index`` made the only visible one, and the
+    process declared a complete 1x1x1 topology of its own, so libtpu
+    neither waits for sibling processes nor needs per-process ports.
+    Inside the worker the chip is always ``jax.devices()[0]`` with id 0.
+    Established on a four-chip v5e host with libtpu 0.0.34
+    (docs/operations.md, "One process per chip"); ``TPU_VISIBLE_CHIPS``
+    alone is not enough. Harmless where libtpu is never initialised
+    (``JAX_PLATFORMS=cpu``)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(worker_index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
 
 
 @dataclass
@@ -57,11 +84,16 @@ def hsdp_spec(
 ) -> List[ReplicaGroupSpec]:
     """One spec per worker (num_replica_groups × workers_per_group total),
     with full rank/store plumbing — rank 0 of each group binds the group
-    store at MASTER_ADDR:MASTER_PORT, other ranks connect to it."""
+    store at MASTER_ADDR:MASTER_PORT, other ranks connect to it — and one
+    chip per worker, numbered in spec order (:func:`chip_env`; a host
+    with fewer chips than workers fails the surplus workers at start-up).
+    ``extra_env`` is applied last, so a scheduler that places workers on
+    several hosts can override the chip variables per host."""
     specs = []
     for i in range(num_replica_groups):
         for rank in range(workers_per_group):
             env = {
+                **chip_env(i * workers_per_group + rank),
                 LIGHTHOUSE_ENV: lighthouse_addr,
                 "REPLICA_GROUP_ID": str(i),
                 "NUM_REPLICA_GROUPS": str(num_replica_groups),
@@ -89,8 +121,9 @@ def launch_local(
 ) -> List[subprocess.Popen]:
     """Spawn every worker as a local subprocess (CI / single-host
     experiments). The processes inherit the current env overlaid with the
-    spec env; callers own wait/kill (a kill+relaunch is exactly a replica
-    failure + rejoin)."""
+    spec env — which carries each worker's chip — and callers own
+    wait/kill (a kill+relaunch is exactly a replica failure + rejoin; a
+    SIGKILLed worker's chip is free for its relaunch at once)."""
     procs = []
     for spec in specs:
         env = dict(os.environ)

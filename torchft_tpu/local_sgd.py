@@ -98,6 +98,7 @@ import numpy as np
 from torchft_tpu.comm.wire import split_weighted
 from torchft_tpu.futures import FutureGroup
 from torchft_tpu.optim import PartitionedOuterOptimizer
+from torchft_tpu.utils.device import land, land_like
 from torchft_tpu.utils.profiling import timed_span
 
 logger = logging.getLogger(__name__)
@@ -259,6 +260,10 @@ class LocalSGD:
         self._shapes: Optional[List[Tuple[int, ...]]] = None
         self._dtypes: Optional[List[np.dtype]] = None
         self._sizes: Optional[List[int]] = None
+        # Where each param leaf lives (its jax sharding; None for host
+        # leaves): everything the outer sync hands back to the device
+        # returns there, not to the process's default device.
+        self._shardings: Optional[List[Any]] = None
         self._fragments: Optional[List[Tuple[int, int]]] = None
         self._boundaries: Optional[List[int]] = None
         # Persistent arenas (satellite: no per-sync host allocation):
@@ -327,6 +332,7 @@ class LocalSGD:
         self._shapes = [tuple(x.shape) for x in leaves]
         self._dtypes = [np.dtype(x.dtype) for x in leaves]
         self._sizes = [int(np.prod(s, dtype=np.int64)) for s in self._shapes]
+        self._shardings = [getattr(x, "sharding", None) for x in leaves]
         if any(np.issubdtype(dt, np.integer) for dt in self._dtypes):
             logger.warning(
                 "param tree contains integer leaves: the outer wire "
@@ -432,16 +438,15 @@ class LocalSGD:
         self._healed_backup = True
 
     def restore(self) -> Any:
-        """The last committed (synced) params, as device arrays.
-        ``jnp.array`` (copy), NOT ``asarray``: the backup is a persistent
-        arena now, and on the CPU backend an aliased restore would be
-        silently mutated by the next in-place backup refresh."""
+        """The last committed (synced) params, as device arrays where
+        the registered params live (copies of the backup arena — see
+        :meth:`_to_device`)."""
         import jax
-        import jax.numpy as jnp
 
         assert self._backup is not None, "register() was never called"
         return jax.tree_util.tree_unflatten(
-            self._treedef, [jnp.array(b) for b in self._backup]
+            self._treedef,
+            [self._to_device(i, b) for i, b in enumerate(self._backup)],
         )
 
     # -- stepping ------------------------------------------------------------
@@ -470,8 +475,23 @@ class LocalSGD:
             leaves, treedef = jax.tree_util.tree_flatten(params)
             self._treedef = treedef
             self._build_layout(leaves)
+        elif all(s is None for s in self._shardings):
+            # layout frozen from a heal's host leaves: the first params
+            # seen say where the leaves live
+            self._shardings = [
+                getattr(x, "sharding", None)
+                for x in jax.tree_util.tree_flatten(params)[0]
+            ]
         if self._backup is None:
             self._save_backup_leaves(jax.tree_util.tree_flatten(params)[0])
+
+    def _to_device(self, i: int, host: np.ndarray) -> Any:
+        """A COPY of ``host`` as a device array where param leaf ``i``
+        lives. A copy because callers pass views of persistent arenas
+        (the backup, the staged fragments) that the next round refreshes
+        in place — on the CPU backend an aliased result would be mutated
+        under the caller."""
+        return land(host, self._shardings[i])
 
     def step(self, params: Any) -> Any:
         """Count one inner optimizer step; drive the round machinery
@@ -903,7 +923,6 @@ class LocalSGD:
         owned fragments adopt locally AND ship through the commit
         allgather; remote fragments adopt the owner's bytes."""
         import jax
-        import jax.numpy as jnp
 
         new_leaves: List[Any] = [None] * len(self._shapes)
         if self._sharded_outer and rnd.world > 1:
@@ -917,7 +936,7 @@ class LocalSGD:
                 for j, i in enumerate(range(start, stop)):
                     np.copyto(self._backup[i], frag_leaves[f][j],
                               casting="unsafe")
-                    new_leaves[i] = jnp.array(self._backup[i])
+                    new_leaves[i] = self._to_device(i, self._backup[i])
             return jax.tree_util.tree_unflatten(self._treedef, new_leaves)
         # Replicated arm: decode straight into the persistent backup
         # arena — zero per-sync allocation, the PR 5 contract (the
@@ -938,9 +957,7 @@ class LocalSGD:
                               casting="unsafe")
                 else:
                     np.copyto(self._backup[i], view, casting="unsafe")
-                # jnp.array (copy): the staged view aliases the donated
-                # arena, which the NEXT round packs over.
-                new_leaves[i] = jnp.array(self._backup[i])
+                new_leaves[i] = self._to_device(i, self._backup[i])
                 off += n
         return jax.tree_util.tree_unflatten(self._treedef, new_leaves)
 
@@ -1038,8 +1055,6 @@ class DiLoCo(LocalSGD):
         STAGED (params and state adopted only on commit). Runs on the
         bounded worker in streaming mode — while later fragments are
         still riding the wire."""
-        import jax.numpy as jnp
-
         with timed_span(self._metrics(), "outer_land",
                         span=f"outer_land_frag{f}"):
             start, stop = self._fragments[f]
@@ -1047,14 +1062,14 @@ class DiLoCo(LocalSGD):
             off = 0
             for i in range(start, stop):
                 n = self._sizes[i]
-                grads.append(
-                    jnp.asarray(reduced[off:off + n].reshape(self._shapes[i]))
-                )
+                grads.append(self._to_device(
+                    i, reduced[off:off + n].reshape(self._shapes[i])
+                ))
                 off += n
             # The outer step moves from the last synced point
             # (ref local_sgd.py:216-225) — the backup, untouched for the
             # whole round.
-            frag_params = [jnp.asarray(self._backup[i])
+            frag_params = [self._to_device(i, self._backup[i])
                            for i in range(start, stop)]
             rnd.staged[f] = self._outer.update_fragment(
                 f, grads, frag_params
@@ -1082,8 +1097,16 @@ class DiLoCo(LocalSGD):
                 f"arrays, the transformation expects {len(t_leaves)} — "
                 "outer optimizer configs diverged across replicas"
             )
+        # each donor array takes the place of its template slot; slots
+        # optax left uncommitted (step counts) stay so and follow the
+        # update that consumes them
         return jax.tree_util.tree_unflatten(
-            treedef, [jnp.asarray(a) for a in arrays]
+            treedef,
+            [
+                land_like(a, t) if getattr(t, "committed", False)
+                else jnp.asarray(a)
+                for a, t in zip(arrays, t_leaves)
+            ],
         )
 
     def _on_owner_map(self, rnd: _SyncRound, params: Any) -> None:
@@ -1198,7 +1221,6 @@ class DiLoCo(LocalSGD):
 
     def _commit_round(self, rnd: _SyncRound) -> Any:
         import jax
-        import jax.numpy as jnp
 
         sharded = self._sharded_outer and rnd.world > 1
         new_leaves: List[Any] = [None] * len(self._shapes)
@@ -1218,10 +1240,7 @@ class DiLoCo(LocalSGD):
                     np.copyto(
                         self._backup[i], gathered[f][j], casting="unsafe"
                     )
-                    # jnp.array (copy): the backup arena is refreshed in
-                    # place next round — an alias would be mutated under
-                    # the caller.
-                    new_leaves[i] = jnp.array(self._backup[i])
+                    new_leaves[i] = self._to_device(i, self._backup[i])
             return jax.tree_util.tree_unflatten(self._treedef, new_leaves)
         for f, (start, stop) in enumerate(self._fragments):
             frag_leaves, new_state = rnd.staged[f]

@@ -1,15 +1,14 @@
-"""Attention ops: XLA path everywhere, pallas flash kernel on real TPU.
+"""Attention ops: pallas flash kernel on a TPU, XLA path elsewhere.
 
 The local (per-device) causal attention used by models/transformer.py.
-On CPU (tests) and as numerical reference, a plain einsum-softmax that XLA
-fuses; on TPU the pallas flash-attention kernel (ops/flash.py) streams KV
-blocks through VMEM without materializing the [S,S] score matrix.
+Off the TPU (the CPU tests) and as numerical reference, a plain
+einsum-softmax that XLA fuses; on a TPU the pallas flash-attention kernel
+(ops/flash.py) streams KV blocks through VMEM without materializing the
+[S,S] score matrix.
 """
 
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional
 
 import jax
@@ -35,76 +34,15 @@ def reference_attention(q, k, v, causal: bool = True,
     return out
 
 
-_PALLAS_OK: Optional[bool] = None
-
-
-def _pallas_lowers() -> bool:
-    """One-time eager probe: compile+run the flash kernel fwd AND bwd on a
-    tiny shape. A try/except around the flash_attention *call* cannot
-    catch Mosaic lowering errors — pallas blockspec validation fires when
-    the enclosing jit compiles, long after dispatch returned — so the
-    probe compiles eagerly (concrete inputs stay independent of any
-    ambient trace) and caches the verdict for the process."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            from torchft_tpu.ops.flash import flash_attention
-
-            key = jax.random.key(0)
-            x = jax.random.normal(key, (1, 256, 1, 64), jnp.bfloat16)
-
-            def probe_loss(threshold):
-                def loss(q):
-                    return jnp.sum(
-                        flash_attention(
-                            q, q, q, causal=True,
-                            _resident_kv_bytes=threshold,
-                        ).astype(jnp.float32)
-                    )
-                return loss
-
-            # resident-KV regime (tiny shape, default threshold)
-            jax.device_get(jax.jit(jax.grad(probe_loss(None)))(x))
-            # streamed regime, forced per-call on the same tiny shape
-            # (the kernels and blockspecs differ; a resident-only probe
-            # would let streamed lowering failures crash long-context
-            # jits)
-            jax.device_get(jax.jit(jax.grad(probe_loss(0)))(x))
-            _PALLAS_OK = True
-        except Exception as e:  # noqa: BLE001 — any lowering/runtime failure
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas flash kernel unavailable on this backend "
-                "(falling back to XLA attention): %s", e
-            )
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
-def _use_pallas() -> bool:
-    if os.environ.get("TORCHFT_TPU_DISABLE_PALLAS"):
-        return False
-    try:
-        if jax.default_backend() in ("cpu",):
-            return False
-    except Exception:  # pragma: no cover
-        return False
-    return _pallas_lowers()
-
-
 def causal_attention(q, k, v, scale: Optional[float] = None):
-    """Dispatch: pallas flash kernel on TPU, reference path elsewhere.
-
-    The try/except catches trace-time rejections (e.g. a sequence length
-    that isn't a multiple of the block size); compile-time Mosaic
-    rejections can't surface here, which is what the one-time lowering
-    probe in _pallas_lowers covers."""
-    if _use_pallas():
+    """The local causal attention of the model zoo. The kernel is chosen
+    from the backend alone: on a TPU the Mosaic flash kernel
+    (ops/flash.py), everywhere else :func:`reference_attention`. A shape
+    the kernel does not take raises its ``ValueError``, and a kernel the
+    compiler refuses fails the enclosing jit's compile; neither is caught
+    here, so the path that ran is never in doubt."""
+    if jax.default_backend() == "tpu":
         from torchft_tpu.ops.flash import flash_attention
 
-        try:
-            return flash_attention(q, k, v, causal=True, scale=scale)
-        except ValueError:  # shape unsupported by the kernel: fall back
-            pass
+        return flash_attention(q, k, v, causal=True, scale=scale)
     return reference_attention(q, k, v, causal=True, scale=scale)

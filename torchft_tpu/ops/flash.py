@@ -23,24 +23,19 @@ prefers 2-D keepdims math over 1-D vectors. (jax's reference TPU kernel
 broadcasts lse across 128 lanes instead; the singleton lane column costs
 128x less HBM traffic and lowers fine.)
 
-Use interpret=True (or TORCHFT_TPU_PALLAS_INTERPRET=1) to run the same
-kernel on CPU for tests.
+``interpret=True`` runs the same kernels through the Pallas interpreter
+(the CPU tests); the default compiles them with Mosaic.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific bits are unavailable when lowering for CPU interpret
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "flash_attention",
@@ -623,21 +618,19 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _bshd_prologue(q, scale, block_q, block_k, interpret):
-    """Shared [B,S,H,D]-surface plumbing: scale default, interpret env
-    read, block clamping, divisibility validation, and the
-    [B,S,H,D] <-> [B*H,S,D] layout pair. One place, two wrappers."""
+def _bshd_prologue(q, scale, block_q, block_k):
+    """Shared [B,S,H,D]-surface plumbing: scale default, block clamping,
+    divisibility validation, and the [B,S,H,D] <-> [B*H,S,D] layout
+    pair. One place, three wrappers."""
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = bool(os.environ.get("TORCHFT_TPU_PALLAS_INTERPRET"))
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(
-            f"seq len {s} must be a multiple of block sizes "
-            f"({block_q}, {block_k})"
+            f"flash attention: seq len {s} of q{tuple(q.shape)} must be a "
+            f"multiple of the block sizes ({block_q}, {block_k})"
         )
 
     def merge(x):  # [B,S,H,D] -> [B*H, S, D]
@@ -646,13 +639,13 @@ def _bshd_prologue(q, scale, block_q, block_k, interpret):
     def unmerge(x):  # [B*H, S, D] -> [B,S,H,D]
         return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
-    return float(scale), block_q, block_k, interpret, merge, unmerge
+    return float(scale), block_q, block_k, merge, unmerge
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              scale: Optional[float] = None,
                              block_q: int = 128, block_k: int = 128,
-                             interpret: Optional[bool] = None):
+                             interpret: bool = False):
     """Forward-only flash attention returning ``(out, lse)`` with
     out [B, S, H, D] and lse [B, H, S] (log-sum-exp of the scaled scores,
     max-folded). The lse output is what makes results MERGEABLE: two
@@ -665,8 +658,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     flash ring differentiates via its own ring-structured VJP built on
     ``flash_block_attention_bwd``."""
     b, s, h, _ = q.shape
-    scale, block_q, block_k, interpret, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k, interpret
+    scale, block_q, block_k, merge, unmerge = _bshd_prologue(
+        q, scale, block_q, block_k
     )
     out, lse = _flash_forward(
         merge(q), merge(k), merge(v), causal, scale,
@@ -678,7 +671,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
 def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
                               scale: Optional[float] = None,
                               block_q: int = 128, block_k: int = 128,
-                              interpret: Optional[bool] = None):
+                              interpret: bool = False):
     """Gradient CONTRIBUTIONS of one (q-block, kv-block) pair under
     global softmax statistics.
 
@@ -692,8 +685,8 @@ def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
     the ring-attention backward (parallel/ring.py): the diagonal pair
     runs causal=True, past pairs causal=False."""
     b, s, h, _ = q.shape
-    scale, block_q, block_k, interpret, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k, interpret
+    scale, block_q, block_k, merge, unmerge = _bshd_prologue(
+        q, scale, block_q, block_k
     )
 
     def merge_stat(x):  # [B,H,S] -> [BH, S]
@@ -711,7 +704,7 @@ def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None,
+                    interpret: bool = False,
                     _resident_kv_bytes: Optional[int] = None):
     """[B, S, H, D] flash attention (pallas on TPU).
 
@@ -719,12 +712,12 @@ def flash_attention(q, k, v, causal: bool = True,
     needed; the model configs here use powers of two).
 
     ``_resident_kv_bytes`` overrides the resident-vs-streamed regime
-    threshold for THIS call (0 forces the streamed kernels); used by the
-    dispatch probe in ops/attention.py to lowering-check both regimes on
-    a tiny shape without touching shared state.
+    threshold for THIS call (0 forces the streamed kernels); used by
+    chip_smoke.py and the tests to run both regimes at one shape without
+    touching shared state.
     """
-    scale, block_q, block_k, interpret, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k, interpret
+    scale, block_q, block_k, merge, unmerge = _bshd_prologue(
+        q, scale, block_q, block_k
     )
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
                  block_q, block_k, interpret, _resident_kv_bytes)

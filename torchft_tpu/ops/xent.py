@@ -201,11 +201,8 @@ def make_vocab_parallel_cross_entropy(mesh, axis_name: str = "tensor",
     Inputs/outputs are replicated over every OTHER mesh axis too (specs
     below say so); compose batch sharding outside if needed.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from torchft_tpu.utils.jaxcompat import get_shard_map
-
-    shard_map, check_kwargs = get_shard_map()
 
     def sharded(h, w_local, targets):
         from jax import lax
@@ -237,7 +234,7 @@ def make_vocab_parallel_cross_entropy(mesh, axis_name: str = "tensor",
         mesh=mesh,
         in_specs=(P(), P(None, axis_name), P()),
         out_specs=P(),
-        **check_kwargs,
+        check_vma=False,
     )
 
     def loss(h, w, targets):

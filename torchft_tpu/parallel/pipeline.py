@@ -75,9 +75,6 @@ def merge_microbatches(x):
     return x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
 
 
-from torchft_tpu.utils.jaxcompat import get_shard_map as _get_shard_map
-
-
 def make_pipeline(mesh, stage_fn: Callable[[Any, Any], Any],
                   axis: str = "stage",
                   embed_fn: Optional[Callable[[Any], Any]] = None,
@@ -95,10 +92,9 @@ def make_pipeline(mesh, stage_fn: Callable[[Any, Any], Any],
     channel is one SPMD-uniform buffer)."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    shard_map, check_kwargs = _get_shard_map()
     num_stages = mesh.shape[axis]
 
     def _body(stacked_params, x):
@@ -160,7 +156,7 @@ def make_pipeline(mesh, stage_fn: Callable[[Any, Any], Any],
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **check_kwargs,
+        check_vma=False,
     )
 
 
@@ -189,12 +185,11 @@ def make_pipeline_1f1b(mesh, stage_fn: Callable[[Any, Any], Any],
 
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from torchft_tpu.parallel.schedule import one_f_one_b_schedule
 
-    shard_map, check_kwargs = _get_shard_map()
     S = mesh.shape[axis]
     M = num_microbatches
 
@@ -315,7 +310,7 @@ def make_pipeline_1f1b(mesh, stage_fn: Callable[[Any, Any], Any],
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=(P(), P(axis)),
-        **check_kwargs,
+        check_vma=False,
     )
 
 
@@ -345,12 +340,11 @@ def make_pipeline_interleaved_1f1b(
 
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from torchft_tpu.parallel.schedule import interleaved_tables
 
-    shard_map, check_kwargs = _get_shard_map()
     S = mesh.shape[axis]
     M = num_microbatches
     V = interleave
@@ -521,5 +515,5 @@ def make_pipeline_interleaved_1f1b(
         mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=(P(), P(axis)),
-        **check_kwargs,
+        check_vma=False,
     )
