@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.data import PrefetchIterator
-from torchft_tpu.utils.profiling import StepProfiler, trace
+from torchft_tpu.utils.profiling import StepProfiler
 
 
 def test_step_profiler_disabled_is_noop(monkeypatch) -> None:
@@ -36,13 +36,6 @@ def test_step_profiler_traces_window(tmp_path) -> None:
     for root, _, files in os.walk(log_dir):
         found.extend(files)
     assert found, f"no trace files under {log_dir}"
-
-
-def test_trace_context_manager(tmp_path) -> None:
-    log_dir = str(tmp_path / "blk")
-    with trace(log_dir):
-        jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
-    assert any(files for _, _, files in os.walk(log_dir))
 
 
 def test_step_profiler_early_loop_exit_closes_trace(tmp_path) -> None:

@@ -1,0 +1,329 @@
+"""The program's own timeline (ISSUE 23): one span primitive that feeds
+the Metrics sink AND the profiler's host plane, recovery episodes split
+into phases by the Manager, and stable step/kernel names on the device
+timeline."""
+
+import dataclasses
+import glob
+import os
+import threading
+import time
+from typing import Any, Dict, List
+
+import numpy as np
+import pytest
+
+from torchft_tpu.comm.store import StoreServer
+from torchft_tpu.comm.transport import TcpCommContext
+from torchft_tpu.control import Lighthouse
+from torchft_tpu.manager import Manager
+from torchft_tpu.utils.metrics import Metrics
+from torchft_tpu.utils.profiling import (
+    SPAN_PREFIX,
+    StepProgram,
+    scope_tables,
+    span,
+    throughput_span,
+)
+
+# ------------------------------------------------------------------ spans
+
+
+def test_span_observes_into_the_sink() -> None:
+    m = Metrics()
+    with span(m, "quorum_wait", step=3) as timed:
+        time.sleep(0.01)
+    snap = m.snapshot()
+    assert snap["quorum_wait_max_ms"] >= 10.0
+    assert timed.elapsed * 1e3 == pytest.approx(snap["quorum_wait_max_ms"])
+    with pytest.raises(ValueError):
+        with span(m, "quorum_wait"):
+            raise ValueError("observed all the same")
+    assert len(m._timings["quorum_wait"]) == 2
+    with span(None, "no_sink"):  # annotation only
+        pass
+
+
+def test_span_leaves_a_tft_event_with_replica_and_step(tmp_path) -> None:
+    import jax
+    from jax.profiler import ProfileData
+
+    m = Metrics()
+    m.label("replica_id", "bm_2_0_abc")
+    with span(m, "outside"):  # no trace: a no-op annotation, still timed
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span(m, "configure", step=41, bucket=7):
+            time.sleep(0.002)
+        with throughput_span(m, "heal_wire", 1000, step=41):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    found: Dict[str, Dict[str, Any]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(SPAN_PREFIX):
+                    found[e.name] = dict(e.stats)
+                    assert e.duration_ns >= 2e6
+    assert set(found) == {"tft.configure", "tft.heal_wire"}
+    assert found["tft.configure"] == {
+        "replica": "bm_2_0_abc", "step": 41, "bucket": 7,
+    }
+    assert found["tft.heal_wire"]["replica"] == "bm_2_0_abc"
+    assert m.snapshot()["heal_wire_bytes"] == 1000
+    assert {"outside_max_ms", "configure_max_ms"} <= set(m.snapshot())
+
+
+# ------------------------------------------------------ recovery episodes
+
+_HEARTBEAT_TIMEOUT_MS = 1000
+
+
+class _Replica:
+    """One replica group's training loop on a thread: quorum, averaged
+    "gradient", commit."""
+
+    def __init__(self, name: str, value: float, lighthouse_addr: str) -> None:
+        self.store = StoreServer()
+        self.state = {"w": np.full((4,), value, np.float32)}
+        self.stop = threading.Event()
+        self.manager = Manager(
+            comm=TcpCommContext(timeout=5.0),
+            load_state_dict=lambda sd: self.state.update(
+                w=np.array(sd["w"], np.float32)),
+            state_dict=lambda: {"w": self.state["w"]},
+            min_replica_size=1,
+            timeout=5.0, quorum_timeout=20.0, connect_timeout=10.0,
+            rank=0, world_size=1, store_addr=self.store.addr,
+            lighthouse_addr=lighthouse_addr, replica_id=f"tl_{name}_",
+            heartbeat_interval=0.05,
+        )
+        self.thread = threading.Thread(target=self._run, daemon=True)
+        self.thread.start()
+
+    def _run(self) -> None:
+        m = self.manager
+        while not self.stop.is_set():
+            try:
+                m.start_quorum()
+                with m.blocked_on_wire():
+                    g = m.allreduce_arrays(
+                        [self.state["w"] * 0.1]
+                    ).future().result(timeout=30)[0]
+                time.sleep(0.01)  # the step's compute: lands in ``other``
+                if m.should_commit():
+                    self.state["w"] = self.state["w"] - g
+            except Exception:  # noqa: BLE001 — torn down under the loop
+                if self.stop.is_set():
+                    return
+                time.sleep(0.05)
+
+    def kill(self) -> None:
+        self.stop.set()
+        self.manager.shutdown(wait=False)
+        self.store.shutdown()
+
+    def episodes(self) -> List[Dict[str, Any]]:
+        return [e for e in self.manager.events.since(0)[0]
+                if e["kind"] == "recovery_episode"]
+
+    def run_to(self, step: int, timeout: float = 60.0) -> None:
+        deadline = time.monotonic() + timeout
+        while self.manager.current_step() < step:
+            assert time.monotonic() < deadline, "the loop stopped committing"
+            time.sleep(0.01)
+
+
+@pytest.fixture(scope="module")
+def kill_and_rejoin():
+    """Two groups; one is torn down, the survivor runs on alone, a
+    replacement with other weights joins, then 20 steady steps."""
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=200,
+                    heartbeat_timeout_ms=_HEARTBEAT_TIMEOUT_MS)
+    live: List[_Replica] = []
+    try:
+        survivor = _Replica("a", 1.0, lh.address())
+        victim = _Replica("b", 1.0, lh.address())
+        live += [survivor, victim]
+        survivor.run_to(8)
+        victim.run_to(8)
+        n_before_kill = len(survivor.episodes())
+        victim.kill()
+        survivor.run_to(survivor.manager.current_step() + 8)
+        n_alone = len(survivor.episodes())
+        replacement = _Replica("c", 99.0, lh.address())
+        live.append(replacement)
+        replacement.run_to(survivor.manager.current_step() + 5)
+        survivor.run_to(replacement.manager.current_step())
+        n_rejoined = len(survivor.episodes())
+        snapshot = survivor.manager.metrics.snapshot()
+        survivor.run_to(survivor.manager.current_step() + 20)
+        yield {
+            "after_kill": survivor.episodes()[n_before_kill:n_alone],
+            "after_rejoin": survivor.episodes()[n_alone:n_rejoined],
+            "steady": survivor.episodes()[n_rejoined:],
+            "replacement": replacement.episodes(),
+            "everything": survivor.episodes() + replacement.episodes(),
+            "snapshot": snapshot,
+            "equal": np.array_equal(
+                survivor.state["w"], replacement.state["w"]),
+        }
+    finally:
+        for r in live:
+            r.kill()
+        lh.shutdown()
+
+
+_PARTITION = ("quorum_wait_ms", "wire_wait_ms", "heal_ms", "barrier_ms",
+              "init_ms", "first_step_ms", "other_ms")
+
+
+def test_survivor_emits_exactly_one_shrink_episode(kill_and_rejoin) -> None:
+    (episode,) = kill_and_rejoin["after_kill"]
+    assert episode["episode"] == "shrink"
+    assert (episode["members_before"], episode["members_after"]) == (2, 1)
+    assert (episode["left"], episode["joined"]) == (1, 0)
+    # the survivor could not form a quorum before the dead group's
+    # heartbeat had expired (its last beat is at most one interval old)
+    assert episode["quorum_wait_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
+    assert episode["quorum_wait_ms"] > 0.8 * episode["gap_ms"]
+    assert episode["configure_ms"] <= episode["quorum_wait_ms"]
+
+
+def test_every_episodes_phases_partition_its_gap(kill_and_rejoin) -> None:
+    episodes = kill_and_rejoin["everything"]
+    assert len(episodes) >= 4
+    for e in episodes:
+        parts = [e[k] for k in _PARTITION if k in e]
+        assert sum(parts) == pytest.approx(e["gap_ms"], rel=0.02)
+        # nothing is counted twice: the remainder is never negative
+        assert all(p >= -0.5 for p in parts), e
+        assert e["other_ms"] >= 5.0  # the 10 ms of "compute" each step
+
+
+def test_replacement_emits_a_rejoin_episode_with_a_heal(
+        kill_and_rejoin) -> None:
+    (episode,) = kill_and_rejoin["replacement"]
+    assert episode["episode"] == "rejoin"
+    assert episode["heal_ms"] > 0 and episode["init_ms"] > 0
+    assert episode["first_step_ms"] >= 0
+    assert kill_and_rejoin["equal"]  # 99.0 became the survivor's weights
+
+
+def test_survivor_sees_the_rejoin_as_a_grow_episode(kill_and_rejoin) -> None:
+    (episode,) = kill_and_rejoin["after_rejoin"]
+    assert episode["episode"] == "grow"
+    assert (episode["left"], episode["joined"]) == (0, 1)
+    # it waited on the wire while the joiner healed
+    assert episode["wire_wait_ms"] > 0
+
+
+def test_steady_steps_emit_no_episode(kill_and_rejoin) -> None:
+    assert kill_and_rejoin["steady"] == []
+
+
+def test_episode_phases_are_timings_of_the_managers_sink(
+        kill_and_rejoin) -> None:
+    snap = kill_and_rejoin["snapshot"]
+    (shrink,) = kill_and_rejoin["after_kill"]
+    for phase in ("gap", "quorum_wait", "wire_wait", "heal", "barrier",
+                  "other", "configure"):
+        assert snap[f"episode_shrink_{phase}_max_ms"] == pytest.approx(
+            shrink[f"{phase}_ms"], abs=1e-3)
+    assert "episode_grow_gap_max_ms" in snap
+    assert "episode_rejoin_init_max_ms" in snap      # its own start
+    assert snap["quorum_wait_max_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
+    assert snap["replica_id"].startswith("tl_a_")
+
+
+# ------------------------------------------- names on the device timeline
+
+
+def _tiny():
+    import jax
+    import optax
+
+    from torchft_tpu.models import CONFIGS, init_params
+
+    cfg = dataclasses.replace(CONFIGS["tiny"], xent_chunks=2)
+    tx = optax.adamw(1e-3)
+    params = init_params(cfg, jax.random.key(0))
+    tokens = jax.numpy.zeros((2, cfg.max_seq_len), jax.numpy.int32)
+    return cfg, tx, params, tokens
+
+
+@pytest.mark.parametrize("which,scopes", [
+    ("train", {"embed", "attn", "mlp", "lm_head_xent", "opt_update"}),
+    ("grad", {"embed", "attn", "mlp", "lm_head_xent"}),
+])
+def test_lowered_step_carries_scopes_and_module_name(which, scopes) -> None:
+    from torchft_tpu.models import make_grad_step, make_train_step
+
+    cfg, tx, params, tokens = _tiny()
+    if which == "train":
+        step = make_train_step(cfg, tx, donate=False)
+        args = (params, tx.init(params), tokens, tokens)
+    else:
+        step = make_grad_step(cfg)
+        args = (params, tokens, tokens)
+    assert isinstance(step, StepProgram)
+    lowered = step.lower(*args)  # a jitted function's own attribute
+    text = lowered.as_text(debug_info=True)
+    assert f"jit_tft_{which}_step" in text
+    for scope in scopes:
+        assert f"({scope})/" in text or f"/{scope}/" in text, scope
+    assert step.scope_table() == {}      # never called: nothing to say
+    step(*args)
+    table = step.scope_table()
+    assert table and scope_tables()[f"jit_tft_{which}_step"] == table
+    paths = set(table.values())
+    for scope in scopes - {"opt_update"}:
+        assert any(f"/jvp({scope})/" in p for p in paths), scope
+        assert any(f"/transpose(jvp({scope}))/" in p for p in paths), scope
+    if which == "train":
+        assert any(p.startswith("jit(tft_train_step)/opt_update/")
+                   for p in paths)
+
+
+def test_optimizer_update_program_is_named() -> None:
+    import optax
+
+    from torchft_tpu.optim import OptimizerWrapper
+
+    class _Manager:
+        def replica_id(self) -> str:
+            return "tl_opt_"
+
+    opt = OptimizerWrapper(_Manager(), optax.sgd(0.1))
+    assert opt._update.name == "tft_opt_update"
+    assert opt._update_donated.name == "tft_opt_update_donated"
+    assert opt.metrics.label_value("replica_id") == "tl_opt_"
+    text = opt._update.lower(
+        {"w": np.ones(3, np.float32)}, opt.init({"w": np.ones(3, np.float32)}),
+        {"w": np.ones(3, np.float32)},
+    ).as_text(debug_info=True)
+    assert "jit_tft_opt_update" in text and "opt_update/" in text
+
+
+def test_flash_kernels_carry_their_names() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from torchft_tpu.ops.flash import flash_attention
+
+    q = jnp.ones((1, 256, 2, 64), jnp.bfloat16)
+
+    def loss(q, k, v, **kw):
+        return flash_attention(q, k, v, interpret=True, **kw).sum()
+
+    for kw in ({}, {"_resident_kv_bytes": 0}):   # resident, streamed
+        jaxpr = str(jax.make_jaxpr(
+            jax.grad(lambda q, k, v: loss(q, k, v, **kw), argnums=(0, 1, 2))
+        )(q, q, q))
+        for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+            assert f"name={name}" in jaxpr, (kw, name)

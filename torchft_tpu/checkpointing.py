@@ -88,7 +88,7 @@ from torchft_tpu.comm.wire import (
 )
 from torchft_tpu.futures import FutureGroup, StealableTask, future_chain
 from torchft_tpu.utils.crc32c import crc32c
-from torchft_tpu.utils.profiling import throughput_span, timed_span
+from torchft_tpu.utils.profiling import span, throughput_span
 from torchft_tpu.utils.serialization import pytree_from_stream, pytree_to_stream
 
 logger = logging.getLogger(__name__)
@@ -421,7 +421,7 @@ def _build_staged(step: int, state: Any,
             def _stage(x=leaf, p=path, pb=tuple(piece_bounds), idx=i):
                 if stage_hook is not None:
                     stage_hook(idx, p)
-                with timed_span(metrics, "heal_stage"):
+                with span(metrics, "heal_stage"):
                     staged = _ShardedLeaf(x)
                     staged.pieces = {
                         b: arr for b, arr in staged.pieces.items()
@@ -445,7 +445,7 @@ def _build_staged(step: int, state: Any,
                 }
             )
         elif isinstance(leaf, np.ndarray):
-            with timed_span(metrics, "heal_stage"):
+            with span(metrics, "heal_stage"):
                 # detach from live training NOW (host arrays are
                 # mutable) — this memcpy is staging work like any D2H
                 snap = np.array(leaf, copy=True)
@@ -1869,7 +1869,7 @@ def recv_checkpoint_sharded(
 
             def _assemble(tleaf=tleaf, shape=shape,
                           region_bufs=region_bufs):
-                with timed_span(metrics, "heal_h2d"):
+                with span(metrics, "heal_h2d"):
                     shards = {
                         b: np.asarray(a) for b, a in region_bufs.items()
                     }

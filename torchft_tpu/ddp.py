@@ -86,13 +86,14 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from torchft_tpu.futures import FutureGroup, future_all, future_chain
 from torchft_tpu.utils.device import land_like
-from torchft_tpu.utils.profiling import timed_span
+from torchft_tpu.utils.profiling import span
 
 __all__ = [
     "DistributedDataParallel",
@@ -440,7 +441,12 @@ class DistributedDataParallel:
         staging buffers may be partially reduced — donation contract);
         that is safe because the commit gate (OptimizerWrapper.step)
         discards the step, but don't log/inspect grads after an error."""
-        return self.average_gradients_async(grads).result()
+        # the step's thread blocks here: the Manager accounts it as the
+        # ``wire_wait`` span and recovery-episode phase (duck-typed: a
+        # test double of the manager may not have it)
+        blocked = getattr(self._manager, "blocked_on_wire", nullcontext)
+        with blocked():
+            return self.average_gradients_async(grads).result()
 
     def average_gradients_async(self, grads: Any):
         import jax
@@ -515,7 +521,7 @@ class DistributedDataParallel:
 
     def _pack_bucket(self, plan: _BucketPlan, k: int,
                      leaves: List[Any], staging: List[np.ndarray],
-                     metrics) -> np.ndarray:
+                     metrics, d2h_t: List[float]) -> np.ndarray:
         """Stage d2h: block only on bucket k's leaves and land them in
         bucket k's slice of the staging arena (the mid-backward comm-hook
         analog, ref ddp.py:49-71) — bucket k rides the wire while later
@@ -523,9 +529,11 @@ class DistributedDataParallel:
         import jax
 
         bucket = plan.buckets[k]
-        with timed_span(metrics, "ddp_d2h", span=f"ddp_pack_bucket{k}"):
+        with span(metrics, "ddp_d2h", bucket=k) as timed:
             host_b = [np.asarray(jax.device_get(leaves[i])) for i in bucket]
-            return plan.pack_bucket_into(bucket, host_b, staging[k])
+            packed = plan.pack_bucket_into(bucket, host_b, staging[k])
+        d2h_t[k] = timed.elapsed
+        return packed
 
     def _ef_residual(self, transmitted: np.ndarray, res: np.ndarray,
                      metrics) -> None:
@@ -533,7 +541,7 @@ class DistributedDataParallel:
         wire's own per-chunk quantizer and ``transmitted`` is g' (or a
         snapshot of it — the donated staging buffer is reduced in place,
         so the contribution is unrecoverable after submit)."""
-        with timed_span(metrics, "ddp_ef"):
+        with span(metrics, "ddp_ef"):
             self._manager.wire_roundtrip(transmitted, res)  # res = C(g')
             np.subtract(transmitted, res, out=res)
             if not np.all(np.isfinite(res)):
@@ -548,15 +556,16 @@ class DistributedDataParallel:
 
     def _land_bucket(self, plan: _BucketPlan, k: int, reduced: np.ndarray,
                      in_leaves: List[Any], out_leaves: List[Any],
-                     metrics) -> None:
+                     metrics, h2d_t: List[float]) -> None:
         """Stage h2d: unpack bucket k's reduced flat array into its
         leaves and copy each back to the device(s) of the gradient leaf
         it replaces (:func:`_land_leaf`). This runs on a pool thread, so
         the placement must come from the leaf, not from any thread-local
         default device of the caller."""
-        with timed_span(metrics, "ddp_h2d", span=f"ddp_unpack_bucket{k}"):
+        with span(metrics, "ddp_h2d", bucket=k) as timed:
             for i, view in plan.unpack_bucket(k, reduced):
                 out_leaves[i] = _land_leaf(view, in_leaves[i])
+        h2d_t[k] = timed.elapsed
 
     # ----------------------------------------------------------- code paths
 
@@ -577,10 +586,16 @@ class DistributedDataParallel:
         device_leaves: List[Any] = [None] * len(plan.shapes)
         submit_t: List[float] = [0.0] * n_buckets
         wire_done_t: List[float] = [0.0] * n_buckets
+        # per-bucket stage seconds (a slot each: landings run on pool
+        # threads), summed once a step into ddp_d2h_total / ddp_h2d_total
+        d2h_t: List[float] = [0.0] * n_buckets
+        h2d_t: List[float] = [0.0] * n_buckets
 
         try:
             for k in range(n_buckets):
-                packed = self._pack_bucket(plan, k, leaves, staging, metrics)
+                packed = self._pack_bucket(
+                    plan, k, leaves, staging, metrics, d2h_t
+                )
                 if ef and arena.residuals[k] is not None:
                     res = arena.residuals[k]
                     # g' = g + e_prev stays inline (one vector add —
@@ -624,7 +639,7 @@ class DistributedDataParallel:
                             reduced = wf.result()[0]
                             self._land_bucket(
                                 plan, k, reduced, leaves, device_leaves,
-                                metrics,
+                                metrics, h2d_t,
                             )
                             landed.set_result(None)
                         except Exception as e:  # noqa: BLE001
@@ -668,6 +683,10 @@ class DistributedDataParallel:
                 exposed = max(0.0, max(wire_done_t) - t_submitted)
                 metrics.observe("ddp_wire_total", total)
                 metrics.observe("ddp_wire_exposed", exposed)
+                # ...and the host staging either side of the socket, so
+                # a step's wire cost splits into down / socket / up
+                metrics.observe("ddp_d2h_total", sum(d2h_t))
+                metrics.observe("ddp_h2d_total", sum(h2d_t))
             return jax.tree_util.tree_unflatten(treedef, device_leaves)
 
         fut = group.seal(_assemble)
@@ -689,9 +708,15 @@ class DistributedDataParallel:
         works = []
         submit_t: List[float] = [0.0] * n_buckets
         wire_done_t: List[float] = [0.0] * n_buckets
+        # per-bucket stage seconds (a slot each: landings run on pool
+        # threads), summed once a step into ddp_d2h_total / ddp_h2d_total
+        d2h_t: List[float] = [0.0] * n_buckets
+        h2d_t: List[float] = [0.0] * n_buckets
         try:
             for k in range(n_buckets):
-                packed = self._pack_bucket(plan, k, leaves, staging, metrics)
+                packed = self._pack_bucket(
+                    plan, k, leaves, staging, metrics, d2h_t
+                )
                 if ef and arena.residuals[k] is not None:
                     res = arena.residuals[k]
                     np.add(packed, res, out=packed)
@@ -742,7 +767,8 @@ class DistributedDataParallel:
             for k, w in enumerate(works):
                 reduced = w.future().result()[0]
                 self._land_bucket(
-                    plan, k, reduced, leaves, device_leaves, metrics
+                    plan, k, reduced, leaves, device_leaves, metrics,
+                    h2d_t,
                 )
             if metrics is not None and all(wire_done_t) \
                     and self._wire_healthy():
@@ -752,6 +778,8 @@ class DistributedDataParallel:
                 metrics.observe("ddp_wire_exposed", max(
                     0.0, max(wire_done_t) - t_submitted
                 ))
+                metrics.observe("ddp_d2h_total", sum(d2h_t))
+                metrics.observe("ddp_h2d_total", sum(h2d_t))
             return jax.tree_util.tree_unflatten(treedef, device_leaves)
 
         fut = future_chain(
@@ -979,7 +1007,7 @@ class ShardedGradReducer:
                 arena.ef_generation = gen
 
         for k, bucket in enumerate(plan.buckets):
-            with timed_span(metrics, "ddp_d2h", span=f"shard_pack_b{k}"):
+            with span(metrics, "ddp_d2h", bucket=k):
                 host_b = [
                     np.asarray(jax.device_get(leaves[i])) for i in bucket
                 ]
@@ -987,7 +1015,7 @@ class ShardedGradReducer:
             if ef and arena.residuals[k] is not None:
                 res = arena.residuals[k]
                 np.add(packed, res, out=packed)
-                with timed_span(metrics, "ddp_ef"):
+                with span(metrics, "ddp_ef"):
                     mgr.wire_roundtrip(packed, res)  # res = C(g')
                     np.subtract(packed, res, out=res)
                     if not np.all(np.isfinite(res)):

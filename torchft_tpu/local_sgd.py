@@ -99,7 +99,7 @@ from torchft_tpu.comm.wire import split_weighted
 from torchft_tpu.futures import FutureGroup
 from torchft_tpu.optim import PartitionedOuterOptimizer
 from torchft_tpu.utils.device import land, land_like
-from torchft_tpu.utils.profiling import timed_span
+from torchft_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -732,7 +732,7 @@ class LocalSGD:
         """e_t = v' − C(v') against the wire's own chunk grid.
         ``transmitted`` is v' (or a snapshot of it — the donated arena is
         reduced in place the moment the wire takes it)."""
-        with timed_span(metrics, "outer_ef"):
+        with span(metrics, "outer_ef"):
             self._manager.wire_roundtrip(transmitted, res)  # res = C(v')
             np.subtract(transmitted, res, out=res)
             if not np.all(np.isfinite(res)):
@@ -748,7 +748,7 @@ class LocalSGD:
         mgr = self._manager
         metrics = self._metrics()
         arena = self._frag_arena(f)
-        with timed_span(metrics, "outer_d2h", span=f"outer_pack_frag{f}"):
+        with span(metrics, "outer_d2h", fragment=f):
             self._fragment_value_into(f, leaves, arena)
         if self._ef_enabled():
             self._ef_prepare()
@@ -832,8 +832,7 @@ class LocalSGD:
                        reduced: np.ndarray) -> None:
         """Stage fragment ``f``'s landed outer result (adopted only on
         commit). LocalSGD: the averaged flat values themselves."""
-        with timed_span(self._metrics(), "outer_land",
-                        span=f"outer_land_frag{f}"):
+        with span(self._metrics(), "outer_land", fragment=f):
             rnd.staged[f] = reduced
 
     # -- round completion ----------------------------------------------------
@@ -1055,8 +1054,7 @@ class DiLoCo(LocalSGD):
         STAGED (params and state adopted only on commit). Runs on the
         bounded worker in streaming mode — while later fragments are
         still riding the wire."""
-        with timed_span(self._metrics(), "outer_land",
-                        span=f"outer_land_frag{f}"):
+        with span(self._metrics(), "outer_land", fragment=f):
             start, stop = self._fragments[f]
             grads: List[Any] = []
             off = 0

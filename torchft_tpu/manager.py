@@ -30,8 +30,10 @@ import logging
 import os
 import socket as _socket
 import threading
+import time
 import uuid
 from concurrent.futures import Future, ThreadPoolExecutor
+from contextlib import contextmanager
 from datetime import timedelta
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional, Sequence, TypeVar
@@ -50,6 +52,7 @@ from torchft_tpu.control import ManagerClient, ManagerServer
 from torchft_tpu.futures import future_chain, future_timeout
 from torchft_tpu.utils.events import EventRecorder
 from torchft_tpu.utils.metrics import Metrics
+from torchft_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -107,6 +110,42 @@ def _build_comm_context(
     )
 
 
+class _Interval:
+    """What the step's thread lost, and to what, since the last commit
+    (or since the Manager was built): the accumulators behind the
+    ``recovery_episode`` event. One lives per Manager and is reset at
+    every commit; an interval becomes an *episode* only if it turns
+    ``dirty`` — a step was discarded, an error latched, the wire
+    membership changed or this replica healed. A steady step pays the
+    reset (a handful of float stores) and one clock read."""
+
+    __slots__ = (
+        "t0", "first", "dirty", "members", "quorum_wait", "configure",
+        "wire_wait", "heal", "barrier", "discards", "errors", "init",
+        "heal_from", "healed_at", "stalled_at_heal",
+    )
+
+    def __init__(self, t0: float) -> None:
+        self.first = True      # no commit yet: opened by the constructor
+        self.init: Optional[float] = None  # constructor -> first quorum_start
+        self.open(t0, ())
+        self.dirty = True      # a new replica's first commit is an episode
+
+    def open(self, t0: float, members: tuple) -> None:
+        self.t0 = t0
+        self.members = members
+        self.dirty = False
+        self.quorum_wait = self.configure = self.wire_wait = 0.0
+        self.heal = self.barrier = 0.0
+        self.discards = self.errors = 0
+        self.heal_from: Optional[float] = None
+        self.healed_at: Optional[float] = None
+        self.stalled_at_heal = 0.0
+
+    def stalled(self) -> float:
+        return self.quorum_wait + self.wire_wait + self.heal + self.barrier
+
+
 class WorldSizeMode(Enum):
     """Numerics policy when more than ``min_replica_size`` replicas are
     healthy (ref manager.py:55-70).
@@ -161,6 +200,7 @@ class Manager:
         # it: a silently-defaulted quorum floor of 1 would let every
         # partition-isolated replica keep committing — the split-brain
         # this knob exists to prevent.
+        t_init = time.perf_counter()  # a rejoin episode opens here
         if min_replica_size is _REQUIRED:
             raise TypeError(
                 "Manager() missing required argument: 'min_replica_size' "
@@ -380,6 +420,13 @@ class Manager:
         # wall time went, and one reset_timings() bounds a measurement
         # window for every layer at once (bench.py relies on this).
         self.metrics = Metrics()
+        # Who this sink belongs to: utils.profiling.span stamps it onto
+        # every ``tft.*`` span as ``replica=``, so a trace of several
+        # replica groups in one process can be told apart.
+        self.metrics.label("replica_id", replica_id)
+        # Recovery episodes (see _Interval): the edges this Manager
+        # already emits as events, turned into durations per phase.
+        self._interval = _Interval(t_init)
         # Every span/gauge in this sink carries the active data-plane
         # backend as a label, so a host-vs-xla A/B's evidence JSONs are
         # distinguishable by inspection (contexts with set_metrics
@@ -504,11 +551,14 @@ class Manager:
         self._lease_stop.set()
         with self._lease_lock:
             self._lease_live = False
-        self._checkpoint_transport.shutdown(wait=wait)
-        if self._manager is not None:
-            self._manager.shutdown()
-        self._executor.shutdown(wait=wait)
-        self._comm.shutdown()
+        # a span: on a shared host a teardown that blocks delays whatever
+        # is relaunched behind it, and nothing else on the timeline says so
+        with span(self.metrics, "shutdown", step=self._step):
+            self._checkpoint_transport.shutdown(wait=wait)
+            if self._manager is not None:
+                self._manager.shutdown()
+            self._executor.shutdown(wait=wait)
+            self._comm.shutdown()
 
     # ------------------------------------------------------------ collectives
 
@@ -771,6 +821,8 @@ class Manager:
             first = self._errored is None
             self._errored = e
         if first:
+            self._interval.errors += 1
+            self._interval.dirty = True
             # one event per latch episode, not per swallowed future —
             # start_quorum clears the latch, re-arming the edge trigger
             ev = self.events
@@ -964,6 +1016,7 @@ class Manager:
             self._errored = None
         self._healing = False
         self._did_heal = False
+        self._interval.heal_from = None
 
         if self._comm.errored() is not None:
             # Latched transport: request a coordinated reconfigure. The
@@ -997,7 +1050,47 @@ class Manager:
         assert self._quorum_future is not None, (
             "must call start_quorum before wait_quorum"
         )
-        self._quorum_future.result()
+        fut = self._quorum_future
+        if fut.done():  # the steady step: nothing to wait for, no clock
+            fut.result()
+            return
+        # The caller's thread blocked on the quorum: the time the STEP
+        # loses to it (``quorum`` is the RPC on the quorum thread, and
+        # overlaps the forward pass in async mode). A heal runs on the
+        # quorum thread inside the same future; what is waited for after
+        # its assignment belongs to the episode's ``heal``.
+        t0 = time.perf_counter()
+        try:
+            with span(self.metrics, "quorum_wait", step=self._step):
+                fut.result()
+        finally:
+            t1 = time.perf_counter()
+            iv = self._interval
+            heal_from = iv.heal_from
+            healing = 0.0 if heal_from is None else max(
+                0.0, t1 - max(t0, heal_from)
+            )
+            iv.heal += healing
+            iv.quorum_wait += (t1 - t0) - healing
+
+    @contextmanager
+    def blocked_on_wire(self):
+        """Wrappers put this around every place the step's thread blocks
+        on cross-replica collectives (``DistributedDataParallel.
+        average_gradients``; the Manager's own drain in
+        ``should_commit``): a ``wire_wait`` span, and the episode's
+        ``wire_wait`` phase. A quorum or heal waited for inside it is
+        counted under its own phase, not here."""
+        iv = self._interval
+        inner0 = iv.quorum_wait + iv.heal
+        t0 = time.perf_counter()
+        try:
+            with span(self.metrics, "wire_wait", step=self._step):
+                yield
+        finally:
+            iv.wire_wait += (time.perf_counter() - t0) - (
+                iv.quorum_wait + iv.heal - inner0
+            )
 
     def quorum_fence(self) -> None:
         """Round-start fence for fragment-scheduled sync wrappers
@@ -1030,7 +1123,10 @@ class Manager:
             ev.emit(
                 "quorum_start", step=self._step, epoch=self._quorum_epoch
             )
-        with self.metrics.timed("quorum"):
+        iv = self._interval
+        if iv.init is None:
+            iv.init = time.perf_counter() - iv.t0
+        with span(self.metrics, "quorum", step=self._step):
             quorum = self._quorum_rpc(allow_heal, shrink_only, quorum_timeout)
         self._finish_quorum(quorum, allow_heal)
 
@@ -1155,6 +1251,8 @@ class Manager:
                     "member_dead", step=self._step,
                     epoch=quorum.quorum_id, member=gone,
                 )
+        if members != self._wire_members:
+            self._interval.dirty = True
         self._wire_members = members
         if ev:
             ev.emit(
@@ -1202,8 +1300,13 @@ class Manager:
             set_members = getattr(self._comm, "set_wire_members", None)
             if callable(set_members) and quorum.transport_replica_ids:
                 set_members(list(quorum.transport_replica_ids))
+            # the transport re-rendezvous: part of what wait_quorum's
+            # caller waits for, so the episode reports it INSIDE
+            # quorum_wait
+            configuring = span(self.metrics, "configure", step=self._step)
             try:
-                self._comm.configure(store_prefixed_addr, t_rank, t_world)
+                with configuring:
+                    self._comm.configure(store_prefixed_addr, t_rank, t_world)
                 self._transport_key = transport_key
             except Exception as e:  # noqa: BLE001
                 # A peer that died between quorum announcement and transport
@@ -1212,6 +1315,7 @@ class Manager:
                 # the next quorum (hardening over ref manager.py:475 TODO).
                 self._logger.exception(f"comm configure failed: {e}")
                 self.report_error(e)
+            self._interval.configure += configuring.elapsed
 
         if allow_heal:
             if quorum.recover_dst_ranks:
@@ -1249,10 +1353,9 @@ class Manager:
                 )
             if quorum.heal:
                 try:
-                    import time as _time
-
                     self._healing = True
-                    self._heal_t0 = _time.perf_counter()
+                    self._heal_t0 = time.perf_counter()
+                    self._interval.heal_from = self._heal_t0
                     if self.events:
                         self.events.emit(
                             "heal_start", step=self._step,
@@ -1323,6 +1426,7 @@ class Manager:
         assert self._quorum_future is not None, (
             "must call start_quorum before should_commit"
         )
+        t_apply = time.perf_counter()
         self._quorum_future.result()
         self._logger.info("applying pending state dict")
         assert self._pending_state_dict is not None, "checkpoint was not staged"
@@ -1337,9 +1441,7 @@ class Manager:
             # heal assignment → healed-state ready, end to end: quorum
             # answer, donor fetch (stage/wire/H2D spans are inside), and
             # the user load_state_dict that just ran
-            import time as _time
-
-            wall_ms = (_time.perf_counter() - self._heal_t0) * 1000.0
+            wall_ms = (time.perf_counter() - self._heal_t0) * 1000.0
             self.metrics.gauge("heal_wall_ms", wall_ms)
             self._heal_t0 = None
         if self.events:
@@ -1347,6 +1449,13 @@ class Manager:
                 "heal_done", step=self._step, epoch=self._quorum_epoch,
                 wall_ms=None if wall_ms is None else round(wall_ms, 3),
             )
+        # the episode: this replica healed, and from here to the closing
+        # commit is its ``first_step``
+        iv = self._interval
+        iv.healed_at = time.perf_counter()
+        iv.heal += iv.healed_at - t_apply
+        iv.stalled_at_heal = iv.stalled()
+        iv.dirty = True
         self._logger.info("loaded state dict")
 
     # ---------------------------------------------------------------- commit
@@ -1405,23 +1514,24 @@ class Manager:
         optimistic dispatch when the outcome is already known to be False
         (a False local vote makes the global AND False).
         """
-        for work in self._pending_work:
-            if self.errored() is not None:
-                break
-            # Errors are swallowed into the latch by wrap_future; this never
-            # raises.
-            try:
-                work.result()
-            except Exception:  # pragma: no cover — defensive
-                pass
-        self._pending_work = []
+        if self._pending_work:
+            with self.blocked_on_wire():
+                for work in self._pending_work:
+                    if self.errored() is not None:
+                        break
+                    # Errors are swallowed into the latch by wrap_future;
+                    # this never raises.
+                    try:
+                        work.result()
+                    except Exception:  # pragma: no cover — defensive
+                        pass
+            self._pending_work = []
 
         if self._healing:
             self._apply_pending_state_dict()
 
         enough_replicas = self.num_participants() >= self._min_replica_size
         local_should_commit = enough_replicas and self.errored() is None
-        import time as _time
 
         # --- steady-state fast path ---------------------------------------
         # Armed by this step's local start_quorum, consumed exactly once.
@@ -1455,6 +1565,7 @@ class Manager:
                         participants=self.num_participants(),
                         fastpath=True,
                     )
+                self._close_interval()
                 self._checkpoint_transport.disallow_checkpoint()
                 self._step += 1
                 self._batches_committed += self.num_participants()
@@ -1476,17 +1587,19 @@ class Manager:
             self.metrics.incr("fallback_steps")
 
         def _barrier() -> bool:
-            commit_start = _time.perf_counter()
             self._count_control_rpc()
-            should_commit = self._client.should_commit(
-                self._rank,
-                self._step,
-                local_should_commit,
-                timeout=_seconds(timeout) if timeout else self._timeout,
-            )
-            self.metrics.observe(
-                "commit_barrier", _time.perf_counter() - commit_start
-            )
+            barrier = span(self.metrics, "commit_barrier", step=self._step)
+            try:
+                with barrier:
+                    should_commit = self._client.should_commit(
+                        self._rank,
+                        self._step,
+                        local_should_commit,
+                        timeout=_seconds(timeout) if timeout
+                        else self._timeout,
+                    )
+            finally:
+                self._interval.barrier += barrier.elapsed
             self._logger.info(
                 f"should_commit={should_commit} "
                 f"enough_replicas={enough_replicas} "
@@ -1502,6 +1615,11 @@ class Manager:
                     step=self._step, epoch=self._quorum_epoch,
                     participants=self.num_participants(),
                 )
+            if should_commit:
+                self._close_interval()
+            else:
+                self._interval.discards += 1
+                self._interval.dirty = True
 
             self._checkpoint_transport.disallow_checkpoint()
 
@@ -1518,6 +1636,63 @@ class Manager:
         fut = self._executor.submit(_barrier)
         fut.local_should_commit = local_should_commit  # type: ignore[attr-defined]
         return fut
+
+    def _close_interval(self) -> None:
+        """A step just committed: if the interval it closes was an
+        episode, say so — one ``recovery_episode`` event and one
+        ``episode_{kind}_{phase}`` timing per phase — then open the next
+        interval. Runs on whichever thread committed (the barrier's
+        executor thread, or the caller's on the fast path); no other
+        thread touches the interval then (the caller is past its
+        prologue and only awaits the decision)."""
+        iv = self._interval
+        now = time.perf_counter()
+        if iv.dirty:
+            before, after = set(iv.members), set(self._wire_members)
+            if iv.first or iv.healed_at is not None:
+                kind = "rejoin"   # this replica is new, or healed
+            elif before - after:
+                kind = "shrink"   # members left (others may have joined)
+            elif after - before:
+                kind = "grow"
+            else:
+                kind = "error"    # discards or a latch, same membership
+            gap = now - iv.t0
+            # phases partition the gap: measured where the step's thread
+            # blocked, the remainder (compute, trace, compile, dispatch)
+            # is ``other``
+            phases = {
+                "quorum_wait": iv.quorum_wait, "wire_wait": iv.wire_wait,
+                "heal": iv.heal, "barrier": iv.barrier,
+            }
+            if iv.first:
+                phases["init"] = iv.init or 0.0
+            if iv.healed_at is not None:
+                # heal applied -> this commit, less what was waited for
+                # under another phase's name in between
+                phases["first_step"] = (now - iv.healed_at) - (
+                    iv.stalled() - iv.stalled_at_heal
+                )
+            phases["other"] = gap - sum(phases.values())
+            for phase, seconds in phases.items():
+                self.metrics.observe(f"episode_{kind}_{phase}", seconds)
+            self.metrics.observe(f"episode_{kind}_gap", gap)
+            # reported inside quorum_wait, not beside it
+            self.metrics.observe(f"episode_{kind}_configure", iv.configure)
+            if self.events:
+                self.events.emit(
+                    "recovery_episode", step=self._step,
+                    epoch=self._quorum_epoch, episode=kind,
+                    t_open=iv.t0, gap_ms=round(gap * 1e3, 3),
+                    configure_ms=round(iv.configure * 1e3, 3),
+                    discards=iv.discards, errors=iv.errors,
+                    members_before=len(before), members_after=len(after),
+                    left=len(before - after), joined=len(after - before),
+                    **{f"{phase}_ms": round(seconds * 1e3, 3)
+                       for phase, seconds in phases.items()},
+                )
+        iv.first = False
+        iv.open(now, self._wire_members)
 
     # ----------------------------------------------------------------- state
 

@@ -140,10 +140,12 @@ def _local_causal_attention(q, k, v):
     return causal_attention(q, k, v)
 
 
+@jax.named_scope("attn")
 def _attn_sublayer(cfg, layer: Dict, x, *, attn_fn):
     """ln_1 + multi-head causal attention + residual. ``cfg`` is duck-typed
     (needs dtype/n_heads/head_dim/d_model) so MoE and other families reuse
-    the exact dense attention path."""
+    the exact dense attention path. Scope ``attn`` on the device trace:
+    projections and the flash call inside (docs/operations.md §6)."""
     dt = cfg.dtype
     h = _layer_norm(x, layer["ln_1"]["scale"], layer["ln_1"]["bias"])
     B, S, _ = h.shape
@@ -164,13 +166,15 @@ def _block(cfg: TransformerConfig, layer: Dict, x, *, attn_fn):
     dt = cfg.dtype
     x = _attn_sublayer(cfg, layer, x, attn_fn=attn_fn)
 
-    h = _layer_norm(x, layer["ln_2"]["scale"], layer["ln_2"]["bias"])
-    h = h @ layer["mlp"]["up_proj"]["kernel"].astype(dt)
-    h = jax.nn.gelu(h)
-    x = x + h @ layer["mlp"]["down_proj"]["kernel"].astype(dt)
+    with jax.named_scope("mlp"):
+        h = _layer_norm(x, layer["ln_2"]["scale"], layer["ln_2"]["bias"])
+        h = h @ layer["mlp"]["up_proj"]["kernel"].astype(dt)
+        h = jax.nn.gelu(h)
+        x = x + h @ layer["mlp"]["down_proj"]["kernel"].astype(dt)
     return x
 
 
+@jax.named_scope("embed")
 def _embed(cfg, params: Dict, tokens):
     """Token + learned-position embeddings in the compute dtype. ``cfg`` is
     duck-typed (needs dtype) so other families share the preamble."""
@@ -180,6 +184,7 @@ def _embed(cfg, params: Dict, tokens):
     return x + params["wpe"]["embedding"].astype(dt)[jnp.arange(S)][None, :, :]
 
 
+@jax.named_scope("lm_head_xent")
 def ce_from_hidden(h, lm_head_kernel, targets, xent_chunks: int = 0):
     """Mean next-token cross entropy from final-norm hidden states.
     ``xent_chunks`` > 0 routes through ops/xent.py's online-logsumexp scan
@@ -252,16 +257,20 @@ def make_train_step(cfg: TransformerConfig, tx,
     never recompile this function."""
     import optax
 
-    def step(params, opt_state, tokens, targets):
+    from torchft_tpu.utils.profiling import StepProgram
+
+    # the function's name is the program's on the trace's XLA Modules line
+    def tft_train_step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(cfg, p, tokens, targets, attn_fn)
         )(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("opt_update"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     donate_argnums = (0, 1) if donate else ()
-    return jax.jit(step, donate_argnums=donate_argnums)
+    return StepProgram(jax.jit(tft_train_step, donate_argnums=donate_argnums))
 
 
 def make_grad_step(cfg: TransformerConfig,
@@ -278,7 +287,9 @@ def make_grad_step(cfg: TransformerConfig,
     mean). The knob large effective batches need under a fixed HBM
     budget; the batch dim must divide evenly."""
 
-    def step(params, tokens, targets):
+    from torchft_tpu.utils.profiling import StepProgram
+
+    def tft_grad_step(params, tokens, targets):
         if microbatches <= 1:
             return jax.value_and_grad(
                 lambda p: loss_fn(cfg, p, tokens, targets, attn_fn)
@@ -316,4 +327,4 @@ def make_grad_step(cfg: TransformerConfig,
             lambda g, p: (g * inv).astype(p.dtype), grad_sum, params
         )
 
-    return jax.jit(step)
+    return StepProgram(jax.jit(tft_grad_step))

@@ -196,6 +196,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             ],
             out_shape=out_shapes,
             interpret=interpret,
+            name="flash_fwd",
         )(q, k, v)
         return out, lse[..., 0]
 
@@ -230,6 +231,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         out_shape=out_shapes,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -459,6 +461,7 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -489,6 +492,7 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 
@@ -546,6 +550,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, g, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -572,6 +577,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

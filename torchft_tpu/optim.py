@@ -21,11 +21,13 @@ recompile anything.
 from __future__ import annotations
 
 import logging
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from torchft_tpu.utils.device import land_like
+from torchft_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -835,14 +837,23 @@ class OptimizerWrapper:
         # bottleneck (the tax is device/transport time), while large
         # dispatch means per-program host overhead.
         from torchft_tpu.utils.metrics import Metrics
+        from torchft_tpu.utils.profiling import StepProgram
 
         self.metrics = Metrics(window=512)
+        # spans of this sink carry the Manager's replica id too (a test
+        # double of the manager may not have one)
+        replica_id = getattr(manager, "replica_id", None)
+        if callable(replica_id):
+            self.metrics.label("replica_id", replica_id())
 
-        def _update(grads, opt_state, params):
-            updates, new_state = tx.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), new_state
+        # the functions' names are the programs' on a trace's XLA Modules
+        # line, the scope is what their operations are filed under
+        def tft_opt_update(grads, opt_state, params):
+            with jax.named_scope("opt_update"):
+                updates, new_state = tx.update(grads, opt_state, params)
+                return optax.apply_updates(params, updates), new_state
 
-        self._update = jax.jit(_update)
+        self._update = StepProgram(jax.jit(tft_opt_update))
 
         # Decide-then-apply variant for HBM-constrained multi-peer wires:
         # donating (opt_state, params) means the update program allocates
@@ -860,8 +871,8 @@ class OptimizerWrapper:
         # its deferred device_get runs. The probe is a fresh 1-element
         # buffer no later step ever consumes (the same role the loss aux
         # plays for the fused path).
-        def _update_probed(grads, opt_state, params):
-            new_params, new_state = _update(grads, opt_state, params)
+        def tft_opt_update_donated(grads, opt_state, params):
+            new_params, new_state = tft_opt_update(grads, opt_state, params)
             probe = jax.tree_util.tree_leaves(new_params)[0].ravel()[0]
             return new_params, new_state, probe
 
@@ -871,12 +882,17 @@ class OptimizerWrapper:
         # would leave one param-shaped donation unusable every step (XLA
         # warns per dispatch, and the grads donation buys no HBM — the
         # peak already excludes a second params+opt footprint).
-        self._update_donated = jax.jit(
-            _update_probed, donate_argnums=(1, 2)
-        )
+        self._update_donated = StepProgram(jax.jit(
+            tft_opt_update_donated, donate_argnums=(1, 2)
+        ))
 
     def init(self, params) -> Any:
         return self.tx.init(params)
+
+    def _span(self, name: str) -> span:
+        """One phase of this step: a timing in ``self.metrics`` and a
+        ``tft.<name>`` span on the profiler timeline."""
+        return span(self.metrics, name, step=self.manager.current_step())
 
     def begin_step(self, **kwargs) -> None:
         """Start the (async) quorum — call before the forward pass
@@ -928,10 +944,12 @@ class OptimizerWrapper:
             # every average_gradients_async path returns exactly a
             # concurrent.futures.Future — an isinstance check can't
             # misfire on a user pytree that happens to expose .result()
-            grads = grads.result()
+            blocked = getattr(self.manager, "blocked_on_wire", nullcontext)
+            with blocked():
+                grads = grads.result()
         if self._donate_update:
             return self._step_donated(params, opt_state, grads)
-        with self.metrics.timed("prologue"):
+        with self._span("prologue"):
             decision = self.manager.should_commit_async()
         dispatched = False
         if getattr(decision, "local_should_commit", True):
@@ -940,13 +958,13 @@ class OptimizerWrapper:
                 # user's holder; the caller's args predate it. Re-read so
                 # the (received-average) update lands on healed state.
                 params, opt_state = self._state_fn()
-            with self.metrics.timed("dispatch"):
+            with self._span("dispatch"):
                 new_params, new_opt = self._update(grads, opt_state, params)
             dispatched = True
         # Exposed barrier time only: whatever the RPC costs BEYOND the
         # dispatch it overlapped — the honest per-step FT tax.
         try:
-            with self.metrics.timed("barrier"):
+            with self._span("barrier"):
                 committed = bool(decision.result())
         except BaseException:
             # Barrier RPC failed (manager wedged, timeout): the caller's
@@ -962,7 +980,7 @@ class OptimizerWrapper:
             # block_until_ready, not a device_get readback: these updates
             # are not donated, so the params tree stays valid to wait on,
             # and waiting moves no bytes to the host.
-            with self.metrics.timed("fence"):
+            with self._span("fence"):
                 self._push_fence("block", new_params)
             return new_params, new_opt, True
         # Non-committing step (error latched, insufficient quorum, heal
@@ -993,16 +1011,16 @@ class OptimizerWrapper:
         HBM adds no second params+opt footprint. The caller's (params,
         opt_state) references are CONSUMED on a committing step (grads
         stay valid; donating them buys nothing — see __init__)."""
-        with self.metrics.timed("barrier"):
+        with self._span("barrier"):
             committed = self.manager.should_commit()
         if committed:
             if self.manager.did_heal() and self._state_fn is not None:
                 params, opt_state = self._state_fn()
-            with self.metrics.timed("dispatch"):
+            with self._span("dispatch"):
                 new_params, new_opt, probe = self._update_donated(
                     grads, opt_state, params
                 )
-            with self.metrics.timed("fence"):
+            with self._span("fence"):
                 # Donated chain: fence via a readback of the probe
                 # scalar — completion of any output of an XLA execution
                 # implies the whole execution (the donated update
@@ -1125,7 +1143,7 @@ class OptimizerWrapper:
         quorum itself) and use the grad/average/:meth:`step` path when it
         returns False."""
         self.fused_steps += 1
-        with self.metrics.timed("barrier"):
+        with self._span("barrier"):
             committed = self.manager.should_commit()
         if committed:
             if self.manager.did_heal() and self._state_fn is not None:
@@ -1140,11 +1158,11 @@ class OptimizerWrapper:
                 # fused entries are loss scalars. Timed separately so a
                 # transition's device-scale wait can't masquerade as
                 # per-program dispatch overhead in the breakdown.
-                with self.metrics.timed("transition_drain"):
+                with self._span("transition_drain"):
                     self._drain_fence()
-            with self.metrics.timed("dispatch"):
+            with self._span("dispatch"):
                 params, opt_state, aux = fused_fn(params, opt_state, *args)
-            with self.metrics.timed("fence"):
+            with self._span("fence"):
                 self._push_fence("readback", aux)
             return params, opt_state, aux, True
         self._drain_fence()

@@ -17,6 +17,16 @@ Event vocabulary (producers in parentheses):
                                       healed state applied)
     member_dead                      (manager.py: a replica left the
                                       wire between two quorums)
+    recovery_episode                 (manager.py: the commit that ends a
+                                      commit-to-commit interval in which
+                                      a step was discarded, an error
+                                      latched, the wire membership
+                                      changed or this replica healed —
+                                      ``episode`` shrink / grow / rejoin
+                                      / error, ``gap_ms`` and the phases
+                                      that partition it: quorum_wait,
+                                      wire_wait, heal, barrier, other,
+                                      and for a rejoin init, first_step)
     error_latched                    (manager.py / comm/transport.py /
                                       comm/xla_backend.py: first latch
                                       of an error episode)
@@ -86,7 +96,11 @@ Event vocabulary (producers in parentheses):
 
 Every event is stamped with a process-monotonic sequence number, wall +
 monotonic clocks, the bound replica_id/rank, and (when the emitter knows
-them) the step and quorum epoch. ``since(seq)`` reads are seq-cursored so
+them) the step and quorum epoch. ``t_mono`` is ``time.monotonic()``,
+which on Linux reads the same clock (CLOCK_MONOTONIC) as
+``time.perf_counter()`` — the clock of the Metrics timings, the ``tft.*``
+spans' durations and a ``recovery_episode``'s ``t_open`` — so an event's
+``t_mono`` can be laid against any of them. ``since(seq)`` reads are seq-cursored so
 pollers (scripts/fleet_top.py) are incremental; overwritten events are
 reported as a ``dropped`` count, never silently.
 
@@ -132,6 +146,7 @@ EVENT_KINDS = (
     "round_abort",
     "error_latched",
     "member_dead",
+    "recovery_episode",
     "mesh_reconfigure",
     "mesh_compile",
     "hier_exchange",

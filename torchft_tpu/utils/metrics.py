@@ -45,6 +45,11 @@ class Metrics:
         with self._lock:
             return dict(self._labels)
 
+    def label_value(self, name: str) -> "str | None":
+        """One label, lock-free (a dict read): what
+        ``utils.profiling.span`` pays per span to stamp ``replica``."""
+        return self._labels.get(name)
+
     def incr(self, name: str, value: float = 1.0) -> None:
         with self._lock:
             self._counters[name] += value

@@ -1,15 +1,29 @@
-"""XLA profiler integration: capture device traces for a step window.
+"""The program's own timeline: host spans on the profiler's clock, and
+XLA device traces for a step window.
 
 The reference ships no profiler hook (SURVEY.md §5); on TPU the natural
 tool is jax.profiler — its traces capture XLA op timelines, HBM traffic,
-and ICI collectives, viewable in TensorBoard/Perfetto. This wraps it in
-the two shapes training loops want:
+and ICI collectives, viewable in TensorBoard/Perfetto. Three shapes:
 
+- ``span(metrics, name, **args)``: THE span primitive. One block, timed
+  once, lands in two places: the ``Metrics`` sink (rolling timing
+  ``name``) and, while a ``jax.profiler`` trace is active, a
+  ``TraceAnnotation`` named ``tft.<name>`` on the host plane, beside
+  the device lines, carrying ``replica=<replica_id>`` (read from the
+  sink's ``replica_id`` label, which the Manager sets) plus whatever
+  the emitter passes (``step=``, ``bucket=``...). Outside a trace the
+  annotation is a no-op of under a microsecond; there is no switch.
+- ``throughput_span``: ``span`` + a bandwidth gauge and a byte counter.
+- ``StepProgram`` / ``scope_tables``: the device side of the same
+  timeline. A step program is jitted under a stable name
+  (``tft_train_step``...) and its body sits in ``jax.named_scope``s; the
+  TPU trace names an operation only by its HLO instruction
+  (``fusion.1916``), so the program hands whoever reads a trace the
+  instruction → scope-path table of what it compiled.
 - ``StepProfiler``: profile steps [start, stop) of a loop, driven by env
   vars so ANY trainer (bench.py, the examples) can be profiled without
   code changes: TORCHFT_TPU_PROFILE_DIR=/tmp/trace
   TORCHFT_TPU_PROFILE_START=10 TORCHFT_TPU_PROFILE_STEPS=5.
-- ``trace()``: a context manager for one-off blocks.
 
 Profiling is strictly zero-cost when TORCHFT_TPU_PROFILE_DIR is unset:
 ``step()`` is two integer compares.
@@ -18,69 +32,78 @@ Profiling is strictly zero-cost when TORCHFT_TPU_PROFILE_DIR is unset:
 from __future__ import annotations
 
 import os
+import re
 import time
 from contextlib import contextmanager
-from typing import Optional
+from typing import Any, Dict, Optional
 
-__all__ = [
-    "StepProfiler", "trace", "host_span", "timed_span", "throughput_span",
-]
+__all__ = ["SPAN_PREFIX", "StepProfiler", "StepProgram", "scope_tables",
+           "span", "throughput_span"]
+
+# Every span of the library is ``tft.<name>`` on the profiler timeline:
+# one prefix a trace reducer selects on.
+SPAN_PREFIX = "tft."
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, False without jax
+
+
+def _annotation_cls() -> Any:
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            import jax
+
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        except Exception:  # pragma: no cover — jax-less environment
+            # (numpy-only transport tools): spans still feed the sink
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+class span:
+    """Time the enclosed block into ``metrics`` under ``name`` AND
+    annotate the profiler timeline as ``tft.<name>`` with ``args``.
+
+    ``metrics=None`` keeps the annotation only. ``elapsed`` holds the
+    block's wall seconds after exit. A class, not a generator: the
+    steady-state step enters half a dozen of these."""
+
+    __slots__ = ("_metrics", "_name", "_annotation", "_start", "elapsed")
+
+    def __init__(self, metrics, name: str, **args: Any) -> None:
+        self._metrics = metrics
+        self._name = name
+        self.elapsed = 0.0
+        cls = _annotation_cls()
+        if cls:
+            if metrics is not None:
+                # getattr: test doubles of the sink only need observe()
+                label = getattr(metrics, "label_value", None)
+                replica = label("replica_id") if label else None
+                if replica is not None:
+                    args["replica"] = replica
+            self._annotation = cls(SPAN_PREFIX + name, **args)
+        else:  # pragma: no cover — jax-less environment
+            self._annotation = None
+
+    def __enter__(self) -> "span":
+        self._start = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        self.elapsed = time.perf_counter() - self._start
+        if self._metrics is not None:
+            self._metrics.observe(self._name, self.elapsed)
 
 
 @contextmanager
-def trace(log_dir: str):
-    """Profile the enclosed block into ``log_dir`` (TensorBoard/Perfetto
-    readable)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@contextmanager
-def host_span(name: str):
-    """Annotate a host-side region (gradient pack/unpack, transport
-    phases) so it shows up on the profiler timeline next to the XLA ops
-    it overlaps with. Near-zero cost when no trace is active (a
-    TraceAnnotation outside a trace window is a no-op); degrades to a
-    plain passthrough when jax is unavailable (numpy-only transport
-    tools)."""
-    try:
-        import jax
-
-        annotation = jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover — jax-less environment
-        yield
-        return
-    with annotation:
-        yield
-
-
-@contextmanager
-def timed_span(metrics, name: str, span: Optional[str] = None):
-    """``host_span`` + ``Metrics.observe`` in one: annotate the profiler
-    timeline (under ``span``, or ``name`` when omitted) AND record the
-    block's wall duration into ``metrics`` under ``name``. The streamed
-    DDP pipeline uses this for its per-bucket stage timers (``ddp_d2h``
-    / ``ddp_ef`` / ``ddp_wire`` / ``ddp_h2d``), so one context manager
-    keeps the trace view and the metrics view of a stage in lockstep.
-    ``metrics=None`` degrades to a plain ``host_span``."""
-    start = time.perf_counter()
-    try:
-        with host_span(span or name):
-            yield
-    finally:
-        if metrics is not None:
-            metrics.observe(name, time.perf_counter() - start)
-
-
-@contextmanager
-def throughput_span(metrics, name: str, nbytes: "int | list"):
-    """``timed_span`` + a derived ``{name}_bytes_per_s`` gauge + a
-    cumulative ``{name}_bytes`` counter.
+def throughput_span(metrics, name: str, nbytes: "int | list", **args: Any):
+    """``span`` + a derived ``{name}_bytes_per_s`` gauge + a cumulative
+    ``{name}_bytes`` counter.
 
     The heal plane wraps its wire phase in this so the same block feeds
     the profiler timeline, the ``{name}`` timing window, AND a
@@ -92,19 +115,89 @@ def throughput_span(metrics, name: str, nbytes: "int | list"):
     span happened to finish last. ``nbytes`` may be a mutable
     single-element list when the byte count is only known at exit (a
     fetch whose manifest arrives inside the span)."""
-    start = time.perf_counter()
+    timed = span(metrics, name, **args)
     try:
-        with host_span(name):
+        with timed:
             yield
     finally:
-        elapsed = time.perf_counter() - start
         if metrics is not None:
-            metrics.observe(name, elapsed)
             n = nbytes[0] if isinstance(nbytes, list) else nbytes
             if n:
                 metrics.incr(f"{name}_bytes", n)
-                if elapsed > 0:
-                    metrics.gauge(f"{name}_bytes_per_s", n / elapsed)
+                if timed.elapsed > 0:
+                    metrics.gauge(f"{name}_bytes_per_s", n / timed.elapsed)
+
+
+# instruction name → op_name of one line of ``Compiled.as_text()``:
+#   %fusion.12 = bf16[...] fusion(...), ..., metadata={op_name="jit(f)/attn/dot_general" ...}
+_HLO_LINE = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*metadata=\{[^}]*op_name="([^"]*)"'
+)
+
+# The newest program that ran under each name (``tft_train_step``,
+# ``tft_grad_step``, ``tft_opt_update``): what ``scope_tables`` reads. A
+# strong reference, because the reader comes after the loop that owned
+# the program has gone; one entry a name, so nothing accumulates.
+_PROGRAMS: Dict[str, "StepProgram"] = {}
+
+
+class StepProgram:
+    """A jitted step function under a stable name.
+
+    Calls go straight to the jitted function; the first call also notes
+    the arguments' shapes, dtypes and placements (no array is kept), so
+    that ``scope_table`` can later lower and compile the same program
+    again — served by jax's compilation cache where one is placed — and
+    read, from the compiled text, which ``jax.named_scope`` path each HLO
+    instruction came from. Every other attribute (``lower``, ``trace``,
+    ``clear_cache``...) is the jitted function's own."""
+
+    def __init__(self, jitted: Any) -> None:
+        self._jitted = jitted
+        self._avals: Any = None
+        self.name: str = jitted.__name__
+
+    def __call__(self, *args: Any) -> Any:
+        if self._avals is None:
+            import jax
+
+            self._avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=getattr(a, "sharding", None)
+                ),
+                args,
+            )
+            _PROGRAMS[self.name] = self
+        return self._jitted(*args)
+
+    def __getattr__(self, attr: str) -> Any:
+        return getattr(self._jitted, attr)
+
+    def scope_table(self) -> Dict[str, str]:
+        """``{HLO instruction name: op_name}`` of the compiled program:
+        ``op_name`` is the scope path jax recorded, e.g.
+        ``jit(tft_train_step)/transpose(jvp(attn))/dot_general``. Empty
+        before the first call."""
+        if self._avals is None:
+            return {}
+        text = self._jitted.lower(*self._avals).compile().as_text()
+        table: Dict[str, str] = {}
+        for line in text.splitlines():
+            m = _HLO_LINE.match(line)
+            if m:
+                table[m.group(1)] = m.group(2)
+        return table
+
+
+def scope_tables() -> Dict[str, Dict[str, str]]:
+    """``{XLA module name: {instruction: op_name}}`` for the newest step
+    program that ran under each name in this process. The module name is
+    the one a trace's ``XLA Modules`` line shows (``jit_tft_train_step``).
+    Compiles (from the cache): call it after the measured window."""
+    return {
+        "jit_" + name: program.scope_table()
+        for name, program in list(_PROGRAMS.items())
+    }
 
 
 class StepProfiler:
