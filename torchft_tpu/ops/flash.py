@@ -1,27 +1,58 @@
-"""Pallas flash-attention (forward) kernel for TPU.
+"""Pallas flash-attention kernels for TPU: forward, dQ and dK/dV.
 
-Streams K/V blocks through VMEM with an online-softmax accumulator so the
-[S, S] score matrix never materializes in HBM; per q-block the causal loop
-runs only over the k-blocks at or before the diagonal, so causal attention
-does half the FLOPs of the dense path. Scores/accumulation in f32 on the
-MXU (preferred_element_type), inputs/outputs bf16.
+Forward: K/V tiles go through VMEM with an online-softmax accumulator, so
+the [S, S] score matrix never materializes in HBM. Backward:
+FlashAttention-2 style — residuals are (q, k, v, out, lse); delta =
+rowsum(dO·O) is a cheap XLA reduce; ``flash_dq`` sweeps k-tiles per
+q-tile and ``flash_dkv`` sweeps q-tiles per k-tile, recomputing P =
+exp(S − lse) tile by tile. Two regimes of each: resident kernels hold K/V
+(resp. Q/dO) whole in VMEM up to ``_RESIDENT_KV_BYTES`` and loop over
+tiles inside the kernel; streamed kernels ride the tiles over the
+innermost grid dimension with VMEM scratch accumulators (long context).
 
-Backward: fused FlashAttention-2-style pallas kernels in the resident-KV
-regime — residuals are (q, k, v, out, lse); delta = rowsum(dO·O) is a
-cheap XLA reduce; a dQ kernel sweeps k-blocks per q-block and a dK/dV
-kernel sweeps q-blocks per k-block, recomputing P = exp(S − lse) tile by
-tile so nothing [S, S]-shaped ever touches HBM in either direction. Both
-regimes are fused: resident kernels hold K/V (resp. Q/dO) in VMEM for
-short/medium sequences; streamed kernels ride tiles over the innermost
-grid dimension with VMEM scratch accumulators for long context.
+What is which dtype. q, k, v, dO arrive and out, dq, dk, dv leave in the
+input dtype (bf16 in the models). Inside a kernel every operand is upcast
+to f32 as it is loaded and every ``dot_general`` is f32 x f32 -> f32
+(``preferred_element_type``); scores, P, dS, the running max / sum, lse,
+delta and all accumulators are f32. The softmax ``scale`` is multiplied
+into the f32 q once per q tile ([BQ, D], not per score tile), so dSᵀ·Q
+already is dk and dS·K takes ``scale`` once, at the end of dq. Measured
+on the v5e (PERF.md, PR 24): at Mosaic's default precision the MXU takes
+f32 operands in ONE pass and rounds them to bf16 itself — with bf16
+inputs the outputs are bit for bit those of a kernel that casts P and dS
+to bf16 first — so that cast buys no MXU time and costs vector work
+(-0.6 % of the 111m step); it is left out.
 
-Mosaic layout note: per-row statistics (lse, delta) ride through HBM as
-[BH, S, 1] so every block spec keeps its last two dims tile-legal
-(second-to-last divisible by 8, last equal to the array dim); inside the
-kernels they stay 2-D [BQ, 1] column vectors — Mosaic's tiled layout
-prefers 2-D keepdims math over 1-D vectors. (jax's reference TPU kernel
-broadcasts lse across 128 lanes instead; the singleton lane column costs
-128x less HBM traffic and lowers fine.)
+The causal sweep. Tiles wholly above the diagonal are skipped; tiles
+wholly at or below it (``_tile_full``) run a body with no iota, compare or
+select; only the tiles that straddle the diagonal run the masked body.
+``_causal_sweep`` is the one closed form of both bounds for the row sweeps
+(forward, dq) and the column sweep (dkv); ``_sweep`` runs the full tiles
+in a loop and the diagonal ones straight-line (a fixed count where one
+tile edge divides the other). The streamed kernels take the same two
+predicates in their ``pl.when``.
+
+dK/dV recomputes its tile TRANSPOSED (Sᵀ = K·Qᵀ, [BK, BQ]): Pᵀ·dO and
+dSᵀ·Q are then plain matmuls and no [BQ, BK] tile goes through the
+transpose unit (``flash_dkv`` -20 % at 64-wide, -26 % at 128-wide heads).
+
+Tiles. ``block_q`` / ``block_k`` default to ``None``: ``_choose_blocks``
+picks them from (S, D, itemsize) alone — the largest of 512 / 256 / 128
+that divides S with ``_vmem_estimate`` <= ``_VMEM_BUDGET``. A tile's cost
+is mostly per loop trip (lane-sparse statistics columns, loop-carried
+accumulators, no overlap of MXU and vector work across trips), so at
+S 2048 512 x 512 tiles run the three kernels 2.3-3.9x faster than
+128 x 128 at both head widths, although the diagonal wastes more (10 of
+16 tiles computed, against 136 of 256). Explicit arguments win
+(parallel/ring.py and the tests pass them).
+
+Mosaic layout note: per-row statistics (lse, delta) are [BH, S] f32 in
+HBM and reach the kernels as views of that array whose last two dims are
+tile-legal: [BH, S, 1] columns for the forward and dq (rows of a score
+tile are q positions; [BQ, 1] keepdims math) and [BH, 1, S] rows for dkv
+(columns of the transposed tile are q positions; [1, BQ]). (jax's
+reference TPU kernel broadcasts lse across 128 lanes instead; a singleton
+dim costs 128x less HBM traffic and lowers fine.)
 
 ``interpret=True`` runs the same kernels through the Pallas interpreter
 (the CPU tests); the default compiles them with Mosaic.
@@ -30,7 +61,7 @@ broadcasts lse across 128 lanes instead; the singleton lane column costs
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,54 +77,150 @@ __all__ = [
 _NEG_INF = -1e30  # avoid nan from (-inf) - (-inf) in the running max
 
 
+# ------------------------------------------------------------ causal tiles
+# Tile (qi, ki) covers rows [qi*BQ, (qi+1)*BQ) and columns [ki*BK,
+# (ki+1)*BK) of the score matrix. Under the causal mask it is
+#   live  iff its first column is at or before its last row, and
+#   full  iff its last column is at or before its first row (no element
+#         masked: the body needs no iota, compare or select).
+# Live tiles that are not full straddle the diagonal and take the mask.
+
+
+def _tile_live(qi, ki, block_q: int, block_k: int):
+    return ki * block_k < (qi + 1) * block_q
+
+
+def _tile_full(qi, ki, block_q: int, block_k: int):
+    return (ki + 1) * block_k - 1 <= qi * block_q
+
+
+def _causal_sweep(idx, block_q: int, block_k: int, seq_len: int,
+                  rows: bool):
+    """The two loop ranges ``(full, diagonal)`` of one causal sweep, each
+    ``(lo, hi)``: the closed forms of :func:`_tile_full` and
+    :func:`_tile_live` along a row of tiles (``rows``: ``idx`` is the q
+    block, the loop runs over k blocks — forward and dq) or along a column
+    (``idx`` is the k block, the loop runs over q blocks — dkv). One
+    definition, so the three resident kernels cannot disagree on which
+    tiles carry the mask."""
+    if rows:
+        full_end = (idx * block_q + 1) // block_k
+        live_end = jnp.minimum(
+            seq_len // block_k,
+            ((idx + 1) * block_q + block_k - 1) // block_k,
+        )
+        return (0, full_end), (full_end, live_end)
+    num_q_blocks = seq_len // block_q
+    live_start = (idx * block_k) // block_q
+    full_start = jnp.minimum(
+        num_q_blocks, ((idx + 1) * block_k + block_q - 2) // block_q
+    )
+    return (full_start, num_q_blocks), (live_start, full_start)
+
+
+def _sweep(idx, block_q: int, block_k: int, seq_len: int, causal: bool,
+           rows: bool, tile, carry):
+    """Run ``tile(i, carry, masked=...)`` over every live tile of a row or
+    a column of tiles: a loop of unmasked bodies over the full tiles, and
+    the masked body on the diagonal ones. Where one block edge divides the
+    other, the diagonal tiles are a fixed count (``block_q // block_k`` of
+    a row, ``block_k // block_q`` of a column, at least one) and are laid
+    out straight-line: a second loop costs more than the mask it saves
+    (PERF.md, PR 24)."""
+    own, other = (block_q, block_k) if rows else (block_k, block_q)
+    if not causal:
+        return jax.lax.fori_loop(
+            0, seq_len // other, functools.partial(tile, masked=False),
+            carry,
+        )
+    full, diagonal = _causal_sweep(idx, block_q, block_k, seq_len, rows)
+    carry = jax.lax.fori_loop(
+        *full, functools.partial(tile, masked=False), carry
+    )
+    if own % other and other % own:
+        return jax.lax.fori_loop(
+            *diagonal, functools.partial(tile, masked=True), carry
+        )
+    for j in range(max(1, own // other)):
+        carry = tile(diagonal[0] + j, carry, masked=True)
+    return carry
+
+
+def _f32(ref_slice):
+    """Kernel operands are upcast as they are loaded: the v5e's MXU takes
+    f32 operands in one pass, and an explicit cast of P or dS back to the
+    input dtype costs more vector work than it saves (PERF.md, PR 24)."""
+    return ref_slice.astype(jnp.float32)
+
+
+def _scores(q, k, qi, ki, masked: bool, transposed: bool = False):
+    """S = Q·Kᵀ for one tile ([BQ, BK]; ``transposed``: Sᵀ = K·Qᵀ,
+    [BK, BQ]); q already carries the softmax scale. ``masked`` adds the
+    causal mask (diagonal tiles only)."""
+    a, b = (k, q) if transposed else (q, k)
+    s = jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if masked:
+        q_axis = 1 if transposed else 0
+        q_pos = qi * q.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, q_axis
+        )
+        k_pos = ki * k.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1 - q_axis
+        )
+        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    return s
+
+
+def _fwd_tile(q, k, v, acc, m, l, qi, ki, masked: bool):
+    """One online-softmax update: (acc, m, l) after tile (qi, ki)."""
+    s = _scores(q, k, qi, ki, masked)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return acc_new, m_new, l_new
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                   block_k: int, seq_len: int, causal: bool, scale: float):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # [BQ, D]
+    q = _f32(q_ref[0]) * scale  # [BQ, D]
     d = q.shape[-1]
 
-    num_k_blocks = seq_len // block_k
-    if causal:
-        # blocks strictly after the diagonal contribute nothing
-        last_block = ((qi + 1) * block_q + block_k - 1) // block_k
-        upper = jnp.minimum(num_k_blocks, last_block)
-    else:
-        upper = num_k_blocks
+    def tile(ki, carry, masked):
+        k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
+        v = _f32(v_ref[0, pl.ds(ki * block_k, block_k), :])
+        return _fwd_tile(q, k, v, *carry, qi, ki, masked)
 
-    acc0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-    m0 = jnp.full((block_q, 1), _NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q, 1), dtype=jnp.float32)
-
-    def body(ki, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [BQ, BK]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc_new, m_new, l_new
-
-    acc, m, l = jax.lax.fori_loop(0, upper, body, (acc0, m0, l0))
+    acc, m, l = _sweep(
+        qi, block_q, block_k, seq_len, causal, True, tile,
+        (jnp.zeros((block_q, d), dtype=jnp.float32),
+         jnp.full((block_q, 1), _NEG_INF, dtype=jnp.float32),
+         jnp.zeros((block_q, 1), dtype=jnp.float32)),
+    )
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)
+
+
+def _when_live(qi, ki, block_q: int, block_k: int, causal: bool, tile):
+    """Streamed regime: run ``tile(masked)`` for grid step (qi, ki) — not
+    at all above the diagonal, masked on it, unmasked below it."""
+    if not causal:
+        tile(False)
+        return
+    full = _tile_full(qi, ki, block_q, block_k)
+    pl.when(full)(functools.partial(tile, False))
+    pl.when(jnp.logical_and(
+        _tile_live(qi, ki, block_q, block_k), jnp.logical_not(full)
+    ))(functools.partial(tile, True))
 
 
 def _flash_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
@@ -111,40 +238,16 @@ def _flash_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Causal: k-blocks strictly above the diagonal contribute nothing.
-    relevant = (
-        ki * block_k < (qi + 1) * block_q if causal else ki >= 0
-    )
+    def _accumulate(masked):
+        acc, m, l = _fwd_tile(
+            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
+            acc_ref[...], m_ref[:, :1], l_ref[:, :1], qi, ki, masked,
+        )
+        acc_ref[...] = acc
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
 
-    @pl.when(relevant)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale   # [BQ, D]
-        k = k_ref[0].astype(jnp.float32)           # [BK, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                      # [BQ, 1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -244,64 +347,70 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 # materializes in HBM in either direction.
 
 
-def _bwd_p_ds(q_scaled, k, v, do, lse, delta, qi, ki, block_q: int,
-              block_k: int, causal: bool):
+def _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked: bool,
+              transposed: bool = False):
     """Shared score recompute for every backward kernel: P = exp(S − lse)
-    with the causal mask, and dS = P ⊙ (dO·Vᵀ − Δ). One definition so
-    mask/softmax changes can never diverge between regimes. lse and delta
-    are [BQ, 1] column vectors (2-D keepdims math lowers best on Mosaic)."""
-    s = jax.lax.dot_general(
-        q_scaled, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    p = jnp.exp(s - lse)
+    (masked on diagonal tiles) and dS = P ⊙ (dO·Vᵀ − Δ), both f32 [BQ, BK]
+    with lse and delta as [BQ, 1] columns — or, ``transposed``, Pᵀ and dSᵀ
+    [BK, BQ] with lse and delta as [1, BQ] rows. One definition so
+    mask/softmax changes can never diverge between kernels or regimes.
+    q carries the softmax scale."""
+    p = jnp.exp(_scores(q, k, qi, ki, masked, transposed) - lse)
+    a, b = (v, do) if transposed else (do, v)
     dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
+        a, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta)
-    return p, ds
+    return p, p * (dp - delta)
+
+
+def _dq_tile(q, k, v, do, lse, delta, qi, ki, masked: bool):
+    """This tile's term of dQ/scale: dS·K, f32 [BQ, D]."""
+    _, ds = _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked)
+    return jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool):
+    """This tile's terms of (dK, dV): dSᵀ·Q (q carries the scale, so this
+    is dL/dK itself) and Pᵀ·dO, f32 [BK, D]. The tile is recomputed
+    TRANSPOSED (lse and delta arrive as [1, BQ] rows), so both products
+    are plain row-by-column matmuls and nothing [BQ, BK]-shaped goes
+    through the transpose unit."""
+    pt, dst = _bwd_p_ds(
+        q, k, v, do, lse, delta, qi, ki, masked, transposed=True
+    )
+    dk = jax.lax.dot_general(
+        dst, q, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dv = jax.lax.dot_general(
+        pt, do, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dk, dv
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, block_q: int, block_k: int,
                          seq_len: int, causal: bool, scale: float):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale      # [BQ, D]
-    do = do_ref[0].astype(jnp.float32)            # [BQ, D]
+    q = _f32(q_ref[0]) * scale                    # [BQ, D]
+    do = _f32(do_ref[0])                          # [BQ, D]
     lse = lse_ref[0]                              # [BQ, 1]
     delta = delta_ref[0]                          # [BQ, 1]
-    d = q.shape[-1]
 
-    num_k_blocks = seq_len // block_k
-    if causal:
-        last_block = ((qi + 1) * block_q + block_k - 1) // block_k
-        upper = jnp.minimum(num_k_blocks, last_block)
-    else:
-        upper = num_k_blocks
+    def tile(ki, dq, masked):
+        k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
+        v = _f32(v_ref[0, pl.ds(ki * block_k, block_k), :])
+        return dq + _dq_tile(q, k, v, do, lse, delta, qi, ki, masked)
 
-    dq0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        _, ds = _bwd_p_ds(
-            q, k, v, do, lse, delta, qi, ki, block_q, block_k, causal
-        )
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    dq = jax.lax.fori_loop(0, upper, body, dq0)
+    dq = _sweep(
+        qi, block_q, block_k, seq_len, causal, True, tile,
+        jnp.zeros(q.shape, dtype=jnp.float32),
+    )
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -309,39 +418,22 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, block_k: int,
                           seq_len: int, causal: bool, scale: float):
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)              # [BK, D]
-    v = v_ref[0].astype(jnp.float32)
-    d = k.shape[-1]
+    k = _f32(k_ref[0])                            # [BK, D]
+    v = _f32(v_ref[0])
 
-    num_q_blocks = seq_len // block_q
-    lower = (ki * block_k) // block_q if causal else 0
-
-    dk0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-    dv0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(
-            jnp.float32
-        ) * scale
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qi * block_q, block_q), :]    # [BQ, 1]
-        delta = delta_ref[0, pl.ds(qi * block_q, block_q), :]
-        p, ds = _bwd_p_ds(
-            q, k, v, do, lse, delta, qi, ki, block_q, block_k, causal
+    def tile(qi, carry, masked):
+        rows = pl.ds(qi * block_q, block_q)
+        dk, dv = _dkv_tile(
+            _f32(q_ref[0, rows, :]) * scale, k, v, _f32(do_ref[0, rows, :]),
+            lse_ref[0, :, rows], delta_ref[0, :, rows],   # [1, BQ]
+            qi, ki, masked,
         )
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # q already carries `scale`, so ds^T @ q includes dL/dk's scale
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
+        return carry[0] + dk, carry[1] + dv
 
-    dk, dv = jax.lax.fori_loop(lower, num_q_blocks, body, (dk0, dv0))
+    zeros = jnp.zeros(k.shape, dtype=jnp.float32)
+    dk, dv = _sweep(
+        ki, block_q, block_k, seq_len, causal, False, tile, (zeros, zeros)
+    )
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -360,25 +452,13 @@ def _flash_bwd_dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    relevant = (
-        ki * block_k < (qi + 1) * block_q if causal else ki >= 0
-    )
+    def _accumulate(masked):
+        dq_acc[...] = dq_acc[...] + _dq_tile(
+            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
+            _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+        )
 
-    @pl.when(relevant)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        _, ds = _bwd_p_ds(
-            q, k, v, do, lse, delta, qi, ki, block_q, block_k, causal
-        )
-        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -400,32 +480,15 @@ def _flash_bwd_dkv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    # causal: this q block contributes iff its last row can see the k
-    # block's first column
-    relevant = (
-        (qi + 1) * block_q > ki * block_k if causal else qi >= 0
-    )
+    def _accumulate(masked):
+        dk, dv = _dkv_tile(
+            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
+            _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+        )
+        dk_acc[...] = dk_acc[...] + dk
+        dv_acc[...] = dv_acc[...] + dv
 
-    @pl.when(relevant)
-    def _accumulate():
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32) * scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        p, ds = _bwd_p_ds(
-            q, k, v, do, lse, delta, qi, ki, block_q, block_k, causal
-        )
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # q already carries `scale`, so ds^T @ q includes dL/dk's scale
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -439,8 +502,10 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
     bh, seq_len, d = q.shape
     num_q_blocks = seq_len // block_q
     num_k_blocks = seq_len // block_k
-    lse = lse[..., None]      # [BH, S, 1] — tile-legal spec layout
-    delta = delta[..., None]
+    # tile-legal views of the [BH, S] statistics (module docstring):
+    # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
+    lse, lse_row = lse[..., None], lse[:, None, :]
+    delta, delta_row = delta[..., None], delta[:, None, :]
 
     dq = pl.pallas_call(
         functools.partial(
@@ -476,8 +541,8 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
@@ -493,7 +558,7 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
         ],
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, g, lse, delta)
+    )(q, k, v, g, lse_row, delta_row)
     return dq, dk, dv
 
 
@@ -529,8 +594,10 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             q, k, v, g, lse, delta, causal, scale, block_q, block_k,
             interpret,
         )
-    lse = lse[..., None]      # [BH, S, 1] — tile-legal spec layout
-    delta = delta[..., None]
+    # tile-legal views of the [BH, S] statistics (module docstring):
+    # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
+    lse, lse_row = lse[..., None], lse[:, None, :]
+    delta, delta_row = delta[..., None], delta[:, None, :]
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
@@ -565,8 +632,8 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, seq_len, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq_len, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq_len, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
@@ -578,7 +645,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
         ),
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, g, lse, delta)
+    )(q, k, v, g, lse_row, delta_row)
     return dq, dk, dv
 
 
@@ -624,15 +691,61 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# Tile edges tried, largest first. Measured on the v5e (PERF.md, PR 24):
+# at S 2048 a 512 x 512 score tile runs the three kernels 2.3-3.9 times
+# faster than 128 x 128 at 64- and at 128-wide heads alike, for the row
+# sweeps and for the column sweep; 1024 gains nothing more.
+_TILE_EDGES = (512, 256, 128)
+# What one kernel instance may plan to hold in VMEM (the v5e's default
+# scoped limit is 16 MiB).
+_VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def _vmem_estimate(seq_len: int, head_dim: int, itemsize: int,
+                   block_q: int, block_k: int) -> int:
+    """Bytes the hungriest of the three kernels (dkv) keeps in VMEM at
+    these tiles: its pipelined operands and statistics twice (double
+    buffering), their f32 copies for one tile, the four f32 score-tile
+    temporaries (S, P, dP, dS) and the two f32 accumulators. In the
+    resident regime Q and dO are whole sequences."""
+    resident = 2 * seq_len * head_dim * itemsize <= _RESIDENT_KV_BYTES
+    q_rows = seq_len if resident else block_q
+    operands = (2 * q_rows + 4 * block_k) * head_dim * itemsize
+    stats = 2 * q_rows * 4
+    upcast = 2 * (block_q + block_k) * head_dim * 4
+    return (2 * (operands + stats) + upcast + 4 * block_q * block_k * 4
+            + 2 * block_k * head_dim * 4)
+
+
+def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
+                   block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> Tuple[int, int]:
+    """``(block_q, block_k)`` as a pure function of the shape: the largest
+    square tile of ``_TILE_EDGES`` that divides ``seq_len`` and whose
+    :func:`_vmem_estimate` fits ``_VMEM_BUDGET`` (the smallest edge when
+    none does, or the whole of a shorter sequence). An explicit argument
+    wins over the rule and is only clamped to the sequence."""
+    edge = min(_TILE_EDGES[-1], seq_len)
+    for cand in _TILE_EDGES:
+        if seq_len % cand == 0 and _vmem_estimate(
+                seq_len, head_dim, itemsize, cand, cand) <= _VMEM_BUDGET:
+            edge = cand
+            break
+    return (edge if block_q is None else min(block_q, seq_len),
+            edge if block_k is None else min(block_k, seq_len))
+
+
 def _bshd_prologue(q, scale, block_q, block_k):
-    """Shared [B,S,H,D]-surface plumbing: scale default, block clamping,
+    """Shared [B,S,H,D]-surface plumbing: scale default, block choice
+    (from the shape where the caller gave none) and clamping,
     divisibility validation, and the [B,S,H,D] <-> [B*H,S,D] layout
     pair. One place, three wrappers."""
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
+    block_q, block_k = _choose_blocks(
+        s, d, q.dtype.itemsize, block_q, block_k
+    )
     if s % block_q or s % block_k:
         raise ValueError(
             f"flash attention: seq len {s} of q{tuple(q.shape)} must be a "
@@ -650,7 +763,8 @@ def _bshd_prologue(q, scale, block_q, block_k):
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              scale: Optional[float] = None,
-                             block_q: int = 128, block_k: int = 128,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
                              interpret: bool = False):
     """Forward-only flash attention returning ``(out, lse)`` with
     out [B, S, H, D] and lse [B, H, S] (log-sum-exp of the scaled scores,
@@ -676,7 +790,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
 
 def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
                               scale: Optional[float] = None,
-                              block_q: int = 128, block_k: int = 128,
+                              block_q: Optional[int] = None,
+                              block_k: Optional[int] = None,
                               interpret: bool = False):
     """Gradient CONTRIBUTIONS of one (q-block, kv-block) pair under
     global softmax statistics.
@@ -709,13 +824,16 @@ def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
 
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False,
                     _resident_kv_bytes: Optional[int] = None):
     """[B, S, H, D] flash attention (pallas on TPU).
 
-    Sequence length must be a multiple of the block sizes (pad upstream if
-    needed; the model configs here use powers of two).
+    ``block_q`` / ``block_k`` left ``None`` are chosen from the shape
+    (:func:`_choose_blocks`). Sequence length must be a multiple of the
+    block sizes (pad upstream if needed; the model configs here use powers
+    of two).
 
     ``_resident_kv_bytes`` overrides the resident-vs-streamed regime
     threshold for THIS call (0 forces the streamed kernels); used by
