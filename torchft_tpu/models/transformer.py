@@ -145,7 +145,7 @@ def _attn_sublayer(cfg, layer: Dict, x, *, attn_fn):
     """ln_1 + multi-head causal attention + residual. ``cfg`` is duck-typed
     (needs dtype/n_heads/head_dim/d_model) so MoE and other families reuse
     the exact dense attention path. Scope ``attn`` on the device trace:
-    projections and the flash call inside (docs/operations.md §6)."""
+    projections and the flash call inside (docs/operations.md §8)."""
     dt = cfg.dtype
     h = _layer_norm(x, layer["ln_1"]["scale"], layer["ln_1"]["bias"])
     B, S, _ = h.shape
@@ -248,34 +248,40 @@ def loss_fn(cfg: TransformerConfig, params, tokens, targets,
     )
 
 
-def make_train_step(cfg: TransformerConfig, tx,
+def make_train_step(cfg: Any, tx,
                     attn_fn: Optional[Callable] = None,
-                    donate: bool = True):
+                    donate: bool = True, loss: Callable = loss_fn):
     """Jitted (params, opt_state, tokens, targets) -> (params, opt_state,
     loss). The replica dimension does not exist here — cross-replica
     averaging happens outside on the grad pytree (ddp.py) so quorum changes
-    never recompile this function."""
+    never recompile this function.
+
+    The one step maker of every model family: ``loss(cfg, params, tokens,
+    targets, attn_fn) -> scalar`` is the family's training loss (this
+    file's ``loss_fn``, ``models/olmoe.py::loss_fn``) and ``cfg`` its
+    config; the program's name, the ``opt_update`` scope, ``StepProgram``
+    and donation are the same for all."""
     import optax
 
     from torchft_tpu.utils.profiling import StepProgram
 
     # the function's name is the program's on the trace's XLA Modules line
     def tft_train_step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(cfg, p, tokens, targets, attn_fn)
+        value, grads = jax.value_and_grad(
+            lambda p: loss(cfg, p, tokens, targets, attn_fn)
         )(params)
         with jax.named_scope("opt_update"):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        return params, opt_state, value
 
     donate_argnums = (0, 1) if donate else ()
     return StepProgram(jax.jit(tft_train_step, donate_argnums=donate_argnums))
 
 
-def make_grad_step(cfg: TransformerConfig,
+def make_grad_step(cfg: Any,
                    attn_fn: Optional[Callable] = None,
-                   microbatches: int = 1):
+                   microbatches: int = 1, loss: Callable = loss_fn):
     """Jitted (params, tokens, targets) -> (loss, grads): the FT-DDP path
     computes grads on-device, averages them across replica groups over DCN,
     then applies the optimizer behind the commit gate.
@@ -292,7 +298,7 @@ def make_grad_step(cfg: TransformerConfig,
     def tft_grad_step(params, tokens, targets):
         if microbatches <= 1:
             return jax.value_and_grad(
-                lambda p: loss_fn(cfg, p, tokens, targets, attn_fn)
+                lambda p: loss(cfg, p, tokens, targets, attn_fn)
             )(params)
         b = tokens.shape[0]
         if b % microbatches:
@@ -306,11 +312,11 @@ def make_grad_step(cfg: TransformerConfig,
         def body(carry, xs):
             loss_acc, grad_acc = carry
             tok, tgt = xs
-            loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(cfg, p, tok, tgt, attn_fn)
+            value, grads = jax.value_and_grad(
+                lambda p: loss(cfg, p, tok, tgt, attn_fn)
             )(params)
             return (
-                loss_acc + loss,
+                loss_acc + value,
                 jax.tree_util.tree_map(jnp.add, grad_acc, grads),
             ), None
 

@@ -38,7 +38,7 @@ from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
 __all__ = ["SPAN_PREFIX", "StepProfiler", "StepProgram", "scope_tables",
-           "span", "throughput_span"]
+           "span", "step_args", "throughput_span"]
 
 # Every span of the library is ``tft.<name>`` on the profiler timeline:
 # one prefix a trace reducer selects on.
@@ -198,6 +198,14 @@ def scope_tables() -> Dict[str, Dict[str, str]]:
         "jit_" + name: program.scope_table()
         for name, program in list(_PROGRAMS.items())
     }
+
+
+def step_args(name: str) -> Any:
+    """What the newest step program that ran under ``name``
+    (``"tft_train_step"``) was first called with: its arguments as a
+    pytree of ``jax.ShapeDtypeStruct``. ``None`` if none ran."""
+    program = _PROGRAMS.get(name)
+    return None if program is None else program._avals
 
 
 class StepProfiler:
