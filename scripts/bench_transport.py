@@ -335,11 +335,10 @@ def _stripe_sweep(store, payload_mb: int, iters_override,
             run(algorithm, world, 4, tree=baseline_tree)  # PR1 default
         for codec in ("none", "bf16", "int8"):
             for chunk_kb in (1024, 4096):
-                for channels, stripe in ((2, True), (4, True), (4, False)):
+                for channels in (2, 4):
                     run(
                         algorithm, world, channels,
                         chunk_bytes=chunk_kb << 10, compression=codec,
-                        stripe=stripe,
                     )
     return cells
 

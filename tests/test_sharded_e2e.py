@@ -86,6 +86,14 @@ class _Replica:
             except InjectedFailure:
                 logger.warning("replica %s restarting after injected kill",
                                self.replica_id)
+                # A killed host is not back within the survivors' join
+                # window (200 ms here). The teardown in _main usually
+                # takes 0.5 s (the checkpoint server's poll interval) and
+                # that alone kept the restart out of it; when it happens
+                # to take 10 ms the new incarnation lands in the SAME
+                # quorum as the survivors' next step and the lifecycle
+                # under test (shrink, then rejoin) never occurs.
+                self.harness.stop.wait(0.5)
                 continue
             except RuntimeError as e:
                 # the failure-after-vote window: restart + heal

@@ -91,6 +91,9 @@ class _Replica:
     def __init__(self, name: str, value: float, lighthouse_addr: str) -> None:
         self.store = StoreServer()
         self.state = {"w": np.full((4,), value, np.float32)}
+        # weights as committed, by the step they made: two free-running
+        # loops are compared at a step both have, never mid-commit
+        self.committed: Dict[int, np.ndarray] = {}
         self.stop = threading.Event()
         self.manager = Manager(
             comm=TcpCommContext(timeout=5.0),
@@ -118,6 +121,7 @@ class _Replica:
                 time.sleep(0.01)  # the step's compute: lands in ``other``
                 if m.should_commit():
                     self.state["w"] = self.state["w"] - g
+                    self.committed[m.current_step()] = self.state["w"]
             except Exception:  # noqa: BLE001 — torn down under the loop
                 if self.stop.is_set():
                     return
@@ -137,6 +141,11 @@ class _Replica:
         while self.manager.current_step() < step:
             assert time.monotonic() < deadline, "the loop stopped committing"
             time.sleep(0.01)
+
+
+def _equal_at_last_common_step(a: _Replica, b: _Replica) -> bool:
+    step = max(set(a.committed) & set(b.committed))
+    return np.array_equal(a.committed[step], b.committed[step])
 
 
 @pytest.fixture(scope="module")
@@ -170,8 +179,7 @@ def kill_and_rejoin():
             "replacement": replacement.episodes(),
             "everything": survivor.episodes() + replacement.episodes(),
             "snapshot": snapshot,
-            "equal": np.array_equal(
-                survivor.state["w"], replacement.state["w"]),
+            "equal": _equal_at_last_common_step(survivor, replacement),
         }
     finally:
         for r in live:

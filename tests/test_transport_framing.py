@@ -242,3 +242,55 @@ def test_bucket_plan_staging_arena_reuse() -> None:
             [np.zeros(plan.sizes[i], np.float64) for i in plan.buckets[0]],
             staging[0],
         )
+
+
+@pytest.mark.parametrize("world", [3, 4])
+def test_ring_member_death_fails_every_survivor_at_once(world) -> None:
+    # The victim's ring neighbours see its sockets close; a survivor that
+    # is NOT its neighbour only ever talks to healthy ranks. It must fail
+    # as soon as they do (they shut their own sockets down on the latch),
+    # not sit in a hop until the timeout: with one op in flight there is
+    # no later op whose header would trip the sequence check.
+    import time
+
+    timeout = 12.0
+    store = StoreServer()
+    ctxs = [TcpCommContext(timeout=timeout, algorithm="ring")
+            for _ in range(world)]
+    victim = world - 1
+    configured = threading.Barrier(world)
+    failed_after = [None] * world
+    died_at = [None]
+
+    def _worker(rank):
+        ctxs[rank].configure(f"{store.addr}/death", rank, world)
+        configured.wait()
+        if rank == victim:
+            time.sleep(0.5)  # the others are inside their first hop
+            died_at[0] = time.perf_counter()
+            ctxs[rank].shutdown()
+            return
+        fut = ctxs[rank].allreduce(
+            [np.ones(4 << 20, np.float32)]  # 16 MiB: hops of megabytes
+        ).future()
+        with pytest.raises(Exception):
+            fut.result(timeout=2 * timeout)
+        failed_after[rank] = time.perf_counter()
+
+    threads = [threading.Thread(target=_worker, args=(r,))
+               for r in range(world)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=3 * timeout)
+        assert not t.is_alive()
+    for ctx in ctxs:
+        ctx.shutdown()
+    store.shutdown()
+    for rank in range(world):
+        if rank != victim:
+            assert ctxs[rank].errored() is not None
+            assert failed_after[rank] - died_at[0] < timeout / 3, (
+                f"rank {rank} sat {failed_after[rank] - died_at[0]:.1f} s "
+                "after the death"
+            )

@@ -7,6 +7,12 @@ the whole grid on a single lane — striping changes where bytes travel,
 never what is computed. Pinned here for every codec, both topologies,
 and chunk sizes that do and do not divide the payload.
 
+The ring's own cut (``chunk_bytes=None``, identity codec): sized from the
+op — every rank derives it from shapes alone, a one-array op gives each
+lane one contiguous view, results are bitwise equal on all ranks and
+equal to the explicit grid of the same slices; an explicit grid
+reproduces the bytes it always gave (golden digests), for every codec.
+
 Error feedback (ddp.py): the per-bucket residual arena makes the lossy
 codecs' quantization error a delayed correction instead of a bias —
 int8+EF tracks the fp32 trajectory on a toy quadratic while raw int8
@@ -20,9 +26,17 @@ from concurrent.futures import Future, ThreadPoolExecutor
 import numpy as np
 import pytest
 
+import hashlib
+
 from torchft_tpu.comm import ReduceOp, StoreServer, TcpCommContext
+from torchft_tpu.comm import transport
 from torchft_tpu.comm.context import Work
-from torchft_tpu.comm.transport import _CODECS, _chunk_grid
+from torchft_tpu.comm.transport import (
+    _CODECS,
+    _chunk_grid,
+    _chunk_grid_owned,
+    _ring_lanes,
+)
 from torchft_tpu.ddp import DistributedDataParallel
 from torchft_tpu.futures import future_chain
 
@@ -173,26 +187,349 @@ def test_striped_allreduce_reduces_in_place_and_avg(store) -> None:
         np.testing.assert_array_equal(out, np.full(4096, 1.5, np.float32))
 
 
-def test_stripe_off_knob_matches_striped_values(store) -> None:
-    # stripe=False (chunks pinned to the op's round-robin lane) is an A/B
-    # lever, not a different reduction: values must match bitwise.
-    payloads = _payloads(2)
+# ------------------------------------------------- the ring's derived cut
+
+# ddp._BucketPlan over cerebras-gpt-111m at 32 MiB (the kill cell's step),
+# in elements, and the same step at 1/64 of the size
+_CELL_PLAN = [8260608, 8263680] + [7080960] * 7 + [
+    4718592, 38633472, 1574400, 38633472,
+]
+_CELL_PLAN_64TH = [n // 64 for n in _CELL_PLAN]
+_MIB = 1 << 20
+
+
+def _shrink_hops(monkeypatch, factor: int) -> None:
+    """The rule at 1/factor of its size: small payloads then take the cuts
+    that real ones take (the rule only ever sees bytes over hop size)."""
+    monkeypatch.setattr(
+        transport, "_RING_HOP_BYTES", transport._RING_HOP_BYTES // factor
+    )
+
+
+@pytest.mark.parametrize("nbytes,world,lanes,want", [
+    (1, 4, 4, 1), (4, 4, 4, 1), (_MIB, 4, 4, 1),
+    (32 * _MIB, 4, 4, 1),            # a DDP bucket: one lane, 8 MiB hops
+    (33_054_720, 4, 4, 1), (18_874_368, 4, 4, 1),
+    (48 * _MIB - 4, 4, 4, 1), (48 * _MIB + 4, 4, 4, 2),
+    (154_533_888, 4, 4, 4),          # wte / lm_head: every lane a slice
+    (154_533_888, 4, 2, 2), (154_533_888, 4, 1, 1),
+    (154_533_888, 3, 4, 4), (33_054_720, 3, 4, 1), (28_323_840, 3, 4, 1),
+    (600 * 10**6, 8, 4, 4), (33_054_720, 8, 4, 1),
+])
+def test_ring_lanes_rule(nbytes, world, lanes, want) -> None:
+    assert _ring_lanes(nbytes, world, lanes) == want
+
+
+def _cut_case(name):
+    f32, f64, i64 = np.float32, np.float64, np.int64
+    return {
+        "one_elem": [(1, f32)],
+        "fewer_than_members": [(3, f32)],
+        "odd": [(131, f32), (40, f64), (9, i64)],
+        "with_empty": [(0, f32), (1031, f32), (0, i64), (7, f64)],
+        "bucket": [(8 << 20, f32)],
+        "big_leaf": [(38633472, f32)],
+        "cell_step_one_op": [(n, f32) for n in _CELL_PLAN],
+        "mixed_big": [(5_000_001, f32), (3_000_003, f64), (11, i64),
+                      (9_000_001, f32)],
+    }[name]
+
+
+@pytest.mark.parametrize("world,lanes", [(2, 4), (3, 4), (4, 4), (4, 2),
+                                         (4, 1)])
+@pytest.mark.parametrize("case", [
+    "one_elem", "fewer_than_members", "odd", "with_empty", "bucket",
+    "big_leaf", "cell_step_one_op", "mixed_big",
+])
+def test_ring_cut_from_shapes_alone(case, world, lanes) -> None:
+    layout = _cut_case(case)
+    owners = [i % world for i in range(len(layout))]
+
+    def cut(make):
+        flats = [make(n, dt) for n, dt in layout]
+        return flats, _chunk_grid_owned(
+            flats, owners, 1 << 20, ring=(world, lanes)
+        )
+
+    # two ranks, different contents, same shapes: the same cut
+    flats, (chunks, ch_owners, shares) = cut(
+        lambda n, dt: np.zeros(n, dt)
+    )
+    _, (chunks2, ch_owners2, shares2) = cut(
+        lambda n, dt: np.ones(n, dt)
+    )
+    assert [c.size for c in chunks] == [c.size for c in chunks2]
+    assert (ch_owners, shares) == (ch_owners2, shares2)
+    # the chunks tile every non-empty view exactly, in view order, as
+    # views of it, and inherit its owner
+    it = iter(zip(chunks, ch_owners))
+    for f, o in zip(flats, owners):
+        at = 0
+        while at < f.size:
+            ch, ch_o = next(it)
+            assert np.shares_memory(ch, f) and ch.dtype == f.dtype
+            assert ch.ctypes.data == f.ctypes.data + at * f.itemsize
+            assert ch_o == o and ch.size > 0
+            at += ch.size
+        assert at == f.size
+    assert next(it, None) is None
+    # shares: 0..k-1 in order, k what the rule says for the op's bytes,
+    # near-equal in bytes, and a view is cut only where a share ends
+    total = sum(f.nbytes for f in flats)
+    k = _ring_lanes(total, world, lanes)
+    assert shares == sorted(shares) and set(shares) <= set(range(k))
+    assert len(chunks) <= sum(1 for f in flats if f.size) + k - 1
+    if total >= 1024 * k:
+        assert sorted(set(shares)) == list(range(k))
+        per_share = [
+            sum(c.nbytes for c, s in zip(chunks, shares) if s == i)
+            for i in range(k)
+        ]
+        assert max(per_share) - min(per_share) <= 8 * k + 16
+    if len(layout) == 1 and flats[0].size >= k:
+        # a one-array op: ONE contiguous view a lane
+        assert len(chunks) == k
+
+
+def _positive_payloads(world, layout, seed=31):
+    """No cancellation: a re-associated f32 sum then stays within a few
+    ulp of any other order."""
+    rng = np.random.default_rng(seed)
+    base = [
+        (1.0 + rng.random(n, dtype=np.float32)).astype(dt)
+        if np.dtype(dt).kind == "f"
+        else np.arange(n, dtype=dt)
+        for n, dt in layout
+    ]
+    return [
+        [(a * (r + 1)).astype(a.dtype) for a in base] for r in range(world)
+    ]
+
+
+def _avg_all(payloads):
+    def _fn(ctx, rank):
+        works = [
+            ctx.allreduce([a], op=ReduceOp.AVG) for a in
+            [a.copy() for a in payloads[rank]]
+        ]
+        return [w.future().result(timeout=60)[0] for w in works]
+
+    return _fn
+
+
+@pytest.mark.parametrize("world", [2, 3, 4])
+@pytest.mark.parametrize("case", [
+    "one_elem", "fewer_than_members", "odd", "forty_million", "cell_step",
+])
+def test_ring_default_all_ranks_bitwise_equal_and_near_mean(
+    store, monkeypatch, case, world
+) -> None:
+    f32 = np.float32
+    if case == "forty_million":
+        layout = [(40_000_000, f32)]  # real size: four slices at world 4
+    elif case == "cell_step":
+        # the kill cell's 13 buckets in proportion, one op each, all in
+        # flight at once; the rule at 1/64 cuts them as it cuts the cell
+        layout = [(n, f32) for n in _CELL_PLAN_64TH]
+        _shrink_hops(monkeypatch, 64)
+        assert [
+            _ring_lanes(4 * n, 4, 4) for n in _CELL_PLAN_64TH
+        ] == [1] * 10 + [4, 1, 4]
+    else:
+        layout = [(n, dt) for n, dt in _cut_case(case)
+                  if np.dtype(dt).kind == "f"]
+    payloads = _positive_payloads(world, layout)
+    results = _run_world(
+        store, world, f"rd_{case}_{world}", _avg_all(payloads),
+        algorithm="ring",
+    )
+    for out in results[1:]:
+        for got, ref in zip(out, results[0]):
+            assert got.tobytes() == ref.tobytes(), "ranks diverged bitwise"
+    for i, got in enumerate(results[0]):
+        want = np.mean(
+            np.stack([payloads[r][i] for r in range(world)]), axis=0,
+            dtype=got.dtype,
+        )
+        np.testing.assert_array_max_ulp(got, want, maxulp=4)
+
+
+@pytest.mark.parametrize("case", ["whole_on_one_lane", "sliced"])
+def test_derived_cut_equals_explicit_grid_of_same_slices(
+    store, monkeypatch, case
+) -> None:
+    # The rule decides WHERE an op is cut and which lane carries what;
+    # given the cut, the values are those of the explicit grid with the
+    # same slices on any lanes (was: stripe=False against stripe=True).
+    world = 3
+    n = 12_000
+    payloads = _positive_payloads(world, [(n, np.float32)], seed=7)
+    if case == "whole_on_one_lane":
+        explicit = dict(chunk_bytes=0, channels=1)
+    else:
+        # hop target 1000 B at world 3: round(48000 / 3000) = 16 -> 4
+        # lanes, 3000 elements a slice
+        monkeypatch.setattr(transport, "_RING_HOP_BYTES", 1000)
+        assert _ring_lanes(4 * n, world, 4) == 4
+        explicit = dict(chunk_bytes=4 * n // 4, channels=2)
 
     def _fn(ctx, rank):
-        return [
-            a.copy() for a in ctx.allreduce(
-                [a.copy() for a in payloads[rank]]
-            ).future().result(timeout=30)
+        return ctx.allreduce(
+            [payloads[rank][0].copy()]
+        ).future().result(timeout=30)[0]
+
+    derived = _run_world(store, world, f"dc_d_{case}", _fn,
+                         algorithm="ring", channels=4)
+    pinned = _run_world(store, world, f"dc_e_{case}", _fn,
+                        algorithm="ring", **explicit)
+    for r in range(world):
+        assert derived[r].tobytes() == pinned[0].tobytes()
+
+
+@pytest.mark.parametrize("world", [3, 4])
+def test_ring_default_reduce_scatter_owners_decode_allreduce_bytes(
+    store, monkeypatch, world
+) -> None:
+    # several arrays in ONE op (the sharded reducer's shape), cut across
+    # lanes by the rule: an owner's arrays hold what allreduce gives
+    _shrink_hops(monkeypatch, 1024)
+    layout = [(9_001, np.float32), (3, np.float32), (20_000, np.float64),
+              (4_097, np.float32), (12_345, np.float32)]
+    owners = [i % world for i in range(len(layout))]
+    payloads = _positive_payloads(world, layout, seed=11)
+    assert _ring_lanes(sum(a.nbytes for a in payloads[0]), world, 4) > 1
+
+    def _ar(ctx, rank):
+        return ctx.allreduce(
+            [a.copy() for a in payloads[rank]], op=ReduceOp.AVG
+        ).future().result(timeout=30)
+
+    def _rs(ctx, rank):
+        return ctx.reduce_scatter(
+            [a.copy() for a in payloads[rank]], op=ReduceOp.AVG,
+            owners=owners,
+        ).future().result(timeout=30)
+
+    full = _run_world(store, world, f"rso_a{world}", _ar, algorithm="ring")
+    scat = _run_world(store, world, f"rso_s{world}", _rs, algorithm="ring")
+    for rank in range(world):
+        for i, o in enumerate(owners):
+            if o == rank:
+                assert scat[rank][i].tobytes() == full[0][i].tobytes()
+
+
+# sha256[:16] over the reduced arrays of _payloads(world) under SUM, as the
+# tree BEFORE the derived cut computed them (PR 27's parent, 20ee77e):
+# an explicit chunk_bytes is the codecs' granularity and the star's
+# pipeline depth, and keeps giving the bytes it gave.
+_PARENT_DIGESTS = {
+    ("star", 3, "bf16", 256): "13a8e57c55b5918e",
+    ("star", 3, "bf16", 524): "13a8e57c55b5918e",
+    ("star", 3, "fp16", 256): "2bdb39c48d906995",
+    ("star", 3, "fp16", 524): "2bdb39c48d906995",
+    ("star", 3, "int8", 256): "9ddb4531c1082ab2",
+    ("star", 3, "int8", 524): "364fc84bbb29ebeb",
+    ("star", 3, "none", 256): "43e36f4683cf1391",
+    ("star", 3, "none", 524): "43e36f4683cf1391",
+    ("ring", 3, "bf16", 256): "64edcb006f466f92",
+    ("ring", 3, "bf16", 524): "64edcb006f466f92",
+    ("ring", 3, "fp16", 256): "8e1d9a66a28a0c9d",
+    ("ring", 3, "fp16", 524): "8e1d9a66a28a0c9d",
+    ("ring", 3, "int8", 256): "7879a63e90b2e97e",
+    ("ring", 3, "int8", 524): "e2577dc47251bf94",
+    ("ring", 3, "none", 256): "a8bc02f8b169987c",
+    ("ring", 3, "none", 524): "190eb2cfafb6bcf5",
+    ("ring", 4, "bf16", 256): "4e86dffd619cc726",
+    ("ring", 4, "bf16", 524): "4e86dffd619cc726",
+    ("ring", 4, "fp16", 256): "aaf422157598af5b",
+    ("ring", 4, "fp16", 524): "aaf422157598af5b",
+    ("ring", 4, "int8", 256): "9192962a1997eb22",
+    ("ring", 4, "int8", 524): "e1a8448f7f34fb06",
+    ("ring", 4, "none", 256): "9a938b31fb5f44de",
+    ("ring", 4, "none", 524): "b88b056d3c7f1511",
+}
+
+
+@pytest.mark.parametrize(
+    "algorithm,world,codec_name,chunk_bytes", sorted(_PARENT_DIGESTS)
+)
+def test_explicit_grid_reproduces_parent_bytes(
+    store, algorithm, world, codec_name, chunk_bytes
+) -> None:
+    payloads = _payloads(world)
+
+    def _fn(ctx, rank):
+        return ctx.allreduce(
+            [a.copy() for a in payloads[rank]], op=ReduceOp.SUM
+        ).future().result(timeout=30)
+
+    results = _run_world(
+        store, world, f"pd_{algorithm}{world}{codec_name}{chunk_bytes}",
+        _fn, algorithm=algorithm, compression=codec_name,
+        chunk_bytes=chunk_bytes, channels=4,
+    )
+    for out in results:
+        h = hashlib.sha256()
+        for a in out:
+            h.update(a.tobytes())
+        assert h.hexdigest()[:16] == _PARENT_DIGESTS[
+            (algorithm, world, codec_name, chunk_bytes)
         ]
 
-    on = _run_world(store, 2, "kn_on", _fn,
-                    algorithm="star", channels=4, chunk_bytes=256,
-                    stripe=True)
-    off = _run_world(store, 2, "kn_off", _fn,
-                     algorithm="star", channels=4, chunk_bytes=256,
-                     stripe=False)
-    for got, ref in zip(on[0], off[0]):
-        assert got.tobytes() == ref.tobytes()
+
+def test_default_grid_kept_for_lossy_codecs_and_star() -> None:
+    # chunk_bytes=None is the ring's licence to cut by size, nothing
+    # else's: the lossy codecs and the star keep the 1 MiB grid (int8
+    # scales per chunk; the star's pipeline depth)
+    a = np.zeros(3 * (1 << 18) + 5, np.float32)  # 3 MiB + 20 B
+    for codec in sorted(_CODECS):
+        ctx = TcpCommContext(compression=codec)
+        assert ctx._grid_bytes == 1 << 20
+        per_chunk = {"none": 0, "bf16": 0, "fp16": 0, "int8": 4}[codec]
+        scale = {"none": 4, "bf16": 2, "fp16": 2, "int8": 1}[codec]
+        assert ctx.wire_nbytes(a) == a.size * scale + 4 * per_chunk
+    assert TcpCommContext(chunk_bytes=0)._grid_bytes == 0
+    with pytest.raises(TypeError):
+        TcpCommContext(stripe=False)  # settled by PR 27's table: gone
+
+
+@pytest.mark.parametrize("n_elems,chunks,hop_bytes", [
+    (8 << 20, 1, 8 << 20),          # a 32 MiB bucket: one lane, whole
+    (38633472, 4, 9658368),         # 154.5 MB: every lane carries a slice
+])
+def test_ring_default_counts(store, n_elems, chunks, hop_bytes) -> None:
+    # Counts only (no time is asserted anywhere): at world 4 with the
+    # defaults a hop is ONE view — one iovec, one receive target, one
+    # np.add / np.copyto — of the size the rule says.
+    world = 4
+    payloads = [np.full(n_elems, float(r + 1), np.float32)
+                for r in range(world)]
+    snaps = [None] * world
+
+    def _fn(ctx, rank):
+        before = ctx.metrics.snapshot()
+        out = ctx.allreduce(
+            [payloads[rank]], op=ReduceOp.SUM
+        ).future().result(timeout=120)[0]
+        after = ctx.metrics.snapshot()
+        snaps[rank] = (before, after)
+        return float(out[0]), float(out[-1])
+
+    results = _run_world(store, world, f"cnt_{n_elems}", _fn)  # defaults
+    assert results == [(10.0, 10.0)] * world
+    hops = 2 * (world - 1)
+    for before, after in snaps:
+        def delta(key):
+            return after.get(key, 0.0) - before.get(key, 0.0)
+
+        assert delta("comm_chunks") == chunks
+        assert delta("comm_ring_hops") == hops * chunks  # hops a lane: 6
+        assert delta("comm_ring_views") == hops * chunks  # views a hop: 1
+        assert after["comm_hop_bytes"] == hop_bytes
+        lanes_used = [
+            i for i in range(4) if f"comm_l{i}_wire_reduce_p50_ms" in after
+        ]
+        assert len(lanes_used) == chunks
 
 
 def test_striped_multi_op_pipelining(store) -> None:

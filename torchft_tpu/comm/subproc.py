@@ -171,12 +171,12 @@ class SubprocessCommContext(CommContext):
     def __init__(self, timeout: "float | timedelta" = 60.0,
                  algorithm: str = "auto", channels: int = 4,
                  compression: str = "none",
-                 chunk_bytes: int = 1 << 20,
-                 stripe: bool = True,
+                 chunk_bytes: Optional[int] = None,
                  topology: str = "flat") -> None:
         """``algorithm``/``channels``/``compression``/``chunk_bytes``/
-        ``stripe``/``topology`` are forwarded to the child's
-        TcpCommContext (see transport.py for their semantics; the
+        ``topology`` are forwarded to the child's TcpCommContext (see
+        transport.py for their semantics, ``chunk_bytes=None`` included:
+        the child then cuts ring ops as its own default does; the
         child resolves hier domains from its own TORCHFT_TPU_DOMAINS
         env or the wire members shipped with each configure)."""
         super().__init__()
@@ -189,7 +189,6 @@ class SubprocessCommContext(CommContext):
             "channels": channels,
             "compression": compression,
             "chunk_bytes": chunk_bytes,
-            "stripe": stripe,
             "topology": topology,
         }
         self._mp = mp.get_context("spawn")

@@ -21,7 +21,11 @@ identical lanes on every rank and each lane's stream stays ordered.
 
 Reconfigure/shutdown closes sockets, which fails in-flight ops with
 ConnectionError — the abort analog for wedged transports (XLA collectives
-cannot be aborted; host sockets can, SURVEY.md §7 hard-part #2).
+cannot be aborted; host sockets can, SURVEY.md §7 hard-part #2). The first
+error a context latches does the same to its own sockets (shutdown, then
+close): a ring member only ever hears from its two neighbours, so a death
+has to be passed on by them at once — a survivor two hops from the victim
+otherwise sits in its hop until the timeout (``_latch_error``).
 
 Zero-copy data path: sends are scatter-gather (``sendmsg`` iovecs: one
 small metadata buffer plus the array bodies themselves — the full payload
@@ -35,25 +39,53 @@ returned future resolves to arrays that may alias the inputs (reduced in
 place); after a transport error their contents are unspecified, which is
 fine because an errored step never commits (manager error latching).
 
-Chunk-striped allreduce: an ALLREDUCE payload is split into a
-deterministic chunk grid (contiguous <= ``chunk_bytes`` slices of each
-flat view, in view order) and chunk c is executed on lane
-``(base + c) % channels`` where ``base`` is the op's round-robin index —
-the same grid and the same chunk->lane map on every rank, so each lane's
-frame stream stays ordered exactly as in the one-op-one-lane model. A
-multi-megabyte DDP bucket therefore rides ALL lanes concurrently instead
-of serializing on one socket while the others idle. Each involved lane
-runs an independent sub-op over its chunk subset (star: per-chunk
-length-prefixed frames, upload and replies interleaved by the
-select-driven ``_duplex_exchange`` so chunk k+1 encodes/ships while the
-root still reduces chunk k; ring: the reduce-scatter/all-gather pair
-over the lane's chunk views, hops through the same duplex loop — no
-thread spawn per hop), and a shared op state resolves the caller's
-future when the last lane finishes. Because the star root drains peers in rank order PER CHUNK and
-the ring treats each chunk view as an independent payload, the reduced
-values are bitwise identical to running the same chunk grid on a single
-lane — striping changes only where bytes travel, never what is computed
-(tests/test_transport_striping.py pins this for every codec).
+How a gradient op is cut and mapped to lanes (``_submit`` →
+``_chunk_grid_owned``; ALLREDUCE and REDUCE_SCATTER alike). Every rank
+computes the same cut and the same chunk->lane map from shapes, world
+size and lane count alone, so each lane's frame stream stays ordered
+exactly as in the one-op-one-lane model; each involved lane runs an
+independent sub-op over its chunk views and a shared op state resolves
+the caller's future when the last lane finishes. Two cuts:
+
+* **A ``chunk_bytes`` grid** — contiguous <= ``chunk_bytes`` slices of
+  each flat view, in view order, chunk c on lane ``(base + c) %
+  channels`` (``base`` = the op's round-robin index). The grid is part of
+  what is computed: it is the lossy codecs' encode granularity (int8
+  carries one scale a chunk) and the star's pipeline depth (per-chunk
+  length-prefixed frames, upload and replies interleaved by the
+  select-driven ``_duplex_exchange`` so chunk k+1 ships while the root
+  still reduces chunk k). An explicit ``chunk_bytes`` is always
+  honoured; with none given, the lossy codecs and the star keep 1 MiB.
+  Because the star root drains peers in rank order PER CHUNK and the ring
+  treats each chunk view as an independent payload, the reduced values
+  are bitwise identical whichever lanes the chunks ride
+  (tests/test_transport_striping.py pins this for every codec, and pins
+  the bytes themselves against golden digests).
+
+* **The ring's own cut** (identity codec, no ``chunk_bytes`` given — the
+  default, and what a DDP bucket rides). A ring sub-op makes 2(n-1)
+  hops, each moving rank-part p of EVERY chunk view the lane holds; a
+  hop's fixed costs (a select round, the header, a lock-step handshake
+  with both neighbours, GIL hand-offs around every syscall and every
+  per-view ``np.add``) are paid per hop and per view, not per byte. On
+  a fixed 1 MiB grid dealt over four lanes a 32 MiB bucket was 4 lanes
+  x 6 hops of 8 separate 256 KB views, and the step's rate was set by
+  views, not by bytes or cores (PERF.md, PR 27: 342 -> 760 MB/s on the
+  same bytes). So the op is cut from its own size: its bytes are dealt
+  in view order into ``_ring_lanes`` near-equal contiguous shares — as
+  many lanes as bring a hop nearest ``_RING_HOP_BYTES`` (8 MiB), at
+  least one, at most all — and a view is cut only where a share ends
+  inside it. A bucket (<= 32 MiB at world 4) rides ONE lane whole: a hop
+  is one iovec, one ``recv_into`` target, one ``np.add`` or
+  ``np.copyto``, and the other buckets in flight fill the other lanes.
+  An array much larger than a bucket (an embedding table; LocalSGD /
+  DiLoCo's whole-model arrays) is cut into at most ``channels``
+  contiguous slices, so every socket carries a stream and no single lane
+  becomes the step's tail. The lanes' receive pools grow to the largest
+  hop (two slots a lane). Moving the part boundaries re-associates the
+  same f32 sum (which rank's partial an element starts from), so results
+  may differ in the last bit from a ``chunk_bytes`` grid's; all ranks
+  still decode the same bytes and stay bitwise equal to each other.
 """
 
 from __future__ import annotations
@@ -102,7 +134,7 @@ _OP_ALLGATHER = 2
 _OP_BROADCAST = 3
 _OP_REDUCE_SCATTER = 4
 
-# Opcodes that ride the chunk-striped gradient data path (and therefore
+# Opcodes that ride the chunked gradient data path (and therefore
 # land in the comm_* phase timers): allreduce plus its scatter variant.
 _GRAD_OPCODES = (_OP_ALLREDUCE, _OP_REDUCE_SCATTER)
 
@@ -222,10 +254,7 @@ def _duplex_exchange(tx_sock: socket.socket, tx_bufs: Sequence,
             if r:
                 while rx_mv is not None:
                     try:
-                        n = rx_sock.recv_into(
-                            rx_mv[rx_off:],
-                            min(len(rx_mv) - rx_off, 1 << 20),
-                        )
+                        n = rx_sock.recv_into(rx_mv[rx_off:])
                     except (BlockingIOError, InterruptedError):
                         break
                     if n == 0:
@@ -240,8 +269,12 @@ def _duplex_exchange(tx_sock: socket.socket, tx_bufs: Sequence,
         # socket timeout bounds each recv, i.e. idle time, not total).
         rx_sock.settimeout(timeout)
         while rx_mv is not None:
-            _recv_into_exact(rx_sock, rx_mv[rx_off:])
-            _advance_rx()
+            n = rx_sock.recv_into(rx_mv[rx_off:])
+            if n == 0:
+                raise ConnectionError("comm transport connection closed")
+            rx_off += n
+            if rx_off == len(rx_mv):
+                _advance_rx()
         if sender is not None:  # pragma: no cover — non-Linux fallback
             sender.join(timeout=timeout)
             if send_err[0] is not None:
@@ -487,6 +520,31 @@ class _PendingOp:
         self.t_submit = time.perf_counter()
 
 
+# Codec granularity where none is given: the lossy codecs quantize per grid
+# chunk (int8 carries one scale a chunk), and the star pipelines chunk k+1's
+# upload under chunk k's reduction. The identity-codec ring needs neither
+# and derives its cut from the op (:func:`_ring_lanes`).
+_DEFAULT_GRID_BYTES = 1 << 20
+
+# What a ring hop should carry: few enough bytes that 2(n-1) hops pipeline
+# against the other ops in flight, enough that a hop's fixed costs (a
+# select round, a header, a lock-step handshake with both neighbours, a
+# GIL hand-off either side of every syscall) are paid once per megabytes
+# and not once per 256 KB view (PERF.md, PR 27: the chip host's table).
+_RING_HOP_BYTES = 8 << 20
+
+
+def _ring_lanes(nbytes: int, world: int, lanes: int) -> int:
+    """How many lanes a ring op of ``nbytes`` rides: as many as bring a
+    hop (1/world of a lane's share) nearest ``_RING_HOP_BYTES``, at least
+    one, at most all. A DDP bucket (<= 32 MiB at world 4) rides one lane
+    whole — the other buckets in flight fill the other lanes; an array
+    much larger than a bucket is cut so every socket carries a stream and
+    no lane becomes the step's tail. Shapes, world size and lane count
+    only: every rank computes the same number."""
+    return max(1, min(lanes, round(nbytes / (_RING_HOP_BYTES * world))))
+
+
 def _chunk_grid(flats: Sequence[np.ndarray],
                 chunk_bytes: int) -> List[np.ndarray]:
     """Deterministic chunk grid over the op's flat views: each view is
@@ -500,20 +558,46 @@ def _chunk_grid(flats: Sequence[np.ndarray],
 
 def _chunk_grid_owned(
     flats: Sequence[np.ndarray], owners: "Optional[Sequence[int]]",
-    chunk_bytes: int,
-) -> "tuple[List[np.ndarray], Optional[List[int]]]":
+    chunk_bytes: int, ring: "Optional[tuple[int, int]]" = None,
+) -> "tuple[List[np.ndarray], Optional[List[int]], Optional[List[int]]]":
     """:func:`_chunk_grid` plus a parallel per-chunk owner list: chunk
     views of ``flats[i]`` inherit ``owners[i]`` (the REDUCE_SCATTER
-    destination). ``owners=None`` returns ``(chunks, None)`` — the
+    destination). ``owners=None`` returns ``None`` in its place — the
     allreduce grid. One step rule for both opcodes, so a reduce_scatter
     over the same views computes the identical grid (and identical int8
-    per-chunk scales) as an allreduce would."""
+    per-chunk scales) as an allreduce would.
+
+    ``ring=(world, lanes)`` derives the cut from the op instead of from
+    ``chunk_bytes`` (the identity-codec ring's default): the op's bytes
+    are dealt, in view order, into :func:`_ring_lanes` near-equal
+    contiguous shares, a view being cut only where a share ends inside
+    it. The third list names each chunk's share (0-based; its lane is
+    ``(base + share) % lanes``); it is None for a ``chunk_bytes`` grid,
+    whose chunks are dealt round-robin. A one-array op thus gives each
+    of its lanes ONE contiguous view, and a ring hop one rank-part of it."""
     chunks: List[np.ndarray] = []
     chunk_owners: "Optional[List[int]]" = None if owners is None else []
+    shares: "Optional[List[int]]" = None if ring is None else []
+    if ring is not None:
+        total = sum(f.nbytes for f in flats)
+        quota = -(-total // _ring_lanes(total, *ring))  # bytes a share
+    offset = 0  # of this view in the op's bytes
     for vi, f in enumerate(flats):
         if f.size == 0:
             continue
-        if chunk_bytes <= 0:
+        if ring is not None:
+            # cut where a share boundary falls inside the view, at the
+            # first element that starts at or after it
+            isz = f.dtype.itemsize
+            starts = sorted({0} | {
+                -(-(b - offset) // isz)
+                for b in range(quota, total, quota)
+                if offset < b < offset + f.nbytes
+            } - {f.size})
+            view_chunks = np.split(f, starts[1:])
+            shares.extend((offset + s * isz) // quota for s in starts)
+            offset += f.nbytes
+        elif chunk_bytes <= 0:
             view_chunks = [f]
         else:
             step = max(1, chunk_bytes // f.dtype.itemsize)
@@ -521,7 +605,7 @@ def _chunk_grid_owned(
         chunks.extend(view_chunks)
         if chunk_owners is not None:
             chunk_owners.extend([int(owners[vi])] * len(view_chunks))
-    return chunks, chunk_owners
+    return chunks, chunk_owners, shares
 
 
 # --------------------------------------------------------------- compression
@@ -819,20 +903,26 @@ class _Lane:
         self._thread.start()
 
     def close_sockets(self) -> None:
-        for s in list(self._peer_socks.values()):
-            try:
-                s.close()
-            except OSError:
-                pass
+        """Shut down, then close, every socket of this lane. The shutdown
+        is what the other side and this lane's own thread feel at once: a
+        bare close() from another thread neither wakes a receive blocked
+        on the socket nor sends the FIN while that receive holds it."""
+        socks = list(self._peer_socks.values())
         self._peer_socks = {}
         for attr in ("_next_sock", "_prev_sock", "_root_sock"):
             s = getattr(self, attr)
             if s is not None:
-                try:
-                    s.close()
-                except OSError:
-                    pass
+                socks.append(s)
                 setattr(self, attr, None)
+        for s in socks:
+            try:
+                s.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+            try:
+                s.close()
+            except OSError:
+                pass
 
     # ------------------------------------------------------ transport thread
 
@@ -883,7 +973,7 @@ class _Lane:
                     metrics.observe("comm_reduce_future", t_done - t_exec)
                     metrics.observe(f"{tag}_wire_reduce", t_exec - t_deq)
             except Exception as e:  # noqa: BLE001 — latch every transport error
-                self._ctx._latch_error(e)
+                self._ctx._latch_error(e, self)
                 logger.warning(
                     "comm op failed (rank %d world %d lane %d): %s",
                     self._rank, self._world_size, self._lane_id, e,
@@ -1416,6 +1506,18 @@ class _Lane:
         vote = self._ring_reduce_scatter_phase(p, flats, reduce_fn, vote)
         vote = self._ring_allgather_phase(p, flats, owned, vote)
         self._ctx._record_vote(vote)
+        # What this sub-op's hops carried: 2(n-1) hops of one rank-part
+        # of each of the lane's views. The gauge is the median hop (raw
+        # bytes; the n parts differ by an element a view) of the sub-op
+        # that finished last.
+        metrics = self._ctx.metrics
+        metrics.incr("comm_ring_hops", float(2 * (n - 1)))
+        metrics.incr("comm_ring_views", float(2 * (n - 1) * len(flats)))
+        if flats:
+            metrics.gauge("comm_hop_bytes", float(sorted(
+                sum(v.nbytes for v in self._part_views(flats, n, c))
+                for c in range(n)
+            )[n // 2]))
         if p.op == ReduceOp.AVG:
             for i, f in enumerate(flats):
                 if owned is None or owned[i]:
@@ -1494,8 +1596,7 @@ class TcpCommContext(CommContext):
     def __init__(self, timeout: "float | timedelta" = 60.0,
                  algorithm: str = "auto", channels: int = 4,
                  compression: str = "none",
-                 chunk_bytes: int = 1 << 20,
-                 stripe: bool = True,
+                 chunk_bytes: Optional[int] = None,
                  topology: str = "flat",
                  domain_resolver=None) -> None:
         """``algorithm``: "star" (rank 0 reduces and fans out — lowest
@@ -1516,17 +1617,17 @@ class TcpCommContext(CommContext):
         verbatim), so replica trajectories stay consistent; allgather and
         broadcast are never compressed. Must match across ranks.
 
-        ``chunk_bytes``: ALLREDUCE payloads are split into contiguous
-        chunks of at most this many bytes (per flat view; 0 keeps each
-        view whole). The chunk grid is also the lossy codecs' encode
-        granularity (int8 scales are per chunk) and, with ``stripe``, the
-        unit distributed across lanes. Must match across ranks.
-
-        ``stripe``: distribute one op's chunks across ALL lanes
-        (chunk c -> lane (base + c) % channels) so a single large payload
-        uses every socket concurrently; False pins every chunk to the
-        op's round-robin lane (the one-op-one-lane PR 1 model, kept as an
-        A/B lever for the bench). Must match across ranks.
+        ``chunk_bytes``: the allreduce chunk grid — payloads are split
+        into contiguous chunks of at most this many bytes (per flat view;
+        0 keeps each view whole) and chunk c rides lane
+        ``(base + c) % channels``. The grid is the lossy codecs' encode
+        granularity (int8 scales are per chunk) and the star's pipeline
+        depth, so it is part of what is computed. ``None`` (the default)
+        keeps a 1 MiB grid for those two, and lets the identity-codec
+        ring cut each op from its own size instead
+        (:func:`_chunk_grid_owned`, ``ring=``): a hop then carries one
+        contiguous rank-part of megabytes, on as few lanes as that takes.
+        Must match across ranks.
 
         ``topology``: the DEFAULT data path for allreduce ops — "flat"
         (one tier spanning the whole wire; the historical behavior) or
@@ -1551,12 +1652,13 @@ class TcpCommContext(CommContext):
             raise ValueError(reason)
         if channels < 1:
             raise ValueError("channels must be >= 1")
-        if chunk_bytes < 0:
+        if chunk_bytes is not None and chunk_bytes < 0:
             raise ValueError("chunk_bytes must be >= 0")
         self._codec = _CODECS[compression]()
         self._compression = compression
-        self._chunk_bytes = int(chunk_bytes)
-        self._stripe = bool(stripe)
+        # None: no grid was asked for (see the ctor doc); _grid_bytes is
+        # the grid every codec-facing surface then uses
+        self._chunk_bytes = None if chunk_bytes is None else int(chunk_bytes)
         self._algorithm = algorithm
         self._channels = int(channels)
         self._topology_default = topology
@@ -1876,7 +1978,7 @@ class TcpCommContext(CommContext):
                 h.intra = TcpCommContext(
                     timeout=self._timeout, algorithm="star",
                     channels=self._channels, compression="none",
-                    chunk_bytes=self._chunk_bytes, stripe=self._stripe,
+                    chunk_bytes=self._chunk_bytes,
                 )
                 h.intra.configure(
                     f"{store_addr}/hier_intra_{d_idx}",
@@ -1896,7 +1998,6 @@ class TcpCommContext(CommContext):
                         channels=self._channels,
                         compression=self._compression,
                         chunk_bytes=self._chunk_bytes,
-                        stripe=self._stripe,
                     )
                     h.inter.configure(
                         f"{store_addr}/hier_inter", d_idx, h.n_domains
@@ -2056,11 +2157,28 @@ class TcpCommContext(CommContext):
         with self._lock:
             return self._error
 
-    def _latch_error(self, e: Exception) -> None:
+    def _latch_error(self, e: Exception,
+                     lane: "Optional[_Lane]" = None) -> None:
+        """Latch ``e`` and, on the latch edge, fail the WIRE and not only
+        the op: every lane's sockets are shut down, so both neighbours see
+        the end of the stream now, latch in turn and pass it on. Without
+        this a rank that is not the dead member's neighbour learns
+        nothing from its own (healthy) neighbours: it sat in a hop until
+        the timeout unless a later op's header happened to arrive where
+        it expected this one's — and with a bucket a hop-sized frame there
+        is often no later op (PERF.md, PR 27: a 77 s stall, ``correct``
+        false). Nothing is lost: a latched context fails every op until
+        the next ``configure`` anyway. ``lane``: the failing lane; one left
+        over from an earlier configure closes nothing of this one's."""
         with self._lock:
             first = self._error is None
             if first:
                 self._error = e
+            lanes = list(self._lanes) if first and (
+                lane is None or lane in self._lanes
+            ) else []
+        for ln in lanes:
+            ln.close_sockets()
         if first:
             # Emit OUTSIDE self._lock (the recorder has its own lock; no
             # nesting) and only on the latch edge — follow-on op
@@ -2198,12 +2316,19 @@ class TcpCommContext(CommContext):
         if not self.wire_compensable():
             np.copyto(out, src)
             return
-        codec_roundtrip(self._codec, self._chunk_bytes, src, out)
+        codec_roundtrip(self._codec, self._grid_bytes, src, out)
+
+    @property
+    def _grid_bytes(self) -> int:
+        """The chunk grid where one is needed: the ctor's, or 1 MiB."""
+        if self._chunk_bytes is None:
+            return _DEFAULT_GRID_BYTES
+        return self._chunk_bytes
 
     def wire_nbytes(self, a: np.ndarray) -> int:
         """Encoded one-direction payload size of ``a`` over the chunk
         grid (see module-level :func:`codec_wire_nbytes`)."""
-        return codec_wire_nbytes(self._codec, self._chunk_bytes, a)
+        return codec_wire_nbytes(self._codec, self._grid_bytes, a)
 
     # ----------------------------------------------------------- collectives
     # _prepare (the donation-contract input normalization) is inherited
@@ -2251,19 +2376,27 @@ class TcpCommContext(CommContext):
                         return Work(fut)
                 else:
                     owners = None
-                # Chunk-striped data path: deterministic grid + chunk->
-                # lane map (identical on every rank — see module
-                # docstring), one sub-op per involved lane sharing the
-                # op's future/state. stripe=False degenerates to the
-                # whole grid on the base lane.
-                chunks, chunk_owners = _chunk_grid_owned(
+                # Deterministic cut + chunk->lane map (identical on
+                # every rank — see module docstring), one sub-op per
+                # involved lane sharing the op's future/state. A
+                # chunk_bytes grid deals its chunks round-robin; the
+                # identity-codec ring with no grid asked for sizes the
+                # cut from the op and names each chunk's lane share.
+                derive = (
+                    self._chunk_bytes is None and self._use_ring
+                    and type(self._codec) is _NoCodec
+                )
+                chunks, chunk_owners, shares = _chunk_grid_owned(
                     [a.reshape(-1) for a in prepared], owners,
-                    self._chunk_bytes,
+                    self._grid_bytes,
+                    ring=(self._world_size, n_lanes) if derive else None,
                 )
                 per_lane: Dict[int, List[np.ndarray]] = {}
                 per_lane_owner: Dict[int, List[int]] = {}
                 for c, ch in enumerate(chunks):
-                    lane_id = (base + c) % n_lanes if self._stripe else base
+                    lane_id = (
+                        base + (c if shares is None else shares[c])
+                    ) % n_lanes
                     per_lane.setdefault(lane_id, []).append(ch)
                     if chunk_owners is not None:
                         per_lane_owner.setdefault(lane_id, []).append(
