@@ -63,7 +63,7 @@ def _sharded_update_phase() -> dict:
     allreduce + full update (replicated arm) — reporting
     ``t1_opt_update_ms`` / ``t1_opt_state_bytes`` for both arms plus
     the per-rep bitwise oracle. In-process threads over a real TCP
-    loopback transport (the bench_smoke/diloco harness shape); guarded:
+    loopback transport (``wire_stub.run_stub_ranks``); guarded:
     a failure yields an ``error`` field, never a lost artifact.
     BENCH_SHARDED=0 skips it."""
     import numpy as np

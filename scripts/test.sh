@@ -12,38 +12,9 @@
 #                                # lint finding or data race; see
 #                                # docs/operations.md "Static analysis
 #                                # & sanitizers"
-#   BENCH_SMOKE=1 scripts/test.sh  # in-process metric smokes: one tiny
-#                                  # heal round + one streaming-DiLoCo round
-#                                  # + one xla allreduce round + one
-#                                  # flight-recorder round + one w2→w3
-#                                  # redistribution grow; asserts the
-#                                  # streamed-pipeline, heal_*, outer_* and
-#                                  # backend-tagged comm_* gauges are present
-#                                  # and finite, that lifecycle events
-#                                  # were recorded and convert to valid
-#                                  # Chrome-trace JSON with quorum/step_commit
-#                                  # present, AND that the redist gauges are
-#                                  # finite with moved == lower-bound bytes
-#                                  # and a plan-cache hit on the second
-#                                  # identical transition, AND one
-#                                  # in-process 2-stage x 4-microbatch
-#                                  # pipeline round per schedule arm with
-#                                  # finite pipe_* gauges and a bitwise
-#                                  # pipelined-vs-stage-serial step,
-#                                  # AND one train->serve adoption round
-#                                  # (serve_smoke: deploy_* bytes pinned
-#                                  # at the planner lower bound, zero
-#                                  # dropped / stale-read requests)
-#                                  # (metric/event regressions fail
-#                                  # loudly instead of vanishing)
 
 set -u
 cd "$(dirname "$0")/.."
-
-if [ "${BENCH_SMOKE:-0}" = "1" ]; then
-    set -ex
-    exec python scripts/bench_smoke.py
-fi
 
 if [ "${CHECK:-0}" = "1" ]; then
     set -ex

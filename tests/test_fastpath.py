@@ -132,7 +132,7 @@ def test_steady_state_steps_are_zero_rpc(store, lease_lighthouse) -> None:
 
 
 def test_fastpath_disabled_by_env(store, lease_lighthouse, monkeypatch) -> None:
-    # BENCH_FASTPATH=0 / TORCHFT_TPU_FASTPATH=0 is the live A/B lever:
+    # TORCHFT_TPU_FASTPATH=0 is the live A/B lever:
     # same lighthouse, same lease grants upstream, but the manager pays
     # the full path every step.
     monkeypatch.setenv("TORCHFT_TPU_FASTPATH", "0")

@@ -249,6 +249,141 @@ def test_episode_phases_are_timings_of_the_managers_sink(
     assert snap["replica_id"].startswith("tl_a_")
 
 
+@pytest.fixture(scope="module")
+def merged_quorum():
+    """Two groups; one is torn down and its replacement is started at
+    once, so it asks for a quorum while the dead group's heartbeat still
+    counts: ONE quorum drops ``b`` and admits ``c``."""
+    # the dead group's heartbeat outlives the replacement's start-up on
+    # any machine; nothing below waits for it to expire
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=200,
+                    heartbeat_timeout_ms=3 * _HEARTBEAT_TIMEOUT_MS)
+    live: List[_Replica] = []
+    try:
+        survivor = _Replica("a", 1.0, lh.address())
+        victim = _Replica("b", 1.0, lh.address())
+        live += [survivor, victim]
+        survivor.run_to(8)
+        victim.run_to(8)
+        n_before_kill = len(survivor.episodes())
+        # the sink then holds this recovery alone, as a window's does
+        survivor.manager.metrics.reset_timings()
+        victim.kill()
+        replacement = _Replica("c", 99.0, lh.address())
+        live.append(replacement)
+        replacement.run_to(survivor.manager.current_step() + 5)
+        survivor.run_to(replacement.manager.current_step())
+        after_kill = survivor.episodes()[n_before_kill:]
+        if not after_kill or after_kill[0]["joined"] == 0:
+            pytest.fail(f"the quorum did not merge: {after_kill}")
+        yield {
+            "after_kill": after_kill,
+            "replacement": replacement.episodes(),
+            "snapshot": survivor.manager.metrics.snapshot(),
+            "equal": _equal_at_last_common_step(survivor, replacement),
+        }
+    finally:
+        for r in live:
+            r.kill()
+        lh.shutdown()
+
+
+_TIMED = ("gap", "quorum_wait", "wire_wait", "heal", "barrier", "other",
+          "configure")
+
+
+def test_one_quorum_that_drops_and_admits_is_one_episode_of_both_kinds(
+        merged_quorum) -> None:
+    (episode,) = merged_quorum["after_kill"]
+    assert episode["episode"] == "shrink+grow"
+    assert (episode["members_before"], episode["members_after"]) == (2, 2)
+    assert (episode["left"], episode["joined"]) == (1, 1)
+    # it waited on the wire while the joiner healed
+    assert episode["wire_wait_ms"] > 0
+
+
+def test_merged_episode_is_observed_under_shrink_and_under_grow(
+        merged_quorum) -> None:
+    snap = merged_quorum["snapshot"]
+    (episode,) = merged_quorum["after_kill"]
+    assert snap["episode_shrink_gap_max_ms"] == snap["episode_grow_gap_max_ms"]
+    assert snap["episode_grow_gap_max_ms"] == pytest.approx(
+        episode["gap_ms"], abs=1e-3)
+    assert not any(k.startswith("episode_error_") for k in snap)
+
+
+@pytest.mark.parametrize("phase", _TIMED)
+def test_merged_episodes_grow_phase_equals_its_shrink_phase(
+        merged_quorum, phase) -> None:
+    snap = merged_quorum["snapshot"]
+    (episode,) = merged_quorum["after_kill"]
+    assert snap[f"episode_grow_{phase}_max_ms"] == (
+        snap[f"episode_shrink_{phase}_max_ms"])
+    assert snap[f"episode_grow_{phase}_max_ms"] == pytest.approx(
+        episode[f"{phase}_ms"], abs=1e-3)
+
+
+def test_merged_episodes_phases_partition_its_gap(merged_quorum) -> None:
+    (episode,) = merged_quorum["after_kill"]
+    parts = [episode[k] for k in _PARTITION if k in episode]
+    assert sum(parts) == pytest.approx(episode["gap_ms"], rel=0.02)
+    assert all(p >= -0.5 for p in parts), episode
+    snap = merged_quorum["snapshot"]
+    for kind in ("shrink", "grow"):   # and so do each kind's timings
+        timed = [snap[f"episode_{kind}_{phase}_max_ms"] for phase in _TIMED
+                 if phase not in ("gap", "configure")]
+        assert sum(timed) == pytest.approx(
+            snap[f"episode_{kind}_gap_max_ms"], rel=0.02)
+
+
+def test_merged_quorums_replacement_emits_a_rejoin_episode_with_a_heal(
+        merged_quorum) -> None:
+    (episode,) = merged_quorum["replacement"]
+    assert episode["episode"] == "rejoin"
+    assert episode["heal_ms"] > 0 and episode["init_ms"] > 0
+    assert merged_quorum["equal"]  # 99.0 became the survivor's weights
+
+
+@pytest.mark.parametrize("left,joined,kinds", [
+    (1, 0, {"shrink"}),
+    (0, 1, {"grow"}),
+    (1, 1, {"shrink", "grow"}),
+    (0, 0, {"error"}),
+])
+def test_an_episode_is_named_by_its_membership_edges(
+        left, joined, kinds) -> None:
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=200)
+    store = StoreServer()
+    try:
+        manager = Manager(
+            comm=TcpCommContext(timeout=5.0),
+            load_state_dict=lambda sd: None, state_dict=dict,
+            min_replica_size=1, rank=0, world_size=1,
+            store_addr=store.addr, lighthouse_addr=lh.address(),
+            replica_id="tl_edges_",
+        )
+        try:
+            iv = manager._interval
+            iv.first, iv.dirty = False, True   # past its first commit
+            iv.members = ("self",) + ("gone",) * left
+            manager._wire_members = ("self",) + ("new",) * joined
+            manager._close_interval()
+            snap = manager.metrics.snapshot()
+            (episode,) = [e for e in manager.events.since(0)[0]
+                          if e["kind"] == "recovery_episode"]
+        finally:
+            manager.shutdown(wait=False)
+    finally:
+        store.shutdown()
+        lh.shutdown()
+    assert {k.split("_")[1] for k in snap
+            if k.startswith("episode_") and k.endswith("_gap_max_ms")
+            } == kinds
+    assert set(episode["episode"].split("+")) == kinds
+    assert (episode["left"], episode["joined"]) == (left, joined)
+    assert not manager._interval.dirty    # and the next interval is open
+
+
 # ------------------------------------------- names on the device timeline
 
 

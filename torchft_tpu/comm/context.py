@@ -118,8 +118,8 @@ class CommContext(ABC):
     # ------------------------------------------------- capability query
     # ONE definition of which (algorithm, compression, op, topology)
     # combos each backend can run, shared by ctor validation,
-    # Manager.comm_options and the bench sweeps
-    # (scripts/bench_transport.py) — so "can the psum path carry int8?"
+    # Manager.comm_options and the backends' tests
+    # (tests/test_quantized_psum.py) — so "can the psum path carry int8?"
     # or "does the host plane run the hierarchical tier?" has exactly
     # one answer everywhere instead of a hard ValueError here and a
     # drifted copy there.

@@ -1,12 +1,12 @@
 """Manager facade over a raw CommContext for single-process harnesses.
 
-tests/test_localsgd_streaming.py, scripts/bench_diloco.py and
-scripts/bench_smoke.py all drive the LocalSGD/DiLoCo round machinery
-over a real loopback transport without a control plane. The wrapper
-probes the manager surface via ``getattr`` (``wire_compensable``,
-``quorum_fence``, ``wire_nbytes``, ...), so a drifted hand-rolled copy
-would silently exercise the getattr-fallback path instead of the real
-one — one shared stub keeps every harness on the same surface.
+tests/test_localsgd_streaming.py and its sibling harnesses drive the
+LocalSGD/DiLoCo round machinery over a real loopback transport without
+a control plane. The wrapper probes the manager surface via ``getattr``
+(``wire_compensable``, ``quorum_fence``, ``wire_nbytes``, ...), so a
+drifted hand-rolled copy would silently exercise the getattr-fallback
+path instead of the real one — one shared stub keeps every harness on
+the same surface.
 
 Semantics: quorum/fence/heal are no-ops, AVG scaling divides float
 payloads by the wire world, and ``should_commit`` mirrors the real
@@ -36,9 +36,9 @@ def run_stub_ranks(store_addr: str, prefix: str, world: int, fn,
     aggregates into one RuntimeError; contexts always shut down.
 
     THE shared scaffold for every single-process sharded/outer-round
-    harness (bench.py's sharded phase, scripts/bench_smoke.py,
-    scripts/bench_sharded.py) — the same drift argument as
-    WireStubManager itself: three hand-rolled copies of the
+    harness (bench.py's sharded phase, tests/test_redistribute.py,
+    tests/test_multijob.py) — the same drift argument as
+    WireStubManager itself: hand-rolled copies of the
     configure/thread/join/shutdown dance would diverge silently."""
     import threading
 

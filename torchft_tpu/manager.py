@@ -506,8 +506,8 @@ class Manager:
         # fast path is restricted to world_size == 1 (single local rank):
         # the ManagerServer's quorum/commit fan-in across local ranks is
         # itself a control RPC per rank, so a multi-rank group always
-        # takes the full path. TORCHFT_TPU_FASTPATH=0 (or BENCH_FASTPATH=0
-        # via the bench) disables it entirely — the A/B lever.
+        # takes the full path. TORCHFT_TPU_FASTPATH=0 disables it
+        # entirely — the A/B lever.
         self._lease_enabled = (
             os.environ.get("TORCHFT_TPU_FASTPATH", "1") not in ("0", "false")
             and self._world_size == 1
@@ -1640,23 +1640,24 @@ class Manager:
     def _close_interval(self) -> None:
         """A step just committed: if the interval it closes was an
         episode, say so — one ``recovery_episode`` event and one
-        ``episode_{kind}_{phase}`` timing per phase — then open the next
-        interval. Runs on whichever thread committed (the barrier's
-        executor thread, or the caller's on the fast path); no other
-        thread touches the interval then (the caller is past its
-        prologue and only awaits the decision)."""
+        ``episode_{kind}_{phase}`` timing per phase, under each kind that
+        applies — then open the next interval. Runs on whichever thread
+        committed (the barrier's executor thread, or the caller's on the
+        fast path); no other thread touches the interval then (the caller
+        is past its prologue and only awaits the decision)."""
         iv = self._interval
         now = time.perf_counter()
         if iv.dirty:
             before, after = set(iv.members), set(self._wire_members)
             if iv.first or iv.healed_at is not None:
-                kind = "rejoin"   # this replica is new, or healed
-            elif before - after:
-                kind = "shrink"   # members left (others may have joined)
-            elif after - before:
-                kind = "grow"
+                kinds = ["rejoin"]  # this replica is new, or healed
             else:
-                kind = "error"    # discards or a latch, same membership
+                # the membership edges this replica saw: one quorum can
+                # drop a member and admit another, which is both
+                kinds = (["shrink"] if before - after else []) + (
+                    ["grow"] if after - before else [])
+                # discards or a latch, same membership
+                kinds = kinds or ["error"]
             gap = now - iv.t0
             # phases partition the gap: measured where the step's thread
             # blocked, the remainder (compute, trace, compile, dispatch)
@@ -1674,15 +1675,15 @@ class Manager:
                     iv.stalled() - iv.stalled_at_heal
                 )
             phases["other"] = gap - sum(phases.values())
-            for phase, seconds in phases.items():
-                self.metrics.observe(f"episode_{kind}_{phase}", seconds)
-            self.metrics.observe(f"episode_{kind}_gap", gap)
-            # reported inside quorum_wait, not beside it
-            self.metrics.observe(f"episode_{kind}_configure", iv.configure)
+            # configure is reported inside quorum_wait, not beside it
+            timed = {"gap": gap, "configure": iv.configure, **phases}
+            for kind in kinds:
+                for name, seconds in timed.items():
+                    self.metrics.observe(f"episode_{kind}_{name}", seconds)
             if self.events:
                 self.events.emit(
                     "recovery_episode", step=self._step,
-                    epoch=self._quorum_epoch, episode=kind,
+                    epoch=self._quorum_epoch, episode="+".join(kinds),
                     t_open=iv.t0, gap_ms=round(gap * 1e3, 3),
                     configure_ms=round(iv.configure * 1e3, 3),
                     discards=iv.discards, errors=iv.errors,

@@ -61,9 +61,9 @@ CONFIGS: Dict[str, TransformerConfig] = {
         max_seq_len=128, remat=False,
     ),
     # remat off: at this size the full activation set fits one chip's HBM
-    # with room to spare, and skipping the recompute measured +18% tokens/s
-    # on v5e (123.2k vs 104.2k at batch 8; docs/evidence). 350m/1b keep
-    # remat — 350m without it did not fit at the bench shape.
+    # with room to spare, and the recompute is then pure cost (the
+    # benchmark's 111m cell runs without it too: PERF.md §4). 350m/1b keep
+    # remat — 350m without it did not fit at bench.py's shape.
     "125m": TransformerConfig(
         vocab_size=32768, d_model=768, n_layers=12, n_heads=12, d_ff=3072,
         max_seq_len=1024, xent_chunks=8, remat=False,

@@ -203,7 +203,7 @@ class ShardedOptimizerWrapper:
     ``redistribute="allgather"`` keeps the legacy exchange — each rank
     allgathers every departing leaf state to the WHOLE cohort — as the
     live A/B arm whose wire bytes measurably exceed the bound
-    (``scripts/bench_reshard.py``). Like ``sharded``, ``redistribute``
+    (``tests/test_redistribute.py``). Like ``sharded``, ``redistribute``
     MUST match across replicas: it changes the collective sequence at
     every membership change (the planned arm runs address/ack
     allgathers the legacy arm never posts — mixed arms wedge the wire

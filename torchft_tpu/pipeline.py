@@ -17,7 +17,7 @@ follows its stage's projection of ``parallel.schedule``'s
 dictates next. Per-microbatch gradients land in store-once slots summed
 in fixed microbatch order at step end, so the pipelined arm is
 sha256-for-sha256 bitwise identical to the stage-serial arm per
-optimizer step — THE oracle ``scripts/bench_pipeline.py`` pins.
+optimizer step — THE oracle ``tests/test_pipeline_plane.py`` pins.
 
 Fault tolerance (the headline): a stage-replica kill heals WITHOUT
 draining the pipeline. Routing is lane-based (lane r of every boundary
@@ -154,8 +154,8 @@ def reconstruct_pipe_schedule(dumps: "Sequence[Dict[str, Any]]",
     Returns ``{step: {stage: [(phase, microbatch), ...]}}`` — each
     stage's executed action order, recovered from its seq-ordered
     ``microbatch_recv`` events. For a fault-free single-lane run this
-    must equal :func:`expected_stage_sequence` per stage; tests and
-    ``scripts/bench_pipeline.py`` pin that equality (the PR 7/12
+    must equal :func:`expected_stage_sequence` per stage;
+    ``tests/test_pipeline_plane.py`` pins that equality (the PR 7/12
     flight-recorder contract at pipeline granularity)."""
     out: "Dict[int, Dict[int, List[Tuple[str, int]]]]" = {}
     for d in dumps:

@@ -23,7 +23,11 @@ Event vocabulary (producers in parentheses):
                                       latched, the wire membership
                                       changed or this replica healed —
                                       ``episode`` shrink / grow / rejoin
-                                      / error, ``gap_ms`` and the phases
+                                      / error, or ``shrink+grow`` where
+                                      one quorum dropped a member and
+                                      admitted another (the interval's
+                                      timings are then observed under
+                                      both kinds), ``gap_ms`` and the phases
                                       that partition it: quorum_wait,
                                       wire_wait, heal, barrier, other,
                                       and for a rejoin init, first_step)
