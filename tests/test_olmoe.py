@@ -329,6 +329,7 @@ def test_step_programs_keep_their_names_and_scopes(family) -> None:
     train = make_train_step(cfg, tx, donate=False, **kw)
     grad = make_grad_step(cfg, **kw)
     assert (train.name, grad.name) == ("tft_train_step", "tft_grad_step")
+    # (its own tx: a program nobody else has, so it has never run)
     assert train.scope_table() == {}            # before the first call
     train(params, tx.init(params), tokens, targets)
     grad(params, tokens, targets)

@@ -405,14 +405,17 @@ def _tiny():
     ("grad", {"embed", "attn", "mlp", "lm_head_xent"}),
 ])
 def test_lowered_step_carries_scopes_and_module_name(which, scopes) -> None:
-    from torchft_tpu.models import make_grad_step, make_train_step
+    from torchft_tpu.models import loss_fn, make_grad_step, make_train_step
 
     cfg, tx, params, tokens = _tiny()
     if which == "train":
         step = make_train_step(cfg, tx, donate=False)
         args = (params, tx.init(params), tokens, tokens)
     else:
-        step = make_grad_step(cfg)
+        # equal arguments return the process's one program, which another
+        # test may have run: a loss of its own makes this one new
+        step = make_grad_step(
+            cfg, loss=lambda c, p, x, y, a=None: loss_fn(c, p, x, y, a))
         args = (params, tokens, tokens)
     assert isinstance(step, StepProgram)
     lowered = step.lower(*args)  # a jitted function's own attribute

@@ -260,10 +260,18 @@ def make_train_step(cfg: Any, tx,
     targets, attn_fn) -> scalar`` is the family's training loss (this
     file's ``loss_fn``, ``models/olmoe.py::loss_fn``) and ``cfg`` its
     config; the program's name, the ``opt_update`` scope, ``StepProgram``
-    and donation are the same for all."""
+    and donation are the same for all.
+
+    Equal arguments return THE SAME ``StepProgram`` within a process
+    (``utils/profiling.step_program``): ``cfg`` is compared by value (a
+    frozen dataclass), ``tx``, ``attn_fn`` and ``loss`` by identity. The
+    program outlives whoever asked first, so a replica group rebuilt in
+    this process with the same model runs the executables already loaded
+    — no trace, no compile. For a private program pass a private ``tx``
+    or ``loss``; an unhashable argument always gets a fresh one."""
     import optax
 
-    from torchft_tpu.utils.profiling import StepProgram
+    from torchft_tpu.utils.profiling import step_program
 
     # the function's name is the program's on the trace's XLA Modules line
     def tft_train_step(params, opt_state, tokens, targets):
@@ -275,8 +283,9 @@ def make_train_step(cfg: Any, tx,
             params = optax.apply_updates(params, updates)
         return params, opt_state, value
 
-    donate_argnums = (0, 1) if donate else ()
-    return StepProgram(jax.jit(tft_train_step, donate_argnums=donate_argnums))
+    return step_program(
+        tft_train_step, (cfg, tx, attn_fn, loss), (0, 1) if donate else ()
+    )[0]
 
 
 def make_grad_step(cfg: Any,
@@ -291,9 +300,12 @@ def make_grad_step(cfg: Any,
     memory of a single slice, identical mean-loss semantics (each slice
     is the same size, so averaging slice means equals the full-batch
     mean). The knob large effective batches need under a fixed HBM
-    budget; the batch dim must divide evenly."""
+    budget; the batch dim must divide evenly.
 
-    from torchft_tpu.utils.profiling import StepProgram
+    As ``make_train_step``: equal ``(cfg, attn_fn, microbatches, loss)``
+    return the same ``StepProgram`` within a process."""
+
+    from torchft_tpu.utils.profiling import step_program
 
     def tft_grad_step(params, tokens, targets):
         if microbatches <= 1:
@@ -333,4 +345,6 @@ def make_grad_step(cfg: Any,
             lambda g, p: (g * inv).astype(p.dtype), grad_sum, params
         )
 
-    return StepProgram(jax.jit(tft_grad_step))
+    return step_program(
+        tft_grad_step, (cfg, attn_fn, microbatches, loss)
+    )[0]

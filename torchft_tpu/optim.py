@@ -27,7 +27,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from torchft_tpu.utils.device import land_like
-from torchft_tpu.utils.profiling import span
+from torchft_tpu.utils.profiling import span, step_program
 
 logger = logging.getLogger(__name__)
 
@@ -227,7 +227,6 @@ class ShardedOptimizerWrapper:
                  redistribute: str = "plan",
                  planner=None,
                  model_shards: "int | str" = "auto") -> None:
-        import jax
         import optax
 
         from torchft_tpu.comm.redistribute import RedistPlanner
@@ -272,8 +271,9 @@ class ShardedOptimizerWrapper:
             return optax.apply_updates(param, updates), new_state
 
         # One jitted per-leaf update, cached by jax per (shape, dtype) —
-        # identical in both arms, which is half the bitwise oracle.
-        self._jit_update = jax.jit(_leaf_update)
+        # identical in both arms, which is half the bitwise oracle; one a
+        # process for this ``tx``, like every step program.
+        self._jit_update = step_program(_leaf_update, (tx,))[0]
 
     # ------------------------------------------------------------ lifecycle
 
@@ -837,7 +837,6 @@ class OptimizerWrapper:
         # bottleneck (the tax is device/transport time), while large
         # dispatch means per-program host overhead.
         from torchft_tpu.utils.metrics import Metrics
-        from torchft_tpu.utils.profiling import StepProgram
 
         self.metrics = Metrics(window=512)
         # spans of this sink carry the Manager's replica id too (a test
@@ -853,7 +852,9 @@ class OptimizerWrapper:
                 updates, new_state = tx.update(grads, opt_state, params)
                 return optax.apply_updates(params, updates), new_state
 
-        self._update = StepProgram(jax.jit(tft_opt_update))
+        # One pair of programs a process for this ``tx``: a wrapper built
+        # after a fault beside its peers runs the executables they loaded.
+        self._update, reused = step_program(tft_opt_update, (tx,))
 
         # Decide-then-apply variant for HBM-constrained multi-peer wires:
         # donating (opt_state, params) means the update program allocates
@@ -882,9 +883,11 @@ class OptimizerWrapper:
         # would leave one param-shaped donation unusable every step (XLA
         # warns per dispatch, and the grads donation buys no HBM — the
         # peak already excludes a second params+opt footprint).
-        self._update_donated = StepProgram(jax.jit(
-            tft_opt_update_donated, donate_argnums=(1, 2)
-        ))
+        self._update_donated = step_program(
+            tft_opt_update_donated, (tx,), donate_argnums=(1, 2)
+        )[0]
+        if reused:
+            self.metrics.incr("update_program_reused")
 
     def init(self, params) -> Any:
         return self.tx.init(params)

@@ -20,6 +20,10 @@ and ICI collectives, viewable in TensorBoard/Perfetto. Three shapes:
   TPU trace names an operation only by its HLO instruction
   (``fusion.1916``), so the program hands whoever reads a trace the
   instruction → scope-path table of what it compiled.
+- ``step_program`` / ``program_stats``: a step program is built once a
+  process. Its identity is what it was built from (config, optimizer,
+  loss...), not the object that asked for it, so a replica group
+  rebuilt beside its peers runs the executables the process holds.
 - ``StepProfiler``: profile steps [start, stop) of a loop, driven by env
   vars so ANY trainer (bench.py, the examples) can be profiled without
   code changes: TORCHFT_TPU_PROFILE_DIR=/tmp/trace
@@ -33,12 +37,15 @@ from __future__ import annotations
 
 import os
 import re
+import threading
 import time
+from collections import OrderedDict
 from contextlib import contextmanager
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Hashable, Optional, Sequence, Tuple
 
-__all__ = ["SPAN_PREFIX", "StepProfiler", "StepProgram", "scope_tables",
-           "span", "step_args", "throughput_span"]
+__all__ = ["SPAN_PREFIX", "StepProfiler", "StepProgram", "program_stats",
+           "scope_tables", "span", "step_args", "step_program",
+           "throughput_span"]
 
 # Every span of the library is ``tft.<name>`` on the profiler timeline:
 # one prefix a trace reducer selects on.
@@ -144,6 +151,12 @@ _PROGRAMS: Dict[str, "StepProgram"] = {}
 class StepProgram:
     """A jitted step function under a stable name.
 
+    Built through ``step_program``, it is the process's ONE program for
+    what it was built from: every caller with equal arguments holds this
+    object, so jax's caches (keyed on the function object) serve a new
+    state with seen shapes on a seen device without a trace, a lowering
+    or a backend compile. It outlives its first caller.
+
     Calls go straight to the jitted function; the first call also notes
     the arguments' shapes, dtypes and placements (no array is kept), so
     that ``scope_table`` can later lower and compile the same program
@@ -187,6 +200,62 @@ class StepProgram:
             if m:
                 table[m.group(1)] = m.group(2)
         return table
+
+
+# Step programs by what they were built from. Strong references, and
+# that is the point: the executables outlive the group that first ran
+# them, so its replacement in this process finds them. Program text only
+# (a program holds no state or gradient buffers); bounded, least recently
+# asked for out.
+_STORE_SIZE = 16
+_STORE: "OrderedDict[Hashable, StepProgram]" = OrderedDict()
+_STORE_LOCK = threading.Lock()
+_STORE_STATS = {"built": 0, "reused": 0}
+
+
+def step_program(fn: Callable, key: Tuple[Any, ...],
+                 donate_argnums: Sequence[int] = ()
+                 ) -> Tuple[StepProgram, bool]:
+    """The process's ``StepProgram`` for ``fn``: ``(program, reused)``.
+
+    ``key`` is everything ``fn`` closes over that can change what it
+    computes; with ``fn.__name__`` and ``donate_argnums`` it is the
+    program's identity. An equal key seen before returns the program
+    built then (``reused`` True; ``fn`` is dropped) — same function
+    object, so jax re-traces nothing for shapes and devices it has
+    served. Configs hash by value, functions and optax transformations
+    by identity: a caller that wants a private program passes a private
+    ``tx`` or ``loss``. A key that cannot be hashed gets a fresh program
+    every time. THE place a step program is jitted."""
+    import jax
+
+    donate_argnums = tuple(donate_argnums)
+    key = (fn.__name__, donate_argnums) + tuple(key)
+    try:
+        hash(key)
+    except TypeError:
+        key = None
+    with _STORE_LOCK:
+        program = _STORE.get(key) if key is not None else None
+        if program is not None:
+            _STORE.move_to_end(key)
+            _STORE_STATS["reused"] += 1
+            return program, True
+        program = StepProgram(jax.jit(fn, donate_argnums=donate_argnums))
+        _STORE_STATS["built"] += 1
+        if key is not None:
+            _STORE[key] = program
+            if len(_STORE) > _STORE_SIZE:
+                _STORE.popitem(last=False)
+        return program, False
+
+
+def program_stats() -> Dict[str, int]:
+    """``{"built", "reused", "held"}``: step programs jitted in this
+    process, requests served with one built earlier, and programs the
+    store holds now."""
+    with _STORE_LOCK:
+        return dict(_STORE_STATS, held=len(_STORE))
 
 
 def scope_tables() -> Dict[str, Dict[str, str]]:
