@@ -266,11 +266,18 @@ def _f32_reference_grads(q, k, v, cot):
 
 @pytest.mark.parametrize("blocks", [None, 128], ids=["rule", "b128"])
 @pytest.mark.parametrize("regime", sorted(_REGIMES))
-@pytest.mark.parametrize("shape", [(2, 512, 4, 64), (1, 512, 2, 128)])
+@pytest.mark.parametrize("shape", [
+    (2, 512, 4, 64), (1, 512, 2, 128),
+    # a value width of its own ([B, S, H, Dqk, Dv]; latent attention's
+    # 192 / 128 and a narrow pair), at sequence lengths no other case has
+    (1, 640, 2, 192, 128), (2, 384, 2, 48, 32),
+])
 def test_bf16_kernels_match_the_f32_reference(shape, regime, blocks) -> None:
     # bf16 in, forward and dq/dk/dv against the reference evaluated in f32
     # on the same bf16 values: chip_smoke.py's 0.02 x max|ref|, not looser.
-    q, k, v, cot = (_rand(shape, i + 30, jnp.bfloat16) for i in range(4))
+    qk_shape, v_shape = shape[:4], (*shape[:3], shape[-1])
+    q, k, v, cot = (_rand(s, i + 30, jnp.bfloat16) for i, s in
+                    enumerate((qk_shape, qk_shape, v_shape, v_shape)))
 
     def flash(q, k, v):
         return flash_attention(
@@ -286,7 +293,7 @@ def test_bf16_kernels_match_the_f32_reference(shape, regime, blocks) -> None:
     got = (flash(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
     want = _f32_reference_grads(q, k, v, cot)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
-        assert a.dtype == jnp.bfloat16
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape
         err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b)))
         assert err <= 0.02 * float(jnp.max(jnp.abs(b))), (name, err)
 
@@ -392,3 +399,64 @@ def test_tile_rule_on_short_and_ragged_sequences() -> None:
     assert _choose_blocks(2048, 64, 2) == _choose_blocks(2048, 128, 2)
     # an explicit block longer than the sequence is clamped to it
     assert _choose_blocks(64, 64, 2, 128, 128) == (64, 64)
+
+
+def test_tile_rule_takes_both_widths() -> None:
+    from torchft_tpu.ops.flash import (
+        _RESIDENT_KV_BYTES, _VMEM_BUDGET, _choose_blocks, _vmem_estimate,
+    )
+
+    # one width named twice is one width
+    for seq_len in (2048, 8192):
+        assert _vmem_estimate(seq_len, 128, 2, 512, 512, 128) == \
+            _vmem_estimate(seq_len, 128, 2, 512, 512)
+        assert _choose_blocks(seq_len, 64, 2, v_dim=64) == \
+            _choose_blocks(seq_len, 64, 2)
+    # latent attention at 8k: K + V of a head are 5.2 MB, so the kernels
+    # stream, one tile a grid step, and the k edge is the streamed one
+    assert 8192 * (192 + 128) * 2 > _RESIDENT_KV_BYTES
+    assert _choose_blocks(8192, 192, 2, v_dim=128) == (512, 1024)
+    assert _vmem_estimate(8192, 192, 2, 512, 1024, 128) <= _VMEM_BUDGET
+    # every shape a cell ran before stays resident and square
+    for seq_len, head_dim in ((2048, 64), (2048, 128), (4096, 128)):
+        assert _choose_blocks(seq_len, head_dim, 2) == (512, 512)
+    # streamed, but the long edge does not divide the sequence
+    assert _choose_blocks(8192 + 512, 128, 2) == (512, 512)
+    # a narrower v needs less than a v as wide as q and k
+    assert _vmem_estimate(8192, 192, 2, 512, 512, 128) < \
+        _vmem_estimate(8192, 192, 2, 512, 512)
+    # the regime's edge counts both widths: 4096 x (128 + 128) x 2 is
+    # resident to the byte, 4096 x (192 + 128) x 2 is not
+    assert _vmem_estimate(4096, 128, 2, 512, 512) > \
+        _vmem_estimate(4096, 192, 2, 512, 512, 128)
+
+
+# sha256 of the jaxpr (source positions cut out) of the gradient of a
+# flash call with ONE head width, as the commit before the value width
+# (a4592dd) traced it: forward, dq and dkv, the block shapes, the grids
+# and every instruction of the kernels. A change that moves these moves
+# what the c111m / c1p3b / olmoe cells run; regenerate on purpose only.
+_EQUAL_WIDTH_JAXPR = {
+    "resident":
+        "f8b02ef3012e1b54bd009de82187ab7387fa71a6f08f9896f031d1589227e58e",
+    "streamed":
+        "ccc05cff9386a79e1393525dc68c0841d6ea2621f793ab21f3ddc6d76aa81eaf",
+}
+
+
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+def test_equal_widths_lower_as_before(regime) -> None:
+    import hashlib
+    import re
+
+    q = jnp.zeros((2, 512, 4, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=True, _resident_kv_bytes=_REGIMES[regime],
+        ).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+    text = re.sub(r"/[^ ]*?\.py:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _EQUAL_WIDTH_JAXPR[regime]

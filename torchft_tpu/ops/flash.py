@@ -10,6 +10,18 @@ exp(S − lse) tile by tile. Two regimes of each: resident kernels hold K/V
 tiles inside the kernel; streamed kernels ride the tiles over the
 innermost grid dimension with VMEM scratch accumulators (long context).
 
+Two widths. q and k are ``Dqk`` wide, v (and out, dO, dv) ``Dv``: one
+for the GPT and OLMoE families, 192 and 128 for latent attention
+(``models/joyai.py``), whose 192 is fed as ONE 192-lane operand. The
+other way, the score as ``q_nope·k_nope + q_rope·k_r`` with the 64-wide
+rotary key never broadcast to the heads in HBM, was measured on the v5e
+at [2, 8192, 32, .] (PERF.md, PR 31): its forward is 11 % faster (20.4
+against 21.9 + 1.05 ms to lay k out), and its backward would be a second
+family of kernels for about 3 % of a step; it is not here. v is not
+padded and P·V is ``Dv`` wide. A call with ``Dqk == Dv`` traces to the
+program it traced to before there were two (``tests/test_flash.py`` pins
+the jaxpr).
+
 What is which dtype. q, k, v, dO arrive and out, dq, dk, dv leave in the
 input dtype (bf16 in the models). Inside a kernel every operand is upcast
 to f32 as it is loaded and every ``dot_general`` is f32 x f32 -> f32
@@ -43,7 +55,10 @@ is mostly per loop trip (lane-sparse statistics columns, loop-carried
 accumulators, no overlap of MXU and vector work across trips), so at
 S 2048 512 x 512 tiles run the three kernels 2.3-3.9x faster than
 128 x 128 at both head widths, although the diagonal wastes more (10 of
-16 tiles computed, against 136 of 256). Explicit arguments win
+16 tiles computed, against 136 of 256). In the streamed regime a grid
+step is one tile, and at 512 x 512 the step's own cost is of the order
+of the tile's arithmetic: there a 512 x 1024 tile is tried first
+(``_STREAMED_TILES``). Explicit arguments win
 (parallel/ring.py and the tests pass them).
 
 Mosaic layout note: per-row statistics (lse, delta) are [BH, S] f32 in
@@ -191,8 +206,7 @@ def _fwd_tile(q, k, v, acc, m, l, qi, ki, masked: bool):
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                   block_k: int, seq_len: int, causal: bool, scale: float):
     qi = pl.program_id(1)
-    q = _f32(q_ref[0]) * scale  # [BQ, D]
-    d = q.shape[-1]
+    q = _f32(q_ref[0]) * scale  # [BQ, Dqk]
 
     def tile(ki, carry, masked):
         k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
@@ -201,7 +215,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
     acc, m, l = _sweep(
         qi, block_q, block_k, seq_len, causal, True, tile,
-        (jnp.zeros((block_q, d), dtype=jnp.float32),
+        (jnp.zeros((block_q, v_ref.shape[-1]), dtype=jnp.float32),
          jnp.full((block_q, 1), _NEG_INF, dtype=jnp.float32),
          jnp.zeros((block_q, 1), dtype=jnp.float32)),
     )
@@ -265,14 +279,16 @@ _RESIDENT_KV_BYTES = 2 * 1024 * 1024
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool,
                    resident_kv_bytes: Optional[int] = None):
-    """q,k,v: [BH, S, D] -> (out [BH, S, D], lse [BH, S] f32)."""
+    """q, k: [BH, S, Dqk], v: [BH, S, Dv] -> (out [BH, S, Dv], lse
+    [BH, S] f32)."""
     bh, seq_len, d = q.shape
+    dv = v.shape[-1]
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
-    kv_bytes = 2 * seq_len * d * q.dtype.itemsize
+    kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
     # lse travels as [BH, S, 1] (see module docstring: tile-legal specs)
     out_shapes = (
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((bh, seq_len, dv), q.dtype),
         jax.ShapeDtypeStruct((bh, seq_len, 1), jnp.float32),
     )
     if kv_bytes <= threshold:
@@ -291,10 +307,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, seq_len, dv), lambda b, i: (b, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             ],
             out_shape=out_shapes,
@@ -315,7 +331,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         scale=scale,
     )
     scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, dv), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
     ]
@@ -325,10 +341,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=out_shapes,
@@ -397,8 +413,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, block_q: int, block_k: int,
                          seq_len: int, causal: bool, scale: float):
     qi = pl.program_id(1)
-    q = _f32(q_ref[0]) * scale                    # [BQ, D]
-    do = _f32(do_ref[0])                          # [BQ, D]
+    q = _f32(q_ref[0]) * scale                    # [BQ, Dqk]
+    do = _f32(do_ref[0])                          # [BQ, Dv]
     lse = lse_ref[0]                              # [BQ, 1]
     delta = delta_ref[0]                          # [BQ, 1]
 
@@ -418,8 +434,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, block_k: int,
                           seq_len: int, causal: bool, scale: float):
     ki = pl.program_id(1)
-    k = _f32(k_ref[0])                            # [BK, D]
-    v = _f32(v_ref[0])
+    k = _f32(k_ref[0])                            # [BK, Dqk]
+    v = _f32(v_ref[0])                            # [BK, Dv]
 
     def tile(qi, carry, masked):
         rows = pl.ds(qi * block_q, block_q)
@@ -432,7 +448,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     zeros = jnp.zeros(k.shape, dtype=jnp.float32)
     dk, dv = _sweep(
-        ki, block_q, block_k, seq_len, causal, False, tile, (zeros, zeros)
+        ki, block_q, block_k, seq_len, causal, False, tile,
+        # one zeros for both where the widths are one: the program of
+        # every call with Dqk == Dv stays what it was, to the instruction
+        (zeros, zeros if v.shape == k.shape
+         else jnp.zeros(v.shape, dtype=jnp.float32)),
     )
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -500,6 +520,7 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
                              scale: float, block_q: int, block_k: int,
                              interpret: bool):
     bh, seq_len, d = q.shape
+    dv = v.shape[-1]
     num_q_blocks = seq_len // block_q
     num_k_blocks = seq_len // block_k
     # tile-legal views of the [BH, S] statistics (module docstring):
@@ -517,8 +538,8 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
@@ -539,14 +560,14 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -554,7 +575,7 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
         ),
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -586,9 +607,10 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     dq/dk/dv contributions are independent (FlashAttention-2), so pairs
     can be revisited in any order/placement and summed."""
     bh, seq_len, d = q.shape
+    dv = v.shape[-1]
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
-    kv_bytes = 2 * seq_len * d * q.dtype.itemsize
+    kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
     if kv_bytes > threshold:
         return _flash_backward_streamed(
             q, k, v, g, lse, delta, causal, scale, block_q, block_k,
@@ -609,8 +631,8 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, seq_len, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
@@ -630,14 +652,14 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
         in_specs=[
             pl.BlockSpec((1, seq_len, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, seq_len, d), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, seq_len, dv), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0)),
         ],
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -691,60 +713,82 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Tile edges tried, largest first. Measured on the v5e (PERF.md, PR 24):
-# at S 2048 a 512 x 512 score tile runs the three kernels 2.3-3.9 times
-# faster than 128 x 128 at 64- and at 128-wide heads alike, for the row
-# sweeps and for the column sweep; 1024 gains nothing more.
-_TILE_EDGES = (512, 256, 128)
+# Score tiles (block_q, block_k) tried, first fit first. Measured on the
+# v5e (PERF.md, PR 24): at S 2048 a 512 x 512 score tile runs the three
+# kernels 2.3-3.9 times faster than 128 x 128 at 64- and at 128-wide heads
+# alike, for the row sweeps and for the column sweep; 1024 gains nothing
+# more where K and V are resident.
+_TILES = ((512, 512), (256, 256), (128, 128))
+# The streamed kernels run ONE tile a grid step, and a step costs about as
+# much again as a 512 x 512 tile's arithmetic, so they try a k edge of
+# 1024 first. Measured on the v5e at [2, 8192, 32, 192 / 128] (PERF.md,
+# PR 31): forward 27.8 -> 19.5 ms; at 128 / 128 it changes nothing.
+_STREAMED_TILES = ((512, 1024),) + _TILES
 # What one kernel instance may plan to hold in VMEM (the v5e's default
 # scoped limit is 16 MiB).
 _VMEM_BUDGET = 16 * 1024 * 1024
 
 
+def _resident(seq_len: int, pair: int, itemsize: int) -> bool:
+    """Whether K and V of one head (``pair`` = their widths' sum) stay
+    whole in VMEM: the regime the kernels' wrappers pick by default."""
+    return seq_len * pair * itemsize <= _RESIDENT_KV_BYTES
+
+
 def _vmem_estimate(seq_len: int, head_dim: int, itemsize: int,
-                   block_q: int, block_k: int) -> int:
+                   block_q: int, block_k: int,
+                   v_dim: Optional[int] = None) -> int:
     """Bytes the hungriest of the three kernels (dkv) keeps in VMEM at
     these tiles: its pipelined operands and statistics twice (double
     buffering), their f32 copies for one tile, the four f32 score-tile
     temporaries (S, P, dP, dS) and the two f32 accumulators. In the
-    resident regime Q and dO are whole sequences."""
-    resident = 2 * seq_len * head_dim * itemsize <= _RESIDENT_KV_BYTES
-    q_rows = seq_len if resident else block_q
-    operands = (2 * q_rows + 4 * block_k) * head_dim * itemsize
+    resident regime Q and dO are whole sequences. ``head_dim`` is q's and
+    k's width, ``v_dim`` v's and dO's where it is another (latent
+    attention: 192 and 128): a row of Q with its dO, or of K with its V,
+    is ``head_dim + v_dim`` wide."""
+    pair = head_dim + (head_dim if v_dim is None else v_dim)
+    q_rows = seq_len if _resident(seq_len, pair, itemsize) else block_q
+    operands = (q_rows + 2 * block_k) * pair * itemsize
     stats = 2 * q_rows * 4
-    upcast = 2 * (block_q + block_k) * head_dim * 4
+    upcast = (block_q + block_k) * pair * 4
     return (2 * (operands + stats) + upcast + 4 * block_q * block_k * 4
-            + 2 * block_k * head_dim * 4)
+            + block_k * pair * 4)
 
 
 def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
                    block_q: Optional[int] = None,
-                   block_k: Optional[int] = None) -> Tuple[int, int]:
-    """``(block_q, block_k)`` as a pure function of the shape: the largest
-    square tile of ``_TILE_EDGES`` that divides ``seq_len`` and whose
-    :func:`_vmem_estimate` fits ``_VMEM_BUDGET`` (the smallest edge when
-    none does, or the whole of a shorter sequence). An explicit argument
-    wins over the rule and is only clamped to the sequence."""
-    edge = min(_TILE_EDGES[-1], seq_len)
-    for cand in _TILE_EDGES:
-        if seq_len % cand == 0 and _vmem_estimate(
-                seq_len, head_dim, itemsize, cand, cand) <= _VMEM_BUDGET:
-            edge = cand
+                   block_k: Optional[int] = None,
+                   v_dim: Optional[int] = None) -> Tuple[int, int]:
+    """``(block_q, block_k)`` as a pure function of the shape: the first
+    tile of ``_TILES`` (``_STREAMED_TILES`` where the kernels stream) whose
+    edges divide ``seq_len`` and whose :func:`_vmem_estimate` fits
+    ``_VMEM_BUDGET`` (the smallest edge when none does, or the whole of a
+    shorter sequence). An explicit argument wins over the rule and is
+    only clamped to the sequence."""
+    pair = head_dim + (head_dim if v_dim is None else v_dim)
+    tiles = _TILES if _resident(seq_len, pair, itemsize) else _STREAMED_TILES
+    q_edge = k_edge = min(_TILES[-1][0], seq_len)
+    for cand_q, cand_k in tiles:
+        if (seq_len % cand_q == 0 and seq_len % cand_k == 0
+                and _vmem_estimate(seq_len, head_dim, itemsize, cand_q,
+                                   cand_k, v_dim) <= _VMEM_BUDGET):
+            q_edge, k_edge = cand_q, cand_k
             break
-    return (edge if block_q is None else min(block_q, seq_len),
-            edge if block_k is None else min(block_k, seq_len))
+    return (q_edge if block_q is None else min(block_q, seq_len),
+            k_edge if block_k is None else min(block_k, seq_len))
 
 
-def _bshd_prologue(q, scale, block_q, block_k):
-    """Shared [B,S,H,D]-surface plumbing: scale default, block choice
-    (from the shape where the caller gave none) and clamping,
-    divisibility validation, and the [B,S,H,D] <-> [B*H,S,D] layout
-    pair. One place, three wrappers."""
+def _bshd_prologue(q, v, scale, block_q, block_k):
+    """Shared [B,S,H,D]-surface plumbing: scale default (from q's
+    width), block choice (from the shape where the caller gave none) and
+    clamping, divisibility validation, and the [B,S,H,D] <-> [B*H,S,D]
+    layout pair, which keeps each array's own last dim. One place, three
+    wrappers."""
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     block_q, block_k = _choose_blocks(
-        s, d, q.dtype.itemsize, block_q, block_k
+        s, d, q.dtype.itemsize, block_q, block_k, v.shape[-1]
     )
     if s % block_q or s % block_k:
         raise ValueError(
@@ -753,10 +797,10 @@ def _bshd_prologue(q, scale, block_q, block_k):
         )
 
     def merge(x):  # [B,S,H,D] -> [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     def unmerge(x):  # [B*H, S, D] -> [B,S,H,D]
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return float(scale), block_q, block_k, merge, unmerge
 
@@ -779,7 +823,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     ``flash_block_attention_bwd``."""
     b, s, h, _ = q.shape
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k
+        q, v, scale, block_q, block_k
     )
     out, lse = _flash_forward(
         merge(q), merge(k), merge(v), causal, scale,
@@ -807,7 +851,7 @@ def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
     runs causal=True, past pairs causal=False."""
     b, s, h, _ = q.shape
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k
+        q, v, scale, block_q, block_k
     )
 
     def merge_stat(x):  # [B,H,S] -> [BH, S]
@@ -828,7 +872,9 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
                     _resident_kv_bytes: Optional[int] = None):
-    """[B, S, H, D] flash attention (pallas on TPU).
+    """Flash attention (pallas on TPU): q, k ``[B, S, H, Dqk]``, v
+    ``[B, S, H, Dv]`` -> ``[B, S, H, Dv]``; the softmax scale defaults to
+    ``1 / sqrt(Dqk)``.
 
     ``block_q`` / ``block_k`` left ``None`` are chosen from the shape
     (:func:`_choose_blocks`). Sequence length must be a multiple of the
@@ -841,7 +887,7 @@ def flash_attention(q, k, v, causal: bool = True,
     touching shared state.
     """
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, scale, block_q, block_k
+        q, v, scale, block_q, block_k
     )
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
                  block_q, block_k, interpret, _resident_kv_bytes)

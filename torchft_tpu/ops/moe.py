@@ -26,7 +26,7 @@ Nothing of ``parallel/moe.py`` (GShard top-2 with a capacity) is used.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,10 +44,26 @@ class Dispatch(NamedTuple):
     group_sizes: Any   # [E] int32, rows per expert, sums to N*k
 
 
-def top_k_routing(probs: Any, k: int) -> Tuple[Any, Any]:
-    """``(weights [N, k], experts [N, k] int32)``: the ``k`` largest router
-    probabilities of each token, as they are (not renormalised)."""
-    return jax.lax.top_k(probs, k)
+def top_k_routing(scores: Any, k: int, bias: Any = None,
+                  renormalise: bool = False,
+                  scale: float = 1.0) -> Tuple[Any, Any]:
+    """``(weights [N, k], experts [N, k] int32)``. Without ``bias``: the
+    ``k`` largest router scores of each token, as they are (OLMoE's
+    softmax probabilities, not renormalised). With a ``bias [E]``
+    (DeepSeek-V3's ``noaux_tc``): the ``k`` largest of ``scores + bias``
+    choose, and the weights are the chosen experts' ``scores`` — the bias
+    selects and never weights, and no gradient reaches it.
+    ``renormalise`` divides a token's weights by their sum (+ 1e-20);
+    ``scale`` multiplies them."""
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias).astype(scores.dtype), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return (weights * scale if scale != 1.0 else weights), experts
 
 
 def sort_by_expert(experts: Any, n_experts: int) -> Dispatch:
@@ -58,6 +74,8 @@ def sort_by_expert(experts: Any, n_experts: int) -> Dispatch:
     inverse = jnp.zeros((n,), jnp.int32).at[order].set(
         jnp.arange(n, dtype=jnp.int32), unique_indices=True
     )
+    # an id past the last expert (a share's sentinel) is dropped: the
+    # default of a scatter
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
     return Dispatch(order, inverse, sizes)
 
@@ -156,14 +174,43 @@ def combine(y: Any, weights: Any, dispatch: Dispatch) -> Any:
 
 
 def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
-            down: Any) -> Any:
+            down: Any, n_routed: Optional[int] = None,
+            first_expert: int = 0) -> Any:
     """The whole sparse sublayer after the router, under the trace's
     scopes ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``: tokens
-    ``h [N, d]`` with their ``[N, k]`` routing -> ``[N, d]``."""
+    ``h [N, d]`` with their ``[N, k]`` routing -> ``[N, d]``.
+
+    The layer is told which experts it holds: the router chose among
+    ``n_routed`` experts (default: as many as ``gate`` holds) and the
+    weights here are those of experts ``first_expert ..
+    first_expert + gate.shape[0]``. What comes back is the held experts'
+    part of the result; assignments to absent experts are computed
+    nowhere and nothing stands in for the chips that hold them.
+
+    With a share held, the shapes stay those of all ``N*k`` assignments
+    (every assignment on a held expert is an ordinary input): absent
+    assignments sort behind the held ones, the group sizes count held
+    rows only, and megablox's grid ends at the groups' sum — the grouped
+    matmuls' time follows the rows held, and the rows past the sum are
+    never written, so they are cut off by a select on the way in (for
+    the gradient's sake) and on the way out."""
+    n_held = gate.shape[0]
+    share = n_routed is not None and (n_routed, first_expert) != (n_held, 0)
     with jax.named_scope("moe_dispatch"):
-        dispatch = sort_by_expert(experts, gate.shape[0])
+        if share:
+            local = experts - first_expert
+            held = (local >= 0) & (local < n_held)             # [N, k]
+            # the sentinel ``n_held`` sorts last and counts nowhere
+            experts = jnp.where(held, local, n_held)
+            weights = jnp.where(held, weights, 0)
+        dispatch = sort_by_expert(experts, n_held)
         x = gather_tokens(h, dispatch)
+        if share:
+            live = (jnp.arange(experts.size, dtype=jnp.int32)
+                    < jnp.sum(dispatch.group_sizes))[:, None]  # [N*k, 1]
+            x = jnp.where(live, x, 0)
     with jax.named_scope("moe_experts"):
         y = swiglu_experts(x, gate, up, down, dispatch.group_sizes)
     with jax.named_scope("moe_combine"):
-        return combine(y, weights, dispatch)
+        return combine(jnp.where(live, y, 0) if share else y, weights,
+                       dispatch)

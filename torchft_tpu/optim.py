@@ -32,11 +32,50 @@ from torchft_tpu.utils.profiling import span, step_program
 logger = logging.getLogger(__name__)
 
 __all__ = [
+    "balance_bias_rule",
+    "with_balance_bias",
     "OptimizerWrapper",
     "PartitionedOuterOptimizer",
     "ShardedOptState",
     "ShardedOptimizerWrapper",
 ]
+
+
+def balance_bias_rule(rate: float):
+    """The optax transformation of a router's balance bias (DeepSeek-V3's
+    auxiliary-loss-free balancing, ``topk_method: noaux_tc``): what
+    arrives as the leaf's gradient is the step's assignments per expert
+    (the model's doing, as ``models/joyai.py::_loads_as_gradient``;
+    averaged over replica groups like any gradient), and the update is
+    ``rate · sign(mean(load) - load_e)``: an expert with fewer than its share is
+    made likelier, one with more less likely. No state, no decay."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    def update(loads, state, params=None):
+        del params
+        return jax.tree_util.tree_map(
+            lambda x: rate * jnp.sign(jnp.mean(x) - x), loads), state
+
+    return optax.GradientTransformation(lambda _p: optax.EmptyState(), update)
+
+
+def with_balance_bias(tx, rate: float, is_bias):
+    """``tx`` for every leaf but those whose path in the parameter tree
+    ``is_bias`` accepts (the model file's predicate: this module knows no
+    model's leaf names), which take :func:`balance_bias_rule`: one optax
+    transformation, so the fused step, the classic update behind the
+    commit gate and the heal treat the bias like any other leaf."""
+    import jax
+    import optax
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _x: "bias" if is_bias(path) else "rest", params)
+
+    return optax.multi_transform(
+        {"rest": tx, "bias": balance_bias_rule(rate)}, labels)
 
 
 class PartitionedOuterOptimizer:
