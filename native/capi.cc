@@ -522,6 +522,12 @@ void ft_iq_heartbeat(void* handle, const char* replica_id, int64_t now_ms) {
                                                                now_ms);
 }
 
+// The door-knock's early expiry; 1 iff the replica was healthy.
+int ft_iq_expire(void* handle, const char* replica_id, int64_t now_ms) {
+  return static_cast<ftquorum::IncrementalQuorum*>(handle)->expire(replica_id,
+                                                                   now_ms);
+}
+
 int ft_iq_join(void* handle, int64_t joined_ms, const char* member_json,
                char** err) {
   try {

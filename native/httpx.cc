@@ -475,6 +475,88 @@ bool parse_http_addr(const std::string& addr, std::string* host, int* port) {
   return true;
 }
 
+std::vector<Knock> knock(const std::vector<std::string>& addrs,
+                         int64_t deadline_ms) {
+  struct Target {
+    int tried = 0, refused = 0;
+    bool connected = false;
+  };
+  struct Flight {
+    size_t target;
+    int fd;
+  };
+  std::vector<Target> targets(addrs.size());
+  std::vector<Flight> flights;
+  for (size_t i = 0; i < addrs.size(); i++) {
+    std::string host;
+    int port = 0;
+    if (!parse_http_addr(addrs[i], &host, &port)) continue;
+    struct addrinfo hints;
+    memset(&hints, 0, sizeof(hints));
+    hints.ai_family = AF_INET;
+    hints.ai_socktype = SOCK_STREAM;
+    struct addrinfo* res = nullptr;
+    if (getaddrinfo(host.c_str(), std::to_string(port).c_str(), &hints,
+                    &res) != 0) {
+      continue;
+    }
+    for (struct addrinfo* ai = res; ai != nullptr; ai = ai->ai_next) {
+      int fd = ::socket(ai->ai_family, ai->ai_socktype | SOCK_NONBLOCK,
+                        ai->ai_protocol);
+      if (fd < 0) continue;
+      targets[i].tried += 1;
+      if (::connect(fd, ai->ai_addr, ai->ai_addrlen) == 0) {
+        targets[i].connected = true;
+      } else if (errno == EINPROGRESS) {
+        flights.push_back({i, fd});
+        continue;
+      } else if (errno == ECONNREFUSED) {
+        targets[i].refused += 1;
+      }
+      ::close(fd);
+    }
+    freeaddrinfo(res);
+  }
+  while (!flights.empty()) {
+    int64_t remaining = deadline_ms - now_ms();
+    if (remaining <= 0) break;
+    std::vector<struct pollfd> pfds(flights.size());
+    for (size_t j = 0; j < flights.size(); j++) {
+      pfds[j].fd = flights[j].fd;
+      pfds[j].events = POLLOUT;
+      pfds[j].revents = 0;
+    }
+    int pr = ::poll(pfds.data(), pfds.size(), static_cast<int>(remaining));
+    if (pr < 0 && errno != EINTR) break;
+    std::vector<Flight> still;
+    for (size_t j = 0; j < flights.size(); j++) {
+      if (pfds[j].revents == 0) {
+        still.push_back(flights[j]);
+        continue;
+      }
+      int so_err = 0;
+      socklen_t len = sizeof(so_err);
+      if (getsockopt(flights[j].fd, SOL_SOCKET, SO_ERROR, &so_err, &len) ==
+          0) {
+        if (so_err == 0) targets[flights[j].target].connected = true;
+        if (so_err == ECONNREFUSED) targets[flights[j].target].refused += 1;
+      }
+      ::close(flights[j].fd);
+    }
+    flights.swap(still);
+  }
+  for (const Flight& f : flights) ::close(f.fd);  // unanswered in time
+  std::vector<Knock> out(addrs.size(), Knock::kNoAddress);
+  for (size_t i = 0; i < addrs.size(); i++) {
+    const Target& t = targets[i];
+    if (t.tried == 0) continue;
+    out[i] = t.connected                ? Knock::kConnected
+             : t.refused == t.tried     ? Knock::kRefused
+                                        : Knock::kUnanswered;
+  }
+  return out;
+}
+
 ClientResult http_post(const std::string& host, int port,
                        const std::string& path, const std::string& body,
                        int64_t deadline_ms) {

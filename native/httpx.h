@@ -87,6 +87,24 @@ struct ClientResult {
 // Parse "http://host:port[/...]" or "host:port" into host/port.
 bool parse_http_addr(const std::string& addr, std::string* host, int* port);
 
+// What one door-knock found at an address: a plain TCP connect, closed
+// the moment it is answered; nothing is sent.
+enum class Knock {
+  kNoAddress,   // does not parse or resolve: no connection was tried
+  kConnected,   // something listens there
+  kRefused,     // every resolved address answered ECONNREFUSED (the
+                // kernel's RST: the host is up and no process listens)
+  kUnanswered,  // timed out, unreachable, or any other error
+};
+
+// Knock on every "http://host:port" at once: non-blocking connects, all
+// in flight together, polled until deadline_ms (now_ms clock). IPv4
+// only, like HttpServer: a v6 address of the same name would refuse
+// while the server lives. Name resolution is the one step the deadline
+// does not bound.
+std::vector<Knock> knock(const std::vector<std::string>& addrs,
+                         int64_t deadline_ms);
+
 // POST with an absolute deadline; sets x-timeout-ms from the remaining
 // budget; retries connection establishment with backoff until the deadline.
 ClientResult http_post(const std::string& host, int port,

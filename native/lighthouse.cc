@@ -234,6 +234,20 @@ void Lighthouse::tick_loop() {
     // One pass over every shard: a stable job's decision() is an epoch
     // cache hit, so the per-tick cost of quiet tenants is O(1) each.
     for (auto& kv : jobs_) tick_job_locked(*kv.second);
+    std::vector<DoorKnock> knocks = collect_knocks_locked(fthttp::now_ms());
+    if (!knocks.empty()) {
+      std::vector<std::string> addrs;
+      for (const auto& k : knocks) addrs.push_back(k.address);
+      // Never connect while holding the state lock: a listener that
+      // accepts nothing must not delay a heartbeat or a quorum RPC.
+      lk.unlock();
+      std::vector<fthttp::Knock> found = fthttp::knock(
+          addrs, fthttp::now_ms() +
+                     static_cast<int64_t>(opts_.quorum.quorum_tick_ms));
+      lk.lock();
+      if (stopping_) break;
+      apply_knocks_locked(knocks, found, fthttp::now_ms());
+    }
     // Evict domain rows silent far past their own advertised interval
     // (well after the 3x staleness flag, so operators see the STALE row
     // first): an aggregator restarting under a fresh generated domain
@@ -276,9 +290,70 @@ void Lighthouse::tick_loop() {
   }
 }
 
+std::vector<Lighthouse::DoorKnock> Lighthouse::collect_knocks_locked(
+    int64_t now_ms) {
+  std::vector<DoorKnock> knocks;
+  const int64_t two_ticks =
+      2 * static_cast<int64_t>(opts_.quorum.quorum_tick_ms);
+  for (const auto& kv : jobs_) {
+    const JobState& job = *kv.second;
+    if (job.held_since_ms < 0 || now_ms - job.held_since_ms < two_ticks) {
+      continue;
+    }
+    const auto& state = job.iq.state();
+    if (!state.prev_quorum.has_value() || state.participants.empty()) {
+      continue;
+    }
+    // Only a member of the last quorum has given an address; a replica
+    // known by heartbeat alone is left to the timeout.
+    for (const auto& m : state.prev_quorum->participants) {
+      if (!job.iq.is_healthy(m.replica_id)) continue;
+      if (state.participants.count(m.replica_id)) continue;
+      knocks.push_back({kv.first, m.replica_id, m.address,
+                        state.heartbeats.at(m.replica_id)});
+    }
+  }
+  return knocks;
+}
+
+void Lighthouse::apply_knocks_locked(const std::vector<DoorKnock>& knocks,
+                                     const std::vector<fthttp::Knock>& found,
+                                     int64_t now_ms) {
+  std::set<JobState*> moved;
+  for (size_t i = 0; i < knocks.size(); i++) {
+    const DoorKnock& k = knocks[i];
+    if (found[i] == fthttp::Knock::kNoAddress) continue;
+    JobState& job = *jobs_.at(k.job_id);  // shards are never erased
+    job.door_knocks += 1;
+    if (found[i] != fthttp::Knock::kRefused) continue;
+    // It beat or asked while the tick thread knocked: it lives.
+    const auto& state = job.iq.state();
+    auto hb = state.heartbeats.find(k.replica_id);
+    if (hb == state.heartbeats.end() || hb->second != k.heartbeat_ms ||
+        state.participants.count(k.replica_id)) {
+      continue;
+    }
+    if (!job.iq.expire(k.replica_id, now_ms)) continue;
+    job.refused_expiries += 1;
+    moved.insert(&job);
+    fprintf(stderr,
+            "[torchft_tpu lighthouse] job %s: replica %s expired, its "
+            "manager address %s refused a connection\n",
+            k.job_id.c_str(), k.replica_id.c_str(), k.address.c_str());
+  }
+  for (JobState* job : moved) tick_job_locked(*job);
+}
+
 void Lighthouse::tick_job_locked(JobState& job) {
-  const auto& decision = job.iq.decision(fthttp::now_ms());
+  int64_t now_ms = fthttp::now_ms();
+  const auto& decision = job.iq.decision(now_ms);
   job.last_reason = decision.reason;
+  if (decision.absent == 0) {
+    job.held_since_ms = -1;
+  } else if (job.held_since_ms < 0 || job.held_epoch != job.iq.epoch()) {
+    job.held_since_ms = now_ms;
+    job.held_epoch = job.iq.epoch();
+  }
   // Epoch-watch wakeup: decision()'s sweep (expiry/prune), any join since
   // the last tick, and evictions may have bumped THIS job's membership
   // epoch without an announcement. Parked EpochWatch waiters key their
@@ -756,7 +831,7 @@ Response Lighthouse::handle_status() {
     if (jobs_.size() > 1) {
       html << "<h3>jobs</h3><table><tr><th>job</th><th>priority</th>"
            << "<th>healthy/budget</th><th>epoch</th><th>preemptions</th>"
-           << "</tr>";
+           << "<th>door knocks (refused)</th></tr>";
       for (const auto& kv : jobs_) {
         const JobState& j = *kv.second;
         html << "<tr><td>" << html_escape(kv.first) << "</td><td>"
@@ -764,7 +839,8 @@ Response Lighthouse::handle_status() {
              << (j.group_budget > 0 ? std::to_string(j.group_budget)
                                     : std::string("∞"))
              << "</td><td>" << j.iq.epoch() << "</td><td>" << j.preemptions
-             << "</td></tr>";
+             << "</td><td>" << j.door_knocks << " (" << j.refused_expiries
+             << ")</td></tr>";
       }
       html << "</table>";
     }
@@ -825,6 +901,7 @@ Response Lighthouse::handle_status_json() {
     uint64_t sum_hb_pruned = 0, sum_part_pruned = 0;
     uint64_t sum_lease_grants = 0, sum_lease_breaks = 0, sum_watch_rpcs = 0;
     uint64_t sum_preemptions = 0, sum_rl_drops = 0, sum_healthy = 0;
+    uint64_t sum_knocks = 0, sum_refused = 0;
     for (const auto& kv : jobs_) {
       const JobState& j = *kv.second;
       sum_compute += j.iq.compute_count();
@@ -841,6 +918,8 @@ Response Lighthouse::handle_status_json() {
       sum_preemptions += j.preemptions;
       sum_rl_drops += j.rate_limit_drops;
       sum_healthy += j.iq.healthy_count();
+      sum_knocks += j.door_knocks;
+      sum_refused += j.refused_expiries;
     }
 
     // Control-plane scaling counters (PR 10): the evidence surface for
@@ -857,6 +936,8 @@ Response Lighthouse::handle_status_json() {
     ctl["domains_pruned"] = static_cast<int64_t>(domains_pruned_);
     ctl["heartbeats_pruned"] = static_cast<int64_t>(sum_hb_pruned);
     ctl["participants_pruned"] = static_cast<int64_t>(sum_part_pruned);
+    ctl["door_knocks"] = static_cast<int64_t>(sum_knocks);
+    ctl["refused_expiries"] = static_cast<int64_t>(sum_refused);
     ctl["lease_grants"] = static_cast<int64_t>(sum_lease_grants);
     ctl["lease_breaks"] = static_cast<int64_t>(sum_lease_breaks);
     ctl["epoch_watch_rpcs"] = static_cast<int64_t>(sum_watch_rpcs);
@@ -896,6 +977,8 @@ Response Lighthouse::handle_status_json() {
       e["epoch_watch_rpcs"] = static_cast<int64_t>(j.epoch_watch_rpcs);
       e["preemptions"] = static_cast<int64_t>(j.preemptions);
       e["rate_limit_drops"] = static_cast<int64_t>(j.rate_limit_drops);
+      e["door_knocks"] = static_cast<int64_t>(j.door_knocks);
+      e["refused_expiries"] = static_cast<int64_t>(j.refused_expiries);
       e["reason"] = j.last_reason;
       if (!j.evicted.empty()) {
         ftjson::Array ev;

@@ -46,6 +46,22 @@
 // and the victim job's epoch bump breaks its leases so the survivors
 // re-form and shrink live through the redistribution planner.
 //
+// Door-knock (PR 32): a replica killed on a live host leaves evidence
+// the heartbeat timeout ignores — the manager address it registered in
+// the last quorum refuses a connection from that instant. While members
+// that have asked are parked and only replicas healthy by heartbeat that
+// have not asked hold their quorum up (the straggler wait, or the
+// split-brain guard counting the same absentees against a majority) and
+// nothing has moved for two ticks, the tick thread connects, off the
+// state lock, to each such absentee that was a member of the previous
+// quorum. A connection
+// REFUSED expires that replica's heartbeat now — the edge sweep() takes
+// at heartbeat_timeout_ms, taken early; every other outcome changes
+// nothing and the timeout decides. The decision rules are untouched:
+// they act on the state after the expiry as they would act on it a
+// timeout later. Only what the lighthouse sees first-hand counts — no
+// survivor's report, no goodbye from the victim.
+//
 // Two-level tree: a lighthouse constructed with an upstream address is a
 // tier-1 aggregator for a domain (rack/ICI) of replica groups — it holds
 // the quorum for that domain and reports ONE membership summary upstream
@@ -133,6 +149,15 @@ struct JobState {
   // expiry sweep, install, evict) wakes parked EpochWatch waiters within
   // one tick instead of their next re-stamp interval.
   uint64_t watched_epoch = 0;
+  // Since when (monotonic ms) absentees have held the quorum up
+  // (QuorumDecision::absent) with nothing moving: every membership edge
+  // — a member asking, a heartbeat's first sighting, an expiry — starts
+  // the count again (held_epoch); -1 = they do not hold it. The
+  // door-knock waits two ticks of this: in a steady step the members ask
+  // within milliseconds of each other and a live manager is owed no
+  // connection a step.
+  int64_t held_since_ms = -1;
+  uint64_t held_epoch = 0;
 
   // Admission registration (RegisterJob, or fields riding a Quorum
   // request body; last writer wins).
@@ -161,6 +186,8 @@ struct JobState {
   uint64_t lease_breaks = 0;
   uint64_t preemptions = 0;       // groups evicted FROM this job
   uint64_t rate_limit_drops = 0;  // heartbeats dropped over rpc_budget
+  uint64_t door_knocks = 0;       // connections tried to a straggler's manager
+  uint64_t refused_expiries = 0;  // heartbeats expired early on a refusal
 };
 
 class Lighthouse {
@@ -191,6 +218,23 @@ class Lighthouse {
   // must hold mu_.
   void tick_job_locked(JobState& job);
   void tick_loop();
+  // The door-knock, in three steps around the tick thread's unlocked
+  // connects. collect: every job held up by absentees for two ticks
+  // gives the previous-quorum members that are healthy and have not
+  // asked, with the heartbeat stamp seen. apply: a refusal expires the
+  // replica unless it has beaten or asked meanwhile, and the job is
+  // ticked again so its parked members get their quorum in this tick.
+  // Both hold mu_.
+  struct DoorKnock {
+    std::string job_id;
+    std::string replica_id;
+    std::string address;
+    int64_t heartbeat_ms;
+  };
+  std::vector<DoorKnock> collect_knocks_locked(int64_t now_ms);
+  void apply_knocks_locked(const std::vector<DoorKnock>& knocks,
+                           const std::vector<fthttp::Knock>& found,
+                           int64_t now_ms);
   // Admission check after `claimant` gained a member: while the fleet is
   // over capacity, evict one group from the lowest-priority over-budget
   // job with priority strictly below the claimant's. Caller holds mu_.

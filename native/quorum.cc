@@ -204,7 +204,8 @@ QuorumDecision quorum_compute(int64_t now_ms, const QuorumState& state,
   if (healthy_participants.size() <= healthy_replicas.size() / 2) {
     return {std::nullopt,
             reason_split_brain(healthy_participants.size(),
-                               healthy_replicas.size(), meta)};
+                               healthy_replicas.size(), meta),
+            healthy_replicas.size() - healthy_participants.size()};
   }
 
   bool all_healthy_joined =
@@ -215,11 +216,10 @@ QuorumDecision quorum_compute(int64_t now_ms, const QuorumState& state,
   }
   if (!all_healthy_joined &&
       now_ms - first_joined < static_cast<int64_t>(opts.join_timeout_ms)) {
+    size_t absent = healthy_replicas.size() - healthy_participants.size();
     return {std::nullopt,
-            reason_stragglers(
-                healthy_participants.size(),
-                healthy_replicas.size() - healthy_participants.size(),
-                meta)};
+            reason_stragglers(healthy_participants.size(), absent, meta),
+            absent};
   }
 
   return {candidates, reason_valid(meta)};
@@ -260,6 +260,15 @@ void IncrementalQuorum::remove_healthy_participant(
   // next decision is bounded by the same edge count as the recompute
   // itself.
   if (!first_dirty_ && d.joined_ms == hp_first_joined_) first_dirty_ = true;
+}
+
+bool IncrementalQuorum::drop_healthy(const std::string& replica_id) {
+  if (!healthy_.erase(replica_id)) return false;
+  auto pit = state_.participants.find(replica_id);
+  if (pit != state_.participants.end()) {
+    remove_healthy_participant(pit->second);
+  }
+  return true;
 }
 
 int64_t IncrementalQuorum::first_joined(int64_t now_ms) {
@@ -328,14 +337,7 @@ void IncrementalQuorum::sweep(int64_t now_ms) {
       ++it;
       continue;
     }
-    // alive->dead edge.
-    if (healthy_.erase(it->first)) {
-      epoch_ += 1;
-      auto pit = state_.participants.find(it->first);
-      if (pit != state_.participants.end()) {
-        remove_healthy_participant(pit->second);
-      }
-    }
+    if (drop_healthy(it->first)) epoch_ += 1;  // alive->dead edge
     if (age >= prune_after_ms_) {
       // Long-dead: drop the heartbeat entry AND any stale participant
       // record so neither the decision scan nor /status.json grows
@@ -390,14 +392,15 @@ void IncrementalQuorum::evaluate(int64_t now_ms) {
     return;
   }
   if (hp <= hb / 2) {
-    cached_ = {std::nullopt, reason_split_brain(hp, hb, meta)};
+    cached_ = {std::nullopt, reason_split_brain(hp, hb, meta), hb - hp};
     return;
   }
   if (hp != hb) {
     int64_t first = first_joined(now_ms);
     int64_t matures = first + static_cast<int64_t>(opts_.join_timeout_ms);
     if (now_ms < matures) {
-      cached_ = {std::nullopt, reason_stragglers(hp, hb - hp, meta)};
+      cached_ = {std::nullopt, reason_stragglers(hp, hb - hp, meta),
+                 hb - hp};
       // The only decision transition driven purely by time passing with
       // no state edge: the join timeout maturing.
       cache_deadline_ms_ = matures;
@@ -427,14 +430,7 @@ const QuorumDecision& IncrementalQuorum::decision(int64_t now_ms) {
 }
 
 bool IncrementalQuorum::evict(const std::string& replica_id) {
-  bool erased = false;
-  if (healthy_.erase(replica_id)) {
-    auto pit = state_.participants.find(replica_id);
-    if (pit != state_.participants.end()) {
-      remove_healthy_participant(pit->second);
-    }
-    erased = true;
-  }
+  bool erased = drop_healthy(replica_id);
   if (state_.participants.erase(replica_id)) {
     // participants.size() appears in the decision meta string.
     erased = true;
@@ -442,6 +438,16 @@ bool IncrementalQuorum::evict(const std::string& replica_id) {
   if (state_.heartbeats.erase(replica_id)) erased = true;
   if (erased) epoch_ += 1;
   return erased;
+}
+
+bool IncrementalQuorum::expire(const std::string& replica_id,
+                               int64_t now_ms) {
+  if (!drop_healthy(replica_id)) return false;
+  int64_t stamp = now_ms - static_cast<int64_t>(opts_.heartbeat_timeout_ms);
+  state_.heartbeats[replica_id] = stamp;
+  next_prune_ms_ = std::min(next_prune_ms_, stamp + prune_after_ms_);
+  epoch_ += 1;
+  return true;
 }
 
 const QuorumInfo& IncrementalQuorum::install(

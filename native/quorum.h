@@ -77,6 +77,12 @@ struct QuorumOpts {
 struct QuorumDecision {
   std::optional<std::vector<Member>> quorum;  // nullopt = not ready
   std::string reason;
+  // How many replicas are healthy by heartbeat and have not asked, when
+  // they are what holds the quorum up: the straggler wait, and the
+  // split-brain guard (the same absentees, counted against a majority).
+  // 0 for every other decision. The holds the lighthouse's door-knock
+  // acts on; not part of decision_to_json.
+  size_t absent = 0;
 };
 
 // Membership (replica-id set) comparison: a quorum "changed" only when the
@@ -167,6 +173,14 @@ class IncrementalQuorum {
   // evicted member (not a fast quorum, but hp==hb once the survivors
   // rejoin, so no join-timeout stall).
   bool evict(const std::string& replica_id);
+  // Early expiry (the lighthouse's door-knock saw the replica's manager
+  // address refuse a connection): the alive->dead edge sweep() takes at
+  // heartbeat_timeout_ms, taken at now_ms. The heartbeat entry is aged
+  // to exactly the timeout, so quorum_compute over state() and
+  // evaluate() keep agreeing, /status.json shows it dead, and pruning
+  // counts from here. A later heartbeat() revives it like any dead id.
+  // Returns false (nothing changes) if the replica is not healthy.
+  bool expire(const std::string& replica_id, int64_t now_ms);
 
   // The decision at now_ms, served from cache when the epoch is
   // unchanged and no time deadline passed.
@@ -192,6 +206,10 @@ class IncrementalQuorum {
   // changed: fold it into (or out of) the healthy-participant aggregates.
   void add_healthy_participant(const ParticipantDetails& d);
   void remove_healthy_participant(const ParticipantDetails& d);
+  // The alive->dead edge shared by sweep(), expire() and evict(): out of
+  // the healthy set and, if it had asked, out of the aggregates. False if
+  // it was not healthy. The caller bumps the epoch.
+  bool drop_healthy(const std::string& replica_id);
   int64_t first_joined(int64_t now_ms);
   std::vector<Member> materialize(bool shrink_filter) const;
   void evaluate(int64_t now_ms);

@@ -658,3 +658,354 @@ def test_control_plane_connection_reuse() -> None:
     finally:
         mgr.shutdown()
         lh.shutdown()
+
+
+# ------------------------------------------------------------ the door-knock
+#
+# A member of the last quorum that holds a new one up and whose manager
+# address REFUSES a connection is expired at once; every other outcome is
+# left to the heartbeat timeout. The timeout here is one no test waits
+# for, so "formed" always means "formed while that heartbeat was fresh".
+
+_KNOCK_HB_TIMEOUT_MS = 30000
+_KNOCK_SOON_S = _KNOCK_HB_TIMEOUT_MS / 1000 / 3
+
+
+def _knock_lighthouse(**kwargs):
+    return Lighthouse(min_replicas=1, join_timeout_ms=60000,
+                      heartbeat_timeout_ms=_KNOCK_HB_TIMEOUT_MS, **kwargs)
+
+
+def _hand_member(replica_id, address):
+    return {"replica_id": replica_id, "address": address,
+            "store_address": f"store:{replica_id}", "step": 1,
+            "world_size": 1, "shrink_only": False}
+
+
+def _first_quorum(pool, lh, n):
+    """n real ManagerServers, all in the lighthouse's last quorum."""
+    mgrs = [_make_manager(lh, f"r{i}") for i in range(n)]
+    clients = [ManagerClient(m.address()) for m in mgrs]
+    first = list(pool.map(
+        lambda c: c.quorum(0, 1, "", False, 20.0), clients))
+    assert [r.replica_world_size for r in first] == [n] * n
+    return mgrs, clients
+
+
+def _ask(pool, clients, step=2):
+    return [pool.submit(c.quorum, 0, step, "", False, 20.0) for c in clients]
+
+
+def _wait_status(addr, pred, timeout=_KNOCK_SOON_S):
+    deadline = time.monotonic() + timeout
+    while True:
+        status = _status_json(addr)
+        if pred(status) or time.monotonic() > deadline:
+            return status
+        time.sleep(0.02)
+
+
+class _Listener:
+    """A manager address that is no manager: ``accepts`` takes every
+    connection and never answers; otherwise its backlog is full, so a
+    connect gets no answer at all (what a silent host looks like)."""
+
+    def __init__(self, accepts: bool) -> None:
+        import socket
+
+        self.sock = socket.socket()
+        self.sock.bind(("127.0.0.1", 0))
+        self.sock.listen(16 if accepts else 0)
+        self.address = f"http://127.0.0.1:{self.sock.getsockname()[1]}"
+        self.held = []
+        if accepts:
+            self.sock.settimeout(0.05)
+            self.stop = threading.Event()
+            self.thread = threading.Thread(target=self._accept, daemon=True)
+            self.thread.start()
+        else:
+            for _ in range(6):  # more than a backlog of 0 ever admits
+                c = socket.socket()
+                c.setblocking(False)
+                c.connect_ex(self.sock.getsockname())
+                self.held.append(c)
+
+    def _accept(self) -> None:
+        while not self.stop.is_set():
+            try:
+                self.held.append(self.sock.accept()[0])
+            except OSError:
+                pass
+
+    def close(self) -> None:
+        if hasattr(self, "stop"):
+            self.stop.set()
+            self.thread.join()
+        for c in self.held + [self.sock]:
+            c.close()
+
+
+def _knock_refused(pool, lh):
+    # a previous member whose server was shut is expired, and the rest
+    # get their quorum
+    mgrs, clients = _first_quorum(pool, lh, 4)
+    try:
+        mgrs[3].shutdown()
+        t0 = time.monotonic()
+        second = [f.result(timeout=20) for f in _ask(pool, clients[:3])]
+        assert time.monotonic() - t0 < _KNOCK_SOON_S
+        assert [r.replica_world_size for r in second] == [3] * 3
+        status = _status_json(lh.address())
+        assert status["heartbeats"]["r3"]["dead"] is True
+        assert status["heartbeats"]["r0"]["dead"] is False
+        job = status["jobs"]["default"]
+        assert job["refused_expiries"] == 1 and job["door_knocks"] >= 1
+        assert status["control"]["refused_expiries"] == 1
+        assert status["control"]["door_knocks"] == job["door_knocks"]
+        assert "[3 heartbeating]" in job["reason"]
+        # an expired id that heartbeats again is healthy again
+        lighthouse_heartbeat(lh.address(), "r3")
+        status = _status_json(lh.address())
+        assert status["heartbeats"]["r3"]["dead"] is False
+        assert status["control"]["healthy_replicas"] == 4
+    finally:
+        for m in mgrs:
+            m.shutdown()
+
+
+def _knock_listening(pool, lh):
+    # a previous member that listens but does not ask is waited for
+    mgrs, clients = _first_quorum(pool, lh, 4)
+    try:
+        futs = _ask(pool, clients[:3])
+        status = _wait_status(
+            lh.address(), lambda s: s["control"]["door_knocks"] >= 3)
+        assert status["control"]["door_knocks"] >= 3
+        assert status["control"]["refused_expiries"] == 0
+        assert status["heartbeats"]["r3"]["dead"] is False
+        assert "stragglers" in status["reason"]
+        assert not any(f.done() for f in futs)
+        futs += _ask(pool, clients[3:])
+        assert [f.result(timeout=20).replica_world_size for f in futs] == [4] * 4
+    finally:
+        for m in mgrs:
+            m.shutdown()
+
+
+def _knock_waits_for_a_lull(pool, lh):
+    # every member that asks starts the two ticks again: four that ask
+    # 0.3 s apart hold the quorum up for 0.9 s, more than two ticks of
+    # 0.4 s, and no live manager gets a connection for it
+    mgrs, clients = _first_quorum(pool, lh, 4)
+    try:
+        futs = []
+        for client in clients:
+            futs += _ask(pool, [client])
+            time.sleep(0.3)
+        assert [f.result(timeout=20).replica_world_size for f in futs] == [4] * 4
+        assert _status_json(lh.address())["control"]["door_knocks"] == 0
+    finally:
+        for m in mgrs:
+            m.shutdown()
+
+
+def _knock_two_of_four(pool, lh):
+    # two of four refused leaves two: the split-brain guard, which holds
+    # two askers against four heartbeats, decides on the state after the
+    # expiries as it would after the timeout
+    mgrs, clients = _first_quorum(pool, lh, 4)
+    try:
+        mgrs[2].shutdown()
+        mgrs[3].shutdown()
+        t0 = time.monotonic()
+        second = [f.result(timeout=20) for f in _ask(pool, clients[:2])]
+        assert time.monotonic() - t0 < _KNOCK_SOON_S
+        assert [r.replica_world_size for r in second] == [2] * 2
+        assert _status_json(lh.address())["control"]["refused_expiries"] == 2
+    finally:
+        for m in mgrs:
+            m.shutdown()
+
+
+def _knock_heartbeat_only(pool, lh):
+    # a replica known by heartbeat only is never knocked: once the shut
+    # member is expired, one asker is still held by one heartbeat and
+    # nobody's door is left to knock on
+    mgrs, clients = _first_quorum(pool, lh, 2)
+    try:
+        lighthouse_heartbeat(lh.address(), "beats_only")
+        mgrs[1].shutdown()
+        (fut,) = _ask(pool, clients[:1])
+        status = _wait_status(
+            lh.address(), lambda s: s["control"]["refused_expiries"] == 1)
+        assert status["control"]["refused_expiries"] == 1
+        knocks = status["control"]["door_knocks"]
+        time.sleep(0.6)  # six ticks of the same hold
+        status = _status_json(lh.address())
+        assert status["control"]["door_knocks"] == knocks
+        assert status["heartbeats"]["beats_only"]["dead"] is False
+        assert "need at least half of 2" in status["reason"]
+        assert not fut.done()
+        # the heartbeat turns out to be a replica: it asks, both get one
+        late = pool.submit(lighthouse_quorum, lh.address(),
+                           _hand_member("beats_only", "addr"), 20.0)
+        assert fut.result(timeout=20).replica_world_size == 2
+        late.result(timeout=20)
+    finally:
+        for m in mgrs:
+            m.shutdown()
+
+
+def _knock_leaves_alone(address, knocked):
+    def scenario(pool, lh):
+        # a hand-made member with this address is in the last quorum and
+        # then does not ask: nothing expires
+        addr = lh.address()
+        mgr = _make_manager(lh, "r0")
+        client = ManagerClient(mgr.address())
+        listener = address if isinstance(address, str) else address()
+        try:
+            absent = _hand_member(
+                "absent", getattr(listener, "address", listener))
+            lighthouse_heartbeat(addr, "absent")
+            first = [pool.submit(client.quorum, 0, 1, "", False, 20.0),
+                     pool.submit(lighthouse_quorum, addr, absent, 20.0)]
+            assert first[0].result(timeout=20).replica_world_size == 2
+            first[1].result(timeout=20)
+            (fut,) = _ask(pool, [client])
+            time.sleep(0.8)  # the hold is two ticks old after 0.2 s
+            status = _status_json(addr)
+            assert (status["control"]["door_knocks"] > 0) is knocked
+            assert status["control"]["refused_expiries"] == 0
+            assert status["heartbeats"]["absent"]["dead"] is False
+            assert not fut.done()
+            late = pool.submit(lighthouse_quorum, addr, absent, 20.0)
+            assert fut.result(timeout=20).replica_world_size == 2
+            late.result(timeout=20)
+        finally:
+            mgr.shutdown()
+            if not isinstance(listener, str):
+                listener.close()
+    return scenario
+
+
+def _knock_holds_no_lock(pool, lh):
+    # the tick thread holds no lock while it connects: one absentee
+    # accepts and never answers, the other answers nothing at all, so
+    # every tick's knock lasts the whole tick, and no heartbeat waits
+    addr = lh.address()
+    mgr = _make_manager(lh, "r0")
+    client = ManagerClient(mgr.address())
+    listeners = [_Listener(accepts=True), _Listener(accepts=False)]
+    try:
+        absent = [_hand_member(f"absent{i}", l.address)
+                  for i, l in enumerate(listeners)]
+        for m in absent:
+            lighthouse_heartbeat(addr, m["replica_id"])
+        first = [pool.submit(client.quorum, 0, 1, "", False, 20.0)] + [
+            pool.submit(lighthouse_quorum, addr, m, 20.0) for m in absent]
+        assert first[0].result(timeout=20).replica_world_size == 3
+        # known before the hold: a first sighting would start its count anew
+        lighthouse_heartbeat(addr, "bystander")
+        (fut,) = _ask(pool, [client])
+        _wait_status(addr, lambda s: s["control"]["door_knocks"] >= 2)
+        slowest = 0.0
+        t_end = time.monotonic() + 2.0  # five ticks of 400 ms
+        while time.monotonic() < t_end:
+            t0 = time.monotonic()
+            lighthouse_heartbeat(addr, "bystander")
+            slowest = max(slowest, time.monotonic() - t0)
+            time.sleep(0.01)
+        status = _status_json(addr)
+        assert status["control"]["door_knocks"] >= 6, status["control"]
+        assert status["control"]["refused_expiries"] == 0
+        assert slowest < 0.2, slowest
+        assert not fut.done()
+        late = [pool.submit(lighthouse_quorum, addr, m, 20.0) for m in absent]
+        assert fut.result(timeout=20).replica_world_size == 3
+        for f in late:
+            f.result(timeout=20)
+    finally:
+        mgr.shutdown()
+        for l in listeners:
+            l.close()
+
+
+def _knock_kernels_agree(pool, lh):
+    # quorum_compute and the incremental evaluator give byte-equal
+    # decisions before and after an early expiry, and the guards decide
+    # on the state after it
+    import json
+
+    from torchft_tpu.control import IncrementalQuorum, quorum_compute_raw
+
+    opts = {"min_replicas": 1, "join_timeout_ms": 60000,
+            "heartbeat_timeout_ms": _KNOCK_HB_TIMEOUT_MS}
+    for incremental in (True, False):
+        iq = IncrementalQuorum(opts, incremental=incremental)
+        now = 100_000
+        ids = [f"r{i}" for i in range(4)]
+        for rid in ids:
+            iq.heartbeat(rid, now)
+            iq.join(now, _hand_member(rid, f"addr_{rid}"))
+        assert iq.install(now)["installed"]
+        reasons = []
+
+        def agree():
+            decision = iq.decision(now)
+            assert decision == quorum_compute_raw(now, iq.state(), opts)
+            reasons.append(json.loads(decision))
+
+        for rid in ids[:2]:
+            iq.heartbeat(rid, now)
+            iq.join(now, _hand_member(rid, f"addr_{rid}"))
+        agree()
+        assert "need at least half of 4" in reasons[-1]["reason"]
+        epoch = iq.counters()["epoch"]
+        assert iq.expire("r3", now + 10) is True
+        assert iq.expire("r3", now + 10) is False  # it is dead already
+        assert iq.expire("never_seen", now + 10) is False
+        assert iq.counters()["epoch"] == epoch + 1
+        now += 10
+        agree()
+        assert "waiting for 1 healthy" in reasons[-1]["reason"]
+        assert iq.expire("r2", now)
+        agree()
+        assert [m["replica_id"] for m in reasons[-1]["quorum"]] == ids[:2]
+        assert iq.counters()["healthy"] == 2
+        # the heartbeat entry is aged to the timeout, no further
+        assert json.loads(iq.state())["heartbeats"]["r3"] == (
+            now - _KNOCK_HB_TIMEOUT_MS)
+        iq.heartbeat("r3", now + 1)  # dead -> alive, as after any expiry
+        now += 1
+        agree()
+        assert iq.counters()["healthy"] == 3
+        assert reasons[-1]["quorum"] is None
+
+
+_DOOR_KNOCK_CASES = {
+    "refused_is_expired_and_a_heartbeat_revives": _knock_refused,
+    "listening_and_silent_is_waited_for": _knock_listening,
+    "two_of_four_refused_leaves_two": _knock_two_of_four,
+    "members_asking_in_turn_restart_the_two_ticks": _knock_waits_for_a_lull,
+    "known_by_heartbeat_only_is_never_knocked": _knock_heartbeat_only,
+    "address_that_does_not_parse": _knock_leaves_alone("addr_0", False),
+    "address_that_does_not_resolve": _knock_leaves_alone(
+        "http://no-such-manager.invalid:29500", False),
+    "address_that_times_out": _knock_leaves_alone(
+        lambda: _Listener(accepts=False), True),
+    "tick_thread_connects_off_the_lock": _knock_holds_no_lock,
+    "both_kernels_agree_around_an_early_expiry": _knock_kernels_agree,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DOOR_KNOCK_CASES))
+def test_door_knock(case) -> None:
+    slow = "off_the_lock" in case or "in_turn" in case
+    tick = {"quorum_tick_ms": 400} if slow else {}
+    lh = _knock_lighthouse(**tick)
+    try:
+        with ThreadPoolExecutor(max_workers=8) as pool:
+            _DOOR_KNOCK_CASES[case](pool, lh)
+    finally:
+        lh.shutdown()

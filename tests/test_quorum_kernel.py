@@ -441,7 +441,7 @@ def test_scale_prev_quorum_tie_break_stable_under_churn() -> None:
 def _iq_random_sequence(seed: int, n_replicas: int, ops: int,
                         incremental: bool = True):
     """Drive the native IncrementalQuorum through a random monotonic
-    heartbeat/join/expiry/install sequence, checking at every step that
+    heartbeat/join/expiry/early-expiry/install sequence, checking at every step that
     its decision JSON is byte-identical to a from-scratch kernel
     recompute over the dumped state. Returns (iq, mismatches, checks)."""
     import random
@@ -464,10 +464,13 @@ def _iq_random_sequence(seed: int, n_replicas: int, ops: int,
         rid = rng.choice(ids)
         if op < 0.35:
             iq.heartbeat(rid, now)
-        elif op < 0.75:
+        elif op < 0.72:
             iq.heartbeat(rid, now)
             iq.join(now, member(rid, step=rng.randrange(3),
                                 shrink_only=rng.random() < 0.05))
+        elif op < 0.77:
+            # the door-knock's early expiry, of a live id or a dead one
+            iq.expire(rid, now)
         elif op < 0.85:
             # time jump: some heartbeats expire (and may be pruned)
             now += rng.choice([5001, 10000, 70000])

@@ -349,13 +349,23 @@ def test_sharded_2d_kill_shrink_rejoin_lower_bound() -> None:
     assert replicas[0].failures == 1
 
     # -- per-survivor rank history, from events alone -------------------
-    def _rank_at(events: List[dict], world: int) -> int:
+    def _reshards(events: List[dict], world: int) -> List[dict]:
+        """Reshards onto a ``world``-wire grid: the last one before the
+        kill for the full grid (replicas that start 200 ms apart grow
+        2 -> 3 first), the ones after it for the shrunken grid."""
+        death = [e["seq"] for e in events if e["kind"] == "member_dead"]
+        assert death, "the kill left no member_dead event"
         resh = [
             e for e in events
             if e["kind"] == "reshard" and e.get("new_world") == world
+            and (e["seq"] > death[0]) == (world == 2)
         ]
         assert resh, f"no reshard onto the {world}-wire grid"
-        return int(resh[0]["rank"])
+        return resh
+
+    def _rank_at(events: List[dict], world: int) -> int:
+        resh = _reshards(events, world)
+        return int(resh[0 if world == 2 else -1]["rank"])
 
     surv_events = {
         rid: _events_of(replicas[rid].telemetry[-1]) for rid in (1, 2)
@@ -380,10 +390,7 @@ def test_sharded_2d_kill_shrink_rejoin_lower_bound() -> None:
     assert plan.lower_bound_bytes == plan.moved_bytes
 
     for rid in (1, 2):
-        shrink = [
-            e for e in surv_events[rid]
-            if e["kind"] == "reshard" and e.get("new_world") == 2
-        ][0]
+        shrink = _reshards(surv_events[rid], 2)[0]
         expected = plan.lower_bound_bytes.get(new_rank[rid], 0)
         assert shrink["mesh_shape"] == f"2x{M}"
         assert shrink["lower_bound_bytes"] == expected, (

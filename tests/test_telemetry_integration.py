@@ -136,6 +136,12 @@ class _Replica:
                     self.harness.report(
                         self.replica_id, manager.current_step()
                     )
+                    # the step's compute: a survivor is rid of a killed
+                    # peer within two lighthouse ticks and then steps
+                    # alone until the restart is back; at a step a
+                    # millisecond its event ring (4096) would lose the
+                    # kill the assertions below read
+                    time.sleep(0.005)
                 else:
                     time.sleep(0.01)
         finally:

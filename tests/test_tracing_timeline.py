@@ -82,13 +82,21 @@ def test_span_leaves_a_tft_event_with_replica_and_step(tmp_path) -> None:
 # ------------------------------------------------------ recovery episodes
 
 _HEARTBEAT_TIMEOUT_MS = 1000
+# How a group dies, and the heartbeat timeout its test runs under.
+# ``killed``: its process is gone and its host lives, so its manager
+# address refuses a connection and the lighthouse's door-knock expires it
+# at once; the timeout is one no test waits for. ``hung``: it stops
+# stepping and beating while its manager address stays open, so the
+# knock is answered and only the heartbeat timeout tells.
+_DEATHS = {"killed": 30000, "hung": _HEARTBEAT_TIMEOUT_MS}
 
 
 class _Replica:
     """One replica group's training loop on a thread: quorum, averaged
     "gradient", commit."""
 
-    def __init__(self, name: str, value: float, lighthouse_addr: str) -> None:
+    def __init__(self, name: str, value: float, lighthouse_addr: str,
+                 will_hang: bool = False) -> None:
         self.store = StoreServer()
         self.state = {"w": np.full((4,), value, np.float32)}
         # weights as committed, by the step they made: two free-running
@@ -104,8 +112,11 @@ class _Replica:
             timeout=5.0, quorum_timeout=20.0, connect_timeout=10.0,
             rank=0, world_size=1, store_addr=self.store.addr,
             lighthouse_addr=lighthouse_addr, replica_id=f"tl_{name}_",
-            heartbeat_interval=0.05,
+            # one that will hang lives by its quorum requests alone, so
+            # that its last step is its last sign of life
+            heartbeat_interval=3600.0 if will_hang else 0.05,
         )
+        self.held_open: Any = None
         self.thread = threading.Thread(target=self._run, daemon=True)
         self.thread.start()
 
@@ -127,8 +138,13 @@ class _Replica:
                     return
                 time.sleep(0.05)
 
-    def kill(self) -> None:
+    def kill(self, hang: bool = False) -> None:
         self.stop.set()
+        if hang:
+            self.held_open, self.manager._manager = self.manager._manager, None
+        elif self.held_open is not None:
+            self.held_open.shutdown()
+            self.held_open = None
         self.manager.shutdown(wait=False)
         self.store.shutdown()
 
@@ -148,21 +164,22 @@ def _equal_at_last_common_step(a: _Replica, b: _Replica) -> bool:
     return np.array_equal(a.committed[step], b.committed[step])
 
 
-@pytest.fixture(scope="module")
-def kill_and_rejoin():
+@pytest.fixture(scope="module", params=sorted(_DEATHS))
+def kill_and_rejoin(request):
     """Two groups; one is torn down, the survivor runs on alone, a
     replacement with other weights joins, then 20 steady steps."""
+    death = request.param
     lh = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                    heartbeat_timeout_ms=_HEARTBEAT_TIMEOUT_MS)
+                    heartbeat_timeout_ms=_DEATHS[death])
     live: List[_Replica] = []
     try:
         survivor = _Replica("a", 1.0, lh.address())
-        victim = _Replica("b", 1.0, lh.address())
+        victim = _Replica("b", 1.0, lh.address(), will_hang=death == "hung")
         live += [survivor, victim]
         survivor.run_to(8)
         victim.run_to(8)
         n_before_kill = len(survivor.episodes())
-        victim.kill()
+        victim.kill(hang=death == "hung")
         survivor.run_to(survivor.manager.current_step() + 8)
         n_alone = len(survivor.episodes())
         replacement = _Replica("c", 99.0, lh.address())
@@ -173,6 +190,7 @@ def kill_and_rejoin():
         snapshot = survivor.manager.metrics.snapshot()
         survivor.run_to(survivor.manager.current_step() + 20)
         yield {
+            "death": death,
             "after_kill": survivor.episodes()[n_before_kill:n_alone],
             "after_rejoin": survivor.episodes()[n_alone:n_rejoined],
             "steady": survivor.episodes()[n_rejoined:],
@@ -196,10 +214,16 @@ def test_survivor_emits_exactly_one_shrink_episode(kill_and_rejoin) -> None:
     assert episode["episode"] == "shrink"
     assert (episode["members_before"], episode["members_after"]) == (2, 1)
     assert (episode["left"], episode["joined"]) == (1, 0)
-    # the survivor could not form a quorum before the dead group's
-    # heartbeat had expired (its last beat is at most one interval old)
-    assert episode["quorum_wait_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
-    assert episode["quorum_wait_ms"] > 0.8 * episode["gap_ms"]
+    if kill_and_rejoin["death"] == "hung":
+        # the survivor could not form a quorum before the dead group's
+        # heartbeat had expired (its last beat is at most one interval old)
+        assert episode["quorum_wait_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
+        assert episode["quorum_wait_ms"] > 0.8 * episode["gap_ms"]
+    else:
+        # the lighthouse knocked once the survivor had waited two ticks,
+        # was refused, and dropped the dead group while its heartbeat
+        # was fresh
+        assert 190 <= episode["quorum_wait_ms"] < _DEATHS["killed"] / 3
     assert episode["configure_ms"] <= episode["quorum_wait_ms"]
 
 
@@ -245,15 +269,61 @@ def test_episode_phases_are_timings_of_the_managers_sink(
             shrink[f"{phase}_ms"], abs=1e-3)
     assert "episode_grow_gap_max_ms" in snap
     assert "episode_rejoin_init_max_ms" in snap      # its own start
-    assert snap["quorum_wait_max_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
+    if kill_and_rejoin["death"] == "hung":
+        assert snap["quorum_wait_max_ms"] >= _HEARTBEAT_TIMEOUT_MS - 100
+    else:
+        assert snap["quorum_wait_max_ms"] < _DEATHS["killed"] / 3
     assert snap["replica_id"].startswith("tl_a_")
+
+
+def test_three_survivors_commit_on_while_a_killed_groups_heartbeat_is_fresh(
+) -> None:
+    """Four groups under the kill cell's lighthouse settings but for a
+    heartbeat timeout no test waits for; one is torn down as
+    ``benchmark.group.ReplicaGroup.teardown`` does it. The three others
+    ask, are held as three of four heartbeats, the lighthouse knocks on
+    the fourth's manager address and is refused, and they step on."""
+    import json
+    import urllib.request
+
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=60000,
+                    heartbeat_timeout_ms=_DEATHS["killed"])
+    groups: List[_Replica] = []
+    try:
+        for name in "abcd":
+            groups.append(_Replica(name, 1.0, lh.address()))
+        for g in groups:
+            g.run_to(8)
+        survivors, victim = groups[:3], groups[3]
+        seen = [len(g.episodes()) for g in survivors]
+        t_kill = time.monotonic()
+        victim.kill()
+        for g in survivors:
+            g.run_to(g.manager.current_step() + 3)
+        assert time.monotonic() - t_kill < _DEATHS["killed"] / 1e3 / 3
+        for g, n in zip(survivors, seen):
+            (episode,) = g.episodes()[n:]
+            assert episode["episode"] == "shrink"
+            assert (episode["members_before"],
+                    episode["members_after"]) == (4, 3)
+            assert episode["quorum_wait_ms"] < _DEATHS["killed"] / 3
+        with urllib.request.urlopen(lh.address() + "/status.json") as resp:
+            control = json.load(resp)["control"]
+        assert control["refused_expiries"] == 1
+        assert control["door_knocks"] >= 1
+    finally:
+        for g in groups:
+            g.kill()
+        lh.shutdown()
 
 
 @pytest.fixture(scope="module")
 def merged_quorum():
-    """Two groups; one is torn down and its replacement is started at
+    """Two groups; one hangs and its replacement is started at
     once, so it asks for a quorum while the dead group's heartbeat still
-    counts: ONE quorum drops ``b`` and admits ``c``."""
+    counts: ONE quorum drops ``b`` and admits ``c``. (A group that is
+    killed on a live host is dropped within two ticks of the survivor's
+    asking, before any replacement can have started.)"""
     # the dead group's heartbeat outlives the replacement's start-up on
     # any machine; nothing below waits for it to expire
     lh = Lighthouse(min_replicas=1, join_timeout_ms=200,
@@ -261,14 +331,14 @@ def merged_quorum():
     live: List[_Replica] = []
     try:
         survivor = _Replica("a", 1.0, lh.address())
-        victim = _Replica("b", 1.0, lh.address())
+        victim = _Replica("b", 1.0, lh.address(), will_hang=True)
         live += [survivor, victim]
         survivor.run_to(8)
         victim.run_to(8)
         n_before_kill = len(survivor.episodes())
         # the sink then holds this recovery alone, as a window's does
         survivor.manager.metrics.reset_timings()
-        victim.kill()
+        victim.kill(hang=True)
         replacement = _Replica("c", 99.0, lh.address())
         live.append(replacement)
         replacement.run_to(survivor.manager.current_step() + 5)

@@ -556,6 +556,14 @@ class IncrementalQuorum:
     def heartbeat(self, replica_id: str, now_ms: int) -> None:
         get_lib().ft_iq_heartbeat(self._handle, replica_id.encode(), now_ms)
 
+    def expire(self, replica_id: str, now_ms: int) -> bool:
+        """The door-knock's early expiry: the alive->dead edge the sweep
+        takes at heartbeat_timeout_ms, taken at ``now_ms``. False (and
+        nothing changes) if the replica is not healthy."""
+        return bool(
+            get_lib().ft_iq_expire(self._handle, replica_id.encode(), now_ms)
+        )
+
     def join(self, joined_ms: int, member: dict) -> None:
         err = ctypes.c_char_p()
         get_lib().ft_iq_join(

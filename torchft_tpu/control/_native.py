@@ -214,6 +214,8 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.ft_iq_free.restype = None
     lib.ft_iq_heartbeat.argtypes = [c_void_p, c_char_p, c_i64]
     lib.ft_iq_heartbeat.restype = None
+    lib.ft_iq_expire.argtypes = [c_void_p, c_char_p, c_i64]
+    lib.ft_iq_expire.restype = c_int
     lib.ft_iq_join.argtypes = [c_void_p, c_i64, c_char_p, err_p]
     lib.ft_iq_join.restype = c_int
     lib.ft_iq_decision.argtypes = [c_void_p, c_i64, err_p]
