@@ -1,0 +1,372 @@
+"""Nemotron-H (models/nemotron_h.py: a Mamba-2 mixer over ops/ssd.py, a
+GQA attention mixer without rotary embedding, a sigmoid top-k expert
+mixer of relu² experts with a balance bias and a shared expert; a layer
+is ONE mixer, which one is a letter of the config's pattern) against the
+plain float32 reference the benchmark keeps
+(benchmark/reference/nemotron_h_f32.py: the recurrence position by
+position), at a small size on the CPU: d 64, pattern ``ME*E``, 4 scan
+heads of 16 in 2 groups with a state of 16, 4 query heads on 2 key/value
+heads, 8 routed experts of width 32 of which 4 are held, top 2, S 64,
+seeded random weights. And the family through the one step maker, the
+one optimizer and the fault-tolerant loop: ``test_nemotron_h_family.py``
+(which says why that is a file of its own)."""
+
+import dataclasses
+import functools
+import hashlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.families import nemotron_h as family
+from benchmark.reference import nemotron_h_f32
+from torchft_tpu.models import joyai, nemotron_h, olmoe
+from torchft_tpu.ops import moe
+from torchft_tpu.ops.attention import reference_attention
+
+CFG = nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"]
+CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
+BIAS = nemotron_h.BALANCE_BIAS
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# tests/conftest.py: of the files that compile for minutes, one at a time
+pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
+
+
+def _params(cfg, seed, bias_std=0.1):
+    """Seeded weights with the balance biases away from zero, so that a
+    system that ignored them would route differently."""
+    params = nemotron_h.init_params(cfg, jax.random.key(seed))
+    key = jax.random.key(1000 + seed)
+
+    def leaf(path, x):
+        if path[-1].key != BIAS:
+            return x
+        return bias_std * jax.random.normal(
+            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
+
+
+def _batch(seed, rows=2):
+    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, 64), 0, 512)
+    return tokens, jnp.roll(tokens, -1, axis=1)
+
+
+def _bias_leaves(tree):
+    return [x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+            if getattr(path[-1], "key", None) == BIAS]
+
+
+def _chosen(experts, n_routed):
+    return jnp.any(jax.nn.one_hot(experts, n_routed, dtype=bool), axis=-2)
+
+
+def _reference(cfg):
+    return functools.partial(nemotron_h_f32.terms,
+                             **family.reference_dims(cfg))
+
+
+def _tiny_model(rows=2):
+    with open(os.path.join(ROOT, "benchmark", "tests",
+                           "tiny-nemotron.json")) as f:
+        config = json.load(f)
+    config["job"]["rows"] = rows
+    # a rate that moves the bias visibly within a few steps
+    config["optimizer"]["balance_bias_rate"] = 0.01
+    return family.build(config)
+
+
+# -- against the reference ---------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_f32_compute_equals_the_reference(seed) -> None:
+    params, (tokens, targets) = _params(CFG32, seed), _batch(seed)
+    got = jax.jit(functools.partial(nemotron_h.loss_terms, CFG32))(
+        params, tokens, targets)
+    want = jax.jit(_reference(CFG32))(params, tokens, targets)
+    assert np.array_equal(_chosen(got["experts"], CFG.n_routed_experts),
+                          want["chosen"])
+    assert float(got["loss"]) == pytest.approx(float(want["loss"]), abs=2e-5)
+    assert float(got["loss"]) == float(got["ce"])     # the carrier adds 0
+    np.testing.assert_allclose(got["hidden"], want["hidden"], atol=5e-5)
+    # what the check line prints: rows on this share's experts, their
+    # share of all assignments, and the busiest expert over the mean
+    loads = np.asarray(got["loads"])
+    assert loads.shape == (2, 8)
+    assert loads.sum(axis=-1).tolist() == [2 * 64 * CFG.top_k] * 2
+    assert np.array_equal(got["rows_held"], loads[:, :4].sum(axis=-1))
+    assert np.allclose(got["held_share"],
+                       loads[:, :4].sum(axis=-1) / (2 * 64 * CFG.top_k))
+    assert np.all((0 < got["held_share"]) & (got["held_share"] < 1))
+    assert np.allclose(got["load_max_over_mean"],
+                       loads.max(axis=-1) / loads.mean(axis=-1))
+
+
+def test_f32_gradients_equal_the_reference() -> None:
+    """A leaf of each kind of layer, and every other one too; the balance
+    bias has no gradient of the loss: its place carries the loads."""
+    params, (tokens, targets) = _params(CFG32, 3), _batch(3)
+    got = jax.jit(jax.grad(functools.partial(nemotron_h.loss_fn, CFG32)))(
+        params, tokens, targets)
+    want = jax.jit(jax.grad(functools.partial(
+        nemotron_h_f32.loss, **family.reference_dims(CFG32))))(
+            params, tokens, targets)
+    terms = nemotron_h.loss_terms(CFG32, params, tokens, targets)
+    assert np.array_equal(got["layers_1"]["moe"][BIAS], terms["loads"][0])
+    assert np.array_equal(got["layers_3"]["moe"][BIAS], terms["loads"][1])
+    assert not np.any(_bias_leaves(want))
+    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
+    seen = set()
+    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
+        if path[-1].key == BIAS:
+            continue
+        w = flat_want[path]
+        err = float(jnp.linalg.norm((g - w).ravel())
+                    / (jnp.linalg.norm(w.ravel()) + 1e-30))
+        assert err < 2e-4, (jax.tree_util.keystr(path), err)
+        assert float(jnp.linalg.norm(w.ravel())) > 0, path
+        seen.add(jax.tree_util.keystr(path))
+    for kind in ("in_proj", "conv']['kernel", "conv']['bias", "dt_bias",
+                 "A_log", "['D']", "mamba']['norm", "out_proj", "q_proj",
+                 "k_proj", "v_proj", "o_proj", "router", "up_proj",
+                 "down_proj", "shared", "wte", "lm_head", "ln_f"):
+        assert any(kind in s for s in seen), kind
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_bf16_compute_agrees_with_the_reference(seed) -> None:
+    """bf16 compute, 128 tokens, the cell's own comparison: the
+    reference is computed on the top-2 sets the system took, its own
+    choice is counted beside it, and every token is compared."""
+    params, (tokens, targets) = _params(CFG, seed), _batch(seed)
+    seen = family.per_token_errors(CFG, params, params, tokens, targets)
+    assert seen["error"].shape == (128,)
+    assert float(seen["disagreement"]) < 0.1
+    assert abs(float(seen["loss"]) - float(seen["reference_loss"])) < 2e-2
+    assert np.sqrt(np.mean(seen["error"] ** 2)) < 0.03
+    assert seen["error"].max() < 0.08
+
+
+def test_the_reference_follows_a_selection_and_still_says_its_own() -> None:
+    """``selection`` is what the expert layers are computed on; ``chosen``
+    stays the reference's own choice. Its own selection handed back
+    changes nothing; another one moves the hidden state, and the first
+    expert layer (whose input no selection has touched) still reports
+    the same choice."""
+    params, (tokens, targets) = _params(CFG32, 6), _batch(6)
+    ref = _reference(CFG32)
+    own = ref(params, tokens, targets)
+    again = ref(params, tokens, targets, selection=own["chosen"])
+    assert np.array_equal(again["hidden"], own["hidden"])
+    assert np.array_equal(again["chosen"], own["chosen"])
+    other = jnp.roll(own["chosen"], 1, axis=-1)       # every set moved on
+    moved = ref(params, tokens, targets, selection=other)
+    assert float(jnp.max(jnp.abs(moved["hidden"] - own["hidden"]))) > 1e-2
+    assert np.array_equal(moved["chosen"][0], own["chosen"][0])
+    assert not np.array_equal(moved["chosen"][1], own["chosen"][1])
+
+
+# -- the three mixers, each alone --------------------------------------------
+
+
+def test_a_key_value_head_serves_consecutive_query_heads() -> None:
+    """Query heads 0-1 read key/value head 0 and 2-3 head 1: zeroing
+    value head 1 zeroes exactly the second half of the heads' output."""
+    params, x = _params(CFG32, 2), jax.random.normal(
+        jax.random.key(3), (1, 16, 64), jnp.float32)
+    layer = params["layers_2"]
+    seen = {}
+
+    def spy(q, k, v):
+        seen["k"], seen["v"] = k, v
+        return reference_attention(q, k, v)
+
+    nemotron_h._attn_mixer(CFG32, layer, x, attn_fn=spy)
+    assert seen["k"].shape == (1, 16, 4, 16)
+    assert np.array_equal(seen["k"][:, :, 0], seen["k"][:, :, 1])
+    assert np.array_equal(seen["v"][:, :, 2], seen["v"][:, :, 3])
+    assert not np.array_equal(seen["k"][:, :, 1], seen["k"][:, :, 2])
+    # no position embedding: a sequence and the same sequence moved two
+    # places to the right give the same outputs two places on, as far as
+    # each still sees all it saw
+    moved = jnp.concatenate([x[:, :2], x[:, :-2]], axis=1)
+    a = nemotron_h._attn_mixer(CFG32, layer, x[:, :1],
+                               attn_fn=reference_attention)
+    b = nemotron_h._attn_mixer(CFG32, layer, moved[:, 2:3],
+                               attn_fn=reference_attention)
+    np.testing.assert_allclose(a - x[:, :1], b - moved[:, 2:3], atol=1e-5)
+
+
+def test_the_scan_starts_from_zero_every_sequence() -> None:
+    """Nothing is carried between the rows of a batch or between calls:
+    row 1 alone is row 1 of the batch."""
+    params, (tokens, _) = _params(CFG32, 4), _batch(4)
+    both, _ = nemotron_h.forward_hidden(CFG32, params, tokens)
+    alone, _ = nemotron_h.forward_hidden(CFG32, params, tokens[1:])
+    np.testing.assert_allclose(both[1:], alone, atol=2e-5)
+
+
+def test_the_pattern_is_data_of_the_config() -> None:
+    for pattern in ("M", "*E", "EM*M"):
+        cfg = dataclasses.replace(CFG32, pattern=pattern)
+        params = nemotron_h.init_params(cfg, jax.random.key(0))
+        kinds = [next(k for k in params[f"layers_{i}"] if k != "norm")
+                 for i in range(len(pattern))]
+        assert kinds == [nemotron_h.MIXERS[c] for c in pattern]
+        tokens, targets = _batch(0)
+        got = nemotron_h.loss_terms(cfg, params, tokens, targets)
+        want = _reference(cfg)(params, tokens, targets)
+        assert float(got["loss"]) == pytest.approx(float(want["loss"]),
+                                                   abs=2e-5)
+        assert ("loads" in got) == ("E" in pattern)
+    with pytest.raises(AssertionError):
+        dataclasses.replace(CFG32, pattern="M-E")
+
+
+# -- the held share ----------------------------------------------------------
+
+
+def _layer_and_stream(seed):
+    params = _params(CFG32, seed)
+    x = jax.random.normal(jax.random.key(50 + seed), (2, 64, 64), jnp.float32)
+    return params["layers_3"], x
+
+
+def _full_layer(layer, seed):
+    """The same layer with all 8 routed experts: the held 4 and 4 more."""
+    extra = nemotron_h.init_params(
+        dataclasses.replace(CFG32, first_expert=4), jax.random.key(900 + seed)
+    )["layers_3"]["moe"]
+    full = jax.tree_util.tree_map(lambda a: a, layer)
+    for name in ("up_proj", "down_proj"):
+        full["moe"][name] = {"kernel": jnp.concatenate(
+            [layer["moe"][name]["kernel"], extra[name]["kernel"]])}
+    return full
+
+
+@pytest.mark.parametrize("split", [(1,) * 8, (2, 2, 2, 2), (4, 4), (3, 5),
+                                   (1, 6, 1), (8,)])
+def test_the_shares_add_up_to_the_uncut_layer(split) -> None:
+    """The routed parts that all the shares give (16 chips of the
+    deployment hold 8 each of 128; here 8 shares of 1, and uneven ones),
+    with the shared expert counted once, are the reference's expert
+    mixer with every expert held."""
+    layer, x = _layer_and_stream(7)
+    full = _full_layer(layer, 7)
+    h = nemotron_h_f32._rms(x, full["norm"]["scale"], CFG.rms_eps).reshape(
+        -1, 64)
+    with jax.default_matmul_precision("highest"):
+        want, _ = nemotron_h_f32._experts(
+            h, full["moe"], top_k=CFG.top_k, first_expert=0,
+            routed_scale=CFG.routed_scale)
+        shared = nemotron_h._relu2(h, full["moe"]["shared"], jnp.float32)
+        total, first = jnp.zeros_like(want), 0
+        for held in split:
+            cfg = dataclasses.replace(CFG32, first_expert=first,
+                                      n_experts_held=held)
+            share = jax.tree_util.tree_map(lambda a: a, full)
+            for name in ("up_proj", "down_proj"):
+                share["moe"][name] = {"kernel": full["moe"][name]["kernel"][
+                    first:first + held]}
+            y, rec = nemotron_h._moe_mixer(cfg, share, x)
+            total = total + (y - x).reshape(-1, 64) - shared
+            first += held
+        assert first == CFG.n_routed_experts
+    np.testing.assert_allclose(total + shared, want, atol=2e-5)
+    assert float(jnp.max(jnp.abs(want - shared))) > 0.1   # the routed part
+
+
+def test_every_assignment_held_and_none_held_run_one_program() -> None:
+    """Dropless for every routing: a bias that sends every assignment to
+    the held experts, and one that sends none there, are ordinary inputs
+    of the one compiled program, and both agree with the reference."""
+    layer, x = _layer_and_stream(9)
+    run = jax.jit(functools.partial(nemotron_h._moe_mixer, CFG32))
+    seen = []
+    for sign in (+1.0, -1.0, 0.0):
+        bias = sign * 10.0 * (jnp.arange(8) < 4)
+        layer["moe"][BIAS] = bias.astype(jnp.float32)
+        y, rec = run(layer, x)
+        seen.append(int(jnp.sum(rec["loads"][:4])))
+        h = nemotron_h_f32._rms(x, layer["norm"]["scale"], CFG.rms_eps)
+        with jax.default_matmul_precision("highest"):
+            want, _ = nemotron_h_f32._experts(
+                h.reshape(-1, 64), layer["moe"], top_k=CFG.top_k,
+                first_expert=0, routed_scale=CFG.routed_scale)
+        np.testing.assert_allclose((y - x).reshape(-1, 64), want, atol=2e-5)
+        assert bool(jnp.all(jnp.isfinite(y)))
+    assert seen[0] == 2 * 64 * CFG.top_k and seen[1] == 0
+    assert 0 < seen[2] < seen[0]
+    assert run._cache_size() == 1
+    # the gradient of a share that holds nothing of a batch is that of
+    # the shared expert alone: finite, and zero for the routed weights
+    layer["moe"][BIAS] = -10.0 * (jnp.arange(8) < 4).astype(jnp.float32)
+    grads = jax.grad(lambda l: jnp.sum(
+        nemotron_h._moe_mixer(CFG32, l, x)[0] ** 2))(layer)
+    assert all(bool(jnp.all(jnp.isfinite(g)))
+               for g in jax.tree_util.tree_leaves(grads))
+    assert not np.any(grads["moe"]["up_proj"]["kernel"])
+    assert np.any(grads["moe"]["shared"]["up_proj"]["kernel"])
+
+
+def _jaxpr_hash(fn, *args):
+    text = re.sub(r"/[^ ]*?\.py:\d+", "", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# the sha256 of joyai_tiny's gradient jaxpr (file paths cut) at d58585e,
+# the parent of PR 33: recorded by running this file's _jaxpr_hash on a
+# checkout of that commit
+JOYAI_PROGRAM_AT_D58585E = (
+    "1c74cd8031aed88edcf9c962c458677fcccb217cedef892342f8357051ff2834")
+
+
+@pytest.mark.parametrize("model", ["olmoe", "joyai"])
+def test_the_gated_expert_paths_are_what_they_were(model) -> None:
+    """``moe_mlp`` carries either expert; a call with a gate — OLMoE's and
+    JoyAI's — traces to the jaxpr the commit before this one (d58585e)
+    traced: same instructions, same order. The outputs' bits follow."""
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    if model == "olmoe":
+        cfg = olmoe.OLMOE_CONFIGS["olmoe_tiny"]
+        params = jax.eval_shape(
+            lambda: olmoe.init_params(cfg, jax.random.key(0)))
+        got = _jaxpr_hash(jax.grad(
+            lambda p, a, b: olmoe.loss_fn(cfg, p, a, b)), params, tokens,
+            tokens)
+        # tests/test_joyai.py pins the same program under the same hash
+        assert got == ("3c40614299f885d4c7d1230c9b2a6a7a"
+                       "68c7e055e6686e838d81f49e710ed6b8")
+    else:
+        cfg = joyai.JOYAI_CONFIGS["joyai_tiny"]
+        params = jax.eval_shape(
+            lambda: joyai.init_params(cfg, jax.random.key(0)))
+        got = _jaxpr_hash(jax.grad(
+            lambda p, a, b: joyai.loss_fn(cfg, p, a, b)), params, tokens,
+            tokens)
+        assert got == JOYAI_PROGRAM_AT_D58585E
+
+
+def test_relu2_experts_are_the_plain_sum_over_experts() -> None:
+    """The two-matrix expert through the grouped matmuls, against every
+    expert on every row weighted by a one-hot."""
+    k = jax.random.split(jax.random.key(0), 4)
+    x = jax.random.normal(k[0], (64, 16), jnp.float32)
+    up = jax.random.normal(k[1], (4, 16, 24), jnp.float32) * 0.3
+    down = jax.random.normal(k[2], (4, 24, 16), jnp.float32) * 0.3
+    sizes = jnp.array([10, 0, 30, 24], jnp.int32)
+    got = moe.relu2_experts(x, up, down, sizes)
+    which = jnp.repeat(jnp.arange(4), sizes, total_repeat_length=64)
+    with jax.default_matmul_precision("highest"):
+        every = jnp.einsum(
+            "enf,efd->end", jnp.square(jax.nn.relu(
+                jnp.einsum("nd,edf->enf", x, up))), down)
+    want = every[which, jnp.arange(64)]
+    np.testing.assert_allclose(got, want, atol=1e-4)

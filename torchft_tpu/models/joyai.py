@@ -30,8 +30,8 @@ The balance bias ``b`` (``topk_method: noaux_tc``) is a leaf of the
 parameters (``.../moe/balance_bias``) that no gradient of the loss moves.
 After each step ``b_e += rate · sign(mean(load) - load_e)`` with
 ``load_e`` the step's count of assignments to ``e``. The loads reach the
-rule IN THE GRADIENT TREE AT ``b``'S PLACE (``_loads_as_gradient``: a term
-that adds 0 to the loss and whose cotangent for ``b`` is the loads), so
+rule IN THE GRADIENT TREE AT ``b``'S PLACE (``common.loads_as_gradient``: a
+term that adds 0 to the loss and whose cotangent for ``b`` is the loads), so
 whatever averages gradients over replica groups averages the loads, and
 the rule is part of the optax transformation
 (``optim.with_balance_bias`` over ``is_balance_bias``), applied behind the same commit gate.
@@ -67,7 +67,12 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.llama import _rms_norm
+from torchft_tpu.models.common import (
+    BALANCE_BIAS,
+    is_balance_bias,
+    loads_as_gradient,
+    rms_norm,
+)
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
     ce_from_hidden,
@@ -76,15 +81,6 @@ from torchft_tpu.ops import moe
 
 __all__ = ["JoyaiConfig", "JOYAI_CONFIGS", "BALANCE_BIAS", "is_balance_bias",
            "init_params", "forward_hidden", "loss_terms", "loss_fn"]
-
-# the key of a router's balance bias in the parameter tree
-BALANCE_BIAS = "balance_bias"
-
-
-def is_balance_bias(path) -> bool:
-    """Whether a ``jax.tree_util`` key path ends at a balance bias: the
-    predicate ``optim.with_balance_bias`` partitions the leaves by."""
-    return getattr(path[-1], "key", None) == BALANCE_BIAS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,9 +235,9 @@ def _mla_sublayer(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
     dt, eps, a = cfg.dtype, cfg.rms_eps, layer["attn"]
     B, S, _ = x.shape
     H, nope, rope = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    h = _rms_norm(x, layer["ln_1"]["scale"], eps)
+    h = rms_norm(x, layer["ln_1"]["scale"], eps)
     with jax.named_scope("mla_q"):
-        c_q = _rms_norm(h @ a["q_a_proj"]["kernel"].astype(dt),
+        c_q = rms_norm(h @ a["q_a_proj"]["kernel"].astype(dt),
                         a["q_a_norm"]["scale"], eps)
         q = (c_q @ a["q_b_proj"]["kernel"].astype(dt)).reshape(
             B, S, H, nope + rope)
@@ -250,8 +246,8 @@ def _mla_sublayer(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
             axis=-1)
     with jax.named_scope("mla_kv"):
         kv_a = h @ a["kv_a_proj"]["kernel"].astype(dt)
-        c_kv = _rms_norm(kv_a[..., :cfg.kv_lora_rank],
-                         a["kv_a_norm"]["scale"], eps)
+        c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank],
+                        a["kv_a_norm"]["scale"], eps)
         k_r = _rope_pairs(kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta)
         kv = (c_kv @ a["kv_b_proj"]["kernel"].astype(dt)).reshape(
             B, S, H, nope + cfg.v_head_dim)
@@ -273,29 +269,8 @@ def _swiglu(h, m: Dict, dt):
 
 @jax.named_scope("mlp")
 def _dense_sublayer(cfg: JoyaiConfig, layer: Dict, x):
-    h = _rms_norm(x, layer["ln_2"]["scale"], cfg.rms_eps)
+    h = rms_norm(x, layer["ln_2"]["scale"], cfg.rms_eps)
     return x + _swiglu(h, layer["mlp"], cfg.dtype)
-
-
-@jax.custom_vjp
-def _loads_as_gradient(bias, loads):
-    """Adds 0 to the loss; its cotangent for ``bias`` is ``loads``. The
-    balance bias has no gradient of its own (it only selects), so its
-    place in the gradient tree carries what its update rule reads: the
-    step's assignments per expert, averaged over replica groups with the
-    gradients."""
-    return jnp.zeros((), jnp.float32)
-
-
-def _loads_fwd(bias, loads):
-    return jnp.zeros((), jnp.float32), loads
-
-
-def _loads_bwd(loads, g):
-    return (g * loads).astype(loads.dtype), jnp.zeros_like(loads)
-
-
-_loads_as_gradient.defvjp(_loads_fwd, _loads_bwd)
 
 
 @jax.named_scope("mlp")
@@ -306,8 +281,8 @@ def _moe_sublayer(cfg: JoyaiConfig, layer: Dict, x) -> Tuple[Any, Dict]:
     m = layer["moe"]
     B, S, d = x.shape
     with jax.named_scope("moe_router"):
-        h32 = _rms_norm(x.astype(jnp.float32), layer["ln_2"]["scale"],
-                        cfg.rms_eps).reshape(B * S, d)
+        h32 = rms_norm(x.astype(jnp.float32), layer["ln_2"]["scale"],
+                       cfg.rms_eps).reshape(B * S, d)
         # as models/olmoe.py: the router reads the normed stream before
         # it is rounded to the compute dtype, in true float32
         scores = jax.nn.sigmoid(jnp.dot(
@@ -318,7 +293,7 @@ def _moe_sublayer(cfg: JoyaiConfig, layer: Dict, x) -> Tuple[Any, Dict]:
             scale=cfg.routed_scale)
         loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
             experts.reshape(-1)].add(1.0)
-        carrier = _loads_as_gradient(
+        carrier = loads_as_gradient(
             m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
     h = h32.astype(cfg.dtype)
     with jax.named_scope("moe_shared"):
@@ -352,8 +327,8 @@ def _mtp_input(cfg: JoyaiConfig, params: Dict, x_last, next_tokens):
     """``W_eh·[RMSNorm(Emb(t_{i+1})) ; RMSNorm(x^L_i)]``: the embedding
     first, as the released DeepSeek-V3 code has it."""
     p, eps = params["mtp"], cfg.rms_eps
-    e = _rms_norm(_embed(cfg, params, next_tokens), p["enorm"]["scale"], eps)
-    h = _rms_norm(x_last, p["hnorm"]["scale"], eps)
+    e = rms_norm(_embed(cfg, params, next_tokens), p["enorm"]["scale"], eps)
+    h = rms_norm(x_last, p["hnorm"]["scale"], eps)
     return jnp.concatenate([e, h], axis=-1) @ p["eh_proj"]["kernel"].astype(
         cfg.dtype)
 
@@ -363,7 +338,7 @@ def forward_hidden(cfg: JoyaiConfig, params: Dict, tokens, next_tokens=None,
     """tokens [B, S] -> (final-norm hidden states [B, S, d] of the main
     model, record). The record holds ``experts`` [L_e, N, top_k] and
     ``loads`` [L_e, routed] of every expert layer (the MTP module's last),
-    ``carrier`` (zero; see ``_loads_as_gradient``) and, where the MTP
+    ``carrier`` (zero; see ``common.loads_as_gradient``) and, where the MTP
     module runs (``next_tokens`` given), its final-norm ``mtp_hidden``."""
     if attn_fn is None:
         attn_fn = _local_causal_attention
@@ -385,14 +360,14 @@ def forward_hidden(cfg: JoyaiConfig, params: Dict, tokens, next_tokens=None,
             y, rec = expert(params["mtp"]["block"],
                             _mtp_input(cfg, params, x, next_tokens))
             records.append(rec)
-            out["mtp_hidden"] = _rms_norm(
+            out["mtp_hidden"] = rms_norm(
                 y, params["mtp"]["ln_f"]["scale"], cfg.rms_eps)
     out.update(
         experts=jnp.stack([r["experts"] for r in records]),
         loads=jnp.stack([r["loads"] for r in records]),
         carrier=sum(r["carrier"] for r in records),
     )
-    return _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps), out
+    return rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps), out
 
 
 def loss_terms(cfg: JoyaiConfig, params, tokens, targets,

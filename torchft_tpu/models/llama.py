@@ -21,6 +21,8 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from torchft_tpu.models.common import rms_norm
+
 __all__ = [
     "LlamaConfig",
     "LLAMA_CONFIGS",
@@ -119,14 +121,6 @@ def llama_init_params(cfg: LlamaConfig, key) -> Dict:
     return params
 
 
-def _rms_norm(x, scale, eps: float):
-    var = jnp.mean(
-        jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True
-    )
-    out = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (out * scale.astype(jnp.float32)).astype(x.dtype)
-
-
 def _rope(x, theta: float):
     """Rotary embedding over [B, S, H, D] (D even)."""
     b, s, h, d = x.shape
@@ -155,7 +149,7 @@ def _block(cfg: LlamaConfig, layer: Dict, x, *, attn_fn):
     B, S, _ = x.shape
     hd = cfg.head_dim
 
-    h = _rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_eps)
+    h = rms_norm(x, layer["attn_norm"]["scale"], cfg.rms_eps)
     q = (h @ layer["attn"]["q_proj"]["kernel"].astype(dt)).reshape(
         B, S, cfg.n_heads, hd
     )
@@ -175,7 +169,7 @@ def _block(cfg: LlamaConfig, layer: Dict, x, *, attn_fn):
     a = attn_fn(q, k, v).reshape(B, S, cfg.d_model)
     x = x + a @ layer["attn"]["o_proj"]["kernel"].astype(dt)
 
-    h = _rms_norm(x, layer["mlp_norm"]["scale"], cfg.rms_eps)
+    h = rms_norm(x, layer["mlp_norm"]["scale"], cfg.rms_eps)
     gate = h @ layer["mlp"]["gate_proj"]["kernel"].astype(dt)
     up = h @ layer["mlp"]["up_proj"]["kernel"].astype(dt)
     x = x + (
@@ -196,7 +190,7 @@ def llama_forward_hidden(cfg: LlamaConfig, params, tokens,
         block = jax.checkpoint(block)
     for layer in params["layers"]:
         x = block(layer, x)
-    return _rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
+    return rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps)
 
 
 def llama_forward(cfg: LlamaConfig, params, tokens,

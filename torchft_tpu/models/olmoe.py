@@ -38,7 +38,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.llama import _rms_norm, _rope
+from torchft_tpu.models.common import rms_norm
+from torchft_tpu.models.llama import _rope
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
     ce_from_hidden,
@@ -131,7 +132,7 @@ def init_params(cfg: OlmoeConfig, key) -> Dict:
 def _qk_norm(x, scale, eps: float):
     """RMSNorm over the whole ``[.., d_model]`` projection, before the
     head split (not per head)."""
-    return _rms_norm(x, scale, eps)
+    return rms_norm(x, scale, eps)
 
 
 @jax.named_scope("attn")
@@ -139,7 +140,7 @@ def _attn_sublayer(cfg: OlmoeConfig, layer: Dict, x, *, attn_fn):
     dt, eps = cfg.dtype, cfg.rms_eps
     a = layer["attn"]
     B, S, d = x.shape
-    h = _rms_norm(x, layer["ln_1"]["scale"], eps)
+    h = rms_norm(x, layer["ln_1"]["scale"], eps)
     q = _qk_norm(h @ a["q_proj"]["kernel"].astype(dt), a["q_norm"]["scale"],
                  eps)
     k = _qk_norm(h @ a["k_proj"]["kernel"].astype(dt), a["k_norm"]["scale"],
@@ -158,8 +159,8 @@ def _moe_sublayer(cfg: OlmoeConfig, layer: Dict, x) -> Tuple[Any, Any]:
     m = layer["moe"]
     B, S, d = x.shape
     with jax.named_scope("moe_router"):
-        h32 = _rms_norm(x.astype(jnp.float32), layer["ln_2"]["scale"],
-                        cfg.rms_eps).reshape(B * S, d)
+        h32 = rms_norm(x.astype(jnp.float32), layer["ln_2"]["scale"],
+                       cfg.rms_eps).reshape(B * S, d)
         # the router reads the normed stream before it is rounded to the
         # compute dtype, in true float32: a near-tie between the k-th and
         # the next expert then flips only on what is upstream of it
@@ -206,7 +207,7 @@ def forward_hidden(cfg: OlmoeConfig, params: Dict, tokens,
         x, (lb_i, z_i, experts) = block(params[f"layers_{i}"], x)
         lb, z = lb + lb_i, z + z_i
         chosen.append(experts)
-    h = _rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
+    h = rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps)
     return h, {"load_balance": lb, "router_z": z,
                "experts": jnp.stack(chosen)}
 

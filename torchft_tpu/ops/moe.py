@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["Dispatch", "top_k_routing", "sort_by_expert", "gather_tokens",
-           "grouped_matmul", "swiglu_experts", "combine", "moe_mlp"]
+           "grouped_matmul", "swiglu_experts", "relu2_experts", "combine",
+           "moe_mlp"]
 
 
 class Dispatch(NamedTuple):
@@ -160,6 +161,17 @@ def swiglu_experts(x: Any, gate: Any, up: Any, down: Any,
     return grouped_matmul(a, down.astype(dt), group_sizes)
 
 
+def relu2_experts(x: Any, up: Any, down: Any, group_sizes: Any) -> Any:
+    """``relu(x·up[e])² · down[e]`` for the rows of each expert ``e``:
+    the two-matrix expert without a gate (Nemotron-H's ``relu2``); the
+    weights are cast to ``x``'s dtype, the activation is computed in
+    float32."""
+    dt = x.dtype
+    u = grouped_matmul(x, up.astype(dt), group_sizes).astype(jnp.float32)
+    a = jnp.square(jax.nn.relu(u)).astype(dt)
+    return grouped_matmul(a, down.astype(dt), group_sizes)
+
+
 def combine(y: Any, weights: Any, dispatch: Dispatch) -> Any:
     """Rows in expert order ``[N*k, d]`` -> ``[N, d]``: unsort, weight
     each copy by its router probability, sum a token's ``k`` copies in
@@ -180,10 +192,15 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
     scopes ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``: tokens
     ``h [N, d]`` with their ``[N, k]`` routing -> ``[N, d]``.
 
+    The expert's shape is the caller's: with ``gate`` [E, d, f] it is
+    :func:`swiglu_experts`' three matrices, with ``gate=None``
+    :func:`relu2_experts`' two; dispatch, share, sentinel and the
+    ``live`` select are one code for both.
+
     The layer is told which experts it holds: the router chose among
-    ``n_routed`` experts (default: as many as ``gate`` holds) and the
+    ``n_routed`` experts (default: as many as ``up`` holds) and the
     weights here are those of experts ``first_expert ..
-    first_expert + gate.shape[0]``. What comes back is the held experts'
+    first_expert + up.shape[0]``. What comes back is the held experts'
     part of the result; assignments to absent experts are computed
     nowhere and nothing stands in for the chips that hold them.
 
@@ -194,7 +211,7 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
     matmuls' time follows the rows held, and the rows past the sum are
     never written, so they are cut off by a select on the way in (for
     the gradient's sake) and on the way out."""
-    n_held = gate.shape[0]
+    n_held = up.shape[0]
     share = n_routed is not None and (n_routed, first_expert) != (n_held, 0)
     with jax.named_scope("moe_dispatch"):
         if share:
@@ -210,7 +227,10 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
                     < jnp.sum(dispatch.group_sizes))[:, None]  # [N*k, 1]
             x = jnp.where(live, x, 0)
     with jax.named_scope("moe_experts"):
-        y = swiglu_experts(x, gate, up, down, dispatch.group_sizes)
+        if gate is None:
+            y = relu2_experts(x, up, down, dispatch.group_sizes)
+        else:
+            y = swiglu_experts(x, gate, up, down, dispatch.group_sizes)
     with jax.named_scope("moe_combine"):
         return combine(jnp.where(live, y, 0) if share else y, weights,
                        dispatch)

@@ -45,7 +45,7 @@ def balance_bias_rule(rate: float):
     """The optax transformation of a router's balance bias (DeepSeek-V3's
     auxiliary-loss-free balancing, ``topk_method: noaux_tc``): what
     arrives as the leaf's gradient is the step's assignments per expert
-    (the model's doing, as ``models/joyai.py::_loads_as_gradient``;
+    (the model's doing, as ``models/common.py::loads_as_gradient``;
     averaged over replica groups like any gradient), and the update is
     ``rate · sign(mean(load) - load_e)``: an expert with fewer than its share is
     made likelier, one with more less likely. No state, no decay."""
