@@ -213,6 +213,26 @@ def test_the_scan_starts_from_zero_every_sequence() -> None:
     np.testing.assert_allclose(both[1:], alone, atol=2e-5)
 
 
+def test_the_mamba_mixer_runs_the_four_fused_kernels() -> None:
+    """The stages around the scan are ``ops/ssm_pointwise.py``'s, forward
+    and backward: the mixer's gradient program holds the four kernels by
+    name, one call each, and no array ``K - 1`` positions longer than the
+    sequence — the convolution's zeros before the sequence are the
+    kernel's halo, not a padded copy in HBM (the pads that are left are
+    ``ops/ssd.py``'s, to whole chunks)."""
+    layer = jax.eval_shape(
+        lambda: nemotron_h.init_params(CFG, jax.random.key(0)))["layers_0"]
+    x = jax.ShapeDtypeStruct((2, 64, CFG.d_model), CFG.dtype)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p, a: jnp.sum(nemotron_h._mamba_mixer(CFG, p, a).astype(
+            jnp.float32))))(layer, x))
+    for kernel in ("ssm_conv_fwd", "ssm_conv_bwd", "ssm_gate_fwd",
+                   "ssm_gate_bwd", "ssd_fwd", "ssd_bwd"):
+        assert len(re.findall(rf"\bname={kernel}\b", text)) == 1, kernel
+    assert f"[2,{64 + CFG.conv_kernel - 1}," not in text
+    assert "[2,64," in text
+
+
 def test_the_pattern_is_data_of_the_config() -> None:
     for pattern in ("M", "*E", "EM*M"):
         cfg = dataclasses.replace(CFG32, pattern=pattern)

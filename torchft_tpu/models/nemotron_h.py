@@ -49,10 +49,18 @@ explicit parameter tree with stable paths ``layers_<i>/norm`` and
 ``remat``, and the step programs of ``transformer.make_train_step`` /
 ``make_grad_step`` (``loss=nemotron_h.loss_fn``).
 
+``_conv_silu`` and ``_gated_norm`` are seams over ``ops/ssm_pointwise.py``
+(one fused kernel forward and one backward each, reading ``xBC`` / ``y``,
+``z`` once in the compute dtype): they keep their names and signatures
+because ``benchmark/tests/nemotron_faults.py`` puts its stand-ins in
+their place by name; the f32 formulas they held are the oracle of
+``tests/test_ssm_pointwise.py``.
+
 Device-trace scopes: ``embed``; both sequence mixers under ``attn``,
 told apart inside — ``ssm_in`` (norm, ``W_in``, the split), ``ssm_conv``
-(convolution + silu), ``ssm_scan`` (softplus, decays, the scan),
-``ssm_gate`` (gate, grouped norm), ``ssm_out``; ``gqa_proj``,
+(convolution + silu: the kernels ``ssm_conv_fwd`` / ``ssm_conv_bwd``),
+``ssm_scan`` (softplus, decays, the scan), ``ssm_gate`` (gate, grouped
+norm: ``ssm_gate_fwd`` / ``ssm_gate_bwd``), ``ssm_out``; ``gqa_proj``,
 ``gqa_core`` (the flash call); ``mlp`` with inner ``moe_router``,
 ``moe_shared``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``;
 ``lm_head_xent``.
@@ -80,6 +88,7 @@ from torchft_tpu.models.transformer import (
 )
 from torchft_tpu.ops import moe
 from torchft_tpu.ops.ssd import ssd_scan
+from torchft_tpu.ops.ssm_pointwise import conv_silu, gated_norm
 
 __all__ = ["NemotronHConfig", "NEMOTRON_H_CONFIGS", "BALANCE_BIAS",
            "is_balance_bias", "init_params", "forward_hidden", "loss_terms",
@@ -237,26 +246,16 @@ def init_params(cfg: NemotronHConfig, key) -> Dict:
 
 def _conv_silu(m: Dict, xbc, dt):
     """``silu(b + Σ_j w_j ⊙ xBC_{t-(K-1)+j})``: tap ``j`` multiplies the
-    position ``K-1-j`` back, zeros before the sequence's start; f32."""
-    f32 = jnp.float32
-    taps = m["conv"]["kernel"].astype(f32)
-    K, S = taps.shape[0], xbc.shape[1]
-    padded = jnp.pad(xbc.astype(f32), ((0, 0), (K - 1, 0), (0, 0)))
-    conv = m["conv"]["bias"].astype(f32) + sum(
-        taps[j] * padded[:, j:j + S] for j in range(K))
-    return jax.nn.silu(conv).astype(dt)
+    position ``K-1-j`` back, zeros before the sequence's start; f32
+    inside ``ops/ssm_pointwise.py``'s kernel."""
+    return conv_silu(xbc, m["conv"]["kernel"], m["conv"]["bias"]).astype(dt)
 
 
 def _gated_norm(y, z, scale, groups: int, eps: float, dt):
     """``RMSNorm_grouped(y ⊙ silu(z))·scale``: the gate first, then each
-    of the ``groups`` runs of channels normalised alone; f32."""
-    f32 = jnp.float32
-    B, S, I = z.shape
-    gated = (y.reshape(B, S, I).astype(f32)
-             * jax.nn.silu(z.astype(f32))).reshape(B, S, groups, I // groups)
-    var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
-    return ((gated * jax.lax.rsqrt(var + eps)).reshape(B, S, I)
-            * scale.astype(f32)).astype(dt)
+    of the ``groups`` runs of channels normalised alone; f32 inside
+    ``ops/ssm_pointwise.py``'s kernel."""
+    return gated_norm(y.reshape(z.shape), z, scale, groups, eps).astype(dt)
 
 
 @jax.named_scope("attn")

@@ -1,0 +1,540 @@
+"""Pallas kernels for the two elementwise stages around the Mamba-2 scan
+(``ops/ssd.py``): the short causal depthwise convolution with its silu,
+and the gate with its grouped RMSNorm. Each is ONE kernel forward and
+ONE backward under its own ``jax.custom_vjp``; each reads its operands
+once, in the dtype they arrive in, and writes its results once. The
+residuals of both are their inputs alone: the backward kernels recompute
+what they need.
+
+``conv_silu(x [B, S, C], taps [K, C], bias [C])``:
+
+    conv_t = bias + Σ_{j<K} taps_j ⊙ x_{t-(K-1)+j}        y_t = silu(conv_t)
+
+per channel, zeros before each sequence's start. Backward, with
+``dconv = dy · silu'(conv)`` and ``silu'(c) = σ(c)(1 + c(1 − σ(c)))``:
+
+    dx_t = Σ_j taps_j ⊙ dconv_{t+(K-1-j)}          dbias = Σ_t dconv_t
+    dtaps_j = Σ_t dconv_t ⊙ x_{t-(K-1)+j}
+
+``gated_norm(y, z [B, S, I], scale [I], groups, eps)``: with ``g = y ⊙
+silu(z)`` and, for each of the ``groups`` runs of ``W = I / groups``
+channels alone, ``r = rsqrt(mean_W(g²) + eps)`` and ``n = g·r``:
+``out = n ⊙ scale``. Backward, with ``dn = dout ⊙ scale``:
+
+    dg = r·(dn − n·mean_W(dn ⊙ n))       dscale = Σ_t dout ⊙ n
+    dy = dg ⊙ silu(z)                     dz = dg ⊙ y ⊙ silu'(z)
+
+What is which dtype: ``x``, ``y``, ``z`` and the cotangents arrive, and
+the results and ``dx``, ``dy``, ``dz`` leave, in the input dtype (bf16
+in the models); every operand is upcast to f32 as it is loaded and all
+that lies between — the taps' sums, the sigmoid, the group's mean, the
+rsqrt — is f32 and exact (no approximate reciprocal), so the one
+rounding is where the jnp formulation had it: after the silu, after the
+norm's scale. ``dtaps``, ``dbias`` and ``dscale`` accumulate in f32 and
+leave in their parameter's dtype.
+
+The halo. A grid step is one (batch row, sequence block, channel block
+of whole lane tiles); the convolution at a block's first ``K − 1``
+positions needs the ``K − 1`` rows before it. Nothing is padded in HBM:
+the forward kernel walks a row's sequence blocks in order and keeps an
+f32 copy of the block in a VMEM scratch whose first ``_HALO`` (8, the
+f32 sublane tile) rows are the previous block's last — zeros in block
+0, so nothing of one batch row reaches the next — and takes the ``K``
+shifted windows from it: an aligned load of a chunk with the eight rows
+before it, rotated down the sublanes (Mosaic takes no unaligned
+dynamic index; a packed bf16 block shifts worse still). The backward
+kernel walks the blocks LAST TO FIRST: ``x``'s rows before the block
+come from a second, one-tile view of the same array (index clamped,
+zeroed in block 0), and
+``dconv``'s rows AFTER the block, which ``dx`` at the block's end
+needs, are the first rows of the block it handled one step before,
+carried in its second scratch. ``dtaps``, ``dbias`` and ``dscale`` are
+output blocks that stay resident while the grid runs over batch rows
+and sequence blocks (the channel block is the outermost axis there).
+
+Inside a grid step the rows go through in chunks (``_CONV_CHUNK``,
+``_GATE_CHUNK``) so that a chunk's chain of f32 values is never a
+block-sized array in VMEM; sums over rows are kept eight sublanes tall
+until the step's end.
+
+Blocks are chosen from the shape (:func:`_lane_block`,
+:func:`_row_block`): a channel block of at most ``_LANE_BLOCK`` lanes
+(whole norm groups for the gate), and as many rows as keep a block near
+``_BLOCK_ELEMS`` elements, a divisor of the sequence where there is
+one. A sequence that is no multiple of the block ends in a partial
+block whose rows past the end are masked in the backward kernels (what
+they would add to the sums is garbage) and thrown away by the forward.
+On the TPU a channel count, or a norm group, that is no multiple of 128
+lanes is refused with a message; off the TPU the same kernels run in
+Pallas's interpreter at any width (the CPU tests), chosen from the
+backend alone.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["conv_silu", "gated_norm"]
+
+_LANES = 128
+_HALO = 8                    # rows kept beside a block: the f32 sublane tile
+_LANE_BLOCK = 512            # the widest channel block
+_BLOCK_ELEMS = 256 * 1024    # elements a block: 1 MiB as f32
+# rows a pass of the loop inside a grid step (measured on the v5e at the
+# cell's shapes, PERF.md PR 34: the convolution's rotated windows want
+# few live rows, the norm's chain is short and wants few loop trips)
+_CONV_CHUNK = 32
+_GATE_CHUNK = 128
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _f32(a):
+    return a.astype(jnp.float32)
+
+
+def _silu_and_slope(v):
+    """``silu(v)`` and ``silu'(v)``."""
+    sig = jax.nn.sigmoid(v)
+    return v * sig, sig * (1.0 + v * (1.0 - sig))
+
+
+def _fold_rows(rows: int) -> int:
+    return _HALO if rows % _HALO == 0 else rows
+
+
+def _fold(a):
+    """``[rows, W]`` -> the sum of its eight-row slabs ``[8, W]`` (vector
+    adds only); as it is where the rows are no multiple of eight."""
+    out = a[:_fold_rows(a.shape[0])]
+    for k in range(1, a.shape[0] // out.shape[0]):
+        out = out + a[k * _HALO:(k + 1) * _HALO]
+    return out
+
+
+def _row_sum(a):
+    return jnp.sum(a, axis=0, keepdims=True)
+
+
+def _past_the_end(first, rows: int, seq_len: int):
+    """``[rows, 1]``: which of the sequence's rows ``first, first + 1,
+    ...`` lie at or past its end."""
+    return first + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) >= seq_len
+
+
+# ------------------------------------------------------- convolution + silu
+def _windows(ref, r0, off: int, rows: int, taps: int):
+    """The ``taps`` windows of ``rows`` rows of an f32 scratch that start
+    at rows ``r0 + off``, ``r0 + off + 1``, ... ``r0`` is a multiple of
+    eight; where it is not static the rows around the windows are loaded
+    tile-aligned and rotated down the sublanes (Mosaic takes no
+    unaligned dynamic index)."""
+    if isinstance(r0, int):
+        return [ref[r0 + off + j:r0 + off + j + rows, :] for j in range(taps)]
+    lo = off // _HALO * _HALO
+    span = -(-(off - lo + taps - 1 + rows) // _HALO) * _HALO
+    around = ref[pl.ds(pl.multiple_of(r0 + lo, _HALO), span), :]
+    return [around[:rows] if off - lo + j == 0 else
+            pltpu.roll(around, shift=span - (off - lo + j), axis=0)[:rows]
+            for j in range(taps)]
+
+
+def _row0(i, chunk: int):
+    return i * chunk if isinstance(i, int) else pl.multiple_of(
+        i * chunk, chunk)
+
+
+def _for_chunks(n: int, body, init):
+    """``fori_loop`` over a grid step's chunks; a single chunk (a short
+    sequence taken whole) straight-line, with a static index."""
+    return body(0, init) if n == 1 else jax.lax.fori_loop(0, n, body, init)
+
+
+def _weighted(w, windows, reverse: bool = False):
+    """``Σ_j w_j ⊙ windows[j]`` (``w_{K-1-j}`` where ``reverse``), summed
+    in the order of ``j``."""
+    k = len(windows)
+    total = None
+    for j, win in enumerate(windows):
+        row = k - 1 - j if reverse else j
+        term = w[row:row + 1] * win
+        total = term if total is None else total + term
+    return total
+
+
+def _conv_fwd_kernel(x_ref, w_ref, b_ref, o_ref, win, *, chunk: int):
+    """One (batch row, channel block, sequence block), the sequence
+    blocks innermost and in order."""
+    si = pl.program_id(2)
+    bs, k = x_ref.shape[1], w_ref.shape[0]
+
+    @pl.when(si == 0)
+    def _start():
+        win[0:_HALO] = jnp.zeros((_HALO, win.shape[1]), jnp.float32)
+
+    @pl.when(si > 0)
+    def _carry():
+        win[0:_HALO] = win[bs:bs + _HALO]
+
+    win[_HALO:_HALO + bs] = _f32(x_ref[0])
+    w, bias = _f32(w_ref[...]), _f32(b_ref[...])
+
+    def rows(i, carry):
+        r0 = _row0(i, chunk)
+        conv = bias + _weighted(
+            w, _windows(win, r0, _HALO - (k - 1), chunk, k))
+        o_ref[0, pl.ds(r0, chunk), :] = (
+            conv * jax.nn.sigmoid(conv)).astype(o_ref.dtype)
+        return carry
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _conv_bwd_kernel(*refs, chunk: int, seq_len: int, halo: bool):
+    """One (channel block, batch row, sequence block), the sequence
+    blocks innermost and LAST TO FIRST; ``dw_ref`` and ``db_ref`` stay
+    resident over a channel block's batch rows and sequence blocks."""
+    x_ref, refs = refs[0], refs[1:]
+    prev_ref, refs = (refs[0], refs[1:]) if halo else (None, refs)
+    dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref, win, dwin = refs
+    bi, si = pl.program_id(1), pl.program_id(2)
+    block = pl.num_programs(2) - 1 - si
+    bs, bc = x_ref.shape[1:]
+    k = w_ref.shape[0]
+    ragged = seq_len % bs != 0
+    f32 = jnp.float32
+
+    @pl.when((bi == 0) & (si == 0))
+    def _zero():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
+        db_ref[...] = jnp.zeros(db_ref.shape, f32)
+
+    # x's rows before the block
+    before = jnp.zeros((_HALO, bc), f32)
+    if halo:
+        before = jnp.where(block == 0, 0.0, _f32(prev_ref[0])[-_HALO:])
+    win[0:_HALO] = before
+    xb = _f32(x_ref[0])
+    if ragged:
+        xb = jnp.where(_past_the_end(block * bs, bs, seq_len), 0.0, xb)
+    win[_HALO:_HALO + bs] = xb
+
+    # dconv's rows after the block: the first rows of the block handled
+    # one step before
+    @pl.when(si == 0)
+    def _start():
+        dwin[bs:bs + _HALO] = jnp.zeros((_HALO, bc), f32)
+
+    @pl.when(si > 0)
+    def _carry():
+        dwin[bs:bs + _HALO] = dwin[0:_HALO]
+
+    w, bias = _f32(w_ref[...]), _f32(b_ref[...])
+
+    def recompute(i, sums):
+        r0 = _row0(i, chunk)
+        windows = _windows(win, r0, _HALO - (k - 1), chunk, k)
+        conv = bias + _weighted(w, windows)
+        dy = _f32(dy_ref[0, pl.ds(r0, chunk), :])
+        if ragged:
+            dy = jnp.where(
+                _past_the_end(block * bs + r0, chunk, seq_len), 0.0, dy)
+        dconv = dy * _silu_and_slope(conv)[1]
+        dwin[pl.ds(r0, chunk), :] = dconv
+        parts = [_fold(dconv * win_j) for win_j in windows] + [_fold(dconv)]
+        return tuple(s + p for s, p in zip(sums, parts))
+
+    sums = _for_chunks(
+        bs // chunk, recompute,
+        tuple(jnp.zeros((_fold_rows(chunk), bc), f32) for _ in range(k + 1)))
+    for j in range(k):
+        dw_ref[j:j + 1, :] += _row_sum(sums[j])
+    db_ref[...] += _row_sum(sums[k])
+
+    def spread(i, carry):
+        r0 = _row0(i, chunk)
+        dx_ref[0, pl.ds(r0, chunk), :] = _weighted(
+            w, _windows(dwin, r0, 0, chunk, k), reverse=True
+        ).astype(dx_ref.dtype)
+        return carry
+
+    _for_chunks(bs // chunk, spread, 0)
+
+
+def _lane_block(width: int, whole: int = _LANES) -> int:
+    """Lanes a channel block: the widest multiple of ``whole`` (itself a
+    multiple of 128) up to ``_LANE_BLOCK`` that divides ``width``, at
+    least one ``whole``; the whole of a width that is no multiple of
+    ``whole`` (off the TPU only)."""
+    if width % whole or whole % _LANES:
+        return width
+    return max([whole] + [bc for bc in range(whole, _LANE_BLOCK + 1, whole)
+                          if width % bc == 0])
+
+
+def _row_block(seq_len: int, lanes: int) -> int:
+    """Rows a block: about ``_BLOCK_ELEMS / lanes``, in sixteens (a
+    packed bf16 tile); the whole of a shorter sequence; a divisor of the
+    sequence where one lies within a factor of two below."""
+    rows = max(16, _BLOCK_ELEMS // lanes // 16 * 16)
+    if seq_len <= rows:
+        return seq_len
+    return next((bs for bs in range(rows, rows // 2, -16)
+                 if seq_len % bs == 0), rows)
+
+
+def _row_chunk(bs: int, chunk: int) -> int:
+    """``chunk`` rows a pass, or the largest of its halves down to a
+    packed bf16 tile that divides the block; a block no sixteen divides
+    (a short sequence taken whole) in one pass."""
+    while chunk > 16 and bs % chunk:
+        chunk //= 2
+    return chunk if bs % chunk == 0 else bs
+
+
+def _conv_forward(x, taps, bias, blocks: Tuple[int, int], interpret: bool):
+    b, s, c = x.shape
+    bs, bc = blocks
+    k = taps.shape[0]
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK)),
+        grid=(b, c // bc, pl.cdiv(s, bs)),
+        in_specs=[
+            pl.BlockSpec((1, bs, bc), lambda b, c, s: (b, s, c)),
+            pl.BlockSpec((k, bc), lambda b, c, s: (0, c)),
+            pl.BlockSpec((1, bc), lambda b, c, s: (0, c)),
+        ],
+        out_specs=pl.BlockSpec((1, bs, bc), lambda b, c, s: (b, s, c)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), jnp.float32)],
+        interpret=interpret, name="ssm_conv_fwd",
+    )(x, taps, bias.reshape(1, c))
+
+
+def _conv_backward(x, taps, bias, dy, blocks: Tuple[int, int],
+                   interpret: bool):
+    b, s, c = x.shape
+    bs, bc = blocks
+    k = taps.shape[0]
+    nb = pl.cdiv(s, bs)
+    halo = nb > 1
+    tile = 32 // x.dtype.itemsize       # rows of x's sublane tile
+
+    def at(s):
+        return nb - 1 - s
+
+    rows = pl.BlockSpec((1, bs, bc), lambda c, b, s: (b, at(s), c))
+    # the tile of rows that ends where the block starts
+    before = pl.BlockSpec(
+        (1, tile, bc),
+        lambda c, b, s: (b, jnp.maximum(at(s) * (bs // tile) - 1, 0), c))
+    tap_rows = pl.BlockSpec((k, bc), lambda c, b, s: (0, c))
+    lanes = pl.BlockSpec((1, bc), lambda c, b, s: (0, c))
+    f32 = jnp.float32
+    dx, dtaps, dbias = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK),
+                          seq_len=s, halo=halo),
+        grid=(c // bc, b, nb),
+        in_specs=[rows] + ([before] if halo else []) + [rows, tap_rows, lanes],
+        out_specs=[rows, tap_rows, lanes],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((k, c), f32),
+            jax.ShapeDtypeStruct((1, c), f32),
+        ],
+        scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), f32),
+                        pltpu.VMEM((bs + _HALO, bc), f32)],
+        interpret=interpret, name="ssm_conv_bwd",
+    )(*([x, x] if halo else [x]), dy, taps, bias.reshape(1, c))
+    return dx, dtaps.astype(taps.dtype), dbias.reshape(c).astype(bias.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv(x, taps, bias, blocks, interpret):
+    return _conv_forward(x, taps, bias, blocks, interpret)
+
+
+def _conv_fwd_rule(x, taps, bias, blocks, interpret):
+    return _conv_forward(x, taps, bias, blocks, interpret), (x, taps, bias)
+
+
+def _conv_bwd_rule(blocks, interpret, residuals, dy):
+    return _conv_backward(*residuals, dy, blocks, interpret)
+
+
+_conv.defvjp(_conv_fwd_rule, _conv_bwd_rule)
+
+
+def _refuse_lanes(what: str, width: int, interpret: bool) -> None:
+    if not interpret and width % _LANES:
+        raise ValueError(
+            f"{what}: {width} channels are no multiple of {_LANES} lanes")
+
+
+def conv_silu(x, taps, bias):
+    """``silu(bias + Σ_j taps[j] ⊙ x[t − (K−1) + j])`` of the module's
+    docstring: ``x [B, S, C]``, ``taps [K, C]``, ``bias [C]`` ->
+    ``[B, S, C]`` in ``x``'s dtype, differentiable in all three. The
+    blocks are chosen from the shape; the result does not depend on
+    them (``tests/test_ssm_pointwise.py`` runs ``_conv`` at others)."""
+    if (x.ndim != 3 or taps.ndim != 2 or taps.shape[1] != x.shape[2]
+            or bias.shape != x.shape[2:]):
+        raise ValueError(
+            f"conv_silu: x{tuple(x.shape)} taps{tuple(taps.shape)} "
+            f"bias{tuple(bias.shape)} do not fit")
+    if not 1 <= taps.shape[0] <= _HALO + 1:
+        raise ValueError(
+            f"conv_silu: {taps.shape[0]} taps; the halo holds {_HALO} rows")
+    interpret = _interpret()
+    _refuse_lanes("conv_silu", x.shape[2], interpret)
+    bc = _lane_block(x.shape[2])
+    return _conv(x, taps, bias, (_row_block(x.shape[1], bc), bc), interpret)
+
+
+# ------------------------------------------------------ gate + grouped norm
+def _gate_fwd_kernel(y_ref, z_ref, s_ref, o_ref, *, chunk: int, group: int,
+                     eps: float):
+    """One (batch row, sequence block, channel block of whole groups)."""
+    bs, bc = y_ref.shape[1:]
+
+    def rows(i, carry):
+        at = pl.ds(_row0(i, chunk), chunk)
+        for g in range(bc // group):
+            lanes = pl.ds(g * group, group)
+            zv = _f32(z_ref[0, at, lanes])
+            gated = _f32(y_ref[0, at, lanes]) * (zv * jax.nn.sigmoid(zv))
+            r = jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True) + eps)
+            o_ref[0, at, lanes] = (
+                (gated * r) * _f32(s_ref[:, lanes])).astype(o_ref.dtype)
+        return carry
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _gate_bwd_kernel(y_ref, z_ref, s_ref, do_ref, dy_ref, dz_ref, ds_ref, *,
+                     chunk: int, group: int, eps: float, seq_len: int):
+    """One (channel block, batch row, sequence block); ``ds_ref`` stays
+    resident over a channel block's batch rows and sequence blocks."""
+    bi, si = pl.program_id(1), pl.program_id(2)
+    bs, bc = y_ref.shape[1:]
+    ragged = seq_len % bs != 0
+    f32 = jnp.float32
+    groups = bc // group
+
+    @pl.when((bi == 0) & (si == 0))
+    def _zero():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, f32)
+
+    def rows(i, sums):
+        r0 = _row0(i, chunk)
+        at = pl.ds(r0, chunk)
+        out = []
+        gone = _past_the_end(si * bs + r0, chunk, seq_len) if ragged else None
+        for g in range(groups):
+            lanes = pl.ds(g * group, group)
+            yv, zv, dov = (_f32(ref[0, at, lanes])
+                           for ref in (y_ref, z_ref, do_ref))
+            if ragged:
+                yv, zv, dov = (jnp.where(gone, 0.0, v) for v in (yv, zv, dov))
+            act, slope = _silu_and_slope(zv)
+            gated = yv * act
+            r = jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True) + eps)
+            n = gated * r
+            dn = dov * _f32(s_ref[:, lanes])
+            dg = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+            dy_ref[0, at, lanes] = (dg * act).astype(dy_ref.dtype)
+            dz_ref[0, at, lanes] = (dg * yv * slope).astype(dz_ref.dtype)
+            out.append(sums[g] + _fold(dov * n))
+        return tuple(out)
+
+    sums = _for_chunks(
+        bs // chunk, rows,
+        tuple(jnp.zeros((_fold_rows(chunk), group), f32)
+              for _ in range(groups)))
+    for g in range(groups):
+        ds_ref[:, g * group:(g + 1) * group] += _row_sum(sums[g])
+
+
+def _gate_forward(y, z, scale, group: int, eps: float,
+                  blocks: Tuple[int, int], interpret: bool):
+    b, s, width = y.shape
+    bs, bc = blocks
+    rows = pl.BlockSpec((1, bs, bc), lambda b, s, c: (b, s, c))
+    return pl.pallas_call(
+        functools.partial(_gate_fwd_kernel, chunk=_row_chunk(bs, _GATE_CHUNK),
+                          group=group, eps=eps),
+        grid=(b, pl.cdiv(s, bs), width // bc),
+        in_specs=[rows, rows, pl.BlockSpec((1, bc), lambda b, s, c: (0, c))],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        interpret=interpret, name="ssm_gate_fwd",
+    )(y, z, scale.reshape(1, width))
+
+
+def _gate_backward(y, z, scale, dout, group: int, eps: float,
+                   blocks: Tuple[int, int], interpret: bool):
+    b, s, width = y.shape
+    bs, bc = blocks
+    rows = pl.BlockSpec((1, bs, bc), lambda c, b, s: (b, s, c))
+    lanes = pl.BlockSpec((1, bc), lambda c, b, s: (0, c))
+    dy, dz, dscale = pl.pallas_call(
+        functools.partial(_gate_bwd_kernel, chunk=_row_chunk(bs, _GATE_CHUNK),
+                          group=group, eps=eps, seq_len=s),
+        grid=(width // bc, b, pl.cdiv(s, bs)),
+        in_specs=[rows, rows, lanes, rows],
+        out_specs=[rows, rows, lanes],
+        out_shape=[
+            jax.ShapeDtypeStruct(y.shape, y.dtype),
+            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct((1, width), jnp.float32),
+        ],
+        interpret=interpret, name="ssm_gate_bwd",
+    )(y, z, scale.reshape(1, width), dout)
+    return dy, dz, dscale.reshape(width).astype(scale.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gate(y, z, scale, group, eps, blocks, interpret):
+    return _gate_forward(y, z, scale, group, eps, blocks, interpret)
+
+
+def _gate_fwd_rule(y, z, scale, group, eps, blocks, interpret):
+    return (_gate_forward(y, z, scale, group, eps, blocks, interpret),
+            (y, z, scale))
+
+
+def _gate_bwd_rule(group, eps, blocks, interpret, residuals, dout):
+    return _gate_backward(*residuals, dout, group, eps, blocks, interpret)
+
+
+_gate.defvjp(_gate_fwd_rule, _gate_bwd_rule)
+
+
+def gated_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm_group(y ⊙ silu(z)) ⊙ scale`` of the module's docstring:
+    ``y, z [B, S, I]``, ``scale [I]``, each of the ``groups`` runs of
+    ``I / groups`` channels normalised alone -> ``[B, S, I]`` in ``y``'s
+    dtype, differentiable in ``y``, ``z`` and ``scale``."""
+    width = y.shape[-1]
+    if (y.ndim != 3 or y.shape != z.shape or scale.shape != (width,)
+            or groups < 1 or width % groups):
+        raise ValueError(
+            f"gated_norm: y{tuple(y.shape)} z{tuple(z.shape)} "
+            f"scale{tuple(scale.shape)} in {groups} groups do not fit")
+    interpret = _interpret()
+    group = width // groups
+    _refuse_lanes("gated_norm: a group's", group, interpret)
+    bc = _lane_block(width, group)
+    return _gate(y, z, scale, group, float(eps),
+                 (_row_block(y.shape[1], bc), bc), interpret)
