@@ -1785,7 +1785,7 @@ def _run() -> None:
             f"{name}_{stat}_ms"
             for name in (
                 "quorum", "commit_barrier", "allreduce",
-                "comm_submit_wire", "comm_wire_reduce", "comm_reduce_future",
+                "comm_submit_wire", "comm_wire_reduce",
                 "comm_op_wire",
             )
             for stat in ("avg", "p50", "p95", "max")

@@ -116,6 +116,7 @@ from torchft_tpu.comm.wire import (
     sendmsg_all as _sendmsg_all,
 )
 from torchft_tpu.utils.metrics import Metrics
+from torchft_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -930,9 +931,9 @@ class _Lane:
         # Phase split (per lane AND aggregate, see Metrics.snapshot):
         #   submit_wire   — submission → lane dequeue (queue wait: how long
         #                   the op sat behind earlier ops on this lane)
-        #   wire_reduce   — dequeue → wire exchange + reduction complete
-        #   reduce_future — result ready → future delivered (continuation
-        #                   chain: normalize/unpack callbacks)
+        #   wire_reduce   — dequeue → wire exchange + reduction complete;
+        #                   a span, so the lane also shows on its own line
+        #                   of a trace's host plane, on the device's clock
         metrics = self._ctx.metrics
         tag = f"comm_l{self._lane_id}"
         while True:
@@ -941,8 +942,24 @@ class _Lane:
                 return
             t_deq = time.perf_counter()
             try:
-                result = self._execute(pending)
-                t_exec = time.perf_counter()
+                if pending.opcode in _GRAD_OPCODES:
+                    # Allreduce only: these split bench's allreduce number
+                    # along the transport's seams — a heal broadcast or
+                    # allgather landing here would pin gradient-path
+                    # regressions on checkpoint traffic. Striped ops
+                    # observe once per SUB-op: the per-lane wire_reduce is
+                    # each lane's share of the op (their max approximates
+                    # the op's wire time; end-to-end latency is the
+                    # manager's `allreduce` timer).
+                    with span(metrics, "comm_wire_reduce",
+                              lane=self._lane_id) as timed:
+                        result = self._execute(pending)
+                    metrics.observe(
+                        "comm_submit_wire", t_deq - pending.t_submit
+                    )
+                    metrics.observe(f"{tag}_wire_reduce", timed.elapsed)
+                else:
+                    result = self._execute(pending)
                 if pending.state is not None:
                     # Striped sub-op: only the LAST lane resolves the
                     # future (with the full donated array list — every
@@ -956,22 +973,6 @@ class _Lane:
                             pass  # a sibling lane already failed the op
                 else:
                     pending.fut.set_result(result)
-                t_done = time.perf_counter()
-                if pending.opcode in _GRAD_OPCODES:
-                    # Allreduce only: these split bench's allreduce number
-                    # along the transport's seams — a heal broadcast or
-                    # allgather landing here would pin gradient-path
-                    # regressions on checkpoint traffic. Striped ops
-                    # observe once per SUB-op: the per-lane wire_reduce is
-                    # each lane's share of the op (their max approximates
-                    # the op's wire time; end-to-end latency is the
-                    # manager's `allreduce` timer).
-                    metrics.observe(
-                        "comm_submit_wire", t_deq - pending.t_submit
-                    )
-                    metrics.observe("comm_wire_reduce", t_exec - t_deq)
-                    metrics.observe("comm_reduce_future", t_done - t_exec)
-                    metrics.observe(f"{tag}_wire_reduce", t_exec - t_deq)
             except Exception as e:  # noqa: BLE001 — latch every transport error
                 self._ctx._latch_error(e, self)
                 logger.warning(
@@ -1404,7 +1405,8 @@ class _Lane:
 
     def _ring_reduce_scatter_phase(self, p: _PendingOp,
                                    flats: Sequence[np.ndarray],
-                                   reduce_fn, vote: int) -> int:
+                                   reduce_fn, vote: int,
+                                   seams: List[float]) -> int:
         """THE reduce-scatter phase, shared verbatim by ALLREDUCE and
         REDUCE_SCATTER (the hoist the ISSUE's satellite asks for): n-1
         hops, each moving ~1/n of the lane's payload; after step s, part
@@ -1416,30 +1418,38 @@ class _Lane:
         size, so this phase always runs uncompressed; the configured
         codec applies only to the all-gather phase, where each completed
         part is encoded exactly once by its owner — the same
-        single-quantization error bound as the star path."""
+        single-quantization error bound as the star path.
+
+        ``seams`` accumulates the sub-op's seconds inside
+        :meth:`_ring_sendrecv` ([0]: socket send + receive AND the wait
+        for the ring neighbour) and inside the reduction ([1])."""
         n, r = self._world_size, self._rank
         rs_codec = _NO_CODEC
         for step in range(n - 1):
             send_views = self._part_views(flats, n, (r - step) % n)
             recv_views = self._part_views(flats, n, (r - step - 1) % n)
+            t0 = time.perf_counter()
             data, rvote = self._ring_sendrecv(
                 p.opcode, step,
                 rs_codec.encode_iovecs(send_views),
                 self._expect_len(rs_codec, send_views),
                 vote=vote,
             )
+            seams[0] += time.perf_counter() - t0
             vote |= rvote
             if len(data) != self._expect_len(rs_codec, recv_views):
                 raise ConnectionError(
                     "ring allreduce chunk size mismatch (divergent shapes?)"
                 )
+            t0 = time.perf_counter()
             rs_codec.decode_into(data, recv_views, reduce_fn)
+            seams[1] += time.perf_counter() - t0
         return vote
 
     def _ring_allgather_phase(self, p: _PendingOp,
                               flats: Sequence[np.ndarray],
                               owned: "Optional[List[bool]]",
-                              vote: int) -> int:
+                              vote: int, seams: List[float]) -> int:
         """All-gather of the completed parts. Each part is encoded ONCE
         by its owner and the received bytes are forwarded VERBATIM, so
         with a lossy codec every rank decodes identical bytes — replicas
@@ -1463,20 +1473,26 @@ class _Lane:
             carry: List = codec.encode_iovecs(own_views)
         else:
             own_bytes = _iov_join(codec.encode_iovecs(own_views))
+            t0 = time.perf_counter()
             self._decode_filtered(codec, own_bytes, own_views, owned, copy)
+            seams[1] += time.perf_counter() - t0
             carry = [own_bytes]
         carry_len = self._expect_len(codec, own_views)
         for step in range(n - 1):
             recv_views = self._part_views(flats, n, (r - step) % n)
+            t0 = time.perf_counter()
             data, rvote = self._ring_sendrecv(
                 p.opcode, n - 1 + step, carry, carry_len, vote=vote
             )
+            seams[0] += time.perf_counter() - t0
             vote |= rvote
             if len(data) != self._expect_len(codec, recv_views):
                 raise ConnectionError(
                     "ring allreduce chunk size mismatch (divergent shapes?)"
                 )
+            t0 = time.perf_counter()
             self._decode_filtered(codec, data, recv_views, owned, copy)
+            seams[1] += time.perf_counter() - t0
             carry, carry_len = [data], len(data)
         return vote
 
@@ -1503,14 +1519,25 @@ class _Lane:
         if p.opcode == _OP_REDUCE_SCATTER:
             owned = [o == self._rank for o in p.owners]
         vote = self._ctx._vote_health_bit()
-        vote = self._ring_reduce_scatter_phase(p, flats, reduce_fn, vote)
-        vote = self._ring_allgather_phase(p, flats, owned, vote)
+        seams = [0.0, 0.0]  # seconds exchanging, seconds reducing
+        cpu0 = time.thread_time()
+        vote = self._ring_reduce_scatter_phase(
+            p, flats, reduce_fn, vote, seams
+        )
+        vote = self._ring_allgather_phase(p, flats, owned, vote, seams)
         self._ctx._record_vote(vote)
+        # The sub-op along its seams, beside its wall (comm_wire_reduce):
+        # wall − exchange − reduce is Python between the seams, which a
+        # lane only spends waiting for the GIL; wall − cpu is time this
+        # thread did not run at all (neighbour, socket buffer, GIL).
+        metrics = self._ctx.metrics
+        metrics.observe("comm_subop_exchange", seams[0])
+        metrics.observe("comm_subop_reduce", seams[1])
+        metrics.observe("comm_subop_cpu", time.thread_time() - cpu0)
         # What this sub-op's hops carried: 2(n-1) hops of one rank-part
         # of each of the lane's views. The gauge is the median hop (raw
         # bytes; the n parts differ by an element a view) of the sub-op
         # that finished last.
-        metrics = self._ctx.metrics
         metrics.incr("comm_ring_hops", float(2 * (n - 1)))
         metrics.incr("comm_ring_views", float(2 * (n - 1) * len(flats)))
         if flats:
@@ -1681,9 +1708,10 @@ class TcpCommContext(CommContext):
         self._vote_lock = threading.Lock()
         self._vote_ops = 0
         self._vote_unhealthy = False
-        # Per-lane phase timers (comm_submit_wire / comm_wire_reduce /
-        # comm_reduce_future + comm_l{i}_wire_reduce). The Manager shares
-        # its own Metrics in via set_metrics so bench surfaces both.
+        # Per-lane phase timers (comm_submit_wire / comm_wire_reduce +
+        # comm_l{i}_wire_reduce, and a ring sub-op's comm_subop_*
+        # seams). The Manager shares its own Metrics in via set_metrics,
+        # so they land in its snapshot and carry its replica on a trace.
         self.metrics = Metrics()
         self.metrics.label("comm_backend", self.backend_name)
         self._events = None  # flight recorder (set_events)

@@ -32,11 +32,13 @@ caller's thread —
 The step future resolves when the last bucket has landed AND every EF
 task has finished, so ``.result()`` still means "arena quiescent,
 residuals final" exactly as in the lock-step model. Per-stage wall times
-land in the Manager's metrics (``ddp_d2h``/``ddp_ef``/``ddp_wire``/
-``ddp_h2d``, one observation per bucket) plus two per-step gauges
-(``ddp_wire_total``: summed per-bucket wire time; ``ddp_wire_exposed``:
-wire time left exposed after the submit loop finished) from which the
-bench derives ``t1_pipeline_overlap`` = 1 − exposed/total.
+land in the Manager's metrics (``ddp_d2h``/``ddp_ef``/``ddp_h2d`` spans and
+``ddp_land_queue``, one observation per bucket) plus once-a-step timings:
+the sums ``ddp_wire_total`` / ``ddp_d2h_total`` / ``ddp_h2d_total``, and
+the step thread's own time, which ``ddp_step_pack`` (the submit loop, a
+span) + ``ddp_wire_exposed`` (end of the loop → last wire completion) +
+``ddp_step_land_tail`` (→ last landing) tile from entry to the step
+future resolving (:meth:`DistributedDataParallel._observe_step`).
 
 Buckets live in step-persistent staging ARENAS (one flat host array per
 bucket per arena): D2H copies land into the arena, the transport reads
@@ -172,6 +174,70 @@ def _land_leaf(view: np.ndarray, like: Any) -> Any:
     staging arena, and a result that aliased it would be silently
     overwritten by the arena's next pack."""
     return land_like(view, like) if hasattr(like, "dtype") else view
+
+
+def _step_arg(manager) -> Dict[str, int]:
+    """``step=`` for a step's spans, so that one step's spans can be
+    joined across its threads on a trace (duck-typed: a test double of
+    the manager may not count steps)."""
+    current_step = getattr(manager, "current_step", None)
+    return {"step": int(current_step())} if callable(current_step) else {}
+
+
+class _StepClock:
+    """One classic step's clock reads. Per bucket (a slot each: landings
+    and wire continuations run on other threads): when it was submitted,
+    when its wire future resolved, the seconds of its ``ddp_d2h`` and
+    ``ddp_h2d`` stages. Of the step's thread in the submit loop, summed
+    over buckets along the loop's seams: seconds inside
+    ``jax.device_get`` (the wait for the device to finish the gradients,
+    plus the D2H DMA), inside ``pack_bucket_into`` (host memcpy into the
+    staging arena) and inside ``manager.allreduce_arrays`` (enqueue);
+    the thread's CPU time across the loop; when the loop ended.
+    ``step`` is the spans' ``step=`` (:func:`_step_arg`)."""
+
+    __slots__ = ("step", "submit_t", "wire_done_t", "d2h_t", "h2d_t",
+                 "fetch", "copy", "submit", "cpu", "t_submitted")
+
+    def __init__(self, manager, n_buckets: int) -> None:
+        self.step = _step_arg(manager)
+        self.submit_t = [0.0] * n_buckets
+        self.wire_done_t = [0.0] * n_buckets
+        self.d2h_t = [0.0] * n_buckets
+        self.h2d_t = [0.0] * n_buckets
+        self.fetch = self.copy = self.submit = self.cpu = 0.0
+        self.t_submitted = 0.0
+
+    def observe(self, metrics) -> None:
+        """The once-a-step timings, observed by whichever thread resolves
+        the step's future, right after the last landing."""
+        t_landed = time.perf_counter()
+        # Per-step sums over buckets: a step's wire cost split into
+        # staging down / socket (submit → wire done, buckets in flight
+        # together) / staging up. The benchmark reads them as
+        # wire_d2h_ms / wire_socket_ms / wire_h2d_ms, and
+        # scripts/fleet_top.py the total beside the exposed part.
+        metrics.observe("ddp_wire_total", sum(
+            done - sub for done, sub in zip(self.wire_done_t, self.submit_t)
+        ))
+        metrics.observe("ddp_d2h_total", sum(self.d2h_t))
+        metrics.observe("ddp_h2d_total", sum(self.h2d_t))
+        # The step thread's own time: the ``ddp_step_pack`` span, then
+        # these two, tile entry → step future resolved. ``exposed`` is
+        # the wire left after the submit loop ended (wire activity
+        # during pack / EF / earlier landings is hidden by construction;
+        # the benchmark's wire_exposed_ms), the tail is the last wire
+        # completion → the last landing.
+        wire_end = max(self.t_submitted, max(self.wire_done_t))
+        metrics.observe("ddp_wire_exposed", wire_end - self.t_submitted)
+        metrics.observe("ddp_step_land_tail", t_landed - wire_end)
+        # ...and what the submit loop was made of (``ddp_step_pack``
+        # minus ``ddp_step_cpu`` is time the step's thread was blocked
+        # while packing: device, DMA, GIL)
+        metrics.observe("ddp_step_fetch", self.fetch)
+        metrics.observe("ddp_step_copy", self.copy)
+        metrics.observe("ddp_step_submit", self.submit)
+        metrics.observe("ddp_step_cpu", self.cpu)
 
 
 class _BucketPlan:
@@ -521,7 +587,7 @@ class DistributedDataParallel:
 
     def _pack_bucket(self, plan: _BucketPlan, k: int,
                      leaves: List[Any], staging: List[np.ndarray],
-                     metrics, d2h_t: List[float]) -> np.ndarray:
+                     metrics, clock: _StepClock) -> np.ndarray:
         """Stage d2h: block only on bucket k's leaves and land them in
         bucket k's slice of the staging arena (the mid-backward comm-hook
         analog, ref ddp.py:49-71) — bucket k rides the wire while later
@@ -529,10 +595,14 @@ class DistributedDataParallel:
         import jax
 
         bucket = plan.buckets[k]
-        with span(metrics, "ddp_d2h", bucket=k) as timed:
+        with span(metrics, "ddp_d2h", bucket=k, **clock.step) as timed:
+            t0 = time.perf_counter()
             host_b = [np.asarray(jax.device_get(leaves[i])) for i in bucket]
+            t1 = time.perf_counter()
             packed = plan.pack_bucket_into(bucket, host_b, staging[k])
-        d2h_t[k] = timed.elapsed
+            clock.copy += time.perf_counter() - t1
+            clock.fetch += t1 - t0
+        clock.d2h_t[k] = timed.elapsed
         return packed
 
     def _ef_residual(self, transmitted: np.ndarray, res: np.ndarray,
@@ -556,16 +626,23 @@ class DistributedDataParallel:
 
     def _land_bucket(self, plan: _BucketPlan, k: int, reduced: np.ndarray,
                      in_leaves: List[Any], out_leaves: List[Any],
-                     metrics, h2d_t: List[float]) -> None:
+                     metrics, clock: _StepClock) -> None:
         """Stage h2d: unpack bucket k's reduced flat array into its
         leaves and copy each back to the device(s) of the gradient leaf
         it replaces (:func:`_land_leaf`). This runs on a pool thread, so
         the placement must come from the leaf, not from any thread-local
         default device of the caller."""
-        with span(metrics, "ddp_h2d", bucket=k) as timed:
+        with span(metrics, "ddp_h2d", bucket=k, **clock.step) as timed:
             for i, view in plan.unpack_bucket(k, reduced):
                 out_leaves[i] = _land_leaf(view, in_leaves[i])
-        h2d_t[k] = timed.elapsed
+        clock.h2d_t[k] = timed.elapsed
+
+    def _observe_step(self, metrics, clock: _StepClock) -> None:
+        """:meth:`_StepClock.observe`, skipped where a bucket never rode
+        the wire (see :meth:`_wire_healthy`)."""
+        if metrics is not None and all(clock.wire_done_t) \
+                and self._wire_healthy():
+            clock.observe(metrics)
 
     # ----------------------------------------------------------- code paths
 
@@ -584,70 +661,77 @@ class DistributedDataParallel:
         group = FutureGroup()
         n_buckets = len(plan.buckets)
         device_leaves: List[Any] = [None] * len(plan.shapes)
-        submit_t: List[float] = [0.0] * n_buckets
-        wire_done_t: List[float] = [0.0] * n_buckets
-        # per-bucket stage seconds (a slot each: landings run on pool
-        # threads), summed once a step into ddp_d2h_total / ddp_h2d_total
-        d2h_t: List[float] = [0.0] * n_buckets
-        h2d_t: List[float] = [0.0] * n_buckets
+        clock = _StepClock(self._manager, n_buckets)
 
         try:
-            for k in range(n_buckets):
-                packed = self._pack_bucket(
-                    plan, k, leaves, staging, metrics, d2h_t
-                )
-                if ef and arena.residuals[k] is not None:
-                    res = arena.residuals[k]
-                    # g' = g + e_prev stays inline (one vector add —
-                    # cheap); the quantizer roundtrip moves to the
-                    # worker, reading a SNAPSHOT of g' because the
-                    # donated buffer below is reduced in place the
-                    # moment the wire takes it.
-                    np.add(packed, res, out=packed)
-                    if arena.ef_scratch is None:
-                        arena.ef_scratch = [None] * n_buckets
-                    if arena.ef_scratch[k] is None:
-                        arena.ef_scratch[k] = np.empty_like(packed)
-                    scratch = arena.ef_scratch[k]
-                    np.copyto(scratch, packed)
-                    group.add(
-                        ef_pool.submit(
-                            self._ef_residual, scratch, res, metrics
-                        )
+            # everything the step's thread does before it can only wait,
+            # as ONE interval on its line of a trace; its CPU time across
+            # the interval is taken here, on this thread
+            cpu0 = time.thread_time()
+            with span(metrics, "ddp_step_pack", **clock.step):
+                for k in range(n_buckets):
+                    packed = self._pack_bucket(
+                        plan, k, leaves, staging, metrics, clock
                     )
-                submit_t[k] = time.perf_counter()
-                work = self._manager.allreduce_arrays(
-                    [packed], **self._ar_kwargs
-                )
-                landed: Future = Future()
-                landed.set_running_or_notify_cancel()
-                group.add(landed)
-
-                def _on_wire(wf: Future, k: int = k,
-                             landed: Future = landed) -> None:
-                    # Lane-thread continuation: timestamp + enqueue only
-                    # (the transport's O(enqueue) contract, _OpState
-                    # docstring).
-                    wire_done_t[k] = time.perf_counter()
-                    if metrics is not None and self._wire_healthy():
-                        metrics.observe(
-                            "ddp_wire", wire_done_t[k] - submit_t[k]
-                        )
-
-                    def _land() -> None:
-                        try:
-                            reduced = wf.result()[0]
-                            self._land_bucket(
-                                plan, k, reduced, leaves, device_leaves,
-                                metrics, h2d_t,
+                    if ef and arena.residuals[k] is not None:
+                        res = arena.residuals[k]
+                        # g' = g + e_prev stays inline (one vector add —
+                        # cheap); the quantizer roundtrip moves to the
+                        # worker, reading a SNAPSHOT of g' because the
+                        # donated buffer below is reduced in place the
+                        # moment the wire takes it.
+                        np.add(packed, res, out=packed)
+                        if arena.ef_scratch is None:
+                            arena.ef_scratch = [None] * n_buckets
+                        if arena.ef_scratch[k] is None:
+                            arena.ef_scratch[k] = np.empty_like(packed)
+                        scratch = arena.ef_scratch[k]
+                        np.copyto(scratch, packed)
+                        group.add(
+                            ef_pool.submit(
+                                self._ef_residual, scratch, res, metrics
                             )
-                            landed.set_result(None)
-                        except Exception as e:  # noqa: BLE001
-                            landed.set_exception(e)
+                        )
+                    clock.submit_t[k] = time.perf_counter()
+                    work = self._manager.allreduce_arrays(
+                        [packed], **self._ar_kwargs
+                    )
+                    clock.submit += time.perf_counter() - clock.submit_t[k]
+                    landed: Future = Future()
+                    landed.set_running_or_notify_cancel()
+                    group.add(landed)
 
-                    land_pool.submit(_land)
+                    def _on_wire(wf: Future, k: int = k,
+                                 landed: Future = landed) -> None:
+                        # Lane-thread continuation: timestamp + enqueue
+                        # only (the transport's O(enqueue) contract,
+                        # _OpState docstring).
+                        clock.wire_done_t[k] = time.perf_counter()
 
-                work.add_done_callback(_on_wire)
+                        def _land() -> None:
+                            # how long the finished bucket waited for one
+                            # of the process-wide pool's threads
+                            if metrics is not None and self._wire_healthy():
+                                metrics.observe(
+                                    "ddp_land_queue",
+                                    time.perf_counter()
+                                    - clock.wire_done_t[k],
+                                )
+                            try:
+                                reduced = wf.result()[0]
+                                self._land_bucket(
+                                    plan, k, reduced, leaves, device_leaves,
+                                    metrics, clock,
+                                )
+                                landed.set_result(None)
+                            except Exception as e:  # noqa: BLE001
+                                landed.set_exception(e)
+
+                        land_pool.submit(_land)
+
+                    work.add_done_callback(_on_wire)
+            clock.t_submitted = time.perf_counter()
+            clock.cpu = time.thread_time() - cpu0
         except BaseException as e:
             # Mid-loop failure with earlier buckets already ON THE WIRE
             # (reducing in place into this arena): seal the group over
@@ -668,25 +752,9 @@ class DistributedDataParallel:
             arena.inflight = group.seal(_fail)
             self._emit_abort(e)
             raise
-        t_submitted = time.perf_counter()
 
         def _assemble():
-            if metrics is not None and self._wire_healthy():
-                # Per-step overlap gauges: total wire time across buckets
-                # vs the slice of it left exposed after the submit loop
-                # ended (wire activity during pack/EF/earlier landings is
-                # hidden by construction). The bench turns these into
-                # t1_pipeline_overlap = 1 - exposed/total.
-                total = sum(
-                    wire_done_t[k] - submit_t[k] for k in range(n_buckets)
-                )
-                exposed = max(0.0, max(wire_done_t) - t_submitted)
-                metrics.observe("ddp_wire_total", total)
-                metrics.observe("ddp_wire_exposed", exposed)
-                # ...and the host staging either side of the socket, so
-                # a step's wire cost splits into down / socket / up
-                metrics.observe("ddp_d2h_total", sum(d2h_t))
-                metrics.observe("ddp_h2d_total", sum(h2d_t))
+            self._observe_step(metrics, clock)
             return jax.tree_util.tree_unflatten(treedef, device_leaves)
 
         fut = group.seal(_assemble)
@@ -706,39 +774,34 @@ class DistributedDataParallel:
         staging = arena.staging
         n_buckets = len(plan.buckets)
         works = []
-        submit_t: List[float] = [0.0] * n_buckets
-        wire_done_t: List[float] = [0.0] * n_buckets
-        # per-bucket stage seconds (a slot each: landings run on pool
-        # threads), summed once a step into ddp_d2h_total / ddp_h2d_total
-        d2h_t: List[float] = [0.0] * n_buckets
-        h2d_t: List[float] = [0.0] * n_buckets
+        clock = _StepClock(self._manager, n_buckets)
         try:
-            for k in range(n_buckets):
-                packed = self._pack_bucket(
-                    plan, k, leaves, staging, metrics, d2h_t
-                )
-                if ef and arena.residuals[k] is not None:
-                    res = arena.residuals[k]
-                    np.add(packed, res, out=packed)
-                    self._ef_residual(packed, res, metrics)
-                submit_t[k] = time.perf_counter()
-                work = self._manager.allreduce_arrays(
-                    [packed], **self._ar_kwargs
-                )
-                works.append(work)
-                if metrics is not None:
-                    # Same per-bucket wire observability as the streamed
-                    # path (timestamp-only continuation — O(enqueue)),
-                    # so an A/B run measures both arms' wire time rather
-                    # than reporting the lock-step arm as null.
+            cpu0 = time.thread_time()
+            with span(metrics, "ddp_step_pack", **clock.step):
+                for k in range(n_buckets):
+                    packed = self._pack_bucket(
+                        plan, k, leaves, staging, metrics, clock
+                    )
+                    if ef and arena.residuals[k] is not None:
+                        res = arena.residuals[k]
+                        np.add(packed, res, out=packed)
+                        self._ef_residual(packed, res, metrics)
+                    clock.submit_t[k] = time.perf_counter()
+                    work = self._manager.allreduce_arrays(
+                        [packed], **self._ar_kwargs
+                    )
+                    clock.submit += time.perf_counter() - clock.submit_t[k]
+                    works.append(work)
+
+                    # Timestamp-only continuation (O(enqueue)), so an A/B
+                    # run measures both arms' wire time rather than
+                    # reporting the lock-step arm as null.
                     def _mark(wf: Future, k: int = k) -> None:
-                        wire_done_t[k] = time.perf_counter()
-                        if self._wire_healthy():
-                            metrics.observe(
-                                "ddp_wire", wire_done_t[k] - submit_t[k]
-                            )
+                        clock.wire_done_t[k] = time.perf_counter()
 
                     work.add_done_callback(_mark)
+            clock.t_submitted = time.perf_counter()
+            clock.cpu = time.thread_time() - cpu0
         except BaseException as e:
             # Same guard-integrity rule as the streamed path: buckets
             # already submitted keep reducing in place into this arena —
@@ -757,7 +820,6 @@ class DistributedDataParallel:
             )
             self._emit_abort(e)
             raise
-        t_submitted = time.perf_counter()
 
         def _finish(_f) -> Any:
             # future_all already resolved every bucket future — collect
@@ -768,18 +830,9 @@ class DistributedDataParallel:
                 reduced = w.future().result()[0]
                 self._land_bucket(
                     plan, k, reduced, leaves, device_leaves, metrics,
-                    h2d_t,
+                    clock,
                 )
-            if metrics is not None and all(wire_done_t) \
-                    and self._wire_healthy():
-                metrics.observe("ddp_wire_total", sum(
-                    wire_done_t[k] - submit_t[k] for k in range(n_buckets)
-                ))
-                metrics.observe("ddp_wire_exposed", max(
-                    0.0, max(wire_done_t) - t_submitted
-                ))
-                metrics.observe("ddp_d2h_total", sum(d2h_t))
-                metrics.observe("ddp_h2d_total", sum(h2d_t))
+            self._observe_step(metrics, clock)
             return jax.tree_util.tree_unflatten(treedef, device_leaves)
 
         fut = future_chain(
@@ -1006,8 +1059,9 @@ class ShardedGradReducer:
                 ]
                 arena.ef_generation = gen
 
+        step = _step_arg(mgr)
         for k, bucket in enumerate(plan.buckets):
-            with span(metrics, "ddp_d2h", bucket=k):
+            with span(metrics, "ddp_d2h", bucket=k, **step):
                 host_b = [
                     np.asarray(jax.device_get(leaves[i])) for i in bucket
                 ]

@@ -407,10 +407,11 @@ class Manager:
         # One metrics sink for the whole step pipeline: the Manager's own
         # timers (quorum / commit_barrier / allreduce), the transport's
         # per-lane and per-op phase timers (comm_submit_wire /
-        # comm_wire_reduce / comm_reduce_future / comm_op_wire, shared in
+        # comm_wire_reduce / comm_subop_* / comm_op_wire, shared in
         # via set_metrics below), and the DDP wrapper's per-bucket stage
-        # timers (ddp_d2h / ddp_ef / ddp_wire / ddp_h2d plus the
-        # ddp_wire_total / ddp_wire_exposed overlap gauges — the DDP
+        # timers (ddp_d2h / ddp_ef / ddp_land_queue / ddp_h2d plus the
+        # once-a-step ddp_wire_total / ddp_step_pack / ddp_wire_exposed
+        # / ddp_step_land_tail — the DDP
         # layer reads this sink through ``manager.metrics``), and the
         # outer-sync fragment scheduler's stage timers (outer_d2h /
         # outer_ef / outer_wire / outer_land plus the per-round
@@ -444,7 +445,7 @@ class Manager:
             f"{self._transport_world_size}x{self.model_shards}",
         )
         # Share our metrics sink with the transport so its per-lane phase
-        # timers (comm_submit_wire / comm_wire_reduce / comm_reduce_future)
+        # timers (comm_submit_wire / comm_wire_reduce / comm_subop_*)
         # land next to quorum/commit_barrier/allreduce in one snapshot.
         set_metrics = getattr(comm, "set_metrics", None)
         if callable(set_metrics):
