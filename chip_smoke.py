@@ -2,8 +2,9 @@
 
 Run it from the root of a checkout, on a machine with a TPU:
 
-    python chip_smoke.py              # stages kernels, ft, procs
+    python chip_smoke.py              # stages kernels, landing, ft, procs
     python chip_smoke.py --xla-plane  # stage ft over Manager(comm_backend="xla")
+    python chip_smoke.py --landing    # stage landing alone
 
 It drives the fault-tolerant training loop once, through the entry points a
 user calls, at the full configured width of ``CONFIGS["125m"]`` with seeded
@@ -13,6 +14,13 @@ random weights, and checks what comes out by the repo's own means:
              resident and the streamed regime, at the heads of the 125m/350m
              and the 1b presets) against ``ops.attention.reference_attention``,
              and the chunked cross entropy against the dense one.
+``landing``  one group on one chip averages a gradient tree of the 111m
+             benchmark cell's bucket sizes through a one-arena
+             ``DistributedDataParallel``; the arena is overwritten with
+             NaN the moment each step's future resolves, and the landed
+             leaves must still equal the reduced values bit for bit, with
+             every byte landed straight from the arena
+             (``ddp_land_borrowed_bytes``).
 ``ft``       every chip of the machine in ONE process: ``max(2, chips)``
              replica groups as threads, group g pinned to chip g mod chips,
              each with its own StoreServer, Manager, DistributedDataParallel
@@ -849,6 +857,132 @@ def _stage_kernels(_args: argparse.Namespace) -> Dict[str, Any]:
     return {"checks": len(checks)}
 
 
+# Cerebras-GPT-111M's gradient tree (the benchmark's four-group cell): 13
+# buckets of the default 32 MiB plan, 598.6 MB, the two tables 154.5 MB each.
+_LANDING_SHAPES: Dict[str, Any] = {
+    "wte": (50304, 768), "wpe": (2048, 768),
+    **{f"h{i}": {"ln1": (2, 768), "qkv": (768, 2304), "proj": (768, 768),
+                 "ln2": (2, 768), "fc": (768, 3072), "out": (3072, 768)}
+       for i in range(10)},
+    "ln_f": (2, 768), "lm_head": (768, 50304),
+}
+
+
+class _HalvingWire:
+    """The surface ``DistributedDataParallel`` needs of a Manager, over a
+    wire of one: every bucket comes back halved IN PLACE (the donation
+    contract) from a thread of its own, as from a transport lane."""
+
+    def __init__(self) -> None:
+        from torchft_tpu.utils.metrics import Metrics
+
+        self.metrics = Metrics()
+
+    def wait_quorum(self) -> None:
+        pass
+
+    def is_solo_wire(self) -> bool:
+        return False
+
+    def allreduce_arrays(self, arrays: Sequence[Any]) -> Any:
+        from concurrent.futures import Future
+
+        from torchft_tpu.comm.context import Work
+
+        fut: "Future[List[Any]]" = Future()
+        fut.set_running_or_notify_cancel()
+        arrays = list(arrays)
+
+        def _reduce() -> None:
+            for a in arrays:
+                a *= a.dtype.type(0.5)
+            fut.set_result(arrays)
+
+        threading.Thread(target=_reduce, daemon=True).start()
+        return Work(fut)
+
+
+def run_landing_check(device: Any, shapes: Any = None,
+                      steps: int = 3) -> Dict[str, Any]:
+    """Average a seeded tree on ``device`` ``steps`` times through ONE
+    staging arena, poisoning the arena the moment each step's future
+    resolves; every landed leaf must equal half its gradient bit for bit.
+    Returns the sink's landing counters and, as observations, a step's
+    summed landing seconds beside the same views landed leaf by leaf
+    through ``land_like``'s host copy."""
+    import jax
+    import numpy as np
+
+    from torchft_tpu.ddp import DistributedDataParallel
+    from torchft_tpu.utils.device import land_like
+
+    shapes = _LANDING_SHAPES if shapes is None else shapes
+    rng = np.random.default_rng(36)
+    host = jax.tree_util.tree_map(
+        lambda shape: rng.standard_normal(shape, dtype=np.float32),
+        shapes, is_leaf=lambda x: isinstance(x, tuple),
+    )
+    grads = jax.device_put(host, device)
+    wire = _HalvingWire()
+    ddp = DistributedDataParallel(wire, staging_arenas=1)
+
+    poisoned = threading.Event()
+
+    def poison(_fut: Any) -> None:
+        for buf in ddp._arenas[0].staging:
+            buf.fill(np.nan)
+        poisoned.set()
+
+    for step in range(steps):
+        poisoned.clear()
+        fut = ddp.average_gradients_async(grads)
+        # on the resolving thread, the moment the arena may be reused
+        fut.add_done_callback(poison)
+        out = fut.result(timeout=_TIMEOUT_S)
+        assert poisoned.wait(_TIMEOUT_S)    # not into the next step's pack
+        for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(out),
+            jax.tree_util.tree_leaves(host),
+        ):
+            assert got.devices() == {device}, (path, got.devices())
+            assert np.array_equal(
+                np.asarray(got).view(np.uint32),
+                (want * np.float32(0.5)).view(np.uint32),
+            ), f"step {step}: landed leaf {path} is not the reduced value"
+    snap = wire.metrics.snapshot()
+
+    # observation: the same bytes through the copying landing, one thread
+    leaves = jax.tree_util.tree_leaves(grads)
+    t0 = time.perf_counter()
+    jax.block_until_ready([
+        land_like(h, like)
+        for h, like in zip(jax.tree_util.tree_leaves(host), leaves)
+    ])
+    copied_s = time.perf_counter() - t0
+    nbytes = sum(h.nbytes for h in jax.tree_util.tree_leaves(host))
+    return {
+        "device": str(device), "steps": steps, "bytes_per_step": nbytes,
+        "buckets": len(ddp._plan.buckets),
+        "borrowed_bytes": int(snap["ddp_land_borrowed_bytes"]),
+        "copied_bytes": int(snap["ddp_land_copied_bytes"]),
+        "land_workers": int(snap["ddp_land_workers"]),
+        "h2d_total_p50_ms": round(snap["ddp_h2d_total_p50_ms"], 1),
+        "land_like_ms": round(copied_s * 1e3, 1),
+    }
+
+
+def _stage_landing(_args: argparse.Namespace) -> Dict[str, Any]:
+    import jax
+
+    result = run_landing_check(jax.devices()[0])
+    assert result["copied_bytes"] == 0 and result["borrowed_bytes"] == \
+        result["steps"] * result["bytes_per_step"], (
+        f"on a TPU every byte lands straight from the arena: {result}"
+    )
+    assert result["land_workers"] == 2, result
+    return result
+
+
 def _ft_batch(n_groups: int, n_chips: int) -> int:
     """Rows per group: 8 on a chip of its own, 4 when two groups share the
     16 GB of one (each holds params, optimizer state, gradients, averaged
@@ -942,7 +1076,8 @@ def _child_main(args: argparse.Namespace) -> None:
         return
     device_line = _claim_tpu()
     counter = _compile_counter()
-    result = {"kernels": _stage_kernels, "ft": _stage_ft}[args.child](args)
+    result = {"kernels": _stage_kernels, "landing": _stage_landing,
+              "ft": _stage_ft}[args.child](args)
     result["device"] = device_line
     result["compile_cache_hits"] = counter.cache_hits
     result["compile_cache_misses"] = counter.cache_misses
@@ -1018,13 +1153,16 @@ def main() -> None:
     ap.add_argument("--xla-plane", action="store_true",
                     help="run stage ft alone over Manager(comm_backend="
                          "'xla'), psum uncompressed and int8")
+    ap.add_argument("--landing", action="store_true",
+                    help="run stage landing alone")
     ap.add_argument("--cfg", default="125m", help=argparse.SUPPRESS)
     ap.add_argument("--steps-per-leg", type=int, default=3,
                     help=argparse.SUPPRESS)
     ap.add_argument("--timeout", type=float, default=_TIMEOUT_S,
                     help=argparse.SUPPRESS)
     # internal: how the parent starts its children
-    ap.add_argument("--child", choices=("kernels", "ft", "worker"),
+    ap.add_argument("--child",
+                    choices=("kernels", "landing", "ft", "worker"),
                     help=argparse.SUPPRESS)
     ap.add_argument("--lighthouse", help=argparse.SUPPRESS)
     ap.add_argument("--batch", type=int, default=8, help=argparse.SUPPRESS)
@@ -1046,7 +1184,9 @@ def main() -> None:
     common = ["--cfg", args.cfg, "--steps-per-leg", str(args.steps_per_leg),
               "--timeout", str(args.timeout)]
     results: Dict[str, Any] = {}
-    if args.xla_plane:
+    if args.landing:
+        results["landing"] = _run_stage("landing", common)
+    elif args.xla_plane:
         lh = scenario_lighthouse()
         try:
             results["ft"] = _run_stage(
@@ -1056,6 +1196,7 @@ def main() -> None:
             lh.shutdown()
     else:
         results["kernels"] = _run_stage("kernels", common)
+        results["landing"] = _run_stage("landing", common)
         lh = scenario_lighthouse()
         try:
             results["ft"] = _run_stage(
@@ -1072,7 +1213,7 @@ def main() -> None:
                 lh.shutdown()
         else:
             _log("=== stage procs not_applicable: 1 chip")
-    device = results["ft"]["device"]
+    device = next(iter(results.values()))["device"]
     hits = sum(r.get("compile_cache_hits", 0) for r in results.values())
     misses = sum(r.get("compile_cache_misses", 0) for r in results.values())
     _log(f"chip_smoke ok in {time.perf_counter() - t0:.0f}s on "

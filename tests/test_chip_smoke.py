@@ -69,6 +69,48 @@ def test_residency_check_names_a_stray_leaf(lighthouse) -> None:
         group.shutdown()
 
 
+_SMALL_TREE = {"wte": (96, 16), "h0": {"ln1": (2, 16), "qkv": (16, 48)},
+               "lm_head": (16, 96)}
+
+
+def test_landing_check_runs_and_counts_on_a_cpu_device() -> None:
+    # the stage's body at a small tree: the arena poisoned the moment each
+    # step resolves, the landed leaves still the reduced values. Here
+    # every byte goes through the host copy; the stage itself asserts the
+    # opposite on the chip.
+    result = chip_smoke.run_landing_check(jax.devices()[1], _SMALL_TREE)
+    assert result["copied_bytes"] == 3 * result["bytes_per_step"] > 0
+    assert result["borrowed_bytes"] == 0
+    assert result["land_workers"] >= 2
+
+
+def test_landing_check_fails_on_a_landing_that_aliases_the_arena(
+    monkeypatch,
+) -> None:
+    from torchft_tpu import ddp
+
+    dev = jax.devices()[1]
+
+    class _Alias:
+        """A landed leaf that still is the arena's view."""
+
+        def __init__(self, view) -> None:
+            self.view = view
+
+        def devices(self):
+            return {dev}
+
+        def __array__(self, dtype=None, copy=None):
+            return self.view
+
+    monkeypatch.setattr(
+        ddp, "land_batch",
+        lambda views, likes: ([_Alias(v) for v in views], 0, 0),
+    )
+    with pytest.raises(AssertionError, match="not the reduced value"):
+        chip_smoke.run_landing_check(dev, _SMALL_TREE)
+
+
 def test_chip_smoke_refuses_to_run_without_a_tpu() -> None:
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(

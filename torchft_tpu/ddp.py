@@ -25,15 +25,20 @@ caller's thread —
           OFF the submit path, so bucket k+1's pack/submit never stalls
           behind bucket k's quantizer)
     wire  the transport round trip (lanes; chunk-striped)
-    h2d   unpack + the copy back to each gradient leaf's own device(s),
-          per bucket AS ITS WIRE FUTURE COMPLETES (continuation →
-          bounded worker), out of order — not after a global drain
+    h2d   unpack + the landing on each gradient leaf's own device(s),
+          per bucket AS ITS WIRE FUTURE COMPLETES (continuation → one
+          of the two workers of the PLACEMENT the bucket lands on), out
+          of order — not after a global drain. Straight from the arena
+          where the target cannot alias host memory, through a host copy
+          where it can (utils/device.land_batch)
 
 The step future resolves when the last bucket has landed AND every EF
 task has finished, so ``.result()`` still means "arena quiescent,
 residuals final" exactly as in the lock-step model. Per-stage wall times
 land in the Manager's metrics (``ddp_d2h``/``ddp_ef``/``ddp_h2d`` spans and
-``ddp_land_queue``, one observation per bucket) plus once-a-step timings:
+``ddp_land_queue``, one observation per bucket; counters
+``ddp_land_borrowed_bytes`` / ``ddp_land_copied_bytes`` and gauge
+``ddp_land_workers``) plus once-a-step timings:
 the sums ``ddp_wire_total`` / ``ddp_d2h_total`` / ``ddp_h2d_total``, and
 the step thread's own time, which ``ddp_step_pack`` (the submit loop, a
 span) + ``ddp_wire_exposed`` (end of the loop → last wire completion) +
@@ -89,12 +94,12 @@ import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from torchft_tpu.futures import FutureGroup, future_all, future_chain
-from torchft_tpu.utils.device import land_like
+from torchft_tpu.utils.device import land_batch, land_like, placement
 from torchft_tpu.utils.profiling import span
 
 __all__ = [
@@ -113,23 +118,51 @@ _DEFAULT_BUCKET_BYTES = 32 * 1024 * 1024
 # pools: an EF roundtrip over a 32MB bucket is the heaviest task in the
 # pipeline, and on a shared pool two back-to-back EF tasks would queue
 # every completed bucket's landing behind them — re-serializing the
-# pipeline exactly in the lossy-codec configuration it targets. Tasks
-# never block on other tasks (both stages are pure compute), so the
-# bounded pools cannot deadlock.
+# pipeline exactly in the lossy-codec configuration it targets. Landings
+# are further keyed by PLACEMENT — the devices of the leaves a bucket
+# replaces — with two workers each, made on first use: a transfer to one
+# chip never waits behind another chip's (several replica groups as
+# threads of one process, a chip each, finish their buckets together
+# because the ring is a barrier), while one group, or any number of
+# wrappers on one device, hold exactly two. The pools live as long as
+# the process; there are as many as distinct placements it has landed
+# on. Tasks never block on other tasks (an EF task is pure compute, a
+# landing waits only for its own transfers), so the bounded pools
+# cannot deadlock.
 _PIPELINE_LOCK = threading.Lock()
-_PIPELINE_EXECUTORS: "Dict[str, ThreadPoolExecutor]" = {}
+_PIPELINE_WORKERS = 2
+_PIPELINE_EXECUTORS: (
+    "Dict[Tuple[str, FrozenSet[Any]], ThreadPoolExecutor]"
+) = {}
 
 
-def _pipeline_executor(kind: str) -> ThreadPoolExecutor:
+def _pipeline_executor(
+    kind: str, placed: "FrozenSet[Any]" = frozenset()
+) -> ThreadPoolExecutor:
+    """The pool of stage ``kind`` for buckets that land on the devices
+    ``placed`` (:func:`torchft_tpu.utils.device.placement`; none for the
+    ``ef`` stage, which touches no device)."""
     with _PIPELINE_LOCK:
-        ex = _PIPELINE_EXECUTORS.get(kind)
+        ex = _PIPELINE_EXECUTORS.get((kind, placed))
         if ex is None:
+            # the lowest device id and how many: a thread's line on a
+            # trace names the chip it lands on
+            tag = f"_d{min(d.id for d in placed)}x{len(placed)}" \
+                if placed else ""
             ex = ThreadPoolExecutor(
-                max_workers=2,
-                thread_name_prefix=f"torchft_tpu_ddp_{kind}",
+                max_workers=_PIPELINE_WORKERS,
+                thread_name_prefix=f"torchft_tpu_ddp_{kind}{tag}",
             )
-            _PIPELINE_EXECUTORS[kind] = ex
+            _PIPELINE_EXECUTORS[(kind, placed)] = ex
         return ex
+
+
+def _land_workers() -> int:
+    """Landing workers this process holds (gauge ``ddp_land_workers``)."""
+    with _PIPELINE_LOCK:
+        return _PIPELINE_WORKERS * sum(
+            kind == "land" for kind, _placed in _PIPELINE_EXECUTORS
+        )
 
 
 def _ef_dtype(dt: np.dtype) -> bool:
@@ -170,9 +203,12 @@ def _ef_gate(manager, error_feedback: "bool | str") -> bool:
 def _land_leaf(view: np.ndarray, like: Any) -> Any:
     """The averaged gradient for leaf ``like``, as a device array with
     ``like``'s dtype and sharding (a numpy gradient's lands on the default
-    device). Always a COPY of ``view``: the views point into a reusable
-    staging arena, and a result that aliased it would be silently
-    overwritten by the arena's next pack."""
+    device). Always a COPY of ``view`` made on the host first
+    (:func:`land_like`): this is the leaf-by-leaf landing of
+    :class:`PureDistributedDataParallel`, whose ``view`` is the
+    transport's own result buffer and which owns no arena whose reuse it
+    could hold back until a transfer has read it. The bucketed wrapper
+    lands through :func:`land_batch` instead (:meth:`_land_bucket`)."""
     return land_like(view, like) if hasattr(like, "dtype") else view
 
 
@@ -628,14 +664,26 @@ class DistributedDataParallel:
                      in_leaves: List[Any], out_leaves: List[Any],
                      metrics, clock: _StepClock) -> None:
         """Stage h2d: unpack bucket k's reduced flat array into its
-        leaves and copy each back to the device(s) of the gradient leaf
-        it replaces (:func:`_land_leaf`). This runs on a pool thread, so
+        leaves and land each on the device(s) of the gradient leaf it
+        replaces (:func:`land_batch`). This runs on a pool thread, so
         the placement must come from the leaf, not from any thread-local
-        default device of the caller."""
+        default device of the caller. A result never aliases the arena:
+        on a CPU device, or where a cast is needed, each view is copied
+        on the host first; on an accelerator the views themselves are
+        handed to the transfer, and this returns — the bucket counts as
+        landed, and the step's future, the arena's ``inflight`` guard,
+        can resolve — only once the transfers have read them."""
         with span(metrics, "ddp_h2d", bucket=k, **clock.step) as timed:
-            for i, view in plan.unpack_bucket(k, reduced):
-                out_leaves[i] = _land_leaf(view, in_leaves[i])
+            indices, views = zip(*plan.unpack_bucket(k, reduced))
+            landed, borrowed, copied = land_batch(
+                views, [in_leaves[i] for i in indices]
+            )
+            for i, leaf in zip(indices, landed):
+                out_leaves[i] = leaf
         clock.h2d_t[k] = timed.elapsed
+        if metrics is not None:
+            metrics.incr("ddp_land_borrowed_bytes", borrowed)
+            metrics.incr("ddp_land_copied_bytes", copied)
 
     def _observe_step(self, metrics, clock: _StepClock) -> None:
         """:meth:`_StepClock.observe`, skipped where a bucket never rode
@@ -656,7 +704,6 @@ class DistributedDataParallel:
 
         metrics = self._metrics()
         staging = arena.staging
-        land_pool = _pipeline_executor("land")
         ef_pool = _pipeline_executor("ef")
         group = FutureGroup()
         n_buckets = len(plan.buckets)
@@ -700,9 +747,14 @@ class DistributedDataParallel:
                     landed: Future = Future()
                     landed.set_running_or_notify_cancel()
                     group.add(landed)
+                    land_pool = _pipeline_executor(
+                        "land", placement(leaves[i] for i in plan.buckets[k])
+                    )
 
                     def _on_wire(wf: Future, k: int = k,
-                                 landed: Future = landed) -> None:
+                                 landed: Future = landed,
+                                 land_pool: ThreadPoolExecutor = land_pool,
+                                 ) -> None:
                         # Lane-thread continuation: timestamp + enqueue
                         # only (the transport's O(enqueue) contract,
                         # _OpState docstring).
@@ -710,7 +762,7 @@ class DistributedDataParallel:
 
                         def _land() -> None:
                             # how long the finished bucket waited for one
-                            # of the process-wide pool's threads
+                            # of its placement's two workers
                             if metrics is not None and self._wire_healthy():
                                 metrics.observe(
                                     "ddp_land_queue",
@@ -732,6 +784,8 @@ class DistributedDataParallel:
                     work.add_done_callback(_on_wire)
             clock.t_submitted = time.perf_counter()
             clock.cpu = time.thread_time() - cpu0
+            if metrics is not None:
+                metrics.gauge("ddp_land_workers", _land_workers())
         except BaseException as e:
             # Mid-loop failure with earlier buckets already ON THE WIRE
             # (reducing in place into this arena): seal the group over

@@ -8,17 +8,24 @@ every entry point and every host-to-device landing shares.
   checkout, placeable from outside through ``JAX_COMPILATION_CACHE_DIR``.
 * :func:`land` / :func:`land_like` — a host array that replaces a device
   leaf returns to that leaf's dtype and sharding (its device, or its mesh
-  layout), never to the process's default device.
+  layout), never to the process's default device. Always through a fresh
+  host copy.
+* :func:`land_batch` — the same for a whole bucket of staging-arena views,
+  without the host copy where the target cannot alias host memory;
+  :func:`placement` is where such a bucket lands.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Any, Sequence
+from typing import Any, FrozenSet, Iterable, List, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["land", "land_like", "place_compile_cache", "require_tpu"]
+__all__ = [
+    "land", "land_batch", "land_like", "place_compile_cache", "placement",
+    "require_tpu",
+]
 
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -79,3 +86,62 @@ def land_like(host: np.ndarray, like: Any, dtype: Any = None) -> Any:
         host, getattr(like, "sharding", None),
         like.dtype if dtype is None else dtype,
     )
+
+
+def placement(likes: Iterable[Any]) -> FrozenSet[Any]:
+    """Where the leaves ``likes`` live: the union of their shardings'
+    devices. A numpy leaf adds none (it lands on the default device)."""
+    devices: set = set()
+    for like in likes:
+        sharding = getattr(like, "sharding", None)
+        if sharding is not None:
+            devices |= sharding.device_set
+    return frozenset(devices)
+
+
+def _may_alias_host(sharding: Any) -> bool:
+    """Whether a buffer put to ``sharding`` may alias the numpy memory it
+    was put from: the CPU backend's zero-copy ``device_put`` does, an
+    accelerator's memory cannot. No sharding — the default device,
+    whatever it is — counts as may."""
+    return sharding is None or any(
+        d.platform == "cpu" for d in sharding.device_set
+    )
+
+
+def land_batch(views: Sequence[np.ndarray],
+               likes: Sequence[Any]) -> Tuple[List[Any], int, int]:
+    """:func:`land_like` for one bucket: ``views[j]`` (a slice of a
+    reusable staging arena) replaces the device leaf ``likes[j]``.
+    Returns the device arrays and the bytes landed straight from the
+    arena / through a host copy.
+
+    Where no target device of a leaf can alias host memory
+    (:func:`_may_alias_host`) and the view already has the leaf's dtype
+    and is contiguous, the view itself is handed to ``device_put`` — no
+    intermediate copy into fresh pages — and the bucket's transfers are
+    issued back to back. The arena must then stay as it is until they have
+    read it: this returns only once every such array is ready, so a
+    caller that reports the bucket landed after this call (and repacks
+    the arena only after that report) holds the lifetime. Everywhere
+    else — a CPU device, a cast — the copy of :func:`land` stays (a
+    cast is the one copy either way)."""
+    import jax
+
+    out: List[Any] = []
+    lent: List[Any] = []
+    borrowed = copied = 0
+    try:
+        for view, like in zip(views, likes):
+            sharding = getattr(like, "sharding", None)
+            if (not _may_alias_host(sharding) and view.dtype == like.dtype
+                    and view.flags.c_contiguous):
+                out.append(jax.device_put(view, sharding))
+                lent.append(out[-1])
+                borrowed += view.nbytes
+            else:
+                out.append(land(view, sharding, like.dtype))
+                copied += view.nbytes
+    finally:
+        jax.block_until_ready(lent)
+    return out, borrowed, copied
