@@ -81,8 +81,9 @@ def init_state(model: Model, seed: int, device: Any) -> Dict[str, Any]:
 
     import numpy as np
 
+    # --seed may pass 2**31; below it the key is the one np.int32(seed) gave
     return jax.jit(make, out_shardings=SingleDeviceSharding(device))(
-        np.int32(seed)
+        np.uint32(seed & 0xFFFFFFFF)
     )
 
 

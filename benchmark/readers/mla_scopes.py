@@ -3,12 +3,11 @@
 roofline. What ``device_scopes`` files whole under ``attn`` is split
 into the projections (``mla_q`` + ``mla_kv`` + ``mla_out``: down, norm,
 up, RoPE, laying ``k`` out, the output matmul) and ``mla_core`` (the
-flash calls); ``moe_shared`` is the shared expert inside ``mlp`` (the
-other inner scopes of the sparse sublayer are ``moe_scopes``'); ``mtp``
-is every operation whose path holds the ``mtp`` scope, whatever else it
-lies in. The metric's file names which: ``{"reader": "mla_scopes",
-"what": "proj" | "core" | "moe_shared" | "mtp" | "flash_fwd_roofline" |
-"flash_dq_roofline" | "flash_dkv_roofline"}``.
+flash calls); ``mtp`` is every operation whose path holds the ``mtp``
+scope, whatever else it lies in (the sparse sublayer's inner scopes, the
+shared expert's among them, are ``moe_scopes``'). The metric's file
+names which: ``{"reader": "mla_scopes", "what": "proj" | "core" | "mtp" |
+"flash_fwd_roofline" | "flash_dq_roofline" | "flash_dkv_roofline"}``.
 
 Read with ``device_scopes``' own functions (the newest trace, self
 times, the programs line, the program's instruction -> ``op_name``
@@ -44,7 +43,7 @@ from benchmark.readers import device_scopes
 
 # inner scope as it stands in an op_name path -> the share's name
 INNER = {"mla_q": "proj", "mla_kv": "proj", "mla_out": "proj",
-         "mla_core": "core", "moe_shared": "moe_shared"}
+         "mla_core": "core"}
 MTP = "mtp"
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 

@@ -1,10 +1,12 @@
 """The sparse-expert sublayer's device time by its inner scopes, and the
 grouped matmuls' share of the chip's peak: what ``device_scopes`` files
 whole under ``mlp`` is split by the ``jax.named_scope``s of
-``torchft_tpu/models/olmoe.py`` and ``ops/moe.py`` — ``moe_router``,
-``moe_dispatch`` + ``moe_combine`` (the data movement), ``moe_experts``.
-The metric's file names which: ``{"reader": "moe_scopes", "what":
-"router" | "dispatch" | "experts" | "experts_roofline"}``.
+the sparse families' models and ``ops/moe.py`` — ``moe_router``,
+``moe_dispatch`` + ``moe_combine`` (the data movement), ``moe_experts``
+and, where the family has one, ``moe_shared`` (the shared expert, a
+sibling of the other four). The metric's file names which: ``{"reader":
+"moe_scopes", "what": "router" | "dispatch" | "experts" | "shared" |
+"experts_roofline"}``.
 
 Read with ``device_scopes``' own functions (the newest trace, self
 times, the programs line, the program's instruction -> ``op_name``
@@ -38,7 +40,8 @@ from benchmark.readers import device_scopes
 
 # inner scope as it stands in an op_name path -> the share's name
 INNER = {"moe_router": "router", "moe_dispatch": "dispatch",
-         "moe_combine": "dispatch", "moe_experts": "experts"}
+         "moe_combine": "dispatch", "moe_experts": "experts",
+         "moe_shared": "shared"}
 # the grouped-matmul kernels' instructions: ``gmm.5``, ``tgmm.2`` (the
 # second is the gradient of the weights)
 KERNELS = ("gmm", "tgmm")
@@ -93,9 +96,13 @@ def reduce(ops: Dict[int, List[device_scopes.Op]],
                 step[kernel] += 1
     if total <= 0 or not any(seconds.values()):
         return None
+    # the shares whose scope some program has: one it has not is not read
+    # (no shared expert), one it has and that took no time reads 0
+    present = {inner_scope(path) for table in tables.values()
+               for path in table.values()} - {None}
     return {"shares": {k: s / total for k, s in seconds.items()},
             "seconds": seconds, "steps": list(steps.values()),
-            "total_s": total}
+            "total_s": total, "present": present}
 
 
 def _reduction(record: Dict[str, Any]) -> Optional[Dict[str, Any]]:
@@ -180,6 +187,8 @@ def read(record: Dict[str, Any], spec: Dict[str, Any]) -> Optional[float]:
     if result is None:
         return None
     if spec["what"] != "experts_roofline":
+        if spec["what"] not in result["present"]:
+            return None
         return float(result["shares"][spec["what"]])
     shapes = cell_shapes(device_scopes.newest_trace())
     if shapes is None:

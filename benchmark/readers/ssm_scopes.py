@@ -5,12 +5,11 @@ sequence mixers stand there — is split into the Mamba-2 mixer's
 ``ssm_in`` + ``ssm_out`` (norm, the two projections, the split),
 ``ssm_conv`` + ``ssm_gate`` (convolution, silu, gate, grouped norm) and
 ``ssm_scan`` (softplus, cumulative sums, the kernels, what XLA does
-around them), and the attention mixer's ``gqa_proj`` + ``gqa_core``;
-``moe_shared`` is the shared expert inside ``mlp`` (the other inner
-scopes of the sparse sublayer are ``moe_scopes``'). The metric's file
-names which: ``{"reader": "ssm_scopes", "what": "ssm" | "ssm_scan" |
-"ssm_proj" | "ssm_conv_gate" | "gqa" | "moe_shared" | "ssd_fwd_roofline"
-| "ssd_bwd_roofline"}``.
+around them), and the attention mixer's ``gqa_proj`` + ``gqa_core``
+(the sparse sublayer's inner scopes, the shared expert's among them, are
+``moe_scopes``'). The metric's file names which: ``{"reader":
+"ssm_scopes", "what": "ssm" | "ssm_scan" | "ssm_proj" | "ssm_conv_gate" |
+"gqa" | "ssd_fwd_roofline" | "ssd_bwd_roofline"}``.
 
 Read with ``device_scopes``' own functions (the newest trace, self
 times, the programs line, the program's instruction -> ``op_name``
@@ -50,11 +49,8 @@ INNER = {
     "ssm_conv": ("ssm", "ssm_conv_gate"), "ssm_gate": ("ssm", "ssm_conv_gate"),
     "ssm_scan": ("ssm", "ssm_scan"),
     "gqa_proj": ("gqa",), "gqa_core": ("gqa",),
-    "moe_shared": ("moe_shared",),
 }
 SHARES = sorted({s for shares in INNER.values() for s in shares})
-# the shares that say "this program has a state-space or GQA mixer"
-OWN = ("ssm", "gqa")
 KERNELS = ("ssd_fwd", "ssd_bwd")
 
 
@@ -103,7 +99,7 @@ def reduce(ops: Dict[int, List[device_scopes.Op]],
                      "calls": {k: 0 for k in KERNELS}})
                 step["seconds"][kernel] += self_s
                 step["calls"][kernel] += 1
-    if total <= 0 or not any(seconds[s] for s in OWN):
+    if total <= 0 or not any(seconds.values()):
         return None
     return {"shares": {k: s / total for k, s in seconds.items()},
             "seconds": seconds, "steps": list(steps.values()),

@@ -249,8 +249,6 @@ def _notes(result: Dict[str, Any], record: Dict[str, Any]) -> List[str]:
         )
     sinks = {k: _sink_ms(record, k + "_p50_ms") for k in (
         "ddp_step_pack", "ddp_wire_exposed", "ddp_step_land_tail",
-        "ddp_step_submit", "ddp_land_queue", "comm_subop_cpu",
-        "comm_submit_wire",
     )}
     if all(v is not None for v in sinks.values()):
         three = (sinks["ddp_step_pack"] + sinks["ddp_wire_exposed"]
@@ -259,12 +257,7 @@ def _notes(result: Dict[str, Any], record: Dict[str, Any]) -> List[str]:
             "; the sinks' p50 over the run's last steps: ddp_step_pack "
             f"{sinks['ddp_step_pack']:.1f} + ddp_wire_exposed "
             f"{sinks['ddp_wire_exposed']:.1f} + ddp_step_land_tail "
-            f"{sinks['ddp_step_land_tail']:.1f} = {three:.1f} ms; with no "
-            f"metric of their own: ddp_step_submit "
-            f"{sinks['ddp_step_submit']:.2f}, ddp_land_queue "
-            f"{sinks['ddp_land_queue']:.2f}, comm_subop_cpu "
-            f"{sinks['comm_subop_cpu']:.2f}, comm_submit_wire "
-            f"{sinks['comm_submit_wire']:.2f} ms"
+            f"{sinks['ddp_step_land_tail']:.1f} = {three:.1f} ms"
         )
     return notes + [check]
 

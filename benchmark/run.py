@@ -6,17 +6,20 @@ Claims the TPU by name (no chip, or fewer chips than the cell asks for:
 non-zero exit and no result line), places the compile cache at
 ``<checkout>/.jax_cache``, sets up, measures for ``--seconds`` and prints
 one JSON line last: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, traced, ``breakdown``. With ``--trace 0`` the metrics are
-the cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics.
+``device``, ``checks`` (each number compared beside its limit, also the
+last lines on standard error) and, traced, ``breakdown``. With
+``--trace 0`` the metrics are the cell's end-to-end metrics, with
+``--trace 1`` its per-layer metrics.
 
 This file holds no table of cells, metrics, families or jobs. The cell is
 an entry of ``BENCHMARK.json``; its configuration names a family
 (``families/<family>.py``), its traffic file names a job
 (``jobs/<job>.py``), and each per-layer metric is
 ``layer_metrics/<metric>.json``, which names either a key of a
-``Metrics.snapshot()`` or a reader (``readers/<reader>.py``). A later PR
-adds files and manifest entries and edits nothing here. No environment
-variable is read.
+``Metrics.snapshot()`` or a reader (``readers/<reader>.py``). Which cells
+report a metric is the manifest's alone to say (``_in_cell``): no file
+under ``layer_metrics/`` names a cell. A later PR adds files and manifest
+entries and edits nothing here. No environment variable is read.
 """
 
 from __future__ import annotations
@@ -41,8 +44,15 @@ def _load_json(*parts: str) -> Dict[str, Any]:
         return json.load(f)
 
 
-def _in_cell(metric: Dict[str, Any], cell: str) -> bool:
-    return "workloads" not in metric or cell in metric["workloads"]
+def _in_cell(metric: Dict[str, Any], cell: str,
+             end_to_end: Sequence[Dict[str, Any]] = ()) -> bool:
+    """Whether ``cell`` reports ``metric``: named in its ``workloads`` or,
+    where the metric names no cells, reporting the end-to-end metric it
+    ``moves`` (an end-to-end metric without a list is every cell's)."""
+    if "workloads" in metric:
+        return cell in metric["workloads"]
+    return all(_in_cell(m, cell) for m in end_to_end
+               if m["name"] == metric.get("moves"))
 
 
 def claim_devices(chips: int) -> Sequence[Any]:
@@ -137,7 +147,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         for m in manifest["per_layer"]:
-            if _in_cell(m, args.workload):
+            if _in_cell(m, args.workload, manifest["end_to_end"]):
                 value = layer_metric_value(m["name"], record)
                 if value is not None:
                     metrics[m["name"]] = {"value": value, "unit": m["unit"]}
@@ -147,14 +157,21 @@ def main(argv: Optional[List[str]] = None) -> int:
         }
     out["metrics"] = metrics
     out["device"] = device
-    # what failed, for whoever reads the run by hand; not on the last line
-    for name, check in record["checks"].items():
-        shown = {k: v for k, v in check.items() if k != "ok"}
-        print(f"check {name}: {'ok' if check['ok'] else 'FAILED'} "
-              f"{json.dumps(shown, default=str)[:600]}", flush=True)
+    # every number compared beside its limit: before the notes for whoever
+    # reads the run by hand, last in the result's line, and once more as
+    # the last lines on standard error (a record of a run that is not
+    # correct keeps only the two ends)
+    out["checks"] = record["checks"]
+    compared = [
+        f"check {name}: {'ok' if check['ok'] else 'FAILED'} " + json.dumps(
+            {k: v for k, v in check.items() if k != "ok"}, default=str)[:600]
+        for name, check in record["checks"].items()
+    ]
+    print("\n".join(compared), flush=True)
     for note in record.get("notes", []):
         print("note " + note, flush=True)
-    print(json.dumps(out), flush=True)
+    print(json.dumps(out, default=str), flush=True)
+    print("\n".join(compared), file=sys.stderr, flush=True)
     return 0
 
 

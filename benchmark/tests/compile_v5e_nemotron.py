@@ -12,9 +12,10 @@ The rule (ISSUE 33, as PRs 26 and 31): sequences of 8192 with remat, the
 largest of 4 / 3 / 2 rows whose donated fused step plans <= 15.0 GiB.
 Nothing runs and nothing here is a measurement: the numbers are the
 compiler's plan for one program at a time. ``causal_attention``,
-``ops/moe.py`` and ``ops/ssd.py`` pick their kernels from
-``jax.default_backend()``, which is the CPU here, so this script (not the
-program) points the model at the Mosaic kernels the chip would run.
+``ops/moe.py``, ``ops/ssd.py`` and ``ops/ssm_pointwise.py`` pick their
+kernels from ``jax.default_backend()``, which is the CPU here, so this
+script (not the program) points the model at the Mosaic kernels the chip
+would run.
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ def main() -> int:
     import torchft_tpu.models.nemotron_h as J
     import torchft_tpu.ops.moe as moe_ops
     import torchft_tpu.ops.ssd as ssd_ops
+    import torchft_tpu.ops.ssm_pointwise as pointwise_ops
     from benchmark.families import nemotron_h as family
     from torchft_tpu.ops.flash import flash_attention
 
@@ -46,6 +48,7 @@ def main() -> int:
         q, k, v, causal=True)
     moe_ops._interpret = lambda: False
     ssd_ops._interpret = lambda: False
+    pointwise_ops._interpret = lambda: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
 

@@ -58,6 +58,18 @@ def make_copy(dst: str, cells: List[Dict[str, Any]],
     return dst
 
 
+def cell_metrics(cell: str) -> set:
+    """The per-layer metrics the real manifest lists for ``cell``."""
+    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    moved = {m["name"]: m for m in manifest["end_to_end"]}
+    # run.py::_in_cell's rule: a metric that names no cells is reported
+    # wherever the end-to-end metric it moves is
+    return {m["name"] for m in manifest["per_layer"]
+            if cell in m.get("workloads", moved[m["moves"]].get(
+                "workloads", [cell]))}
+
+
 def run_in_copy(root: str, argv: List[str]) -> Tuple[int, Dict[str, Any]]:
     """``run.main(argv)`` of the copy at ``root``, steered onto the CPU.
     Returns the exit code and the parsed last line."""

@@ -71,7 +71,12 @@ def test_new_files_are_found_without_editing_run_py(tmp_path) -> None:
     assert rc == 0 and line["correct"] and line["failed"] == 0
     assert line["metrics"]["steps_in_window"]["value"] == line["attempted"] > 0
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device", "breakdown"}
+                         "device", "breakdown", "checks"}
+    # each number compared beside its limit comes last in the line
+    assert list(line)[-1] == "checks" and all(
+        c["ok"] for c in line["checks"].values())
+    assert line["checks"]["reference"]["abs_diff"] <= \
+        line["checks"]["reference"]["atol"]
     assert line["device"]["busy_s"] > 0
     with open(os.path.join(bench, "run.py"), "rb") as f:
         assert f.read() == run_py

@@ -12,9 +12,10 @@ import os
 import pytest
 
 from benchmark import mla_flops
-from benchmark.readers import mla_scopes
+from benchmark.readers import mla_scopes, moe_scopes
 from benchmark.tests import rehearse
 
+CELL = "joyai-ep16-solo-steady"
 MS = 1e-3
 
 
@@ -67,35 +68,29 @@ def test_the_joyai_family_runs_the_steady_job_at_the_tiny_size(
                                         "peak_hbm_gib", "setup_s"}
         return
     got = line["metrics"]
-    assert got["joyai_compiles_in_window"]["value"] == 0
-    six = [got[f"joyai_{s}_device_share"]["value"] for s in
+    assert got["compiles_in_window"]["value"] == 0
+    six = [got[f"{s}_device_share"]["value"] for s in
            ("xent", "attn", "mlp", "embed", "opt", "unnamed")]
     assert sum(six) == pytest.approx(1.0)
     # attn is the projections and the core, in the main layers and the MTP's
     assert (got["mla_proj_device_share"]["value"]
             + got["mla_core_device_share"]["value"]) == pytest.approx(
-        got["joyai_attn_device_share"]["value"], rel=0.02)
+        got["attn_device_share"]["value"], rel=0.02)
     # the sparse sublayer's five inner scopes and the dense layer's MLP
-    inner = [got[f"joyai_moe_{s}_device_share"]["value"] for s in
+    inner = [got[f"moe_{s}_device_share"]["value"] for s in
              ("router", "dispatch", "experts", "shared")]
     assert all(v > 0 for v in inner)
-    assert sum(inner) < got["joyai_mlp_device_share"]["value"]
+    assert sum(inner) < got["mlp_device_share"]["value"]
     assert 0 < got["mtp_device_share"]["value"] < 0.6
-    # the cell's twins read what the originals read
-    with open(os.path.join(rehearse._REPO, "BENCHMARK.json")) as f:
-        mine = [m for m in json.load(f)["per_layer"]
-                if m.get("workloads") == ["joyai-ep16-solo-steady"]]
-    assert len(mine) == 25
-    twins = 0
-    for m in mine:
-        with open(os.path.join(rehearse._REPO, "benchmark", "layer_metrics",
-                               m["name"] + ".json")) as f:
-            spec = json.load(f)
-        if "twin_of" in spec:       # absent where the original is (4 s)
-            assert got.get(m["name"]) == got.get(spec["twin_of"]), m["name"]
-            twins += 1
-    assert twins == 18
-    assert len({m["name"] for m in mine} & set(got)) >= 20
+    # every metric the cell lists: the 2 of set-up, the 15 solo ones, the
+    # sparse sublayer's 4 and the family's own 6; all but the three
+    # rooflines and the two rates of untraced steps (a 4 s window is all
+    # traced) are printed here
+    mine = rehearse.cell_metrics(CELL)
+    assert len(mine) == 27
+    assert mine - set(got) <= {
+        "mla_flash_fwd_roofline", "mla_flash_dq_roofline",
+        "mla_flash_dkv_roofline", "ft_over_bare", "window_over_blocks"}
     # on the CPU attention is the XLA path: no flash event, so no roofline
     assert not any(k.endswith("_roofline") for k in got)
 
@@ -109,8 +104,11 @@ def test_inner_scope_classification() -> None:
     assert mla_scopes.inner_scope(step + "jvp(attn)/mla_q/mul") == "proj"
     assert mla_scopes.inner_scope(
         step + "jvp(mtp)/attn/mla_out/dot_general") == "proj"
+    # the sparse sublayer's inner scopes are moe_scopes'
     assert mla_scopes.inner_scope(
-        step + "jvp(mlp)/moe_shared/dot_general") == "moe_shared"
+        step + "jvp(mlp)/moe_shared/dot_general") is None
+    assert moe_scopes.inner_scope(
+        step + "jvp(mlp)/moe_shared/dot_general") == "shared"
     assert mla_scopes.inner_scope(step + "jvp(mlp)/moe_experts/mul") is None
     assert mla_scopes.inner_scope(None) is None
 
@@ -135,8 +133,8 @@ def test_shares_and_rooflines_on_a_small_recorded_table() -> None:
         ("fusion.2", 1 * MS, 2 * MS),           # proj 1
         ("flash_fwd.1", 2 * MS, 4 * MS),        # core 2
         ("fusion.3", 4 * MS, 5 * MS),           # proj 1
-        ("fusion.4", 5 * MS, 6 * MS),           # shared 1
-        ("fusion.5", 6 * MS, 8 * MS),           # nothing of this reader's
+        ("fusion.4", 5 * MS, 6 * MS),           # moe_scopes': shared 1
+        ("fusion.5", 6 * MS, 8 * MS),           # moe_scopes': experts 2
         ("fusion.6", 8 * MS, 9 * MS),           # proj 1 and mtp 1
         ("fusion.7", 9 * MS, 10 * MS),          # mtp 1
         ("flash_fwd.2", 10 * MS, 12 * MS),      # core 2, the remat's
@@ -151,7 +149,11 @@ def test_shares_and_rooflines_on_a_small_recorded_table() -> None:
     got = mla_scopes.reduce(ops, modules, tables)
     assert got["total_s"] == pytest.approx(24 * MS)
     assert got["shares"] == pytest.approx({
-        "proj": 4 / 24, "core": 15 / 24, "moe_shared": 1 / 24, "mtp": 2 / 24})
+        "proj": 4 / 24, "core": 15 / 24, "mtp": 2 / 24})
+    # the shared expert's share is served beside the other three, with
+    # the same denominator
+    assert moe_scopes.reduce(ops, modules, tables)["shares"] == pytest.approx({
+        "router": 0.0, "dispatch": 0.0, "experts": 2 / 24, "shared": 1 / 24})
     assert [s["calls"] for s in got["steps"]] == [
         {"flash_fwd": 2, "flash_dq": 1, "flash_dkv": 1},
         {"flash_fwd": 1, "flash_dq": 0, "flash_dkv": 0},

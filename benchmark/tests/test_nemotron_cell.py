@@ -12,7 +12,7 @@ import os
 import pytest
 
 from benchmark import ssd_flops
-from benchmark.readers import ssm_scopes
+from benchmark.readers import moe_scopes, ssm_scopes
 from benchmark.tests import rehearse
 
 MS = 1e-3
@@ -69,44 +69,35 @@ def test_the_nemotron_family_runs_the_steady_job_at_the_tiny_size(
                                         "peak_hbm_gib", "setup_s"}
         return
     got = line["metrics"]
-    assert got["nemo_compiles_in_window"]["value"] == 0
-    six = [got[f"nemo_{s}_device_share"]["value"] for s in
+    assert got["compiles_in_window"]["value"] == 0
+    six = [got[f"{s}_device_share"]["value"] for s in
            ("xent", "attn", "mlp", "embed", "opt", "unnamed")]
     assert sum(six) == pytest.approx(1.0)
     # both sequence mixers stand under attn: its two parts are they
     assert (got["ssm_device_share"]["value"]
             + got["gqa_device_share"]["value"]) == pytest.approx(
-        got["nemo_attn_device_share"]["value"], rel=0.02)
+        got["attn_device_share"]["value"], rel=0.02)
     assert (got["ssm_scan_device_share"]["value"]
             + got["ssm_proj_device_share"]["value"]
             + got["ssm_conv_gate_device_share"]["value"]) == pytest.approx(
         got["ssm_device_share"]["value"], rel=1e-6)
     # the sparse sublayer's inner scopes are the whole of mlp here
-    inner = [got[f"nemo_moe_{s}_device_share"]["value"] for s in
+    inner = [got[f"moe_{s}_device_share"]["value"] for s in
              ("router", "dispatch", "experts", "shared")]
     assert all(v > 0 for v in inner)
     assert sum(inner) == pytest.approx(
-        got["nemo_mlp_device_share"]["value"], rel=0.05)
-    # every metric of the cell but the two rooflines: on the CPU the scan
-    # runs in Pallas's interpreter, and no event is named ``ssd_fwd``
-    with open(os.path.join(rehearse._REPO, "BENCHMARK.json")) as f:
-        mine = [m["name"] for m in json.load(f)["per_layer"]
-                if m.get("workloads") == [CELL]]
-    assert len(mine) == 26
-    missing = set(mine) - set(got)
+        got["mlp_device_share"]["value"], rel=0.05)
+    # every metric the cell lists (the 2 of set-up, the 15 solo ones, the
+    # sparse sublayer's 4, the family's own 7) but the two rooflines: on
+    # the CPU the scan runs in Pallas's interpreter, and no event is named
+    # ``ssd_fwd``
+    mine = rehearse.cell_metrics(CELL)
+    assert len(mine) == 28
+    missing = mine - set(got)
     assert missing <= {"ssd_fwd_roofline", "ssd_bwd_roofline",
-                       # absent where its original is: a 4 s window is
-                       # all traced, so no rate of untraced steps
-                       "nemo_ft_over_bare", "nemo_window_over_blocks"}, missing
-    twins = 0
-    for name in mine:
-        with open(os.path.join(rehearse._REPO, "benchmark", "layer_metrics",
-                               name + ".json")) as f:
-            spec = json.load(f)
-        if "twin_of" in spec:
-            assert got.get(name) == got.get(spec["twin_of"]), name
-            twins += 1
-    assert twins == 18
+                       # a 4 s window is all traced, so no rate of
+                       # untraced steps
+                       "ft_over_bare", "window_over_blocks"}, missing
 
 
 def test_inner_scope_classification() -> None:
@@ -121,8 +112,9 @@ def test_inner_scope_classification() -> None:
             "ssm", "ssm_conv_gate")
     assert ssm_scopes.inner_scopes(
         step + "jvp(attn)/gqa_core/pallas_call") == ("gqa",)
+    # the sparse sublayer's inner scopes are moe_scopes'
     assert ssm_scopes.inner_scopes(
-        step + "jvp(mlp)/moe_shared/dot_general") == ("moe_shared",)
+        step + "jvp(mlp)/moe_shared/dot_general") == ()
     assert ssm_scopes.inner_scopes(step + "jvp(mlp)/moe_experts/mul") == ()
     assert ssm_scopes.inner_scopes(None) == ()
 
@@ -152,8 +144,8 @@ def test_shares_and_rooflines_on_a_small_recorded_table() -> None:
         ("fusion.5", 6 * MS, 7 * MS),           # ssm_proj 1
         ("flash_fwd.1", 7 * MS, 9 * MS),        # gqa 2
         ("fusion.6", 9 * MS, 10 * MS),          # gqa 1
-        ("fusion.7", 10 * MS, 11 * MS),         # moe_shared 1
-        ("fusion.8", 11 * MS, 12 * MS),         # nothing of this reader's
+        ("fusion.7", 10 * MS, 11 * MS),         # moe_scopes': shared 1
+        ("fusion.8", 11 * MS, 12 * MS),         # moe_scopes': experts 1
         ("ssd_fwd.2", 12 * MS, 14 * MS),        # scan 2, the remat's
         ("ssd_bwd.1", 14 * MS, 19 * MS),        # scan 5
         ("copy.1", 19 * MS, 20 * MS),           # no path
@@ -166,7 +158,11 @@ def test_shares_and_rooflines_on_a_small_recorded_table() -> None:
     assert got["total_s"] == pytest.approx(24 * MS)
     assert got["shares"] == pytest.approx({
         "ssm": 18 / 24, "ssm_scan": 14 / 24, "ssm_proj": 2 / 24,
-        "ssm_conv_gate": 2 / 24, "gqa": 3 / 24, "moe_shared": 1 / 24})
+        "ssm_conv_gate": 2 / 24, "gqa": 3 / 24})
+    # the shared expert's share is served beside the other three, with
+    # the same denominator
+    assert moe_scopes.reduce(ops, modules, tables)["shares"] == pytest.approx({
+        "router": 0.0, "dispatch": 0.0, "experts": 1 / 24, "shared": 1 / 24})
     assert [s["calls"] for s in got["steps"]] == [
         {"ssd_fwd": 2, "ssd_bwd": 1}, {"ssd_fwd": 1, "ssd_bwd": 0}]
     # one Mamba-2 layer, 32 768 tokens at 64 x 64, 8 groups, state 128:

@@ -14,6 +14,7 @@ from benchmark import moe_flops
 from benchmark.readers import moe_scopes
 from benchmark.tests import rehearse
 
+CELL = "olmoe-solo-steady"
 MS = 1e-3
 
 
@@ -69,16 +70,16 @@ def test_the_olmoe_family_runs_the_steady_job_at_the_tiny_size(
     # the inner scopes lie inside the sparse sublayer, which is all of mlp
     assert sum(inner) == pytest.approx(got["mlp_device_share"]["value"],
                                        rel=0.02)
-    # the cell's twins of the solo metrics read what the originals read
-    with open(os.path.join(rehearse._REPO, "BENCHMARK.json")) as f:
-        twins = [m["name"] for m in json.load(f)["per_layer"]
-                 if m["name"].startswith("olmoe_")]
-    assert len(twins) == 15
-    for name in twins:      # absent where the original is (a 4 s window)
-        assert got.get(name) == got.get(name[len("olmoe_"):]), name
-    assert len(set(twins) & set(got)) >= 12
-    # on the CPU the kernel is interpreted: no gmm event, so no roofline
-    assert "moe_experts_roofline" not in got
+    # every metric the cell lists (the 2 of set-up, the 15 solo ones, the
+    # sparse sublayer's 3 and its roofline) but the two rates of untraced
+    # steps (a 4 s window is all traced) and, the kernel being interpreted
+    # on the CPU (no gmm event), the roofline
+    mine = rehearse.cell_metrics(CELL)
+    assert len(mine) == 21
+    assert mine - set(got) <= {"moe_experts_roofline", "ft_over_bare",
+                               "window_over_blocks"}
+    # no shared expert in this family: nothing to read, so left out
+    assert "moe_shared_device_share" not in got
 
 
 def test_inner_scope_classification() -> None:
@@ -140,7 +141,18 @@ def test_shares_and_roofline_on_a_small_recorded_table() -> None:
     got = moe_scopes.reduce(ops, modules, tables)
     assert got["total_s"] == pytest.approx(24 * MS)
     assert got["shares"] == pytest.approx(
-        {"router": 1 / 24, "dispatch": 3 / 24, "experts": 14 / 24})
+        {"router": 1 / 24, "dispatch": 3 / 24, "experts": 14 / 24,
+         "shared": 0.0})
+    # no shared expert in this program: that share has nothing to read
+    assert got["present"] == {"router", "dispatch", "experts"}
+    assert moe_scopes.read({"_moe_scopes": got}, {"what": "shared"}) is None
+    assert moe_scopes.read(
+        {"_moe_scopes": got}, {"what": "router"}) == pytest.approx(1 / 24)
+    # a scope the program has and the trace holds no time of reads 0: a
+    # share that vanished would hide a scope lost between two runs
+    idle = {0: [op for op in ops[0] if op[0] != "fusion.1"]}
+    lost = moe_scopes.reduce(idle, modules, tables)
+    assert moe_scopes.read({"_moe_scopes": lost}, {"what": "router"}) == 0.0
     assert got["steps"] == [
         {"kernel_s": pytest.approx(8 * MS), "gmm": 6, "tgmm": 3},
         {"kernel_s": pytest.approx(4 * MS), "gmm": 1, "tgmm": 0},

@@ -169,3 +169,27 @@ def test_batches_are_a_pure_function_of_the_seed() -> None:
     assert (a[0] == b[0]).all() and (a[1] == b[1]).all()
     assert (a[0] != c[0]).any()
     assert a[0].max() < 500 and (a[1][:, :-1] == a[0][:, 1:]).all()
+
+
+def test_gpt_weights_come_from_any_seed_of_the_driver() -> None:
+    """``--seed`` may pass 2**31 (and the kill job adds its poison offset
+    to it); a seed below it gives the weights it gave as an ``int32``."""
+    import jax
+    import numpy as np
+
+    from benchmark.families import gpt
+    from torchft_tpu.models import init_params
+
+    with open(os.path.join(_HERE, "tiny-test.json")) as f:
+        model = gpt.build(json.load(f))
+    device = jax.devices()[0]
+
+    def head(seed):
+        return np.asarray(jax.tree_util.tree_leaves(
+            gpt.init_state(model, seed, device)["params"])[0])
+
+    big, bigger = head(2**31 + 3), head(2**31 + 4)
+    assert np.isfinite(big).all() and (big != bigger).any()
+    old = np.asarray(jax.tree_util.tree_leaves(
+        init_params(model.cfg, jax.random.key(np.int32(2147480101))))[0])
+    assert (head(2147480101) == old).all()
