@@ -362,7 +362,10 @@ def test_the_bias_rule_moves_towards_balance_on_a_skewed_router() -> None:
                           router)
 
 
-def test_the_rule_is_stateless_and_leaves_the_other_leaves_to_tx() -> None:
+def test_the_rule_has_no_moments_and_leaves_the_other_leaves_to_tx() -> None:
+    """The bias rule never reads its state; what it keeps there since
+    PR 38 is the loads it last saw (``optim.BalanceBiasState``), for
+    ``OptimizerWrapper``'s routing gauges."""
     params = {"a": {"kernel": jnp.ones((3,))},
               "moe": {joyai.BALANCE_BIAS: jnp.zeros((4,))}}
     tx = with_balance_bias(optax.adamw(0.1, weight_decay=0.5), 0.001,
@@ -375,10 +378,11 @@ def test_the_rule_is_stateless_and_leaves_the_other_leaves_to_tx() -> None:
                                [-0.001, 0.001, 0.0, 0.0])
     assert float(updates["a"]["kernel"][0]) < -0.05       # adamw's, decayed
     # twice the loads (two groups' sum, not their mean) is the same update
-    doubled, _ = balance_bias_rule(0.001).update(
+    doubled, kept = balance_bias_rule(0.001).update(
         {"b": 2 * grads["moe"][joyai.BALANCE_BIAS]}, optax.EmptyState())
     np.testing.assert_allclose(doubled["b"],
                                updates["moe"][joyai.BALANCE_BIAS])
+    np.testing.assert_array_equal(kept.loads["b"], [8.0, 0.0, 4.0, 4.0])
 
 
 def test_microbatched_grad_step_carries_the_mean_loads() -> None:

@@ -1,3 +1,12 @@
+"""Model families. Re-exported here: the GPT transformer (and the one
+step maker every family trains through, ``make_train_step`` /
+``make_grad_step``), Llama, the toy MoE transformer and the MLP. The
+families the benchmark trains at published widths are modules of their
+own, each with ``<Family>Config``, ``init_params``, ``forward_hidden``,
+``loss_terms`` and ``loss_fn`` (the step maker's ``loss=``): ``olmoe``,
+``joyai``, ``nemotron_h`` and ``lfm2``; what more than one of them
+computes is in ``common``."""
+
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,
     init_mlp,

@@ -1,16 +1,19 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
-``olmoe``, ``joyai``, ``nemotron_h``) and the router's balance bias — its
+``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``), the repeat of grouped
+key/value heads (``nemotron_h``, ``lfm2``) and the router's balance bias — its
 key in the parameter tree, the predicate ``optim.with_balance_bias``
 partitions the leaves by, and the way a step's loads reach that rule in
-the gradient tree at the bias's place (``joyai``, ``nemotron_h``)."""
+the gradient tree at the bias's place (``joyai``, ``nemotron_h``,
+``lfm2``)."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["rms_norm", "BALANCE_BIAS", "is_balance_bias", "loads_as_gradient"]
+__all__ = ["rms_norm", "repeat_kv", "BALANCE_BIAS", "is_balance_bias",
+           "loads_as_gradient"]
 
 
 def rms_norm(x, scale, eps: float):
@@ -19,6 +22,14 @@ def rms_norm(x, scale, eps: float):
     )
     out = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def repeat_kv(kv, n_heads: int):
+    """``[B, S, KV, D]`` -> ``[B, S, n_heads, D]``: query head ``i`` reads
+    key/value head ``i // (n_heads / KV)``. The flash kernels take as
+    many key/value heads as query heads; the sum over a key/value head's
+    copies is the repeat's own transpose."""
+    return jnp.repeat(kv, n_heads // kv.shape[2], axis=2)
 
 
 # the key of a router's balance bias in the parameter tree

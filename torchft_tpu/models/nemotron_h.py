@@ -80,6 +80,7 @@ from torchft_tpu.models.common import (
     BALANCE_BIAS,
     is_balance_bias,
     loads_as_gradient,
+    repeat_kv,
     rms_norm,
 )
 from torchft_tpu.models.transformer import (
@@ -297,9 +298,7 @@ def _attn_mixer(cfg: NemotronHConfig, layer: Dict, x, *, attn_fn):
         q = (h @ a["q_proj"]["kernel"].astype(dt)).reshape(B, S, H, D)
         k = (h @ a["k_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
         v = (h @ a["v_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
-        # query head i reads key/value head i // (H / KV)
-        k = jnp.repeat(k, H // KV, axis=2)
-        v = jnp.repeat(v, H // KV, axis=2)
+        k, v = repeat_kv(k, H), repeat_kv(v, H)
     with jax.named_scope("gqa_core"):
         o = attn_fn(q, k, v)
     with jax.named_scope("gqa_proj"):

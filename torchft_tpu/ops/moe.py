@@ -47,15 +47,16 @@ class Dispatch(NamedTuple):
 
 def top_k_routing(scores: Any, k: int, bias: Any = None,
                   renormalise: bool = False,
-                  scale: float = 1.0) -> Tuple[Any, Any]:
+                  scale: float = 1.0, eps: float = 1e-20) -> Tuple[Any, Any]:
     """``(weights [N, k], experts [N, k] int32)``. Without ``bias``: the
     ``k`` largest router scores of each token, as they are (OLMoE's
     softmax probabilities, not renormalised). With a ``bias [E]``
     (DeepSeek-V3's ``noaux_tc``): the ``k`` largest of ``scores + bias``
     choose, and the weights are the chosen experts' ``scores`` — the bias
     selects and never weights, and no gradient reaches it.
-    ``renormalise`` divides a token's weights by their sum (+ 1e-20);
-    ``scale`` multiplies them."""
+    ``renormalise`` divides a token's weights by their sum + ``eps``
+    (1e-20: DeepSeek-V3's; LFM2 publishes 1e-6); ``scale`` multiplies
+    them."""
     if bias is None:
         weights, experts = jax.lax.top_k(scores, k)
     else:
@@ -63,7 +64,7 @@ def top_k_routing(scores: Any, k: int, bias: Any = None,
             scores + jax.lax.stop_gradient(bias).astype(scores.dtype), k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return (weights * scale if scale != 1.0 else weights), experts
 
 

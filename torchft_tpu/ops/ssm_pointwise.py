@@ -1,10 +1,12 @@
-"""Pallas kernels for the two elementwise stages around the Mamba-2 scan
-(``ops/ssd.py``): the short causal depthwise convolution with its silu,
-and the gate with its grouped RMSNorm. Each is ONE kernel forward and
-ONE backward under its own ``jax.custom_vjp``; each reads its operands
-once, in the dtype they arrive in, and writes its results once. The
-residuals of both are their inputs alone: the backward kernels recompute
-what they need.
+"""Pallas kernels for pointwise stages of the sequence mixers: the two
+around the Mamba-2 scan (``ops/ssd.py``) — the short causal depthwise
+convolution with its silu, and the gate with its grouped RMSNorm — and
+LFM2's gated short convolution, which is the first's kernel body with
+two multiplicands where that has a bias and a silu. Each is ONE kernel
+forward and ONE backward under its own ``jax.custom_vjp``; each reads its
+operands once, in the dtype they arrive in, and writes its results once.
+The residuals of all three are their inputs alone: the backward kernels
+recompute what they need.
 
 ``conv_silu(x [B, S, C], taps [K, C], bias [C])``:
 
@@ -15,6 +17,24 @@ per channel, zeros before each sequence's start. Backward, with
 
     dx_t = Σ_j taps_j ⊙ dconv_{t+(K-1-j)}          dbias = Σ_t dconv_t
     dtaps_j = Σ_t dconv_t ⊙ x_{t-(K-1)+j}
+
+``gated_conv(bcx [B, S, 3C], taps [K, C])`` (``models/lfm2.py``): ``bcx``
+is ``[B ; C ; X]`` along the channels, as one projection writes them;
+
+    u = B ⊙ X      conv_t = Σ_{j<K} taps_j ⊙ u_{t-(K-1)+j}      y_t = C_t ⊙ conv_t
+
+no bias, no activation. Backward, with ``dconv = dy ⊙ C``:
+
+    dC = dy ⊙ conv        du_t = Σ_j taps_j ⊙ dconv_{t+(K-1-j)}
+    dB = du ⊙ X           dX = du ⊙ B        dtaps_j = Σ_t dconv_t ⊙ u_{t-(K-1)+j}
+
+The kernels are ``conv_silu``'s (``_conv_fwd_kernel`` / ``_conv_bwd_kernel``
+with ``gated=True``; named ``sconv_fwd`` / ``sconv_bwd`` in a trace): the
+three thirds of ``bcx`` come in as three views of the one array, what
+goes into the window scratch is ``B ⊙ X``, and the one cotangent array
+``[dB ; dC ; dX]`` is written a third a grid step (the backward's fourth
+grid axis: the work is done at step 0, steps 1 and 2 hand over what it
+kept in VMEM).
 
 ``gated_norm(y, z [B, S, I], scale [I], groups, eps)``: with ``g = y ⊙
 silu(z)`` and, for each of the ``groups`` runs of ``W = I / groups``
@@ -80,7 +100,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["conv_silu", "gated_norm"]
+__all__ = ["conv_silu", "gated_conv", "gated_norm"]
 
 _LANES = 128
 _HALO = 8                    # rows kept beside a block: the f32 sublane tile
@@ -171,9 +191,15 @@ def _weighted(w, windows, reverse: bool = False):
     return total
 
 
-def _conv_fwd_kernel(x_ref, w_ref, b_ref, o_ref, win, *, chunk: int):
+def _conv_fwd_kernel(*refs, chunk: int, gated: bool):
     """One (batch row, channel block, sequence block), the sequence
-    blocks innermost and in order."""
+    blocks innermost and in order. ``gated``: what is convolved is ``B ⊙
+    X`` and the result is ``C ⊙ conv`` (no bias, no silu); else ``x``
+    and ``silu(bias + conv)``."""
+    if gated:
+        b_ref, c_ref, x_ref, w_ref, o_ref, win = refs
+    else:
+        x_ref, w_ref, b_ref, o_ref, win = refs
     si = pl.program_id(2)
     bs, k = x_ref.shape[1], w_ref.shape[0]
 
@@ -185,27 +211,56 @@ def _conv_fwd_kernel(x_ref, w_ref, b_ref, o_ref, win, *, chunk: int):
     def _carry():
         win[0:_HALO] = win[bs:bs + _HALO]
 
-    win[_HALO:_HALO + bs] = _f32(x_ref[0])
-    w, bias = _f32(w_ref[...]), _f32(b_ref[...])
+    win[_HALO:_HALO + bs] = (
+        _f32(b_ref[0]) * _f32(x_ref[0]) if gated else _f32(x_ref[0]))
+    w = _f32(w_ref[...])
+    bias = None if gated else _f32(b_ref[...])
 
     def rows(i, carry):
         r0 = _row0(i, chunk)
-        conv = bias + _weighted(
-            w, _windows(win, r0, _HALO - (k - 1), chunk, k))
-        o_ref[0, pl.ds(r0, chunk), :] = (
-            conv * jax.nn.sigmoid(conv)).astype(o_ref.dtype)
+        conv = _weighted(w, _windows(win, r0, _HALO - (k - 1), chunk, k))
+        if gated:
+            out = _f32(c_ref[0, pl.ds(r0, chunk), :]) * conv
+        else:
+            conv = bias + conv
+            out = conv * jax.nn.sigmoid(conv)
+        o_ref[0, pl.ds(r0, chunk), :] = out.astype(o_ref.dtype)
         return carry
 
     _for_chunks(bs // chunk, rows, 0)
 
 
-def _conv_bwd_kernel(*refs, chunk: int, seq_len: int, halo: bool):
+def _conv_bwd_kernel(*refs, chunk: int, seq_len: int, halo: bool,
+                     gated: bool):
     """One (channel block, batch row, sequence block), the sequence
-    blocks innermost and LAST TO FIRST; ``dw_ref`` and ``db_ref`` stay
-    resident over a channel block's batch rows and sequence blocks."""
-    x_ref, refs = refs[0], refs[1:]
-    prev_ref, refs = (refs[0], refs[1:]) if halo else (None, refs)
-    dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref, win, dwin = refs
+    blocks innermost and LAST TO FIRST; ``dw_ref`` (and ``db_ref``) stay
+    resident over a channel block's batch rows and sequence blocks.
+
+    ``gated`` (what is convolved is ``u = B ⊙ X``, the result ``C ⊙
+    conv``): the one cotangent array is ``[dB ; dC ; dX]`` along the
+    channels, so the grid has a fourth, innermost axis of three — step 0
+    does all the work, writes ``dB``'s block and keeps ``dC``'s and
+    ``dX``'s in VMEM, steps 1 and 2 hand those to their blocks of the
+    same array (the inputs' blocks do not move, so nothing is fetched
+    again)."""
+    if gated:
+        b_ref, c_ref, x_ref, refs = refs[0], refs[1], refs[2], refs[3:]
+        prev_b, prev_ref, refs = (
+            (refs[0], refs[1], refs[2:]) if halo else (None, None, refs))
+        dy_ref, w_ref, dx_ref, dw_ref, win, dwin, keep_c, keep_x = refs
+        part = pl.program_id(3)
+
+        @pl.when(part == 1)
+        def _dc():
+            dx_ref[0] = keep_c[...]
+
+        @pl.when(part == 2)
+        def _dx():
+            dx_ref[0] = keep_x[...]
+    else:
+        x_ref, refs = refs[0], refs[1:]
+        prev_ref, refs = (refs[0], refs[1:]) if halo else (None, refs)
+        dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref, win, dwin = refs
     bi, si = pl.program_id(1), pl.program_id(2)
     block = pl.num_programs(2) - 1 - si
     bs, bc = x_ref.shape[1:]
@@ -213,61 +268,91 @@ def _conv_bwd_kernel(*refs, chunk: int, seq_len: int, halo: bool):
     ragged = seq_len % bs != 0
     f32 = jnp.float32
 
-    @pl.when((bi == 0) & (si == 0))
-    def _zero():
-        dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
-        db_ref[...] = jnp.zeros(db_ref.shape, f32)
+    def work():
+        @pl.when((bi == 0) & (si == 0))
+        def _zero():
+            dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
+            if not gated:
+                db_ref[...] = jnp.zeros(db_ref.shape, f32)
 
-    # x's rows before the block
-    before = jnp.zeros((_HALO, bc), f32)
-    if halo:
-        before = jnp.where(block == 0, 0.0, _f32(prev_ref[0])[-_HALO:])
-    win[0:_HALO] = before
-    xb = _f32(x_ref[0])
-    if ragged:
-        xb = jnp.where(_past_the_end(block * bs, bs, seq_len), 0.0, xb)
-    win[_HALO:_HALO + bs] = xb
-
-    # dconv's rows after the block: the first rows of the block handled
-    # one step before
-    @pl.when(si == 0)
-    def _start():
-        dwin[bs:bs + _HALO] = jnp.zeros((_HALO, bc), f32)
-
-    @pl.when(si > 0)
-    def _carry():
-        dwin[bs:bs + _HALO] = dwin[0:_HALO]
-
-    w, bias = _f32(w_ref[...]), _f32(b_ref[...])
-
-    def recompute(i, sums):
-        r0 = _row0(i, chunk)
-        windows = _windows(win, r0, _HALO - (k - 1), chunk, k)
-        conv = bias + _weighted(w, windows)
-        dy = _f32(dy_ref[0, pl.ds(r0, chunk), :])
+        # the convolved rows before the block
+        before = jnp.zeros((_HALO, bc), f32)
+        if halo:
+            rows_before = _f32(prev_ref[0])[-_HALO:]
+            if gated:
+                rows_before = _f32(prev_b[0])[-_HALO:] * rows_before
+            before = jnp.where(block == 0, 0.0, rows_before)
+        win[0:_HALO] = before
+        xb = _f32(b_ref[0]) * _f32(x_ref[0]) if gated else _f32(x_ref[0])
         if ragged:
-            dy = jnp.where(
-                _past_the_end(block * bs + r0, chunk, seq_len), 0.0, dy)
-        dconv = dy * _silu_and_slope(conv)[1]
-        dwin[pl.ds(r0, chunk), :] = dconv
-        parts = [_fold(dconv * win_j) for win_j in windows] + [_fold(dconv)]
-        return tuple(s + p for s, p in zip(sums, parts))
+            xb = jnp.where(_past_the_end(block * bs, bs, seq_len), 0.0, xb)
+        win[_HALO:_HALO + bs] = xb
 
-    sums = _for_chunks(
-        bs // chunk, recompute,
-        tuple(jnp.zeros((_fold_rows(chunk), bc), f32) for _ in range(k + 1)))
-    for j in range(k):
-        dw_ref[j:j + 1, :] += _row_sum(sums[j])
-    db_ref[...] += _row_sum(sums[k])
+        # dconv's rows after the block: the first rows of the block
+        # handled one step before
+        @pl.when(si == 0)
+        def _start():
+            dwin[bs:bs + _HALO] = jnp.zeros((_HALO, bc), f32)
 
-    def spread(i, carry):
-        r0 = _row0(i, chunk)
-        dx_ref[0, pl.ds(r0, chunk), :] = _weighted(
-            w, _windows(dwin, r0, 0, chunk, k), reverse=True
-        ).astype(dx_ref.dtype)
-        return carry
+        @pl.when(si > 0)
+        def _carry():
+            dwin[bs:bs + _HALO] = dwin[0:_HALO]
 
-    _for_chunks(bs // chunk, spread, 0)
+        w = _f32(w_ref[...])
+        bias = None if gated else _f32(b_ref[...])
+
+        def recompute(i, sums):
+            r0 = _row0(i, chunk)
+            windows = _windows(win, r0, _HALO - (k - 1), chunk, k)
+            conv = _weighted(w, windows)
+            if not gated:
+                conv = bias + conv
+            dy = _f32(dy_ref[0, pl.ds(r0, chunk), :])
+            gone = _past_the_end(
+                block * bs + r0, chunk, seq_len) if ragged else None
+            if ragged:
+                dy = jnp.where(gone, 0.0, dy)
+            if gated:
+                keep_c[pl.ds(r0, chunk), :] = (dy * conv).astype(keep_c.dtype)
+                dconv = dy * _f32(c_ref[0, pl.ds(r0, chunk), :])
+                if ragged:      # C's rows past the end are anything
+                    dconv = jnp.where(gone, 0.0, dconv)
+            else:
+                dconv = dy * _silu_and_slope(conv)[1]
+            dwin[pl.ds(r0, chunk), :] = dconv
+            parts = [_fold(dconv * win_j) for win_j in windows]
+            if not gated:
+                parts.append(_fold(dconv))
+            return tuple(s + p for s, p in zip(sums, parts))
+
+        n_sums = k if gated else k + 1
+        sums = _for_chunks(
+            bs // chunk, recompute,
+            tuple(jnp.zeros((_fold_rows(chunk), bc), f32)
+                  for _ in range(n_sums)))
+        for j in range(k):
+            dw_ref[j:j + 1, :] += _row_sum(sums[j])
+        if not gated:
+            db_ref[...] += _row_sum(sums[k])
+
+        def spread(i, carry):
+            r0 = _row0(i, chunk)
+            at = pl.ds(r0, chunk)
+            d_in = _weighted(w, _windows(dwin, r0, 0, chunk, k), reverse=True)
+            if gated:
+                # du -> dB = du ⊙ X (this step's block), dX = du ⊙ B
+                keep_x[at, :] = (d_in * _f32(b_ref[0, at, :])).astype(
+                    keep_x.dtype)
+                d_in = d_in * _f32(x_ref[0, at, :])
+            dx_ref[0, at, :] = d_in.astype(dx_ref.dtype)
+            return carry
+
+        _for_chunks(bs // chunk, spread, 0)
+
+    if gated:
+        pl.when(part == 0)(work)
+    else:
+        work()
 
 
 def _lane_block(width: int, whole: int = _LANES) -> int:
@@ -306,7 +391,8 @@ def _conv_forward(x, taps, bias, blocks: Tuple[int, int], interpret: bool):
     bs, bc = blocks
     k = taps.shape[0]
     return pl.pallas_call(
-        functools.partial(_conv_fwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK)),
+        functools.partial(_conv_fwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK),
+                          gated=False),
         grid=(b, c // bc, pl.cdiv(s, bs)),
         in_specs=[
             pl.BlockSpec((1, bs, bc), lambda b, c, s: (b, s, c)),
@@ -342,7 +428,7 @@ def _conv_backward(x, taps, bias, dy, blocks: Tuple[int, int],
     f32 = jnp.float32
     dx, dtaps, dbias = pl.pallas_call(
         functools.partial(_conv_bwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK),
-                          seq_len=s, halo=halo),
+                          seq_len=s, halo=halo, gated=False),
         grid=(c // bc, b, nb),
         in_specs=[rows] + ([before] if halo else []) + [rows, tap_rows, lanes],
         out_specs=[rows, tap_rows, lanes],
@@ -398,6 +484,113 @@ def conv_silu(x, taps, bias):
     _refuse_lanes("conv_silu", x.shape[2], interpret)
     bc = _lane_block(x.shape[2])
     return _conv(x, taps, bias, (_row_block(x.shape[1], bc), bc), interpret)
+
+
+# ------------------------------------------- gated short convolution
+def _gconv_forward(bcx, taps, blocks: Tuple[int, int], interpret: bool):
+    b, s, c3 = bcx.shape
+    c = c3 // 3
+    bs, bc = blocks
+    k, nc = taps.shape[0], c // bc
+
+    def third(n):
+        return pl.BlockSpec((1, bs, bc), lambda b, c, s: (b, s, n * nc + c))
+
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK),
+                          gated=True),
+        grid=(b, nc, pl.cdiv(s, bs)),
+        in_specs=[third(0), third(1), third(2),
+                  pl.BlockSpec((k, bc), lambda b, c, s: (0, c))],
+        out_specs=pl.BlockSpec((1, bs, bc), lambda b, c, s: (b, s, c)),
+        out_shape=jax.ShapeDtypeStruct((b, s, c), bcx.dtype),
+        scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), jnp.float32)],
+        interpret=interpret, name="sconv_fwd",
+    )(bcx, bcx, bcx, taps)
+
+
+def _gconv_backward(bcx, taps, dy, blocks: Tuple[int, int], interpret: bool):
+    b, s, c3 = bcx.shape
+    c = c3 // 3
+    bs, bc = blocks
+    k, nc = taps.shape[0], c // bc
+    nb = pl.cdiv(s, bs)
+    halo = nb > 1
+    tile = 32 // bcx.dtype.itemsize     # rows of bcx's sublane tile
+
+    def at(s):
+        return nb - 1 - s
+
+    def third(n):
+        return pl.BlockSpec(
+            (1, bs, bc), lambda c, b, s, p: (b, at(s), n * nc + c))
+
+    def before(n):      # the tile of rows that ends where the block starts
+        return pl.BlockSpec(
+            (1, tile, bc), lambda c, b, s, p: (
+                b, jnp.maximum(at(s) * (bs // tile) - 1, 0), n * nc + c))
+
+    tap_rows = pl.BlockSpec((k, bc), lambda c, b, s, p: (0, c))
+    f32 = jnp.float32
+    d_bcx, dtaps = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, chunk=_row_chunk(bs, _CONV_CHUNK),
+                          seq_len=s, halo=halo, gated=True),
+        grid=(nc, b, nb, 3),
+        in_specs=[third(0), third(1), third(2)]
+        + ([before(0), before(2)] if halo else [])
+        + [pl.BlockSpec((1, bs, bc), lambda c, b, s, p: (b, at(s), c)),
+           tap_rows],
+        out_specs=[
+            # part 0 writes dB's block, 1 dC's, 2 dX's
+            pl.BlockSpec((1, bs, bc),
+                         lambda c, b, s, p: (b, at(s), p * nc + c)),
+            tap_rows],
+        out_shape=[jax.ShapeDtypeStruct(bcx.shape, bcx.dtype),
+                   jax.ShapeDtypeStruct((k, c), f32)],
+        scratch_shapes=[pltpu.VMEM((_HALO + bs, bc), f32),
+                        pltpu.VMEM((bs + _HALO, bc), f32),
+                        pltpu.VMEM((bs, bc), bcx.dtype),
+                        pltpu.VMEM((bs, bc), bcx.dtype)],
+        interpret=interpret, name="sconv_bwd",
+    )(*([bcx] * (5 if halo else 3)), dy, taps)
+    return d_bcx, dtaps.astype(taps.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _gconv(bcx, taps, blocks, interpret):
+    return _gconv_forward(bcx, taps, blocks, interpret)
+
+
+def _gconv_fwd_rule(bcx, taps, blocks, interpret):
+    return _gconv_forward(bcx, taps, blocks, interpret), (bcx, taps)
+
+
+def _gconv_bwd_rule(blocks, interpret, residuals, dy):
+    return _gconv_backward(*residuals, dy, blocks, interpret)
+
+
+_gconv.defvjp(_gconv_fwd_rule, _gconv_bwd_rule)
+
+
+def gated_conv(bcx, taps):
+    """``C ⊙ conv(B ⊙ X)`` of the module's docstring: ``bcx [B, S, 3C]``
+    (``[B ; C ; X]`` along the channels, as one projection writes them),
+    ``taps [K, C]`` -> ``[B, S, C]`` in ``bcx``'s dtype, differentiable
+    in both. The blocks are chosen from the shape; the result does not
+    depend on them (``tests/test_ssm_pointwise.py`` runs ``_gconv`` at
+    others)."""
+    if (bcx.ndim != 3 or taps.ndim != 2 or bcx.shape[2] != 3 * taps.shape[1]):
+        raise ValueError(
+            f"gated_conv: bcx{tuple(bcx.shape)} taps{tuple(taps.shape)} "
+            f"do not fit")
+    if not 1 <= taps.shape[0] <= _HALO + 1:
+        raise ValueError(
+            f"gated_conv: {taps.shape[0]} taps; the halo holds {_HALO} rows")
+    interpret = _interpret()
+    c = taps.shape[1]
+    _refuse_lanes("gated_conv", c, interpret)
+    bc = _lane_block(c)
+    return _gconv(bcx, taps, (_row_block(bcx.shape[1], bc), bc), interpret)
 
 
 # ------------------------------------------------------ gate + grouped norm

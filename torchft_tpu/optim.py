@@ -20,9 +20,10 @@ recompile anything.
 
 from __future__ import annotations
 
+import functools
 import logging
 from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -32,13 +33,23 @@ from torchft_tpu.utils.profiling import span, step_program
 logger = logging.getLogger(__name__)
 
 __all__ = [
+    "BalanceBiasState",
     "balance_bias_rule",
     "with_balance_bias",
+    "routing_gauges",
     "OptimizerWrapper",
     "PartitionedOuterOptimizer",
     "ShardedOptState",
     "ShardedOptimizerWrapper",
 ]
+
+
+class BalanceBiasState(NamedTuple):
+    """What :func:`balance_bias_rule` keeps: the loads it last saw (the
+    step's assignments per expert, one vector a router), so that whoever
+    holds the optimizer state can read a step's routing without a second
+    forward pass. No moments, no count."""
+    loads: Any
 
 
 def balance_bias_rule(rate: float):
@@ -48,25 +59,51 @@ def balance_bias_rule(rate: float):
     (the model's doing, as ``models/common.py::loads_as_gradient``;
     averaged over replica groups like any gradient), and the update is
     ``rate · sign(mean(load) - load_e)``: an expert with fewer than its share is
-    made likelier, one with more less likely. No state, no decay."""
+    made likelier, one with more less likely. No moments, no decay; the
+    state is the loads themselves (:class:`BalanceBiasState`), which the
+    update never reads."""
     import jax
     import jax.numpy as jnp
     import optax
 
+    def init(params):
+        return BalanceBiasState(jax.tree_util.tree_map(jnp.zeros_like, params))
+
     def update(loads, state, params=None):
-        del params
+        del state, params
         return jax.tree_util.tree_map(
-            lambda x: rate * jnp.sign(jnp.mean(x) - x), loads), state
+            lambda x: rate * jnp.sign(jnp.mean(x) - x), loads
+        ), BalanceBiasState(loads)
 
-    return optax.GradientTransformation(lambda _p: optax.EmptyState(), update)
+    return optax.GradientTransformation(init, update)
 
 
-def with_balance_bias(tx, rate: float, is_bias):
+@functools.lru_cache(maxsize=None)
+def _balanced_transformation():
+    import optax
+
+    class BalancedTransformation(optax.GradientTransformation):
+        """:func:`with_balance_bias`'s result: an optax transformation
+        that also says which of a router's experts this replica holds
+        (``held_experts``: ``(first, count)`` or None)."""
+        held_experts: Optional[Tuple[int, int]] = None
+
+    return BalancedTransformation
+
+
+def with_balance_bias(tx, rate: float, is_bias,
+                      held: Optional[Tuple[int, int]] = None):
     """``tx`` for every leaf but those whose path in the parameter tree
     ``is_bias`` accepts (the model file's predicate: this module knows no
     model's leaf names), which take :func:`balance_bias_rule`: one optax
     transformation, so the fused step, the classic update behind the
-    commit gate and the heal treat the bias like any other leaf."""
+    commit gate and the heal treat the bias like any other leaf.
+
+    ``held`` = ``(first_expert, n_held)`` where the replica holds a share
+    of every router's experts: :class:`OptimizerWrapper` then reports the
+    share of a step's assignments that fell on them (``moe_held_share``)
+    beside ``moe_load_max_over_mean``, which it reports for every
+    transformation made here."""
     import jax
     import optax
 
@@ -74,8 +111,39 @@ def with_balance_bias(tx, rate: float, is_bias):
         return jax.tree_util.tree_map_with_path(
             lambda path, _x: "bias" if is_bias(path) else "rest", params)
 
-    return optax.multi_transform(
+    both = optax.multi_transform(
         {"rest": tx, "bias": balance_bias_rule(rate)}, labels)
+    out = _balanced_transformation()(both.init, both.update)
+    out.held_experts = None if held is None else (int(held[0]), int(held[1]))
+    return out
+
+
+def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
+    """``[moe_load_max_over_mean, moe_held_share]`` (float32) from the
+    loads every :class:`BalanceBiasState` inside ``opt_state`` holds: the
+    largest, over routers, of an expert's load over the mean load; and
+    the mean, over routers, of the share of all assignments that fell on
+    experts ``held[0] .. held[0] + held[1]`` (NaN without ``held``). None
+    where ``opt_state`` holds no such state. Traceable."""
+    import jax
+    import jax.numpy as jnp
+
+    states = [s for s in jax.tree_util.tree_leaves(
+        opt_state, is_leaf=lambda x: isinstance(x, BalanceBiasState))
+        if isinstance(s, BalanceBiasState)]
+    loads = [x.astype(jnp.float32) for s in states
+             for x in jax.tree_util.tree_leaves(s.loads)]
+    if not loads:
+        return None
+    skew = jnp.max(jnp.stack(
+        [jnp.max(x) / jnp.maximum(jnp.mean(x), 1e-30) for x in loads]))
+    share = jnp.float32(jnp.nan)
+    if held is not None:
+        first, count = held
+        share = jnp.mean(jnp.stack(
+            [jnp.sum(x[first:first + count]) / jnp.maximum(jnp.sum(x), 1e-30)
+             for x in loads]))
+    return jnp.stack([skew, share])
 
 
 class PartitionedOuterOptimizer:
@@ -927,9 +995,43 @@ class OptimizerWrapper:
         )[0]
         if reused:
             self.metrics.incr("update_program_reused")
+        # The routing gauges of a :func:`with_balance_bias` transformation
+        # (None for any other): a program of a few operations over the
+        # loads the optimizer state holds, and the one result of it whose
+        # host copy is under way.
+        self._routing = None
+        self._routing_pending = None
+        if hasattr(tx, "held_experts"):
+            held = tx.held_experts
+
+            def tft_routing_gauges(opt_state):
+                return routing_gauges(opt_state, held)
+
+            self._routing = step_program(tft_routing_gauges, (tx,))[0]
 
     def init(self, params) -> Any:
         return self.tx.init(params)
+
+    def _observe_routing(self, opt_state: Any) -> None:
+        """Gauges ``moe_load_max_over_mean`` and (where the
+        transformation was told the share held) ``moe_held_share`` of a
+        committed step, without a wait: the gauges' program is
+        dispatched behind the step's and its host copy started; what a
+        LATER commit finds ready it reads, and starts the next. A result
+        the device has not reached yet stays pending and this step's is
+        not asked for (the loop's host runs steps ahead of the chip)."""
+        if self._routing is None:
+            return
+        pending = self._routing_pending
+        if pending is not None:
+            if not pending.is_ready():
+                return
+            skew, share = (float(v) for v in np.asarray(pending))
+            self.metrics.gauge("moe_load_max_over_mean", skew)
+            if share == share:      # NaN: the share held was not said
+                self.metrics.gauge("moe_held_share", share)
+        self._routing_pending = self._routing(opt_state)
+        self._routing_pending.copy_to_host_async()
 
     def _span(self, name: str) -> span:
         """One phase of this step: a timing in ``self.metrics`` and a
@@ -1024,6 +1126,7 @@ class OptimizerWrapper:
             # and waiting moves no bytes to the host.
             with self._span("fence"):
                 self._push_fence("block", new_params)
+            self._observe_routing(new_opt)
             return new_params, new_opt, True
         # Non-committing step (error latched, insufficient quorum, heal
         # retry): drain the fence by WAITING, not dropping — dropping
@@ -1069,6 +1172,7 @@ class OptimizerWrapper:
                 # included) ran. See __init__ for why the probe, not a
                 # leaf of new_params.
                 self._push_fence("readback", probe)
+            self._observe_routing(new_opt)
             return new_params, new_opt, True
         self._drain_fence()
         return params, opt_state, False
@@ -1206,6 +1310,7 @@ class OptimizerWrapper:
                 params, opt_state, aux = fused_fn(params, opt_state, *args)
             with self._span("fence"):
                 self._push_fence("readback", aux)
+            self._observe_routing(opt_state)
             return params, opt_state, aux, True
         self._drain_fence()
         return params, opt_state, None, False
