@@ -181,7 +181,9 @@ def test_a_key_value_head_serves_consecutive_query_heads() -> None:
     """``common.repeat_kv`` (Nemotron-H's and this model's): query heads
     0-1 read key/value head 0 and 2-3 head 1; and Nemotron-H's whole
     gradient program is the one it traced before the helper and the
-    shared kernel body (sha256 of its jaxpr at 2d59480)."""
+    shared kernel body (sha256 of its jaxpr at 2d59480; the same across
+    PR 39, c6c2ccf -> the row buffer: at this size a share's buffer is
+    all ``N*k`` rows and the call takes the path it took)."""
     kv = jnp.arange(2 * 3 * 2 * 4, dtype=jnp.float32).reshape(2, 3, 2, 4)
     out = common.repeat_kv(kv, 4)
     assert out.shape == (2, 3, 4, 4)
@@ -567,8 +569,9 @@ def test_the_warm_up_is_a_schedule_and_the_rule_keeps_the_loads() -> None:
     assert len(states) == 1
     kept = jax.tree_util.tree_leaves(states[0].loads)
     assert len(kept) == 3 and all(np.array_equal(k, held_loads) for k in kept)
-    skew, share = optim.routing_gauges(opt, model.tx.held_experts)
+    skew, share, fits = optim.routing_gauges(opt, model.tx.held_experts)
     assert float(skew) == pytest.approx(4.0) and float(share) == 1.0
+    assert float(fits) == 1.0       # 8 assignments: the buffer is all of them
     assert np.isnan(float(optim.routing_gauges(opt)[1]))
     assert optim.routing_gauges(optax.adam(1e-3).init(params)) is None
     zero = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -622,6 +625,7 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
         seen = group.opt.metrics.snapshot()
         assert 0.0 < seen["moe_held_share"] < 1.0
         assert seen["moe_load_max_over_mean"] >= 1.0
+        assert seen["moe_row_buffer_share"] == 1.0
     finally:
         if group is not None:
             group.teardown()

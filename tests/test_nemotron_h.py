@@ -343,7 +343,11 @@ def _jaxpr_hash(fn, *args):
 
 # the sha256 of joyai_tiny's gradient jaxpr (file paths cut) at d58585e,
 # the parent of PR 33: recorded by running this file's _jaxpr_hash on a
-# checkout of that commit
+# checkout of that commit. Read again across PR 39 (c6c2ccf -> the row
+# buffer of a held share): the same, because at this size the buffer
+# would be all N*k rows and such a call takes the path it took
+# (tests/test_moe_rows.py holds the three models at a size where it
+# does not)
 JOYAI_PROGRAM_AT_D58585E = (
     "1c74cd8031aed88edcf9c962c458677fcccb217cedef892342f8357051ff2834")
 

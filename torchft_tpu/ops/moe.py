@@ -4,15 +4,30 @@ matmuls over ragged groups, unsort, weight, sum.
 Every (token, expert) assignment is computed: there is no capacity and
 nothing is dropped, so the result equals "every expert on every token,
 weighted by a matrix that is zero outside the top k" (the benchmark's
-plain reference is written that way). All shapes are static — ``[N*k]``
-assignments whatever the routing — and the group sizes are data, so one
-compiled program serves every routing; an empty group and a group that
-holds every row are ordinary inputs.
+plain reference is written that way). All shapes are static and the
+group sizes are data, so one compiled program serves every routing; an
+empty group and a group that holds every row are ordinary inputs.
 
-The data movement is gathers in both directions: a permutation's
-transpose is the inverse permutation, so the backward pass of the sort
-gathers through ``inverse`` instead of scattering, and the gradient of
-the ``k`` copies of a token is a gather and a sum over ``k``.
+A layer that holds every expert moves ``[N*k]`` rows whatever the
+routing. The data movement is gathers in both directions: a
+permutation's transpose is the inverse permutation, so the backward
+pass of the sort gathers through ``inverse`` instead of scattering, and
+the gradient of the ``k`` copies of a token is a gather and a sum over
+``k``.
+
+A layer that holds a share of the experts (one chip of an
+expert-parallel deployment) moves the rows it holds: held rows sort
+first, and a pass takes :func:`held_capacity` rows of the expert order
+— a static size, the rows an even router sends times a slack — gathers
+them from their tokens, runs them through the experts and sums them
+into their tokens row by row (a scatter-add in float32; its transpose
+is the gather). How many rows are held is data, and so is the number of
+passes (:func:`_share_mlp`): one for a router as even as the deployment
+expects, none where nothing is held, as many as it takes where every
+assignment falls on held experts — dropless in one program, and nothing
+of ``[N*k, d]`` exists. Per row the scatter-add costs twice the
+gathers, so a share that holds nearly every row pays more than a layer
+that holds every expert; it is the price of one code path.
 
 The grouped matmul is jax's megablox Pallas kernel
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` forward and for
@@ -26,6 +41,8 @@ Nothing of ``parallel/moe.py`` (GShard top-2 with a capacity) is used.
 
 from __future__ import annotations
 
+import functools
+from fractions import Fraction
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -33,7 +50,7 @@ import jax.numpy as jnp
 
 __all__ = ["Dispatch", "top_k_routing", "sort_by_expert", "gather_tokens",
            "grouped_matmul", "swiglu_experts", "relu2_experts", "combine",
-           "moe_mlp"]
+           "held_capacity", "moe_mlp"]
 
 
 class Dispatch(NamedTuple):
@@ -186,6 +203,210 @@ def combine(y: Any, weights: Any, dispatch: Dispatch) -> Any:
     return out.astype(y.dtype)
 
 
+# the row buffer of a held share: the rows expected of an even router
+# times this (PERF.md, PR 39, says why no more)
+_SLACK = Fraction(5, 4)
+
+
+def held_capacity(n_assignments: Any, n_held: int, n_routed: int) -> Any:
+    """Rows of the buffer in which a share of ``n_held`` of ``n_routed``
+    experts moves the rows it holds: the rows expected of
+    ``n_assignments`` spread evenly, times ``_SLACK``, rounded up to the
+    grouped matmul's row tile; never above ``n_assignments`` (a share
+    that is the whole layer, or a small problem, moves every row).
+    ``n_assignments`` may be a traced integer (``optim.routing_gauges``
+    counts it from a router's loads)."""
+    tile = _TILING[0]
+    rows = -(-n_assignments * n_held * _SLACK.numerator
+             // (n_routed * _SLACK.denominator * tile)) * tile
+    if isinstance(n_assignments, int):
+        return min(n_assignments, rows)
+    return jnp.minimum(n_assignments, rows)
+
+
+def _experts(x: Any, gate: Any, up: Any, down: Any, group_sizes: Any) -> Any:
+    if gate is None:
+        return relu2_experts(x, up, down, group_sizes)
+    return swiglu_experts(x, gate, up, down, group_sizes)
+
+
+def _all_rows(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
+              down: Any, share: bool) -> Any:
+    """Every one of the ``N*k`` assignments a row. With a ``share`` held
+    (``experts``: local ids, the sentinel ``len(up)`` for an absent
+    expert) the absent ones sort last and ``group_sizes`` count held
+    rows only: megablox's grid ends at the groups' sum and the rows past
+    it are never written, so they are cut off by a select on the way in
+    (for the gradient's sake) and on the way out."""
+    with jax.named_scope("moe_dispatch"):
+        dispatch = sort_by_expert(experts, up.shape[0])
+        x = gather_tokens(h, dispatch)
+        if share:
+            live = (jnp.arange(x.shape[0], dtype=jnp.int32)
+                    < jnp.sum(dispatch.group_sizes))[:, None]  # [N*k, 1]
+            x = jnp.where(live, x, 0)
+    with jax.named_scope("moe_experts"):
+        y = _experts(x, gate, up, down, dispatch.group_sizes)
+    with jax.named_scope("moe_combine"):
+        return combine(jnp.where(live, y, 0) if share else y, weights,
+                       dispatch)
+
+
+def _add_rows(into: Any, v: Any, token: Any) -> Any:
+    """``into [N, d]`` with the rows ``v [C, d]`` summed into their
+    tokens: the transpose of the gather ``h[token]``."""
+    return into.at[token].add(v)
+
+
+class _Passes(NamedTuple):
+    """A share's held rows, cut into passes of ``capacity`` rows of the
+    expert order (held rows sort first, so the passes that hold any are
+    the first ``count``)."""
+    order: Any      # [passes * capacity] int32, ``N*k`` past the end
+    starts: Any     # [n_held] int32: first row of each expert's group
+    sizes: Any      # [n_held] int32
+    count: Any      # int32: passes that hold a row; a data value
+
+
+def _passes(capacity: int, experts: Any, n_held: int) -> _Passes:
+    """The sort, without :func:`sort_by_expert`'s inverse (nothing here
+    gathers through it) and with the group sizes counted by comparison:
+    1.8 ms a call cheaper on the chip than the two scatters (PERF.md,
+    PR 39)."""
+    flat = experts.reshape(-1)
+    n = flat.shape[0]
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(
+        flat[None, :] == jnp.arange(n_held, dtype=flat.dtype)[:, None],
+        axis=1, dtype=jnp.int32)
+    order = jnp.pad(order, (0, -n % capacity), constant_values=n)
+    total = jnp.sum(sizes)
+    return _Passes(order, jnp.cumsum(sizes) - sizes, sizes,
+                   (total + capacity - 1) // capacity)
+
+
+def _pass_rows(passes: _Passes, i: Any, capacity: int, n: int, k: int):
+    """Pass ``i``: ``(rows [C], token [C], live [C, 1], group_sizes)`` —
+    the assignment in each row (``N*k`` where there is none), its token,
+    whether a held assignment stands there, and how many rows of the
+    pass each expert has."""
+    lo = i * capacity
+    rows = jax.lax.dynamic_slice(passes.order, (lo,), (capacity,))
+    at = lo + jnp.arange(capacity, dtype=jnp.int32)
+    live = (at < passes.starts[-1] + passes.sizes[-1])[:, None]
+    inside = (jnp.clip(passes.starts + passes.sizes - lo, 0, capacity)
+              - jnp.clip(passes.starts - lo, 0, capacity))
+    return rows, jnp.minimum(rows, n * k - 1) // k, live, inside
+
+
+def _pass(x: Any, w: Any, live: Any, group_sizes: Any, gate: Any, up: Any,
+          down: Any) -> Any:
+    """One pass's rows through the experts: gathered rows ``x [C, d]``
+    and their router weights ``w [C]`` -> weighted rows, float32. Rows
+    past the held ones are never written by megablox, whose grid ends at
+    the groups' sum: cut off by a select on the way in (for the
+    gradient's sake) and on the way out."""
+    with jax.named_scope("moe_dispatch"):
+        x = jnp.where(live, x, 0)
+    with jax.named_scope("moe_experts"):
+        y = _experts(x, gate, up, down, group_sizes)
+    with jax.named_scope("moe_combine"):
+        return jnp.where(live, y, 0).astype(jnp.float32) * w[:, None]
+
+
+def _cast(dtype: Any, *weights: Any):
+    return tuple(None if w is None else w.astype(dtype) for w in weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _share_mlp(capacity: int, h: Any, weights: Any, experts: Any, gate: Any,
+               up: Any, down: Any) -> Any:
+    """A held share's layer over a row buffer of ``capacity`` rows.
+    ``experts`` holds local ids, the sentinel ``len(up)`` for an absent
+    expert. Held rows sort first; a pass gathers ``capacity`` of them
+    from their tokens, runs them through the experts, weights them in
+    float32 and sums them into their tokens row by row. How many rows
+    are held is data, and so is the number of passes: one where the
+    held rows fit the buffer (an even router's always do), none where
+    nothing is held, ``N*k / capacity`` where every assignment is —
+    dropless, in one program, and no ``[N*k, d]`` array in either
+    direction.
+
+    Differentiated by hand (a loop whose length is data has no
+    transpose): the residuals are the inputs, and the backward pass
+    runs each pass forward again and differentiates it there. The
+    experts so run forward twice a step — as they do under the blocks'
+    remat, whose own recomputation of this layer is then dead code (the
+    sparse sublayer ends its block)."""
+    (n, d), k = h.shape, weights.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        passes = _passes(capacity, experts, up.shape[0])
+    with jax.named_scope("moe_experts"):
+        cast = _cast(h.dtype, gate, up, down)
+    flat = weights.reshape(-1).astype(jnp.float32)
+
+    def one(i, out):
+        with jax.named_scope("moe_dispatch"):
+            rows, token, live, inside = _pass_rows(passes, i, capacity, n, k)
+            x = h[token]
+        y = _pass(x, flat[jnp.minimum(rows, n * k - 1)], live, inside, *cast)
+        with jax.named_scope("moe_combine"):
+            return _add_rows(out, y, token)
+
+    out = jax.lax.fori_loop(0, passes.count, one,
+                            jnp.zeros((n, d), jnp.float32))
+    return out.astype(h.dtype)
+
+
+def _share_mlp_fwd(capacity, h, weights, experts, gate, up, down):
+    return (_share_mlp(capacity, h, weights, experts, gate, up, down),
+            (h, weights, experts, gate, up, down))
+
+
+def _share_mlp_bwd(capacity, res, g):
+    h, weights, experts, gate, up, down = res
+    (n, d), k = h.shape, weights.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        passes = _passes(capacity, experts, up.shape[0])
+    with jax.named_scope("moe_experts"):
+        cast = _cast(h.dtype, gate, up, down)
+    flat = weights.reshape(-1).astype(jnp.float32)
+
+    def one(i, carry):
+        dh, dflat, dcast = carry
+        with jax.named_scope("moe_dispatch"):
+            rows, token, live, inside = _pass_rows(passes, i, capacity, n, k)
+            x = h[token]
+        with jax.named_scope("moe_combine"):
+            dy = g[token].astype(jnp.float32)
+        _, pull = jax.vjp(
+            lambda x, w, *cast: _pass(x, w, live, inside, *cast),
+            x, flat[jnp.minimum(rows, n * k - 1)], *cast)
+        dx, dw, *dpass = pull(dy)
+        with jax.named_scope("moe_dispatch"):
+            dh = _add_rows(dh, dx.astype(jnp.float32), token)
+        with jax.named_scope("moe_combine"):
+            # a row with no assignment in it has index N*k: dropped
+            dflat = dflat.at[rows].set(jnp.where(live[:, 0], dw, 0),
+                                       mode="drop", unique_indices=True)
+        with jax.named_scope("moe_experts"):
+            dcast = tuple(None if a is None else a + b.astype(jnp.float32)
+                          for a, b in zip(dcast, dpass))
+        return dh, dflat, dcast
+
+    zeros = functools.partial(jnp.zeros, dtype=jnp.float32)
+    dh, dflat, dcast = jax.lax.fori_loop(0, passes.count, one, (
+        zeros((n, d)), zeros((n * k,)),
+        tuple(None if w is None else zeros(w.shape) for w in cast)))
+    dgate, dup, ddown = (None if w is None else dw.astype(w.dtype)
+                         for w, dw in zip((gate, up, down), dcast))
+    return (dh.astype(h.dtype), dflat.reshape(n, k).astype(weights.dtype),
+            None, dgate, dup, ddown)
+
+
+_share_mlp.defvjp(_share_mlp_fwd, _share_mlp_bwd)
+
+
 def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
             down: Any, n_routed: Optional[int] = None,
             first_expert: int = 0) -> Any:
@@ -205,33 +426,25 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
     part of the result; assignments to absent experts are computed
     nowhere and nothing stands in for the chips that hold them.
 
-    With a share held, the shapes stay those of all ``N*k`` assignments
-    (every assignment on a held expert is an ordinary input): absent
-    assignments sort behind the held ones, the group sizes count held
-    rows only, and megablox's grid ends at the groups' sum — the grouped
-    matmuls' time follows the rows held, and the rows past the sum are
-    never written, so they are cut off by a select on the way in (for
-    the gradient's sake) and on the way out."""
+    A held share moves the rows it holds: absent assignments get a
+    sentinel and sort behind the held ones, and the rows gathered,
+    activated and combined are those of a buffer of
+    :func:`held_capacity` rows — a static size read off the call
+    (``N*k``, ``len(up)``, ``n_routed``) — pass after pass until the
+    held rows, whose number is data, are done (:func:`_share_mlp`: one
+    pass for an even router). A share whose buffer would be all ``N*k``
+    rows (the whole layer, or a small problem) moves them as a layer
+    that holds every expert does, the absent rows cut off by a select.
+    Every assignment on a held expert is computed either way."""
     n_held = up.shape[0]
     share = n_routed is not None and (n_routed, first_expert) != (n_held, 0)
-    with jax.named_scope("moe_dispatch"):
-        if share:
-            local = experts - first_expert
-            held = (local >= 0) & (local < n_held)             # [N, k]
-            # the sentinel ``n_held`` sorts last and counts nowhere
-            experts = jnp.where(held, local, n_held)
-            weights = jnp.where(held, weights, 0)
-        dispatch = sort_by_expert(experts, n_held)
-        x = gather_tokens(h, dispatch)
-        if share:
-            live = (jnp.arange(experts.size, dtype=jnp.int32)
-                    < jnp.sum(dispatch.group_sizes))[:, None]  # [N*k, 1]
-            x = jnp.where(live, x, 0)
-    with jax.named_scope("moe_experts"):
-        if gate is None:
-            y = relu2_experts(x, up, down, dispatch.group_sizes)
-        else:
-            y = swiglu_experts(x, gate, up, down, dispatch.group_sizes)
-    with jax.named_scope("moe_combine"):
-        return combine(jnp.where(live, y, 0) if share else y, weights,
-                       dispatch)
+    if share:
+        local = experts - first_expert
+        held = (local >= 0) & (local < n_held)             # [N, k]
+        # the sentinel ``n_held`` sorts last and counts nowhere
+        experts = jnp.where(held, local, n_held)
+        weights = jnp.where(held, weights, 0)
+        capacity = held_capacity(experts.size, n_held, n_routed)
+        if capacity < experts.size:
+            return _share_mlp(capacity, h, weights, experts, gate, up, down)
+    return _all_rows(h, weights, experts, gate, up, down, share)

@@ -102,8 +102,9 @@ def with_balance_bias(tx, rate: float, is_bias,
     ``held`` = ``(first_expert, n_held)`` where the replica holds a share
     of every router's experts: :class:`OptimizerWrapper` then reports the
     share of a step's assignments that fell on them (``moe_held_share``)
-    beside ``moe_load_max_over_mean``, which it reports for every
-    transformation made here."""
+    and the share of routers whose held rows fit ``ops/moe.py``'s row
+    buffer (``moe_row_buffer_share``) beside ``moe_load_max_over_mean``,
+    which it reports for every transformation made here."""
     import jax
     import optax
 
@@ -119,14 +120,23 @@ def with_balance_bias(tx, rate: float, is_bias,
 
 
 def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
-    """``[moe_load_max_over_mean, moe_held_share]`` (float32) from the
-    loads every :class:`BalanceBiasState` inside ``opt_state`` holds: the
-    largest, over routers, of an expert's load over the mean load; and
-    the mean, over routers, of the share of all assignments that fell on
-    experts ``held[0] .. held[0] + held[1]`` (NaN without ``held``). None
-    where ``opt_state`` holds no such state. Traceable."""
+    """``[moe_load_max_over_mean, moe_held_share, moe_row_buffer_share]``
+    (float32) from the loads every :class:`BalanceBiasState` inside
+    ``opt_state`` holds: the largest, over routers, of an expert's load
+    over the mean load; the mean, over routers, of the share of all
+    assignments that fell on experts ``held[0] .. held[0] + held[1]``;
+    and the share of routers whose held rows fit the row buffer
+    ``ops/moe.py::moe_mlp`` moves them in (:func:`~torchft_tpu.ops.moe.
+    held_capacity` of the router's own count: its loads sum to ``N*k``
+    and their length is the number routed among) — both NaN without
+    ``held``. The loads are the groups' mean where gradients were
+    averaged, so the third is exact for a replica alone and otherwise
+    says whether the mean routing fits. None where ``opt_state`` holds
+    no such state. Traceable."""
     import jax
     import jax.numpy as jnp
+
+    from torchft_tpu.ops.moe import held_capacity
 
     states = [s for s in jax.tree_util.tree_leaves(
         opt_state, is_leaf=lambda x: isinstance(x, BalanceBiasState))
@@ -137,13 +147,17 @@ def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
         return None
     skew = jnp.max(jnp.stack(
         [jnp.max(x) / jnp.maximum(jnp.mean(x), 1e-30) for x in loads]))
-    share = jnp.float32(jnp.nan)
+    share = fits = jnp.float32(jnp.nan)
     if held is not None:
         first, count = held
+        rows = [jnp.sum(x[first:first + count]) for x in loads]
         share = jnp.mean(jnp.stack(
-            [jnp.sum(x[first:first + count]) / jnp.maximum(jnp.sum(x), 1e-30)
-             for x in loads]))
-    return jnp.stack([skew, share])
+            [r / jnp.maximum(jnp.sum(x), 1e-30) for r, x in zip(rows, loads)]))
+        fits = jnp.mean(jnp.stack(
+            [r <= held_capacity(jnp.round(jnp.sum(x)).astype(jnp.int32),
+                                count, x.shape[0])
+             for r, x in zip(rows, loads)]).astype(jnp.float32))
+    return jnp.stack([skew, share, fits])
 
 
 class PartitionedOuterOptimizer:
@@ -1014,8 +1028,9 @@ class OptimizerWrapper:
 
     def _observe_routing(self, opt_state: Any) -> None:
         """Gauges ``moe_load_max_over_mean`` and (where the
-        transformation was told the share held) ``moe_held_share`` of a
-        committed step, without a wait: the gauges' program is
+        transformation was told the share held) ``moe_held_share`` and
+        ``moe_row_buffer_share`` of a committed step, without a wait: the
+        gauges' program is
         dispatched behind the step's and its host copy started; what a
         LATER commit finds ready it reads, and starts the next. A result
         the device has not reached yet stays pending and this step's is
@@ -1026,10 +1041,11 @@ class OptimizerWrapper:
         if pending is not None:
             if not pending.is_ready():
                 return
-            skew, share = (float(v) for v in np.asarray(pending))
+            skew, share, fits = (float(v) for v in np.asarray(pending))
             self.metrics.gauge("moe_load_max_over_mean", skew)
             if share == share:      # NaN: the share held was not said
                 self.metrics.gauge("moe_held_share", share)
+                self.metrics.gauge("moe_row_buffer_share", fits)
         self._routing_pending = self._routing(opt_state)
         self._routing_pending.copy_to_host_async()
 
