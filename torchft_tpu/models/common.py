@@ -1,6 +1,6 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
-``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``), the repeat of grouped
+``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``), the repeat of grouped
 key/value heads (``nemotron_h``, ``lfm2``) and the router's balance bias — its
 key in the parameter tree, the predicate ``optim.with_balance_bias``
 partitions the leaves by, and the way a step's loads reach that rule in

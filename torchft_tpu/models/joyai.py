@@ -236,19 +236,29 @@ def _mla_sublayer(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
     B, S, _ = x.shape
     H, nope, rope = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     h = rms_norm(x, layer["ln_1"]["scale"], eps)
+    # ``models/kimi_linear.py`` calls this with ITS config: no q latent
+    # (``q_lora_rank`` 0: one ``q_proj``) and no rotation (``rope_theta``
+    # None: the shared key channels stay in the score as they are)
+    rotate = cfg.rope_theta is not None
     with jax.named_scope("mla_q"):
-        c_q = rms_norm(h @ a["q_a_proj"]["kernel"].astype(dt),
-                        a["q_a_norm"]["scale"], eps)
-        q = (c_q @ a["q_b_proj"]["kernel"].astype(dt)).reshape(
-            B, S, H, nope + rope)
-        q = jnp.concatenate(
-            [q[..., :nope], _rope_pairs(q[..., nope:], cfg.rope_theta)],
-            axis=-1)
+        if cfg.q_lora_rank:
+            c_q = rms_norm(h @ a["q_a_proj"]["kernel"].astype(dt),
+                            a["q_a_norm"]["scale"], eps)
+            q = c_q @ a["q_b_proj"]["kernel"].astype(dt)
+        else:
+            q = h @ a["q_proj"]["kernel"].astype(dt)
+        q = q.reshape(B, S, H, nope + rope)
+        if rotate:
+            q = jnp.concatenate(
+                [q[..., :nope], _rope_pairs(q[..., nope:], cfg.rope_theta)],
+                axis=-1)
     with jax.named_scope("mla_kv"):
         kv_a = h @ a["kv_a_proj"]["kernel"].astype(dt)
         c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank],
                         a["kv_a_norm"]["scale"], eps)
-        k_r = _rope_pairs(kv_a[..., None, cfg.kv_lora_rank:], cfg.rope_theta)
+        k_r = kv_a[..., None, cfg.kv_lora_rank:]
+        if rotate:
+            k_r = _rope_pairs(k_r, cfg.rope_theta)
         kv = (c_kv @ a["kv_b_proj"]["kernel"].astype(dt)).reshape(
             B, S, H, nope + cfg.v_head_dim)
         k = jnp.concatenate(

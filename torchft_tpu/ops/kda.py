@@ -1,0 +1,469 @@
+"""Pallas kernels for Kimi Delta Attention (KDA; Kimi Linear,
+arXiv:2510.26692): the gated delta rule with a decay PER KEY CHANNEL, in
+its chunked form, forward and backward under one ``jax.custom_vjp``.
+
+Per head, with a state ``S ∈ R^{K×V}``, ``S_0 = 0``, log-decays ``g_t ∈
+R^K`` (<= 0), ``α_t = exp(g_t)`` and a step size ``β_t ∈ (0, 1)``:
+
+    S_t = (I − β_t k_t k_tᵀ) Diag(α_t) S_{t-1} + β_t k_t v_tᵀ,
+    o_t = S_tᵀ q_t.
+
+Written with the delta-corrected value ``u_t = β_t (v_t − (Diag(α_t)
+S_{t-1})ᵀ k_t)`` the state is ``S_t = Diag(α_t) S_{t-1} + k_t u_tᵀ``. In
+chunks of ``C`` positions, ``G_i`` the sum of ``g`` from the chunk's
+first position to ``i`` (a vector of ``K`` channels), ``S`` the state
+that enters the chunk:
+
+    A_ij  = Σ_c k_ic k_jc exp(G_ic − G_jc)   (i > j)
+    B_ij  = Σ_c q_ic k_jc exp(G_ic − G_jc)   (i >= j)
+    U     = (I + Diag(β) A)^{-1} Diag(β) (V − (K ∘ exp G) S)
+    O     = (Q ∘ exp G) S + B U
+    S'    = Diag(exp G_C) S + (K ∘ exp(G_C − G))ᵀ U
+
+which is the recurrence whatever ``C`` is. Unlike ``ops/ssd.py``'s
+scalar-a-head decay, ``exp(G_i − G_j)`` is a vector: ``A`` and ``B`` are
+no product of two matrices, and ``exp(−G_j)`` alone may overflow. **Every
+exponent is a difference of cumulative log-decays taken so that it is <=
+0** — a channel whose decay underflows gives 0, never inf or nan — by
+splitting the pairs ``(i, j)`` of a chunk by the highest bit in which
+``i`` and ``j`` differ:
+
+- bit ``b >= 3`` (``h = 2^b``: ``i`` in the upper and ``j`` in the lower
+  half of one ``2h``-block): with ``m`` the first position of ``i``'s
+  half, ``exp(G_i − G_j) = exp(G_i − G_{m-1}) · exp(G_{m-1} − G_j)``,
+  both <= 0, the first a function of the row and the second of the
+  column alone: one masked matmul a level (``log2(C) − 3`` levels);
+- bits 0-2 (``i`` and ``j`` in one tile of 8 rows): for each distance
+  ``d = 1..7`` the band ``exp(G_i − G_{i-d})`` is formed on the whole
+  chunk at once from a sublane roll, and the sum over the channels is a
+  lane reduction.
+
+The triangular inverse ``T = (I + Diag(β) A)^{-1}`` is built by block
+doubling, ``T_{2h} = T_h − T_h X_h T_h`` with ``X_h`` the entries of
+``Diag(β) A`` between the halves of each ``2h``-block: every factor is
+an inverse of a diagonal block, bounded because the delta rule
+contracts. (The Neumann product ``(I − L)(I + L²)(I + L⁴)…`` is the same
+matrix on paper and cancels catastrophically: with correlated keys its
+terms reach ``e^{‖L‖}``.)
+
+The kernels. One grid step is one (batch, head, chunk), the chunks
+innermost and in order; the state is carried from step to step in a VMEM
+scratch, TRANSPOSED (``[V, K]``: the decay of a channel is then a lane's
+factor), in f32. No per-position state exists anywhere: nothing ``[B, S,
+H, K, V]`` is formed. ``kda_bwd`` walks the chunks LAST TO FIRST with
+the state's cotangent in the same scratch. **What the backward keeps**:
+the scan's inputs and the chunk-boundary states ``[B, H, S/C, V, K]``
+f32 (written by the forward kernel only when it runs as the vjp's
+forward rule; 537 MB a layer at 32 768 tokens, 32 heads and C 128; under
+``jax.checkpoint`` it lives for that layer's backward alone). ``A``,
+``B``, ``T``, ``U`` are recomputed. With ``R = V − K̄ S``, ``K̄ = K ∘
+exp G``, ``Q̄ = Q ∘ exp G``, ``K̃ = K ∘ exp(G_C − G)``:
+
+    dU  = Bᵀ dO + K̃ dS'                dB = tril(dO Uᵀ)
+    dRβ = Tᵀ dU                          dL = −stril(dRβ Uᵀ)   (L = βA)
+    dA  = Diag(β) dL                     dβ = Σ_j dL ∘ A + Σ_v dRβ ∘ R
+    dV  = β dRβ = dR                     dK̄ = −dR Sᵀ, dQ̄ = dO Sᵀ, dK̃ = U dS'ᵀ
+    dS  = Q̄ᵀ dO + Diag(exp G_C) dS' − K̄ᵀ dR
+
+and the pair sums' own cotangents by the same levels and bands (the
+transposed matmuls and the rolls back). ``G`` stands in every factor, so
+``dG = q ∘ dq + k ∘ dk_row − k ∘ dk_col`` (a pair adds at its row and
+subtracts at its column) ``+ dQ̄ ∘ Q̄ + dK̄ ∘ K̄ − dK̃ ∘ K̃``, the chunk's
+last row takes what ``G_C`` carries, and ``dg`` is the sum of ``dG``
+from a position to the chunk's end.
+
+What is which dtype: ``q, k, v`` arrive and ``o, dq, dk, dv`` leave in
+the input dtype (bf16 in the models); ``g`` and ``β`` are f32, as are
+the cumulative sums, every decay, the state and every accumulator. The
+two cumulative sums are matmuls against a triangle of ones at
+``Precision.HIGHEST`` (an f32 ``g`` rounded to bf16 on the MXU would
+move an exponent by 2^-9 of its size).
+
+An exponent is a difference of two of the chunk's cumulative sums, so
+its absolute error is 2^-24 of the chunk's total log-decay: 1e-5 at the
+model's decays, 1e-3 where a chunk forgets by ``e^-10000``.
+
+The chunk is ``_CHUNK`` (a shorter sequence takes the power of two that
+holds it, at least 16); a sequence that is no multiple is padded with
+``g = 0, β = 0`` positions at its end (decay 1, nothing written: they
+change nothing before them). Off the TPU the same kernels run in
+Pallas's interpreter (the CPU tests), chosen from the backend alone. On
+the TPU ``K`` and ``V`` must be multiples of 128 lanes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["kda_scan"]
+
+_NEG = -1e30     # exp(_NEG) == 0: a pair outside its band
+_LANES = 128
+_TILE = 8        # rows of an f32 tile: pairs closer than this go by bands
+# positions a chunk; see the module docstring
+_CHUNK = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _f32(a):
+    return a.astype(jnp.float32)
+
+
+def _dot(a, b, contract=((1,), (0,)), precision=None):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=precision)
+
+
+def _dot_nt(a, b):
+    """``a·bᵀ``."""
+    return _dot(a, b, ((1,), (1,)))
+
+
+def _iota(shape, axis: int):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _cross(row, col, lg: int):
+    """Pairs whose row lies in the upper and whose column in the lower
+    half of one block of ``2^(lg+1)`` positions."""
+    rb, cb = row >> lg, col >> lg
+    return (rb == cb + 1) & ((cb & 1) == 0)
+
+
+def _decays(g):
+    """``g [C, K]`` -> the cumulative sum ``G`` and what every pair's
+    decay is made of: per level ``(lg, exp(G_i − G_{m-1}) by row,
+    exp(G_{m-1} − G_j) by column)`` and per band ``(d, exp(G_i −
+    G_{i-d}))``, zero where ``i`` and ``i − d`` lie in two tiles."""
+    C, K = g.shape
+    tri = _iota((C, C), 0) >= _iota((C, C), 1)
+    G = _dot(jnp.where(tri, 1.0, 0.0), g, precision=_HIGHEST)
+    pos = _iota((C, K), 0)
+    levels = []
+    h = _TILE
+    while h < C:
+        ends = jnp.broadcast_to(
+            G.reshape(C // h, h, K)[:, h - 1:h, :], (C // h, h, K)
+        ).reshape(C, K)                                  # G at the block's end
+        before = jnp.where(pos >= h, pltpu.roll(ends, h, 0), 0.0)
+        levels.append((h.bit_length() - 1, jnp.exp(G - before),
+                       jnp.exp(ends - G)))
+        h *= 2
+    tile = pos & (_TILE - 1)
+    bands = [(d, jnp.exp(jnp.where(tile >= d, G - pltpu.roll(G, d, 0), _NEG)))
+             for d in range(1, min(_TILE, C))]
+    return G, levels, bands
+
+
+def _pairs(xs, k, levels, bands):
+    """For each ``x`` of ``xs``: ``M[i, j] = Σ_c x_ic k_jc exp(G_ic −
+    G_jc)`` over the pairs ``i > j``."""
+    C = k.shape[0]
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    out = [jnp.zeros((C, C), jnp.float32) for _ in xs]
+    for lg, by_row, by_col in levels:
+        prod = _dot_nt(jnp.concatenate([x * by_row for x in xs], axis=0),
+                       k * by_col)                       # [n·C, C]
+        live = _cross(row, col, lg)
+        for t in range(len(xs)):
+            out[t] = out[t] + jnp.where(live, prod[t * C:(t + 1) * C], 0.0)
+    for d, decay in bands:
+        kd = pltpu.roll(k, d, 0) * decay
+        live = col == row - d
+        for t, x in enumerate(xs):
+            out[t] = out[t] + jnp.where(
+                live, jnp.sum(x * kd, axis=1, keepdims=True), 0.0)
+    return out
+
+
+def _pairs_bwd(dms, xs, k, levels, bands):
+    """:func:`_pairs`' transpose: ``(dxs, dk_col)`` — the cotangents of
+    each ``x`` and of ``k`` in its column's place."""
+    C, K = k.shape
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    dxs = [jnp.zeros((C, K), jnp.float32) for _ in xs]
+    dk_col = jnp.zeros((C, K), jnp.float32)
+    for lg, by_row, by_col in levels:
+        live = _cross(row, col, lg)
+        stack = jnp.concatenate(
+            [jnp.where(live, dm, 0.0) for dm in dms], axis=0)    # [n·C, C]
+        by_rows = _dot(stack, k * by_col)                        # [n·C, K]
+        for t in range(len(xs)):
+            dxs[t] = dxs[t] + by_row * by_rows[t * C:(t + 1) * C]
+        dk_col = dk_col + by_col * _dot(
+            stack.T, jnp.concatenate([x * by_row for x in xs], axis=0))
+    for d, decay in bands:
+        kd = pltpu.roll(k, d, 0) * decay
+        live = col == row - d
+        at_row = jnp.zeros((C, K), jnp.float32)
+        for t, (dm, x) in enumerate(zip(dms, xs)):
+            band = jnp.sum(jnp.where(live, dm, 0.0), axis=1, keepdims=True)
+            dxs[t] = dxs[t] + band * kd
+            at_row = at_row + band * x
+        # what row i gives to k_{i-d}: rolled back (rows whose partner is
+        # in another tile carry a zero decay, so nothing wraps)
+        dk_col = dk_col + pltpu.roll(at_row * decay, C - d, 0)
+    return dxs, dk_col
+
+
+def _solve(lower):
+    """``(I + lower)^{-1}`` of a strictly lower-triangular ``[C, C]`` by
+    block doubling (the module docstring)."""
+    C = lower.shape[0]
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    T = jnp.where(row == col, 1.0, 0.0)
+    lg = 0
+    while (1 << lg) < C:
+        between = jnp.where(_cross(row, col, lg), lower, 0.0)
+        T = T - _dot(_dot(T, between), T)
+        lg += 1
+    return T
+
+
+def _chunk(q, k, v, g, beta, st):
+    """What both kernels compute of one chunk (f32 operands; ``beta [C,
+    1]``, ``st [V, K]`` the transposed state that enters)."""
+    C = q.shape[0]
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    G, levels, bands = _decays(g)
+    A, B = _pairs([k, q], k, levels, bands)
+    B = B + jnp.where(row == col, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    T = _solve(beta * A)
+    last = G[C - 1:C, :]                                 # [1, K]
+    gam, to_end = jnp.exp(G), jnp.exp(last - G)
+    kbar, qbar, ktil = k * gam, q * gam, k * to_end
+    R = v - _dot_nt(kbar, st)                            # [C, V]
+    U = _dot(T, beta * R)
+    return dict(levels=levels, bands=bands, A=A, B=B, T=T, last=last,
+                gam=gam, to_end=to_end, kbar=kbar, qbar=qbar, ktil=ktil,
+                R=R, U=U)
+
+
+def _beta_col(beta_ref):
+    """This head's column of the ``[C, H]`` block as ``[C, 1]`` (a
+    select and a lane sum: exact, and no one-lane slice)."""
+    block = beta_ref[0, 0]
+    lane = _iota(block.shape, 1)
+    return jnp.sum(jnp.where(lane == pl.program_id(1), block, 0.0), axis=1,
+                   keepdims=True)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                    save_states: bool):
+    """One (batch, head, chunk): ``o`` of the chunk and the state it
+    leaves, the state it entered with written out for the backward where
+    asked."""
+    state = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[...] = jnp.zeros_like(state)
+
+    st = state[...]                                      # [V, K]
+    if save_states:
+        rest[0][0, 0, 0] = st
+    q, k, v = _f32(q_ref[0]), _f32(k_ref[0]), _f32(v_ref[0])
+    c = _chunk(q, k, v, g_ref[0], _beta_col(beta_ref), st)
+    o = _dot_nt(c["qbar"], st) + _dot(c["B"], c["U"])
+    o_ref[0] = o.astype(o_ref.dtype)
+    state[...] = st * jnp.exp(c["last"]) + _dot(c["U"].T, c["ktil"])
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate):
+    """One (batch, head, chunk), chunks last to first; ``dstate`` carries
+    the cotangent of the (transposed) state the chunk leaves."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    q, k, v = _f32(q_ref[0]), _f32(k_ref[0]), _f32(v_ref[0])
+    beta = _beta_col(beta_ref)
+    st, dst, do = st_ref[0, 0, 0], dstate[...], _f32(do_ref[0])
+    C, K = q.shape
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    c = _chunk(q, k, v, g_ref[0], beta, st)
+    U, R, T = c["U"], c["R"], c["T"]
+    dU = _dot(c["B"].T, do) + _dot_nt(c["ktil"], dst)    # [C, V]
+    dB = jnp.where(row >= col, _dot_nt(do, U), 0.0)
+    dqbar, dktil = _dot(do, st), _dot(U, dst)            # [C, K]
+    dRb = _dot(T.T, dU)
+    dL = jnp.where(row > col, -_dot_nt(dRb, U), 0.0)
+    dbeta = (jnp.sum(dL * c["A"], axis=1, keepdims=True)
+             + jnp.sum(dRb * R, axis=1, keepdims=True))  # [C, 1]
+    dR = beta * dRb
+    dkbar = -_dot(dR, st)
+    decay = jnp.exp(c["last"])                           # [1, K]
+    dstate[...] = (dst * decay + _dot(do.T, c["qbar"])
+                   - _dot(dR.T, c["kbar"]))
+    (dk_row, dq), dk_col = _pairs_bwd(
+        [beta * dL, dB], [k, q], k, c["levels"], c["bands"])
+    diag = jnp.sum(jnp.where(row == col, dB, 0.0), axis=1, keepdims=True)
+    dG = (k * (dk_row - dk_col) + q * dq + dqbar * c["qbar"]
+          + dkbar * c["kbar"] - dktil * c["ktil"])
+    # what the chunk's total decay carries, on its last row
+    carried = (jnp.sum(dktil * c["ktil"], axis=0, keepdims=True)
+               + decay * jnp.sum(st * dst, axis=0, keepdims=True))
+    dG = dG + jnp.where(_iota((C, K), 0) == C - 1, carried, 0.0)
+    dq_ref[0] = (dq + diag * k + dqbar * c["gam"]).astype(dq_ref.dtype)
+    dk_ref[0] = (dk_row + dk_col + diag * q + dkbar * c["gam"]
+                 + dktil * c["to_end"]).astype(dk_ref.dtype)
+    dv_ref[0] = dR.astype(dv_ref.dtype)
+    dg_ref[0] = _dot(jnp.where(col >= row, 1.0, 0.0), dG,
+                     precision=_HIGHEST).astype(dg_ref.dtype)
+    # the column as a row: lane-dense in HBM
+    dbeta_ref[0, 0, 0] = jnp.sum(jnp.where(row == col, dbeta, 0.0), axis=0,
+                                 keepdims=True)
+
+
+def _layouts(q, k, v, g, beta, chunk: int):
+    """The kernels' operands from the public ones (padded to whole
+    chunks): ``q, k, g [B, S, H·K]``, ``v [B, S, H·V]``, ``β [B, S/C, C,
+    H]``."""
+    b, s, h, kd = q.shape
+    pad = (-s) % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))
+            for z in (q, k, v, g, beta))
+    sp = s + pad
+    return (q.reshape(b, sp, h * kd), k.reshape(b, sp, h * kd),
+            v.reshape(b, sp, h * v.shape[3]), g.reshape(b, sp, h * kd),
+            beta.reshape(b, sp // chunk, chunk, h))
+
+
+def _specs(chunk: int, h: int, kd: int, vd: int, at):
+    """Block specs of the five operands both kernels read; ``at`` maps
+    the grid's chunk index to the chunk (the backward's runs down)."""
+    def wide(width):
+        return pl.BlockSpec((1, chunk, width), lambda b, h, c: (b, at(c), h))
+
+    return [wide(kd), wide(kd), wide(vd), wide(kd),
+            pl.BlockSpec((1, 1, chunk, h), lambda b, h, c: (b, at(c), 0, 0))]
+
+
+def _forward(q, k, v, g, beta, chunk: int, interpret: bool,
+             save_states: bool):
+    b, s, h, kd = q.shape
+    vd = v.shape[3]
+    ops = _layouts(q, k, v, g, beta, chunk)
+    sp = ops[0].shape[1]
+    nc = sp // chunk
+    out_shape = [jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype)]
+    out_specs = [pl.BlockSpec((1, chunk, vd), lambda b, h, c: (b, c, h))]
+    if save_states:
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, vd, kd),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, vd, kd),
+                                      lambda b, h, c: (b, h, c, 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, save_states=save_states),
+        grid=(b, h, nc),
+        in_specs=_specs(chunk, h, kd, vd, lambda c: c),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((vd, kd), jnp.float32)],
+        interpret=interpret, name="kda_fwd",
+    )(*ops)
+    o = out[0][:, :s].reshape(b, s, h, vd)
+    return o, (out[1] if save_states else None)
+
+
+def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
+    b, s, h, kd = q.shape
+    vd = v.shape[3]
+    ops = _layouts(q, k, v, g, beta, chunk)
+    sp = ops[0].shape[1]
+    nc = sp // chunk
+    do = jnp.pad(do, ((0, 0), (0, sp - s), (0, 0), (0, 0))).reshape(
+        b, sp, h * vd)
+
+    def at(c):
+        return nc - 1 - c
+
+    specs = _specs(chunk, h, kd, vd, at)
+    keys, _, values, _, _ = specs
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        _kda_bwd_kernel,
+        grid=(b, h, nc),
+        in_specs=specs + [
+            pl.BlockSpec((1, 1, 1, vd, kd),
+                         lambda b, h, c: (b, h, at(c), 0, 0)),
+            values,
+        ],
+        out_specs=[
+            keys, keys, values, keys,
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b, h, c: (b, h, at(c), 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sp, h * kd), q.dtype),
+            jax.ShapeDtypeStruct((b, sp, h * kd), k.dtype),
+            jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype),
+            jax.ShapeDtypeStruct((b, sp, h * kd), g.dtype),
+            jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((vd, kd), jnp.float32)],
+        interpret=interpret, name="kda_bwd",
+    )(*ops, states, do)
+    dbeta = dbeta.reshape(b, h, sp).transpose(0, 2, 1)
+    return (dq[:, :s].reshape(q.shape), dk[:, :s].reshape(k.shape),
+            dv[:, :s].reshape(v.shape), dg[:, :s].reshape(g.shape),
+            dbeta[:, :s].astype(beta.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda(q, k, v, g, beta, chunk, interpret):
+    return _forward(q, k, v, g, beta, chunk, interpret, False)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, chunk, interpret):
+    o, states = _forward(q, k, v, g, beta, chunk, interpret, True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _kda_bwd(chunk, interpret, residuals, do):
+    return _backward(*residuals, do, chunk, interpret)
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def _choose_chunk(seq_len: int) -> int:
+    """``_CHUNK``, or for a shorter sequence the power of two that holds
+    it (at least the 16 rows of a packed bf16 tile)."""
+    return min(_CHUNK, max(16, 1 << (seq_len - 1).bit_length()))
+
+
+def kda_scan(q, k, v, g, beta):
+    """The gated delta rule of the module's docstring.
+
+    ``q, k [B, S, H, K]`` (the caller's normalisation and scale already
+    in them), ``v [B, S, H, V]``, ``g [B, S, H, K]`` (log-decays, <= 0,
+    f32), ``beta [B, S, H]`` (f32) -> ``o [B, S, H, V]`` in ``v``'s
+    dtype, differentiable in all five. The chunk is chosen from the
+    sequence length (:func:`_choose_chunk`); the result does not depend
+    on it beyond rounding (``tests/test_kda.py`` runs ``_kda`` at
+    others)."""
+    if (k.shape != q.shape or g.shape != q.shape
+            or v.shape[:3] != q.shape[:3] or beta.shape != q.shape[:3]):
+        raise ValueError(
+            f"kda_scan: q{tuple(q.shape)} k{tuple(k.shape)} "
+            f"v{tuple(v.shape)} g{tuple(g.shape)} beta{tuple(beta.shape)} "
+            "do not fit")
+    interpret = _interpret()
+    if not interpret and (q.shape[3] % _LANES or v.shape[3] % _LANES):
+        raise ValueError(
+            f"kda_scan: heads of {q.shape[3]} key and {v.shape[3]} value "
+            f"channels are no multiples of {_LANES} lanes")
+    return _kda(q, k, v, _f32(g), _f32(beta), _choose_chunk(q.shape[1]),
+                interpret)
