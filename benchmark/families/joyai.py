@@ -3,8 +3,10 @@ the six functions of ``families/olmoe.py`` — ``build``, ``init_state``,
 ``make_train_step``, ``make_grad_step``, ``flops_per_token``,
 ``check_reference`` — and nothing of any one configuration. The step
 programs are the one step maker's (``models/transformer.py``) with this
-family's loss, and the optimizer is the configuration's AdamW with the
-balance-bias rule on the bias leaves (``optim.with_balance_bias``).
+family's loss, and the optimizer is the configuration's AdamW behind its
+linear warm-up (an optax schedule: its count is optimizer state) with the
+balance-bias rule on the bias leaves (``optim.with_balance_bias``, told
+which experts are held).
 ``check_reference`` is ``judge(per_token_errors(...))``; the two are
 apart so that a test or ``tests/joyai_faults.py`` can run a faulty
 system against the sound reference under the cell's own limits.
@@ -129,10 +131,15 @@ def build(config: Dict[str, Any]) -> Model:
         init_std=float(config["initializer_range"]),
         remat=bool(job["remat"]), xent_chunks=int(job["xent_chunks"]),
     )
+    peak, warm = float(opt["learning_rate"]), int(opt["warmup_steps"])
     tx = with_balance_bias(
-        optax.adamw(opt["learning_rate"], b1=opt["b1"], b2=opt["b2"],
-                    eps=opt["eps"], weight_decay=opt["weight_decay"]),
+        optax.adamw(
+            # step c (from 0) runs at peak x (c + 1) / warm, then at peak
+            optax.linear_schedule(peak / warm, peak, warm - 1),
+            b1=opt["b1"], b2=opt["b2"], eps=opt["eps"],
+            weight_decay=opt["weight_decay"]),
         float(opt["balance_bias_rate"]), is_balance_bias,
+        held=(cfg.first_expert, cfg.n_experts_held),
     )
     return Model(
         cfg=cfg, tx=tx, seq_len=int(job["seq_len"]),

@@ -222,6 +222,7 @@ def build(config: Dict[str, Any]) -> Model:
             mask=lambda params: jax.tree_util.tree_map(
                 lambda x: x.ndim >= 2, params)),
         float(opt["balance_bias_rate"]), is_balance_bias,
+        held=(cfg.first_expert, cfg.n_experts_held),
     )
     return Model(
         cfg=cfg, tx=tx, seq_len=int(job["seq_len"]),

@@ -82,17 +82,53 @@ def test_the_joyai_family_runs_the_steady_job_at_the_tiny_size(
     assert all(v > 0 for v in inner)
     assert sum(inner) < got["mlp_device_share"]["value"]
     assert 0 < got["mtp_device_share"]["value"] < 0.6
+    # the three gauges of the optimizer wrapper's sink: the family says
+    # which experts are held (4 of the tiny router's 8)
+    assert 0.0 < got["moe_held_share"]["value"] < 1.0
+    assert got["moe_load_max_over_mean"]["value"] >= 1.0
+    assert got["moe_row_buffer_share"]["value"] in (0.0, 0.5, 1.0)
     # every metric the cell lists: the 2 of set-up, the 15 solo ones, the
-    # sparse sublayer's 4 and the family's own 6; all but the three
-    # rooflines and the two rates of untraced steps (a 4 s window is all
-    # traced) are printed here
+    # sparse sublayer's 4, the 3 gauges and the family's own 6; all but
+    # the three rooflines and the two rates of untraced steps (a 4 s
+    # window is all traced) are printed here
     mine = rehearse.cell_metrics(CELL)
-    assert len(mine) == 27
+    assert len(mine) == 30
     assert mine - set(got) <= {
         "mla_flash_fwd_roofline", "mla_flash_dq_roofline",
         "mla_flash_dkv_roofline", "ft_over_bare", "window_over_blocks"}
     # on the CPU attention is the XLA path: no flash event, so no roofline
     assert not any(k.endswith("_roofline") for k in got)
+
+
+def test_the_configurations_first_update_runs_at_the_warm_ups_first_rate(
+) -> None:
+    """``joyai-llm-flash-ep16``'s optimizer as the family builds it: AdamW
+    behind the 2000-step linear warm-up (step c at 4e-4 x (c + 1) / 2000;
+    at the constant 4e-4 the router collapsed within 6 - 9 steps), the
+    schedule's count in the optimizer state, and the held experts said."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.families import joyai as family
+
+    with open(os.path.join(rehearse._REPO, "benchmark", "configs",
+                           "joyai-llm-flash-ep16.json")) as f:
+        config = json.load(f)
+    model, opt = family.build(config), config["optimizer"]
+    assert model.tx.held_experts == (0, 16)
+    first_rate = opt["learning_rate"] / opt["warmup_steps"]
+    assert first_rate == pytest.approx(2e-7)
+    # Adam's first step on a zero weight is the rate times the gradient's sign
+    params = {"w": jnp.zeros((3,), jnp.float32)}
+    grads = {"w": jnp.ones((3,), jnp.float32)}
+    state = model.tx.init(params)
+    for c in range(3):
+        update, state = model.tx.update(grads, state, params)
+        assert np.allclose(update["w"], -first_rate * (c + 1), rtol=1e-4)
+    counts = [int(x) for x in jax.tree_util.tree_leaves(state)
+              if x.ndim == 0 and x.dtype == jnp.int32]
+    assert counts and set(counts) == {3}
 
 
 def test_inner_scope_classification() -> None:

@@ -154,3 +154,77 @@ def test_every_cell_has_a_per_layer_metric_and_the_whole_steps_mfu() -> None:
                    if cell in _cells(m)}
         if "committed_tokens_per_s" in reports:
             assert "bare_mfu" in mine, cell
+
+
+# -- the cells that hold a share of their experts ----------------------------
+
+GAUGES = ("moe_held_share", "moe_load_max_over_mean", "moe_row_buffer_share")
+
+
+def _load(path):
+    with open(os.path.join(os.path.dirname(_BENCH), path)) as f:
+        return json.load(f)
+
+
+CONFIGS = {c["name"]: _load(c["file"]) for c in MANIFEST["configs"]}
+SHARE_CONFIGS = sorted(n for n, c in CONFIGS.items() if "share" in c)
+
+
+@pytest.mark.parametrize("gauge", GAUGES)
+def test_a_routing_gauge_lists_every_share_cell_and_no_other(gauge) -> None:
+    """The three numbers a share cell's balance rule has to hold stand on
+    the driver's line of every cell whose configuration holds a share
+    (PR 41 was lost to two cells whose routing no line showed)."""
+    assert len(SHARE_CONFIGS) >= 4
+    cells = {w["name"] for w in MANIFEST["workloads"]
+             if w["config"] in SHARE_CONFIGS}
+    assert set(ENTRIES[gauge]["workloads"]) == cells
+    assert {"joyai-ep16-solo-steady", "nemo3-ep16-solo-steady"} <= cells
+
+
+@pytest.mark.parametrize("name", SHARE_CONFIGS)
+def test_a_share_configuration_names_held_and_the_rule_its_rate_was_set_by(
+        name) -> None:
+    """The family tells the optimizer which experts are held (so the cell
+    emits all three gauges), the configuration states the rule a share
+    cell's bias rate is set by, once, with its rate, and a rate that
+    differs from a sibling's names that sibling's and says why."""
+    config = CONFIGS[name]
+    assert {"first_expert", "router_width"} <= set(config["share"])
+    # read, not imported: this file stays off jax (the cell tests hold the
+    # gauges' emission and ``tx.held_experts``)
+    with open(os.path.join(_BENCH, "families",
+                           config["family"] + ".py")) as f:
+        assert "held=(cfg.first_expert, cfg.n_experts_held)" in f.read()
+    rate = config["optimizer"]["balance_bias_rate"]
+    rule = config["assumed"]["balance_rule"]
+    assert f"b_e += {rate:g} x sign(mean(load) - load_e)" in rule
+    assert "0.8 - 1.25" in rule and "load max / mean" in rule
+    others = {CONFIGS[n]["optimizer"]["balance_bias_rate"]
+              for n in SHARE_CONFIGS} - {rate}
+    for other in others:
+        assert f"{other:g}" in rule.replace(f"{rate:g} x sign", ""), other
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_drift_tool_takes_a_share_cell_and_refuses_any_other(cell) -> None:
+    """``routing_drift.py --workload``: one tool for the cells that hold a
+    share, found through the manifest as ``run.py`` finds them."""
+    from benchmark.tests import routing_drift
+
+    config = {w["name"]: w for w in MANIFEST["workloads"]}[cell]["config"]
+    if config in SHARE_CONFIGS:
+        assert routing_drift.cell_config(cell) == CONFIGS[config]
+    else:
+        with pytest.raises(SystemExit, match="holds no share"):
+            routing_drift.cell_config(cell)
+
+
+def test_the_drift_tools_band_is_the_rule_the_configurations_state() -> None:
+    from benchmark.tests import routing_drift
+
+    sixteenth = 1 / 16
+    assert routing_drift.in_band([0.050, 0.078], [1.3, 2.0], sixteenth)
+    assert not routing_drift.in_band([0.049, 0.06], [1.3, 1.3], sixteenth)
+    assert not routing_drift.in_band([0.06, 0.079], [1.3, 1.3], sixteenth)
+    assert not routing_drift.in_band([0.06, 0.06], [1.3, 2.1], sixteenth)

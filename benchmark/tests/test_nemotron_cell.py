@@ -87,12 +87,17 @@ def test_the_nemotron_family_runs_the_steady_job_at_the_tiny_size(
     assert all(v > 0 for v in inner)
     assert sum(inner) == pytest.approx(
         got["mlp_device_share"]["value"], rel=0.05)
+    # the three gauges of the optimizer wrapper's sink: the family says
+    # which experts are held
+    assert 0.0 < got["moe_held_share"]["value"] < 1.0
+    assert got["moe_load_max_over_mean"]["value"] >= 1.0
+    assert 0.0 <= got["moe_row_buffer_share"]["value"] <= 1.0
     # every metric the cell lists (the 2 of set-up, the 15 solo ones, the
-    # sparse sublayer's 4, the family's own 7) but the two rooflines: on
-    # the CPU the scan runs in Pallas's interpreter, and no event is named
-    # ``ssd_fwd``
+    # sparse sublayer's 4, the 3 gauges, the family's own 7) but the two
+    # rooflines: on the CPU the scan runs in Pallas's interpreter, and no
+    # event is named ``ssd_fwd``
     mine = rehearse.cell_metrics(CELL)
-    assert len(mine) == 28
+    assert len(mine) == 31
     missing = mine - set(got)
     assert missing <= {"ssd_fwd_roofline", "ssd_bwd_roofline",
                        # a 4 s window is all traced, so no rate of
