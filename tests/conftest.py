@@ -49,3 +49,19 @@ def one_compiling_file_at_a_time():
     worker that waits here takes no core."""
     with _one_at_a_time("compiling_file"):
         yield
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e host, for the tests that compile a
+    kernel at a cell's widths with the chip's compiler (nothing runs;
+    ``tests/test_kda.py``, ``tests/test_ssm_pointwise.py``). Described
+    only once a test asks: never at import."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])

@@ -150,18 +150,6 @@ def test_the_chunk_and_what_is_refused() -> None:
 # -- the kernels at the cell's widths, for a described v5e --------------------
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
     """[1, 1024] of 32 heads of 128 at the cell's chunk: Mosaic takes the
     rolls, the tile reshapes, the transposes and the HIGHEST-precision

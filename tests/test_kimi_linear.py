@@ -212,17 +212,53 @@ def test_latent_attention_is_joyais_without_a_q_latent_or_a_turn() -> None:
         run(turned, layer, x) - run(CFG32, layer, x)))) > 1e-3
 
 
+def _made_outside_kernels(jaxpr, keep):
+    """``(primitive, aval)`` of every output that ``keep`` takes of the
+    equations that compute (a call's are its body's), through every
+    nested jaxpr but a kernel's own."""
+    seen = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        inner = list(jax.core.jaxprs_in_params(eqn.params))
+        for sub in inner:
+            seen += _made_outside_kernels(sub, keep)
+        if not inner:
+            seen += [(eqn.primitive.name, v.aval) for v in eqn.outvars
+                     if keep(v.aval)]
+    return seen
+
+
 def test_the_delta_rule_mixer_runs_the_kernels_and_gates_after_the_norm():
     params = _params(CFG, 4)
     x = jax.random.normal(jax.random.key(2), (2, 32, 64), jnp.bfloat16)
     run = functools.partial(kimi_linear._kda_sublayer, CFG,
                             params["layers_0"])
+
+    def loss(a):
+        return jnp.sum(run(a).astype(jnp.float32))
+
     text = str(jax.make_jaxpr(run)(x))
-    assert text.count("name=ssm_conv_fwd") == 1      # ONE convolution
-    assert text.count("name=kda_fwd") == 1
-    grad = str(jax.make_jaxpr(jax.grad(
-        lambda a: jnp.sum(run(a).astype(jnp.float32))))(x))
-    assert "name=kda_bwd" in grad and "name=ssm_conv_bwd" in grad
+    # ONE convolution, the norms and the decay, the scan, the gate
+    for kernel in ("ssm_conv_fwd", "kda_qkg_fwd", "kda_fwd", "kda_ogate_fwd"):
+        assert text.count(f"name={kernel}") == 1, kernel
+    grad = str(jax.make_jaxpr(jax.grad(loss))(x))
+    for kernel in ("ssm_conv_bwd", "kda_qkg_bwd", "kda_bwd", "kda_ogate_bwd"):
+        assert grad.count(f"name={kernel}") == 1, kernel
+    # bf16 compute: of the stream's size [B, S, H·D] nothing f32 is made
+    # outside a kernel; the decays and their cotangent (the kernels'
+    # own results) are only laid out anew. Eight heads, so that H·D is
+    # not d_model
+    wide = dataclasses.replace(CFG, n_heads=8)
+    layer = kimi_linear.init_params(wide, jax.random.key(4))["layers_0"]
+    sub = functools.partial(kimi_linear._kda_sublayer, wide, layer)
+
+    def stream_f32(aval):
+        return aval.dtype == jnp.float32 and aval.size == 2 * 32 * 8 * 16
+
+    for fn in (sub, jax.grad(lambda a: jnp.sum(sub(a).astype(jnp.float32)))):
+        made = _made_outside_kernels(jax.make_jaxpr(fn)(x).jaxpr, stream_f32)
+        assert {name for name, _ in made} <= {"reshape", "pad", "slice"}, made
     o = jax.random.normal(jax.random.key(3), (1, 8, 4, 16), jnp.float32)
     gate = jax.random.normal(jax.random.key(4), (1, 8, 4, 16), jnp.float32)
     scale = jnp.linspace(0.5, 1.5, 16)

@@ -1,6 +1,8 @@
 """``ops/ssm_pointwise.py``: the two fused stages around the Mamba-2
-scan, and LFM2's gated short convolution (the convolution's kernel body
-with two multiplicands in the bias's and silu's place), against plain
+scan, LFM2's gated short convolution (the convolution's kernel body
+with two multiplicands in the bias's and silu's place), and the two
+around the delta rule (l2 norms and decay before it; head norm, then a
+sigmoid gate, behind it), against plain
 f32 formulas — value and every gradient, whatever the blocks. Interpreter-mode Pallas on the
 CPU, so the shapes are small. The formulas here are the oracle (and
 ``scripts/ssm_pointwise_micro.py``'s jnp side);
@@ -60,6 +62,81 @@ def gated_conv_formula(bcx, taps):
     padded = jnp.pad(b * x, ((0, 0), (K - 1, 0), (0, 0)))
     return (c * sum(taps[j].astype(F32) * padded[:, j:j + S]
                     for j in range(K))).astype(bcx.dtype)
+
+
+L2_EPS = 1e-6
+
+
+def l2_normed(x):
+    """A head's l2 normalisation, as ``models/kimi_linear.py`` has it."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(
+        jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+
+
+def head_norm_then_gate(o, scale, gate, eps):
+    """``RMSNorm_head(o) ⊙ σ(gate)``: the norm first."""
+    o = o.astype(F32)
+    normed = o * jax.lax.rsqrt(
+        jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps)
+    return normed * scale.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
+
+
+def kda_qkg_formula(qkv, f, dt_bias, a_log):
+    """``q̃ / ‖q̃‖ · D^{-1/2}``, ``k̃ / ‖k̃‖`` and ``v`` in ``qkv``'s dtype,
+    ``−exp(A_log_h) · softplus(f + dt_bias)`` in f32; a head at a time,
+    f32 inside."""
+    (B, S, W), H = f.shape, a_log.shape[0]
+    D = W // H
+    q, k, v = (qkv[..., i * W:(i + 1) * W].reshape(B, S, H, D)
+               for i in range(3))
+    g = -jnp.exp(a_log.astype(F32))[:, None] * jax.nn.softplus(
+        f.astype(F32).reshape(B, S, H, D) + dt_bias.astype(F32).reshape(H, D))
+    return ((l2_normed(q) * D ** -0.5).astype(qkv.dtype).reshape(B, S, W),
+            l2_normed(k).astype(qkv.dtype).reshape(B, S, W),
+            v.reshape(B, S, W), g.reshape(B, S, W))
+
+
+def kda_ogate_formula(o, gate, scale, eps):
+    B, S, W = o.shape
+    heads = (B, S, W // scale.shape[0], scale.shape[0])
+    return head_norm_then_gate(o.reshape(heads), scale, gate.reshape(heads),
+                               eps).astype(o.dtype).reshape(B, S, W)
+
+
+def qkg_inputs(seed, b, s, h, d, dtype=F32):
+    """The operands, and a cotangent for each of ``q, k, v, g``;
+    ``dt_bias`` and ``A_log`` as ``models/kimi_linear.py`` draws them,
+    their first channels and heads at the initialiser's extremes (a step
+    of 1e-3 and of 1e-1, a rate of 1 and of 16)."""
+    key = jax.random.split(jax.random.key(seed), 8)
+    w = h * d
+    dt = jnp.exp(jax.random.uniform(
+        key[2], (w,), F32, np.log(1e-3), np.log(1e-1)))
+    dt = dt.at[0].set(1e-3).at[1].set(1e-1)
+    a_log = jnp.log(jax.random.uniform(key[3], (h,), F32, 1.0, 16.0))
+    a_log = a_log.at[0].set(0.0).at[-1].set(np.log(16.0))
+    cots = tuple(jax.random.normal(key[4 + i], (b, s, w), F32).astype(
+        F32 if i == 3 else dtype) for i in range(4))
+    return (jax.random.normal(key[0], (b, s, 3 * w), F32).astype(dtype),
+            2.0 * jax.random.normal(key[1], (b, s, w), F32).astype(dtype),
+            dt + jnp.log(-jnp.expm1(-dt)), a_log), cots
+
+
+def qkg(qkv, f, dt_bias, a_log, blocks=None):
+    """``kda_qkg`` at blocks of the test's choosing."""
+    if blocks is None:
+        return sp.kda_qkg(qkv, f, dt_bias, a_log, l2_normed)
+    head = f.shape[-1] // a_log.shape[0]
+    return sp._qkg(qkv, f, dt_bias, jnp.repeat(a_log, head), head, l2_normed,
+                   blocks, sp._interpret())
+
+
+def ogate(o, gate, scale, blocks=None, eps=1e-5):
+    if blocks is None:
+        return sp.kda_ogate(o, gate, scale, eps, head_norm_then_gate)
+    return sp._ogate(o, gate, scale, scale.shape[0], eps,
+                     head_norm_then_gate, blocks, sp._interpret())
 
 
 def gated_inputs(seed, b, s, c, k, dtype=F32):
@@ -307,6 +384,131 @@ def test_bf16_operands_f32_inside(stage):
         assert_close(g, w, 1e-5, "parameter")
 
 
+# (case, B, S, heads, a head's channels, (rows, lanes) a block or None)
+KDA_CASES = [
+    ("one-block-heads-of-16", 1, 32, 4, 16, None),
+    ("two-channel-blocks-of-two-heads", 1, 32, 4, 128, (32, 256)),
+    ("four-sequence-blocks", 1, 64, 2, 128, (16, 256)),
+    ("a-sequence-that-is-no-multiple-of-the-block", 1, 40, 2, 128, (16, 128)),
+    ("two-batch-rows", 2, 32, 2, 128, (16, 256)),
+    ("a-head-a-channel-block", 1, 32, 3, 128, (16, 128)),
+    ("rows-and-channel-blocks-together", 2, 48, 4, 128, (16, 256)),
+    ("blocks-chosen-from-the-shape-narrow-heads", 2, 24, 4, 16, None),
+]
+KDA_IDS = [c[0] for c in KDA_CASES]
+
+
+@pytest.mark.parametrize("case", KDA_CASES, ids=KDA_IDS)
+def test_kda_qkg_equals_the_formula(case):
+    """Value and the ``jax.vjp`` of every operand, the parameters at the
+    initialiser's extremes, and a head whose ``q̃`` is all zeros at a
+    position (``L2_EPS`` alone under the root)."""
+    name, b, s, h, d, blocks = case
+    (qkv, f, dt_bias, a_log), cots = qkg_inputs(len(name), b, s, h, d)
+    qkv = qkv.at[0, 3, :d].set(0.0)
+    want, pull = jax.vjp(kda_qkg_formula, qkv, f, dt_bias, a_log)
+    got, pull_got = jax.vjp(
+        lambda *a: qkg(*a, blocks=blocks), qkv, f, dt_bias, a_log)
+    for leaf, g, w in zip(("q", "k", "v", "g"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape == f.shape, leaf
+        assert_close(g, w, 2e-6, leaf)
+    assert not np.any(np.asarray(got[0][0, 3, :d]))
+    # the one cotangent array, a third at a time: dq̃, dk̃, dv
+    (d_qkv, *grads), (w_qkv, *wants) = pull_got(cots), pull(cots)
+    assert d_qkv.dtype == w_qkv.dtype and d_qkv.shape == w_qkv.shape
+    w = h * d
+    for i, leaf in enumerate(("dq~", "dk~", "dv")):
+        assert_close(d_qkv[..., i * w:(i + 1) * w],
+                     w_qkv[..., i * w:(i + 1) * w], 1e-5, leaf)
+    np.testing.assert_array_equal(d_qkv[..., 2 * w:], cots[2])
+    for leaf, g, w in zip(("f", "dt_bias", "A_log"), grads, wants):
+        assert g.dtype == w.dtype and g.shape == w.shape, leaf
+        assert_close(g, w, 1e-5, leaf)
+
+
+@pytest.mark.parametrize("case", KDA_CASES, ids=KDA_IDS)
+def test_kda_ogate_equals_the_formula(case):
+    name, b, s, h, d, blocks = case
+    o, gate, _, dy = gate_inputs(len(name), b, s, h * d)
+    scale = jnp.linspace(0.5, 1.5, d)
+    want, pull = jax.vjp(
+        lambda *a: kda_ogate_formula(*a, 1e-5), o, gate, scale)
+    got, pull_got = jax.vjp(
+        lambda *a: ogate(*a, blocks=blocks), o, gate, scale)
+    assert_close(got, want, 2e-6, "value")
+    for leaf, g, w in zip(("o", "gate", "scale"), pull_got(dy), pull(dy)):
+        assert g.dtype == w.dtype and g.shape == w.shape, leaf
+        assert_close(g, w, 1e-5, leaf)
+
+
+@pytest.mark.parametrize("stage", ["kda_qkg", "kda_ogate"])
+def test_kda_bf16_operands_f32_inside(stage):
+    """bf16 in and out, the decay out and its cotangent in f32; what
+    lies between is f32: the f32 formula on the rounded inputs to the
+    one rounding of each result, and so is every gradient; the
+    parameters' are f32 sums."""
+    bf16, one = jnp.bfloat16, 2 ** -8 + 1e-5
+
+    def up(tree):
+        return jax.tree_util.tree_map(lambda a: a.astype(F32), tree)
+
+    if stage == "kda_qkg":
+        args, cot = qkg_inputs(3, 2, 48, 2, 128, bf16)
+        got, pull_got = jax.vjp(lambda *a: qkg(*a, blocks=(16, 128)), *args)
+        want, pull = jax.vjp(kda_qkg_formula, *up(args))
+        assert [g.dtype for g in got] == [bf16, bf16, bf16, F32]
+        for leaf, g, w in zip(("q", "k", "v"), got[:3], want[:3]):
+            assert_close(g, w, one, leaf)
+        assert_close(got[3], want[3], 2e-6, "g")
+    else:
+        o, gate, _, cot = gate_inputs(4, 2, 48, 256, bf16)
+        args = (o, gate, jnp.linspace(0.5, 1.5, 128))
+        got, pull_got = jax.vjp(lambda *a: ogate(*a, blocks=(16, 128)), *args)
+        want, pull = jax.vjp(
+            lambda *a: kda_ogate_formula(*a, 1e-5), *up(args))
+        assert got.dtype == bf16
+        assert_close(got, want, one, "value")
+    grads, wants = pull_got(cot), pull(up(cot))
+    assert [g.dtype for g in grads] == [a.dtype for a in args]
+    # two bf16 operands, then the parameters
+    for g, w in zip(grads[:2], wants[:2]):
+        assert_close(g, w, one, "operand")
+    for g, w in zip(grads[2:], wants[2:]):
+        assert_close(g, w, 1e-5, "parameter")
+
+
+def test_the_kernels_call_the_callers_head_functions():
+    """What a head's normalisation and gate ARE is the caller's: another
+    function in their place changes the result (``models/kimi_linear.py``
+    hands over its seams, which the benchmark's faults patch), and a
+    counter sees ``q``'s call before ``k``'s, pair by pair, forward and
+    backward."""
+    (qkv, f, dt_bias, a_log), cots = qkg_inputs(7, 1, 32, 2, 128)
+    calls = []
+
+    def q_dropped(x):
+        calls.append(len(calls) % 2)
+        return x.astype(F32) if calls[-1] == 0 else l2_normed(x)
+
+    def run(*a):
+        return sp._qkg(*a, 128, q_dropped, (16, 128), sp._interpret())
+
+    got, pull = jax.vjp(run, qkv, f, dt_bias, jnp.repeat(a_log, 128))
+    want = kda_qkg_formula(qkv, f, dt_bias, a_log)
+    assert calls and len(calls) % 2 == 0
+    np.testing.assert_allclose(got[0], qkv[..., :256] * 128 ** -0.5,
+                               rtol=1e-6)
+    assert_close(got[1], want[1], 2e-6, "k")
+    np.testing.assert_allclose(pull(cots)[0][..., :256],
+                               cots[0] * 128 ** -0.5, rtol=1e-6)
+    o, gate, _, _ = gate_inputs(7, 1, 32, 256)
+    scale = jnp.linspace(0.5, 1.5, 128)
+    gate_first = sp.kda_ogate(
+        o, gate, scale, 1e-5, lambda o, s, z, eps: head_norm_then_gate(
+            o * jax.nn.sigmoid(z), s, jnp.full_like(z, 1e9), eps))
+    assert float(jnp.max(jnp.abs(gate_first - ogate(o, gate, scale)))) > 0.05
+
+
 def test_shapes_the_kernels_refuse():
     x, taps, bias, _ = conv_inputs(1, 1, 16, 128, 4)
     with pytest.raises(ValueError, match="do not fit"):
@@ -329,11 +531,39 @@ def test_shapes_the_kernels_refuse():
         sp.gated_norm(y, z, scale, 3, 1e-5)
     with pytest.raises(ValueError, match="do not fit"):
         sp.gated_norm(y, z, scale[:128], 2, 1e-5)
-    # on the chip channels come in whole 128-lane tiles
+    (qkv, f, dt_bias, a_log), _ = qkg_inputs(1, 1, 16, 2, 128)
+    with pytest.raises(ValueError, match="kda_qkg: .* do not fit"):
+        sp.kda_qkg(qkv[..., :512], f, dt_bias, a_log, l2_normed)
+    with pytest.raises(ValueError, match="kda_qkg: .* do not fit"):
+        sp.kda_qkg(qkv, f, dt_bias[:128], a_log, l2_normed)
+    with pytest.raises(ValueError, match="kda_qkg: .* do not fit"):
+        sp.kda_qkg(qkv, f, dt_bias, jnp.zeros((3,)), l2_normed)
+    with pytest.raises(ValueError, match="kda_ogate: .* do not fit"):
+        sp.kda_ogate(y, z[:, :8], scale[:128], 1e-5, head_norm_then_gate)
+    with pytest.raises(ValueError, match="kda_ogate: .* do not fit"):
+        sp.kda_ogate(y, z, scale[:96], 1e-5, head_norm_then_gate)
+    # on the chip channels come in whole 128-lane tiles, and so do heads
+    with pytest.raises(ValueError, match="kda_qkg: a head's: 16 channels "
+                                         "are no multiple of 128 lanes"):
+        sp._refuse_lanes("kda_qkg: a head's", 16, interpret=False)
     with pytest.raises(ValueError, match="no multiple of 128 lanes"):
         sp._refuse_lanes("conv_silu", 96, interpret=False)
     sp._refuse_lanes("conv_silu", 96, interpret=True)
     sp._refuse_lanes("conv_silu", 6144, interpret=False)
+
+
+def test_on_the_chip_a_head_is_whole_lane_tiles(monkeypatch):
+    """Heads of 16 run in the interpreter (the tiny model's); where the
+    kernels would be compiled they are refused by name, before any
+    kernel is built."""
+    monkeypatch.setattr(sp, "_interpret", lambda: False)
+    (qkv, f, dt_bias, a_log), _ = qkg_inputs(1, 1, 16, 4, 16)
+    with pytest.raises(ValueError, match="kda_qkg: a head's: 16 channels "
+                                         "are no multiple of 128 lanes"):
+        sp.kda_qkg(qkv, f, dt_bias, a_log, l2_normed)
+    with pytest.raises(ValueError, match="kda_ogate: a head's: 16 channels "
+                                         "are no multiple of 128 lanes"):
+        sp.kda_ogate(f, f, jnp.ones((16,)), 1e-5, head_norm_then_gate)
 
 
 def test_blocks_are_chosen_from_the_shape():
@@ -346,9 +576,53 @@ def test_blocks_are_chosen_from_the_shape():
     assert sp._lane_block(4096, 4096) == 4096
     assert sp._row_block(8192, 4096) == 64
     assert sp._lane_block(1024, 128) == 512
+    # kimi's: [4, 8192, 4096] in 32 heads of 128, four heads a block
+    assert sp._lane_block(4096, 128) == 512
     # no multiple of a lane tile: the whole width (off the TPU)
     assert sp._lane_block(64) == 64 and sp._lane_block(64, 32) == 64
     # a short sequence whole; a divisor where one is near; else ragged
     assert sp._row_block(24, 512) == 24
     assert sp._row_block(8960, 512) == 448
     assert sp._row_block(1000, 512) == 512
+
+
+# -- the delta rule's two stages at the cell's widths, for a described v5e ----
+
+
+def test_the_kda_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
+    """[1, 8192] of 32 heads of 128 at the blocks the shape chooses:
+    Mosaic takes the seams' jnp code and its ``jax.vjp`` on a head's
+    lane tile, and the blocks fit the kernels' VMEM (the chip's compiler
+    alone: nothing runs)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    b, s, h, d = 1, 8192, 32, 128
+    w, bf16 = h * d, jnp.bfloat16
+    blocks = sp._row_block(s, sp._lane_block(w, d)), sp._lane_block(w, d)
+    assert blocks == (512, 512)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(qkv, f, dt_bias, a_chan, cots, o, gate, scale, dy):
+        qkg, pull = jax.vjp(lambda *a: sp._qkg(
+            *a, d, l2_normed, blocks, False), qkv, f, dt_bias, a_chan)
+        y, pull_y = jax.vjp(lambda *a: sp._ogate(
+            *a, d, 1e-5, head_norm_then_gate, blocks, False), o, gate, scale)
+        return qkg, pull(cots), y, pull_y(dy)
+
+    wide = sd((b, s, w), bf16)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(both).lower(
+            sd((b, s, 3 * w), bf16), wide, sd((w,), F32), sd((w,), F32),
+            (wide, wide, wide, sd((b, s, w), F32)), wide, wide,
+            sd((d,), F32), wide).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    for kernel in ("kda_qkg_fwd", "kda_qkg_bwd", "kda_ogate_fwd",
+                   "kda_ogate_bwd"):
+        assert kernel in text, kernel
+

@@ -19,14 +19,25 @@ projection ``d -> 3HD`` and ONE causal depthwise convolution of
 = q̃ / ‖q̃‖₂ · D^{-1/2}``, ``k = k̃ / ‖k̃‖₂``; the log-decay of every key
 channel ``g = −exp(A_log_h) · softplus(n·W_f↓·W_f↑ + dt_bias)`` (``d ->
 kda_rank -> HD``; ``A_log`` a scalar a head, ``dt_bias`` a channel; f32)
-and the step ``β = σ(n·W_β)`` (a scalar a head; f32);
+— the three in ONE kernel (``ops/ssm_pointwise.py::kda_qkg``: ``q̃`` and
+``k̃`` read as thirds of the convolution's one array, the decay's
+projection in bf16, ``q`` and ``k`` written in bf16 and ``g`` in f32,
+``[B, S, H·D]`` as the scan's kernels read them) — and the step ``β =
+σ(n·W_β)`` (a scalar a head; f32; XLA's: ``[B, S, H]``);
 
     S_t = (I − β_t k_t k_tᵀ) Diag(exp g_t) S_{t-1} + β_t k_t v_tᵀ,
     o_t = S_tᵀ q_t                       (``ops/kda.py::kda_scan``)
 
 then ``y = W_o·[RMSNorm_head(o) ⊙ σ(n·W_g↓·W_g↑)]``: the norm over a
 head's ``D`` channels with one learned ``D``-wide weight, the gate AFTER
-it and a sigmoid (Nemotron-H's ``gated_norm`` gates first, with silu).
+it and a sigmoid (Nemotron-H's ``gated_norm`` gates first, with silu),
+ONE kernel too (``ops/ssm_pointwise.py::kda_ogate``). What a head's
+normalisation and its gated norm ARE stays here: :func:`_l2_normed` and
+:func:`_gated_head_norm` are jnp code over the last axis, and the two
+kernels call them on the ``[rows, D]`` f32 blocks they load (``q``'s
+before ``k``'s, head by head) and take their ``jax.vjp`` in the backward
+kernels, so whatever stands in those names' place
+(``benchmark/tests/kimi_faults.py``) is what the step computes.
 
 MLA mixer: ``models/joyai.py::_mla_sublayer`` itself, called with this
 config — ``q_lora_rank`` 0 (no q latent: one ``q_proj`` to ``H × (nope +
@@ -45,7 +56,10 @@ holds ``first_expert .. first_expert + n_experts_held``
 (``ops/moe.py::moe_mlp``), and one shared expert.
 
 Conventions of ``models/joyai.py``: float32 parameters, bf16 compute,
-float32 norms / router / decays / step sizes, an explicit parameter tree
+float32 norms / router / decays / step sizes (the delta-rule mixer's
+projections stay bf16 up to its kernels, which upcast on load, compute
+in f32 and round ``q``, ``k`` and ``y`` once; of the stream's size ``[B,
+S, H·D]`` only ``g`` and its cotangent are f32 in HBM), an explicit parameter tree
 with stable paths ``layers_<i>/{ln_1, ln_2}``, ``layers_<i>/{kda|attn}``,
 ``layers_<i>/{mlp|moe}``, per-layer ``jax.checkpoint`` behind ``remat``,
 and the step programs of ``transformer.make_train_step`` /
@@ -53,9 +67,11 @@ and the step programs of ``transformer.make_train_step`` /
 
 Device-trace scopes: ``embed``; both mixers under ``attn``, told apart
 inside — ``kda_in`` (norm, the five projections), ``kda_conv`` (the
-convolution's kernels, the l2 norms, the decay's softplus and the step's
-sigmoid), ``kda_core`` (the scan's kernels ``kda_fwd`` / ``kda_bwd``),
-``kda_gate`` (the head norm and the gate), ``kda_out``; JoyAI's ``mla_q``,
+convolution's kernels ``ssm_conv_fwd`` / ``ssm_conv_bwd``, the l2 norms'
+and the decay's ``kda_qkg_fwd`` / ``kda_qkg_bwd``, the slice of ``v``
+and the step's sigmoid), ``kda_core`` (the scan's kernels ``kda_fwd`` /
+``kda_bwd``), ``kda_gate`` (the head norm's and the gate's
+``kda_ogate_fwd`` / ``kda_ogate_bwd``), ``kda_out``; JoyAI's ``mla_q``,
 ``mla_kv``, ``mla_core``, ``mla_out``; ``mlp`` with the dense SwiGLU
 straight under it and JoyAI's ``moe_router``, ``moe_shared``,
 ``moe_dispatch``, ``moe_experts``, ``moe_combine``; ``lm_head_xent``.
@@ -82,7 +98,7 @@ from torchft_tpu.models.transformer import (
     ce_from_hidden,
 )
 from torchft_tpu.ops.kda import kda_scan
-from torchft_tpu.ops.ssm_pointwise import conv_silu
+from torchft_tpu.ops.ssm_pointwise import conv_silu, kda_ogate, kda_qkg
 
 __all__ = ["KimiLinearConfig", "KIMI_LINEAR_CONFIGS", "BALANCE_BIAS",
            "is_balance_bias", "init_params", "forward_hidden", "loss_terms",
@@ -239,14 +255,18 @@ def _kda_scan(q, k, v, g, beta):
 
 def _gated_head_norm(o, scale, gate, eps: float):
     """``RMSNorm_head(o) ⊙ σ(gate)``: the norm FIRST, over a head's
-    channels (``[B, S, H, D]``, one ``D``-wide weight), then the sigmoid
-    gate; in f32, rounded once."""
+    channels (the last axis; one ``D``-wide weight), then the sigmoid
+    gate; in f32, rounded once. The body of ``kda_ogate``'s kernels (a
+    seam: see :func:`_kda_scan`)."""
     f32 = jnp.float32
     return (rms_norm(o.astype(f32), scale, eps)
             * jax.nn.sigmoid(gate.astype(f32))).astype(o.dtype)
 
 
 def _l2_normed(x):
+    """``x / ‖x‖₂`` over the last axis, a head's channels, in f32. The
+    body of ``kda_qkg``'s kernels, called for ``q̃``, then for ``k̃`` (a
+    seam: see :func:`_kda_scan`)."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(
         jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
@@ -268,20 +288,14 @@ def _kda_sublayer(cfg: KimiLinearConfig, layer: Dict, x):
     with jax.named_scope("kda_conv"):
         taps = m["conv"]["kernel"]
         qkv = conv_silu(qkv, taps, jnp.zeros(taps.shape[1:], taps.dtype))
-        q, k, v = (qkv[..., i * H * D:(i + 1) * H * D].reshape(B, S, H, D)
-                   for i in range(3))
-        q = (_l2_normed(q) * D ** -0.5).astype(dt)
-        k = _l2_normed(k).astype(dt)
-        g = -jnp.exp(m["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-            f.astype(f32).reshape(B, S, H, D)
-            + m["dt_bias"].astype(f32).reshape(H, D))
+        q, k, v, g = (a.reshape(B, S, H, D) for a in kda_qkg(
+            qkv, f, m["dt_bias"], m["A_log"], _l2_normed))
         beta = jax.nn.sigmoid(b.astype(f32))
     with jax.named_scope("kda_core"):
         o = _kda_scan(q, k, v, g, beta)                  # [B, S, H, D]
     with jax.named_scope("kda_gate"):
-        y = _gated_head_norm(o, m["o_norm"]["scale"],
-                             gate.reshape(B, S, H, D), cfg.rms_eps
-                             ).reshape(B, S, H * D)
+        y = kda_ogate(o.reshape(B, S, H * D), gate, m["o_norm"]["scale"],
+                      cfg.rms_eps, _gated_head_norm)
     with jax.named_scope("kda_out"):
         return x + y @ m["o_proj"]["kernel"].astype(dt)
 

@@ -1,12 +1,14 @@
 """Pallas kernels for pointwise stages of the sequence mixers: the two
 around the Mamba-2 scan (``ops/ssd.py``) — the short causal depthwise
-convolution with its silu, and the gate with its grouped RMSNorm — and
+convolution with its silu, and the gate with its grouped RMSNorm —,
 LFM2's gated short convolution, which is the first's kernel body with
-two multiplicands where that has a bias and a silu. Each is ONE kernel
-forward and ONE backward under its own ``jax.custom_vjp``; each reads its
-operands once, in the dtype they arrive in, and writes its results once.
-The residuals of all three are their inputs alone: the backward kernels
-recompute what they need.
+two multiplicands where that has a bias and a silu, and the two around
+the delta rule (``ops/kda.py``) — the heads' normalisation of ``q`` and
+``k`` with the decay, and the head norm with the gate after it. Each is
+ONE kernel forward and ONE backward under its own ``jax.custom_vjp``;
+each reads its operands once, in the dtype they arrive in, and writes
+its results once. The residuals of all five are their inputs alone: the
+backward kernels recompute what they need.
 
 ``conv_silu(x [B, S, C], taps [K, C], bias [C])``:
 
@@ -44,14 +46,66 @@ channels alone, ``r = rsqrt(mean_W(g²) + eps)`` and ``n = g·r``:
     dg = r·(dn − n·mean_W(dn ⊙ n))       dscale = Σ_t dout ⊙ n
     dy = dg ⊙ silu(z)                     dz = dg ⊙ y ⊙ silu'(z)
 
+``kda_qkg(qkv [B, S, 3·H·D], f [B, S, H·D], dt_bias [H·D], a_log [H],
+normed)`` (``models/kimi_linear.py``): ``qkv`` is ``[q̃ ; k̃ ; v]`` along
+the channels, as the one convolution writes them, and a head is a run of
+``D`` channels. With ``normed`` the caller's normalisation of one head —
+jnp code over the last axis of a ``[rows, D]`` f32 block, there ``x ·
+rsqrt(Σ_D x² + ε)`` —, per head ``h``:
+
+    q = normed(q̃) · D^{-1/2}      k = normed(k̃)
+    g = −exp(a_log_h) · softplus(f + dt_bias)
+
+``q``, ``k`` and ``v`` (the third of ``qkv``, copied through the kernel)
+leave in ``qkv``'s dtype and ``g`` in f32, each ``[B, S, H·D]``: the layout the scan's
+kernels read, so the caller's ``reshape`` to ``[B, S, H, D]`` and
+``ops/kda.py``'s back fold away. Backward (``kda_qkg_bwd``), from ``dq,
+dk, dv`` and ``dg`` (f32): the kernel takes ``jax.vjp(normed, ·)`` on
+the block it loaded — for the l2 norm, with ``r = rsqrt(Σ x² + ε)`` and
+``n = x·r``, ``dx = r·(dn − n·Σ_D(dn ⊙ n))`` — at ``dn = dq · D^{-1/2}``
+and ``dn = dk``, and
+
+    df = dg ⊙ (−exp(a_log_h)) ⊙ σ(f + dt_bias)      d dt_bias = Σ_t df
+    d a_log (a channel; the caller's ``repeat`` sums a head's) = Σ_t dg ⊙ g
+
+``dq̃`` and ``dk̃`` leave as two arrays and XLA lays ``[dq̃ ; dk̃ ; dv]``
+out as the convolution's one cotangent (0.55 ms a call at ``[4, 8192,
+3·4096]``; written a third a grid step as ``gated_conv``'s is, the
+kernel took 7.7 ms against 3.9 of bytes, the next block's 4 MiB of
+operands being fetched during a step that only hands a block over:
+PERF.md, PR 44). ``df`` leaves in ``f``'s dtype; the two sums accumulate
+in f32.
+
+``kda_ogate(o, gate [B, S, H·D], scale [D], eps, gated)``: with
+``gated`` the caller's ``(o, scale, gate, eps) -> y`` of one head — jnp
+code on ``[rows, D]`` f32 blocks and the weight as ``[1, D]``, there
+``RMSNorm(o) ⊙ scale ⊙ σ(gate)``: THE NORM FIRST and a sigmoid after it,
+where ``gated_norm`` gates first, with silu, which is why the two do not
+share a body — ``y = gated(o_h, scale, gate_h, eps)`` per head, in
+``o``'s dtype. Backward (``kda_ogate_bwd``): ``jax.vjp(gated, ·)`` on
+the blocks it loaded, the weight broadcast to a row a position so that
+its cotangent comes a row a position too — for the norm-then-gate, with
+``r = rsqrt(mean_D(o²) + eps)``, ``n = o·r``, ``s = σ(gate)`` and ``dn =
+dy ⊙ scale ⊙ s``:
+
+    do = r·(dn − n·mean_D(dn ⊙ n))         dscale = Σ_{t,h} dy ⊙ n ⊙ s
+    dgate = dy ⊙ n ⊙ scale ⊙ s(1 − s)
+
+``dscale`` accumulates in f32 a channel; the heads' sums are added up
+outside. Handing the head's function in keeps what it IS in the model
+(``benchmark/tests/kimi_faults.py`` puts its faults in those seams'
+place, and a fault there must change what the kernels compute); the
+kernels own the blocks, the dtypes and the sums.
+
 What is which dtype: ``x``, ``y``, ``z`` and the cotangents arrive, and
 the results and ``dx``, ``dy``, ``dz`` leave, in the input dtype (bf16
 in the models); every operand is upcast to f32 as it is loaded and all
 that lies between — the taps' sums, the sigmoid, the group's mean, the
 rsqrt — is f32 and exact (no approximate reciprocal), so the one
 rounding is where the jnp formulation had it: after the silu, after the
-norm's scale. ``dtaps``, ``dbias`` and ``dscale`` accumulate in f32 and
-leave in their parameter's dtype.
+norm's scale, after the l2 norm and ``q``'s scale, after the gate.
+``dtaps``, ``dbias``, ``dscale``, ``d dt_bias`` and ``d a_log`` accumulate
+in f32 and leave in their parameter's dtype.
 
 The halo. A grid step is one (batch row, sequence block, channel block
 of whole lane tiles); the convolution at a block's first ``K − 1``
@@ -79,13 +133,13 @@ until the step's end.
 
 Blocks are chosen from the shape (:func:`_lane_block`,
 :func:`_row_block`): a channel block of at most ``_LANE_BLOCK`` lanes
-(whole norm groups for the gate), and as many rows as keep a block near
+(whole norm groups for the gate, whole heads around the delta rule), and as many rows as keep a block near
 ``_BLOCK_ELEMS`` elements, a divisor of the sequence where there is
 one. A sequence that is no multiple of the block ends in a partial
 block whose rows past the end are masked in the backward kernels (what
 they would add to the sums is garbage) and thrown away by the forward.
-On the TPU a channel count, or a norm group, that is no multiple of 128
-lanes is refused with a message; off the TPU the same kernels run in
+On the TPU a channel count, a norm group or a head that is no multiple
+of 128 lanes is refused with a message; off the TPU the same kernels run in
 Pallas's interpreter at any width (the CPU tests), chosen from the
 backend alone.
 """
@@ -100,7 +154,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["conv_silu", "gated_conv", "gated_norm"]
+__all__ = ["conv_silu", "gated_conv", "gated_norm", "kda_qkg", "kda_ogate"]
 
 _LANES = 128
 _HALO = 8                    # rows kept beside a block: the f32 sublane tile
@@ -731,3 +785,339 @@ def gated_norm(y, z, scale, groups: int, eps: float):
     bc = _lane_block(width, group)
     return _gate(y, z, scale, group, float(eps),
                  (_row_block(y.shape[1], bc), bc), interpret)
+
+
+# ------------------------------- around the delta rule: norms and decay
+def _softplus_and_slope(v):
+    """``softplus(v)`` and ``softplus'(v) = σ(v)``."""
+    return (jnp.maximum(v, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(v))),
+            jax.nn.sigmoid(v))
+
+
+def _qkg_fwd_kernel(q_ref, k_ref, v_ref, f_ref, b_ref, a_ref, qo_ref, ko_ref,
+                    vo_ref, g_ref, *, chunk: int, head: int, normed):
+    """One (batch row, sequence block, channel block of whole heads);
+    ``v``'s block goes through as it is (its bytes ride under the
+    norms' lane reductions, which bound the step)."""
+    bs, bc = f_ref.shape[1:]
+    bias, rate = _f32(b_ref[...]), -jnp.exp(_f32(a_ref[...]))
+    vo_ref[0] = v_ref[0]
+
+    def rows(i, carry):
+        at = pl.ds(_row0(i, chunk), chunk)
+        for h in range(bc // head):
+            lanes = pl.ds(h * head, head)
+            qo_ref[0, at, lanes] = (
+                normed(_f32(q_ref[0, at, lanes])) * head ** -0.5
+            ).astype(qo_ref.dtype)
+            ko_ref[0, at, lanes] = normed(
+                _f32(k_ref[0, at, lanes])).astype(ko_ref.dtype)
+        g_ref[0, at, :] = rate * _softplus_and_slope(
+            _f32(f_ref[0, at, :]) + bias)[0]
+        return carry
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _qkg_bwd_kernel(q_ref, k_ref, f_ref, b_ref, a_ref, dq_ref, dk_ref,
+                    dg_ref, dqo_ref, dko_ref, df_ref, db_ref, da_ref, *,
+                    chunk: int, head: int, seq_len: int, normed):
+    """One (channel block, batch row, sequence block); ``db_ref`` and
+    ``da_ref`` stay resident over a channel block's batch rows and
+    sequence blocks."""
+    bi, si = pl.program_id(1), pl.program_id(2)
+    bs, bc = f_ref.shape[1:]
+    ragged = seq_len % bs != 0
+    f32 = jnp.float32
+
+    @pl.when((bi == 0) & (si == 0))
+    def _zero():
+        db_ref[...] = jnp.zeros(db_ref.shape, f32)
+        da_ref[...] = jnp.zeros(da_ref.shape, f32)
+
+    bias, rate = _f32(b_ref[...]), -jnp.exp(_f32(a_ref[...]))
+
+    def rows(i, sums):
+        r0 = _row0(i, chunk)
+        at = pl.ds(r0, chunk)
+        # rows past the end: what they give dq̃ and dk̃ is thrown away
+        for h in range(bc // head):
+            lanes = pl.ds(h * head, head)
+            dqo_ref[0, at, lanes] = jax.vjp(
+                normed, _f32(q_ref[0, at, lanes]))[1](
+                _f32(dq_ref[0, at, lanes]) * head ** -0.5
+            )[0].astype(dqo_ref.dtype)
+            dko_ref[0, at, lanes] = jax.vjp(
+                normed, _f32(k_ref[0, at, lanes]))[1](
+                _f32(dk_ref[0, at, lanes]))[0].astype(dko_ref.dtype)
+        fv, dgv = _f32(f_ref[0, at, :]), _f32(dg_ref[0, at, :])
+        if ragged:
+            gone = _past_the_end(si * bs + r0, chunk, seq_len)
+            fv, dgv = (jnp.where(gone, 0.0, v) for v in (fv, dgv))
+        soft, slope = _softplus_and_slope(fv + bias)
+        dfv = dgv * rate * slope
+        df_ref[0, at, :] = dfv.astype(df_ref.dtype)
+        return sums[0] + _fold(dfv), sums[1] + _fold(dgv * soft)
+
+    sums = _for_chunks(
+        bs // chunk, rows,
+        tuple(jnp.zeros((_fold_rows(chunk), bc), f32) for _ in range(2)))
+    db_ref[...] += _row_sum(sums[0])
+    da_ref[...] += rate * _row_sum(sums[1])
+
+
+# The four functions that build these kernels are jitted: a model calls
+# each once a layer, again under remat and for every program it makes, and
+# tracing a kernel's body (the seams' jnp code and its ``jax.vjp``, a head
+# at a time) and lowering it for Mosaic is Python that every run pays,
+# compile cache or not. Under an inner jit a process traces each body
+# once a shape (PERF.md, PR 44: kimi's step program traced and lowered in
+# 14.3 s without and 11.3 s with, the parent's in 10.0 s, on the chip's
+# host) — and reads ``_GATE_CHUNK`` once a shape: whoever sets another
+# clears jax's caches (``scripts/ssm_pointwise_micro.py``).
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _qkg_forward(qkv, f, dt_bias, a_chan, head: int, normed,
+                 blocks: Tuple[int, int], interpret: bool):
+    b, s, width = f.shape
+    bs, bc = blocks
+    nc = width // bc
+    rows = pl.BlockSpec((1, bs, bc), lambda b, s, c: (b, s, c))
+    lanes = pl.BlockSpec((1, bc), lambda b, s, c: (0, c))
+
+    def third(n):
+        return pl.BlockSpec((1, bs, bc), lambda b, s, c: (b, s, n * nc + c))
+
+    return tuple(pl.pallas_call(
+        functools.partial(_qkg_fwd_kernel, chunk=_row_chunk(bs, _GATE_CHUNK),
+                          head=head, normed=normed),
+        grid=(b, pl.cdiv(s, bs), nc),
+        in_specs=[third(0), third(1), third(2), rows, lanes, lanes],
+        out_specs=[rows, rows, rows, rows],
+        out_shape=[jax.ShapeDtypeStruct(f.shape, qkv.dtype)] * 3
+        + [jax.ShapeDtypeStruct(f.shape, jnp.float32)],
+        interpret=interpret, name="kda_qkg_fwd",
+    )(qkv, qkv, qkv, f, dt_bias.reshape(1, width), a_chan.reshape(1, width)))
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _qkg_backward(qkv, f, dt_bias, a_chan, dq, dk, dv, dg, head: int, normed,
+                  blocks: Tuple[int, int], interpret: bool):
+    b, s, width = f.shape
+    bs, bc = blocks
+    nc = width // bc
+    rows = pl.BlockSpec((1, bs, bc), lambda c, b, s: (b, s, c))
+    lanes = pl.BlockSpec((1, bc), lambda c, b, s: (0, c))
+
+    def third(n):
+        return pl.BlockSpec((1, bs, bc), lambda c, b, s: (b, s, n * nc + c))
+
+    f32 = jnp.float32
+    dq, dk, df, d_bias, d_a = pl.pallas_call(
+        functools.partial(_qkg_bwd_kernel, chunk=_row_chunk(bs, _GATE_CHUNK),
+                          head=head, seq_len=s, normed=normed),
+        grid=(nc, b, pl.cdiv(s, bs)),
+        in_specs=[third(0), third(1), rows, lanes, lanes, rows, rows, rows],
+        out_specs=[rows, rows, rows, lanes, lanes],
+        out_shape=[jax.ShapeDtypeStruct(f.shape, qkv.dtype),
+                   jax.ShapeDtypeStruct(f.shape, qkv.dtype),
+                   jax.ShapeDtypeStruct(f.shape, f.dtype),
+                   jax.ShapeDtypeStruct((1, width), f32),
+                   jax.ShapeDtypeStruct((1, width), f32)],
+        interpret=interpret, name="kda_qkg_bwd",
+    )(qkv, qkv, f, dt_bias.reshape(1, width), a_chan.reshape(1, width),
+      dq, dk, dg)
+    # the convolution's cotangent: its v third is the scan's dv as it is
+    return (jnp.concatenate([dq, dk, dv], axis=-1), df,
+            d_bias.reshape(width).astype(dt_bias.dtype),
+            d_a.reshape(width).astype(a_chan.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _qkg(qkv, f, dt_bias, a_chan, head, normed, blocks, interpret):
+    return _qkg_forward(qkv, f, dt_bias, a_chan, head, normed, blocks,
+                        interpret)
+
+
+def _qkg_fwd_rule(qkv, f, dt_bias, a_chan, head, normed, blocks, interpret):
+    return (_qkg_forward(qkv, f, dt_bias, a_chan, head, normed, blocks,
+                         interpret), (qkv, f, dt_bias, a_chan))
+
+
+def _qkg_bwd_rule(head, normed, blocks, interpret, residuals, cotangents):
+    return _qkg_backward(*residuals, *cotangents, head, normed, blocks,
+                         interpret)
+
+
+_qkg.defvjp(_qkg_fwd_rule, _qkg_bwd_rule)
+
+
+def kda_qkg(qkv, f, dt_bias, a_log, normed):
+    """What stands between the delta-rule mixer's convolution and its
+    scan (the module's docstring): ``qkv [B, S, 3·H·D]`` (``[q̃ ; k̃ ; v]``
+    along the channels, as the one convolution writes them), ``f [B, S,
+    H·D]``, ``dt_bias [H·D]``, ``a_log [H]``, and ``normed`` the
+    caller's normalisation of a head (jnp code over the last axis of a
+    ``[rows, D]`` f32 block) -> ``q = normed(q̃)·D^{-1/2}``, ``k =
+    normed(k̃)`` and ``v`` in ``qkv``'s dtype and ``g = −exp(a_log_h) ·
+    softplus(f + dt_bias)`` in f32, each ``[B, S, H·D]``; differentiable
+    in the four arrays."""
+    width, heads = f.shape[-1], a_log.shape[0]
+    if (qkv.ndim != 3 or qkv.shape != f.shape[:2] + (3 * width,)
+            or dt_bias.shape != (width,) or a_log.ndim != 1
+            or width % heads):
+        raise ValueError(
+            f"kda_qkg: qkv{tuple(qkv.shape)} f{tuple(f.shape)} "
+            f"dt_bias{tuple(dt_bias.shape)} a_log{tuple(a_log.shape)} "
+            f"do not fit")
+    interpret = _interpret()
+    head = width // heads
+    _refuse_lanes("kda_qkg: a head's", head, interpret)
+    bc = _lane_block(width, head)
+    return _qkg(qkv, f, dt_bias, jnp.repeat(a_log, head), head, normed,
+                (_row_block(f.shape[1], bc), bc), interpret)
+
+
+# ------------------------------------- behind the delta rule: norm, then gate
+def _ogate_fwd_kernel(o_ref, z_ref, s_ref, y_ref, *, chunk: int, head: int,
+                      eps: float, gated):
+    """One (batch row, sequence block, channel block of whole heads)."""
+    bs, bc = o_ref.shape[1:]
+    scale = _f32(s_ref[...])
+
+    def rows(i, carry):
+        at = pl.ds(_row0(i, chunk), chunk)
+        for h in range(bc // head):
+            lanes = pl.ds(h * head, head)
+            y_ref[0, at, lanes] = gated(
+                _f32(o_ref[0, at, lanes]), scale, _f32(z_ref[0, at, lanes]),
+                eps).astype(y_ref.dtype)
+        return carry
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _ogate_bwd_kernel(o_ref, z_ref, s_ref, dy_ref, do_ref, dz_ref, ds_ref, *,
+                      chunk: int, head: int, eps: float, seq_len: int, gated):
+    """One (channel block, batch row, sequence block); ``ds_ref`` (a sum a
+    channel: the heads' are added up outside) stays resident over a
+    channel block's batch rows and sequence blocks."""
+    bi, si = pl.program_id(1), pl.program_id(2)
+    bs, bc = o_ref.shape[1:]
+    ragged = seq_len % bs != 0
+    f32 = jnp.float32
+    heads = bc // head
+
+    @pl.when((bi == 0) & (si == 0))
+    def _zero():
+        ds_ref[...] = jnp.zeros(ds_ref.shape, f32)
+
+    scale = _f32(s_ref[...])
+
+    def rows(i, sums):
+        r0 = _row0(i, chunk)
+        at = pl.ds(r0, chunk)
+        gone = _past_the_end(si * bs + r0, chunk, seq_len) if ragged else None
+        out = []
+        for h in range(heads):
+            lanes = pl.ds(h * head, head)
+            ov, zv, dyv = (_f32(ref[0, at, lanes])
+                           for ref in (o_ref, z_ref, dy_ref))
+            if ragged:
+                ov, zv, dyv = (jnp.where(gone, 0.0, v) for v in (ov, zv, dyv))
+            # the weight a row, so that its cotangent comes a row too
+            do, ds, dz = jax.vjp(
+                lambda o, s, z: gated(o, s, z, eps),
+                ov, jnp.broadcast_to(scale, ov.shape), zv)[1](dyv)
+            do_ref[0, at, lanes] = do.astype(do_ref.dtype)
+            dz_ref[0, at, lanes] = dz.astype(dz_ref.dtype)
+            out.append(sums[h] + _fold(ds))
+        return tuple(out)
+
+    sums = _for_chunks(
+        bs // chunk, rows,
+        tuple(jnp.zeros((_fold_rows(chunk), head), f32)
+              for _ in range(heads)))
+    for h in range(heads):
+        ds_ref[:, h * head:(h + 1) * head] += _row_sum(sums[h])
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _ogate_forward(o, gate, scale, head: int, eps: float, gated,
+                   blocks: Tuple[int, int], interpret: bool):
+    b, s, width = o.shape
+    bs, bc = blocks
+    rows = pl.BlockSpec((1, bs, bc), lambda b, s, c: (b, s, c))
+    return pl.pallas_call(
+        functools.partial(_ogate_fwd_kernel,
+                          chunk=_row_chunk(bs, _GATE_CHUNK), head=head,
+                          eps=eps, gated=gated),
+        grid=(b, pl.cdiv(s, bs), width // bc),
+        in_specs=[rows, rows, pl.BlockSpec((1, head), lambda b, s, c: (0, 0))],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(o.shape, o.dtype),
+        interpret=interpret, name="kda_ogate_fwd",
+    )(o, gate, scale.reshape(1, head))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _ogate_backward(o, gate, scale, dy, head: int, eps: float, gated,
+                    blocks: Tuple[int, int], interpret: bool):
+    b, s, width = o.shape
+    bs, bc = blocks
+    rows = pl.BlockSpec((1, bs, bc), lambda c, b, s: (b, s, c))
+    do, dgate, dscale = pl.pallas_call(
+        functools.partial(_ogate_bwd_kernel,
+                          chunk=_row_chunk(bs, _GATE_CHUNK), head=head,
+                          eps=eps, seq_len=s, gated=gated),
+        grid=(width // bc, b, pl.cdiv(s, bs)),
+        in_specs=[rows, rows, pl.BlockSpec((1, head), lambda c, b, s: (0, 0)),
+                  rows],
+        out_specs=[rows, rows, pl.BlockSpec((1, bc), lambda c, b, s: (0, c))],
+        out_shape=[
+            jax.ShapeDtypeStruct(o.shape, o.dtype),
+            jax.ShapeDtypeStruct(gate.shape, gate.dtype),
+            jax.ShapeDtypeStruct((1, width), jnp.float32),
+        ],
+        interpret=interpret, name="kda_ogate_bwd",
+    )(o, gate, scale.reshape(1, head), dy)
+    return do, dgate, jnp.sum(
+        dscale.reshape(width // head, head), axis=0).astype(scale.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _ogate(o, gate, scale, head, eps, gated, blocks, interpret):
+    return _ogate_forward(o, gate, scale, head, eps, gated, blocks, interpret)
+
+
+def _ogate_fwd_rule(o, gate, scale, head, eps, gated, blocks, interpret):
+    return (_ogate_forward(o, gate, scale, head, eps, gated, blocks,
+                           interpret), (o, gate, scale))
+
+
+def _ogate_bwd_rule(head, eps, gated, blocks, interpret, residuals, dy):
+    return _ogate_backward(*residuals, dy, head, eps, gated, blocks,
+                           interpret)
+
+
+_ogate.defvjp(_ogate_fwd_rule, _ogate_bwd_rule)
+
+
+def kda_ogate(o, gate, scale, eps: float, gated):
+    """What stands between the delta-rule mixer's scan and its output
+    projection (the module's docstring): ``o, gate [B, S, H·D]``, ``scale
+    [D]`` (the one weight every head shares) and ``gated`` the caller's
+    ``(o, scale, gate, eps) -> y`` of a head (jnp code over the last
+    axis of ``[rows, D]`` f32 blocks, ``scale`` as ``[1, D]``) -> ``y
+    [B, S, H·D]`` in ``o``'s dtype, differentiable in ``o``, ``gate``
+    and ``scale``."""
+    width, head = o.shape[-1], scale.shape[-1]
+    if (o.ndim != 3 or o.shape != gate.shape or scale.ndim != 1
+            or width % head):
+        raise ValueError(
+            f"kda_ogate: o{tuple(o.shape)} gate{tuple(gate.shape)} "
+            f"scale{tuple(scale.shape)} do not fit")
+    interpret = _interpret()
+    _refuse_lanes("kda_ogate: a head's", head, interpret)
+    bc = _lane_block(width, head)
+    return _ogate(o, gate, scale, head, float(eps), gated,
+                  (_row_block(o.shape[1], bc), bc), interpret)
