@@ -1,6 +1,8 @@
 """Flash-attention kernel tests (interpret mode on CPU; the same kernel
 compiles for TPU)."""
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -436,27 +438,178 @@ def test_tile_rule_takes_both_widths() -> None:
 # (a4592dd) traced it: forward, dq and dkv, the block shapes, the grids
 # and every instruction of the kernels. A change that moves these moves
 # what the c111m / c1p3b / olmoe cells run; regenerate on purpose only.
+# The streamed causal call was regenerated on purpose in PR 45 (its grid
+# enumerates the live tiles only; ccc05cff... before); the streamed call
+# WITHOUT the mask is pinned as PR 44's tree (2717011) traced it.
 _EQUAL_WIDTH_JAXPR = {
-    "resident":
+    ("resident", True):
         "f8b02ef3012e1b54bd009de82187ab7387fa71a6f08f9896f031d1589227e58e",
-    "streamed":
-        "ccc05cff9386a79e1393525dc68c0841d6ea2621f793ab21f3ddc6d76aa81eaf",
+    ("streamed", True):
+        "0af334852f724114d76129a1d1929eafe5fdd5c3828193cf1e9417963a4f920e",
+    ("streamed", False):
+        "049a6f742164814830be2c32337c590c5abf2f3c8b6e70ba343890f9638dc0a2",
 }
 
 
-@pytest.mark.parametrize("regime", sorted(_REGIMES))
-def test_equal_widths_lower_as_before(regime) -> None:
+@pytest.mark.parametrize(
+    "regime,causal", sorted(_EQUAL_WIDTH_JAXPR),
+    ids=lambda value: value if isinstance(value, str)
+    else ("causal" if value else "unmasked"),
+)
+def test_equal_widths_lower_as_before(regime, causal) -> None:
     import hashlib
     import re
 
     q = jnp.zeros((2, 512, 4, 64), jnp.bfloat16)
+    # the causal calls leave the argument to its default, as the pinned
+    # commit's test did
+    mask = {} if causal else {"causal": False}
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(
             q, k, v, interpret=True, _resident_kv_bytes=_REGIMES[regime],
+            **mask,
         ).astype(jnp.float32))
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
     text = re.sub(r"/[^ ]*?\.py:\d+", "", text)
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        _EQUAL_WIDTH_JAXPR[regime]
+        _EQUAL_WIDTH_JAXPR[regime, causal]
+
+
+# ------------------------------------------------------------ PR 45 contracts
+# A streamed causal grid enumerates the live tiles only: (a) the tables,
+# (b) the kernels over them against the kernels that take no grid step a
+# tile at all.
+
+# every (seq_len, block_q, block_k) this file runs a kernel or a sweep at,
+# and the two the cells' 8k calls could take
+_SWEPT_SHAPES = [
+    (128, 64, 64), (256, 64, 64), (384, 128, 128), (512, 128, 128),
+    (512, 256, 128), (512, 512, 512), (640, 128, 128), (768, 128, 384),
+    (768, 256, 384), (768, 384, 256), (1024, 128, 128), (1024, 128, 256),
+    (1024, 128, 512), (1024, 256, 128), (1024, 512, 256), (1024, 512, 512),
+    (2048, 512, 512), (8192, 512, 512), (8192, 512, 1024),
+]
+
+
+@pytest.mark.parametrize("rows", [True, False], ids=["rows", "columns"])
+@pytest.mark.parametrize("seq_len,block_q,block_k", _SWEPT_SHAPES)
+def test_live_tile_tables(seq_len, block_q, block_k, rows) -> None:
+    from torchft_tpu.ops.flash import (
+        _grid_steps, _live_tiles, _sweep_ends, _tile_live,
+    )
+
+    q_of, k_of = _live_tiles(seq_len, block_q, block_k, rows)
+    assert q_of.dtype == k_of.dtype == np.int32
+    listed = list(zip(q_of.tolist(), k_of.tolist()))
+    nq, nk = seq_len // block_q, seq_len // block_k
+    # every listed tile is live, none is listed twice, none is missing
+    live = {(qi, ki) for qi in range(nq) for ki in range(nk)
+            if _tile_live(qi, ki, block_q, block_k)}
+    assert len(listed) == len(set(listed)) and set(listed) == live
+    assert _grid_steps(seq_len, block_q, block_k) == (len(live), nq * nk)
+    # row-major (forward, dq) or column-major (dkv), swept index ascending
+    assert listed == sorted(listed, key=lambda t: t if rows else t[::-1])
+    # the accumulators are cleared at a row's (column's) first listed tile
+    # and written out at its last: _causal_sweep's ends (the swept index
+    # ascends, so it meets either end once a row)
+    own_at, swept_at = (0, 1) if rows else (1, 0)
+    for own, tiles in itertools.groupby(listed, key=lambda t: t[own_at]):
+        swept = [t[swept_at] for t in tiles]
+        first, last = _sweep_ends(own, block_q, block_k, seq_len, rows)
+        assert (int(first), int(last)) == (swept[0], swept[-1])
+
+
+def test_grid_steps_at_the_cells_call() -> None:
+    from torchft_tpu.ops.flash import _choose_blocks, _grid_steps
+
+    # joyai's and nemo3's 8k calls: 56 of 128 grid steps a head computed
+    # nothing until PR 45
+    assert _grid_steps(8192, *_choose_blocks(8192, 192, 2, v_dim=128)) == \
+        (72, 128)
+    assert _grid_steps(8192, *_choose_blocks(8192, 128, 2)) == (72, 128)
+    assert _grid_steps(8192, 512, 512) == (136, 256)
+    assert _grid_steps(2048, 512, 512) == (10, 16)
+
+
+def _dkv_in_table_order(q, k, v, do, lse, delta, scale, block_q, block_k):
+    """dk and dv as the streamed causal dkv kernel sums them — the kernel's
+    own tile body over :func:`_live_tiles` in its order, a column's q
+    blocks ascending — with no grid, table lookup or scratch involved."""
+    from torchft_tpu.ops.flash import (
+        _dkv_tile, _f32, _live_tiles, _tile_full,
+    )
+
+    dk = np.zeros(k.shape, np.float32)
+    dv = np.zeros(v.shape, np.float32)
+    for head in range(q.shape[0]):
+        for qi, ki in zip(*_live_tiles(q.shape[1], block_q, block_k, False)):
+            rows = slice(qi * block_q, (qi + 1) * block_q)
+            cols = slice(ki * block_k, (ki + 1) * block_k)
+            tile_dk, tile_dv = _dkv_tile(
+                _f32(q[head, rows]) * scale, _f32(k[head, cols]),
+                _f32(v[head, cols]), _f32(do[head, rows]),
+                lse[head, None, rows], delta[head, None, rows], qi, ki,
+                masked=not _tile_full(qi, ki, block_q, block_k),
+            )
+            dk[head, cols] = np.asarray(jnp.asarray(dk[head, cols]) + tile_dk)
+            dv[head, cols] = np.asarray(jnp.asarray(dv[head, cols]) + tile_dv)
+    return jnp.asarray(dk).astype(k.dtype), jnp.asarray(dv).astype(v.dtype)
+
+
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize(
+    "seq_len,block_q,block_k,widths",
+    [(1024, 128, 256, (32, 32)), (1024, 256, 256, (32, 32)),
+     (1024, 256, 128, (32, 32)),
+     # latent attention's 192 / 128 a quarter the size, at the cell's ratio
+     (1024, 128, 256, (48, 32)), (768, 128, 128, (48, 32)),
+     # neither edge divides the other
+     (768, 384, 256, (32, 32)), (768, 256, 384, (32, 32))],
+)
+def test_causal_kernels_agree_across_regimes_bit_for_bit(
+        seq_len, block_q, block_k, widths, dtype, regime) -> None:
+    # A streamed causal grid is a table of live tiles, and an accumulator
+    # meets them in the order the resident kernels' loops do, so out, lse
+    # and dq are the RESIDENT kernels' bit for bit. dk and dv are not: the
+    # resident column sweep adds its full tiles before its diagonal ones
+    # and the streamed one runs a column top to bottom. Both regimes' dk
+    # and dv are therefore held, bit for bit where the order is theirs, to
+    # the kernel's own tile body summed in the table's order.
+    from torchft_tpu.ops.flash import _flash_backward_core, _flash_forward
+
+    dqk, dv = widths
+    q, k = (_rand((2, seq_len, dqk), i + 50, dtype) for i in range(2))
+    v, do = (_rand((2, seq_len, dv), i + 52, dtype) for i in range(2))
+    scale = 1.0 / dqk ** 0.5
+    common = (True, scale, block_q, block_k, True)
+
+    def kernels(threshold):
+        out, lse = _flash_forward(q, k, v, *common, threshold)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        return (out, lse, delta), _flash_backward_core(
+            q, k, v, do, lse, delta, *common, threshold)
+
+    resident = kernels(_REGIMES["resident"])
+    (out, lse, delta), (dq, dk, dv_) = (
+        resident if regime == "resident" else kernels(_REGIMES[regime]))
+    (r_out, r_lse, _), (r_dq, _, _) = resident
+    for name, a, b in (("out", out, r_out), ("lse", lse, r_lse),
+                       ("dq", dq, r_dq)):
+        assert a.dtype == b.dtype and jnp.array_equal(a, b), name
+    want_dk, want_dv = _dkv_in_table_order(
+        q, k, v, do, lse, delta, scale, block_q, block_k)
+    if regime == "streamed":
+        assert jnp.array_equal(dk, want_dk) and jnp.array_equal(dv_, want_dv)
+    # the resident order against the table's: rounding of the last place
+    # of an f32 sum, before the cast to the operands' dtype
+    for name, a, b in (("dk", dk, want_dk), ("dv", dv_, want_dv)):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=1e-5 if dtype == jnp.float32 else 2e-3, rtol=0,
+            err_msg=name,
+        )

@@ -41,8 +41,21 @@ select; only the tiles that straddle the diagonal run the masked body.
 ``_causal_sweep`` is the one closed form of both bounds for the row sweeps
 (forward, dq) and the column sweep (dkv); ``_sweep`` runs the full tiles
 in a loop and the diagonal ones straight-line (a fixed count where one
-tile edge divides the other). The streamed kernels take the same two
-predicates in their ``pl.when``.
+tile edge divides the other). A streamed causal grid takes a step only for
+a tile it computes: its one inner axis enumerates the live tiles
+(``_live_tiles``: two int32 tables built from ``_tile_live`` at trace
+time and prefetched as scalars, which the index maps read the q and the k
+block from), row-major for the forward and dq, column-major for dkv — the
+order the rectangular grid met them in, so every result is bit for bit
+what that grid gave — and the accumulators are cleared and written out at
+``_sweep_ends``, ``_causal_sweep``'s first and last live block. At S 8192
+with 512 x 1024 tiles that is 72 grid steps a head where the rectangle
+has 128 (``_grid_steps``). Both ways of sparing the dead steps were
+measured on the v5e (PERF.md): clamping their index maps so that they
+fetch nothing (PR 31) gained nothing, and not taking them (PR 45) gained
+8.9 / 10.0 / 4.2 ms a call of 41.2 / 51.5 / 51.0 (forward / dq / dkv at
+[128, 8192, 192 / 128]), 0.6 - 1.4 us a step not taken. Without the mask
+the streamed grid is the rectangle of blocks, as it was.
 
 dK/dV recomputes its tile TRANSPOSED (Sᵀ = K·Qᵀ, [BK, BQ]): Pᵀ·dO and
 dSᵀ·Q are then plain matmuls and no [BQ, BK] tile goes through the
@@ -80,6 +93,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -131,6 +145,38 @@ def _causal_sweep(idx, block_q: int, block_k: int, seq_len: int,
         num_q_blocks, ((idx + 1) * block_k + block_q - 2) // block_q
     )
     return (full_start, num_q_blocks), (live_start, full_start)
+
+
+def _sweep_ends(idx, block_q: int, block_k: int, seq_len: int, rows: bool):
+    """``(first, last)`` block of one causal sweep's live tiles: the k
+    blocks of q block ``idx`` (``rows``) or the q blocks of k block ``idx``,
+    from :func:`_causal_sweep`'s ranges."""
+    full, diagonal = _causal_sweep(idx, block_q, block_k, seq_len, rows)
+    return ((full[0], diagonal[1] - 1) if rows
+            else (diagonal[0], full[1] - 1))
+
+
+def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool):
+    """``(q_of, k_of)``: the q block and the k block of the t-th live tile
+    under the causal mask, as two ``int32`` tables — row-major (a q block's
+    k blocks ascending: forward and dq) or, ``rows`` false, column-major (a
+    k block's q blocks ascending: dkv). What a streamed causal grid
+    enumerates; :func:`_tile_live` alone decides which tiles are in it."""
+    q_of, k_of = np.indices(
+        (seq_len // block_q, seq_len // block_k), dtype=np.int32
+    )
+    if not rows:
+        q_of, k_of = q_of.T, k_of.T
+    live = _tile_live(q_of, k_of, block_q, block_k)
+    return q_of[live], k_of[live]
+
+
+def _grid_steps(seq_len: int, block_q: int, block_k: int) -> Tuple[int, int]:
+    """``(live, rectangular)`` grid steps a head of one streamed sweep at
+    these tiles: what a causal call takes and what a call without the mask
+    does (72 and 128 at S 8192 with 512 x 1024 tiles)."""
+    return (len(_live_tiles(seq_len, block_q, block_k, True)[0]),
+            (seq_len // block_q) * (seq_len // block_k))
 
 
 def _sweep(idx, block_q: int, block_k: int, seq_len: int, causal: bool,
@@ -224,29 +270,79 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     lse_ref[0] = m + jnp.log(l)
 
 
-def _when_live(qi, ki, block_q: int, block_k: int, causal: bool, tile):
-    """Streamed regime: run ``tile(masked)`` for grid step (qi, ki) — not
-    at all above the diagonal, masked on it, unmasked below it."""
+def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
+                   causal: bool, rows: bool):
+    """Streamed regime: which tile this grid step computes. Returns ``(qi,
+    ki, first, last, refs)``: the tile, the first and the last value the
+    swept index (``ki`` of a row sweep, ``qi`` of a column sweep) takes in
+    this row or column — where the accumulators are cleared and written
+    out — and the kernel's operands. Without the mask the two inner grid
+    axes are the blocks themselves. Under it the one inner axis runs over
+    the live tiles only and ``refs`` starts with the two tables of
+    :func:`_live_tiles`, prefetched as scalars; the sweep's ends are
+    :func:`_causal_sweep`'s."""
+    if not causal:
+        own, swept = pl.program_id(1), pl.program_id(2)
+        qi, ki = (own, swept) if rows else (swept, own)
+        return qi, ki, 0, seq_len // (block_k if rows else block_q) - 1, refs
+    q_of, k_of, *refs = refs
+    step = pl.program_id(1)
+    qi, ki = q_of[step], k_of[step]
+    first, last = _sweep_ends(
+        qi if rows else ki, block_q, block_k, seq_len, rows
+    )
+    return qi, ki, first, last, refs
+
+
+def _full_or_masked(qi, ki, block_q: int, block_k: int, causal: bool, tile):
+    """Streamed regime: run ``tile(masked)`` for the live tile (qi, ki) —
+    masked on the diagonal, unmasked below it."""
     if not causal:
         tile(False)
         return
     full = _tile_full(qi, ki, block_q, block_k)
     pl.when(full)(functools.partial(tile, False))
-    pl.when(jnp.logical_and(
-        _tile_live(qi, ki, block_q, block_k), jnp.logical_not(full)
-    ))(functools.partial(tile, True))
+    pl.when(jnp.logical_not(full))(functools.partial(tile, True))
 
 
-def _flash_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                           m_ref, l_ref, *, block_q: int, block_k: int,
-                           num_k_blocks: int, causal: bool, scale: float):
+def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
+                   causal: bool, rows: bool):
+    """A streamed call's ``(grid, tables, by_q, by_k, q_lanes)``: the grid,
+    the scalar-prefetch operands and the index maps of a block of q rows
+    ([.., BQ, D]), of k rows and of q positions along the lanes ([.., 1,
+    BQ], dkv's statistics). Without the mask the grid is the rectangle of
+    blocks, swept axis innermost, and nothing is prefetched; under it one
+    axis enumerates :func:`_live_tiles` and the maps read the tile's blocks
+    off the two tables."""
+    if causal:
+        tables = _live_tiles(seq_len, block_q, block_k, rows)
+        return (
+            (bh, len(tables[0])), tuple(jnp.asarray(t) for t in tables),
+            lambda b, t, q_of, k_of: (b, q_of[t], 0),
+            lambda b, t, q_of, k_of: (b, k_of[t], 0),
+            lambda b, t, q_of, k_of: (b, 0, q_of[t]),
+        )
+    num_q, num_k = seq_len // block_q, seq_len // block_k
+    if rows:
+        return ((bh, num_q, num_k), (),
+                lambda b, i, j: (b, i, 0), lambda b, i, j: (b, j, 0),
+                lambda b, i, j: (b, 0, i))
+    return ((bh, num_k, num_q), (),
+            lambda b, i, j: (b, j, 0), lambda b, i, j: (b, i, 0),
+            lambda b, i, j: (b, 0, j))
+
+
+def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
+                           causal: bool, scale: float):
     """K-blocks ride the innermost grid dimension: only (block_k, d) K/V
     tiles are VMEM-resident at a time, so sequence length is bounded by
     HBM, not VMEM. acc/m/l live in VMEM scratch across the k sweep."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, first, last, refs = _streamed_tile(
+        refs, block_q, block_k, seq_len, causal, True
+    )
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
-    @pl.when(ki == 0)
+    @pl.when(ki == first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -261,9 +357,9 @@ def _flash_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
 
-    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(ki == last)
     def _finalize():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
@@ -320,13 +416,14 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         return out, lse[..., 0]
 
     # Long context: stream K/V tiles via the grid.
-    num_k_blocks = seq_len // block_k
-    grid = (bh, seq_len // block_q, num_k_blocks)
+    grid, tables, by_q, by_k, _ = _streamed_grid(
+        bh, seq_len, block_q, block_k, causal, True
+    )
     kernel = functools.partial(
         _flash_streamed_kernel,
         block_q=block_q,
         block_k=block_k,
-        num_k_blocks=num_k_blocks,
+        seq_len=seq_len,
         causal=causal,
         scale=scale,
     )
@@ -337,21 +434,24 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     ]
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), by_q),
+                pl.BlockSpec((1, block_k, d), by_k),
+                pl.BlockSpec((1, block_k, dv), by_k),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, dv), by_q),
+                pl.BlockSpec((1, block_q, 1), by_q),
+            ],
+            scratch_shapes=scratch,
+        ),
         out_shape=out_shapes,
-        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(*tables, q, k, v)
     return out, lse[..., 0]
 
 
@@ -458,17 +558,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                  delta_ref, dq_ref, dq_acc, *,
-                                  block_q: int, block_k: int,
-                                  num_k_blocks: int, causal: bool,
-                                  scale: float):
+def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
+                                  seq_len: int, causal: bool, scale: float):
     """K/V tiles ride the innermost grid dim (long-context regime); dq
     accumulates in VMEM scratch across the k sweep."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, first, last, refs = _streamed_tile(
+        refs, block_q, block_k, seq_len, causal, True
+    )
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
 
-    @pl.when(ki == 0)
+    @pl.when(ki == first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -478,24 +577,24 @@ def _flash_bwd_dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
             _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
         )
 
-    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(ki == last)
     def _finalize():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                   delta_ref, dk_ref, dv_ref, dk_acc,
-                                   dv_acc, *, block_q: int, block_k: int,
-                                   num_q_blocks: int, causal: bool,
-                                   scale: float):
+def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
+                                   seq_len: int, causal: bool, scale: float):
     """Q/dO tiles ride the innermost grid dim; dk/dv accumulate in VMEM
     scratch across the q sweep."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, ki, first, last, refs = _streamed_tile(
+        refs, block_q, block_k, seq_len, causal, False
+    )
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
+     dv_acc) = refs
 
-    @pl.when(qi == 0)
+    @pl.when(qi == first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -508,9 +607,9 @@ def _flash_bwd_dkv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = dk_acc[...] + dk
         dv_acc[...] = dv_acc[...] + dv
 
-    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(qi == last)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -521,65 +620,73 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
                              interpret: bool):
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
-    num_q_blocks = seq_len // block_q
-    num_k_blocks = seq_len // block_k
     # tile-legal views of the [BH, S] statistics (module docstring):
     # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
     lse, lse_row = lse[..., None], lse[:, None, :]
     delta, delta_row = delta[..., None], delta[:, None, :]
 
+    grid, tables, by_q, by_k, _ = _streamed_grid(
+        bh, seq_len, block_q, block_k, causal, True
+    )
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_streamed_kernel, block_q=block_q,
-            block_k=block_k, num_k_blocks=num_k_blocks, causal=causal,
-            scale=scale,
+            block_k=block_k, seq_len=seq_len, causal=causal, scale=scale,
         ),
-        grid=(bh, num_q_blocks, num_k_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), by_q),
+                pl.BlockSpec((1, block_k, d), by_k),
+                pl.BlockSpec((1, block_k, dv), by_k),
+                pl.BlockSpec((1, block_q, dv), by_q),
+                pl.BlockSpec((1, block_q, 1), by_q),
+                pl.BlockSpec((1, block_q, 1), by_q),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), by_q),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_dq",
-    )(q, k, v, g, lse, delta)
+    )(*tables, q, k, v, g, lse, delta)
 
+    grid, tables, by_q, by_k, q_lanes = _streamed_grid(
+        bh, seq_len, block_q, block_k, causal, False
+    )
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_streamed_kernel, block_q=block_q,
-            block_k=block_k, num_q_blocks=num_q_blocks, causal=causal,
-            scale=scale,
+            block_k=block_k, seq_len=seq_len, causal=causal, scale=scale,
         ),
-        grid=(bh, num_k_blocks, num_q_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), by_q),
+                pl.BlockSpec((1, block_k, d), by_k),
+                pl.BlockSpec((1, block_k, dv), by_k),
+                pl.BlockSpec((1, block_q, dv), by_q),
+                pl.BlockSpec((1, 1, block_q), q_lanes),
+                pl.BlockSpec((1, 1, block_q), q_lanes),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), by_k),
+                pl.BlockSpec((1, block_k, dv), by_k),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
+            ],
+        ),
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_dkv",
-    )(q, k, v, g, lse_row, delta_row)
+    )(*tables, q, k, v, g, lse_row, delta_row)
     return dq, dk, dv
 
 
