@@ -44,24 +44,28 @@ def test_lighthouse_address(lighthouse) -> None:
     assert addr.startswith("http://")
 
 
-def test_lighthouse_quorum_join_timing(lighthouse) -> None:
-    # Single replica quorum resolves well under 0.4s with 100ms join timeout
-    # (parity with ref lighthouse_test.py:44-47).
-    start = time.monotonic()
-    result = lighthouse_quorum(
-        lighthouse.address(),
-        {
-            "replica_id": "timing",
-            "address": "addr",
-            "store_address": "store",
-            "step": 0,
-            "world_size": 1,
-            "shrink_only": False,
-        },
-        timeout=5.0,
-    )
-    elapsed = time.monotonic() - start
-    assert elapsed < 0.4, f"quorum took {elapsed}s"
+def test_lighthouse_quorum_join_timing() -> None:
+    # A single replica's quorum does not wait for the join window (parity
+    # with ref lighthouse_test.py:44-47): every heartbeating replica has
+    # joined, so nobody is waited for. The window here is a minute and
+    # the RPC's deadline 5 s: a lighthouse that held the request for the
+    # window would fail the call, on any machine at any load.
+    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=60_000)
+    try:
+        result = lighthouse_quorum(
+            lighthouse.address(),
+            {
+                "replica_id": "timing",
+                "address": "addr",
+                "store_address": "store",
+                "step": 0,
+                "world_size": 1,
+                "shrink_only": False,
+            },
+            timeout=5.0,
+        )
+    finally:
+        lighthouse.shutdown()
     ids = [p["replica_id"] for p in result["quorum"]["participants"]]
     assert ids == ["timing"]
 
@@ -751,11 +755,10 @@ def _knock_refused(pool, lh):
     mgrs, clients = _first_quorum(pool, lh, 4)
     try:
         mgrs[3].shutdown()
-        t0 = time.monotonic()
         second = [f.result(timeout=20) for f in _ask(pool, clients[:3])]
-        assert time.monotonic() - t0 < _KNOCK_SOON_S
         assert [r.replica_world_size for r in second] == [3] * 3
         status = _status_json(lh.address())
+        # dead by the refusal (counted below), not by the heartbeat timeout
         assert status["heartbeats"]["r3"]["dead"] is True
         assert status["heartbeats"]["r0"]["dead"] is False
         job = status["jobs"]["default"]
@@ -817,10 +820,9 @@ def _knock_two_of_four(pool, lh):
     try:
         mgrs[2].shutdown()
         mgrs[3].shutdown()
-        t0 = time.monotonic()
         second = [f.result(timeout=20) for f in _ask(pool, clients[:2])]
-        assert time.monotonic() - t0 < _KNOCK_SOON_S
         assert [r.replica_world_size for r in second] == [2] * 2
+        # by the refusals, not by the heartbeat timeout
         assert _status_json(lh.address())["control"]["refused_expiries"] == 2
     finally:
         for m in mgrs:
@@ -908,18 +910,28 @@ def _knock_holds_no_lock(pool, lh):
         # known before the hold: a first sighting would start its count anew
         lighthouse_heartbeat(addr, "bystander")
         (fut,) = _ask(pool, [client])
-        _wait_status(addr, lambda s: s["control"]["door_knocks"] >= 2)
-        slowest = 0.0
-        t_end = time.monotonic() + 2.0  # five ticks of 400 ms
-        while time.monotonic() < t_end:
-            t0 = time.monotonic()
+        # a heartbeat is ANSWERED WHILE THE TICK THREAD KNOCKS, three
+        # times: the accepting listener sees a round of knocks begin (its
+        # connection comes), the bystander's heartbeat goes out and comes
+        # back, and the lighthouse has still not counted that round (it
+        # counts both absentees when the round ends, a whole tick later,
+        # under the lock). Under a lock held through the round the
+        # heartbeat would come back after the count. The deadline is for
+        # that, not a bound on a heartbeat's time.
+        rounds, answered_inside = listeners[0].held, 0
+        deadline = time.monotonic() + 20.0
+        while answered_inside < 3:
+            before = len(rounds)
+            while len(rounds) == before:
+                assert time.monotonic() < deadline, answered_inside
+                time.sleep(0.005)
             lighthouse_heartbeat(addr, "bystander")
-            slowest = max(slowest, time.monotonic() - t0)
-            time.sleep(0.01)
-        status = _status_json(addr)
+            counted = _status_json(addr)["control"]["door_knocks"]
+            answered_inside += counted < 2 * len(rounds)
+        status = _wait_status(
+            addr, lambda s: s["control"]["door_knocks"] >= 6)
         assert status["control"]["door_knocks"] >= 6, status["control"]
         assert status["control"]["refused_expiries"] == 0
-        assert slowest < 0.2, slowest
         assert not fut.done()
         late = [pool.submit(lighthouse_quorum, addr, m, 20.0) for m in absent]
         assert fut.result(timeout=20).replica_world_size == 3

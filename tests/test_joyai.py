@@ -23,7 +23,13 @@ import optax
 import pytest
 
 from benchmark.reference import joyai_f32
-from torchft_tpu.models import joyai, make_grad_step, make_train_step, olmoe
+from torchft_tpu.models import (
+    common,
+    joyai,
+    make_grad_step,
+    make_train_step,
+    olmoe,
+)
 from torchft_tpu.ops import moe
 from torchft_tpu.optim import balance_bias_rule, with_balance_bias
 
@@ -242,7 +248,7 @@ def test_the_shares_add_up_to_the_uncut_layer(split) -> None:
         want, _ = joyai_f32._moe(h, full["moe"], top_k=CFG.top_k,
                                  first_expert=0,
                                  routed_scale=CFG.routed_scale)
-        shared = joyai._swiglu(h, full["moe"]["shared"], jnp.float32)
+        shared = common.swiglu(h, full["moe"]["shared"], jnp.float32)
         total, first = jnp.zeros_like(want), 0
         for held in split:
             cfg = dataclasses.replace(CFG32, first_expert=first,

@@ -15,9 +15,6 @@ from benchmark.reference.kimi_linear_f32 import kda_recurrence
 from torchft_tpu.ops import kda
 from torchft_tpu.ops.kda import _choose_chunk, kda_scan
 
-# tests/conftest.py: of the files that compile for minutes, one at a time
-# (a minute here, but the v5e compile takes every core while it runs)
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
 
 LEAVES = ("dq", "dk", "dv", "dg", "dbeta")
 

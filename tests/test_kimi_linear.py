@@ -39,9 +39,6 @@ TWO32 = dataclasses.replace(CFG32, kda_layers=(1,), full_attn_layers=(2,))
 BIAS = kimi_linear.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# tests/conftest.py: of the files that compile for minutes, one at a time
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
-
 
 def _params(cfg, seed, bias_std=0.1):
     """Seeded weights with the balance biases away from zero, so that a
@@ -191,7 +188,7 @@ def test_the_mixers_are_two_lists_and_the_mlp_a_count(kda, full, n_dense):
 
 
 def test_latent_attention_is_joyais_without_a_q_latent_or_a_turn() -> None:
-    """The MLA layer is ``models/joyai.py::_mla_sublayer`` under this
+    """The MLA layer is ``models/joyai.py::mla_sublayer`` under this
     config: one ``q_proj`` where JoyAI has three q leaves, and no cos or
     sin anywhere in the program; with a theta (the faults' stand-in) the
     same function turns the 8 shared channels."""
@@ -202,7 +199,7 @@ def test_latent_attention_is_joyais_without_a_q_latent_or_a_turn() -> None:
     assert layer["attn"]["q_proj"]["kernel"].shape == (64, 4 * 24)
     assert layer["attn"]["kv_a_proj"]["kernel"].shape == (64, 32 + 8)
     x = jax.random.normal(jax.random.key(2), (1, 32, 64), jnp.float32)
-    run = functools.partial(joyai._mla_sublayer, attn_fn=(
+    run = functools.partial(joyai.mla_sublayer, attn_fn=(
         kimi_linear._local_causal_attention))
     text = str(jax.make_jaxpr(lambda a: run(CFG32, layer, a))(x))
     assert " cos " not in text and " sin " not in text
@@ -313,7 +310,7 @@ def test_the_shares_add_up_to_the_uncut_layer(split) -> None:
             for name in ("gate_proj", "up_proj", "down_proj"):
                 share["moe"][name] = {"kernel": full["moe"][name]["kernel"][
                     first:first + held]}
-            y, _ = joyai._moe_sublayer(cfg, share, x)
+            y, _ = kimi_linear._moe_sublayer(cfg, share, x)
             # every share computes the shared expert: counted once, above
             total = total + (y - x).reshape(-1, 64) - shared
             first += held

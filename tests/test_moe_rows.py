@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from torchft_tpu import optim
-from torchft_tpu.models import joyai, lfm2, nemotron_h
+from torchft_tpu.models import common, joyai, lfm2, nemotron_h
 from torchft_tpu.ops import moe
 from torchft_tpu.utils.metrics import Metrics
 
@@ -220,12 +220,24 @@ def test_the_number_of_passes_is_data(held_rows, want) -> None:
     assert seen == held_rows
 
 
+def _neither_shared_nor_gated(cfg, layer, x):
+    """What no model binds: relu² experts (no gate matrix) and no shared
+    expert in one call."""
+    return common.routed_sublayer(
+        cfg, x, layer["norm"]["scale"],
+        {k: v for k, v in layer["moe"].items() if k != "shared"})
+
+
+# the three models' bindings of ``common.routed_sublayer``, and the
+# function itself
 _FAMILIES = {
     "joyai": (joyai, joyai.JOYAI_CONFIGS["joyai_tiny"], joyai._moe_sublayer),
     "nemotron_h": (nemotron_h,
                    nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"],
                    nemotron_h._moe_mixer),
     "lfm2": (lfm2, lfm2.LFM2_CONFIGS["lfm2_tiny"], lfm2._moe_mlp),
+    "common": (nemotron_h, nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"],
+               _neither_shared_nor_gated),
 }
 
 

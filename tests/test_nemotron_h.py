@@ -25,7 +25,7 @@ import pytest
 
 from benchmark.families import nemotron_h as family
 from benchmark.reference import nemotron_h_f32
-from torchft_tpu.models import joyai, nemotron_h, olmoe
+from torchft_tpu.models import joyai, kimi_linear, lfm2, nemotron_h, olmoe
 from torchft_tpu.ops import moe
 from torchft_tpu.ops.attention import reference_attention
 
@@ -33,9 +33,6 @@ CFG = nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
 BIAS = nemotron_h.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# tests/conftest.py: of the files that compile for minutes, one at a time
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
 
 
 def _params(cfg, seed, bias_std=0.1):
@@ -336,46 +333,83 @@ def test_every_assignment_held_and_none_held_run_one_program() -> None:
     assert np.any(grads["moe"]["shared"]["up_proj"]["kernel"])
 
 
+def _scope_paths(jaxpr, out):
+    """Every ``jax.named_scope`` path an equation of ``jaxpr`` carries,
+    nested jaxprs included (the jaxpr's text does not hold them)."""
+    for eqn in jaxpr.eqns:
+        out.add(str(eqn.source_info.name_stack))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scope_paths(sub, out)
+    return out
+
+
 def _jaxpr_hash(fn, *args):
-    text = re.sub(r"/[^ ]*?\.py:\d+", "", str(jax.make_jaxpr(fn)(*args)))
-    return hashlib.sha256(text.encode()).hexdigest()
+    """The sha256 of ``fn``'s jaxpr (file paths cut) and the sorted set of
+    its scope paths, from one trace."""
+    closed = jax.make_jaxpr(fn)(*args)
+    text = re.sub(r"/[^ ]*?\.py:\d+", "", str(closed))
+    return (hashlib.sha256(text.encode()).hexdigest(),
+            sorted(_scope_paths(closed.jaxpr, set())))
 
 
-# the sha256 of joyai_tiny's gradient jaxpr (file paths cut) at d58585e,
-# the parent of PR 33: recorded by running this file's _jaxpr_hash on a
-# checkout of that commit. Read again across PR 39 (c6c2ccf -> the row
-# buffer of a held share): the same, because at this size the buffer
-# would be all N*k rows and such a call takes the path it took
-# (tests/test_moe_rows.py holds the three models at a size where it
-# does not)
-JOYAI_PROGRAM_AT_D58585E = (
-    "1c74cd8031aed88edcf9c962c458677fcccb217cedef892342f8357051ff2834")
+# model -> (module, tiny config, sha256 of the gradient jaxpr's text with
+# file paths cut, sha256 of its sorted set of scope paths joined by "\n",
+# how many paths). Recorded by running this file's _jaxpr_hash on a
+# checkout of the commit named, nothing compiled:
+# - olmoe, joyai: programs at d58585e, the parent of PR 33
+#   (tests/test_joyai.py pins olmoe's under the same hash). Read again
+#   across PR 39 (c6c2ccf -> the row buffer of a held share): the same,
+#   because at this size the buffer would be all N*k rows and such a call
+#   takes the path it took (tests/test_moe_rows.py holds the models at a
+#   size where it does not)
+# - nemotron_h: program at 2d59480 (tests/test_lfm2.py held it until
+#   PR 46)
+# - lfm2, kimi, and all five sets of scope paths: at 7be2396, the parent
+#   of PR 46, before models/common.py took the routed sublayer and the
+#   share's loss_terms. The scope readers of benchmark/readers classify
+#   device time by these paths.
+PROGRAMS_THAT_WERE = {
+    "olmoe": (
+        olmoe, olmoe.OLMOE_CONFIGS["olmoe_tiny"],
+        "3c40614299f885d4c7d1230c9b2a6a7a68c7e055e6686e838d81f49e710ed6b8",
+        "4f8d4efc3b1fb55ff5284b37019772ac887b59510bdbc56c1490d1234741739c",
+        24),
+    "joyai": (
+        joyai, joyai.JOYAI_CONFIGS["joyai_tiny"],
+        "1c74cd8031aed88edcf9c962c458677fcccb217cedef892342f8357051ff2834",
+        "b6eb31ce88265df1a34020e32d232f563094c86d492d8e125f707dcdee94bd0f",
+        68),
+    "nemotron_h": (
+        nemotron_h, CFG,
+        "10330c120050e7def392ddfebd3a40478d8001ccba37850b1ffcc81dcb9e0cba",
+        "7da4580a487db94456b2e2e93db5fb4ee664a4d6c2d718a99312e2641f54ef0a",
+        44),
+    "lfm2": (
+        lfm2, lfm2.LFM2_CONFIGS["lfm2_tiny"],
+        "636c3fb7a6e8986e65f3459c3198909d5bbb17caabeccae3228ee5d746bbf400",
+        "8b3555543f04f822f65e368bafd8cc46ee6e72c34c13793337027dc215acdbbb",
+        32),
+    "kimi": (
+        kimi_linear, kimi_linear.KIMI_LINEAR_CONFIGS["kimi_linear_tiny"],
+        "faeea6a7fae84398adeb15fc473b60ea42db40b81079895570a8f3884ff02506",
+        "272f87412d31aeacffd6b18bcf1620de8d6b0d627e20c9e74087845f7b91364e",
+        52),
+}
 
 
-@pytest.mark.parametrize("model", ["olmoe", "joyai"])
+@pytest.mark.parametrize("model", list(PROGRAMS_THAT_WERE))
 def test_the_gated_expert_paths_are_what_they_were(model) -> None:
-    """``moe_mlp`` carries either expert; a call with a gate — OLMoE's and
-    JoyAI's — traces to the jaxpr the commit before this one (d58585e)
-    traced: same instructions, same order. The outputs' bits follow."""
+    """Each sparse model's whole gradient program traces to the jaxpr the
+    commit named above traced — same instructions, same order, so the
+    outputs' bits follow — under the same ``jax.named_scope`` paths."""
+    mod, cfg, program, scopes, n_scopes = PROGRAMS_THAT_WERE[model]
+    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
     tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
-    if model == "olmoe":
-        cfg = olmoe.OLMOE_CONFIGS["olmoe_tiny"]
-        params = jax.eval_shape(
-            lambda: olmoe.init_params(cfg, jax.random.key(0)))
-        got = _jaxpr_hash(jax.grad(
-            lambda p, a, b: olmoe.loss_fn(cfg, p, a, b)), params, tokens,
-            tokens)
-        # tests/test_joyai.py pins the same program under the same hash
-        assert got == ("3c40614299f885d4c7d1230c9b2a6a7a"
-                       "68c7e055e6686e838d81f49e710ed6b8")
-    else:
-        cfg = joyai.JOYAI_CONFIGS["joyai_tiny"]
-        params = jax.eval_shape(
-            lambda: joyai.init_params(cfg, jax.random.key(0)))
-        got = _jaxpr_hash(jax.grad(
-            lambda p, a, b: joyai.loss_fn(cfg, p, a, b)), params, tokens,
-            tokens)
-        assert got == JOYAI_PROGRAM_AT_D58585E
+    grad = jax.grad(lambda p, a, b: mod.loss_fn(cfg, p, a, b))
+    got, paths = _jaxpr_hash(grad, params, tokens, tokens)
+    assert got == program
+    assert (hashlib.sha256("\n".join(paths).encode()).hexdigest(),
+            len(paths)) == (scopes, n_scopes), paths
 
 
 def test_relu2_experts_are_the_plain_sum_over_experts() -> None:

@@ -4,11 +4,9 @@ the cell's check compares, the stand-ins are sound without their fault,
 the cell's own check of the scan — forward and six gradients against the
 recurrence — holds the sound kernels and fails the two lower-precision
 stand-ins; then the family through the one step maker, the one optimizer
-and the fault-tolerant loop.
-
-A file of its own so that neither file compiles for more than two
-minutes: both, and ``test_ssd.py``, ask for
-``conftest.py::one_compiling_file_at_a_time`` (which says why)."""
+and the fault-tolerant loop. A file of its own: pytest-xdist hands whole
+files to its workers, and two files of two minutes end sooner than one
+of four."""
 
 from __future__ import annotations
 
@@ -38,9 +36,6 @@ from test_nemotron_h import (
     _tiny_model,
 )
 from torchft_tpu.models import nemotron_h
-
-# tests/conftest.py: of the files that compile for minutes, one at a time
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
 
 
 @pytest.mark.parametrize("fault", FAULTS)

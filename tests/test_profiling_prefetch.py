@@ -1,7 +1,7 @@
 """StepProfiler (XLA trace windows) and PrefetchIterator (H2D pipeline)."""
 
 import os
-import time
+import threading
 
 import numpy as np
 import pytest
@@ -60,27 +60,25 @@ def test_prefetch_yields_all_batches_in_order() -> None:
 
 
 def test_prefetch_overlaps_source_latency() -> None:
-    # with depth=2, consuming N slow batches takes ~max(consume, produce)
-    # not their sum — the worker runs ahead while the consumer "computes"
-    delay = 0.05
+    # with depth=2 the worker runs ahead while the consumer "computes":
+    # the consumer's step on batch i IS waiting until the source has been
+    # asked for batch i+1. An iterator that read the source only when
+    # asked would never get there (10 s: the deadline for that, no bound
+    # on a time)
+    asked_for = [threading.Event() for _ in range(6)]
 
-    def slow_source():
-        for i in range(6):
-            time.sleep(delay)
+    def source():
+        for i, asked in enumerate(asked_for):
+            asked.set()
             yield np.full((2,), i)
 
-    it = PrefetchIterator(slow_source(), depth=2)
-    first = next(it)  # warm: worker now prefetching ahead
-    t0 = time.perf_counter()
-    seen = [first]
-    for b in it:
-        time.sleep(delay)  # simulated device step
+    seen = []
+    for i, b in enumerate(PrefetchIterator(source(), depth=2)):
+        if i + 1 < len(asked_for):
+            assert asked_for[i + 1].wait(timeout=10), (
+                f"batch {i + 1} was not read while batch {i} was in use")
         seen.append(b)
-    elapsed = time.perf_counter() - t0
     assert len(seen) == 6
-    # serial would be ~2 * 5 * delay in this window; overlap keeps it
-    # well under (generous bound for CI noise)
-    assert elapsed < 1.8 * 5 * delay, elapsed
 
 
 def test_prefetch_propagates_source_error() -> None:

@@ -42,22 +42,55 @@ def _fetch(url: str, timeout: float = 10.0) -> dict:
 
 
 class _Harness:
+    """Ends the run on the EVENT the tests are about, not on a count of
+    steps the restart has to beat: the killed replica's next incarnation
+    has committed a step in which all ``num_replicas`` took part (and
+    everyone is past ``total_steps``). The survivors keep stepping until
+    then, however long the restart takes on a loaded machine; the only
+    clock is :func:`_run_to_the_end`'s deadline for a hung run."""
+
     def __init__(self, num_replicas: int, total_steps: int) -> None:
         self.num_replicas = num_replicas
         self.total_steps = total_steps
         self.stop = threading.Event()
         self.progress: Dict[int, int] = {}
+        self.rejoined_at_full_width = False
         self._lock = threading.Lock()
 
-    def report(self, replica_id: int, step: int) -> None:
+    def report(self, replica_id: int, step: int, restarted: bool,
+               participants: int) -> None:
         with self._lock:
             self.progress[replica_id] = max(
                 self.progress.get(replica_id, 0), step
             )
-            if len(self.progress) == self.num_replicas and all(
-                s >= self.total_steps for s in self.progress.values()
+            if restarted and participants == self.num_replicas:
+                self.rejoined_at_full_width = True
+            if (
+                self.rejoined_at_full_width
+                and len(self.progress) == self.num_replicas
+                and all(s >= self.total_steps
+                        for s in self.progress.values())
             ):
                 self.stop.set()
+
+
+def _run_to_the_end(replicas: List["_Replica"], harness: _Harness,
+                    lighthouse: Lighthouse) -> None:
+    """Every replica's loop to the harness's stop, or 180 s: a run still
+    going then is hung, and is stopped INSIDE the pool (whose exit waits
+    for loops that only ``harness.stop`` ends) so that it fails here and
+    does not hang there."""
+    try:
+        with ThreadPoolExecutor(max_workers=len(replicas)) as pool:
+            futs = [pool.submit(r.run) for r in replicas]
+            deadline = time.monotonic() + 180.0
+            try:
+                for f in futs:
+                    f.result(timeout=max(1.0, deadline - time.monotonic()))
+            finally:
+                harness.stop.set()
+    finally:
+        lighthouse.shutdown()
 
 
 class _Replica:
@@ -187,7 +220,9 @@ class _Replica:
                 holder["params"], holder["opt"] = params, opt_state
                 if committed:
                     self.harness.report(
-                        self.replica_id, manager.current_step()
+                        self.replica_id, manager.current_step(),
+                        restarted=self.failures > 0,
+                        participants=manager.num_participants(),
                     )
                 else:
                     time.sleep(0.01)
@@ -216,15 +251,7 @@ def test_sharded_kill_shrink_rejoin_lifecycle() -> None:
         _Replica(1, lighthouse.address(), harness),
         _Replica(2, lighthouse.address(), harness),
     ]
-    try:
-        with ThreadPoolExecutor(max_workers=3) as pool:
-            futs = [pool.submit(r.run) for r in replicas]
-            deadline = time.monotonic() + 180.0
-            for f in futs:
-                f.result(timeout=max(1.0, deadline - time.monotonic()))
-    finally:
-        harness.stop.set()
-        lighthouse.shutdown()
+    _run_to_the_end(replicas, harness, lighthouse)
 
     assert replicas[0].failures == 1
     # the killed replica restarted at least once; every replica finished
@@ -336,15 +363,7 @@ def test_sharded_2d_kill_shrink_rejoin_lower_bound() -> None:
         _Replica(1, lighthouse.address(), harness, model_shards=M),
         _Replica(2, lighthouse.address(), harness, model_shards=M),
     ]
-    try:
-        with ThreadPoolExecutor(max_workers=3) as pool:
-            futs = [pool.submit(r.run) for r in replicas]
-            deadline = time.monotonic() + 180.0
-            for f in futs:
-                f.result(timeout=max(1.0, deadline - time.monotonic()))
-    finally:
-        harness.stop.set()
-        lighthouse.shutdown()
+    _run_to_the_end(replicas, harness, lighthouse)
 
     assert replicas[0].failures == 1
 

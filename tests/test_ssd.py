@@ -12,9 +12,6 @@ import pytest
 from torchft_tpu.ops import ssd
 from torchft_tpu.ops.ssd import _choose_chunk, _heads_per_block, ssd_scan
 
-# tests/conftest.py: of the files that compile for minutes, one at a time
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
-
 
 def scan(x, dt, A, B, C, D, chunk=None):
     """``ssd_scan`` at a chunk of the test's choosing: the public function

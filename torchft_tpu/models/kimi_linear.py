@@ -39,7 +39,7 @@ before ``k``'s, head by head) and take their ``jax.vjp`` in the backward
 kernels, so whatever stands in those names' place
 (``benchmark/tests/kimi_faults.py``) is what the step computes.
 
-MLA mixer: ``models/joyai.py::_mla_sublayer`` itself, called with this
+MLA mixer: ``models/joyai.py::mla_sublayer`` itself, called with this
 config — ``q_lora_rank`` 0 (no q latent: one ``q_proj`` to ``H × (nope +
 rope)``) and ``rope_theta`` None (``mla_use_nope``: nothing is rotated;
 the ``rope``-wide key channels a token shares between its heads stay in
@@ -47,8 +47,8 @@ the score); ``c_kv`` (``kv_lora_rank``, RMSNorm) up to ``nope`` key +
 ``v_head_dim`` value channels a head; causal softmax at ``(nope +
 rope)^{-1/2}`` through the flash kernels.
 
-MLPs: ``models/joyai.py``'s ``_dense_sublayer`` and ``_moe_sublayer``
-themselves — the dense SwiGLU ``d_ff`` wide; the sparse sublayer with
+MLPs: ``models/common.py``'s ``dense_sublayer`` and ``routed_sublayer``,
+bound as ``models/joyai.py`` binds them — the dense SwiGLU ``d_ff`` wide; the sparse sublayer with
 sigmoid scores over all ``n_routed_experts``, the balance bias
 (``models/common.py``), the ``top_k`` largest of ``s + b``, renormalised,
 ``× routed_scale``, SwiGLU experts ``d_expert`` wide of which this share
@@ -90,8 +90,14 @@ import jax.numpy as jnp
 from torchft_tpu.models import joyai
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    dense_sublayer,
+    embed,
     is_balance_bias,
     rms_norm,
+    routed_sublayer,
+    routing_record,
+    share_loss_terms,
+    swiglu,
 )
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
@@ -230,7 +236,7 @@ def init_params(cfg: KimiLinearConfig, key) -> Dict:
     for i in range(cfg.n_layers):
         # JoyAI's layer for ``ln_1``, ``ln_2`` and the MLP's leaves; its
         # attention's leaves kept only where this layer has them
-        layer = joyai._layer_params(cfg, keys[2 + i], normal, ones,
+        layer = joyai.layer_params(cfg, keys[2 + i], normal, ones,
                                     dense=i < cfg.n_dense_layers)
         a = layer.pop("attn")
         mk = jax.random.fold_in(keys[2 + i], 2)
@@ -300,16 +306,26 @@ def _kda_sublayer(cfg: KimiLinearConfig, layer: Dict, x):
         return x + y @ m["o_proj"]["kernel"].astype(dt)
 
 
+def _moe_sublayer(cfg: KimiLinearConfig, layer: Dict, x) -> Tuple[Any, Dict]:
+    """``common.routed_sublayer`` as ``models/joyai.py`` binds it: the
+    norm ``ln_2``, SwiGLU experts, a SwiGLU shared expert."""
+    m = layer["moe"]
+    return routed_sublayer(
+        cfg, x, layer["ln_2"]["scale"], m,
+        shared=lambda h: swiglu(h, m["shared"], cfg.dtype))
+
+
 def _layer(cfg: KimiLinearConfig, kda: bool, dense: bool, layer: Dict, x, *,
            attn_fn):
     """One layer: ``(x, record or None)``."""
     if kda:
         x = _kda_sublayer(cfg, layer, x)
     else:
-        x = joyai._mla_sublayer(cfg, layer, x, attn_fn=attn_fn)
+        x = joyai.mla_sublayer(cfg, layer, x, attn_fn=attn_fn)
     if dense:
-        return joyai._dense_sublayer(cfg, layer, x), None
-    return joyai._moe_sublayer(cfg, layer, x)
+        return dense_sublayer(
+            cfg, x, layer["ln_2"]["scale"], layer["mlp"]), None
+    return _moe_sublayer(cfg, layer, x)
 
 
 def forward_hidden(cfg: KimiLinearConfig, params: Dict, tokens,
@@ -320,7 +336,7 @@ def forward_hidden(cfg: KimiLinearConfig, params: Dict, tokens,
     ``common.loads_as_gradient``)."""
     if attn_fn is None:
         attn_fn = _local_causal_attention
-    x = joyai._embed(cfg, params, tokens)
+    x = embed(cfg, params, tokens)
     records = []
     for i in range(cfg.n_layers):
         run = functools.partial(_layer, cfg, cfg.is_kda(i),
@@ -330,40 +346,17 @@ def forward_hidden(cfg: KimiLinearConfig, params: Dict, tokens,
         x, rec = run(params[f"layers_{i}"], x)
         if rec is not None:
             records.append(rec)
-    out: Dict[str, Any] = {"carrier": jnp.zeros((), jnp.float32)}
-    if records:
-        out = dict(
-            experts=jnp.stack([r["experts"] for r in records]),
-            loads=jnp.stack([r["loads"] for r in records]),
-            carrier=sum(r["carrier"] for r in records),
-        )
+    out = routing_record(records)
     return rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps), out
 
 
 def loss_terms(cfg: KimiLinearConfig, params, tokens, targets,
                attn_fn: Optional[Callable] = None) -> Dict[str, Any]:
-    """``loss`` (the mean next-token cross entropy; the balance bias's
-    carrier adds 0) and beside it the routing ``experts`` and ``loads``;
-    per expert layer ``rows_held`` (assignments on this share's
-    experts), ``held_share`` (of all ``N·top_k``) and
-    ``load_max_over_mean``; the final-norm ``hidden`` states, for
-    whoever compares them per token."""
+    """``common.share_loss_terms`` of this model's forward pass, the cross
+    entropy through ``lm_head``."""
     h, rec = forward_hidden(cfg, params, tokens, attn_fn)
-    ce = ce_from_hidden(h, params["lm_head"]["kernel"], targets,
-                        cfg.xent_chunks)
-    loss = ce + rec.pop("carrier")
-    out = dict(rec, ce=ce, loss=loss, hidden=h)
-    if "loads" in rec:
-        loads = rec["loads"]
-        held = slice(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
-        rows_held = jnp.sum(loads[:, held], axis=-1)
-        out.update(
-            rows_held=rows_held,
-            held_share=rows_held / jnp.sum(loads, axis=-1),
-            load_max_over_mean=jnp.max(loads, axis=-1)
-            / jnp.mean(loads, axis=-1),
-        )
-    return out
+    return share_loss_terms(cfg, h, rec, ce_from_hidden(
+        h, params["lm_head"]["kernel"], targets, cfg.xent_chunks))
 
 
 def loss_fn(cfg: KimiLinearConfig, params, tokens, targets,

@@ -70,17 +70,20 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    dense_sublayer,
+    embed,
     is_balance_bias,
-    loads_as_gradient,
     repeat_kv,
     rms_norm,
+    routed_sublayer,
+    routing_record,
+    share_loss_terms,
 )
 from torchft_tpu.models.llama import _rope
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
     ce_from_hidden,
 )
-from torchft_tpu.ops import moe
 from torchft_tpu.ops.ssm_pointwise import gated_conv
 
 __all__ = ["Lfm2Config", "LFM2_CONFIGS", "BALANCE_BIAS", "is_balance_bias",
@@ -250,44 +253,15 @@ def _attn_mixer(cfg: Lfm2Config, layer: Dict, x, *, attn_fn):
         return x + o.reshape(B, S, H * D) @ a["o_proj"]["kernel"].astype(dt)
 
 
-@jax.named_scope("mlp")
 def _dense_mlp(cfg: Lfm2Config, layer: Dict, x):
-    m, dt = layer["mlp"], cfg.dtype
-    n = rms_norm(x, layer["norm_2"]["scale"], cfg.rms_eps)
-    g = n @ m["gate_proj"]["kernel"].astype(dt)
-    u = n @ m["up_proj"]["kernel"].astype(dt)
-    return x + (jax.nn.silu(g) * u) @ m["down_proj"]["kernel"].astype(dt)
+    return dense_sublayer(cfg, x, layer["norm_2"]["scale"], layer["mlp"])
 
 
-@jax.named_scope("mlp")
 def _moe_mlp(cfg: Lfm2Config, layer: Dict, x) -> Tuple[Any, Dict]:
-    """``(x + y, record)``: ``experts`` [N, top_k], ``loads`` [routed]
-    (float32 counts), and ``carrier``, the zero that hands the loads to
-    the bias's place in the gradient tree."""
-    m = layer["moe"]
-    B, S, d = x.shape
-    with jax.named_scope("moe_router"):
-        n32 = rms_norm(x.astype(jnp.float32), layer["norm_2"]["scale"],
-                       cfg.rms_eps).reshape(B * S, d)
-        # as models/joyai.py: the router reads the normed stream before
-        # it is rounded to the compute dtype, in true float32
-        scores = jax.nn.sigmoid(jnp.dot(
-            n32, m["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        weights, experts = moe.top_k_routing(
-            scores, cfg.top_k, bias=m[BALANCE_BIAS], renormalise=True,
-            scale=cfg.routed_scale, eps=cfg.renorm_eps)
-        loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
-            experts.reshape(-1)].add(1.0)
-        carrier = loads_as_gradient(
-            m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
-    routed = moe.moe_mlp(
-        n32.astype(cfg.dtype), weights, experts, m["gate_proj"]["kernel"],
-        m["up_proj"]["kernel"], m["down_proj"]["kernel"],
-        n_routed=cfg.n_routed_experts, first_expert=cfg.first_expert,
-    )
-    return x + routed.reshape(B, S, d), {
-        "experts": experts, "loads": loads, "carrier": carrier}
+    """``common.routed_sublayer`` with this model's norm and the
+    renormalisation's published epsilon; no shared expert."""
+    return routed_sublayer(cfg, x, layer["norm_2"]["scale"], layer["moe"],
+                           renorm_eps=cfg.renorm_eps)
 
 
 def _layer(cfg: Lfm2Config, kind: str, dense: bool, layer: Dict, x, *,
@@ -302,11 +276,6 @@ def _layer(cfg: Lfm2Config, kind: str, dense: bool, layer: Dict, x, *,
     return _moe_mlp(cfg, layer, x)
 
 
-@jax.named_scope("embed")
-def _embed(cfg: Lfm2Config, params: Dict, tokens):
-    return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
-
-
 def forward_hidden(cfg: Lfm2Config, params: Dict, tokens,
                    attn_fn: Optional[Callable] = None) -> Tuple[Any, Dict]:
     """tokens [B, S] -> (final-norm hidden states [B, S, d], record). The
@@ -315,7 +284,7 @@ def forward_hidden(cfg: Lfm2Config, params: Dict, tokens,
     ``common.loads_as_gradient``)."""
     if attn_fn is None:
         attn_fn = _local_causal_attention
-    x = _embed(cfg, params, tokens)
+    x = embed(cfg, params, tokens)
     records = []
     for i, kind in enumerate(cfg.layer_types):
         run = functools.partial(_layer, cfg, kind, i < cfg.n_dense_layers,
@@ -325,42 +294,20 @@ def forward_hidden(cfg: Lfm2Config, params: Dict, tokens,
         x, rec = run(params[f"layers_{i}"], x)
         if rec is not None:
             records.append(rec)
-    out: Dict[str, Any] = {"carrier": jnp.zeros((), jnp.float32)}
-    if records:
-        out = dict(
-            experts=jnp.stack([r["experts"] for r in records]),
-            loads=jnp.stack([r["loads"] for r in records]),
-            carrier=sum(r["carrier"] for r in records),
-        )
+    out = routing_record(records)
     return rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps), out
 
 
 def loss_terms(cfg: Lfm2Config, params, tokens, targets,
                attn_fn: Optional[Callable] = None) -> Dict[str, Any]:
-    """``loss`` (the mean next-token cross entropy through the tied head;
-    the balance bias's carrier adds 0) and beside it the routing
-    ``experts`` and ``loads``; per expert layer ``rows_held``
-    (assignments on this share's experts), ``held_share`` (of all
-    ``N·top_k``) and ``load_max_over_mean``; the final-norm ``hidden``
-    states, for whoever compares them per token."""
+    """``common.share_loss_terms`` of this model's forward pass, the cross
+    entropy through the tied head."""
     h, rec = forward_hidden(cfg, params, tokens, attn_fn)
     with jax.named_scope("lm_head_xent"):
         # the head is the table: [V, d] read as [d, V]
         head = params["wte"]["embedding"].T
-    ce = ce_from_hidden(h, head, targets, cfg.xent_chunks)
-    loss = ce + rec.pop("carrier")
-    out = dict(rec, ce=ce, loss=loss, hidden=h)
-    if "loads" in rec:
-        loads = rec["loads"]
-        held = slice(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
-        rows_held = jnp.sum(loads[:, held], axis=-1)
-        out.update(
-            rows_held=rows_held,
-            held_share=rows_held / jnp.sum(loads, axis=-1),
-            load_max_over_mean=jnp.max(loads, axis=-1)
-            / jnp.mean(loads, axis=-1),
-        )
-    return out
+    return share_loss_terms(
+        cfg, h, rec, ce_from_hidden(h, head, targets, cfg.xent_chunks))
 
 
 def loss_fn(cfg: Lfm2Config, params, tokens, targets,

@@ -78,16 +78,18 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    embed,
     is_balance_bias,
-    loads_as_gradient,
     repeat_kv,
     rms_norm,
+    routed_sublayer,
+    routing_record,
+    share_loss_terms,
 )
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
     ce_from_hidden,
 )
-from torchft_tpu.ops import moe
 from torchft_tpu.ops.ssd import ssd_scan
 from torchft_tpu.ops.ssm_pointwise import conv_silu, gated_norm
 
@@ -311,43 +313,13 @@ def _relu2(h, m: Dict, dt):
         "kernel"].astype(dt)
 
 
-@jax.named_scope("mlp")
 def _moe_mixer(cfg: NemotronHConfig, layer: Dict, x) -> Tuple[Any, Dict]:
-    """``(x + y, record)``: ``experts`` [N, top_k], ``loads`` [routed]
-    (float32 counts), and ``carrier``, the zero that hands the loads to
-    the bias's place in the gradient tree."""
+    """``common.routed_sublayer`` with this model's norm, its relu²
+    experts (no gate matrix) and its relu² shared expert."""
     m = layer["moe"]
-    B, S, d = x.shape
-    with jax.named_scope("moe_router"):
-        h32 = rms_norm(x.astype(jnp.float32), layer["norm"]["scale"],
-                       cfg.rms_eps).reshape(B * S, d)
-        # as models/joyai.py: the router reads the normed stream before
-        # it is rounded to the compute dtype, in true float32
-        scores = jax.nn.sigmoid(jnp.dot(
-            h32, m["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        weights, experts = moe.top_k_routing(
-            scores, cfg.top_k, bias=m[BALANCE_BIAS], renormalise=True,
-            scale=cfg.routed_scale)
-        loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
-            experts.reshape(-1)].add(1.0)
-        carrier = loads_as_gradient(
-            m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
-    h = h32.astype(cfg.dtype)
-    with jax.named_scope("moe_shared"):
-        shared = _relu2(h, m["shared"], cfg.dtype)
-    routed = moe.moe_mlp(
-        h, weights, experts, None, m["up_proj"]["kernel"],
-        m["down_proj"]["kernel"], n_routed=cfg.n_routed_experts,
-        first_expert=cfg.first_expert,
-    )
-    return x + (routed + shared).reshape(B, S, d), {
-        "experts": experts, "loads": loads, "carrier": carrier}
-
-
-@jax.named_scope("embed")
-def _embed(cfg: NemotronHConfig, params: Dict, tokens):
-    return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
+    return routed_sublayer(
+        cfg, x, layer["norm"]["scale"], m,
+        shared=lambda h: _relu2(h, m["shared"], cfg.dtype))
 
 
 def forward_hidden(cfg: NemotronHConfig, params: Dict, tokens,
@@ -365,47 +337,24 @@ def forward_hidden(cfg: NemotronHConfig, params: Dict, tokens,
     }
     if cfg.remat:
         mixers = {k: jax.checkpoint(f) for k, f in mixers.items()}
-    x = _embed(cfg, params, tokens)
+    x = embed(cfg, params, tokens)
     records = []
     for i, letter in enumerate(cfg.pattern):
         x = mixers[letter](params[f"layers_{i}"], x)
         if letter == "E":
             x, rec = x
             records.append(rec)
-    out: Dict[str, Any] = {"carrier": jnp.zeros((), jnp.float32)}
-    if records:
-        out = dict(
-            experts=jnp.stack([r["experts"] for r in records]),
-            loads=jnp.stack([r["loads"] for r in records]),
-            carrier=sum(r["carrier"] for r in records),
-        )
+    out = routing_record(records)
     return rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps), out
 
 
 def loss_terms(cfg: NemotronHConfig, params, tokens, targets,
                attn_fn: Optional[Callable] = None) -> Dict[str, Any]:
-    """``loss`` (the mean next-token cross entropy; the balance bias's
-    carrier adds 0) and beside it the routing ``experts`` and ``loads``;
-    per expert layer ``rows_held`` (assignments on this share's
-    experts), ``held_share`` (of all ``N·top_k``) and
-    ``load_max_over_mean``; the final-norm ``hidden`` states, for
-    whoever compares them per token."""
+    """``common.share_loss_terms`` of this model's forward pass, the cross
+    entropy through ``lm_head``."""
     h, rec = forward_hidden(cfg, params, tokens, attn_fn)
-    ce = ce_from_hidden(h, params["lm_head"]["kernel"], targets,
-                        cfg.xent_chunks)
-    loss = ce + rec.pop("carrier")
-    out = dict(rec, ce=ce, loss=loss, hidden=h)
-    if "loads" in rec:
-        loads = rec["loads"]
-        held = slice(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
-        rows_held = jnp.sum(loads[:, held], axis=-1)
-        out.update(
-            rows_held=rows_held,
-            held_share=rows_held / jnp.sum(loads, axis=-1),
-            load_max_over_mean=jnp.max(loads, axis=-1)
-            / jnp.mean(loads, axis=-1),
-        )
-    return out
+    return share_loss_terms(cfg, h, rec, ce_from_hidden(
+        h, params["lm_head"]["kernel"], targets, cfg.xent_chunks))
 
 
 def loss_fn(cfg: NemotronHConfig, params, tokens, targets,

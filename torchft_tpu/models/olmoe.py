@@ -38,7 +38,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.common import rms_norm
+from torchft_tpu.models.common import embed, rms_norm
 from torchft_tpu.models.llama import _rope
 from torchft_tpu.models.transformer import (
     _local_causal_attention,
@@ -185,11 +185,6 @@ def _block(cfg: OlmoeConfig, layer: Dict, x, *, attn_fn):
     return _moe_sublayer(cfg, layer, x)
 
 
-@jax.named_scope("embed")
-def _embed(cfg: OlmoeConfig, params: Dict, tokens):
-    return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
-
-
 def forward_hidden(cfg: OlmoeConfig, params: Dict, tokens,
                    attn_fn: Optional[Callable] = None) -> Tuple[Any, Dict]:
     """tokens [B, S] -> (final-norm hidden states [B, S, d], router
@@ -197,7 +192,7 @@ def forward_hidden(cfg: OlmoeConfig, params: Dict, tokens,
     ``experts`` [L, B*S, top_k])."""
     if attn_fn is None:
         attn_fn = _local_causal_attention
-    x = _embed(cfg, params, tokens)
+    x = embed(cfg, params, tokens)
     block = functools.partial(_block, cfg, attn_fn=attn_fn)
     if cfg.remat:
         block = jax.checkpoint(block)
