@@ -613,3 +613,141 @@ def test_causal_kernels_agree_across_regimes_bit_for_bit(
             atol=1e-5 if dtype == jnp.float32 else 2e-3, rtol=0,
             err_msg=name,
         )
+
+
+# ------------------------------------------------------------ PR 47 contracts
+# A window in the mask: (a) the two predicates and the band's closed form
+# against the element-wise mask, (b) the tables a streamed grid enumerates,
+# (c) the kernels over the band against the reference with the band mask.
+
+# windows smaller than, equal to, and no multiple of a tile edge; one that
+# is shorter than every tile and one a column short of the sequence
+_WINDOWS = [96, 128, 200, 1, 511]
+
+
+@pytest.mark.parametrize("window", _WINDOWS)
+@pytest.mark.parametrize(
+    "seq_len,block_q,block_k",
+    [(512, 128, 128), (512, 128, 256), (512, 256, 128), (768, 384, 256),
+     (768, 128, 384)],
+)
+def test_band_sweep_is_the_closed_form_of_the_tile_predicates(
+        seq_len, block_q, block_k, window) -> None:
+    from torchft_tpu.ops.flash import (
+        _band_sweep, _sweep_ends, _tile_full, _tile_live,
+    )
+
+    nq, nk = seq_len // block_q, seq_len // block_k
+    for rows, n_own, n_other in ((True, nq, nk), (False, nk, nq)):
+        for idx in range(n_own):
+            full, diagonal, trailing = (
+                range(int(lo), int(hi)) for lo, hi in
+                _band_sweep(idx, block_q, block_k, seq_len, rows, window)
+            )
+            live = []
+            for other in range(n_other):
+                qi, ki = (idx, other) if rows else (other, idx)
+                is_live = bool(_tile_live(qi, ki, block_q, block_k, window))
+                is_full = bool(_tile_full(qi, ki, block_q, block_k, window))
+                # the predicates against the element-wise mask itself
+                r, c = np.meshgrid(
+                    np.arange(qi * block_q, (qi + 1) * block_q),
+                    np.arange(ki * block_k, (ki + 1) * block_k),
+                    indexing="ij",
+                )
+                seen = (r >= c) & (r - c < window)
+                assert is_live == bool(seen.any())
+                assert is_full == bool(seen.all())
+                assert (other in full) == is_full
+                masked = (other in diagonal) + (other in trailing)
+                assert masked == (is_live and not is_full)
+                live += [other] * is_live
+            # the three loops run in the swept index's order
+            order = ([*trailing, *full, *diagonal] if rows
+                     else [*diagonal, *full, *trailing])
+            assert order == live
+            first, last = _sweep_ends(
+                idx, block_q, block_k, seq_len, rows, window)
+            assert (int(first), int(last)) == (live[0], live[-1])
+
+
+@pytest.mark.parametrize("rows", [True, False], ids=["rows", "columns"])
+@pytest.mark.parametrize("window", _WINDOWS)
+def test_live_tile_tables_of_the_band(window, rows) -> None:
+    from torchft_tpu.ops.flash import _grid_steps, _live_tiles, _tile_live
+
+    seq_len, block_q, block_k = 768, 128, 256
+    q_of, k_of = _live_tiles(seq_len, block_q, block_k, rows, window)
+    listed = list(zip(q_of.tolist(), k_of.tolist()))
+    live = {(qi, ki) for qi in range(seq_len // block_q)
+            for ki in range(seq_len // block_k)
+            if _tile_live(qi, ki, block_q, block_k, window)}
+    assert len(listed) == len(set(listed)) and set(listed) == live
+    assert listed == sorted(listed, key=lambda t: t if rows else t[::-1])
+    assert _grid_steps(seq_len, block_q, block_k, window)[0] == len(live)
+
+
+def test_grid_steps_of_the_band_at_the_cells_call() -> None:
+    from torchft_tpu.ops.flash import _choose_blocks, _grid_steps
+
+    # phi4flash's windowed call: 64-wide q and k, 128-wide v, 512 keys.
+    # K and V of a head stream (3.1 MB), and under a window the rule keeps
+    # the square tile: 31 of its 256 are the band, where the causal call
+    # takes 72 tiles twice as wide
+    blocks = _choose_blocks(8192, 64, 2, v_dim=128, window=512)
+    assert blocks == (512, 512)
+    assert _grid_steps(8192, *blocks, 512) == (31, 256)
+    assert _choose_blocks(8192, 64, 2, v_dim=128) == (512, 1024)
+    assert _grid_steps(8192, 512, 1024) == (72, 128)
+
+
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+@pytest.mark.parametrize("widths", [(32, 32), (64, 128)],
+                         ids=["equal", "64-128"])
+@pytest.mark.parametrize("window", _WINDOWS[:3])
+def test_windowed_kernels_match_the_band_mask(window, widths,
+                                              regime) -> None:
+    # forward, dq and dkv over the band (resident loops, streamed tables)
+    # against the reference with the explicit band mask; unequal blocks,
+    # so a column sweep and a row sweep disagree on nothing
+    dqk, dv = widths
+    q, k = (_rand((1, 512, 2, dqk), i + 60) for i in range(2))
+    v, cot = (_rand((1, 512, 2, dv), i + 62) for i in range(2))
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=256, interpret=True,
+            _resident_kv_bytes=_REGIMES[regime], window=window,
+        )
+
+    def reference(q, k, v):
+        return reference_attention(q, k, v, causal=True, window=window)
+
+    got = (flash(q, k, v), *jax.grad(
+        lambda q, k, v: jnp.sum(flash(q, k, v) * cot), argnums=(0, 1, 2)
+    )(q, k, v))
+    want = (reference(q, k, v), *jax.grad(
+        lambda q, k, v: jnp.sum(reference(q, k, v) * cot), argnums=(0, 1, 2)
+    )(q, k, v))
+    np.testing.assert_allclose(
+        np.asarray(got[0]), np.asarray(want[0]), atol=2e-5, rtol=2e-5
+    )
+    for name, a, b in zip(("dq", "dk", "dv"), got[1:], want[1:]):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-4,
+            err_msg=f"{name} mismatch",
+        )
+
+
+def test_a_window_is_a_causal_matter() -> None:
+    q = _rand((1, 128, 1, 32), 70)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, causal=False, window=16, interpret=True)
+    # a window as long as the sequence is the causal call: same program
+    whole, causal = (str(jax.make_jaxpr(lambda q: flash_attention(
+        q, q, q, interpret=True, **kw))(q)) for kw in ({"window": 128}, {}))
+    assert whole == causal
+    # the band mask itself: one key a row is that key's value
+    out = flash_attention(q, q, q, window=1, interpret=True, block_q=64,
+                          block_k=64)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(q), atol=1e-6)

@@ -18,8 +18,10 @@ __all__ = ["causal_attention", "reference_attention"]
 
 
 def reference_attention(q, k, v, causal: bool = True,
-                        scale: Optional[float] = None):
-    """[B,S,H,D] einsum attention (fp32 softmax)."""
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """[B,S,H,D] einsum attention (fp32 softmax). ``window`` = W under
+    the causal mask: position ``t`` sees keys ``t − (W − 1) … t``."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     s = jnp.einsum(
@@ -28,14 +30,18 @@ def reference_attention(q, k, v, causal: bool = True,
     if causal:
         S = q.shape[1]
         mask = jnp.tril(jnp.ones((S, S), dtype=bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((S, S), dtype=bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
     return out
 
 
-def causal_attention(q, k, v, scale: Optional[float] = None):
-    """The local causal attention of the model zoo. The kernel is chosen
+def causal_attention(q, k, v, scale: Optional[float] = None,
+                     window: Optional[int] = None):
+    """The local causal attention of the model zoo (``window``: of the
+    last W keys only). The kernel is chosen
     from the backend alone: on a TPU the Mosaic flash kernel
     (ops/flash.py), everywhere else :func:`reference_attention`. A shape
     the kernel does not take raises its ``ValueError``, and a kernel the
@@ -44,5 +50,7 @@ def causal_attention(q, k, v, scale: Optional[float] = None):
     if jax.default_backend() == "tpu":
         from torchft_tpu.ops.flash import flash_attention
 
-        return flash_attention(q, k, v, causal=True, scale=scale)
-    return reference_attention(q, k, v, causal=True, scale=scale)
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               window=window)
+    return reference_attention(q, k, v, causal=True, scale=scale,
+                               window=window)

@@ -57,6 +57,15 @@ fetch nothing (PR 31) gained nothing, and not taking them (PR 45) gained
 [128, 8192, 192 / 128]), 0.6 - 1.4 us a step not taken. Without the mask
 the streamed grid is the rectangle of blocks, as it was.
 
+A window (``window=W``: row ``t`` sees columns ``t − (W − 1) … t``) gives
+the mask a trailing edge. ``_tile_live`` / ``_tile_full`` take it, so the
+streamed grid enumerates the band's tiles and nothing else (31 of 256 a
+head at S 8192, W 512, 512 x 512 tiles, where the causal call takes 72
+of 512 x 1024), and ``_band_sweep`` is the closed form of the resident
+sweeps' three ranges: tiles the trailing edge cuts, full tiles, tiles on
+the diagonal (all three as loops; the masked body applies both edges).
+``_choose_blocks`` keeps square tiles under a window.
+
 dK/dV recomputes its tile TRANSPOSED (Sᵀ = K·Qᵀ, [BK, BQ]): Pᵀ·dO and
 dSᵀ·Q are then plain matmuls and no [BQ, BK] tile goes through the
 transpose unit (``flash_dkv`` -20 % at 64-wide, -26 % at 128-wide heads).
@@ -113,14 +122,29 @@ _NEG_INF = -1e30  # avoid nan from (-inf) - (-inf) in the running max
 #   full  iff its last column is at or before its first row (no element
 #         masked: the body needs no iota, compare or select).
 # Live tiles that are not full straddle the diagonal and take the mask.
+# Under a window of W keys (row t sees columns t − (W − 1) … t) the mask
+# has a second, trailing edge W − 1 columns behind the diagonal: a tile is
+#   live  iff, besides, its last column is at or after its first row's
+#         earliest key, and
+#   full  iff, besides, its first column is at or after its last row's.
+# Live tiles that are not full straddle either edge (both, where W is
+# shorter than a tile) and take the mask of both.
 
 
-def _tile_live(qi, ki, block_q: int, block_k: int):
-    return ki * block_k < (qi + 1) * block_q
+def _tile_live(qi, ki, block_q: int, block_k: int,
+               window: Optional[int] = None):
+    live = ki * block_k < (qi + 1) * block_q
+    if window is None:
+        return live
+    return live & ((ki + 1) * block_k - 1 >= qi * block_q - (window - 1))
 
 
-def _tile_full(qi, ki, block_q: int, block_k: int):
-    return (ki + 1) * block_k - 1 <= qi * block_q
+def _tile_full(qi, ki, block_q: int, block_k: int,
+               window: Optional[int] = None):
+    full = (ki + 1) * block_k - 1 <= qi * block_q
+    if window is None:
+        return full
+    return full & (ki * block_k >= (qi + 1) * block_q - window)
 
 
 def _causal_sweep(idx, block_q: int, block_k: int, seq_len: int,
@@ -147,18 +171,58 @@ def _causal_sweep(idx, block_q: int, block_k: int, seq_len: int,
     return (full_start, num_q_blocks), (live_start, full_start)
 
 
-def _sweep_ends(idx, block_q: int, block_k: int, seq_len: int, rows: bool):
+def _band_sweep(idx, block_q: int, block_k: int, seq_len: int, rows: bool,
+                window: int):
+    """:func:`_causal_sweep` under a window: the three loop ranges
+    ``(full, diagonal, trailing)`` of one sweep of the band — the closed
+    forms of the two predicates with both edges. ``trailing`` are the live
+    tiles that only the window's edge masks (before the full ones along a
+    row, after them along a column); where no tile is full the other two
+    ranges meet and every live tile takes the mask."""
+    num_q, num_k = seq_len // block_q, seq_len // block_k
+    if rows:
+        first_row, past_row = idx * block_q, (idx + 1) * block_q
+        live_start = jnp.maximum(
+            (jnp.maximum(first_row - window + 2, 0) + block_k - 1)
+            // block_k - 1, 0)
+        live_end = jnp.minimum(num_k, (past_row + block_k - 1) // block_k)
+        full_start = (jnp.maximum(past_row - window, 0) + block_k - 1
+                      ) // block_k
+        full_end = (first_row + 1) // block_k
+        full_start = jnp.clip(full_start, live_start, live_end)
+        full_end = jnp.clip(full_end, full_start, live_end)
+        return ((full_start, full_end), (full_end, live_end),
+                (live_start, full_start))
+    first_col, past_col = idx * block_k, (idx + 1) * block_k
+    live_start = first_col // block_q
+    live_end = jnp.minimum(num_q, (past_col + window - 2) // block_q + 1)
+    full_start = jnp.minimum(live_end, (past_col + block_q - 2) // block_q)
+    full_end = jnp.clip((first_col + window) // block_q, full_start, live_end)
+    return ((full_start, full_end), (live_start, full_start),
+            (full_end, live_end))
+
+
+def _sweep_ends(idx, block_q: int, block_k: int, seq_len: int, rows: bool,
+                window: Optional[int] = None):
     """``(first, last)`` block of one causal sweep's live tiles: the k
     blocks of q block ``idx`` (``rows``) or the q blocks of k block ``idx``,
-    from :func:`_causal_sweep`'s ranges."""
+    from :func:`_causal_sweep`'s ranges (:func:`_band_sweep`'s under a
+    window)."""
+    if window is not None:
+        _, diagonal, trailing = _band_sweep(
+            idx, block_q, block_k, seq_len, rows, window)
+        return ((trailing[0], diagonal[1] - 1) if rows
+                else (diagonal[0], trailing[1] - 1))
     full, diagonal = _causal_sweep(idx, block_q, block_k, seq_len, rows)
     return ((full[0], diagonal[1] - 1) if rows
             else (diagonal[0], full[1] - 1))
 
 
-def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool):
+def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool,
+                window: Optional[int] = None):
     """``(q_of, k_of)``: the q block and the k block of the t-th live tile
-    under the causal mask, as two ``int32`` tables — row-major (a q block's
+    under the causal mask (the band's, under a window), as two ``int32``
+    tables — row-major (a q block's
     k blocks ascending: forward and dq) or, ``rows`` false, column-major (a
     k block's q blocks ascending: dkv). What a streamed causal grid
     enumerates; :func:`_tile_live` alone decides which tiles are in it."""
@@ -167,20 +231,22 @@ def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool):
     )
     if not rows:
         q_of, k_of = q_of.T, k_of.T
-    live = _tile_live(q_of, k_of, block_q, block_k)
+    live = _tile_live(q_of, k_of, block_q, block_k, window)
     return q_of[live], k_of[live]
 
 
-def _grid_steps(seq_len: int, block_q: int, block_k: int) -> Tuple[int, int]:
+def _grid_steps(seq_len: int, block_q: int, block_k: int,
+                window: Optional[int] = None) -> Tuple[int, int]:
     """``(live, rectangular)`` grid steps a head of one streamed sweep at
     these tiles: what a causal call takes and what a call without the mask
-    does (72 and 128 at S 8192 with 512 x 1024 tiles)."""
-    return (len(_live_tiles(seq_len, block_q, block_k, True)[0]),
+    does (72 and 128 at S 8192 with 512 x 1024 tiles; under a window of
+    512 keys 31 of the 256 tiles of 512 x 512)."""
+    return (len(_live_tiles(seq_len, block_q, block_k, True, window)[0]),
             (seq_len // block_q) * (seq_len // block_k))
 
 
 def _sweep(idx, block_q: int, block_k: int, seq_len: int, causal: bool,
-           rows: bool, tile, carry):
+           rows: bool, tile, carry, window: Optional[int] = None):
     """Run ``tile(i, carry, masked=...)`` over every live tile of a row or
     a column of tiles: a loop of unmasked bodies over the full tiles, and
     the masked body on the diagonal ones. Where one block edge divides the
@@ -194,6 +260,16 @@ def _sweep(idx, block_q: int, block_k: int, seq_len: int, causal: bool,
             0, seq_len // other, functools.partial(tile, masked=False),
             carry,
         )
+    if window is not None:
+        # the band: three loops in the order of the swept index
+        full, diagonal, trailing = _band_sweep(
+            idx, block_q, block_k, seq_len, rows, window)
+        for bounds, masked in (
+                (trailing if rows else diagonal, True), (full, False),
+                (diagonal if rows else trailing, True)):
+            carry = jax.lax.fori_loop(
+                *bounds, functools.partial(tile, masked=masked), carry)
+        return carry
     full, diagonal = _causal_sweep(idx, block_q, block_k, seq_len, rows)
     carry = jax.lax.fori_loop(
         *full, functools.partial(tile, masked=False), carry
@@ -214,10 +290,12 @@ def _f32(ref_slice):
     return ref_slice.astype(jnp.float32)
 
 
-def _scores(q, k, qi, ki, masked: bool, transposed: bool = False):
+def _scores(q, k, qi, ki, masked: bool, transposed: bool = False,
+            window: Optional[int] = None):
     """S = Q·Kᵀ for one tile ([BQ, BK]; ``transposed``: Sᵀ = K·Qᵀ,
     [BK, BQ]); q already carries the softmax scale. ``masked`` adds the
-    causal mask (diagonal tiles only)."""
+    causal mask (diagonal tiles only) and, under a ``window``, its
+    trailing edge."""
     a, b = (k, q) if transposed else (q, k)
     s = jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())),
@@ -231,13 +309,19 @@ def _scores(q, k, qi, ki, masked: bool, transposed: bool = False):
         k_pos = ki * k.shape[0] + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1 - q_axis
         )
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = keep & (q_pos - k_pos < window)
+        s = jnp.where(keep, s, _NEG_INF)
     return s
 
 
-def _fwd_tile(q, k, v, acc, m, l, qi, ki, masked: bool):
-    """One online-softmax update: (acc, m, l) after tile (qi, ki)."""
-    s = _scores(q, k, qi, ki, masked)
+def _fwd_tile(q, k, v, acc, m, l, qi, ki, masked: bool,
+              window: Optional[int] = None):
+    """One online-softmax update: (acc, m, l) after tile (qi, ki). A row
+    that a window masks whole in its first tile accumulates at ``m =
+    _NEG_INF``; the first score it sees clears that (``alpha`` = 0)."""
+    s = _scores(q, k, qi, ki, masked, window=window)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
@@ -250,20 +334,22 @@ def _fwd_tile(q, k, v, acc, m, l, qi, ki, masked: bool):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
-                  block_k: int, seq_len: int, causal: bool, scale: float):
+                  block_k: int, seq_len: int, causal: bool, scale: float,
+                  window: Optional[int] = None):
     qi = pl.program_id(1)
     q = _f32(q_ref[0]) * scale  # [BQ, Dqk]
 
     def tile(ki, carry, masked):
         k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
         v = _f32(v_ref[0, pl.ds(ki * block_k, block_k), :])
-        return _fwd_tile(q, k, v, *carry, qi, ki, masked)
+        return _fwd_tile(q, k, v, *carry, qi, ki, masked, window)
 
     acc, m, l = _sweep(
         qi, block_q, block_k, seq_len, causal, True, tile,
         (jnp.zeros((block_q, v_ref.shape[-1]), dtype=jnp.float32),
          jnp.full((block_q, 1), _NEG_INF, dtype=jnp.float32),
          jnp.zeros((block_q, 1), dtype=jnp.float32)),
+        window=window,
     )
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -271,7 +357,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
 
 def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
-                   causal: bool, rows: bool):
+                   causal: bool, rows: bool, window: Optional[int] = None):
     """Streamed regime: which tile this grid step computes. Returns ``(qi,
     ki, first, last, refs)``: the tile, the first and the last value the
     swept index (``ki`` of a row sweep, ``qi`` of a column sweep) takes in
@@ -289,24 +375,26 @@ def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
     step = pl.program_id(1)
     qi, ki = q_of[step], k_of[step]
     first, last = _sweep_ends(
-        qi if rows else ki, block_q, block_k, seq_len, rows
+        qi if rows else ki, block_q, block_k, seq_len, rows,
+        window=window
     )
     return qi, ki, first, last, refs
 
 
-def _full_or_masked(qi, ki, block_q: int, block_k: int, causal: bool, tile):
+def _full_or_masked(qi, ki, block_q: int, block_k: int, causal: bool, tile,
+                    window: Optional[int] = None):
     """Streamed regime: run ``tile(masked)`` for the live tile (qi, ki) —
-    masked on the diagonal, unmasked below it."""
+    masked on the diagonal (and on a window's edge), unmasked between."""
     if not causal:
         tile(False)
         return
-    full = _tile_full(qi, ki, block_q, block_k)
+    full = _tile_full(qi, ki, block_q, block_k, window=window)
     pl.when(full)(functools.partial(tile, False))
     pl.when(jnp.logical_not(full))(functools.partial(tile, True))
 
 
 def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
-                   causal: bool, rows: bool):
+                   causal: bool, rows: bool, window: Optional[int] = None):
     """A streamed call's ``(grid, tables, by_q, by_k, q_lanes)``: the grid,
     the scalar-prefetch operands and the index maps of a block of q rows
     ([.., BQ, D]), of k rows and of q positions along the lanes ([.., 1,
@@ -315,7 +403,8 @@ def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
     axis enumerates :func:`_live_tiles` and the maps read the tile's blocks
     off the two tables."""
     if causal:
-        tables = _live_tiles(seq_len, block_q, block_k, rows)
+        tables = _live_tiles(seq_len, block_q, block_k, rows,
+                             window=window)
         return (
             (bh, len(tables[0])), tuple(jnp.asarray(t) for t in tables),
             lambda b, t, q_of, k_of: (b, q_of[t], 0),
@@ -333,12 +422,13 @@ def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
 
 
 def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
-                           causal: bool, scale: float):
+                           causal: bool, scale: float,
+                           window: Optional[int] = None):
     """K-blocks ride the innermost grid dimension: only (block_k, d) K/V
     tiles are VMEM-resident at a time, so sequence length is bounded by
     HBM, not VMEM. acc/m/l live in VMEM scratch across the k sweep."""
     qi, ki, first, last, refs = _streamed_tile(
-        refs, block_q, block_k, seq_len, causal, True
+        refs, block_q, block_k, seq_len, causal, True, window=window
     )
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
@@ -352,12 +442,14 @@ def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
         acc, m, l = _fwd_tile(
             _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
             acc_ref[...], m_ref[:, :1], l_ref[:, :1], qi, ki, masked,
+            window=window,
         )
         acc_ref[...] = acc
         m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
 
-    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
+                    window=window)
 
     @pl.when(ki == last)
     def _finalize():
@@ -374,7 +466,8 @@ _RESIDENT_KV_BYTES = 2 * 1024 * 1024
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool,
-                   resident_kv_bytes: Optional[int] = None):
+                   resident_kv_bytes: Optional[int] = None,
+                   window: Optional[int] = None):
     """q, k: [BH, S, Dqk], v: [BH, S, Dv] -> (out [BH, S, Dv], lse
     [BH, S] f32)."""
     bh, seq_len, d = q.shape
@@ -396,6 +489,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             seq_len=seq_len,
             causal=causal,
             scale=scale,
+            window=window,
         )
         out, lse = pl.pallas_call(
             kernel,
@@ -417,7 +511,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 
     # Long context: stream K/V tiles via the grid.
     grid, tables, by_q, by_k, _ = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, True
+        bh, seq_len, block_q, block_k, causal, True, window=window
     )
     kernel = functools.partial(
         _flash_streamed_kernel,
@@ -426,6 +520,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         seq_len=seq_len,
         causal=causal,
         scale=scale,
+        window=window,
     )
     scratch = [
         pltpu.VMEM((block_q, dv), jnp.float32),
@@ -464,14 +559,15 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked: bool,
-              transposed: bool = False):
+              transposed: bool = False, window: Optional[int] = None):
     """Shared score recompute for every backward kernel: P = exp(S − lse)
     (masked on diagonal tiles) and dS = P ⊙ (dO·Vᵀ − Δ), both f32 [BQ, BK]
     with lse and delta as [BQ, 1] columns — or, ``transposed``, Pᵀ and dSᵀ
     [BK, BQ] with lse and delta as [1, BQ] rows. One definition so
     mask/softmax changes can never diverge between kernels or regimes.
     q carries the softmax scale."""
-    p = jnp.exp(_scores(q, k, qi, ki, masked, transposed) - lse)
+    p = jnp.exp(
+        _scores(q, k, qi, ki, masked, transposed, window=window) - lse)
     a, b = (v, do) if transposed else (do, v)
     dp = jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())),
@@ -480,23 +576,27 @@ def _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked: bool,
     return p, p * (dp - delta)
 
 
-def _dq_tile(q, k, v, do, lse, delta, qi, ki, masked: bool):
+def _dq_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
+             window: Optional[int] = None):
     """This tile's term of dQ/scale: dS·K, f32 [BQ, D]."""
-    _, ds = _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked)
+    _, ds = _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked,
+                      window=window)
     return jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
 
-def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool):
+def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
+              window: Optional[int] = None):
     """This tile's terms of (dK, dV): dSᵀ·Q (q carries the scale, so this
     is dL/dK itself) and Pᵀ·dO, f32 [BK, D]. The tile is recomputed
     TRANSPOSED (lse and delta arrive as [1, BQ] rows), so both products
     are plain row-by-column matmuls and nothing [BQ, BK]-shaped goes
     through the transpose unit."""
     pt, dst = _bwd_p_ds(
-        q, k, v, do, lse, delta, qi, ki, masked, transposed=True
+        q, k, v, do, lse, delta, qi, ki, masked, transposed=True,
+        window=window
     )
     dk = jax.lax.dot_general(
         dst, q, (((1,), (0,)), ((), ())),
@@ -511,7 +611,8 @@ def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool):
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, block_q: int, block_k: int,
-                         seq_len: int, causal: bool, scale: float):
+                         seq_len: int, causal: bool, scale: float,
+                         window: Optional[int] = None):
     qi = pl.program_id(1)
     q = _f32(q_ref[0]) * scale                    # [BQ, Dqk]
     do = _f32(do_ref[0])                          # [BQ, Dv]
@@ -521,18 +622,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def tile(ki, dq, masked):
         k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
         v = _f32(v_ref[0, pl.ds(ki * block_k, block_k), :])
-        return dq + _dq_tile(q, k, v, do, lse, delta, qi, ki, masked)
+        return dq + _dq_tile(q, k, v, do, lse, delta, qi, ki, masked,
+                             window=window)
 
     dq = _sweep(
         qi, block_q, block_k, seq_len, causal, True, tile,
-        jnp.zeros(q.shape, dtype=jnp.float32),
+        jnp.zeros(q.shape, dtype=jnp.float32), window=window,
     )
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, block_k: int,
-                          seq_len: int, causal: bool, scale: float):
+                          seq_len: int, causal: bool, scale: float,
+                          window: Optional[int] = None):
     ki = pl.program_id(1)
     k = _f32(k_ref[0])                            # [BK, Dqk]
     v = _f32(v_ref[0])                            # [BK, Dv]
@@ -542,7 +645,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = _dkv_tile(
             _f32(q_ref[0, rows, :]) * scale, k, v, _f32(do_ref[0, rows, :]),
             lse_ref[0, :, rows], delta_ref[0, :, rows],   # [1, BQ]
-            qi, ki, masked,
+            qi, ki, masked, window=window,
         )
         return carry[0] + dk, carry[1] + dv
 
@@ -553,17 +656,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # every call with Dqk == Dv stays what it was, to the instruction
         (zeros, zeros if v.shape == k.shape
          else jnp.zeros(v.shape, dtype=jnp.float32)),
+        window=window,
     )
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
-                                  seq_len: int, causal: bool, scale: float):
+                                  seq_len: int, causal: bool, scale: float,
+                                  window: Optional[int] = None):
     """K/V tiles ride the innermost grid dim (long-context regime); dq
     accumulates in VMEM scratch across the k sweep."""
     qi, ki, first, last, refs = _streamed_tile(
-        refs, block_q, block_k, seq_len, causal, True
+        refs, block_q, block_k, seq_len, causal, True, window=window
     )
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
 
@@ -575,9 +680,11 @@ def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
         dq_acc[...] = dq_acc[...] + _dq_tile(
             _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
             _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+            window=window,
         )
 
-    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
+                    window=window)
 
     @pl.when(ki == last)
     def _finalize():
@@ -585,11 +692,12 @@ def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
 
 
 def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
-                                   seq_len: int, causal: bool, scale: float):
+                                   seq_len: int, causal: bool, scale: float,
+                                   window: Optional[int] = None):
     """Q/dO tiles ride the innermost grid dim; dk/dv accumulate in VMEM
     scratch across the q sweep."""
     qi, ki, first, last, refs = _streamed_tile(
-        refs, block_q, block_k, seq_len, causal, False
+        refs, block_q, block_k, seq_len, causal, False, window=window
     )
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
      dv_acc) = refs
@@ -603,11 +711,13 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
         dk, dv = _dkv_tile(
             _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
             _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+            window=window,
         )
         dk_acc[...] = dk_acc[...] + dk
         dv_acc[...] = dv_acc[...] + dv
 
-    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate)
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
+                    window=window)
 
     @pl.when(qi == last)
     def _finalize():
@@ -617,7 +727,7 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
 
 def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
                              scale: float, block_q: int, block_k: int,
-                             interpret: bool):
+                             interpret: bool, window: Optional[int] = None):
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
     # tile-legal views of the [BH, S] statistics (module docstring):
@@ -626,12 +736,13 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
     delta, delta_row = delta[..., None], delta[:, None, :]
 
     grid, tables, by_q, by_k, _ = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, True
+        bh, seq_len, block_q, block_k, causal, True, window=window
     )
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_streamed_kernel, block_q=block_q,
             block_k=block_k, seq_len=seq_len, causal=causal, scale=scale,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
@@ -653,12 +764,13 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
     )(*tables, q, k, v, g, lse, delta)
 
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, False
+        bh, seq_len, block_q, block_k, causal, False, window=window
     )
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_streamed_kernel, block_q=block_q,
             block_k=block_k, seq_len=seq_len, causal=causal, scale=scale,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
@@ -692,21 +804,23 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, scale: float,
                     block_q: int, block_k: int, interpret: bool,
-                    resident_kv_bytes: Optional[int] = None):
+                    resident_kv_bytes: Optional[int] = None,
+                    window: Optional[int] = None):
     """Fused pallas backward: delta from (out, g), then the kernel core."""
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )  # [BH, S]
     return _flash_backward_core(
         q, k, v, g, lse, delta, causal, scale, block_q, block_k,
-        interpret, resident_kv_bytes,
+        interpret, resident_kv_bytes, window=window,
     )
 
 
 def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
                          scale: float, block_q: int, block_k: int,
                          interpret: bool,
-                         resident_kv_bytes: Optional[int] = None):
+                         resident_kv_bytes: Optional[int] = None,
+                         window: Optional[int] = None):
     """Kernel core with EXTERNAL lse/delta ([BH, S] f32): resident variant
     (full K/V resp. Q/dO in VMEM) below the threshold, streamed tiles
     above it. External statistics are what make the ring backward work —
@@ -721,7 +835,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     if kv_bytes > threshold:
         return _flash_backward_streamed(
             q, k, v, g, lse, delta, causal, scale, block_q, block_k,
-            interpret,
+            interpret, window=window,
         )
     # tile-legal views of the [BH, S] statistics (module docstring):
     # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
@@ -730,7 +844,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-        seq_len=seq_len, causal=causal, scale=scale,
+        seq_len=seq_len, causal=causal, scale=scale, window=window,
     )
     dq = pl.pallas_call(
         dq_kernel,
@@ -751,7 +865,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
 
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-        seq_len=seq_len, causal=causal, scale=scale,
+        seq_len=seq_len, causal=causal, scale=scale, window=window,
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -789,31 +903,31 @@ def _reference(q, k, v, causal: bool, scale: float):
     return out[:, :, 0].astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-           resident_kv_bytes):
+           resident_kv_bytes, window=None):
     out, _ = _flash_forward(
         q, k, v, causal, scale, block_q, block_k, interpret,
-        resident_kv_bytes,
+        resident_kv_bytes, window=window,
     )
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               resident_kv_bytes):
+               resident_kv_bytes, window):
     out, lse = _flash_forward(
         q, k, v, causal, scale, block_q, block_k, interpret,
-        resident_kv_bytes,
+        resident_kv_bytes, window=window,
     )
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret,
-               resident_kv_bytes, residuals, g):
+               resident_kv_bytes, window, residuals, g):
     q, k, v, out, lse = residuals
     return _flash_backward(
         q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret,
-        resident_kv_bytes,
+        resident_kv_bytes, window=window,
     )
 
 
@@ -865,15 +979,20 @@ def _vmem_estimate(seq_len: int, head_dim: int, itemsize: int,
 def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
                    block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
-                   v_dim: Optional[int] = None) -> Tuple[int, int]:
+                   v_dim: Optional[int] = None,
+                   window: Optional[int] = None) -> Tuple[int, int]:
     """``(block_q, block_k)`` as a pure function of the shape: the first
     tile of ``_TILES`` (``_STREAMED_TILES`` where the kernels stream) whose
     edges divide ``seq_len`` and whose :func:`_vmem_estimate` fits
     ``_VMEM_BUDGET`` (the smallest edge when none does, or the whole of a
     shorter sequence). An explicit argument wins over the rule and is
-    only clamped to the sequence."""
+    only clamped to the sequence. Under a ``window`` the tiles are the
+    square ones in either regime: a k edge of 1024 is live for every q
+    block whose window touches it, and at 512 keys that doubles the
+    band's arithmetic."""
     pair = head_dim + (head_dim if v_dim is None else v_dim)
-    tiles = _TILES if _resident(seq_len, pair, itemsize) else _STREAMED_TILES
+    tiles = (_TILES if window is not None
+             or _resident(seq_len, pair, itemsize) else _STREAMED_TILES)
     q_edge = k_edge = min(_TILES[-1][0], seq_len)
     for cand_q, cand_k in tiles:
         if (seq_len % cand_q == 0 and seq_len % cand_k == 0
@@ -885,7 +1004,7 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
             k_edge if block_k is None else min(block_k, seq_len))
 
 
-def _bshd_prologue(q, v, scale, block_q, block_k):
+def _bshd_prologue(q, v, scale, block_q, block_k, window=None):
     """Shared [B,S,H,D]-surface plumbing: scale default (from q's
     width), block choice (from the shape where the caller gave none) and
     clamping, divisibility validation, and the [B,S,H,D] <-> [B*H,S,D]
@@ -895,7 +1014,7 @@ def _bshd_prologue(q, v, scale, block_q, block_k):
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     block_q, block_k = _choose_blocks(
-        s, d, q.dtype.itemsize, block_q, block_k, v.shape[-1]
+        s, d, q.dtype.itemsize, block_q, block_k, v.shape[-1], window
     )
     if s % block_q or s % block_k:
         raise ValueError(
@@ -978,10 +1097,17 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    _resident_kv_bytes: Optional[int] = None):
+                    _resident_kv_bytes: Optional[int] = None,
+                    window: Optional[int] = None):
     """Flash attention (pallas on TPU): q, k ``[B, S, H, Dqk]``, v
     ``[B, S, H, Dv]`` -> ``[B, S, H, Dv]``; the softmax scale defaults to
     ``1 / sqrt(Dqk)``.
+
+    ``window`` = W (causal calls only): position ``t`` sees keys ``t − (W
+    − 1) … t``, W with itself. The sweeps then run over the band's tiles
+    alone (module docstring); a window as long as the sequence is the
+    causal call itself, and ``None`` traces to the program it always
+    traced to.
 
     ``block_q`` / ``block_k`` left ``None`` are chosen from the shape
     (:func:`_choose_blocks`). Sequence length must be a multiple of the
@@ -993,9 +1119,16 @@ def flash_attention(q, k, v, causal: bool = True,
     chip_smoke.py and the tests to run both regimes at one shape without
     touching shared state.
     """
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"flash attention: a window ({window}) is a positive "
+                f"number of keys under the causal mask")
+        if window >= q.shape[1]:
+            window = None
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, v, scale, block_q, block_k
+        q, v, scale, block_q, block_k, window
     )
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
-                 block_q, block_k, interpret, _resident_kv_bytes)
+                 block_q, block_k, interpret, _resident_kv_bytes, window)
     return unmerge(out)
