@@ -144,14 +144,149 @@ def test_the_chunk_and_what_is_refused() -> None:
         kda_scan(q, k, v, g, beta[:, :8])
 
 
+# -- several heads a grid step ------------------------------------------------
+
+
+def both_ways(args, do, chunk):
+    """``(o, dq, dk, dv, dg, dβ)`` of ``kda._kda`` at ``chunk``."""
+    o, pull = jax.vjp(
+        lambda *a: kda._kda(*a, chunk, kda._interpret()), *args)
+    return (o,) + pull(do)
+
+
+@pytest.fixture
+def ladder(monkeypatch):
+    """Sets the rungs ``ops/kda.py`` may choose from. jit keeps a traced
+    body a shape, so whoever moves the ladder clears jax's caches."""
+    def to(*rungs):
+        monkeypatch.setattr(kda, "_LADDER", rungs)
+        jax.clear_caches()
+    yield to
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("heads,b,s,h,chunk", [
+    (2, 1, 40, 4, 16),       # a ragged end
+    (4, 1, 40, 4, 16),
+    (2, 2, 48, 8, 16),       # two batch rows
+    (4, 2, 48, 8, 16),
+    (2, 1, 256, 4, 128),     # the cell's chunk
+    (4, 1, 256, 4, 128),
+    (4, 2, 200, 8, 128),     # two rows, a ragged end
+    (2, 2, 200, 8, 128),
+])
+def test_heads_that_share_a_step_change_no_bit(ladder, heads, b, s, h, chunk):
+    """A head's mathematics does not know what else its grid step holds:
+    ``o`` and the five gradients are the one-head-a-step kernels' bit for
+    bit."""
+    args, do = inputs(s + h, b, s, h, 16, 32, 0.3)
+    ladder(1)
+    assert kda._heads_a_step(h, chunk, 16, 32) == 1
+    want = both_ways(args, do, chunk)
+    ladder(heads)
+    assert kda._heads_a_step(h, chunk, 16, 32) == heads
+    got = both_ways(args, do, chunk)
+    for name, a, w in zip(("o",) + LEAVES, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w), name)
+
+
+@pytest.mark.parametrize("h", [3, 5, 6])
+def test_a_head_count_takes_the_largest_rung_that_divides_it(h) -> None:
+    """3 and 5 heads: no rung but 1; 6: two a step, not four. Each is
+    the recurrence."""
+    args, do = inputs(h, 1, 48, h, 16, 16)
+    assert kda._heads_a_step(h, 16, 16, 16) == {3: 1, 5: 1, 6: 2}[h]
+    with jax.default_matmul_precision("highest"):
+        want, pull_ref = jax.vjp(kda_recurrence, *args)
+        got, pull = jax.vjp(lambda *a: scan(*a, chunk=16), *args)
+        grads, grads_ref = pull(do), pull_ref(do)
+    assert rel(got, want) < 2e-6
+    for name, a, b in zip(LEAVES, grads, grads_ref):
+        assert rel(a, b) < 5e-6, name
+
+
+def _grids(fn, *args):
+    """``{kernel's name: its grid}`` over the ``pallas_call``s of ``fn``'s
+    jaxpr, nested ones too."""
+    seen = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                seen[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return seen
+
+
+def test_the_rule_and_the_grid_at_the_cells_shape() -> None:
+    """``kimi-ep32-solo-steady``'s call, ``[4, 8192]`` of 32 heads of 128:
+    four heads a step (PERF.md section 6, PR 48), a grid of ``(b, h / 4,
+    nc)`` = 2 048 steps a call both ways; the cell's own check (one row of
+    2048) takes its heads from the head axis too. Wider heads take fewer
+    a step: VMEM."""
+    assert kda._LADDER == (4, 2, 1)
+    assert kda._heads_a_step(32, 128, 128, 128) == 4
+    assert kda._heads_a_step(32, 128, 256, 256) == 4
+    assert kda._heads_a_step(32, 128, 512, 512) == 2
+    assert kda._heads_a_step(6, 128, 128, 128) == 2
+    assert [kda._heads_a_step(h, 16, 16, 16) for h in (1, 2, 3, 4, 5)] == [
+        1, 2, 1, 4, 1]
+
+    def shapes(b, s):
+        wide = jax.ShapeDtypeStruct((b, s, 32, 128), jnp.bfloat16)
+        return (wide, wide, wide,
+                jax.ShapeDtypeStruct((b, s, 32, 128), jnp.float32),
+                jax.ShapeDtypeStruct((b, s, 32), jnp.float32))
+
+    for b, s in ((4, 8192), (1, 2048)):
+        grids = _grids(
+            lambda *a: jax.vjp(kda_scan, *a[:-1])[1](a[-1]),
+            *shapes(b, s), jax.ShapeDtypeStruct((b, s, 32, 128),
+                                                jnp.bfloat16))
+        assert grids == {"kda_fwd": (b, 8, s // 128),
+                         "kda_bwd": (b, 8, s // 128)}
+
+
+def test_a_call_site_traces_no_kernel_body_again() -> None:
+    """The builders stand under an inner ``jax.jit``: the second layer of
+    a shape finds the first's traced body (a body of four heads is four
+    times the Python), and the kernels keep their names in the scope
+    path the benchmark's reader files them by."""
+    args, do = inputs(9, 1, 32, 2, 16, 16)
+    calls = []
+    plain = kda._kda_fwd_kernel
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return plain(*a, **kw)
+
+    def two_layers(q, k, v, g, beta):
+        v = kda_scan(q, k, v, g, beta)
+        return kda_scan(q, k, v, g, beta)
+
+    kda._kda_fwd_kernel = counted
+    jax.clear_caches()
+    try:
+        text = str(jax.make_jaxpr(two_layers)(*args))
+    finally:
+        kda._kda_fwd_kernel = plain
+        jax.clear_caches()
+    assert text.count("name=kda_fwd") >= 1 and len(calls) == 1
+
+
 # -- the kernels at the cell's widths, for a described v5e --------------------
 
 
 def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
-    """[1, 1024] of 32 heads of 128 at the cell's chunk: Mosaic takes the
-    rolls, the tile reshapes, the transposes and the HIGHEST-precision
-    cumulative sums, and nothing ``[B, S, H, K, V]`` is planned (the
-    states are ``S / C`` of them)."""
+    """[1, 1024] of 32 heads of 128 at the cell's chunk and the cell's
+    four heads a grid step: Mosaic takes the rolls, the tile reshapes,
+    the transposes, the HIGHEST-precision cumulative sums and the VMEM
+    four heads' temporaries ask for (``_VMEM_LIMIT``), and nothing ``[B,
+    S, H, K, V]`` is planned (the states are ``S / C`` of them)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     b, s, h, d = 1, 1024, 32, 128
@@ -176,7 +311,9 @@ def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
     text = compiled.as_text()
-    assert "kda_fwd" in text and "kda_bwd" in text
+    assert kda._heads_a_step(h, kda._CHUNK, d, d) == 4
+    for kernel in ("kda_fwd", "kda_bwd"):
+        assert f"{kernel}/pallas_call" in text, kernel
     per_position_states = b * s * h * d * d * 4
     assert compiled.memory_analysis().temp_size_in_bytes < \
         per_position_states / 16

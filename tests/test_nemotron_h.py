@@ -368,6 +368,14 @@ def _jaxpr_hash(fn, *args):
 #   of PR 46, before models/common.py took the routed sublayer and the
 #   share's loss_terms. The scope readers of benchmark/readers classify
 #   device time by these paths.
+# - kimi again at PR 48: ops/kda.py's builders went under an inner
+#   jax.jit (four heads a grid step), so the two kernels' equations stand
+#   inside jit(_forward) / jit(_backward) and carry "kda_fwd" / "kda_bwd"
+#   where they carried "jvp(attn)/kda_core/kda_fwd" and
+#   "transpose(jvp(attn))/kda_core/kda_bwd" (the pjit equation carries
+#   the outer path; on the device the two join:
+#   .../kda_core/jit(_forward)/kda_fwd/pallas_call); the other 50 paths
+#   are those of 7be2396
 PROGRAMS_THAT_WERE = {
     "olmoe": (
         olmoe, olmoe.OLMOE_CONFIGS["olmoe_tiny"],
@@ -391,8 +399,8 @@ PROGRAMS_THAT_WERE = {
         32),
     "kimi": (
         kimi_linear, kimi_linear.KIMI_LINEAR_CONFIGS["kimi_linear_tiny"],
-        "faeea6a7fae84398adeb15fc473b60ea42db40b81079895570a8f3884ff02506",
-        "272f87412d31aeacffd6b18bcf1620de8d6b0d627e20c9e74087845f7b91364e",
+        "3f4d40d876f593fbc640dc37514f359cdf766ae0387aac0e1a21f0fd51d6cb77",
+        "04e92318800edc9b5a6588653c5d38b4e8999cd866d2e694e92aeecaf1d2ebbd",
         52),
 }
 

@@ -46,12 +46,21 @@ contracts. (The Neumann product ``(I − L)(I + L²)(I + L⁴)…`` is the same
 matrix on paper and cancels catastrophically: with correlated keys its
 terms reach ``e^{‖L‖}``.)
 
-The kernels. One grid step is one (batch, head, chunk), the chunks
-innermost and in order; the state is carried from step to step in a VMEM
-scratch, TRANSPOSED (``[V, K]``: the decay of a channel is then a lane's
-factor), in f32. No per-position state exists anywhere: nothing ``[B, S,
-H, K, V]`` is formed. ``kda_bwd`` walks the chunks LAST TO FIRST with
-the state's cotangent in the same scratch. **What the backward keeps**:
+The kernels. One grid step is one chunk of SEVERAL heads of one batch
+row (:func:`_heads_a_step`: four of the cell's 32), the chunks innermost
+and in order. A head's chunk is one chain — the cumulative sum, the pair
+sums, the fourteen matmuls of the inverse each of which needs the one
+before, ``R``, ``U``, ``o`` — and a step that held one head ran it on one
+of the chip's four MXUs with the vector unit waiting beside it: 2.5 µs a
+step, about what its matmuls take one after another. Heads share nothing,
+so a step's heads are written side by side (:func:`_side_by_side`) and
+each fills the others' waits; a head is a 128-lane slice of the step's
+blocks, and what it computes does not change by a bit. The states are
+carried from step to step in a VMEM scratch, TRANSPOSED (``[G, V, K]``:
+the decay of a channel is then a lane's factor), in f32. No per-position
+state exists anywhere: nothing ``[B, S, H, K, V]`` is formed. ``kda_bwd``
+walks the chunks LAST TO FIRST with the states' cotangents in the same
+scratch. **What the backward keeps**:
 the scan's inputs and the chunk-boundary states ``[B, H, S/C, V, K]``
 f32 (written by the forward kernel only when it runs as the vjp's
 forward rule; 537 MB a layer at 32 768 tokens, 32 heads and C 128; under
@@ -108,6 +117,14 @@ _TILE = 8        # rows of an f32 tile: pairs closer than this go by bands
 # positions a chunk; see the module docstring
 _CHUNK = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
+# heads a grid step, largest first: :func:`_heads_a_step`. Eight gain 0.6
+# ms a forward and 0.9 a backward call on four (of 14.2 / 24.0: PERF.md
+# section 6, PR 48) and cost a body twice as long to trace, once a call
+# shape in every process: 5 s of the cell's ``setup_s``, most of its bound
+_LADDER = (4, 2, 1)
+# what a kernel may take of the chip's 128 MiB of VMEM; ``kda_bwd`` at four
+# heads of 128 takes 15.23 MiB, 0.8 under what Mosaic allows unasked
+_VMEM_LIMIT = 32 << 20
 
 
 def _interpret() -> bool:
@@ -138,6 +155,20 @@ def _cross(row, col, lg: int):
     half of one block of ``2^(lg+1)`` positions."""
     rb, cb = row >> lg, col >> lg
     return (rb == cb + 1) & ((cb & 1) == 0)
+
+
+def _side_by_side(chains):
+    """Runs the generators ``chains`` in lockstep, each to its next
+    ``yield`` in turn, until all have ended. A kernel's body is
+    traced in that order, and Mosaic's scheduler keeps close to the order
+    it is given: four heads one AFTER the other in the body gain 2.8 ms a
+    forward call of 25.5, side by side 11.3 (PERF.md section 6, PR 48).
+    So a head's chain yields where its next matmul needs the last — at
+    every matmul of the inverse, between the stages elsewhere; finer
+    than that gained nothing — and the step's other heads stand there."""
+    live = list(chains)
+    while live:
+        live = [chain for chain in live if next(chain, False) is None]
 
 
 def _decays(g):
@@ -218,112 +249,147 @@ def _pairs_bwd(dms, xs, k, levels, bands):
 
 def _solve(lower):
     """``(I + lower)^{-1}`` of a strictly lower-triangular ``[C, C]`` by
-    block doubling (the module docstring)."""
+    block doubling (the module docstring): two matmuls a doubling, each
+    of which needs the one before (a generator: :func:`_side_by_side`)."""
     C = lower.shape[0]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     T = jnp.where(row == col, 1.0, 0.0)
     lg = 0
     while (1 << lg) < C:
-        between = jnp.where(_cross(row, col, lg), lower, 0.0)
-        T = T - _dot(_dot(T, between), T)
+        half = _dot(T, jnp.where(_cross(row, col, lg), lower, 0.0))
+        yield
+        T = T - _dot(half, T)
+        yield
         lg += 1
     return T
 
 
 def _chunk(q, k, v, g, beta, st):
-    """What both kernels compute of one chunk (f32 operands; ``beta [C,
-    1]``, ``st [V, K]`` the transposed state that enters)."""
+    """What both kernels compute of one chunk of one head (f32 operands;
+    ``beta [C, 1]``, ``st [V, K]`` the transposed state that enters; a
+    generator: :func:`_side_by_side`)."""
     C = q.shape[0]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     G, levels, bands = _decays(g)
+    yield
     A, B = _pairs([k, q], k, levels, bands)
+    yield
     B = B + jnp.where(row == col, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    T = _solve(beta * A)
+    T = yield from _solve(beta * A)
     last = G[C - 1:C, :]                                 # [1, K]
     gam, to_end = jnp.exp(G), jnp.exp(last - G)
     kbar, qbar, ktil = k * gam, q * gam, k * to_end
     R = v - _dot_nt(kbar, st)                            # [C, V]
+    yield
     U = _dot(T, beta * R)
+    yield
     return dict(levels=levels, bands=bands, A=A, B=B, T=T, last=last,
                 gam=gam, to_end=to_end, kbar=kbar, qbar=qbar, ktil=ktil,
                 R=R, U=U)
 
 
-def _beta_col(beta_ref):
-    """This head's column of the ``[C, H]`` block as ``[C, 1]`` (a
-    select and a lane sum: exact, and no one-lane slice)."""
+def _head(j: int, heads: int, kd: int, vd: int, q_ref, k_ref, v_ref, g_ref,
+          beta_ref):
+    """Head ``j`` of a step's ``heads``: its key and its value lanes in
+    the step's ``[1, C, G·width]`` blocks (aligned slices: free), ``q, k,
+    v, g`` in f32 and its ``β``, a column of the ``[C, H]`` block as ``[C,
+    1]`` (a select and a lane sum: exact, and no one-lane slice)."""
+    keys, values = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
     block = beta_ref[0, 0]
-    lane = _iota(block.shape, 1)
-    return jnp.sum(jnp.where(lane == pl.program_id(1), block, 0.0), axis=1,
-                   keepdims=True)
+    mine = _iota(block.shape, 1) == pl.program_id(1) * heads + j
+    beta = jnp.sum(jnp.where(mine, block, 0.0), axis=1, keepdims=True)
+    return keys, values, (_f32(q_ref[0, :, keys]), _f32(k_ref[0, :, keys]),
+                          _f32(v_ref[0, :, values]), g_ref[0, :, keys], beta)
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                    save_states: bool):
-    """One (batch, head, chunk): ``o`` of the chunk and the state it
-    leaves, the state it entered with written out for the backward where
-    asked."""
-    state = rest[-1]
+                    heads: int, save_states: bool):
+    """One (batch, group of ``heads`` heads, chunk): ``o`` of the chunk
+    and the states it leaves, the states it entered with written out for
+    the backward where asked."""
+    state = rest[-1]                                     # [G, V, K]
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         state[...] = jnp.zeros_like(state)
 
-    st = state[...]                                      # [V, K]
-    if save_states:
-        rest[0][0, 0, 0] = st
-    q, k, v = _f32(q_ref[0]), _f32(k_ref[0]), _f32(v_ref[0])
-    c = _chunk(q, k, v, g_ref[0], _beta_col(beta_ref), st)
-    o = _dot_nt(c["qbar"], st) + _dot(c["B"], c["U"])
-    o_ref[0] = o.astype(o_ref.dtype)
-    state[...] = st * jnp.exp(c["last"]) + _dot(c["U"].T, c["ktil"])
+    _, vd, kd = state.shape
+
+    def head(j):
+        st = state[j]
+        if save_states:
+            rest[0][0, j, 0] = st
+        _, values, ins = _head(j, heads, kd, vd, q_ref, k_ref, v_ref, g_ref,
+                               beta_ref)
+        c = yield from _chunk(*ins, st)
+        o = _dot_nt(c["qbar"], st) + _dot(c["B"], c["U"])
+        o_ref[0, :, values] = o.astype(o_ref.dtype)
+        yield
+        state[j] = st * jnp.exp(c["last"]) + _dot(c["U"].T, c["ktil"])
+
+    _side_by_side(head(j) for j in range(heads))
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
-                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate):
-    """One (batch, head, chunk), chunks last to first; ``dstate`` carries
-    the cotangent of the (transposed) state the chunk leaves."""
+                    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, *,
+                    heads: int):
+    """One (batch, group of ``heads`` heads, chunk), chunks last to
+    first; ``dstate`` carries the cotangents of the (transposed) states
+    the chunk leaves."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dstate[...] = jnp.zeros_like(dstate)
 
-    q, k, v = _f32(q_ref[0]), _f32(k_ref[0]), _f32(v_ref[0])
-    beta = _beta_col(beta_ref)
-    st, dst, do = st_ref[0, 0, 0], dstate[...], _f32(do_ref[0])
-    C, K = q.shape
+    _, vd, kd = dstate.shape
+    C = q_ref.shape[1]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
-    c = _chunk(q, k, v, g_ref[0], beta, st)
-    U, R, T = c["U"], c["R"], c["T"]
-    dU = _dot(c["B"].T, do) + _dot_nt(c["ktil"], dst)    # [C, V]
-    dB = jnp.where(row >= col, _dot_nt(do, U), 0.0)
-    dqbar, dktil = _dot(do, st), _dot(U, dst)            # [C, K]
-    dRb = _dot(T.T, dU)
-    dL = jnp.where(row > col, -_dot_nt(dRb, U), 0.0)
-    dbeta = (jnp.sum(dL * c["A"], axis=1, keepdims=True)
-             + jnp.sum(dRb * R, axis=1, keepdims=True))  # [C, 1]
-    dR = beta * dRb
-    dkbar = -_dot(dR, st)
-    decay = jnp.exp(c["last"])                           # [1, K]
-    dstate[...] = (dst * decay + _dot(do.T, c["qbar"])
-                   - _dot(dR.T, c["kbar"]))
-    (dk_row, dq), dk_col = _pairs_bwd(
-        [beta * dL, dB], [k, q], k, c["levels"], c["bands"])
-    diag = jnp.sum(jnp.where(row == col, dB, 0.0), axis=1, keepdims=True)
-    dG = (k * (dk_row - dk_col) + q * dq + dqbar * c["qbar"]
-          + dkbar * c["kbar"] - dktil * c["ktil"])
-    # what the chunk's total decay carries, on its last row
-    carried = (jnp.sum(dktil * c["ktil"], axis=0, keepdims=True)
-               + decay * jnp.sum(st * dst, axis=0, keepdims=True))
-    dG = dG + jnp.where(_iota((C, K), 0) == C - 1, carried, 0.0)
-    dq_ref[0] = (dq + diag * k + dqbar * c["gam"]).astype(dq_ref.dtype)
-    dk_ref[0] = (dk_row + dk_col + diag * q + dkbar * c["gam"]
-                 + dktil * c["to_end"]).astype(dk_ref.dtype)
-    dv_ref[0] = dR.astype(dv_ref.dtype)
-    dg_ref[0] = _dot(jnp.where(col >= row, 1.0, 0.0), dG,
-                     precision=_HIGHEST).astype(dg_ref.dtype)
-    # the column as a row: lane-dense in HBM
-    dbeta_ref[0, 0, 0] = jnp.sum(jnp.where(row == col, dbeta, 0.0), axis=0,
-                                 keepdims=True)
+
+    def head(j):
+        keys, values, ins = _head(j, heads, kd, vd, q_ref, k_ref, v_ref,
+                                  g_ref, beta_ref)
+        q, k, _, _, beta = ins
+        st, dst, do = st_ref[0, j, 0], dstate[j], _f32(do_ref[0, :, values])
+        c = yield from _chunk(*ins, st)
+        U, R, T = c["U"], c["R"], c["T"]
+        dU = _dot(c["B"].T, do) + _dot_nt(c["ktil"], dst)    # [C, V]
+        dB = jnp.where(row >= col, _dot_nt(do, U), 0.0)
+        dqbar, dktil = _dot(do, st), _dot(U, dst)            # [C, K]
+        yield
+        dRb = _dot(T.T, dU)
+        yield
+        dL = jnp.where(row > col, -_dot_nt(dRb, U), 0.0)
+        yield
+        dbeta = (jnp.sum(dL * c["A"], axis=1, keepdims=True)
+                 + jnp.sum(dRb * R, axis=1, keepdims=True))  # [C, 1]
+        dR = beta * dRb
+        dkbar = -_dot(dR, st)
+        decay = jnp.exp(c["last"])                           # [1, K]
+        dstate[j] = (dst * decay + _dot(do.T, c["qbar"])
+                     - _dot(dR.T, c["kbar"]))
+        yield
+        (dk_row, dq), dk_col = _pairs_bwd(
+            [beta * dL, dB], [k, q], k, c["levels"], c["bands"])
+        yield
+        diag = jnp.sum(jnp.where(row == col, dB, 0.0), axis=1, keepdims=True)
+        dG = (k * (dk_row - dk_col) + q * dq + dqbar * c["qbar"]
+              + dkbar * c["kbar"] - dktil * c["ktil"])
+        # what the chunk's total decay carries, on its last row
+        carried = (jnp.sum(dktil * c["ktil"], axis=0, keepdims=True)
+                   + decay * jnp.sum(st * dst, axis=0, keepdims=True))
+        dG = dG + jnp.where(_iota((C, kd), 0) == C - 1, carried, 0.0)
+        dq_ref[0, :, keys] = (dq + diag * k
+                              + dqbar * c["gam"]).astype(dq_ref.dtype)
+        dk_ref[0, :, keys] = (dk_row + dk_col + diag * q + dkbar * c["gam"]
+                              + dktil * c["to_end"]).astype(dk_ref.dtype)
+        dv_ref[0, :, values] = dR.astype(dv_ref.dtype)
+        yield
+        dg_ref[0, :, keys] = _dot(jnp.where(col >= row, 1.0, 0.0), dG,
+                                  precision=_HIGHEST).astype(dg_ref.dtype)
+        # the column as a row: lane-dense in HBM
+        dbeta_ref[0, j, 0] = jnp.sum(jnp.where(row == col, dbeta, 0.0),
+                                     axis=0, keepdims=True)
+
+    _side_by_side(head(j) for j in range(heads))
 
 
 def _layouts(q, k, v, g, beta, chunk: int):
@@ -342,45 +408,77 @@ def _layouts(q, k, v, g, beta, chunk: int):
             beta.reshape(b, sp // chunk, chunk, h))
 
 
-def _specs(chunk: int, h: int, kd: int, vd: int, at):
-    """Block specs of the five operands both kernels read; ``at`` maps
-    the grid's chunk index to the chunk (the backward's runs down)."""
+def _specs(chunk: int, heads: int, h: int, kd: int, vd: int, at):
+    """Block specs of the five operands both kernels read, ``heads``
+    heads wide; ``at`` maps the grid's chunk index to the chunk (the
+    backward's runs down)."""
     def wide(width):
-        return pl.BlockSpec((1, chunk, width), lambda b, h, c: (b, at(c), h))
+        return pl.BlockSpec((1, chunk, heads * width),
+                            lambda b, h, c: (b, at(c), h))
 
     return [wide(kd), wide(kd), wide(vd), wide(kd),
             pl.BlockSpec((1, 1, chunk, h), lambda b, h, c: (b, at(c), 0, 0))]
 
 
+def _heads_a_step(h: int, chunk: int, kd: int, vd: int) -> int:
+    """Heads a grid step holds: the largest rung of ``_LADDER`` that
+    divides ``h`` and whose step fits ``_VMEM_LIMIT``. A head of
+    ``kda_bwd``, the larger kernel, keeps 61 f32 tiles of ``[128, 128]``
+    at the cell's chunk and widths (Mosaic's plan for a described v5e:
+    15.23 MiB at four heads a step, 30.21 at eight), reckoned here as 64
+    tiles of ``[C, max(C, K, V)]``."""
+    tile = 4 * chunk * max(chunk, kd, vd)
+    return next(n for n in _LADDER
+                if h % n == 0 and (n == 1 or n * 64 * tile <= _VMEM_LIMIT))
+
+
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _forward(q, k, v, g, beta, chunk: int, interpret: bool,
-             save_states: bool):
+             save_states: bool, dot=None):
+    # under jit a body is traced once a shape and process, not at each of
+    # the cell's twelve call sites (both bodies of four heads: ~1 s of
+    # Python and lowering). ``dot`` is the module's ``_dot`` as a key of
+    # that cache and nothing else: ``benchmark/tests/kda_micro.py`` swaps
+    # it between two traces (whoever sets another ``_LADDER`` clears jax's
+    # caches)
+    del dot
     b, s, h, kd = q.shape
     vd = v.shape[3]
+    heads = _heads_a_step(h, chunk, kd, vd)
     ops = _layouts(q, k, v, g, beta, chunk)
     sp = ops[0].shape[1]
     nc = sp // chunk
     out_shape = [jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype)]
-    out_specs = [pl.BlockSpec((1, chunk, vd), lambda b, h, c: (b, c, h))]
+    out_specs = [pl.BlockSpec((1, chunk, heads * vd),
+                              lambda b, h, c: (b, c, h))]
     if save_states:
         out_shape.append(jax.ShapeDtypeStruct((b, h, nc, vd, kd),
                                               jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, 1, vd, kd),
+        out_specs.append(pl.BlockSpec((1, heads, 1, vd, kd),
                                       lambda b, h, c: (b, h, c, 0, 0)))
     out = pl.pallas_call(
-        functools.partial(_kda_fwd_kernel, save_states=save_states),
-        grid=(b, h, nc),
-        in_specs=_specs(chunk, h, kd, vd, lambda c: c),
+        functools.partial(_kda_fwd_kernel, heads=heads,
+                          save_states=save_states),
+        grid=(b, h // heads, nc),
+        in_specs=_specs(chunk, heads, h, kd, vd, lambda c: c),
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((vd, kd), jnp.float32)],
-        interpret=interpret, name="kda_fwd",
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), jnp.float32)],
+        interpret=interpret, name="kda_fwd", compiler_params=_PARAMS,
     )(*ops)
     o = out[0][:, :s].reshape(b, s, h, vd)
     return o, (out[1] if save_states else None)
 
 
-def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool,
+              dot=None):
+    del dot
     b, s, h, kd = q.shape
     vd = v.shape[3]
+    heads = _heads_a_step(h, chunk, kd, vd)
     ops = _layouts(q, k, v, g, beta, chunk)
     sp = ops[0].shape[1]
     nc = sp // chunk
@@ -390,19 +488,19 @@ def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
     def at(c):
         return nc - 1 - c
 
-    specs = _specs(chunk, h, kd, vd, at)
+    specs = _specs(chunk, heads, h, kd, vd, at)
     keys, _, values, _, _ = specs
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _kda_bwd_kernel,
-        grid=(b, h, nc),
+        functools.partial(_kda_bwd_kernel, heads=heads),
+        grid=(b, h // heads, nc),
         in_specs=specs + [
-            pl.BlockSpec((1, 1, 1, vd, kd),
+            pl.BlockSpec((1, heads, 1, vd, kd),
                          lambda b, h, c: (b, h, at(c), 0, 0)),
             values,
         ],
         out_specs=[
             keys, keys, values, keys,
-            pl.BlockSpec((1, 1, 1, 1, chunk),
+            pl.BlockSpec((1, heads, 1, 1, chunk),
                          lambda b, h, c: (b, h, at(c), 0, 0)),
         ],
         out_shape=[
@@ -412,8 +510,8 @@ def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
             jax.ShapeDtypeStruct((b, sp, h * kd), g.dtype),
             jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((vd, kd), jnp.float32)],
-        interpret=interpret, name="kda_bwd",
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), jnp.float32)],
+        interpret=interpret, name="kda_bwd", compiler_params=_PARAMS,
     )(*ops, states, do)
     dbeta = dbeta.reshape(b, h, sp).transpose(0, 2, 1)
     return (dq[:, :s].reshape(q.shape), dk[:, :s].reshape(k.shape),
@@ -423,16 +521,16 @@ def _backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kda(q, k, v, g, beta, chunk, interpret):
-    return _forward(q, k, v, g, beta, chunk, interpret, False)[0]
+    return _forward(q, k, v, g, beta, chunk, interpret, False, _dot)[0]
 
 
 def _kda_fwd(q, k, v, g, beta, chunk, interpret):
-    o, states = _forward(q, k, v, g, beta, chunk, interpret, True)
+    o, states = _forward(q, k, v, g, beta, chunk, interpret, True, _dot)
     return o, (q, k, v, g, beta, states)
 
 
 def _kda_bwd(chunk, interpret, residuals, do):
-    return _backward(*residuals, do, chunk, interpret)
+    return _backward(*residuals, do, chunk, interpret, _dot)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
