@@ -47,7 +47,7 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     remat: bool = True
     # >0: llama_loss_fn fuses the vocab projection via ops/xent.py's
-    # online-logsumexp scan (never materializes [B,S,V] logits); 0=dense
+    # sweep over row tiles (never materializes [B,S,V] logits); 0=dense
     xent_chunks: int = 0
 
     def __post_init__(self) -> None:

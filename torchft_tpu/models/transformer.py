@@ -43,10 +43,10 @@ class TransformerConfig:
     remat: bool = True
     attention: str = "local"    # "local" | "ring"
     seq_axis: str = "seq"       # mesh axis for ring attention
-    # >0: loss_fn uses ops/xent.py's online-logsumexp scan over this many
-    # vocab chunks instead of materializing [B, S, V] logits (the logits
-    # tensor is the single largest HBM consumer at small-d_model/32k-vocab
-    # shapes). 0 = dense log_softmax.
+    # >0: loss_fn uses ops/xent.py's fused sweep, the logits cut into (at
+    # least) this many tiles of rows instead of materializing [B, S, V]
+    # (the logits tensor is the single largest HBM consumer at
+    # small-d_model/large-vocab shapes). 0 = dense log_softmax.
     xent_chunks: int = 0
 
     @property
@@ -187,9 +187,10 @@ def _embed(cfg, params: Dict, tokens):
 @jax.named_scope("lm_head_xent")
 def ce_from_hidden(h, lm_head_kernel, targets, xent_chunks: int = 0):
     """Mean next-token cross entropy from final-norm hidden states.
-    ``xent_chunks`` > 0 routes through ops/xent.py's online-logsumexp scan
-    so the [B, S, V] logits tensor is never materialized (exact up to fp
-    reassociation); 0 = dense log_softmax. Assumes a replicated lm head —
+    ``xent_chunks`` > 0 routes through ops/xent.py's fused sweep over row
+    tiles (loss and gradients in one scan), so the [B, S, V] logits tensor
+    is never materialized (exact up to fp reassociation); 0 = dense
+    log_softmax. Assumes a replicated lm head —
     under TP (vocab-sharded head) use
     ops/xent.py make_vocab_parallel_cross_entropy instead."""
     if xent_chunks > 0:

@@ -1,41 +1,76 @@
-"""Memory-efficient cross entropy: online logsumexp over vocab chunks.
+"""The head's cross entropy without the ``[N, V]`` logits: two paths, by
+whether the head is sharded.
 
-The flagship configs pair a small d_model with a 32k vocab, so the logits
-tensor dwarfs everything else the train step touches: [B, S, V] f32 at the
-125m bench shape is ~1 GB written + read back per step, pure HBM traffic
-(the reference has no analog — its torch models never fuse this; XLA can't
-either, because log_softmax needs the full row before the gather).
+The logits dwarf everything else the head touches: ``[T, V]`` f32 is 6.6 GB
+at Cerebras-GPT-111M's cell (T = 16 rows x 2 048 = 32 768, d 768, V 50 304),
+4.9 GB at OLMoE's (T 24 576, d 2 048) and 2.1 - 3.3 GB at the cells that
+hold an 8- to 32-way share of a vocabulary (T 32 768, V 16 160 - 25 088), on
+a 16 GB chip. Both paths cut them into ``num_chunks`` pieces and hold one.
 
-The core primitive ``chunked_lse_and_target`` never materializes [N, V]:
-a lax.scan over vocab chunks runs the classic online-softmax recurrence
-on [N, V/C] tiles — running row max m, running sumexp s rescaled by
-exp(m_old - m_new), plus the target logit gathered from whichever chunk
-holds it. Its custom VJP re-runs the same scan, rebuilding each chunk's
-logits on the fly and accumulating
+**Unsharded head: ``chunked_cross_entropy``, the fused sweep.** The mean
+NLL is a ``custom_vjp`` whose FORWARD rule sweeps tiles of rows, a whole row
+of the vocabulary at a time, so a row's exact log-sum-exp is known before
+its ``dlogits`` are formed and each logit is built once:
+
+    z    = x_r @ w                                 [rows, V] f32
+    lse  = max(z) + log(sum(exp(z - max(z))))      [rows]
+    dz   = (exp(z - lse) - onehot(targets)) / N    [rows, V] f32
+    dx_r = dz @ w^T                                [rows, D]
+    dW  += x_r^T @ dz                              [D, V] f32 carry
+
+The residuals are ``dx`` and ``dW``; the backward rule multiplies them by
+the scalar cotangent and does nothing else. A call that is not
+differentiated (evaluation, a reference check) runs the same sweep without
+the last three lines. That is 6 T d V FLOP a differentiated call, the three
+matmuls any head needs, and one ``[rows, V]`` tile of logits through HBM:
+written by the matmul, read for the max, for the sum and to form ``dz``,
+and ``dz`` written and read by two matmuls, 28 B an element where nothing
+fuses and 16 as XLA compiles it for the v5e (the max rides the first
+matmul, each gradient matmul forms ``dz`` from the logits as it reads
+them). ``num_chunks`` is into how many pieces at least the logits are cut:
+the sweep asks for that many tiles, or for as many as keep a tile within
+4 096 rows (at 8 192, the tile of the chunked scan it replaced, OLMoE's
+fused step peaked 4.1 % over its parent's and Phi-4-mini-flash's 2.2 %),
+and takes the smallest divisor of N from there (24 576 rows in 3 pieces: 6
+tiles of 4 096; 16 384 in 3: 4 of 4 096), so a tile is never larger than
+N / ``num_chunks`` rows of the vocabulary; where no divisor leaves a tile at
+least half the rows asked for (a prime N), the tiles are ceil(N / tiles
+asked for) rows and the last is padded with rows that count for nothing in
+the loss or the gradients. V need not divide. A tile costs a read and a
+write of the f32 ``dW`` carry (8 d V bytes), which is why the tiles are not
+smaller still; a head whose 4 096 rows of logits are still too much (a
+vocabulary of 256k unsharded is 4 GiB) raises ``num_chunks``.
+
+**Vocabulary-sharded head: ``chunked_lse_and_target`` under
+``make_vocab_parallel_cross_entropy``, the recompute.** A shard cannot form
+``exp(z - lse)`` before the shards' ``lse`` have been combined, so its
+gradients cannot be had in the forward sweep. Its primitive scans VOCABULARY
+chunks with the online-softmax recurrence on ``[N, V/C]`` tiles (running row
+max m, running sumexp s rescaled by exp(m_old - m_new), the target logit
+gathered from whichever chunk holds it) and its custom VJP runs the scan
+again, rebuilding each chunk's logits:
 
     dlogits_c = exp(logits_c - lse) * g_lse + onehot_c * g_tl
     dx       += dlogits_c @ w_c^T               [N, D]
     dw_c      = dlogits_c^T @ x                 [V/C, D] per chunk
 
-so backward peak memory matches forward (one [N, V/C] tile live at a
-time) at the cost of recomputing the chunk matmuls — the same
-FLOPs-for-HBM trade as flash attention, applied to the lm head. Because
-the VJP is written for GENERIC cotangents (g_lse, g_tl), the primitive
-composes under further transformations — in particular the
-vocab-parallel loss below differentiates through psum/logaddexp on top
-of it.
+8 T d V FLOP for 6 of use, and the f32 tile goes through HBM in both scans
+(12 B an element forward, 20 B backward): the FLOPs-for-HBM trade of flash
+attention, kept where the combine between the scans is a collective.
+Because that VJP is written for GENERIC cotangents (g_lse, g_tl), the
+primitive composes under further transformations - the vocab-parallel loss
+differentiates through psum/logaddexp on top of it: each device computes its
+shard's (lse, target-logit) pair locally, then the shards combine with a
+pmax-stabilized logaddexp psum - Megatron's vocab-parallel cross entropy,
+done the TPU way (shard_map + XLA collectives, no gathered logits anywhere).
 
-``make_vocab_parallel_cross_entropy`` is the TP-native loss for a
-column-parallel (vocab-sharded) lm head: each device computes its
-shard's (lse, target-logit) pair locally via the chunked scan, then the
-shards combine with a pmax-stabilized logaddexp psum — Megatron's
-vocab-parallel cross entropy, done the TPU way (shard_map + XLA
-collectives, no gathered logits anywhere).
-
-Numerics match the dense log_softmax path up to fp reassociation of the
-sumexp (tests pin this to ~1e-6 in f32). Out-of-range targets clamp
-exactly like dense take_along_axis (clip semantics), so flipping
-xent_chunks can never change a loss value.
+What is rounded where is the same on both paths: operands as the caller
+casts them (``hidden_cross_entropy``: f32), default matmul precision, f32
+logits, f32 ``lse``, ``dlogits`` formed in f32. Numerics match the dense
+log_softmax path up to fp reassociation of the sumexp (tests pin this to
+~1e-6 in f32). Out-of-range targets clamp exactly like dense
+take_along_axis (clip semantics), so flipping xent_chunks can never change a
+loss value.
 """
 
 from __future__ import annotations
@@ -153,28 +188,113 @@ def _lse_bwd(num_chunks: int, residuals, cotangents):
 chunked_lse_and_target.defvjp(_lse_fwd, _lse_bwd)
 
 
+# the most rows a tile of the fused sweep holds: at 8 192 the fused steps of
+# two cells peaked 2 - 4 % over their parents' (PERF.md section 6, PR 49)
+_TILE_ROWS = 4096
+
+
+def _row_tiles(n: int, num_chunks: int):
+    """(tiles, rows a tile, rows of padding) of the fused sweep. At least
+    ``num_chunks`` tiles and as many as keep a tile within ``_TILE_ROWS``;
+    from there the smallest divisor of ``n``, unless it would leave a tile
+    under half as many rows as asked for; then tiles of ceil(n / tiles
+    asked for) rows, the last one padded."""
+    c = max(1, min(max(num_chunks, -(-n // _TILE_ROWS)), n))
+    for tiles in range(c, 2 * c + 1):
+        if n % tiles == 0:
+            return tiles, n // tiles, 0
+    rows = -(-n // c)
+    tiles = -(-n // rows)
+    return tiles, rows, tiles * rows - n
+
+
+def _sweep(x, w, targets, num_chunks: int, with_grads: bool):
+    """One scan over row tiles: the mean NLL and, ``with_grads``, its
+    gradients (dx [N, D], dW [D, V], both f32) for a cotangent of 1."""
+    n, d = x.shape
+    v = w.shape[1]
+    tiles, rows, pad = _row_tiles(n, num_chunks)
+    t = jnp.clip(targets, 0, v - 1)
+    scanned = (x, t)
+    if pad:
+        scanned = (jnp.pad(x, ((0, pad), (0, 0))), jnp.pad(t, (0, pad)),
+                   jnp.arange(tiles * rows) < n)
+    cols = jnp.arange(v)
+
+    def tile(xr, tr, live=None):
+        """A tile's summed NLL and its dz; ``live`` is False on padding."""
+        z = (xr @ w).astype(jnp.float32)                # [rows, V]
+        hit = cols[None, :] == tr[:, None]
+        m = jnp.max(z, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1))
+        nll = lse - jnp.sum(jnp.where(hit, z, 0.0), axis=-1)
+        scale = 1.0 / n
+        if live is not None:
+            nll = jnp.where(live, nll, 0.0)
+            scale = jnp.where(live, scale, 0.0)[:, None]
+        if not with_grads:
+            return jnp.sum(nll), None
+        return jnp.sum(nll), (jnp.exp(z - lse[:, None]) - hit) * scale
+
+    def loss_body(total, inputs):
+        return total + tile(*inputs)[0], None
+
+    def grad_body(carry, inputs):
+        total, dw = carry
+        tile_total, dz = tile(*inputs)
+        xr = inputs[0]
+        dxr = dz @ w.T.astype(jnp.float32)              # [rows, D]
+        dw = dw + xr.T.astype(jnp.float32) @ dz         # [D, V]
+        return (total + tile_total, dw), dxr
+
+    scanned = tuple(a.reshape(tiles, rows, *a.shape[1:]) for a in scanned)
+    zero = jnp.zeros((), jnp.float32)
+    if not with_grads:
+        return jax.lax.scan(loss_body, zero, scanned)[0] / n
+    (total, dw), dx = jax.lax.scan(
+        grad_body, (zero, jnp.zeros((d, v), jnp.float32)), scanned)
+    return total / n, dx.reshape(tiles * rows, d)[:n], dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def chunked_cross_entropy(x, w, targets, num_chunks: int = 8):
     """Mean next-token NLL of softmax(x @ w) rows vs integer targets.
 
-    Equals ``mean(-log_softmax(x @ w)[i, targets[i]])`` without ever
-    holding [N, V] in memory (see module docstring).
+    Equals ``mean(-log_softmax(x @ w)[i, targets[i]])`` with one tile of
+    at most N / ``num_chunks`` rows of logits in memory at a time, and with
+    both gradients computed in the sweep that computes the loss (see module
+    docstring). x: [N, D], w: [D, V], targets: [N] int (clamped to
+    [0, V-1]); any N, any V.
     """
-    mask = jnp.ones(targets.shape, dtype=bool)
-    lse, tl = chunked_lse_and_target(x, w, targets, mask, num_chunks)
-    return jnp.mean(lse - tl)
+    return _sweep(x, w, targets, num_chunks, with_grads=False)
+
+
+def _ce_fwd(x, w, targets, num_chunks: int):
+    loss, dx, dw = _sweep(x, w, targets, num_chunks, with_grads=True)
+    return loss, (dx.astype(x.dtype), dw.astype(w.dtype))
+
+
+def _ce_bwd(num_chunks: int, residuals, g):
+    dx, dw = residuals
+    zeros_t = np.zeros(dx.shape[:1], dtype=jax.dtypes.float0)
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), zeros_t
+
+
+chunked_cross_entropy.defvjp(_ce_fwd, _ce_bwd)
 
 
 def hidden_cross_entropy(h, w, targets, num_chunks: int):
     """Model-facing adapter: mean CE of [B, S, D] hidden states against
-    [B, S] targets through vocab projection ``w`` [D, V], chunked. One
-    definition so every model family's loss dispatch stays in lockstep
-    (transformer.loss_fn, llama.llama_loss_fn).
+    [B, S] targets through vocab projection ``w`` [D, V], a tile of rows at
+    a time. One definition so every model family's loss dispatch stays in
+    lockstep (transformer.loss_fn, llama.llama_loss_fn).
 
-    Assumes an UNSHARDED (replicated) lm head: the chunk reshape + scan
-    is opaque to GSPMD, so a vocab-sharded ``w`` (tp_rules_gpt) may be
-    silently all-gathered here every step. For a TP-sharded head, build
-    the loss with make_vocab_parallel_cross_entropy instead — it runs
-    this same scan per shard and combines with psum."""
+    Assumes an UNSHARDED (replicated) lm head: the sweep needs a whole row
+    of the vocabulary to form its gradients, so a vocab-sharded ``w``
+    (tp_rules_gpt) may be silently all-gathered here every step. For a
+    TP-sharded head, build the loss with make_vocab_parallel_cross_entropy
+    instead - it scans vocabulary chunks per shard and combines with
+    psum."""
     d = h.shape[-1]
     return chunked_cross_entropy(
         h.astype(jnp.float32).reshape(-1, d),
