@@ -751,3 +751,111 @@ def test_a_window_is_a_causal_matter() -> None:
     out = flash_attention(q, q, q, window=1, interpret=True, block_q=64,
                           block_k=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(q), atol=1e-6)
+
+
+# ------------------------------------------------------------ PR 50 contracts
+# A window several k edges long under tiles with block_q != block_k (the
+# streamed 512 x 1024 tile at W 4096, in small): the closed forms, the
+# tables, the kernels in both regimes, and the rule's table.
+
+# four k edges, no multiple of either edge, and between two and three
+_LONG_WINDOWS = [512, 450, 300]
+_LONG_SHAPES = [(1024, 64, 128), (1024, 128, 64), (768, 128, 256)]
+
+
+@pytest.mark.parametrize("window", _LONG_WINDOWS)
+@pytest.mark.parametrize("seq_len,block_q,block_k", _LONG_SHAPES)
+def test_band_sweep_of_a_window_several_k_edges_long(
+        seq_len, block_q, block_k, window) -> None:
+    test_band_sweep_is_the_closed_form_of_the_tile_predicates(
+        seq_len, block_q, block_k, window)
+
+
+@pytest.mark.parametrize("rows", [True, False], ids=["rows", "columns"])
+@pytest.mark.parametrize("window", _LONG_WINDOWS)
+@pytest.mark.parametrize("seq_len,block_q,block_k", _LONG_SHAPES)
+def test_live_tile_tables_of_a_long_band(seq_len, block_q, block_k, window,
+                                         rows) -> None:
+    from torchft_tpu.ops.flash import _grid_steps, _live_tiles
+
+    q_of, k_of = _live_tiles(seq_len, block_q, block_k, rows, window)
+    listed = list(zip(q_of.tolist(), k_of.tolist()))
+    # against the element-wise band mask itself
+    r, c = np.indices((seq_len, seq_len))
+    seen = ((r >= c) & (r - c < window)).reshape(
+        seq_len // block_q, block_q, seq_len // block_k, block_k
+    ).any(axis=(1, 3))
+    live = {(int(qi), int(ki)) for qi, ki in zip(*np.nonzero(seen))}
+    assert len(listed) == len(set(listed)) and set(listed) == live
+    assert listed == sorted(listed, key=lambda t: t if rows else t[::-1])
+    assert _grid_steps(seq_len, block_q, block_k, window) == (
+        len(live), seen.size)
+    # some q block's band is several k blocks long
+    assert seen.sum(axis=1).max() >= 3
+
+
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+@pytest.mark.parametrize("window", _LONG_WINDOWS[:2])
+@pytest.mark.parametrize("block_q,block_k", [(64, 128), (128, 64)])
+def test_long_windowed_kernels_match_the_band_mask(block_q, block_k, window,
+                                                   regime) -> None:
+    # forward, dq and dkv over a band of several k edges, block_q !=
+    # block_k, resident loops and streamed tables alike
+    q, k, v, cot = (_rand((1, 1024, 2, 32), i + 80) for i in range(4))
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k,
+            interpret=True, _resident_kv_bytes=_REGIMES[regime],
+            window=window,
+        )
+
+    def reference(q, k, v):
+        return reference_attention(q, k, v, causal=True, window=window)
+
+    got = (flash(q, k, v), *jax.grad(
+        lambda q, k, v: jnp.sum(flash(q, k, v) * cot), argnums=(0, 1, 2)
+    )(q, k, v))
+    want = (reference(q, k, v), *jax.grad(
+        lambda q, k, v: jnp.sum(reference(q, k, v) * cot), argnums=(0, 1, 2)
+    )(q, k, v))
+    np.testing.assert_allclose(
+        np.asarray(got[0]), np.asarray(want[0]), atol=2e-5, rtol=2e-5
+    )
+    for name, a, b in zip(("dq", "dk", "dv"), got[1:], want[1:]):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-4,
+            err_msg=f"{name} mismatch",
+        )
+
+
+@pytest.mark.parametrize("seq_len,head_dim,v_dim,window,blocks,steps", [
+    # smallthinker's windowed call: the streamed tile from 2 k edges on
+    (16384, 128, None, 4096, (512, 1024), (140, 512)),
+    (16384, 128, None, 8192, (512, 1024), (216, 512)),
+    (16384, 128, None, 2048, (512, 1024), (90, 512)),
+    # a key short of that, and the windows the other cells run: square
+    (16384, 128, None, 2047, (512, 512), (150, 1024)),
+    (16384, 128, None, 1024, (512, 512), (93, 1024)),
+    (8192, 64, 128, 512, (512, 512), (31, 256)),
+    (8192, 128, None, 512, (512, 512), (31, 256)),
+    # no window: what it was
+    (16384, 128, None, None, (512, 1024), (272, 512)),
+    (8192, 64, 128, None, (512, 1024), (72, 128)),
+    (8192, 128, None, None, (512, 1024), (72, 128)),
+    (2048, 64, None, None, (512, 512), (10, 16)),
+])
+def test_the_tile_rule_under_a_window(seq_len, head_dim, v_dim, window,
+                                      blocks, steps) -> None:
+    """``_choose_blocks`` is a pure function of (sequence, widths,
+    window): the streamed 512 x 1024 tile where the kernels stream and the
+    window is at least two of its k edges long (PERF.md, PR 50: the chip
+    read it 17 % faster over the three kernels at W 4096 and 11 % at W
+    2048, and no faster at W 1024), the square tile under a shorter one
+    (PR 47), and without a window what it always chose."""
+    from torchft_tpu.ops.flash import _choose_blocks, _grid_steps
+
+    got = _choose_blocks(seq_len, head_dim, 2, v_dim=v_dim, window=window)
+    assert got == blocks == _choose_blocks(
+        seq_len, head_dim, 2, v_dim=v_dim, window=window)
+    assert _grid_steps(seq_len, *got, window) == steps

@@ -333,3 +333,70 @@ def test_the_gauge_is_absent_where_the_share_was_not_said() -> None:
         assert ("moe_row_buffer_share" in seen) == emitted
         if emitted:
             assert seen["moe_row_buffer_share"] == 0.5
+
+
+# -- the third activation (PR 50) ---------------------------------------------
+
+
+def _reglu_on_every_token(h, weights, experts, gate, up, down, first):
+    """``relu(h·W_g) ⊙ (h·W_u)``: every held expert on every token."""
+    out = 0.0
+    for i in range(up.shape[0]):
+        w = jnp.sum(jnp.where(experts == first + i, weights, 0), axis=1)
+        out = out + w[:, None] * (
+            (jax.nn.relu(h @ gate[i]) * (h @ up[i])) @ down[i])
+    return out
+
+
+@pytest.mark.parametrize("path,held_rows", [
+    ("row_buffer", 100), ("row_buffer", CAPACITY + 1), ("row_buffer", 0),
+    ("all_rows", 100), ("whole_layer", N * K)])
+def test_reglu_is_a_static_choice_through_every_path(path, held_rows) -> None:
+    """``activation="reglu"`` through ``moe_mlp``'s three ways — the row
+    buffer and its hand-written backward in one pass, two and none, a
+    share over all ``N*k`` rows, a layer that holds every expert —, value
+    and every gradient against "every held expert on every token"; the
+    same call without it is SwiGLU, another result; and another name is
+    refused."""
+    n_held, first, routed = {
+        "row_buffer": (HELD, FIRST, ROUTED), "all_rows": (HELD, FIRST, ROUTED),
+        "whole_layer": (ROUTED, 0, ROUTED)}[path]
+    h, gate, up, down = _weights(n_held)
+    weights, experts = _routing(held_rows)
+
+    def layer(h, weights, experts, gate, up, down, activation="reglu"):
+        if path == "all_rows":
+            local = experts - first
+            held = (local >= 0) & (local < n_held)
+            return moe._all_rows(
+                h, jnp.where(held, weights, 0),
+                jnp.where(held, local, n_held), gate, up, down, True,
+                activation)
+        return moe.moe_mlp(h, weights, experts, gate, up, down,
+                           n_routed=routed, first_expert=first,
+                           activation=activation)
+
+    def reference(h, weights, experts, gate, up, down):
+        return _reglu_on_every_token(h, weights, experts, gate, up, down,
+                                     first)
+
+    args, wrt = (h, weights, experts, gate, up, down), (0, 1, 3, 4, 5)
+    with jax.default_matmul_precision("highest"):
+        got, got_grads = _value_and_grads(layer, args, wrt)
+        want, want_grads = _value_and_grads(reference, args, wrt)
+        np.testing.assert_allclose(layer(*args), reference(*args), atol=2e-5)
+        text = str(jax.make_jaxpr(layer)(*args))
+        assert ("_share_mlp" in text) == (path == "row_buffer")
+        if held_rows:
+            silu = layer(*args, activation=None)
+            assert float(jnp.max(jnp.abs(silu - layer(*args)))) > 1e-2
+    assert float(got) == pytest.approx(float(want), abs=2e-3)
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape and bool(jnp.all(jnp.isfinite(a)))
+        if held_rows == 0:
+            assert not np.any(a) and not np.any(b)
+        else:
+            assert _rel(a, b) < 1e-5
+    if path != "all_rows":          # moe_mlp refuses another name
+        with pytest.raises(AssertionError):
+            layer(*args, activation="gelu")

@@ -5,10 +5,12 @@ families the benchmark trains at published widths are modules of their
 own, each with ``<Family>Config``, ``init_params``, ``forward_hidden``,
 ``loss_terms`` and ``loss_fn`` (the step maker's ``loss=``): ``olmoe``,
 ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear`` (which calls
-``joyai``'s latent-attention and sparse sublayers with its own config)
-and ``phi4flash`` (a stack that is not a chain: two layers hand their scan
-output and their keys and values on to later layers); what more than one
-of them computes is in ``common``."""
+``joyai``'s latent-attention and sparse sublayers with its own config),
+``phi4flash`` (a stack that is not a chain: two layers hand their scan
+output and their keys and values on to later layers) and ``smallthinker``
+(attention windowed or full and rotated or not by two lists of the
+config, a router that reads the stream before attention, ReGLU experts);
+what more than one of them computes is in ``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

@@ -1,11 +1,12 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
-``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``), the
-token table's lookup (``olmoe`` and the four below), the repeat of grouped
-key/value heads (``nemotron_h``, ``lfm2``), the SwiGLU MLP and its dense
-sublayer (``joyai``, ``lfm2``, ``kimi_linear``), and what the four models
-that hold ONE CHIP'S SHARE of an expert-parallel layer (``joyai``,
-``nemotron_h``, ``lfm2``, ``kimi_linear``) have in common: the router's
+``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``,
+``smallthinker``), the token table's lookup (``olmoe`` and the five
+below), the repeat of grouped key/value heads (``nemotron_h``, ``lfm2``,
+``smallthinker``), the SwiGLU MLP and its dense sublayer (``joyai``,
+``lfm2``, ``kimi_linear``), and what the five models that hold ONE CHIP'S
+SHARE of an expert-parallel layer (``joyai``, ``nemotron_h``, ``lfm2``,
+``kimi_linear``, ``smallthinker``) have in common: the router's
 balance bias — its key in the parameter tree, the predicate
 ``optim.with_balance_bias`` partitions the leaves by, and the way a
 step's loads reach that rule in the gradient tree at the bias's place —
@@ -106,7 +107,9 @@ loads_as_gradient = _loads_as_gradient
 @jax.named_scope("mlp")
 def routed_sublayer(cfg, x, scale, m: Dict, *,
                     shared: Optional[Callable] = None,
-                    renorm_eps: float = 1e-20) -> Tuple[Any, Dict]:
+                    renorm_eps: float = 1e-20,
+                    route_on: Any = None, score: str = "sigmoid",
+                    activation: Optional[str] = None) -> Tuple[Any, Dict]:
     """A share's routed-expert sublayer, ``(x + y, record)``: on ``h =
     RMSNorm(x)`` (weight ``scale``), ``s = sigmoid(h·W_r)`` in float32
     over all ``cfg.n_routed_experts``; the ``cfg.top_k`` largest of ``s +
@@ -117,24 +120,49 @@ def routed_sublayer(cfg, x, scale, m: Dict, *,
     ``gate_proj``), and ``shared(h)`` is added where a model has a shared
     expert. The record: ``experts`` [N, top_k], ``loads`` [routed]
     (float32 counts), and ``carrier``, the zero that hands the loads to
-    the bias's place in the gradient tree."""
+    the bias's place in the gradient tree.
+
+    What SmallThinker changes, each a default that leaves the other
+    models' traced programs what they are: ``route_on`` [B, S, d], the
+    tensor the router scores where it is NOT the experts' input (that
+    model's router reads the layer's input norm, before attention; in
+    float32, before it is rounded); ``score="softmax"``: the top of the
+    logits ``z + b`` choose and the weights are ``softmax`` over the
+    chosen ``z`` (= the softmax over all, renormalised) times
+    ``cfg.routed_scale``; ``activation``, the expert's, handed to
+    ``moe.moe_mlp`` (``"reglu"``)."""
     B, S, d = x.shape
+    assert score in ("sigmoid", "softmax"), score
     with jax.named_scope("moe_router"):
-        h32 = rms_norm(x.astype(jnp.float32), scale,
-                       cfg.rms_eps).reshape(B * S, d)
+        if route_on is None:
+            h32 = rms_norm(x.astype(jnp.float32), scale,
+                           cfg.rms_eps).reshape(B * S, d)
+            r32 = h32
+        else:
+            r32 = route_on.astype(jnp.float32).reshape(B * S, -1)
         # as models/olmoe.py: the router reads the normed stream before
         # it is rounded to the compute dtype, in true float32
-        scores = jax.nn.sigmoid(jnp.dot(
-            h32, m["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        weights, experts = moe.top_k_routing(
-            scores, cfg.top_k, bias=m[BALANCE_BIAS], renormalise=True,
-            scale=cfg.routed_scale, eps=renorm_eps)
+        scores = jnp.dot(
+            r32, m["router"]["kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        if score == "sigmoid":
+            weights, experts = moe.top_k_routing(
+                jax.nn.sigmoid(scores), cfg.top_k, bias=m[BALANCE_BIAS],
+                renormalise=True, scale=cfg.routed_scale, eps=renorm_eps)
+        else:
+            weights, experts = moe.top_k_routing(
+                scores, cfg.top_k, bias=m[BALANCE_BIAS], softmax=True,
+                scale=cfg.routed_scale)
         loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
             experts.reshape(-1)].add(1.0)
         carrier = loads_as_gradient(
             m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
-    h = h32.astype(cfg.dtype)
+    if route_on is None:
+        h = h32.astype(cfg.dtype)
+    else:
+        # the experts' own norm: the rows the dispatch gathers
+        with jax.named_scope("moe_dispatch"):
+            h = rms_norm(x, scale, cfg.rms_eps).reshape(B * S, d)
     also = None
     if shared is not None:
         with jax.named_scope("moe_shared"):
@@ -144,6 +172,7 @@ def routed_sublayer(cfg, x, scale, m: Dict, *,
         m["gate_proj"]["kernel"] if "gate_proj" in m else None,
         m["up_proj"]["kernel"], m["down_proj"]["kernel"],
         n_routed=cfg.n_routed_experts, first_expert=cfg.first_expert,
+        activation=activation,
     )
     if also is not None:
         y = y + also

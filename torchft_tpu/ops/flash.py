@@ -64,7 +64,9 @@ head at S 8192, W 512, 512 x 512 tiles, where the causal call takes 72
 of 512 x 1024), and ``_band_sweep`` is the closed form of the resident
 sweeps' three ranges: tiles the trailing edge cuts, full tiles, tiles on
 the diagonal (all three as loops; the masked body applies both edges).
-``_choose_blocks`` keeps square tiles under a window.
+``_choose_blocks`` keeps square tiles under a window shorter than two k
+edges of the streamed tile (2048 keys) and takes the streamed 512 x 1024
+from there on (140 grid steps a head for 252 at S 16384, W 4096).
 
 dK/dV recomputes its tile TRANSPOSED (Sᵀ = K·Qᵀ, [BK, BQ]): Pᵀ·dO and
 dSᵀ·Q are then plain matmuls and no [BQ, BK] tile goes through the
@@ -945,6 +947,17 @@ _TILES = ((512, 512), (256, 256), (128, 128))
 # 1024 first. Measured on the v5e at [2, 8192, 32, 192 / 128] (PERF.md,
 # PR 31): forward 27.8 -> 19.5 ms; at 128 / 128 it changes nothing.
 _STREAMED_TILES = ((512, 1024),) + _TILES
+# Under a window a k edge of 1024 is live for every q block the band
+# touches it in, so it computes more above the band than the square tile
+# (tiles' area over live pairs 1.25 against 1.125 at W 4096, 2.0 against
+# 1.5 at W 1024) for fewer grid steps. It pays where the window is this
+# many of those k edges long. Measured on the v5e at [2, 16384, 28, 128]
+# (PERF.md, PR 50), forward / dq / dkv in ms, square -> streamed: at W 4096
+# 34.1 / 23.0 / 26.1 -> 20.7 / 21.7 / 26.2 (140 grid steps a head for
+# 252), at W 2048 21.3 / 14.9 / 16.0 -> 14.1 / 14.7 / 17.4; at W 1024 the
+# forward gains (14.0 -> 10.5) what dq and dkv lose (10.1 / 10.6 -> 11.0 /
+# 12.6), and at W 512 the square tile stays (PR 47).
+_STREAMED_WINDOW_EDGES = 2
 # What one kernel instance may plan to hold in VMEM (the v5e's default
 # scoped limit is 16 MiB).
 _VMEM_BUDGET = 16 * 1024 * 1024
@@ -986,12 +999,15 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
     edges divide ``seq_len`` and whose :func:`_vmem_estimate` fits
     ``_VMEM_BUDGET`` (the smallest edge when none does, or the whole of a
     shorter sequence). An explicit argument wins over the rule and is
-    only clamped to the sequence. Under a ``window`` the tiles are the
-    square ones in either regime: a k edge of 1024 is live for every q
-    block whose window touches it, and at 512 keys that doubles the
+    only clamped to the sequence. Under a ``window`` shorter than
+    ``_STREAMED_WINDOW_EDGES`` k edges of the streamed tile the tiles are
+    the square ones in either regime: a k edge of 1024 is live for every
+    q block whose window touches it, and at 512 keys that doubles the
     band's arithmetic."""
     pair = head_dim + (head_dim if v_dim is None else v_dim)
-    tiles = (_TILES if window is not None
+    band = (window is not None
+            and window < _STREAMED_WINDOW_EDGES * _STREAMED_TILES[0][1])
+    tiles = (_TILES if band
              or _resident(seq_len, pair, itemsize) else _STREAMED_TILES)
     q_edge = k_edge = min(_TILES[-1][0], seq_len)
     for cand_q, cand_k in tiles:

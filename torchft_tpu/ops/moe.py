@@ -49,8 +49,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["Dispatch", "top_k_routing", "sort_by_expert", "gather_tokens",
-           "grouped_matmul", "swiglu_experts", "relu2_experts", "combine",
-           "held_capacity", "moe_mlp"]
+           "grouped_matmul", "swiglu_experts", "reglu_experts",
+           "relu2_experts", "combine", "held_capacity", "moe_mlp"]
 
 
 class Dispatch(NamedTuple):
@@ -64,7 +64,8 @@ class Dispatch(NamedTuple):
 
 def top_k_routing(scores: Any, k: int, bias: Any = None,
                   renormalise: bool = False,
-                  scale: float = 1.0, eps: float = 1e-20) -> Tuple[Any, Any]:
+                  scale: float = 1.0, eps: float = 1e-20,
+                  softmax: bool = False) -> Tuple[Any, Any]:
     """``(weights [N, k], experts [N, k] int32)``. Without ``bias``: the
     ``k`` largest router scores of each token, as they are (OLMoE's
     softmax probabilities, not renormalised). With a ``bias [E]``
@@ -72,14 +73,18 @@ def top_k_routing(scores: Any, k: int, bias: Any = None,
     choose, and the weights are the chosen experts' ``scores`` — the bias
     selects and never weights, and no gradient reaches it.
     ``renormalise`` divides a token's weights by their sum + ``eps``
-    (1e-20: DeepSeek-V3's; LFM2 publishes 1e-6); ``scale`` multiplies
-    them."""
+    (1e-20: DeepSeek-V3's; LFM2 publishes 1e-6); ``softmax`` takes
+    ``scores`` for logits and weighs by the softmax over the CHOSEN ones
+    (SmallThinker's: the softmax over all, renormalised); ``scale``
+    multiplies them."""
     if bias is None:
         weights, experts = jax.lax.top_k(scores, k)
     else:
         _, experts = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias).astype(scores.dtype), k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if softmax:
+        weights = jax.nn.softmax(weights, axis=-1)
     if renormalise:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return (weights * scale if scale != 1.0 else weights), experts
@@ -167,16 +172,29 @@ def grouped_matmul(x: Any, w: Any, group_sizes: Any) -> Any:
                         False, _interpret())
 
 
+def _gated_experts(act, x: Any, gate: Any, up: Any, down: Any,
+                   group_sizes: Any) -> Any:
+    dt = x.dtype
+    g = grouped_matmul(x, gate.astype(dt), group_sizes)
+    u = grouped_matmul(x, up.astype(dt), group_sizes)
+    a = (act(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(dt)
+    return grouped_matmul(a, down.astype(dt), group_sizes)
+
+
 def swiglu_experts(x: Any, gate: Any, up: Any, down: Any,
                    group_sizes: Any) -> Any:
     """``(silu(x·gate[e]) * (x·up[e])) · down[e]`` for the rows of each
     expert ``e``; the weights are cast to ``x``'s dtype, the activation
     is computed in float32."""
-    dt = x.dtype
-    g = grouped_matmul(x, gate.astype(dt), group_sizes)
-    u = grouped_matmul(x, up.astype(dt), group_sizes)
-    a = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(dt)
-    return grouped_matmul(a, down.astype(dt), group_sizes)
+    return _gated_experts(jax.nn.silu, x, gate, up, down, group_sizes)
+
+
+def reglu_experts(x: Any, gate: Any, up: Any, down: Any,
+                  group_sizes: Any) -> Any:
+    """``(relu(x·gate[e]) * (x·up[e])) · down[e]``: the three-matrix
+    expert whose gate is a relu (SmallThinker's ReGLU); weights and
+    activation as :func:`swiglu_experts`'."""
+    return _gated_experts(jax.nn.relu, x, gate, up, down, group_sizes)
 
 
 def relu2_experts(x: Any, up: Any, down: Any, group_sizes: Any) -> Any:
@@ -224,14 +242,19 @@ def held_capacity(n_assignments: Any, n_held: int, n_routed: int) -> Any:
     return jnp.minimum(n_assignments, rows)
 
 
-def _experts(x: Any, gate: Any, up: Any, down: Any, group_sizes: Any) -> Any:
+def _experts(x: Any, gate: Any, up: Any, down: Any, group_sizes: Any,
+             activation: Optional[str] = None) -> Any:
+    # ``activation``: the gated expert's where it is not SwiGLU's silu, a
+    # static choice of the caller's
     if gate is None:
         return relu2_experts(x, up, down, group_sizes)
-    return swiglu_experts(x, gate, up, down, group_sizes)
+    gated = reglu_experts if activation == "reglu" else swiglu_experts
+    return gated(x, gate, up, down, group_sizes)
 
 
 def _all_rows(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
-              down: Any, share: bool) -> Any:
+              down: Any, share: bool,
+              activation: Optional[str] = None) -> Any:
     """Every one of the ``N*k`` assignments a row. With a ``share`` held
     (``experts``: local ids, the sentinel ``len(up)`` for an absent
     expert) the absent ones sort last and ``group_sizes`` count held
@@ -246,7 +269,7 @@ def _all_rows(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
                     < jnp.sum(dispatch.group_sizes))[:, None]  # [N*k, 1]
             x = jnp.where(live, x, 0)
     with jax.named_scope("moe_experts"):
-        y = _experts(x, gate, up, down, dispatch.group_sizes)
+        y = _experts(x, gate, up, down, dispatch.group_sizes, activation)
     with jax.named_scope("moe_combine"):
         return combine(jnp.where(live, y, 0) if share else y, weights,
                        dispatch)
@@ -300,7 +323,7 @@ def _pass_rows(passes: _Passes, i: Any, capacity: int, n: int, k: int):
 
 
 def _pass(x: Any, w: Any, live: Any, group_sizes: Any, gate: Any, up: Any,
-          down: Any) -> Any:
+          down: Any, activation: Optional[str] = None) -> Any:
     """One pass's rows through the experts: gathered rows ``x [C, d]``
     and their router weights ``w [C]`` -> weighted rows, float32. Rows
     past the held ones are never written by megablox, whose grid ends at
@@ -309,7 +332,7 @@ def _pass(x: Any, w: Any, live: Any, group_sizes: Any, gate: Any, up: Any,
     with jax.named_scope("moe_dispatch"):
         x = jnp.where(live, x, 0)
     with jax.named_scope("moe_experts"):
-        y = _experts(x, gate, up, down, group_sizes)
+        y = _experts(x, gate, up, down, group_sizes, activation)
     with jax.named_scope("moe_combine"):
         return jnp.where(live, y, 0).astype(jnp.float32) * w[:, None]
 
@@ -318,9 +341,10 @@ def _cast(dtype: Any, *weights: Any):
     return tuple(None if w is None else w.astype(dtype) for w in weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _share_mlp(capacity: int, h: Any, weights: Any, experts: Any, gate: Any,
-               up: Any, down: Any) -> Any:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _share_mlp(capacity: int, activation: Optional[str], h: Any,
+               weights: Any, experts: Any, gate: Any, up: Any,
+               down: Any) -> Any:
     """A held share's layer over a row buffer of ``capacity`` rows.
     ``experts`` holds local ids, the sentinel ``len(up)`` for an absent
     expert. Held rows sort first; a pass gathers ``capacity`` of them
@@ -349,7 +373,8 @@ def _share_mlp(capacity: int, h: Any, weights: Any, experts: Any, gate: Any,
         with jax.named_scope("moe_dispatch"):
             rows, token, live, inside = _pass_rows(passes, i, capacity, n, k)
             x = h[token]
-        y = _pass(x, flat[jnp.minimum(rows, n * k - 1)], live, inside, *cast)
+        y = _pass(x, flat[jnp.minimum(rows, n * k - 1)], live, inside, *cast,
+                  activation=activation)
         with jax.named_scope("moe_combine"):
             return _add_rows(out, y, token)
 
@@ -358,12 +383,13 @@ def _share_mlp(capacity: int, h: Any, weights: Any, experts: Any, gate: Any,
     return out.astype(h.dtype)
 
 
-def _share_mlp_fwd(capacity, h, weights, experts, gate, up, down):
-    return (_share_mlp(capacity, h, weights, experts, gate, up, down),
+def _share_mlp_fwd(capacity, activation, h, weights, experts, gate, up, down):
+    return (_share_mlp(capacity, activation, h, weights, experts, gate, up,
+                       down),
             (h, weights, experts, gate, up, down))
 
 
-def _share_mlp_bwd(capacity, res, g):
+def _share_mlp_bwd(capacity, activation, res, g):
     h, weights, experts, gate, up, down = res
     (n, d), k = h.shape, weights.shape[1]
     with jax.named_scope("moe_dispatch"):
@@ -380,7 +406,8 @@ def _share_mlp_bwd(capacity, res, g):
         with jax.named_scope("moe_combine"):
             dy = g[token].astype(jnp.float32)
         _, pull = jax.vjp(
-            lambda x, w, *cast: _pass(x, w, live, inside, *cast),
+            lambda x, w, *cast: _pass(x, w, live, inside, *cast,
+                                      activation=activation),
             x, flat[jnp.minimum(rows, n * k - 1)], *cast)
         dx, dw, *dpass = pull(dy)
         with jax.named_scope("moe_dispatch"):
@@ -409,15 +436,16 @@ _share_mlp.defvjp(_share_mlp_fwd, _share_mlp_bwd)
 
 def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
             down: Any, n_routed: Optional[int] = None,
-            first_expert: int = 0) -> Any:
+            first_expert: int = 0, activation: Optional[str] = None) -> Any:
     """The whole sparse sublayer after the router, under the trace's
     scopes ``moe_dispatch`` / ``moe_experts`` / ``moe_combine``: tokens
     ``h [N, d]`` with their ``[N, k]`` routing -> ``[N, d]``.
 
     The expert's shape is the caller's: with ``gate`` [E, d, f] it is
     :func:`swiglu_experts`' three matrices, with ``gate=None``
-    :func:`relu2_experts`' two; dispatch, share, sentinel and the
-    ``live`` select are one code for both.
+    :func:`relu2_experts`' two, and ``activation="reglu"`` takes the
+    three through :func:`reglu_experts` (a static choice); dispatch,
+    share, sentinel and the ``live`` select are one code for all three.
 
     The layer is told which experts it holds: the router chose among
     ``n_routed`` experts (default: as many as ``up`` holds) and the
@@ -436,6 +464,7 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
     rows (the whole layer, or a small problem) moves them as a layer
     that holds every expert does, the absent rows cut off by a select.
     Every assignment on a held expert is computed either way."""
+    assert activation in (None, "reglu"), activation
     n_held = up.shape[0]
     share = n_routed is not None and (n_routed, first_expert) != (n_held, 0)
     if share:
@@ -446,5 +475,6 @@ def moe_mlp(h: Any, weights: Any, experts: Any, gate: Any, up: Any,
         weights = jnp.where(held, weights, 0)
         capacity = held_capacity(experts.size, n_held, n_routed)
         if capacity < experts.size:
-            return _share_mlp(capacity, h, weights, experts, gate, up, down)
-    return _all_rows(h, weights, experts, gate, up, down, share)
+            return _share_mlp(capacity, activation, h, weights, experts, gate,
+                              up, down)
+    return _all_rows(h, weights, experts, gate, up, down, share, activation)
