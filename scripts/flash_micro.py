@@ -1,5 +1,5 @@
 """The three flash kernels alone at a cell's call shape (run by hand on the
-chip; PERF.md section 6, PR 45): ``flash_fwd``, ``flash_dq`` and
+chip; PERF.md section 6, PRs 45 and 51): ``flash_fwd``, ``flash_dq`` and
 ``flash_dkv`` of ``ops/flash.py``, ms a call each, with the grid steps a
 head the call takes and the rectangle of blocks would (``_grid_steps``).
 
@@ -8,14 +8,14 @@ head the call takes and the rectangle of blocks would (``_grid_steps``).
 
 ``--parent`` names a second ``ops/flash.py`` that is read in the same
 process: its kernels run on the same operands, turn about with this tree's,
-``out``, ``lse``, ``dq``, ``dk`` and ``dv`` are compared bit for bit, and the
-difference a call is divided by the grid steps this tree no longer takes.
-``--cells`` picks the shapes: ``joyai`` (q, k ``[128, 8192, 192]``, v
-``[128, 8192, 128]``: ``joyai-ep16-solo-steady`` and kimi's one MLA layer)
-and ``nemo3`` (``[128, 8192, 128]`` all three), bf16, causal, the blocks the
-kernels choose from the shape. Prints one JSON object and writes it to
-``chiprun_out/flash_micro.json``. A CPU run (the interpreter, a small shape
-forced into the streamed regime) gives agreement and step counts only.
+and ``out``, ``lse``, ``dq``, ``dk`` and ``dv`` are compared bit for bit. A
+timed call is the jitted wrapper: the kernel and whatever XLA lays out
+around it. ``--cells`` picks the shapes (``_CELLS``: a cell's call as ``[BH,
+S, Dqk / Dv]``, bf16, the blocks the kernels choose from the shape; a name
+ending in ``-swa`` is under a window, in ``-unmasked`` without the mask).
+Prints one JSON object and writes it to ``chiprun_out/flash_micro.json``. A
+CPU run (the interpreter, a small shape) gives agreement and step counts
+only.
 """
 
 from __future__ import annotations
@@ -31,6 +31,35 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
 _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+# cell -> ((heads x rows, sequence, Dqk, Dv, causal, window) on the chip,
+# the same on the CPU with the regime's threshold last): there the same
+# width ratio a quarter the size, at a tile ratio of 1 : 2; the resident
+# cell keeps its regime, the others are streamed by force
+_CELLS = {
+    "joyai": ((128, 8192, 192, 128, True, None),
+              (2, 512, 48, 32, True, None, 0)),
+    "nemo3": ((128, 8192, 128, 128, True, None),
+              (2, 512, 32, 32, True, None, 0)),
+    # the calls PR 51 was held to, bit for bit: resident at 64 wide,
+    # streamed at 192 / 128 and at 128, masked, windowed and not
+    "c111m": ((192, 2048, 64, 64, True, None),
+              (2, 512, 16, 16, True, None, None)),
+    "c111m-unmasked": ((192, 2048, 64, 64, False, None),
+                       (2, 512, 16, 16, False, None, None)),
+    "joyai-ep16": ((80, 8192, 192, 128, True, None),
+                   (2, 512, 48, 32, True, None, 0)),
+    "smallthinker": ((56, 16384, 128, 128, True, None),
+                     (2, 512, 32, 32, True, None, 0)),
+    "smallthinker-swa": ((56, 16384, 128, 128, True, 4096),
+                         (2, 512, 32, 32, True, 256, 0)),
+    "smallthinker-unmasked": ((56, 16384, 128, 128, False, None),
+                              (2, 512, 32, 32, False, None, 0)),
+    # the shortest sweeps of any cell (512 keys: one or two tiles a q
+    # block), where whatever a kernel does once a q block shows most
+    "phi4flash-swa": ((80, 8192, 64, 128, True, 512),
+                      (2, 512, 16, 32, True, 128, 0)),
+}
 
 
 def main() -> int:
@@ -58,14 +87,6 @@ def main() -> int:
         sides["parent"] = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(sides["parent"])
 
-    # cell -> (heads x rows, sequence, Dqk, Dv, streamed by force): on the
-    # CPU the same width ratio a quarter the size, at a tile ratio of 1 : 2
-    shapes = {
-        "joyai": (128, 8192, 192, 128, None) if on_chip
-        else (2, 512, 48, 32, 0),
-        "nemo3": (128, 8192, 128, 128, None) if on_chip
-        else (2, 512, 32, 32, 0),
-    }
     out = {"device": jax.devices()[0].device_kind, "calls": args.calls}
 
     def time_ms(fn, *a):
@@ -80,31 +101,35 @@ def main() -> int:
         return 1e3 * sorted(seen)[1]
 
     for cell in args.cells:
-        bh, seq, dqk, dv, threshold = shapes[cell]
+        chip, cpu = _CELLS[cell]
+        bh, seq, dqk, dv, causal, window, threshold = (
+            (*chip, None) if on_chip else cpu)
         rng = np.random.default_rng(45)
         q, k, v, g = (
             jnp.asarray(rng.standard_normal((bh, seq, w)), jnp.bfloat16)
             for w in (dqk, dqk, dv, dv))
         scale = 1.0 / dqk ** 0.5
-        blocks = (flash._choose_blocks(seq, dqk, 2, v_dim=dv) if on_chip
-                  else (64, 128))
-        live, rectangular = flash._grid_steps(seq, *blocks)
+        blocks = (flash._choose_blocks(seq, dqk, 2, v_dim=dv, window=window)
+                  if on_chip else (64, 128))
+        live, rectangular = flash._grid_steps(seq, *blocks, window)
         entry = {"q": [bh, seq, dqk], "v": [bh, seq, dv], "blocks": blocks,
-                 "grid_steps_a_head": {"live": live,
-                                       "rectangular": rectangular}}
+                 "causal": causal, "window": window,
+                 "grid_steps_a_head": {
+                     "live": live if causal else rectangular,
+                     "rectangular": rectangular}}
 
         # the operands are arguments (a closed-over array is a constant of
         # the program); dq and dkv are two results of one builder, and the
         # one a function does not return is dead code to XLA
         def kernels(mod):
-            common = (True, scale, *blocks, not on_chip, threshold)
+            common = (causal, scale, *blocks, not on_chip, threshold)
 
             def forward(q, k, v):
-                return mod._flash_forward(q, k, v, *common)
+                return mod._flash_forward(q, k, v, *common, window=window)
 
             def backward(q, k, v, g, lse, delta):
                 return mod._flash_backward_core(q, k, v, g, lse, delta,
-                                                *common)
+                                                *common, window=window)
             return {
                 "flash_fwd": jax.jit(forward),
                 "flash_dq": jax.jit(lambda *a: backward(*a)[0]),
@@ -147,14 +172,9 @@ def main() -> int:
                 for side, per in ms.items()}
             entry["ms_a_call_every_round"] = ms
             if "parent" in ms:
-                gone = bh * (rectangular - live)
                 entry["gain_ms_a_call"] = {
                     name: entry["ms_a_call"]["parent"][name]
                     - entry["ms_a_call"]["this"][name] for name in _KERNELS}
-                entry["us_a_step_gone"] = {
-                    name: 1e3 * gain / gone
-                    for name, gain in entry["gain_ms_a_call"].items()}
-                entry["steps_gone_a_call"] = gone
         out[cell] = entry
         print(cell, json.dumps(entry), flush=True)
         del built

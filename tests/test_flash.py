@@ -201,24 +201,31 @@ _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 _REGIMES = {"resident": None, "streamed": 0}
 
 
-def _kernel_dots(jaxpr, found, kernel=None):
-    """{kernel name: [(lhs dtype, rhs dtype, out dtype), ...]} of every
-    dot_general inside every pallas_call of ``jaxpr``, loops and branches
-    included."""
+def _eqns(jaxpr, kernel=None):
+    """Every equation of ``jaxpr`` — loops, branches and kernel bodies
+    included — with the name of the ``pallas_call`` it stands inside
+    (``None`` outside any)."""
     for eqn in jaxpr.eqns:
-        name = kernel
-        if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-        elif eqn.primitive.name == "dot_general" and kernel is not None:
-            found.setdefault(kernel, []).append(
-                tuple(v.aval.dtype for v in (*eqn.invars, *eqn.outvars))
-            )
+        yield eqn, kernel
+        inside = (eqn.params["name"] if eqn.primitive.name == "pallas_call"
+                  else kernel)
         for param in eqn.params.values():
             for sub in (param if isinstance(param, (list, tuple))
                         else [param]):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _kernel_dots(sub, found, name)
+                    yield from _eqns(sub, inside)
+
+
+def _kernel_dots(jaxpr):
+    """{kernel name: [(lhs dtype, rhs dtype, out dtype), ...]} of every
+    dot_general inside every pallas_call of ``jaxpr``."""
+    found = {}
+    for eqn, kernel in _eqns(jaxpr):
+        if eqn.primitive.name == "dot_general" and kernel is not None:
+            found.setdefault(kernel, []).append(
+                tuple(v.aval.dtype for v in (*eqn.invars, *eqn.outvars))
+            )
     return found
 
 
@@ -246,7 +253,7 @@ def test_kernel_matmuls_are_f32_whatever_arrives(kernel, regime,
     grad = jax.grad(loss, argnums=(0, 1, 2))
     jaxpr = jax.make_jaxpr(grad)(q, q, q)
     assert all(g.dtype == dtype for g in jax.eval_shape(grad, q, q, q))
-    dots = _kernel_dots(jaxpr.jaxpr, {})
+    dots = _kernel_dots(jaxpr.jaxpr)
     assert set(dots) == set(_KERNELS)
     per_tile = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}[kernel]
     assert len(dots[kernel]) >= per_tile
@@ -434,20 +441,23 @@ def test_tile_rule_takes_both_widths() -> None:
 
 
 # sha256 of the jaxpr (source positions cut out) of the gradient of a
-# flash call with ONE head width, as the commit before the value width
-# (a4592dd) traced it: forward, dq and dkv, the block shapes, the grids
-# and every instruction of the kernels. A change that moves these moves
-# what the c111m / c1p3b / olmoe cells run; regenerate on purpose only.
-# The streamed causal call was regenerated on purpose in PR 45 (its grid
-# enumerates the live tiles only; ccc05cff... before); the streamed call
-# WITHOUT the mask is pinned as PR 44's tree (2717011) traced it.
+# flash call with ONE head width: forward, dq and dkv, the block shapes,
+# the grids and every instruction of the kernels. A change that moves
+# these moves what the c111m / c1p3b / olmoe cells run; regenerate on
+# purpose only. Pinned first as the commit before the value width
+# (a4592dd) traced them; the streamed causal call regenerated in PR 45
+# (its grid enumerates the live tiles only); all three regenerated in
+# PR 51: lse leaves the forward, and lse and delta reach dq, as [BH, 1, S]
+# rows, and the kernels turn them to and from the tile's [BQ, 1] columns
+# in VMEM (f8b02ef3..., 0af33485... and 049a6f74... before; every output
+# stayed bit for bit: scripts/flash_micro.py --parent on the chip).
 _EQUAL_WIDTH_JAXPR = {
     ("resident", True):
-        "f8b02ef3012e1b54bd009de82187ab7387fa71a6f08f9896f031d1589227e58e",
+        "4f629dc8e60f4c0d602d36f6df83c86843238c17e5c908829e96ccf433fd2874",
     ("streamed", True):
-        "0af334852f724114d76129a1d1929eafe5fdd5c3828193cf1e9417963a4f920e",
+        "7fb6fbf792643a53493b633772df327f2ced650b91e9990a5c9d938da7865c05",
     ("streamed", False):
-        "049a6f742164814830be2c32337c590c5abf2f3c8b6e70ba343890f9638dc0a2",
+        "373a1e8194f3af15891f78a9d74deaa154e538f28f422136f052048570f90078",
 }
 
 
@@ -568,7 +578,10 @@ def _dkv_in_table_order(q, k, v, do, lse, delta, scale, block_q, block_k):
      # latent attention's 192 / 128 a quarter the size, at the cell's ratio
      (1024, 128, 256, (48, 32)), (768, 128, 128, (48, 32)),
      # neither edge divides the other
-     (768, 384, 256, (32, 32)), (768, 256, 384, (32, 32))],
+     (768, 384, 256, (32, 32)), (768, 256, 384, (32, 32)),
+     # shorter than a lane tile (PR 51): the statistics' [1, BQ] rows are
+     # then blocks as long as the whole dimension, one tile and several
+     (64, 64, 64, (32, 32)), (96, 32, 32, (48, 32))],
 )
 def test_causal_kernels_agree_across_regimes_bit_for_bit(
         seq_len, block_q, block_k, widths, dtype, regime) -> None:
@@ -859,3 +872,108 @@ def test_the_tile_rule_under_a_window(seq_len, head_dim, v_dim, window,
     assert got == blocks == _choose_blocks(
         seq_len, head_dim, 2, v_dim=v_dim, window=window)
     assert _grid_steps(seq_len, *got, window) == steps
+
+
+# ------------------------------------------------------------ PR 51 contracts
+# The row statistics cross HBM lane-dense: (a) no flash call takes or gives
+# a [.., S, 1] f32 array, and dq and dkv take the same two, (b) the calls
+# compile for the chip at the cells' shapes and plan no padded copy.
+
+def _flash_calls(jaxpr):
+    """{kernel name: [eqn, ...]} of every ``pallas_call`` named ``flash_*``
+    in ``jaxpr``."""
+    found = {}
+    for eqn, _ in _eqns(jaxpr):
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"].startswith("flash_")):
+            found.setdefault(eqn.params["name"], []).append(eqn)
+    return found
+
+
+_MASKS = {"causal": {}, "window": {"window": 200},
+          "unmasked": {"causal": False}}
+
+
+@pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128)],
+                         ids=["64", "128", "192-128"])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+@pytest.mark.parametrize("regime", sorted(_REGIMES))
+def test_the_row_statistics_cross_hbm_lane_dense(regime, mask,
+                                                 widths) -> None:
+    # An f32 array whose last dimension is 1 is tiled (8, 128) in HBM: one
+    # lane of 128 used, 512 bytes a query row (PERF.md, PRs 47 and 51). lse
+    # and delta are [BH, S] between the calls and reach every kernel as the
+    # ONE [BH, 1, S] view; the [BQ, 1] columns exist in VMEM only.
+    dqk, dv = widths
+    b, s, h = 1, 512, 2
+    q = jnp.zeros((b, s, h, dqk), jnp.bfloat16)
+    v = jnp.zeros((b, s, h, dv), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, block_q=128, block_k=256, interpret=True,
+            _resident_kv_bytes=_REGIMES[regime], **_MASKS[mask],
+        ).astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+    calls = _flash_calls(jaxpr.jaxpr)
+    assert {k: len(c) for k, c in calls.items()} == dict.fromkeys(_KERNELS, 1)
+    f32 = jnp.dtype(jnp.float32)
+    for name, (eqn,) in calls.items():
+        for var in (*eqn.invars, *eqn.outvars):
+            aval = var.aval
+            assert not (aval.dtype == f32 and aval.ndim and aval.shape[-1]
+                        == 1), (name, aval)
+    row = jax.core.ShapedArray((b * h, 1, s), f32)
+    (fwd,), (dq,), (dkv,) = (calls[k] for k in _KERNELS)
+    assert fwd.outvars[1].aval == row
+    # the very same two operands, not two equal views
+    assert dq.invars[-2:] == dkv.invars[-2:]
+    assert [x.aval for x in dq.invars[-2:]] == [row, row]
+
+
+@pytest.mark.parametrize("bh,seq_len,widths,window", [
+    (8, 2048, (64, 64), None),        # c111m's call: resident
+    (4, 8192, (192, 128), None),      # joyai's: streamed, two widths
+    (4, 16384, (128, 128), 4096),     # smallthinker's windowed call
+], ids=["c111m", "joyai", "smallthinker-swa"])
+def test_the_calls_compile_for_the_v5e_with_no_padded_statistic(
+        one_chip, bh, seq_len, widths, window) -> None:
+    """Mosaic takes the two turns of a statistic through the transpose
+    unit at the cells' tiles (the interpreter takes anything), and the
+    program around the three kernels holds no ``f32[BH, S, 1]`` array in
+    any layout (PERF.md, PR 51: it held lse out of the forward, its slice's
+    operand and both of dq's operands so)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops.flash import (
+        _choose_blocks, _flash_backward_core, _flash_forward,
+    )
+
+    dqk, dv = widths
+    blocks = _choose_blocks(seq_len, dqk, 2, v_dim=dv, window=window)
+    common = (True, 1.0 / dqk ** 0.5, *blocks, False, None)
+
+    def sd(width):
+        return jax.ShapeDtypeStruct(
+            (bh, seq_len, width), jnp.bfloat16, sharding=one_chip)
+
+    def both(q, k, v, g):
+        out, lse = _flash_forward(q, k, v, *common, window=window)
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        return out, lse, _flash_backward_core(
+            q, k, v, g, lse, delta, *common, window=window)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(both).lower(
+            sd(dqk), sd(dqk), sd(dv), sd(dv)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    for kernel in _KERNELS:
+        assert f"{kernel}/pallas_call" in text, kernel
+    assert f"f32[{bh},1,{seq_len}]" in text
+    assert f"f32[{bh},{seq_len},1]" not in text

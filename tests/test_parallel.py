@@ -319,3 +319,57 @@ def test_ring_attention_flash_grad_matches_einsum_grad() -> None:
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5
         )
+
+
+def test_flash_ring_rides_on_lse_and_delta_as_bhs_rows() -> None:
+    # parallel/ring.py's two surfaces of ops/flash.py after PR 51 (the
+    # kernels hand lse and delta about as [BH, 1, S] rows): the forward
+    # still gives lse [B, H, S] f32 — the log-sum-exp of the scaled scores
+    # itself —, the block backward still takes lse and delta so, and the
+    # flash ring's forward and gradients are the einsum-block ring's.
+    from torchft_tpu.ops.flash import (
+        flash_attention_with_lse, flash_block_attention_bwd,
+    )
+
+    mesh = ft_mesh({"seq": 4}, devices=jax.devices()[:4])
+    B, S, H, D = 2, 64, 2, 16
+    rng = np.random.default_rng(51)
+    q, k, v, g = (jnp.asarray(rng.standard_normal((B, S, H, D)),
+                              dtype=jnp.float32) for _ in range(4))
+
+    out, lse = flash_attention_with_lse(
+        q, k, v, causal=True, block_q=16, block_k=16, interpret=True)
+    assert lse.shape == (B, H, S) and lse.dtype == jnp.float32
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / D ** 0.5
+    scores = jnp.where(np.tril(np.ones((S, S), dtype=bool)), scores,
+                       -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(scores, axis=-1)),
+        atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_reference_attention(q, k, v, True)),
+        atol=2e-5, rtol=2e-5)
+    delta = jnp.sum(g * out, axis=-1).transpose(0, 2, 1)
+    assert delta.shape == lse.shape
+    grads = flash_block_attention_bwd(
+        q, k, v, g, lse, delta, causal=True, block_q=16, block_k=16,
+        interpret=True)
+    want = jax.grad(
+        lambda q, k, v: jnp.sum(_reference_attention(q, k, v, True) * g),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(grads, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+    spec = NamedSharding(mesh, P(None, "seq", None, None))
+    qs, ks, vs = (jax.device_put(x, spec) for x in (q, k, v))
+    rings = [make_ring_attention(mesh, "seq", causal=True, **kw)
+             for kw in ({}, dict(block_impl="flash", block_q=8, block_k=8,
+                                 interpret=True))]
+    blockwise, flash = (
+        (jax.jit(ring)(qs, ks, vs), *jax.jit(jax.grad(
+            lambda q, k, v, ring=ring: jnp.sum(ring(q, k, v) * g),
+            argnums=(0, 1, 2)))(qs, ks, vs)) for ring in rings)
+    for a, b in zip(flash, blockwise):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)

@@ -86,12 +86,27 @@ of the tile's arithmetic: there a 512 x 1024 tile is tried first
 (parallel/ring.py and the tests pass them).
 
 Mosaic layout note: per-row statistics (lse, delta) are [BH, S] f32 in
-HBM and reach the kernels as views of that array whose last two dims are
-tile-legal: [BH, S, 1] columns for the forward and dq (rows of a score
-tile are q positions; [BQ, 1] keepdims math) and [BH, 1, S] rows for dkv
-(columns of the transposed tile are q positions; [1, BQ]). (jax's
-reference TPU kernel broadcasts lse across 128 lanes instead; a singleton
-dim costs 128x less HBM traffic and lowers fine.)
+HBM and reach EVERY kernel as the one view of that array whose minor
+dimension is the sequence: [BH, 1, S] rows, q positions along the lanes,
+4 bytes a query row. The forward writes lse so and dq and dkv are handed
+the same two operands. A [BH, S, 1] column — the form the forward's and
+dq's tile arithmetic takes, rows of their score tiles being q positions
+([BQ, 1] keepdims) — is tiled (8, 128) in HBM like any f32 array: one
+lane of 128 used, 512 bytes a query row, 128 x the logical size there and
+in every DMA (a 4 KB tile for 32 useful bytes). PERF.md has what that
+cost while the statistics travelled so: one call of 160 heads at 8k
+planned 15.04 GiB (PR 47); 335 MB a call at 80 heads of 8k, written by
+the forward, read back by XLA to slice it, written twice more for dq
+(PR 50); 1.86 GiB of ``c111m``'s peak and 2 % of its step (PR 51). So the
+[BQ, 1] column lives in VMEM only: the forward turns ``m + log(l)`` into
+its [1, BQ] row once a q block (``_wide_to_row``), dq turns both rows into
+columns once a q block (``_rows_to_cols``: at the top of the resident
+kernel, into scratch at the first step of a streamed sweep), and dkv,
+whose transposed tile has q positions along its columns, reads the rows
+as they are. Both turns go through the transpose unit as a 128-lane-wide
+copy: exact, where an identity product at the MXU's default precision
+would round lse to bf16. Every result is bit for bit what the column
+layout gave (``scripts/flash_micro.py --parent``).
 
 ``interpret=True`` runs the same kernels through the Pallas interpreter
 (the CPU tests); the default compiles them with Mosaic.
@@ -292,6 +307,36 @@ def _f32(ref_slice):
     return ref_slice.astype(jnp.float32)
 
 
+# A row statistic changes hands between its two forms, the [1, BQ] row HBM
+# holds and the [BQ, 1] column a score tile's keepdims arithmetic takes,
+# through the transpose unit, as a 128-lane-wide tile, which Mosaic
+# transposes natively: every element is copied, none computed, so all 24
+# bits arrive (a product with an identity at the MXU's default precision
+# would round them to bf16). Once a q block, never a score tile. Measured
+# on the v5e (PERF.md, PR 51): a turn costs what its tile's 64 vregs cost
+# the transpose unit whatever is in them (an 8-lane tile reads the same),
+# so dq's two statistics share one.
+_LANES = 128
+
+
+def _wide_to_row(wide):
+    """``[BQ, _LANES]``, a ``[BQ, 1]`` column in every lane (q positions
+    down the sublanes: what a score tile's row reductions give, broadcast),
+    as the ``[1, BQ]`` row HBM holds."""
+    return wide.T[:1]
+
+
+def _rows_to_cols(lse_row, delta_row):
+    """The two ``[1, BQ]`` rows HBM holds as ONE ``[BQ, _LANES]`` tile:
+    lse's column in the even lanes, delta's in the odd, so ``[:, :1]`` and
+    ``[:, 1:2]`` of it are the two ``[BQ, 1]`` columns and both statistics
+    cross the transpose unit in one turn."""
+    shape = (_LANES, lse_row.shape[1])
+    even = jax.lax.broadcasted_iota(jnp.int32, shape, 0) % 2 == 0
+    return jnp.where(even, jnp.broadcast_to(lse_row, shape),
+                     jnp.broadcast_to(delta_row, shape)).T
+
+
 def _scores(q, k, qi, ki, masked: bool, transposed: bool = False,
             window: Optional[int] = None):
     """S = Q·Kᵀ for one tile ([BQ, BK]; ``transposed``: Sᵀ = K·Qᵀ,
@@ -355,7 +400,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     )
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)
+    lse_ref[0] = _wide_to_row(
+        jnp.broadcast_to(m + jnp.log(l), (block_q, _LANES)))
 
 
 def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
@@ -400,7 +446,7 @@ def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
     """A streamed call's ``(grid, tables, by_q, by_k, q_lanes)``: the grid,
     the scalar-prefetch operands and the index maps of a block of q rows
     ([.., BQ, D]), of k rows and of q positions along the lanes ([.., 1,
-    BQ], dkv's statistics). Without the mask the grid is the rectangle of
+    BQ], the statistics). Without the mask the grid is the rectangle of
     blocks, swept axis innermost, and nothing is prefetched; under it one
     axis enumerates :func:`_live_tiles` and the maps read the tile's blocks
     off the two tables."""
@@ -455,10 +501,10 @@ def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
 
     @pl.when(ki == last)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[...]          # [BQ, _LANES], every lane alike, as m_ref
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:, :1] + jnp.log(l)
+        o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
+        lse_ref[0] = _wide_to_row(m_ref[...] + jnp.log(l))
 
 
 # KV footprint above which the k-streamed kernel is used (resident variant
@@ -477,10 +523,10 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
     kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
-    # lse travels as [BH, S, 1] (see module docstring: tile-legal specs)
+    # lse leaves as [BH, 1, S] rows (module docstring: lane-dense)
     out_shapes = (
         jax.ShapeDtypeStruct((bh, seq_len, dv), q.dtype),
-        jax.ShapeDtypeStruct((bh, seq_len, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
     )
     if kv_bytes <= threshold:
         grid = (bh, seq_len // block_q)
@@ -503,16 +549,16 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             ],
             out_shape=out_shapes,
             interpret=interpret,
             name="flash_fwd",
         )(q, k, v)
-        return out, lse[..., 0]
+        return out, lse[:, 0, :]
 
     # Long context: stream K/V tiles via the grid.
-    grid, tables, by_q, by_k, _ = _streamed_grid(
+    grid, tables, by_q, by_k, q_lanes = _streamed_grid(
         bh, seq_len, block_q, block_k, causal, True, window=window
     )
     kernel = functools.partial(
@@ -526,8 +572,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     )
     scratch = [
         pltpu.VMEM((block_q, dv), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
-        pltpu.VMEM((block_q, 128), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
     ]
     out, lse = pl.pallas_call(
         kernel,
@@ -541,7 +587,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, dv), by_q),
-                pl.BlockSpec((1, block_q, 1), by_q),
+                pl.BlockSpec((1, 1, block_q), q_lanes),
             ],
             scratch_shapes=scratch,
         ),
@@ -549,7 +595,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         interpret=interpret,
         name="flash_fwd",
     )(*tables, q, k, v)
-    return out, lse[..., 0]
+    return out, lse[:, 0, :]
 
 
 # ------------------------------------------------------------- backward pass
@@ -618,8 +664,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     q = _f32(q_ref[0]) * scale                    # [BQ, Dqk]
     do = _f32(do_ref[0])                          # [BQ, Dv]
-    lse = lse_ref[0]                              # [BQ, 1]
-    delta = delta_ref[0]                          # [BQ, 1]
+    cols = _rows_to_cols(lse_ref[0], delta_ref[0])
+    lse, delta = cols[:, :1], cols[:, 1:2]        # [BQ, 1] each
 
     def tile(ki, dq, masked):
         k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
@@ -668,20 +714,24 @@ def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
                                   seq_len: int, causal: bool, scale: float,
                                   window: Optional[int] = None):
     """K/V tiles ride the innermost grid dim (long-context regime); dq
-    accumulates in VMEM scratch across the k sweep."""
+    accumulates in VMEM scratch across the k sweep, beside the q block's
+    two statistics as columns (``_rows_to_cols``), made from their rows at
+    the sweep's first step."""
     qi, ki, first, last, refs = _streamed_tile(
         refs, block_q, block_k, seq_len, causal, True, window=window
     )
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
+     cols) = refs
 
     @pl.when(ki == first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        cols[...] = _rows_to_cols(lse_ref[0], delta_ref[0])
 
     def _accumulate(masked):
         dq_acc[...] = dq_acc[...] + _dq_tile(
             _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
-            _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+            _f32(do_ref[0]), cols[:, :1], cols[:, 1:2], qi, ki, masked,
             window=window,
         )
 
@@ -727,17 +777,15 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
+def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
                              scale: float, block_q: int, block_k: int,
                              interpret: bool, window: Optional[int] = None):
+    """The streamed dq and dkv calls; ``lse_row`` and ``delta_row`` are the
+    ``[BH, 1, S]`` views both take."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
-    # tile-legal views of the [BH, S] statistics (module docstring):
-    # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
-    lse, lse_row = lse[..., None], lse[:, None, :]
-    delta, delta_row = delta[..., None], delta[:, None, :]
 
-    grid, tables, by_q, by_k, _ = _streamed_grid(
+    grid, tables, by_q, by_k, q_lanes = _streamed_grid(
         bh, seq_len, block_q, block_k, causal, True, window=window
     )
     dq = pl.pallas_call(
@@ -754,16 +802,19 @@ def _flash_backward_streamed(q, k, v, g, lse, delta, causal: bool,
                 pl.BlockSpec((1, block_k, d), by_k),
                 pl.BlockSpec((1, block_k, dv), by_k),
                 pl.BlockSpec((1, block_q, dv), by_q),
-                pl.BlockSpec((1, block_q, 1), by_q),
-                pl.BlockSpec((1, block_q, 1), by_q),
+                pl.BlockSpec((1, 1, block_q), q_lanes),
+                pl.BlockSpec((1, 1, block_q), q_lanes),
             ],
             out_specs=pl.BlockSpec((1, block_q, d), by_q),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_dq",
-    )(*tables, q, k, v, g, lse, delta)
+    )(*tables, q, k, v, g, lse_row, delta_row)
 
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
         bh, seq_len, block_q, block_k, causal, False, window=window
@@ -834,15 +885,14 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
     kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
+    # the one view of the [BH, S] statistics that dq and dkv both take
+    # (module docstring): [BH, 1, S] rows, q positions along the lanes
+    lse_row, delta_row = lse[:, None, :], delta[:, None, :]
     if kv_bytes > threshold:
         return _flash_backward_streamed(
-            q, k, v, g, lse, delta, causal, scale, block_q, block_k,
-            interpret, window=window,
+            q, k, v, g, lse_row, delta_row, causal, scale, block_q,
+            block_k, interpret, window=window,
         )
-    # tile-legal views of the [BH, S] statistics (module docstring):
-    # [BH, S, 1] columns for the dq sweep, [BH, 1, S] rows for dkv
-    lse, lse_row = lse[..., None], lse[:, None, :]
-    delta, delta_row = delta[..., None], delta[:, None, :]
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
@@ -856,14 +906,14 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, seq_len, dv), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_dq",
-    )(q, k, v, g, lse, delta)
+    )(q, k, v, g, lse_row, delta_row)
 
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
