@@ -28,12 +28,14 @@ STEP_TIMINGS = ("ddp_step_pack", "ddp_step_fetch", "ddp_step_copy",
                 "ddp_step_submit", "ddp_step_cpu", "ddp_step_land_tail",
                 "ddp_wire_exposed", "ddp_wire_total", "ddp_d2h_total",
                 "ddp_h2d_total")
-# once a bucket
-BUCKET_TIMINGS = ("ddp_land_queue", "ddp_d2h", "ddp_h2d")
+# once a bucket (a bucket is one op on the wire: its hand-over to the
+# lanes, and the last lane resolving its future)
+BUCKET_TIMINGS = ("ddp_land_queue", "ddp_d2h", "ddp_h2d", "ddp_submit",
+                  "comm_op_resolve")
 # once a lane's share of a bucket (a ring sub-op)
 SUBOP_TIMINGS = ("comm_wire_reduce", "comm_submit_wire",
                  "comm_subop_exchange", "comm_subop_reduce",
-                 "comm_subop_cpu")
+                 "comm_subop_cpu", "comm_subop_first_hop")
 GONE = ("ddp_wire", "comm_reduce_future")
 
 
@@ -70,6 +72,9 @@ class _StubManager:
     def wire_compensable(self) -> bool:
         return False
 
+    def next_wire_op(self) -> int:
+        return self._ctx.next_grad_op()
+
     def allreduce_arrays(self, arrays, op=ReduceOp.SUM) -> Work:
         work = self._ctx.allreduce(list(arrays), ReduceOp.SUM)
         scale = 1.0 / self._world
@@ -80,7 +85,7 @@ class _StubManager:
                 np.multiply(a, a.dtype.type(scale), out=a)
             return reduced
 
-        return Work(future_chain(work.future(), _avg))
+        return Work(future_chain(work.future(), _avg), op=work.op)
 
 
 def _grads(rank: int) -> Dict[str, np.ndarray]:
@@ -93,23 +98,27 @@ def _grads(rank: int) -> Dict[str, np.ndarray]:
 N_BUCKETS = 5
 
 
-def _run(world: int, prefix: str, trace_dir: "str | None" = None
+def _run(world: int, prefix: str, trace_dir: "str | None" = None,
+         streamed: bool = True, chunk_bytes: "int | None" = None,
          ) -> List[Dict[str, Any]]:
     """``STEPS`` classic steps of ``world`` groups as threads of this
     process over a ring of real sockets. Returns, a rank: the sink, and a
     pair a step of the test's own clock around ``average_gradients`` and
-    the tiling the sink observed for it."""
+    the tiling the sink observed for it. ``chunk_bytes`` deals a bucket's
+    chunks over both lanes: two sub-ops an op."""
     import jax
 
     store = StoreServer()
-    ctxs = [TcpCommContext(timeout=15.0, algorithm="ring", channels=2)
+    ctxs = [TcpCommContext(timeout=15.0, algorithm="ring", channels=2,
+                           chunk_bytes=chunk_bytes)
             for _ in range(world)]
     out: List[Dict[str, Any]] = [{} for _ in range(world)]
 
     def worker(rank: int) -> None:
         mgr = _StubManager(ctxs[rank], world, f"bm_{rank}_0_test")
         ctxs[rank].configure(f"{store.addr}/{prefix}", rank, world)
-        ddp = DistributedDataParallel(mgr, bucket_bytes=4096)
+        ddp = DistributedDataParallel(mgr, bucket_bytes=4096,
+                                      streamed=streamed)
         grads = _grads(rank)
         pairs = []
         for step in range(STEPS):
@@ -185,6 +194,17 @@ def test_a_sub_ops_seams_lie_inside_its_wall(classic) -> None:
             <= sum(t["comm_wire_reduce"]) + 1e-4
 
 
+def test_the_first_hop_lies_inside_the_exchange(classic) -> None:
+    # sub-op start -> the end of its first _ring_sendrecv: the stretch
+    # before that hop is Python between the seams, so, summed, the first
+    # hops fit in what the walls leave once the reductions are taken out
+    for rank in classic:
+        t = rank["metrics"]._timings
+        assert min(t["comm_subop_first_hop"]) >= 0.0
+        assert sum(t["comm_subop_first_hop"]) <= (
+            sum(t["comm_wire_reduce"]) - sum(t["comm_subop_reduce"]) + 1e-4)
+
+
 def test_pack_wire_tail_and_landing_tail_tile_the_call(classic) -> None:
     """``ddp_step_pack + ddp_wire_exposed + ddp_step_land_tail`` is the
     step thread's time inside ``average_gradients``, measured here from
@@ -197,15 +217,17 @@ def test_pack_wire_tail_and_landing_tail_tile_the_call(classic) -> None:
                 outside, tiled)
 
 
-def test_spans_carry_replica_and_step_on_their_threads_lines(tmp_path) -> None:
+def _traced(trace_dir: str, prefix: str, **how: Any
+            ) -> Dict[str, List[Any]]:
+    """The ``tft.*`` spans of a traced ``_run``: {span name: [(line,
+    stats)]}; a thread's line is told by its place in the plane (the
+    profiler names every Python thread's line alike)."""
     from jax.profiler import ProfileData
 
-    _run(WORLD, "step_path_traced", trace_dir=str(tmp_path))
+    _run(WORLD, prefix, trace_dir=trace_dir, **how)
     (path,) = glob.glob(
-        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
     )
-    # {span name: [(line, stats)]}; a thread's line is told by its place
-    # in the plane (the profiler names every Python thread's line alike)
     found: Dict[str, List[Any]] = {}
     for p, plane in enumerate(ProfileData.from_file(path).planes):
         for i, line in enumerate(plane.lines):
@@ -214,6 +236,11 @@ def test_spans_carry_replica_and_step_on_their_threads_lines(tmp_path) -> None:
                     found.setdefault(e.name[len(SPAN_PREFIX):], []).append(
                         ((p, i), dict(e.stats))
                     )
+    return found
+
+
+def test_spans_carry_replica_and_step_on_their_threads_lines(tmp_path) -> None:
+    found = _traced(str(tmp_path), "step_path_traced")
     replicas = {f"bm_{r}_0_test" for r in range(WORLD)}
     for name, events in found.items():
         assert {s.get("replica") for _l, s in events} <= replicas, name
@@ -243,6 +270,77 @@ def test_spans_carry_replica_and_step_on_their_threads_lines(tmp_path) -> None:
     assert all(len(owners) == 1 for owners in by_line.values())
     assert not set(by_line) & {line for _r, line in pack_lines}
     assert "ddp_wire" not in found and "comm_reduce_future" not in found
+
+
+@pytest.fixture(scope="module", params=["streamed", "lockstep"])
+def timeline(request, tmp_path_factory):
+    """A traced run a code path, every bucket dealt over both lanes."""
+    return _traced(
+        str(tmp_path_factory.mktemp(request.param)),
+        "step_path_" + request.param,
+        streamed=request.param == "streamed", chunk_bytes=2048,
+    )
+
+
+def _by_replica(events: List[Any]) -> Dict[str, List[Dict[str, Any]]]:
+    out: Dict[str, List[Dict[str, Any]]] = {}
+    for _line, stats in events:
+        out.setdefault(stats["replica"], []).append(stats)
+    return out
+
+
+def test_every_sub_op_says_whose_it_is(timeline) -> None:
+    lanes = timeline["comm_wire_reduce"]
+    # two sub-ops an op: a 4 KiB bucket is two chunks of 2 KiB
+    assert len(lanes) == WORLD * STEPS * N_BUCKETS * 2
+    for _line, stats in lanes:
+        assert {"op", "bytes", "queue_us", "lane", "replica"} <= set(stats)
+        assert stats["lane"] in (0, 1) and stats["bytes"] == 2048
+        assert stats["queue_us"] >= 0 and "step" not in stats
+
+
+def test_a_steps_spans_join_by_op_to_its_buckets(timeline) -> None:
+    submits = _by_replica(timeline["ddp_submit"])
+    assert len(submits) == WORLD
+    for replica, mine in submits.items():
+        # an op number is one bucket of one step, and a step's buckets
+        # are 0 ... n-1 in the order their ops were numbered
+        assert len({s["op"] for s in mine}) == len(mine) == STEPS * N_BUCKETS
+        for step in range(40, 40 + STEPS):
+            ops = sorted(s["op"] for s in mine if s["step"] == step)
+            assert [s["bucket"] for s in sorted(
+                (s for s in mine if s["step"] == step),
+                key=lambda s: s["op"])] == list(range(N_BUCKETS))
+            assert ops == list(range(ops[0], ops[0] + N_BUCKETS))
+        bucket_of = {s["op"]: (s["step"], s["bucket"]) for s in mine}
+        # the landing carries the same three
+        lands = _by_replica(timeline["ddp_h2d"])[replica]
+        assert sorted((s["op"], (s["step"], s["bucket"])) for s in lands) \
+            == sorted(bucket_of.items())
+        # the last lane resolves an op once, and every sub-op is an op's
+        resolved = _by_replica(timeline["comm_op_resolve"])[replica]
+        assert sorted(s["op"] for s in resolved) == sorted(bucket_of)
+        subops = _by_replica(timeline["comm_wire_reduce"])[replica]
+        assert {s["op"] for s in subops} == set(bucket_of)
+
+
+def test_an_ops_sub_ops_carry_the_ops_bytes_between_them(timeline) -> None:
+    for replica, mine in _by_replica(timeline["ddp_submit"]).items():
+        subops = _by_replica(timeline["comm_wire_reduce"])[replica]
+        for s in mine:
+            assert s["bytes"] == 4096 == sum(
+                x["bytes"] for x in subops if x["op"] == s["op"])
+            assert {x["lane"] for x in subops if x["op"] == s["op"]} \
+                == {0, 1}
+    for _line, stats in timeline["ddp_d2h"]:
+        assert stats["bytes"] == 4096
+
+
+def test_ranks_that_configured_together_number_their_ops_alike(
+        timeline) -> None:
+    per_rank = [sorted((s["op"], s["step"], s["bucket"]) for s in mine)
+                for mine in _by_replica(timeline["ddp_submit"]).values()]
+    assert per_rank[0] == per_rank[1]
 
 
 @pytest.fixture(scope="module")

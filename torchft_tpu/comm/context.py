@@ -55,10 +55,15 @@ class ReduceOp:
 class Work:
     """Handle for an in-flight collective (the c10d Work analog,
     ref process_group.py:150-187). ``future()`` resolves to the op's result
-    (list of np.ndarray) or raises the transport error."""
+    (list of np.ndarray) or raises the transport error. ``op`` is the
+    number the context gave a gradient op at submit, where it gives one
+    (``TcpCommContext.next_grad_op``): what the op's spans on a trace
+    carry as ``op=``; None otherwise."""
 
-    def __init__(self, fut: "Future[List[np.ndarray]]") -> None:
+    def __init__(self, fut: "Future[List[np.ndarray]]",
+                 op: Optional[int] = None) -> None:
         self._fut = fut
+        self.op = op
 
     def wait(self, timeout: "float | timedelta | None" = None) -> bool:
         if isinstance(timeout, timedelta):

@@ -501,13 +501,14 @@ class _OpState:
 
 class _PendingOp:
     __slots__ = ("opcode", "arrays", "op", "root", "fut", "t_submit",
-                 "chunks", "state", "owners")
+                 "chunks", "state", "owners", "seq", "nbytes")
 
     def __init__(self, opcode: int, arrays: List[np.ndarray], op: str,
                  root: int, fut: Future,
                  chunks: "Optional[List[np.ndarray]]" = None,
                  state: "Optional[_OpState]" = None,
-                 owners: "Optional[List[int]]" = None) -> None:
+                 owners: "Optional[List[int]]" = None,
+                 seq: "Optional[int]" = None, nbytes: int = 0) -> None:
         self.opcode = opcode
         self.arrays = arrays
         self.op = op
@@ -518,6 +519,11 @@ class _PendingOp:
         # REDUCE_SCATTER only: destination rank per chunk (aligned with
         # ``chunks``) — the rank whose update shard the chunk feeds.
         self.owners = owners
+        # Gradient ops only: the op's number in its context
+        # (TcpCommContext.next_grad_op) and the raw bytes THIS sub-op
+        # carries (the whole op's where it is one).
+        self.seq = seq
+        self.nbytes = nbytes
         self.t_submit = time.perf_counter()
 
 
@@ -933,7 +939,18 @@ class _Lane:
         #                   the op sat behind earlier ops on this lane)
         #   wire_reduce   — dequeue → wire exchange + reduction complete;
         #                   a span, so the lane also shows on its own line
-        #                   of a trace's host plane, on the device's clock
+        #                   of a trace's host plane, on the device's clock.
+        #                   It says whose it is by ``op=`` (the op's number
+        #                   in this context, which ``tft.ddp_submit`` and
+        #                   ``tft.ddp_h2d`` carry beside ``bucket=`` and
+        #                   ``step=``) and needs no ``step=`` of its own;
+        #                   ``bytes=`` is this sub-op's raw bytes and
+        #                   ``queue_us=`` its submit_wire, known before
+        #                   the span opens
+        #   op_resolve    — the LAST lane of an op resolving its future: a
+        #                   span, because the continuations run inline
+        #                   here (_OpState docstring) and were the unnamed
+        #                   gap between a lane's spans until they had one
         metrics = self._ctx.metrics
         tag = f"comm_l{self._lane_id}"
         while True:
@@ -942,7 +959,8 @@ class _Lane:
                 return
             t_deq = time.perf_counter()
             try:
-                if pending.opcode in _GRAD_OPCODES:
+                grad = pending.opcode in _GRAD_OPCODES
+                if grad:
                     # Allreduce only: these split bench's allreduce number
                     # along the transport's seams — a heal broadcast or
                     # allgather landing here would pin gradient-path
@@ -951,12 +969,13 @@ class _Lane:
                     # each lane's share of the op (their max approximates
                     # the op's wire time; end-to-end latency is the
                     # manager's `allreduce` timer).
+                    queued = t_deq - pending.t_submit
                     with span(metrics, "comm_wire_reduce",
-                              lane=self._lane_id) as timed:
+                              lane=self._lane_id, op=pending.seq,
+                              bytes=pending.nbytes,
+                              queue_us=int(queued * 1e6)) as timed:
                         result = self._execute(pending)
-                    metrics.observe(
-                        "comm_submit_wire", t_deq - pending.t_submit
-                    )
+                    metrics.observe("comm_submit_wire", queued)
                     metrics.observe(f"{tag}_wire_reduce", timed.elapsed)
                 else:
                     result = self._execute(pending)
@@ -965,12 +984,17 @@ class _Lane:
                     # future (with the full donated array list — every
                     # lane reduced its own disjoint chunk views in place).
                     if pending.state.subop_done():
-                        try:
-                            pending.state.fut.set_result(
-                                pending.state.arrays
-                            )
-                        except Exception:
-                            pass  # a sibling lane already failed the op
+                        with span(metrics, "comm_op_resolve",
+                                  op=pending.seq):
+                            try:
+                                pending.state.fut.set_result(
+                                    pending.state.arrays
+                                )
+                            except Exception:
+                                pass  # a sibling lane already failed the op
+                elif grad:
+                    with span(metrics, "comm_op_resolve", op=pending.seq):
+                        pending.fut.set_result(result)
                 else:
                     pending.fut.set_result(result)
             except Exception as e:  # noqa: BLE001 — latch every transport error
@@ -1422,7 +1446,10 @@ class _Lane:
 
         ``seams`` accumulates the sub-op's seconds inside
         :meth:`_ring_sendrecv` ([0]: socket send + receive AND the wait
-        for the ring neighbour) and inside the reduction ([1])."""
+        for the ring neighbour) and inside the reduction ([1]), and
+        keeps the clock's reading at the end of the FIRST hop ([2]):
+        until then the lane has waited for its neighbours to reach this
+        op, after it the ring moves in step."""
         n, r = self._world_size, self._rank
         rs_codec = _NO_CODEC
         for step in range(n - 1):
@@ -1435,7 +1462,10 @@ class _Lane:
                 self._expect_len(rs_codec, send_views),
                 vote=vote,
             )
-            seams[0] += time.perf_counter() - t0
+            t1 = time.perf_counter()
+            seams[0] += t1 - t0
+            if step == 0:
+                seams[2] = t1
             vote |= rvote
             if len(data) != self._expect_len(rs_codec, recv_views):
                 raise ConnectionError(
@@ -1519,7 +1549,9 @@ class _Lane:
         if p.opcode == _OP_REDUCE_SCATTER:
             owned = [o == self._rank for o in p.owners]
         vote = self._ctx._vote_health_bit()
-        seams = [0.0, 0.0]  # seconds exchanging, seconds reducing
+        # seconds exchanging, seconds reducing, when the first hop ended
+        seams = [0.0, 0.0, 0.0]
+        t_start = time.perf_counter()
         cpu0 = time.thread_time()
         vote = self._ring_reduce_scatter_phase(
             p, flats, reduce_fn, vote, seams
@@ -1530,7 +1562,11 @@ class _Lane:
         # wall − exchange − reduce is Python between the seams, which a
         # lane only spends waiting for the GIL; wall − cpu is time this
         # thread did not run at all (neighbour, socket buffer, GIL).
+        # Of the exchange, the first hop apart: sub-op start → the end of
+        # its first _ring_sendrecv is the wait for the ring neighbours to
+        # take up the same op (plus one hop's bytes).
         metrics = self._ctx.metrics
+        metrics.observe("comm_subop_first_hop", seams[2] - t_start)
         metrics.observe("comm_subop_exchange", seams[0])
         metrics.observe("comm_subop_reduce", seams[1])
         metrics.observe("comm_subop_cpu", time.thread_time() - cpu0)
@@ -1698,6 +1734,9 @@ class TcpCommContext(CommContext):
         self._lock = threading.Lock()
         self._lanes: List[_Lane] = []
         self._rr = 0
+        # Gradient ops submitted so far, over every configure: the next
+        # one's number (next_grad_op), never reused in a process
+        self._grad_ops = 0
         self._listener: Optional[socket.socket] = None
         self._error: Optional[Exception] = None
         self._op_delay = 0.0  # test hook: simulated per-op wire latency
@@ -2062,6 +2101,10 @@ class TcpCommContext(CommContext):
             h = self._hier
             world = self._world_size
             configured = bool(self._lanes)
+            # the op's number, so that next_grad_op stays true; the tiers'
+            # sub-ops ride child contexts and carry THEIR numbers
+            seq = self._grad_ops
+            self._grad_ops += 1
         if world == 1:
             # solo wire: identity, exactly like the flat path
             if not configured:
@@ -2080,7 +2123,7 @@ class TcpCommContext(CommContext):
             ))
             return Work(fut)
         h.exec.submit(self._run_hier, h, prepared, op, fut)
-        return Work(fut)
+        return Work(fut, op=seq)
 
     def _run_hier(self, h: "_HierState", arrays: List[np.ndarray],
                   op: str, fut: Future) -> None:
@@ -2362,6 +2405,21 @@ class TcpCommContext(CommContext):
     # _prepare (the donation-contract input normalization) is inherited
     # from CommContext — one definition for every data plane.
 
+    def next_grad_op(self) -> int:
+        """The number the next gradient op (allreduce, reduce_scatter)
+        submitted to this context gets: its ``Work.op`` and the ``op=``
+        of its sub-ops' ``tft.comm_wire_reduce`` and of its
+        ``tft.comm_op_resolve``. For a caller that must name the op
+        BEFORE it submits it (a span's arguments are fixed when it
+        opens: ``tft.ddp_submit``). Exact because a context's ops are
+        submitted in one order, the same on every rank (the lane map and
+        the frame sequence hang on it): no other thread submits between
+        this read and that submit. Counted over every configure, so a
+        number is not reused in a process; ranks that configured
+        together from new count alike."""
+        with self._lock:
+            return self._grad_ops
+
     def _submit(self, opcode: int, arrays: Sequence[np.ndarray], op: str,
                 root: int,
                 owners: "Optional[Sequence[int]]" = None) -> Work:
@@ -2385,6 +2443,10 @@ class TcpCommContext(CommContext):
             n_lanes = len(self._lanes)
             base = self._rr % n_lanes
             self._rr += 1
+            seq = None
+            if opcode in _GRAD_OPCODES:
+                seq = self._grad_ops
+                self._grad_ops += 1
             if opcode in _GRAD_OPCODES and self._world_size > 1:
                 if opcode == _OP_REDUCE_SCATTER:
                     if owners is None:
@@ -2452,12 +2514,18 @@ class TcpCommContext(CommContext):
                     self._lanes[lane_id]._queue.put(_PendingOp(
                         opcode, prepared, op, root, fut,
                         chunks=per_lane[lane_id], state=state,
-                        owners=per_lane_owner.get(lane_id),
+                        owners=per_lane_owner.get(lane_id), seq=seq,
+                        nbytes=sum(
+                            ch.nbytes for ch in per_lane[lane_id]
+                        ),
                     ))
-                return Work(fut)
-            pending = _PendingOp(opcode, prepared, op, root, fut)
+                return Work(fut, op=seq)
+            pending = _PendingOp(
+                opcode, prepared, op, root, fut, seq=seq,
+                nbytes=sum(a.nbytes for a in prepared),
+            )
             self._lanes[base]._queue.put(pending)
-        return Work(fut)
+        return Work(fut, op=seq)
 
     def allreduce(
         self, arrays: Sequence[np.ndarray], op: str = ReduceOp.SUM,

@@ -35,7 +35,9 @@ caller's thread —
 The step future resolves when the last bucket has landed AND every EF
 task has finished, so ``.result()`` still means "arena quiescent,
 residuals final" exactly as in the lock-step model. Per-stage wall times
-land in the Manager's metrics (``ddp_d2h``/``ddp_ef``/``ddp_h2d`` spans and
+land in the Manager's metrics (``ddp_d2h``/``ddp_ef``/``ddp_h2d`` spans,
+``ddp_submit`` — the span around a bucket's ``allreduce_arrays``, whose
+``op=`` the bucket's sub-ops on the lanes and its landing share — and
 ``ddp_land_queue``, one observation per bucket; counters
 ``ddp_land_borrowed_bytes`` / ``ddp_land_copied_bytes`` and gauge
 ``ddp_land_workers``) plus once-a-step timings:
@@ -220,6 +222,12 @@ def _step_arg(manager) -> Dict[str, int]:
     return {"step": int(current_step())} if callable(current_step) else {}
 
 
+def _op_arg(op: Optional[int]) -> Dict[str, int]:
+    """``op=`` for a bucket's spans: its number on the wire (``Work.op``),
+    left out on a data plane that gives none."""
+    return {} if op is None else {"op": op}
+
+
 class _StepClock:
     """One classic step's clock reads. Per bucket (a slot each: landings
     and wire continuations run on other threads): when it was submitted,
@@ -230,19 +238,39 @@ class _StepClock:
     plus the D2H DMA), inside ``pack_bucket_into`` (host memcpy into the
     staging arena) and inside ``manager.allreduce_arrays`` (enqueue);
     the thread's CPU time across the loop; when the loop ended.
-    ``step`` is the spans' ``step=`` (:func:`_step_arg`)."""
+    ``step`` is the spans' ``step=`` (:func:`_step_arg`); ``op`` a
+    bucket's number on the wire (``Work.op``, None where the data plane
+    gives none): the ``op=`` its ``ddp_submit`` and ``ddp_h2d`` spans
+    share with its sub-ops' ``comm_wire_reduce`` on the lanes' lines."""
 
-    __slots__ = ("step", "submit_t", "wire_done_t", "d2h_t", "h2d_t",
-                 "fetch", "copy", "submit", "cpu", "t_submitted")
+    __slots__ = ("step", "op", "submit_t", "wire_done_t", "d2h_t", "h2d_t",
+                 "fetch", "copy", "submit", "cpu", "t_submitted", "_next_op")
 
     def __init__(self, manager, n_buckets: int) -> None:
         self.step = _step_arg(manager)
+        self.op: List[Optional[int]] = [None] * n_buckets
+        # duck-typed like ``step``: a test double numbers no ops
+        self._next_op = getattr(manager, "next_wire_op", None)
         self.submit_t = [0.0] * n_buckets
         self.wire_done_t = [0.0] * n_buckets
         self.d2h_t = [0.0] * n_buckets
         self.h2d_t = [0.0] * n_buckets
         self.fetch = self.copy = self.submit = self.cpu = 0.0
         self.t_submitted = 0.0
+
+    def submit_span(self, metrics, k: int, nbytes: int) -> span:
+        """The ``ddp_submit`` span of bucket ``k``, opened around its
+        ``allreduce_arrays``: the bucket's hand-over to the lanes, with
+        the number the wire is about to give the op (a span's arguments
+        are fixed when it opens, so it is asked for, not read back)."""
+        op = self._next_op() if callable(self._next_op) else None
+        return span(metrics, "ddp_submit", bucket=k, bytes=nbytes,
+                    **_op_arg(op), **self.step)
+
+    def submitted(self, k: int, work) -> None:
+        """After the submit: the op's number as the wire gave it."""
+        self.submit += time.perf_counter() - self.submit_t[k]
+        self.op[k] = getattr(work, "op", None)
 
     def observe(self, metrics) -> None:
         """The once-a-step timings, observed by whichever thread resolves
@@ -631,7 +659,8 @@ class DistributedDataParallel:
         import jax
 
         bucket = plan.buckets[k]
-        with span(metrics, "ddp_d2h", bucket=k, **clock.step) as timed:
+        with span(metrics, "ddp_d2h", bucket=k, bytes=staging[k].nbytes,
+                  **clock.step) as timed:
             t0 = time.perf_counter()
             host_b = [np.asarray(jax.device_get(leaves[i])) for i in bucket]
             t1 = time.perf_counter()
@@ -673,7 +702,8 @@ class DistributedDataParallel:
         handed to the transfer, and this returns — the bucket counts as
         landed, and the step's future, the arena's ``inflight`` guard,
         can resolve — only once the transfers have read them."""
-        with span(metrics, "ddp_h2d", bucket=k, **clock.step) as timed:
+        with span(metrics, "ddp_h2d", bucket=k, **_op_arg(clock.op[k]),
+                  **clock.step) as timed:
             indices, views = zip(*plan.unpack_bucket(k, reduced))
             landed, borrowed, copied = land_batch(
                 views, [in_leaves[i] for i in indices]
@@ -740,10 +770,11 @@ class DistributedDataParallel:
                             )
                         )
                     clock.submit_t[k] = time.perf_counter()
-                    work = self._manager.allreduce_arrays(
-                        [packed], **self._ar_kwargs
-                    )
-                    clock.submit += time.perf_counter() - clock.submit_t[k]
+                    with clock.submit_span(metrics, k, packed.nbytes):
+                        work = self._manager.allreduce_arrays(
+                            [packed], **self._ar_kwargs
+                        )
+                    clock.submitted(k, work)
                     landed: Future = Future()
                     landed.set_running_or_notify_cancel()
                     group.add(landed)
@@ -841,10 +872,11 @@ class DistributedDataParallel:
                         np.add(packed, res, out=packed)
                         self._ef_residual(packed, res, metrics)
                     clock.submit_t[k] = time.perf_counter()
-                    work = self._manager.allreduce_arrays(
-                        [packed], **self._ar_kwargs
-                    )
-                    clock.submit += time.perf_counter() - clock.submit_t[k]
+                    with clock.submit_span(metrics, k, packed.nbytes):
+                        work = self._manager.allreduce_arrays(
+                            [packed], **self._ar_kwargs
+                        )
+                    clock.submitted(k, work)
                     works.append(work)
 
                     # Timestamp-only continuation (O(enqueue)), so an A/B
@@ -1115,7 +1147,8 @@ class ShardedGradReducer:
 
         step = _step_arg(mgr)
         for k, bucket in enumerate(plan.buckets):
-            with span(metrics, "ddp_d2h", bucket=k, **step):
+            with span(metrics, "ddp_d2h", bucket=k,
+                      bytes=staging[k].nbytes, **step):
                 host_b = [
                     np.asarray(jax.device_get(leaves[i])) for i in bucket
                 ]

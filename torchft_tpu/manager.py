@@ -655,7 +655,8 @@ class Manager:
                 return reduced
 
             fut = future_chain(work.future(), _normalize)
-            return Work(self.wrap_future(fut, list(arrays)))
+            return Work(self.wrap_future(fut, list(arrays)),
+                        op=getattr(work, "op", None))
         except Exception as e:  # noqa: BLE001
             self._logger.exception(f"allreduce submit failed: {e}")
             self.report_error(e)
@@ -733,7 +734,8 @@ class Manager:
                 return reduced
 
             fut = future_chain(work.future(), _normalize)
-            return Work(self.wrap_future(fut, list(arrays)))
+            return Work(self.wrap_future(fut, list(arrays)),
+                        op=getattr(work, "op", None))
         except Exception as e:  # noqa: BLE001
             self._logger.exception(f"reduce_scatter submit failed: {e}")
             self.report_error(e)
@@ -1784,6 +1786,15 @@ class Manager:
     def wire_generation(self) -> int:
         fn = getattr(self._comm, "wire_generation", None)
         return int(fn()) if callable(fn) else 0
+
+    def next_wire_op(self) -> Optional[int]:
+        """The number the comm context gives the next gradient op
+        submitted through this Manager (``TcpCommContext.next_grad_op``:
+        the ``Work.op`` that ``allreduce_arrays`` hands back), for a
+        caller that names the op on a span it opens around the submit;
+        None on a data plane that numbers nothing."""
+        fn = getattr(self._comm, "next_grad_op", None)
+        return int(fn()) if callable(fn) else None
 
     def wire_roundtrip(self, src: np.ndarray, out: np.ndarray) -> None:
         fn = getattr(self._comm, "wire_roundtrip", None)
