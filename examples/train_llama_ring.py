@@ -41,6 +41,7 @@ import optax
 from torchft_tpu import Manager, TcpCommContext
 from torchft_tpu.comm.store import StoreServer
 from torchft_tpu.ddp import DistributedDataParallel
+from torchft_tpu.models.common import repeat_kv
 from torchft_tpu.models.llama import (
     LlamaConfig,
     llama_init_params,
@@ -63,7 +64,7 @@ def main() -> None:
     assert seq_len % n_dev == 0, (seq_len, n_dev)
     mesh = ft_mesh({"seq": n_dev})
     ring_impl = os.environ.get("RING_IMPL", "einsum")  # einsum | flash
-    ring_fn = make_ring_attention(
+    ring = make_ring_attention(
         mesh, "seq", causal=True, block_impl=ring_impl,
         block_q=min(128, seq_len // n_dev),
         block_k=min(128, seq_len // n_dev),
@@ -74,6 +75,12 @@ def main() -> None:
         d_ff=176, max_seq_len=seq_len, remat=False,
         xent_chunks=4,  # fused loss: no [B, S, V] logits
     )
+
+    def ring_fn(q, k, v):
+        # the model hands k and v at their own head count; the ring's
+        # blocks take as many key/value heads as query heads
+        return ring(q, repeat_kv(k, cfg.n_heads), repeat_kv(v, cfg.n_heads))
+
     tx = optax.adamw(3e-4)
     params = llama_init_params(cfg, jax.random.key(0))
     state = {"params": params, "opt": tx.init(params)}

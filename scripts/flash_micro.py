@@ -13,6 +13,16 @@ timed call is the jitted wrapper: the kernel and whatever XLA lays out
 around it. ``--cells`` picks the shapes (``_CELLS``: a cell's call as ``[BH,
 S, Dqk / Dv]``, bf16, the blocks the kernels choose from the shape; a name
 ending in ``-swa`` is under a window, in ``-unmasked`` without the mask).
+A cell of ``_GROUPED`` is another cell's call with the key/value heads the
+model has (``name: (cell, query heads a key/value head, sequences a
+step)``): there the two sides are THIS file's kernels on k and v as they
+lie (``grouped``, ``[BH / group, S, D]``) and on their copies
+(``repeated``, what ``repeat_kv`` fed the kernels until PR 55). ``out``,
+``lse`` and ``dq`` are compared bit for
+bit, ``dk`` and ``dv`` against the float32 sum over the copies' gradients,
+and ``layer_call_ms`` is the whole of ``value_and_grad(flash_attention)`` on
+``[B, S, H, D]`` operands either way: the kernels with the repeat, the
+layouts and the sum over the copies that XLA puts around them.
 Prints one JSON object and writes it to ``chiprun_out/flash_micro.json``. A
 CPU run (the interpreter, a small shape) gives agreement and step counts
 only.
@@ -59,6 +69,20 @@ _CELLS = {
     # block), where whatever a kernel does once a q block shows most
     "phi4flash-swa": ((80, 8192, 64, 128, True, 512),
                       (2, 512, 16, 32, True, 128, 0)),
+    "phi4flash": ((80, 8192, 64, 128, True, None),
+                  (2, 512, 16, 32, True, None, 0)),
+    "lfm2": ((128, 8192, 64, 64, True, None),
+             (2, 512, 16, 16, True, None, None)),
+}
+
+# the four cells whose key/value heads serve several query heads (PR 55)
+_GROUPED = {
+    "smallthinker-gqa": ("smallthinker", 7, 2),
+    "smallthinker-swa-gqa": ("smallthinker-swa", 7, 2),
+    "nemo3-gqa": ("nemo3", 16, 4),
+    "lfm2-gqa": ("lfm2", 4, 4),
+    "phi4flash-gqa": ("phi4flash", 2, 4),
+    "phi4flash-swa-gqa": ("phi4flash-swa", 2, 4),
 }
 
 
@@ -80,12 +104,12 @@ def main() -> int:
 
     place_compile_cache()
     on_chip = jax.default_backend() == "tpu"
-    sides = {"this": flash}
+    modules = {"this": flash}
     if args.parent:
         spec = importlib.util.spec_from_file_location(
             "flash_parent", args.parent)
-        sides["parent"] = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(sides["parent"])
+        modules["parent"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(modules["parent"])
 
     out = {"device": jax.devices()[0].device_kind, "calls": args.calls}
 
@@ -101,19 +125,33 @@ def main() -> int:
         return 1e3 * sorted(seen)[1]
 
     for cell in args.cells:
-        chip, cpu = _CELLS[cell]
+        base, group, batch = _GROUPED.get(cell, (cell, 1, 1))
+        chip, cpu = _CELLS[base]
         bh, seq, dqk, dv, causal, window, threshold = (
             (*chip, None) if on_chip else cpu)
+        if not on_chip:
+            bh *= group
         rng = np.random.default_rng(45)
         q, k, v, g = (
-            jnp.asarray(rng.standard_normal((bh, seq, w)), jnp.bfloat16)
-            for w in (dqk, dqk, dv, dv))
+            jnp.asarray(rng.standard_normal((rows, seq, w)), jnp.bfloat16)
+            for rows, w in ((bh, dqk), (bh // group, dqk),
+                            (bh // group, dv), (bh, dv)))
+        # side -> (module, k, v): this tree against the parent's on the
+        # same operands, or this tree on k and v as they lie against
+        # itself on their copies
+        if group > 1:
+            sides = {"grouped": (flash, k, v), "repeated": (
+                flash, jnp.repeat(k, group, axis=0),
+                jnp.repeat(v, group, axis=0))}
+        else:
+            sides = {side: (mod, k, v) for side, mod in modules.items()}
+        other = list(sides)[-1]
         scale = 1.0 / dqk ** 0.5
         blocks = (flash._choose_blocks(seq, dqk, 2, v_dim=dv, window=window)
                   if on_chip else (64, 128))
         live, rectangular = flash._grid_steps(seq, *blocks, window)
-        entry = {"q": [bh, seq, dqk], "v": [bh, seq, dv], "blocks": blocks,
-                 "causal": causal, "window": window,
+        entry = {"q": [bh, seq, dqk], "v": [bh // group, seq, dv],
+                 "blocks": blocks, "causal": causal, "window": window,
                  "grid_steps_a_head": {
                      "live": live if causal else rectangular,
                      "rectangular": rectangular}}
@@ -136,18 +174,56 @@ def main() -> int:
                 "flash_dkv": jax.jit(lambda *a: backward(*a)[1:]),
             }
 
-        built = {side: kernels(mod) for side, mod in sides.items()}
+        def layer_call(repeated):
+            """``value_and_grad`` of the model's call on ``[B, S, H, D]``
+            operands (``q``, ``k``, ``v``, ``g`` arrive merged and are laid
+            out here, outside the timed function's hot part as little as
+            the model's projections are): with ``repeated`` the key/value
+            heads are copied first, as the models did."""
+            b = batch if on_chip else 1
+
+            def bshd(x):
+                return x.reshape(b, -1, seq, x.shape[-1]).transpose(
+                    0, 2, 1, 3)
+
+            def loss(q, k, v, g):
+                if repeated:
+                    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+                o = flash.flash_attention(
+                    q, k, v, causal=causal, window=window,
+                    interpret=not on_chip, _resident_kv_bytes=threshold)
+                return jnp.sum(o.astype(jnp.float32)
+                               * g.astype(jnp.float32))
+
+            grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+            return jax.jit(lambda q, k, v, g: grad(
+                bshd(q), bshd(k), bshd(v), bshd(g)))
+
+        built = {side: kernels(mod) for side, (mod, _, _) in sides.items()}
         results, statistics = {}, {}
         for side, fns in built.items():
-            o, lse = fns["flash_fwd"](q, k, v)
+            _, k_, v_ = sides[side]
+            o, lse = fns["flash_fwd"](q, k_, v_)
             delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                             axis=-1)
             statistics[side] = lse, delta
-            dk, dvv = fns["flash_dkv"](q, k, v, g, lse, delta)
+            dk, dvv = fns["flash_dkv"](q, k_, v_, g, lse, delta)
             results[side] = {"out": o, "lse": lse,
-                             "dq": fns["flash_dq"](q, k, v, g, lse, delta),
+                             "dq": fns["flash_dq"](q, k_, v_, g, lse, delta),
                              "dk": dk, "dv": dvv}
-        if "parent" in results:
+        if group > 1:
+            entry["bit_for_bit"] = {
+                name: bool(jnp.array_equal(
+                    results["grouped"][name], results["repeated"][name]))
+                for name in ("out", "lse", "dq")}
+            # the copies' gradients, each rounded to bf16, summed in f32
+            entry["max_abs_from_the_copies_sum"] = {
+                name: float(jnp.max(jnp.abs(
+                    results["grouped"][name].astype(jnp.float32)
+                    - results["repeated"][name].astype(jnp.float32).reshape(
+                        bh // group, group, seq, -1).sum(axis=1))))
+                for name in ("dk", "dv")}
+        elif "parent" in results:
             entry["bit_for_bit"] = {
                 name: bool(jnp.array_equal(a, results["parent"][name]))
                 for name, a in results["this"].items()}
@@ -155,26 +231,34 @@ def main() -> int:
             # both sides' backward kernels on ONE side's statistics: the
             # times do not depend on them, and two sets of results do not
             # fit beside the operands at every shape
-            lse, delta = statistics["this"]
+            lse, delta = statistics[other]
             del results, statistics
-            ms = {side: {name: [] for name in _KERNELS} for side in built}
-            order = list(built)
+            timed = dict(built)
+            if group > 1:
+                timed = {side: dict(fns, layer_call=layer_call(
+                    side == "repeated")) for side, fns in timed.items()}
+            ms = {side: {name: [] for name in fns}
+                  for side, fns in timed.items()}
+            order = list(timed)
             for turn in range(args.rounds):
                 for side in (order if turn % 2 == 0 else order[::-1]):
-                    for name in _KERNELS:
-                        a = ((q, k, v) if name == "flash_fwd"
-                             else (q, k, v, g, lse, delta))
-                        ms[side][name].append(
-                            time_ms(built[side][name], *a))
+                    _, k_, v_ = sides[side]
+                    for name, fn in timed[side].items():
+                        a = ((q, k, v, g) if name == "layer_call"
+                             else (q, k_, v_) if name == "flash_fwd"
+                             else (q, k_, v_, g, lse, delta))
+                        ms[side][name].append(time_ms(fn, *a))
             entry["ms_a_call"] = {
                 side: {name: sorted(seen)[len(seen) // 2]
                        for name, seen in per.items()}
                 for side, per in ms.items()}
             entry["ms_a_call_every_round"] = ms
-            if "parent" in ms:
+            if len(ms) == 2:
+                first = order[0]
                 entry["gain_ms_a_call"] = {
-                    name: entry["ms_a_call"]["parent"][name]
-                    - entry["ms_a_call"]["this"][name] for name in _KERNELS}
+                    name: entry["ms_a_call"][other][name]
+                    - entry["ms_a_call"][first][name]
+                    for name in ms[first]}
         out[cell] = entry
         print(cell, json.dumps(entry), flush=True)
         del built

@@ -885,9 +885,19 @@ def _flash_calls(jaxpr):
     found = {}
     for eqn, _ in _eqns(jaxpr):
         if (eqn.primitive.name == "pallas_call"
-                and eqn.params["name"].startswith("flash_")):
+                and (eqn.params["name"] or "").startswith("flash_")):
             found.setdefault(eqn.params["name"], []).append(eqn)
     return found
+
+
+def _traced_flash_calls():
+    """``(flash_calls, flash_calls_grouped)`` so far in this process
+    (``utils/metrics.py::TRACED``): read it on both sides of a trace."""
+    from torchft_tpu.utils.metrics import TRACED
+
+    seen = TRACED.snapshot()
+    return np.array([seen.get("flash_calls", 0),
+                     seen.get("flash_calls_grouped", 0)], dtype=int)
 
 
 _MASKS = {"causal": {}, "window": {"window": 200},

@@ -173,10 +173,12 @@ def test_mixer_and_mlp_are_two_axes_of_the_config(kinds, n_dense) -> None:
 
 
 def test_a_key_value_head_serves_consecutive_query_heads() -> None:
-    """``common.repeat_kv`` (Nemotron-H's and this model's): query heads
-    0-1 read key/value head 0 and 2-3 head 1. That Nemotron-H's whole
-    gradient program is the one it traced before the helper and the
-    shared kernel body is a case of ``tests/test_nemotron_h.py::
+    """``common.repeat_kv`` (what Nemotron-H's and this model's attention
+    is held to; since PR 55 it reads the heads by index and no program
+    holds the copy): query heads 0-1 read key/value head 0 and 2-3 head
+    1. That Nemotron-H's whole gradient program is the one it traced
+    before the helper and the shared kernel body is a case of
+    ``tests/test_nemotron_h.py::
     test_the_gated_expert_paths_are_what_they_were``, beside this
     model's."""
     kv = jnp.arange(2 * 3 * 2 * 4, dtype=jnp.float32).reshape(2, 3, 2, 4)

@@ -178,7 +178,8 @@ def test_llama_trains_and_flash_matches() -> None:
     )
     targets = jnp.roll(tokens, -1, axis=1)
 
-    # flash kernel (interpret) plugs into the GQA path via head repeat
+    # the flash kernel (interpret) and the reference are both handed K and
+    # V at their own head count (2 of 4 here) and copy nothing
     def flash_fn(q, k, v):
         return flash_attention(q, k, v, causal=True, block_q=64,
                                block_k=64, interpret=True)
@@ -264,3 +265,108 @@ def test_grad_accumulation_rejects_ragged_batch() -> None:
     tokens = jnp.zeros((3, cfg.max_seq_len), jnp.int32)
     with _pytest.raises(ValueError, match="microbatches"):
         make_grad_step(cfg, microbatches=2)(params, tokens, tokens)
+
+
+# -- K and V at their own head count (PR 55) ---------------------------------
+
+
+@pytest.mark.parametrize("kw", [{}, {"window": 9}, {"causal": False}],
+                         ids=["causal", "window", "unmasked"])
+def test_reference_attention_groups_the_query_heads(kw) -> None:
+    """``reference_attention`` at ``KV < H`` is itself on ``repeat_kv``-ed
+    operands (query head ``i`` reads key/value head ``i // group``), value
+    and all three gradients: ``causal_attention`` means one thing on both
+    backends."""
+    from torchft_tpu.models.common import repeat_kv
+    from torchft_tpu.ops.attention import reference_attention
+
+    rng = np.random.default_rng(5)
+    q, k, v, cot = (jnp.asarray(rng.standard_normal(s), jnp.float32)
+                    for s in ((2, 32, 14, 8), (2, 32, 2, 8), (2, 32, 2, 12),
+                              (2, 32, 14, 12)))
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out, *vjp(cot))
+
+    got = both(lambda q, k, v: reference_attention(q, k, v, **kw))
+    want = both(lambda q, k, v: reference_attention(
+        q, repeat_kv(k, 14), repeat_kv(v, 14), **kw))
+    assert got[0].shape == (2, 32, 14, 12)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
+
+def _zoo(name):
+    """``(module's loss_fn, its init_params, tiny config, KV heads a flash
+    call is handed or None where they are the query heads')``."""
+    from torchft_tpu.models import (
+        joyai, lfm2, llama, nemotron_h, olmoe, phi4flash, smallthinker,
+        transformer,
+    )
+
+    return {
+        "smallthinker": lambda: (
+            smallthinker.loss_fn, smallthinker.init_params,
+            smallthinker.SMALLTHINKER_CONFIGS["smallthinker_tiny"], 2),
+        "lfm2": lambda: (lfm2.loss_fn, lfm2.init_params,
+                         lfm2.LFM2_CONFIGS["lfm2_tiny"],
+                         lfm2.LFM2_CONFIGS["lfm2_tiny"].n_kv_heads),
+        "nemotron_h": lambda: (
+            nemotron_h.loss_fn, nemotron_h.init_params,
+            nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"],
+            nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"].n_kv_heads),
+        # a call a half: pairs of key/value heads
+        "phi4flash": lambda: (
+            phi4flash.loss_fn, phi4flash.init_params,
+            phi4flash.PHI4FLASH_CONFIGS["phi4flash_tiny"], 2),
+        "llama": lambda: (llama.llama_loss_fn, llama.llama_init_params,
+                          llama.LLAMA_CONFIGS["llama_tiny"], 2),
+        "transformer": lambda: (transformer.loss_fn, transformer.init_params,
+                                TINY, None),
+        "olmoe": lambda: (olmoe.loss_fn, olmoe.init_params,
+                          olmoe.OLMOE_CONFIGS["olmoe_tiny"], None),
+        "joyai": lambda: (joyai.loss_fn, joyai.init_params,
+                          joyai.JOYAI_CONFIGS["joyai_tiny"], None),
+    }[name]()
+
+
+@pytest.mark.parametrize("model", [
+    "smallthinker", "lfm2", "nemotron_h", "phi4flash", "llama",
+    "transformer", "olmoe", "joyai"])
+def test_a_models_program_holds_no_copy_of_k_or_v(model) -> None:
+    """The gradient program of every model whose key/value heads serve
+    several query heads, traced as a TPU traces it (the flash kernels,
+    here through the interpreter; nothing runs): every flash kernel is
+    handed K and V ``B · KV`` rows tall — there is no ``[B, S, H, D]`` copy
+    to hand it — and ``flash_calls_grouped`` counts every call; the models
+    of equal head counts engage none of it."""
+    from test_flash import _flash_calls, _traced_flash_calls
+    from torchft_tpu.ops.flash import flash_attention
+
+    loss, init, cfg, kv_heads = _zoo(model)
+    batch, seq = 2, 64
+    params = jax.eval_shape(lambda: init(cfg, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+
+    def attn_fn(q, k, v, window=None):
+        return flash_attention(q, k, v, window=window, interpret=True)
+
+    before = _traced_flash_calls()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, a, b: loss(cfg, p, a, b, attn_fn)))(params, tokens, tokens)
+    calls, grouped = _traced_flash_calls() - before
+    assert calls > 0
+    assert grouped == (calls if kv_heads else 0)
+    found = _flash_calls(jaxpr.jaxpr)
+    assert set(found) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    for name, eqns in found.items():
+        for eqn in eqns:
+            tables = eqn.params["grid_mapping"].num_index_operands
+            q, k, v = (x.aval for x in eqn.invars[tables:tables + 3])
+            assert k.shape[0] == v.shape[0], name
+            if kv_heads:
+                assert k.shape[0] == batch * kv_heads < q.shape[0], name
+            else:
+                assert k.shape[0] == q.shape[0], name

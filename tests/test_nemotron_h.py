@@ -25,6 +25,7 @@ import pytest
 
 from benchmark.families import nemotron_h as family
 from benchmark.reference import nemotron_h_f32
+from benchmark.tests.nemotron_faults import with_leaf
 from torchft_tpu.models import joyai, kimi_linear, lfm2, nemotron_h, olmoe
 from torchft_tpu.ops import moe
 from torchft_tpu.ops.attention import reference_attention
@@ -174,22 +175,35 @@ def test_the_reference_follows_a_selection_and_still_says_its_own() -> None:
 
 
 def test_a_key_value_head_serves_consecutive_query_heads() -> None:
-    """Query heads 0-1 read key/value head 0 and 2-3 head 1: zeroing
-    value head 1 zeroes exactly the second half of the heads' output."""
+    """Query heads 0-1 read key/value head 0 and 2-3 head 1: the
+    attention is handed the two key/value heads as they are (PR 55: no
+    copy a query head), and silencing value head 1 silences exactly
+    query heads 2-3's rows of ``W_o``."""
     params, x = _params(CFG32, 2), jax.random.normal(
         jax.random.key(3), (1, 16, 64), jnp.float32)
     layer = params["layers_2"]
     seen = {}
 
     def spy(q, k, v):
-        seen["k"], seen["v"] = k, v
+        seen["q"], seen["k"], seen["v"] = q, k, v
         return reference_attention(q, k, v)
 
     nemotron_h._attn_mixer(CFG32, layer, x, attn_fn=spy)
-    assert seen["k"].shape == (1, 16, 4, 16)
-    assert np.array_equal(seen["k"][:, :, 0], seen["k"][:, :, 1])
-    assert np.array_equal(seen["v"][:, :, 2], seen["v"][:, :, 3])
-    assert not np.array_equal(seen["k"][:, :, 1], seen["k"][:, :, 2])
+    assert seen["q"].shape == (1, 16, 4, 16)
+    assert seen["k"].shape == seen["v"].shape == (1, 16, 2, 16)
+    assert not np.array_equal(seen["k"][:, :, 0], seen["k"][:, :, 1])
+
+    def out_of(lay):
+        return nemotron_h._attn_mixer(
+            CFG32, lay, x, attn_fn=reference_attention) - x
+
+    hd = CFG32.head_dim
+    half = with_leaf({"l": layer}, "l", ("attn", "v_proj", "kernel"),
+                     lambda w: w.at[:, hd:].set(0))["l"]
+    first2 = with_leaf({"l": layer}, "l", ("attn", "o_proj", "kernel"),
+                       lambda w: w.at[2 * hd:].set(0))["l"]
+    np.testing.assert_allclose(out_of(half), out_of(first2), atol=1e-5)
+    assert float(jnp.max(jnp.abs(out_of(half) - out_of(layer)))) > 1e-3
     # no position embedding: a sequence and the same sequence moved two
     # places to the right give the same outputs two places on, as far as
     # each still sees all it saw
@@ -376,6 +390,15 @@ def _jaxpr_hash(fn, *args):
 #   the outer path; on the device the two join:
 #   .../kda_core/jit(_forward)/kda_fwd/pallas_call); the other 50 paths
 #   are those of 7be2396
+# - nemotron_h and lfm2 again at PR 55: k and v reach the attention at
+#   their own head count, so the two repeats, their transposes' sums and
+#   the H-wide einsums are out of both programs and this CPU trace's
+#   reference groups the query heads in its einsums. Four of each model's
+#   paths are named after an einsum and follow it ("gqa_core/bqhd,bkhd->
+#   bhqk" -> "gqa_core/bqngd,bknd->bngqk", and the other, under jvp and
+#   its transpose); the other 40 and 28 are those of 7be2396, and on the
+#   device the flash call under gqa_core carries the names it carried.
+#   olmoe, joyai and kimi (equal head counts) did not move
 PROGRAMS_THAT_WERE = {
     "olmoe": (
         olmoe, olmoe.OLMOE_CONFIGS["olmoe_tiny"],
@@ -389,13 +412,13 @@ PROGRAMS_THAT_WERE = {
         68),
     "nemotron_h": (
         nemotron_h, CFG,
-        "10330c120050e7def392ddfebd3a40478d8001ccba37850b1ffcc81dcb9e0cba",
-        "7da4580a487db94456b2e2e93db5fb4ee664a4d6c2d718a99312e2641f54ef0a",
+        "bb1157a9290c54acf5755cec187e0a777e34376b06df84b0edefe6a9cce7f099",
+        "7bf2e754cf45add0c8e060e7a6342a6c2b54e8a9d13baadbbed1d9048d77719c",
         44),
     "lfm2": (
         lfm2, lfm2.LFM2_CONFIGS["lfm2_tiny"],
-        "636c3fb7a6e8986e65f3459c3198909d5bbb17caabeccae3228ee5d746bbf400",
-        "8b3555543f04f822f65e368bafd8cc46ee6e72c34c13793337027dc215acdbbb",
+        "dbfed6dd1f7e645635f6442311b5fbdde684ffe03f86660cf8d2e592f92c4560",
+        "5c68d83b6aad7a6b41fd31c37684ab6b17e395bc1222f50b7299eecb9af6851b",
         32),
     "kimi": (
         kimi_linear, kimi_linear.KIMI_LINEAR_CONFIGS["kimi_linear_tiny"],

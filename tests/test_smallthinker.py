@@ -163,14 +163,17 @@ def test_the_two_lists_are_two_axes_of_the_config(windowed, rotated) -> None:
 
 
 def test_a_key_value_head_serves_seven_consecutive_query_heads() -> None:
-    """``common.repeat_kv`` at this model's 7 : 1: query heads 0-6 read
-    key/value head 0 and 7-13 head 1; silencing key/value head 1's values
-    silences exactly the last seven query heads' rows of ``W_o``."""
+    """``common.repeat_kv`` (the yardstick) at this model's 7 : 1: query
+    heads 0-6 read key/value head 0 and 7-13 head 1; the model's own seam
+    of that name copies nothing (PR 55: the attention reads head ``i //
+    7`` where it lies); silencing key/value head 1's values silences
+    exactly the last seven query heads' rows of ``W_o``."""
     kv = jnp.arange(2 * 3 * 2 * 4, dtype=jnp.float32).reshape(2, 3, 2, 4)
     out = common.repeat_kv(kv, 14)
     assert out.shape == (2, 3, 14, 4)
     for head in range(14):
         assert np.array_equal(out[:, :, head], kv[:, :, head // 7])
+    assert smallthinker.repeat_kv(kv, 14) is kv
     layer = _params(CFG32, 3)["layers_1"]
     x = jax.random.normal(jax.random.key(1), (2, S, D), jnp.float32)
 

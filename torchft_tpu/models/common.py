@@ -2,8 +2,9 @@
 a change to every model that imports it, and says so. RMSNorm (``llama``,
 ``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``,
 ``smallthinker``), the token table's lookup (``olmoe`` and the five
-below), the repeat of grouped key/value heads (``nemotron_h``, ``lfm2``,
-``smallthinker``), the SwiGLU MLP and its dense sublayer (``joyai``,
+below), the repeat of grouped key/value heads (what the attention of
+``ops/`` does by index since PR 55: the tests' and the references'
+yardstick), the SwiGLU MLP and its dense sublayer (``joyai``,
 ``lfm2``, ``kimi_linear``), and what the five models that hold ONE CHIP'S
 SHARE of an expert-parallel layer (``joyai``, ``nemotron_h``, ``lfm2``,
 ``kimi_linear``, ``smallthinker``) have in common: the router's
@@ -49,9 +50,11 @@ def embed(cfg, params: Dict, tokens):
 
 def repeat_kv(kv, n_heads: int):
     """``[B, S, KV, D]`` -> ``[B, S, n_heads, D]``: query head ``i`` reads
-    key/value head ``i // (n_heads / KV)``. The flash kernels take as
-    many key/value heads as query heads; the sum over a key/value head's
-    copies is the repeat's own transpose."""
+    key/value head ``i // (n_heads / KV)``, by a copy. No model's program
+    holds it since PR 55 (``ops/flash.py`` and ``ops/attention.py`` read a
+    key/value head where it lies): it is what they are held against, in
+    the tests, and what a kernel of equal head counts only is handed
+    (``examples/train_llama_ring.py``)."""
     return jnp.repeat(kv, n_heads // kv.shape[2], axis=2)
 
 

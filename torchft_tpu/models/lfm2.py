@@ -26,7 +26,8 @@ learned ``head_dim``-wide weight on every q head and every k head; RoPE
 ``rope_theta`` over the whole head (``rotate_half``: ``models/llama.py::
 _rope``); causal softmax of ``q·k / sqrt(head_dim)``, ``·v``, each
 key/value head serving ``n_heads / n_kv_heads`` consecutive query heads
-(repeated before the flash call: ``common.repeat_kv``); ``·W_o``.
+(the flash call takes k and v at their own head count and reads head ``i
+// group`` for query head ``i``: ``ops/flash.py``); ``·W_o``.
 
 Dense MLP: ``W_down·(silu(W_gate n) ⊙ W_up n)``, ``d_ff`` wide.
 
@@ -51,7 +52,7 @@ parameter tree with stable paths ``layers_<i>/{norm_1,norm_2}`` and
 
 Device-trace scopes: ``embed``; both mixers under ``attn``, told apart
 inside — ``sconv_in`` (norm, ``W_in``), ``sconv_core`` (the kernels),
-``sconv_out``; ``gqa_proj`` (norm, q / k / v, QK-norm, RoPE, the repeat,
+``sconv_out``; ``gqa_proj`` (norm, q / k / v, QK-norm, RoPE,
 ``W_o``), ``gqa_core`` (the flash call) — Nemotron-H's names: the same
 measurement; ``mlp`` with the dense SwiGLU straight under it and the
 experts' ``moe_router``, ``moe_dispatch``, ``moe_experts``,
@@ -73,7 +74,6 @@ from torchft_tpu.models.common import (
     dense_sublayer,
     embed,
     is_balance_bias,
-    repeat_kv,
     rms_norm,
     routed_sublayer,
     routing_record,
@@ -246,7 +246,6 @@ def _attn_mixer(cfg: Lfm2Config, layer: Dict, x, *, attn_fn):
         # a head at a time: the norm's weight is head_dim wide
         q = _rope(rms_norm(q, a["q_norm"]["scale"], eps), cfg.rope_theta)
         k = _rope(rms_norm(k, a["k_norm"]["scale"], eps), cfg.rope_theta)
-        k, v = repeat_kv(k, H), repeat_kv(v, H)
     with jax.named_scope("gqa_core"):
         o = attn_fn(q, k, v)
     with jax.named_scope("gqa_proj"):

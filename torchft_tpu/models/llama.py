@@ -7,9 +7,12 @@ the q_proj/k_proj/v_proj/o_proj/gate_proj/up_proj/down_proj naming that
 ``parallel.sharding.tp_rules_gpt`` already matches, so the same
 Megatron-style TP rules shard this family unchanged.
 
-GQA: ``n_kv_heads <= n_heads`` with K/V heads repeated before attention,
-so any [B, S, H, D] attention kernel — including ops/flash.py — plugs in
-via ``attn_fn``.
+GQA: ``n_kv_heads <= n_heads``; ``attn_fn(q, k, v)`` is handed K and V at
+their own head count, ``[B, S, n_kv_heads, D]``, and query head ``i`` reads
+key/value head ``i // (n_heads / n_kv_heads)``: ``ops/attention.py::
+causal_attention`` (the default) and ``ops/flash.py`` take them so and
+copy nothing; a kernel of equal head counts only wants ``common.
+repeat_kv`` in its ``attn_fn`` (``examples/train_llama_ring.py``).
 """
 
 from __future__ import annotations
@@ -161,11 +164,7 @@ def _block(cfg: LlamaConfig, layer: Dict, x, *, attn_fn):
     )
     q = _rope(q, cfg.rope_theta)
     k = _rope(k, cfg.rope_theta)
-    # GQA: repeat kv heads so any [B,S,H,D] kernel applies
-    rep = cfg.n_heads // cfg.n_kv_heads
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    # GQA: k and v go at their own head count (module docstring)
     a = attn_fn(q, k, v).reshape(B, S, cfg.d_model)
     x = x + a @ layer["attn"]["o_proj"]["kernel"].astype(dt)
 

@@ -28,8 +28,9 @@ and ``out = y·W_out``.
 ``k, v = h·W_k, h·W_v`` -> ``n_kv_heads`` × ``head_dim`` (each serves
 ``n_heads / n_kv_heads`` consecutive query heads), causal softmax of
 ``q·k / sqrt(head_dim)``, ``·v``, ``·W_o``. No rotary embedding. The
-key/value heads are repeated to ``n_heads`` before the flash call, so
-the sum over a key/value head's copies is the repeat's own transpose.
+key/value heads reach the flash call as they are, ``n_kv_heads`` of
+them: its index maps read head ``i // group`` for query head ``i`` and
+its dkv kernel sums a group's gradients in float32 (``ops/flash.py``).
 
 ``E`` — expert mixer: ``s = sigmoid(h·W_r)`` in float32 over all routed
 experts; ``sel`` = the ``top_k`` largest of ``s + b``; ``g_e =
@@ -80,7 +81,6 @@ from torchft_tpu.models.common import (
     BALANCE_BIAS,
     embed,
     is_balance_bias,
-    repeat_kv,
     rms_norm,
     routed_sublayer,
     routing_record,
@@ -300,7 +300,6 @@ def _attn_mixer(cfg: NemotronHConfig, layer: Dict, x, *, attn_fn):
         q = (h @ a["q_proj"]["kernel"].astype(dt)).reshape(B, S, H, D)
         k = (h @ a["k_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
         v = (h @ a["v_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
-        k, v = repeat_kv(k, H), repeat_kv(v, H)
     with jax.named_scope("gqa_core"):
         o = attn_fn(q, k, v)
     with jax.named_scope("gqa_proj"):

@@ -80,7 +80,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.common import embed, repeat_kv, rms_norm, swiglu
+from torchft_tpu.models.common import embed, rms_norm, swiglu
 from torchft_tpu.models.transformer import _layer_norm, ce_from_hidden
 from torchft_tpu.ops.attention import causal_attention
 from torchft_tpu.ops.s6 import s6_scan
@@ -337,20 +337,19 @@ def _mamba_mixer(cfg: Phi4FlashConfig, layer: Dict, x, hand_on: bool):
 def _halves(cfg: Phi4FlashConfig, q, k, v):
     """``q [B, S, n_heads·head_dim]``, ``k, v [B, S, n_kv_heads·head_dim]``
     -> ``((q1, k1), (q2, k2), v)`` as the flash calls take them: ``q_j [B,
-    S, n_heads/2, head_dim]``, the half ``j`` of every query pair; ``k_j``
-    the same half of the key pairs and ``v`` the pairs' two value heads
-    joined (``2·head_dim`` wide), each key/value pair repeated for the
-    ``n_heads / n_kv_heads`` consecutive query pairs that read it
-    (``common.repeat_kv``: the kernels take as many key/value heads as
-    query heads; the sum over the copies is the repeat's own
-    transpose)."""
+    S, n_heads/2, head_dim]``, the half ``j`` of every query pair; ``k_j
+    [B, S, n_kv_heads/2, head_dim]`` the same half of the key pairs and
+    ``v [B, S, n_kv_heads/2, 2·head_dim]`` the pairs' two value heads
+    joined. A key/value pair serves the ``n_heads / n_kv_heads``
+    consecutive query pairs that read it inside the call (``ops/
+    flash.py``: query head ``i`` reads key/value head ``i // group``);
+    nothing is copied."""
     B, S, _ = q.shape
-    D, pairs = cfg.head_dim, cfg.n_heads // 2
-    q = q.reshape(B, S, pairs, 2, D)
+    D = cfg.head_dim
+    q = q.reshape(B, S, cfg.n_heads // 2, 2, D)
     k = k.reshape(B, S, cfg.n_kv_heads // 2, 2, D)
-    v = repeat_kv(v.reshape(B, S, cfg.n_kv_heads // 2, 2 * D), pairs)
-    return tuple((q[:, :, :, j], repeat_kv(k[:, :, :, j], pairs))
-                 for j in (0, 1)) + (v,)
+    v = v.reshape(B, S, cfg.n_kv_heads // 2, 2 * D)
+    return tuple((q[:, :, :, j], k[:, :, :, j]) for j in (0, 1)) + (v,)
 
 
 def _lambda(a: Dict, init: float):

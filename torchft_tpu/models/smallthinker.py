@@ -24,7 +24,9 @@ two lists are one, ``[0, 1, 1, 1] × 13``: full unrotated layers between
 threes of windowed rotated ones. ``n_heads`` × ``head_dim`` is NOT
 ``d_model`` (28 × 128 = 3584 on 2560); each of the ``n_kv_heads``
 key/value heads serves ``n_heads / n_kv_heads`` consecutive query heads
-(repeated before the flash call: ``common.repeat_kv``). A final RMSNorm,
+(k and v reach the flash call at their own head count and its index maps
+read head ``i // group`` for query head ``i``; nothing is copied: ``ops/
+flash.py``). A final RMSNorm,
 an untied ``lm_head``; no bias anywhere, no shared expert, no dense layer.
 
 The layer is told which routed experts it holds (``first_expert``,
@@ -64,7 +66,6 @@ from torchft_tpu.models.common import (
     BALANCE_BIAS,
     embed,
     is_balance_bias,
-    repeat_kv,
     rms_norm,
     routed_sublayer,
     routing_record,
@@ -184,6 +185,17 @@ def init_params(cfg: SmallThinkerConfig, key) -> Dict:
             },
         }
     return params
+
+
+def repeat_kv(kv, n_heads: int):
+    """``[B, S, KV, D]`` as it is: the place where a query head's
+    key/value head is decided. That is the kernels' ``i // (n_heads /
+    KV)`` now (``ops/flash.py``; off the TPU ``reference_attention``'s
+    grouped einsums), so nothing is copied here. A seam by name: a fault
+    (``benchmark/tests/smallthinker_faults.py::kv_heads_modulo``) puts an
+    ``n_heads``-wide copy in another order in this function's place, and
+    the call then runs at equal head counts on that copy."""
+    return kv
 
 
 @jax.named_scope("attn")

@@ -22,6 +22,36 @@ padded and P·V is ``Dv`` wide. A call with ``Dqk == Dv`` traces to the
 program it traced to before there were two (``tests/test_flash.py`` pins
 the jaxpr).
 
+Which head. k and v have ``KV`` heads where q has ``H = KV · group``, and
+query head ``i`` reads key/value head ``i // group`` — WHERE IT LIES: in
+the merged ``[B·H, S, D]`` / ``[B·KV, S, D]`` layout row ``b`` of q reads
+row ``b // group`` of k and v, and that division stands in the K and V
+index maps of ``flash_fwd`` and ``flash_dq`` (``_heads_of``), whose
+bodies, tiles and grids are what they are for one head a head. No copy of
+a key/value head a query head exists in HBM, before or after a kernel
+(until PR 55 the models repeated K and V ``group`` times, XLA laid the
+copies out twice, ``flash_dkv`` wrote dK and dV ``H`` heads wide and a
+``group``-way sum followed: ≈ 20 H-wide arrays a layer-step, 235 MB each
+at 28 heads of 16k). In the resident regime consecutive heads of a group
+keep the K / V block index and Pallas does not fetch the block again.
+``flash_dkv``'s grid leads over the ``B·KV`` key/value heads and takes one
+more axis, INNERMOST, over the group's query heads: a column's sweep (the
+resident kernel's loop, a streamed tile) runs for head ``b · group + g``
+at step ``g``, the K / V block stays where it is, and dk and dv
+accumulate in float32 scratch across the whole group, cleared at the
+column's first tile of head 0 and written ONCE, at its last tile of head
+``group − 1`` (``_at_group_head``): one rounding of the group's sum,
+where the copies' gradients were each rounded and then summed. Under the
+mask the enumeration stays key-block-major with the group inside a tile,
+so an accumulator is live for one column at a time and nothing
+``group``-wide is resident. ``group == 1`` — every MHA call, latent
+attention, the ring — traces to the program it always traced to: no ``//
+1`` in a map, no extra axis, no scratch in the resident dkv (the rule the
+``window=None`` path and the ``Dqk == Dv`` zeros follow; ``tests/
+test_flash.py`` pins the jaxprs). Measured on the v5e (PERF.md, PR 55):
+the three kernels take the same time grouped as on the copies, within
+0.5 %, at 7, 16, 4 and 2 heads a key/value head.
+
 What is which dtype. q, k, v, dO arrive and out, dq, dk, dv leave in the
 input dtype (bf16 in the models). Inside a kernel every operand is upcast
 to f32 as it is loaded and every ``dot_general`` is f32 x f32 -> f32
@@ -122,6 +152,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from torchft_tpu.utils.metrics import TRACED
 
 __all__ = [
     "flash_attention",
@@ -441,32 +473,72 @@ def _full_or_masked(qi, ki, block_q: int, block_k: int, causal: bool, tile,
     pl.when(jnp.logical_not(full))(functools.partial(tile, True))
 
 
-def _streamed_grid(bh: int, seq_len: int, block_q: int, block_k: int,
-                   causal: bool, rows: bool, window: Optional[int] = None):
+def _heads_of(group: int, rows: bool):
+    """``(q_head, kv_head, inner)``: which head of q (out, dO, the
+    statistics) and which of k and v a grid step reads, as functions of
+    the step's grid indices, and the axes a grouped call adds to the grid.
+    Query head ``i`` reads key/value head ``i // group`` (merged layout:
+    row ``batch * H + i`` of q, row ``batch * KV + i // group`` =
+    ``(batch * H + i) // group`` of k). At ``group == 1`` both are the
+    leading index itself and nothing is added: the program such a call
+    always traced to, with no ``// 1`` in it."""
+    if group == 1:
+        return (lambda ids: ids[0]), (lambda ids: ids[0]), ()
+    if rows:            # the leading axis runs over the query heads
+        return (lambda ids: ids[0]), (lambda ids: ids[0] // group), ()
+    # the column sweep: key/value heads lead, the group's heads innermost
+    return ((lambda ids: ids[0] * group + ids[-1]), (lambda ids: ids[0]),
+            (group,))
+
+
+def _whole_of(head):
+    """The index map of a resident operand: all ``[S, D]`` (``[1, S]``) of
+    the head that ``head`` (of :func:`_heads_of`) reads off the grid."""
+    return lambda *ids: (head(ids), 0, 0)
+
+
+def _streamed_grid(heads: int, seq_len: int, block_q: int, block_k: int,
+                   causal: bool, rows: bool, window: Optional[int] = None,
+                   group: int = 1):
     """A streamed call's ``(grid, tables, by_q, by_k, q_lanes)``: the grid,
     the scalar-prefetch operands and the index maps of a block of q rows
     ([.., BQ, D]), of k rows and of q positions along the lanes ([.., 1,
     BQ], the statistics). Without the mask the grid is the rectangle of
     blocks, swept axis innermost, and nothing is prefetched; under it one
     axis enumerates :func:`_live_tiles` and the maps read the tile's blocks
-    off the two tables."""
+    off the two tables. ``heads`` is what the leading axis runs over: the
+    query heads of a row sweep, the key/value heads of the column sweep.
+    Where a key/value head serves a ``group`` of query heads, a row sweep's
+    ``by_k`` reads head ``b // group``, and the column sweep takes one more
+    grid axis, innermost, over the group's heads: its ``by_q`` and
+    ``q_lanes`` read head ``b * group + g`` (:func:`_heads_of`)."""
+    q_head, kv_head, inner = _heads_of(group, rows)
     if causal:
         tables = _live_tiles(seq_len, block_q, block_k, rows,
                              window=window)
+
+        def at(head, of, lanes=False):
+            def index_map(*ids_and_tables):
+                *ids, q_of, k_of = ids_and_tables
+                block = (q_of if of == "q" else k_of)[ids[1]]
+                return ((head(ids), 0, block) if lanes
+                        else (head(ids), block, 0))
+            return index_map
+
         return (
-            (bh, len(tables[0])), tuple(jnp.asarray(t) for t in tables),
-            lambda b, t, q_of, k_of: (b, q_of[t], 0),
-            lambda b, t, q_of, k_of: (b, k_of[t], 0),
-            lambda b, t, q_of, k_of: (b, 0, q_of[t]),
+            (heads, len(tables[0])) + inner,
+            tuple(jnp.asarray(t) for t in tables),
+            at(q_head, "q"), at(kv_head, "k"), at(q_head, "q", lanes=True),
         )
     num_q, num_k = seq_len // block_q, seq_len // block_k
-    if rows:
-        return ((bh, num_q, num_k), (),
-                lambda b, i, j: (b, i, 0), lambda b, i, j: (b, j, 0),
-                lambda b, i, j: (b, 0, i))
-    return ((bh, num_k, num_q), (),
-            lambda b, i, j: (b, j, 0), lambda b, i, j: (b, i, 0),
-            lambda b, i, j: (b, 0, j))
+    q_at, k_at = (1, 2) if rows else (2, 1)   # swept axis innermost
+    return (
+        ((heads, num_q, num_k) if rows else (heads, num_k, num_q)) + inner,
+        (),
+        lambda *ids: (q_head(ids), ids[q_at], 0),
+        lambda *ids: (kv_head(ids), ids[k_at], 0),
+        lambda *ids: (q_head(ids), 0, ids[q_at]),
+    )
 
 
 def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
@@ -516,10 +588,12 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool,
                    resident_kv_bytes: Optional[int] = None,
                    window: Optional[int] = None):
-    """q, k: [BH, S, Dqk], v: [BH, S, Dv] -> (out [BH, S, Dv], lse
-    [BH, S] f32)."""
+    """q [BH, S, Dqk], k [BKV, S, Dqk], v [BKV, S, Dv] -> (out [BH, S,
+    Dv], lse [BH, S] f32); row ``b`` of q reads row ``b // (BH // BKV)``
+    of k and v."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
     kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
@@ -530,6 +604,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
     )
     if kv_bytes <= threshold:
         grid = (bh, seq_len // block_q)
+        whole_kv = _whole_of(_heads_of(group, True)[1])
         kernel = functools.partial(
             _flash_kernel,
             block_q=block_q,
@@ -544,8 +619,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((1, seq_len, dv), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, seq_len, d), whole_kv),
+                pl.BlockSpec((1, seq_len, dv), whole_kv),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
@@ -559,7 +634,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
 
     # Long context: stream K/V tiles via the grid.
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, True, window=window
+        bh, seq_len, block_q, block_k, causal, True, window=window,
+        group=group,
     )
     kernel = functools.partial(
         _flash_streamed_kernel,
@@ -680,10 +756,23 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
+def _at_group_head(edge, head: int, axis: int, group: int):
+    """``edge`` — where ONE head's sweep of a dkv column starts (ends) —
+    narrowed to where the accumulators are cleared (written out). A
+    key/value head that serves a ``group`` of query heads meets them on
+    grid axis ``axis``, the innermost: dk and dv are the float32 sum over
+    the whole group, cleared at head 0's first tile and rounded once, at
+    head ``group - 1``'s last."""
+    if group == 1:
+        return edge
+    return edge & (pl.program_id(axis) == head)
+
+
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, block_k: int,
-                          seq_len: int, causal: bool, scale: float,
-                          window: Optional[int] = None):
+                          dk_ref, dv_ref, *acc_refs, block_q: int,
+                          block_k: int, seq_len: int, causal: bool,
+                          scale: float, window: Optional[int] = None,
+                          group: int = 1):
     ki = pl.program_id(1)
     k = _f32(k_ref[0])                            # [BK, Dqk]
     v = _f32(v_ref[0])                            # [BK, Dv]
@@ -706,8 +795,25 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          else jnp.zeros(v.shape, dtype=jnp.float32)),
         window=window,
     )
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    # one head of the group a grid step, this column's sums in scratch
+    dk_acc, dv_acc = acc_refs
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    dk_acc[...] = dk_acc[...] + dk
+    dv_acc[...] = dv_acc[...] + dv
+
+    @pl.when(pl.program_id(2) == group - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
@@ -745,16 +851,20 @@ def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
 
 def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
                                    seq_len: int, causal: bool, scale: float,
-                                   window: Optional[int] = None):
+                                   window: Optional[int] = None,
+                                   group: int = 1):
     """Q/dO tiles ride the innermost grid dim; dk/dv accumulate in VMEM
-    scratch across the q sweep."""
+    scratch across the q sweep — of every query head of the ``group``
+    this key/value head serves, a tile's heads one after the other
+    (:func:`_at_group_head`)."""
     qi, ki, first, last, refs = _streamed_tile(
         refs, block_q, block_k, seq_len, causal, False, window=window
     )
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
      dv_acc) = refs
+    heads_axis = 2 if causal else 3
 
-    @pl.when(qi == first)
+    @pl.when(_at_group_head(qi == first, 0, heads_axis, group))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -771,7 +881,7 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
     _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
                     window=window)
 
-    @pl.when(qi == last)
+    @pl.when(_at_group_head(qi == last, group - 1, heads_axis, group))
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -784,9 +894,11 @@ def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
     ``[BH, 1, S]`` views both take."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
 
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, True, window=window
+        bh, seq_len, block_q, block_k, causal, True, window=window,
+        group=group,
     )
     dq = pl.pallas_call(
         functools.partial(
@@ -817,13 +929,14 @@ def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
     )(*tables, q, k, v, g, lse_row, delta_row)
 
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
-        bh, seq_len, block_q, block_k, causal, False, window=window
+        k.shape[0], seq_len, block_q, block_k, causal, False, window=window,
+        group=group,
     )
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_streamed_kernel, block_q=block_q,
             block_k=block_k, seq_len=seq_len, causal=causal, scale=scale,
-            window=window,
+            window=window, group=group,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
@@ -882,6 +995,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     can be revisited in any order/placement and summed."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
     threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
                  else resident_kv_bytes)
     kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
@@ -894,6 +1008,7 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             block_k, interpret, window=window,
         )
 
+    whole_kv = _whole_of(_heads_of(group, True)[1])
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
         seq_len=seq_len, causal=causal, scale=scale, window=window,
@@ -903,8 +1018,8 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
         grid=(bh, seq_len // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_len, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_len, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, seq_len, d), whole_kv),
+            pl.BlockSpec((1, seq_len, dv), whole_kv),
             pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
@@ -918,22 +1033,34 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
         seq_len=seq_len, causal=causal, scale=scale, window=window,
+        group=group,
     )
+    # the column sweep: the key/value heads lead; a group's query heads
+    # ride one more grid axis, innermost, and their sums two scratch
+    # accumulators (none at group 1: the sweep's own carry is written out)
+    q_head, _, inner = _heads_of(group, False)
+    by_head = _whole_of(q_head)
+
+    def by_k(b, j, *head_in_group):
+        return b, j, 0
+
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, seq_len // block_k),
+        grid=(k.shape[0], seq_len // block_k) + inner,
         in_specs=[
-            pl.BlockSpec((1, seq_len, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, seq_len, dv), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq_len), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, seq_len, d), by_head),
+            pl.BlockSpec((1, block_k, d), by_k),
+            pl.BlockSpec((1, block_k, dv), by_k),
+            pl.BlockSpec((1, seq_len, dv), by_head),
+            pl.BlockSpec((1, 1, seq_len), by_head),
+            pl.BlockSpec((1, 1, seq_len), by_head),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), by_k),
+            pl.BlockSpec((1, block_k, dv), by_k),
         ],
+        scratch_shapes=[pltpu.VMEM((block_k, width), jnp.float32)
+                        for width in (d, dv) if group > 1],
         out_shape=(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -1070,12 +1197,13 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
             k_edge if block_k is None else min(block_k, seq_len))
 
 
-def _bshd_prologue(q, v, scale, block_q, block_k, window=None):
+def _bshd_prologue(q, k, v, scale, block_q, block_k, window=None):
     """Shared [B,S,H,D]-surface plumbing: scale default (from q's
     width), block choice (from the shape where the caller gave none) and
-    clamping, divisibility validation, and the [B,S,H,D] <-> [B*H,S,D]
-    layout pair, which keeps each array's own last dim. One place, three
-    wrappers."""
+    clamping, validation (the sequence a multiple of the blocks, the query
+    heads a multiple of the key/value heads), and the [B,S,H,D] <->
+    [B*H,S,D] layout pair, which keeps each array's own head count and
+    last dim. One place, three wrappers."""
     b, s, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -1087,12 +1215,18 @@ def _bshd_prologue(q, v, scale, block_q, block_k, window=None):
             f"flash attention: seq len {s} of q{tuple(q.shape)} must be a "
             f"multiple of the block sizes ({block_q}, {block_k})"
         )
+    if k.shape[2] != v.shape[2] or h % k.shape[2]:
+        raise ValueError(
+            f"flash attention: the {h} heads of q{tuple(q.shape)} must be a "
+            f"multiple of the key/value heads of k{tuple(k.shape)} and "
+            f"v{tuple(v.shape)}, which must be as many"
+        )
 
     def merge(x):  # [B,S,H,D] -> [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
+        return x.transpose(0, 2, 1, 3).reshape(-1, s, x.shape[-1])
 
     def unmerge(x):  # [B*H, S, D] -> [B,S,H,D]
-        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
+        return x.reshape(b, -1, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return float(scale), block_q, block_k, merge, unmerge
 
@@ -1115,7 +1249,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     ``flash_block_attention_bwd``."""
     b, s, h, _ = q.shape
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, v, scale, block_q, block_k
+        q, k, v, scale, block_q, block_k
     )
     out, lse = _flash_forward(
         merge(q), merge(k), merge(v), causal, scale,
@@ -1143,7 +1277,7 @@ def flash_block_attention_bwd(q, k, v, do, lse, delta, causal: bool,
     runs causal=True, past pairs causal=False."""
     b, s, h, _ = q.shape
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, v, scale, block_q, block_k
+        q, k, v, scale, block_q, block_k
     )
 
     def merge_stat(x):  # [B,H,S] -> [BH, S]
@@ -1165,9 +1299,11 @@ def flash_attention(q, k, v, causal: bool = True,
                     interpret: bool = False,
                     _resident_kv_bytes: Optional[int] = None,
                     window: Optional[int] = None):
-    """Flash attention (pallas on TPU): q, k ``[B, S, H, Dqk]``, v
-    ``[B, S, H, Dv]`` -> ``[B, S, H, Dv]``; the softmax scale defaults to
-    ``1 / sqrt(Dqk)``.
+    """Flash attention (pallas on TPU): q ``[B, S, H, Dqk]``, k ``[B, S,
+    KV, Dqk]``, v ``[B, S, KV, Dv]`` with ``H % KV == 0`` -> ``[B, S, H,
+    Dv]``; query head ``i`` reads key/value head ``i // (H / KV)`` where
+    it lies (module docstring: nothing is copied, dk and dv come back
+    ``KV`` heads wide); the softmax scale defaults to ``1 / sqrt(Dqk)``.
 
     ``window`` = W (causal calls only): position ``t`` sees keys ``t − (W
     − 1) … t``, W with itself. The sweeps then run over the band's tiles
@@ -1193,8 +1329,11 @@ def flash_attention(q, k, v, causal: bool = True,
         if window >= q.shape[1]:
             window = None
     scale, block_q, block_k, merge, unmerge = _bshd_prologue(
-        q, v, scale, block_q, block_k, window
+        q, k, v, scale, block_q, block_k, window
     )
+    TRACED.incr("flash_calls")
+    if k.shape[2] != q.shape[2]:
+        TRACED.incr("flash_calls_grouped")
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
                  block_q, block_k, interpret, _resident_kv_bytes, window)
     return unmerge(out)

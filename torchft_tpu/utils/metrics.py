@@ -16,7 +16,7 @@ from collections import defaultdict, deque
 from contextlib import contextmanager
 from typing import Deque, Dict
 
-__all__ = ["Metrics"]
+__all__ = ["Metrics", "TRACED"]
 
 
 class Metrics:
@@ -112,3 +112,11 @@ class Metrics:
                     )
                     out[f"{name}_max_ms"] = vals[-1] * 1000.0
         return out
+
+
+# What happened while programs were TRACED, process-wide: a kernel's wrapper
+# counts here which of its paths a model's program engaged (once a trace,
+# not once a step; ``ops/flash.py``: ``flash_calls`` and, of those, the
+# ``flash_calls_grouped`` whose key/value heads each serve several query
+# heads). Tests read the difference across a ``jax.make_jaxpr``.
+TRACED = Metrics()
