@@ -12,8 +12,9 @@ import numpy as np
 import pytest
 
 from benchmark.reference.kimi_linear_f32 import kda_recurrence
+from benchmark.reference.olmo_hybrid_f32 import gdn_recurrence
 from torchft_tpu.ops import kda
-from torchft_tpu.ops.kda import _choose_chunk, kda_scan
+from torchft_tpu.ops.kda import _choose_chunk, gdn_scan, kda_scan
 
 
 LEAVES = ("dq", "dk", "dv", "dg", "dbeta")
@@ -278,6 +279,199 @@ def test_a_call_site_traces_no_kernel_body_again() -> None:
     assert text.count("name=kda_fwd") >= 1 and len(calls) == 1
 
 
+# -- one decay a head: the scalar-decay kernels (Gated DeltaNet) -------------
+
+
+def gdn_inputs(seed, b, s, h, kd, vd, decay=1.0):
+    """As :func:`inputs`, with ONE log-decay a head a position and steps
+    over (0, 2)."""
+    (q, key, v, _, _), do = inputs(seed, b, s, h, kd, vd)
+    k = jax.random.split(jax.random.key(1000 + seed), 2)
+    g = -decay * jax.nn.softplus(jax.random.normal(k[0], (b, s, h)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(k[1], (b, s, h)))
+    return (q, key, v, g, beta), do
+
+
+def gdn(q, k, v, g, beta, chunk=None):
+    if chunk is None:
+        return gdn_scan(q, k, v, g, beta)
+    return kda._gdn(q, k, v, g, beta, chunk, kda._interpret())
+
+
+def broadcast(q, k, v, g, beta, chunk):
+    """``kda_scan``'s kernels fed the scalar decay a channel."""
+    return kda._kda(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta,
+                    chunk, kda._interpret())
+
+
+@pytest.mark.parametrize("b,s,h,kd,vd,chunk,decay", [
+    (2, 40, 6, 16, 32, 8, 1.0),       # a ragged end, six heads a step
+    (1, 100, 6, 24, 48, 32, 0.1),     # slow decays, a ragged end
+    (1, 256, 6, 96, 192, 128, 0.3),   # the cell's widths and chunk
+    (1, 192, 6, 64, 128, 64, 0.3),    # 64 / 128
+    (1, 48, 30, 12, 24, 16, 0.05),    # the cell's head count
+    (1, 128, 3, 96, 192, None, 0.02),  # the public function's own choice
+])
+def test_the_scalar_decay_scan_is_the_recurrence_and_the_broadcast(
+        b, s, h, kd, vd, chunk, decay):
+    args, do = gdn_inputs(s, b, s, h, kd, vd, decay)
+    with jax.default_matmul_precision("highest"):
+        want, pull_ref = jax.vjp(gdn_recurrence, *args)
+        got, pull = jax.vjp(lambda *a: gdn(*a, chunk=chunk), *args)
+        grads, grads_ref = pull(do), pull_ref(do)
+        other, pull_other = jax.vjp(
+            lambda *a: broadcast(*a, chunk or _choose_chunk(s)), *args)
+        grads_other = pull_other(do)
+    assert got.shape == (b, s, h, vd) and rel(got, want) < 2e-6
+    assert rel(got, other) < 3e-6
+    assert float(jnp.max(args[4])) > 1.0     # the negative-eigenvalue branch
+    for name, a, ref, via in zip(LEAVES, grads, grads_ref, grads_other):
+        assert a.shape == ref.shape, name
+        assert rel(a, ref) < 5e-6, name
+        assert rel(a, via) < 5e-6, name
+
+
+def test_each_scan_refuses_the_others_decay() -> None:
+    """A decay of rank three is ``gdn_scan``'s, one of rank four
+    ``kda_scan``'s: each refuses the other's, and operands that do not
+    fit, with a message."""
+    args, _ = gdn_inputs(5, 1, 32, 2, 8, 16)
+    q, k, v, g, beta = args
+    with pytest.raises(ValueError, match="kda_scan"):
+        kda_scan(*args)
+    for bad in ((q, k, v, g[:, :8], beta), (q, k, v, g, beta[..., :1]),
+                (q, k[..., :4], v, g, beta), (q, k, v[:, :8], g, beta),
+                (q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)):
+        with pytest.raises(ValueError, match="gdn_scan.*do not fit"):
+            gdn_scan(*bad)
+
+
+@pytest.mark.parametrize("contract", [((1,), (0,)), kda._NT, ((0,), (0,))],
+                         ids=["ab", "abT", "aTb"])
+def test_the_three_pass_matmul_is_an_f32_matmul_to_2e_minus_5(contract):
+    """``_gdot`` as the chip runs it (the interpreter's kernels multiply
+    in f32): ``hi·hi + hi·lo + lo·hi`` of f32 operands split in two bf16
+    halves against the f32 product — 2^-16 of ``|a|·|b|`` where ONE bf16
+    pass, the channel-wise kernels' ``_dot``, is off by 2^-8."""
+    a, b = (jax.random.normal(jax.random.key(i), (128, 128), jnp.float32)
+            for i in (1, 2))
+    want = jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST)
+    scale = jax.lax.dot_general(jnp.abs(a), jnp.abs(b), (contract, ((), ())),
+                                precision=jax.lax.Precision.HIGHEST)
+    three = float(jnp.max(
+        jnp.abs(kda._gdot(False, a, b, contract) - want) / scale))
+    bf = jnp.bfloat16
+    one = float(jnp.max(jnp.abs(
+        kda._dot(a.astype(bf), b.astype(bf), contract) - want) / scale))
+    assert three < 2e-5 < 1e-3 < one * 4, (three, one)
+    # operands that are exact in bf16 lose nothing
+    exact = kda._gdot(False, kda._f32(a.astype(bf)), kda._f32(b.astype(bf)),
+                      contract)
+    assert float(jnp.max(jnp.abs(exact - jax.lax.dot_general(
+        kda._f32(a.astype(bf)), kda._f32(b.astype(bf)), (contract, ((), ())),
+        precision=jax.lax.Precision.HIGHEST)) / scale)) < 1e-6
+
+
+def test_the_scalar_decay_scan_in_bf16_and_a_decay_that_underflows() -> None:
+    (q, k, v, g, beta), do = gdn_inputs(11, 1, 64, 3, 24, 48)
+    bf = jnp.bfloat16
+    got, pull = jax.vjp(
+        lambda *a: gdn(*a, chunk=16), q.astype(bf), k.astype(bf),
+        v.astype(bf), g, beta)
+    grads = pull(do.astype(bf))
+    assert got.dtype == bf
+    assert [x.dtype for x in grads] == [bf, bf, bf, jnp.float32, jnp.float32]
+    rounded = tuple(x.astype(bf).astype(jnp.float32) for x in (q, k, v))
+    assert rel(got.astype(jnp.float32),
+               gdn_recurrence(*rounded, g, beta)) < 5e-3
+    # a chunk that forgets by e^-12800: 0, never inf or nan
+    hard = jnp.full_like(g, -200.0)
+    out, pull = jax.vjp(lambda *a: gdn(*a, chunk=64), q, k, v, hard, beta)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in (out,) + pull(do))
+    with jax.default_matmul_precision("highest"):
+        assert rel(out, gdn_recurrence(q, k, v, hard, beta)) < 2e-6
+
+
+@pytest.mark.parametrize("h,kd,vd,want", [
+    (30, 96, 192, 4),     # the cell: 4 x 96 = 3 tiles, 4 x 192 = 6; 7.5 groups
+    (32, 96, 192, 4),
+    (30, 128, 128, 6), (32, 128, 128, 4), (7, 128, 128, 1), (10, 128, 128, 5),
+    (30, 64, 128, 6), (9, 64, 128, 2),    # two heads of 64 are a tile
+    (30, 128, 1024, 1),   # a step too large for the limit: a smaller group
+])
+def test_the_scalar_decay_grid_takes_any_head_count(h, kd, vd, want) -> None:
+    """A group is whole lane tiles on the TPU (of 96 / 192 only four heads
+    are) and need not divide the heads: the rung that leaves the fewest
+    heads of the last group outside the arrays, the largest of those."""
+    assert kda._gdn_heads_a_step(h, 128, kd, vd, False) == want
+
+
+def test_the_scalar_decay_grid_refuses_widths_no_group_tiles() -> None:
+    with pytest.raises(ValueError, match="gdn_scan: no group.*100 key"):
+        kda._gdn_heads_a_step(30, 128, 100, 192, False)
+    # the interpreter takes any width, and the group that wastes least
+    assert kda._gdn_heads_a_step(30, 16, 12, 24, True) == 6
+    assert kda._gdn_heads_a_step(7, 16, 12, 24, True) == 1
+
+
+@pytest.fixture
+def gdn_ladder(monkeypatch):
+    def to(*rungs):
+        monkeypatch.setattr(kda, "_GDN_LADDER", rungs)
+        jax.clear_caches()
+    yield to
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("heads", [2, 3, 4, 5, 6])
+def test_scalar_decay_heads_that_share_a_step_change_no_bit(
+        gdn_ladder, heads) -> None:
+    """Four and five do not divide six: the last group's missing heads
+    lie outside the arrays (an edge block), as two of the cell's 32 head
+    places do, and change nothing in the six that are there."""
+    args, do = gdn_inputs(21, 2, 40, 6, 16, 32)
+
+    def all_six(chunk=16):
+        o, pull = jax.vjp(lambda *a: gdn(*a, chunk=chunk), *args)
+        return (o,) + pull(do)
+
+    gdn_ladder(1)
+    want = all_six()
+    gdn_ladder(heads)
+    for name, a, b in zip(("o",) + LEAVES, all_six(), want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+# sha256 of the jaxpr (source positions cut out) of the gradient of
+# ``kda_scan`` at Kimi's call ([4, 8192] of 32 heads of 128, bf16 q / k /
+# v, f32 g a channel and β): both kernels, their grids and every
+# instruction. Pinned at the commit before the scalar-decay path entered
+# the file (6762fd9): a change that moves it moves what
+# ``kimi-ep32-solo-steady`` runs; regenerate on purpose only.
+_KIMI_CALL_JAXPR = \
+    "0ccd965ce1f92ce6410b312c1761048ab2d2269b6d5745972acd5e4fb89fdec7"
+
+
+def test_kimis_call_traces_to_the_kernels_it_traced_to() -> None:
+    import hashlib
+    import re
+
+    q = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.float32)
+    beta = jax.ShapeDtypeStruct((4, 8192, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(kda_scan(q, k, v, g, beta).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        q, q, q, g, beta))
+    text = re.sub(r"/[^ ]*?\.py:\d+", "", text)
+    assert "name=kda_fwd" in text and "name=kda_bwd" in text
+    assert "name=gdn_" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _KIMI_CALL_JAXPR
+
+
 # -- the kernels at the cell's widths, for a described v5e --------------------
 
 
@@ -317,3 +511,49 @@ def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
     per_position_states = b * s * h * d * d * 4
     assert compiled.memory_analysis().temp_size_in_bytes < \
         per_position_states / 16
+
+
+def test_the_scalar_decay_kernels_compile_for_the_v5e_at_the_cells_widths(
+        one_chip):
+    """[1, 1024] of 30 heads of 96 key and 192 value channels at the
+    cell's chunk and four heads a grid step: Mosaic takes the lane slices
+    that start between tiles, the matmuls 96 and 192 wide, the edge block
+    of the eighth group, the ``[1, 1]`` decays, the transposes and the
+    VMEM four heads' temporaries ask for; no head is padded in HBM (no
+    operand 30 x 128 or 30 x 256 wide), ``g`` stands nowhere a channel
+    (no f32 ``[B, S, H·K]`` operand), and nothing ``[B, S, H, K, V]`` is
+    planned."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    b, s, h, kd, vd = 1, 1024, 30, 96, 192
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    keys, values = (sd((b, s, h, d), jnp.bfloat16) for d in (kd, vd))
+    scalar = sd((b, s, h), jnp.float32)
+
+    def both(q, k, v, g, beta, do):
+        o, pull = jax.vjp(
+            lambda *a: kda._gdn(*a, kda._CHUNK, False), q, k, v, g, beta)
+        return o, pull(do)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(both).lower(
+            keys, keys, values, scalar, scalar, values).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert kda._gdn_heads_a_step(h, kda._CHUNK, kd, vd, False) == 4
+    for kernel in ("gdn_fwd", "gdn_bwd"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    for wide in (h * kd, h * 128, h * 256):
+        assert f"f32[{b},{s},{wide}]" not in text
+    assert f"bf16[{b},{s},{h * 128}]" not in text
+    assert f"bf16[{b},{s},{h * 256}]" not in text
+    per_position_states = b * s * h * kd * vd * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        per_position_states / 8

@@ -559,9 +559,15 @@ def test_on_the_chip_a_head_is_whole_lane_tiles(monkeypatch):
     with pytest.raises(ValueError, match="kda_qkg: a head's: 16 channels "
                                          "are no multiple of 128 lanes"):
         sp.kda_qkg(qkv, f, dt_bias, a_log, l2_normed)
-    with pytest.raises(ValueError, match="kda_ogate: a head's: 16 channels "
-                                         "are no multiple of 128 lanes"):
+    # the head norm takes any head whose blocks can be whole heads AND
+    # whole lane tiles: 4 heads of 16 are half a tile, 2 of 192 are three
+    with pytest.raises(ValueError, match="kda_ogate: 64 channels in heads "
+                                         "of 16 are no whole blocks of 128"):
         sp.kda_ogate(f, f, jnp.ones((16,)), 1e-5, head_norm_then_gate)
+    odd = jnp.ones((1, 16, 3 * 192))
+    with pytest.raises(ValueError, match="kda_ogate: 576 channels in heads "
+                                         "of 192 are no whole blocks of 384"):
+        sp.kda_ogate(odd, odd, jnp.ones((192,)), 1e-5, head_norm_then_gate)
 
 
 def test_blocks_are_chosen_from_the_shape():
@@ -578,6 +584,13 @@ def test_blocks_are_chosen_from_the_shape():
     assert sp._lane_block(4096, 128) == 512
     # no multiple of a lane tile: the whole width (off the TPU)
     assert sp._lane_block(64) == 64 and sp._lane_block(64, 32) == 64
+    # Olmo Hybrid's head norm: [1, 8192, 5760] in 30 heads of 192, two
+    # heads (three lane tiles) a block; an odd number of such heads is no
+    # whole blocks
+    assert sp._whole_tiles(192) == 384 and sp._whole_tiles(128) == 128
+    assert sp._lane_block(5760, 192) == 384
+    assert sp._row_block(8192, 384) == 512
+    assert sp._lane_block(3 * 192, 192) == 3 * 192
     # a short sequence whole; a divisor where one is near; else ragged
     assert sp._row_block(24, 512) == 24
     assert sp._row_block(8960, 512) == 448
@@ -624,3 +637,64 @@ def test_the_kda_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
                    "kda_ogate_bwd"):
         assert kernel in text, kernel
 
+
+
+def silu_gate_after_norm(o, scale, gate, eps):
+    """Olmo Hybrid's seam: the head norm, then a SiLU gate."""
+    r = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * r * scale * (gate * jax.nn.sigmoid(gate))
+
+
+@pytest.mark.parametrize("heads,head", [(4, 24), (6, 192)])
+def test_kda_ogate_takes_the_heads_width_and_the_gates_activation(heads, head):
+    """A head of any width (the weight's) and the caller's activation: a
+    SiLU after the norm at 24 and at 192 lanes (two heads to three tiles
+    on the chip), forward and the three cotangents against jnp."""
+    k = jax.random.split(jax.random.key(heads), 4)
+    o, gate, dy = (jax.random.normal(k[i], (2, 40, heads * head))
+                   for i in range(3))
+    scale = 1.0 + 0.1 * jax.random.normal(k[3], (head,))
+
+    def formula(o, gate, scale):
+        shape = o.shape[:2] + (heads, head)
+        return silu_gate_after_norm(
+            o.reshape(shape), scale, gate.reshape(shape), 1e-6
+        ).reshape(o.shape)
+
+    got, pull = jax.vjp(lambda o, gate, scale: sp.kda_ogate(
+        o, gate, scale, 1e-6, silu_gate_after_norm), o, gate, scale)
+    want, pull_ref = jax.vjp(formula, o, gate, scale)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for a, b in zip(pull(dy), pull_ref(dy)):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+def test_the_head_norm_compiles_for_the_v5e_at_heads_of_192(one_chip):
+    """[1, 8192] of 30 heads of 192 with the SiLU seam at the blocks the
+    shape chooses (512 rows x two heads): Mosaic takes a head that starts
+    between lane tiles, forward and backward."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    b, s, h, d = 1, 8192, 30, 192
+    w, bf16 = h * d, jnp.bfloat16
+    blocks = sp._row_block(s, sp._lane_block(w, d)), sp._lane_block(w, d)
+    assert blocks == (512, 384)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(o, gate, scale, dy):
+        y, pull = jax.vjp(lambda *a: sp._ogate(
+            *a, d, 1e-6, silu_gate_after_norm, blocks, False), o, gate, scale)
+        return y, pull(dy)
+
+    wide = sd((b, s, w), bf16)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(both).lower(
+            wide, wide, sd((d,), F32), wide).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "kda_ogate_fwd" in text and "kda_ogate_bwd" in text

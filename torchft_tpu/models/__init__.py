@@ -7,10 +7,12 @@ own, each with ``<Family>Config``, ``init_params``, ``forward_hidden``,
 ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear`` (which calls
 ``joyai``'s latent-attention and sparse sublayers with its own config),
 ``phi4flash`` (a stack that is not a chain: two layers hand their scan
-output and their keys and values on to later layers) and ``smallthinker``
+output and their keys and values on to later layers), ``smallthinker``
 (attention windowed or full and rotated or not by two lists of the
-config, a router that reads the stream before attention, ReGLU experts);
-what more than one of them computes is in ``common``."""
+config, a router that reads the stream before attention, ReGLU experts)
+and ``olmo_hybrid`` (a dense stack of scalar-gated delta-rule mixers 3 : 1
+with unrotated full attention, the OLMo family's norms on each sublayer's
+output); what more than one of them computes is in ``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

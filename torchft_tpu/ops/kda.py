@@ -1,9 +1,17 @@
-"""Pallas kernels for Kimi Delta Attention (KDA; Kimi Linear,
-arXiv:2510.26692): the gated delta rule with a decay PER KEY CHANNEL, in
-its chunked form, forward and backward under one ``jax.custom_vjp``.
+"""Pallas kernels for the gated delta rule in its chunked form, forward
+and backward under one ``jax.custom_vjp`` a kind of decay: a decay PER
+KEY CHANNEL (:func:`kda_scan`: Kimi Delta Attention, KDA; Kimi Linear,
+arXiv:2510.26692), which this docstring describes first, and ONE decay a
+head (:func:`gdn_scan`: Gated DeltaNet, arXiv:2412.06464; Olmo Hybrid),
+whose kernels ``gdn_fwd`` / ``gdn_bwd`` share the triangular inverse, the
+state pass, the backward's replay and the head-group grid and are
+described where they stand, at the end of the file. A head has ``K`` key
+and ``V`` value channels, equal or not; the step size ``β`` is whatever
+the caller hands over (Kimi's model bounds it by 1, Olmo Hybrid's by 2:
+the rule contracts for ``β`` in (0, 2), and no op here bounds it).
 
 Per head, with a state ``S ∈ R^{K×V}``, ``S_0 = 0``, log-decays ``g_t ∈
-R^K`` (<= 0), ``α_t = exp(g_t)`` and a step size ``β_t ∈ (0, 1)``:
+R^K`` (<= 0), ``α_t = exp(g_t)`` and a step size ``β_t``:
 
     S_t = (I − β_t k_t k_tᵀ) Diag(α_t) S_{t-1} + β_t k_t v_tᵀ,
     o_t = S_tᵀ q_t.
@@ -97,7 +105,12 @@ holds it, at least 16); a sequence that is no multiple is padded with
 ``g = 0, β = 0`` positions at its end (decay 1, nothing written: they
 change nothing before them). Off the TPU the same kernels run in
 Pallas's interpreter (the CPU tests), chosen from the backend alone. On
-the TPU ``K`` and ``V`` must be multiples of 128 lanes.
+the TPU the channel-wise kernels take ``K`` and ``V`` that are multiples
+of 128 lanes and refuse others with a message (a head there is an
+aligned lane slice of the step's block); the scalar-decay kernels take
+any ``K`` and ``V`` of which some group of heads fills whole lane tiles
+(four heads of 96 are three tiles, of 192 six), refuse others with a
+message, and take any head count (:func:`_gdn_heads_a_step`).
 """
 
 from __future__ import annotations
@@ -109,7 +122,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["kda_scan"]
+__all__ = ["kda_scan", "gdn_scan"]
 
 _NEG = -1e30     # exp(_NEG) == 0: a pair outside its band
 _LANES = 128
@@ -141,9 +154,12 @@ def _dot(a, b, contract=((1,), (0,)), precision=None):
                                precision=precision)
 
 
+_NT = ((1,), (1,))     # the contraction of ``a·bᵀ``
+
+
 def _dot_nt(a, b):
     """``a·bᵀ``."""
-    return _dot(a, b, ((1,), (1,)))
+    return _dot(a, b, _NT)
 
 
 def _iota(shape, axis: int):
@@ -247,18 +263,21 @@ def _pairs_bwd(dms, xs, k, levels, bands):
     return dxs, dk_col
 
 
-def _solve(lower):
+def _solve(lower, dot=None):
     """``(I + lower)^{-1}`` of a strictly lower-triangular ``[C, C]`` by
     block doubling (the module docstring): two matmuls a doubling, each
-    of which needs the one before (a generator: :func:`_side_by_side`)."""
+    of which needs the one before (a generator: :func:`_side_by_side`);
+    ``dot`` the matmul (:func:`_dot`; the scalar-decay kernels hand in
+    theirs)."""
+    dot = dot or _dot
     C = lower.shape[0]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     T = jnp.where(row == col, 1.0, 0.0)
     lg = 0
     while (1 << lg) < C:
-        half = _dot(T, jnp.where(_cross(row, col, lg), lower, 0.0))
+        half = dot(T, jnp.where(_cross(row, col, lg), lower, 0.0))
         yield
-        T = T - _dot(half, T)
+        T = T - dot(half, T)
         yield
         lg += 1
     return T
@@ -565,3 +584,408 @@ def kda_scan(q, k, v, g, beta):
             f"channels are no multiples of {_LANES} lanes")
     return _kda(q, k, v, _f32(g), _f32(beta), _choose_chunk(q.shape[1]),
                 interpret)
+
+
+# ------------------------------------------------ a decay that is ONE SCALAR
+# a head a position (Gated DeltaNet, arXiv:2412.06464): ``g [B, S, H]``.
+# With ``G_i`` the chunk's cumulative log-decay (a number a position),
+# every pair's weight is one ``[C, C]`` matrix ``D_ij = exp(G_i − G_j)``
+# (``i >= j``: the exponent is <= 0, masked BEFORE the exponential), so
+#
+#     A = stril((K Kᵀ) ∘ D)        B = tril((Q Kᵀ) ∘ D)
+#
+# are two matmuls and a product: the levels, the bands and their lane
+# reductions of the channel-wise rule have no counterpart, and ``g``
+# never exists a channel anywhere. The triangular inverse
+# (:func:`_solve`), the transposed f32 state carried in VMEM, the
+# backward's replay from the chunk-boundary states, the head-group grid
+# and the heads side by side are the channel-wise kernels' own. The
+# cumulative sums are taken OUTSIDE the kernels, on ``[B, S, H]`` (a
+# thousandth of the stream): ``G`` comes in twice, a column a head (``[C,
+# H]`` blocks, as ``β``) and a row a head (``[1, C]``, lane-dense), and
+# its cotangent leaves as a row; ``dg`` is the sum of ``dG`` from a
+# position to its chunk's end, outside too. Backward, beyond the
+# channel-wise formulas (``dU, dB, dRβ, dL, dβ, dR, dK̄, dQ̄, dK̃, dS``
+# are the same): with ``dA = β dL``,
+#
+#     dK = (dA∘D + (dA∘D)ᵀ) K + (dB∘D)ᵀ Q + dK̄ γ + dK̃ e^{G_C − G}
+#     dQ = (dB∘D) K + dQ̄ γ
+#     dG_i = Σ_j (P_ij − P_ji) + Σ_c (dQ̄∘Q̄ + dK̄∘K̄ − dK̃∘K̃)_ic,
+#     P = dA∘A + dB∘B
+#
+# and the chunk's last row takes what ``G_C`` carries.
+#
+# LANES. ``Dk`` and ``Dv`` need not be equal nor multiples of 128, and
+# nothing is padded: the operands stay ``[B, S, H·Dk]`` / ``[B, S, H·Dv]``
+# as the model hands them over, a grid step takes a group of heads whose
+# lanes are whole 128-lane tiles (four heads of 96 key channels are three
+# tiles, of 192 value channels six), and a head is a lane slice of the
+# step's block that may start between tiles — Mosaic shifts it, and a
+# ``[C, 96]`` operand is padded in VMEM, never in HBM. The group need not
+# divide the heads: 30 heads are seven groups of four and one of two,
+# whose two missing heads are the part of an edge block that lies outside
+# the arrays — read as whatever the buffer holds, computed beside the
+# others (heads share nothing) and never written. Measured against every
+# head padded to whole tiles in HBM (96 -> 128, 192 -> 256, five heads a
+# step), at [1, 8192, 30, 96 | 192] on the v5e with the XLA ops around
+# the kernels: 5.19 ms forward and 12.49 forward +
+# backward against 6.12 and 13.32, every leaf equal to the bit. In the
+# cell the kernels themselves are 6 - 10 % slower (the shifts), XLA's
+# pads and slices are gone, and what it now spends relaying ``[B, S, H,
+# D]`` tiles to the flat operands takes most of that back: + 0.1 % in
+# rate, 0.2 GiB less in the step's plan
+# (PERF.md section 6, PR 56). ``β`` is whatever the caller hands over: the
+# rule contracts for ``β`` in (0, 2) (``I − β k kᵀ`` has the eigenvalue
+# ``1 − β``), and nothing here bounds it.
+_GDN_LADDER = (6, 5, 4, 3, 2, 1)
+
+
+def _gdot(exact: bool, a, b, contract=((1,), (0,))):
+    """The scalar-decay kernels' matmul of two f32 operands in THREE
+    bf16 passes of the MXU, where the channel-wise kernels' :func:`_dot`
+    takes one. ``T``, ``U``, ``R``, the state and every cotangent are
+    f32 values that one pass rounds to bf16 each time they meet the MXU,
+    a chunk after a chunk; measured on the v5e
+    (``benchmark/tests/gdn_micro.py``; PERF.md section 6, PR 56) the
+    one-pass kernels read 0.005 – 0.010 in every leaf against the
+    recurrence where a RECURRENCE whose state is rounded to bf16 at
+    every chunk boundary reads 0.001 – 0.003: the kernels would be less
+    exact than a fault ``correct`` has to catch. Each operand is split
+    into its bf16 rounding and the bf16 rounding of what is left (``x =
+    hi + lo`` to 2^-17), and ``hi·hi + (hi·lo + lo·hi)`` is summed in f32
+    (``lo·lo``, 2^-18 of the product, is dropped) — ``Precision.HIGH``,
+    which Mosaic does not lower; the six passes of ``Precision.HIGHEST``
+    agree no better with the recurrence and take 1.7 x the time.
+    ``exact`` (the interpreter, where the CPU tests hold the kernels'
+    mathematics to f32's rounding) multiplies in f32;
+    ``tests/test_kda.py`` holds the three passes by themselves."""
+    if exact:
+        return _dot(a, b, contract, _HIGHEST)
+    bf16 = jnp.bfloat16
+    a_hi, b_hi = a.astype(bf16), b.astype(bf16)
+    a_lo, b_lo = (a - _f32(a_hi)).astype(bf16), (b - _f32(b_hi)).astype(bf16)
+    return _dot(a_hi, b_hi, contract) + (
+        _dot(a_hi, b_lo, contract) + _dot(a_lo, b_hi, contract))
+
+
+def _up(n: int, to: int = _LANES) -> int:
+    return -(-n // to) * to
+
+
+def _gdn_heads_a_step(h: int, chunk: int, kd: int, vd: int,
+                      interpret: bool) -> int:
+    """Heads a grid step holds: of the rungs of ``_GDN_LADDER`` whose
+    blocks are whole lane tiles (``n·K`` and ``n·V`` multiples of 128;
+    the interpreter takes any) and whose step fits ``_VMEM_LIMIT`` — a
+    head of ``gdn_bwd`` reckoned as 48 f32 tiles of ``[C, max(C, K, V)]``,
+    the lanes rounded up —, the one that leaves the fewest heads of the
+    last group outside the arrays, the largest of those."""
+    tile = 4 * chunk * _up(max(chunk, kd, vd))
+    rungs = [n for n in _GDN_LADDER
+             if (interpret or (n * kd % _LANES == 0 and n * vd % _LANES == 0))
+             and (n == 1 or n * 48 * tile <= _VMEM_LIMIT)]
+    if not rungs:
+        raise ValueError(
+            f"gdn_scan: no group of {_GDN_LADDER} heads of {kd} key and "
+            f"{vd} value channels fills whole {_LANES}-lane tiles")
+    return min(rungs, key=lambda n: (-(-h // n) * n, -n))
+
+
+def _column(block, j):
+    """Column ``j`` of a ``[C, H]`` block as ``[C, 1]`` (a select and a
+    lane sum: exact, and no one-lane slice)."""
+    return jnp.sum(jnp.where(_iota(block.shape, 1) == j, block, 0.0),
+                   axis=1, keepdims=True)
+
+
+def _as_row(column):
+    """``[C, 1]`` -> ``[1, C]`` through the diagonal (exact)."""
+    C = column.shape[0]
+    return jnp.sum(jnp.where(_iota((C, C), 0) == _iota((C, C), 1),
+                             column, 0.0), axis=0, keepdims=True)
+
+
+def _gdn_chunk(dot, q, k, v, gcol, grow, beta, st):
+    """What both scalar-decay kernels compute of one chunk of one head
+    (f32 operands; ``gcol [C, 1]`` and ``grow [1, C]`` the chunk's
+    cumulative log-decay, ``beta [C, 1]``, ``st [V, K]`` the transposed
+    state that enters; ``dot`` the matmul, :func:`_gdot`; a generator:
+    :func:`_side_by_side`)."""
+    C = q.shape[0]
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    D = jnp.exp(jnp.where(row >= col, gcol - grow, _NEG))
+    qk = dot(jnp.concatenate([k, q], axis=0), k, _NT)     # [2C, C]
+    yield
+    A = jnp.where(row > col, qk[:C] * D, 0.0)
+    B = qk[C:] * D
+    T = yield from _solve(beta * A, dot)
+    last = jnp.sum(jnp.where(_iota((C, 1), 0) == C - 1, gcol, 0.0),
+                   axis=0, keepdims=True)                # [1, 1]
+    gam, to_end = jnp.exp(gcol), jnp.exp(last - gcol)    # [C, 1]
+    kbar, qbar, ktil = k * gam, q * gam, k * to_end
+    R = v - dot(kbar, st, _NT)           # [C, V]
+    yield
+    U = dot(T, beta * R)
+    yield
+    return dict(D=D, A=A, B=B, T=T, last=last, gam=gam, to_end=to_end,
+                kbar=kbar, qbar=qbar, ktil=ktil, R=R, U=U)
+
+
+def _gdn_head(j: int, heads: int, kd: int, vd: int, q_ref, k_ref, v_ref,
+              gc_ref, gr_ref, beta_ref):
+    """Head ``j`` of a step's ``heads``: its lanes and its operands in
+    f32 (:func:`_head`), the decay's column and row."""
+    keys, values = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
+    mine = pl.program_id(1) * heads + j
+    return keys, values, (
+        _f32(q_ref[0, :, keys]), _f32(k_ref[0, :, keys]),
+        _f32(v_ref[0, :, values]), _column(gc_ref[0, 0], mine),
+        gr_ref[0, j, 0], _column(beta_ref[0, 0], mine))
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, o_ref,
+                    *rest, heads: int, save_states: bool, exact: bool):
+    """One (batch, group of ``heads`` heads, chunk), as
+    :func:`_kda_fwd_kernel`."""
+    state = rest[-1]                                     # [G, V, K]
+    dot = functools.partial(_gdot, exact)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[...] = jnp.zeros_like(state)
+
+    _, vd, kd = state.shape
+
+    def head(j):
+        st = state[j]
+        if save_states:
+            rest[0][0, j, 0] = st
+        _, values, ins = _gdn_head(j, heads, kd, vd, q_ref, k_ref, v_ref,
+                                   gc_ref, gr_ref, beta_ref)
+        c = yield from _gdn_chunk(dot, *ins, st)
+        o = dot(c["qbar"], st, _NT) + dot(c["B"], c["U"])
+        o_ref[0, :, values] = o.astype(o_ref.dtype)
+        yield
+        state[j] = st * jnp.exp(c["last"]) + dot(c["U"].T, c["ktil"])
+
+    _side_by_side(head(j) for j in range(heads))
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, st_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                    dstate, *, heads: int, exact: bool):
+    """One (batch, group of ``heads`` heads, chunk), chunks last to
+    first, as :func:`_kda_bwd_kernel`; ``dg_ref`` takes the cotangent of
+    the CUMULATIVE log-decay, a row a head."""
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    _, vd, kd = dstate.shape
+    C = q_ref.shape[1]
+    row, col = _iota((C, C), 0), _iota((C, C), 1)
+    dot = functools.partial(_gdot, exact)
+
+    def head(j):
+        keys, values, ins = _gdn_head(j, heads, kd, vd, q_ref, k_ref, v_ref,
+                                      gc_ref, gr_ref, beta_ref)
+        q, k, _, _, _, beta = ins
+        st, dst, do = st_ref[0, j, 0], dstate[j], _f32(do_ref[0, :, values])
+        c = yield from _gdn_chunk(dot, *ins, st)
+        U, R, T, D = c["U"], c["R"], c["T"], c["D"]
+        dU = dot(c["B"].T, do) + dot(
+            c["ktil"], dst, _NT)                    # [C, V]
+        dB = jnp.where(row >= col, dot(do, U, _NT), 0.0)
+        dqbar, dktil = dot(do, st), dot(U, dst)    # [C, K]
+        yield
+        dRb = dot(T.T, dU)
+        yield
+        dL = jnp.where(row > col, -dot(dRb, U, _NT), 0.0)
+        yield
+        dbeta = (jnp.sum(dL * c["A"], axis=1, keepdims=True)
+                 + jnp.sum(dRb * R, axis=1, keepdims=True))  # [C, 1]
+        dR = beta * dRb
+        dkbar = -dot(dR, st)
+        decay = jnp.exp(c["last"])                           # [1, 1]
+        dstate[j] = (dst * decay + dot(do.T, c["qbar"])
+                     - dot(dR.T, c["kbar"]))
+        yield
+        dA = beta * dL
+        dkk, dqk = dA * D, dB * D
+        dq = dot(dqk, k) + dqbar * c["gam"]
+        dk = (dot(dkk + dkk.T, k) + dot(dqk.T, q) + dkbar * c["gam"]
+              + dktil * c["to_end"])
+        dq_ref[0, :, keys] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, keys] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, values] = dR.astype(dv_ref.dtype)
+        yield
+        pairs = dA * c["A"] + dB * c["B"]
+        moved = dktil * c["ktil"]
+        mine = jnp.sum(dqbar * c["qbar"] + dkbar * c["kbar"] - moved,
+                       axis=1, keepdims=True)                # [C, 1]
+        # what the chunk's total decay carries, on its last position
+        carried = (jnp.sum(jnp.sum(moved, axis=1, keepdims=True),
+                           axis=0, keepdims=True)
+                   + decay * jnp.sum(jnp.sum(st * dst, axis=1, keepdims=True),
+                                     axis=0, keepdims=True))  # [1, 1]
+        dG = jnp.sum(pairs.T - pairs + jnp.where(row == col, mine, 0.0),
+                     axis=0, keepdims=True)                  # [1, C]
+        dg_ref[0, j, 0] = dG + jnp.where(
+            _iota((1, C), 1) == C - 1, carried, 0.0)
+        dbeta_ref[0, j, 0] = _as_row(dbeta)
+
+    _side_by_side(head(j) for j in range(heads))
+
+
+def _gdn_layouts(q, k, v, g, beta, chunk: int):
+    """The scalar-decay kernels' operands from the public ones, padded
+    to whole chunks (``g = 0, β = 0``): ``q, k [B, S, H·K]``, ``v [B, S,
+    H·V]``, the chunk's cumulative log-decay as columns ``[B, S/C, C,
+    H]`` and as rows ``[B, H, S/C, 1, C]``, ``β [B, S/C, C, H]``."""
+    b, s, h, _ = q.shape
+    sp = _up(s, chunk)
+
+    def wide(z):
+        return jnp.pad(z, ((0, 0), (0, sp - s), (0, 0), (0, 0))).reshape(
+            b, sp, -1)
+
+    g, beta = (jnp.pad(z, ((0, 0), (0, sp - s), (0, 0))).reshape(
+        b, sp // chunk, chunk, h) for z in (g, beta))
+    G = jnp.cumsum(g, axis=2)
+    return (wide(q), wide(k), wide(v), G,
+            G.transpose(0, 3, 1, 2)[:, :, :, None, :], beta)
+
+
+def _gdn_specs(chunk: int, heads: int, h: int, kd: int, vd: int, at):
+    """Block specs of the six operands both scalar-decay kernels read
+    (:func:`_specs`)."""
+    def wide(width):
+        return pl.BlockSpec((1, chunk, heads * width),
+                            lambda b, h, c: (b, at(c), h))
+
+    column = pl.BlockSpec((1, 1, chunk, h), lambda b, h, c: (b, at(c), 0, 0))
+    rows = pl.BlockSpec((1, heads, 1, 1, chunk),
+                        lambda b, h, c: (b, h, at(c), 0, 0))
+    return [wide(kd), wide(kd), wide(vd), column, rows, column]
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _gdn_forward(q, k, v, g, beta, chunk: int, interpret: bool,
+                 save_states: bool):
+    b, s, h, kd = q.shape
+    vd = v.shape[3]
+    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret)
+    ops = _gdn_layouts(q, k, v, g, beta, chunk)
+    sp = ops[0].shape[1]
+    nc = sp // chunk
+    out_shape = [jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype)]
+    out_specs = [pl.BlockSpec((1, chunk, heads * vd),
+                              lambda b, h, c: (b, c, h))]
+    if save_states:
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, vd, kd),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, heads, 1, vd, kd),
+                                      lambda b, h, c: (b, h, c, 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, heads=heads,
+                          save_states=save_states, exact=interpret),
+        grid=(b, pl.cdiv(h, heads), nc),
+        in_specs=_gdn_specs(chunk, heads, h, kd, vd, lambda c: c),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), jnp.float32)],
+        interpret=interpret, name="gdn_fwd", compiler_params=_PARAMS,
+    )(*ops)
+    o = out[0].reshape(b, sp, h, vd)[:, :s]
+    return o, (out[1] if save_states else None)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _gdn_backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
+    b, s, h, kd = q.shape
+    vd = v.shape[3]
+    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret)
+    ops = _gdn_layouts(q, k, v, g, beta, chunk)
+    sp = ops[0].shape[1]
+    nc = sp // chunk
+    do = jnp.pad(do, ((0, 0), (0, sp - s), (0, 0), (0, 0))).reshape(
+        b, sp, h * vd)
+
+    def at(c):
+        return nc - 1 - c
+
+    specs = _gdn_specs(chunk, heads, h, kd, vd, at)
+    keys, _, values, _, rows, _ = specs
+    dq, dk, dv, dG, dbeta = pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, heads=heads, exact=interpret),
+        grid=(b, pl.cdiv(h, heads), nc),
+        in_specs=specs + [
+            pl.BlockSpec((1, heads, 1, vd, kd),
+                         lambda b, h, c: (b, h, at(c), 0, 0)),
+            values,
+        ],
+        out_specs=[keys, keys, values, rows, rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, sp, h * kd), q.dtype),
+            jax.ShapeDtypeStruct((b, sp, h * kd), k.dtype),
+            jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype),
+            jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), jnp.float32)],
+        interpret=interpret, name="gdn_bwd", compiler_params=_PARAMS,
+    )(*ops, states, do)
+    # dg: the sum of dG from a position to its chunk's end
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dG, axis=-1), axis=-1), axis=-1)
+    dg, dbeta = (z.reshape(b, h, sp).transpose(0, 2, 1)[:, :s]
+                 for z in (dg, dbeta))
+
+    def narrow(z, like):
+        return z.reshape(b, sp, h, -1)[:, :s].astype(like.dtype)
+
+    return (narrow(dq, q), narrow(dk, k), narrow(dv, v),
+            dg.astype(g.dtype), dbeta.astype(beta.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gdn(q, k, v, g, beta, chunk, interpret):
+    return _gdn_forward(q, k, v, g, beta, chunk, interpret, False)[0]
+
+
+def _gdn_fwd(q, k, v, g, beta, chunk, interpret):
+    o, states = _gdn_forward(q, k, v, g, beta, chunk, interpret, True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _gdn_bwd(chunk, interpret, residuals, do):
+    return _gdn_backward(*residuals, do, chunk, interpret)
+
+
+_gdn.defvjp(_gdn_fwd, _gdn_bwd)
+
+
+def gdn_scan(q, k, v, g, beta):
+    """The gated delta rule with ONE decay a head a position:
+
+        S_t = exp(g_t) (I − β_t k_t k_tᵀ) S_{t-1} + β_t k_t v_tᵀ,
+        o_t = S_tᵀ q_t,       S_0 = 0.
+
+    ``q, k [B, S, H, K]`` (the caller's normalisation and scale already
+    in them), ``v [B, S, H, V]`` — ``K`` and ``V`` equal or not; on the
+    TPU widths of which some group of heads fills whole lane tiles
+    (:func:`_gdn_heads_a_step`), others are refused —, ``g [B, S, H]``
+    (log-decays, <= 0, f32), ``beta [B, S, H]`` (f32; the rule contracts
+    for ``β`` in (0, 2), the op bounds nothing) -> ``o [B, S, H, V]`` in
+    ``v``'s dtype, differentiable in all five. It is :func:`kda_scan`
+    fed ``g`` broadcast over the key channels (``tests/test_kda.py``),
+    in kernels of its own (``gdn_fwd``, ``gdn_bwd``). The chunk is
+    chosen from the sequence length; a sequence that is no multiple is
+    padded at its end."""
+    if (q.ndim != 4 or k.shape != q.shape or v.ndim != 4
+            or v.shape[:3] != q.shape[:3] or g.shape != q.shape[:3]
+            or beta.shape != q.shape[:3]):
+        raise ValueError(
+            f"gdn_scan: q{tuple(q.shape)} k{tuple(k.shape)} "
+            f"v{tuple(v.shape)} g{tuple(g.shape)} beta{tuple(beta.shape)} "
+            "do not fit")
+    return _gdn(q, k, v, _f32(g), _f32(beta), _choose_chunk(q.shape[1]),
+                _interpret())

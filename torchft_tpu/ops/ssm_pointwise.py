@@ -78,11 +78,13 @@ in f32.
 
 ``kda_ogate(o, gate [B, S, H·D], scale [D], eps, gated)``: with
 ``gated`` the caller's ``(o, scale, gate, eps) -> y`` of one head — jnp
-code on ``[rows, D]`` f32 blocks and the weight as ``[1, D]``, there
-``RMSNorm(o) ⊙ scale ⊙ σ(gate)``: THE NORM FIRST and a sigmoid after it,
-where ``gated_norm`` gates first, with silu, which is why the two do not
-share a body — ``y = gated(o_h, scale, gate_h, eps)`` per head, in
-``o``'s dtype. Backward (``kda_ogate_bwd``): ``jax.vjp(gated, ·)`` on
+code on ``[rows, D]`` f32 blocks and the weight as ``[1, D]``: the
+head's width is the weight's and the gate's activation is the caller's
+(Kimi Linear: ``RMSNorm(o) ⊙ scale ⊙ σ(gate)`` at 128; Olmo Hybrid:
+``RMSNorm(o) ⊙ scale ⊙ silu(gate)`` at 192, two heads to three lane
+tiles) — THE NORM FIRST and the gate after it, where ``gated_norm``
+gates first, which is why the two do not share a body — ``y =
+gated(o_h, scale, gate_h, eps)`` per head, in ``o``'s dtype. Backward (``kda_ogate_bwd``): ``jax.vjp(gated, ·)`` on
 the blocks it loaded, the weight broadcast to a row a position so that
 its cotangent comes a row a position too — for the norm-then-gate, with
 ``r = rsqrt(mean_D(o²) + eps)``, ``n = o·r``, ``s = σ(gate)`` and ``dn =
@@ -138,15 +140,20 @@ Blocks are chosen from the shape (:func:`_lane_block`,
 one. A sequence that is no multiple of the block ends in a partial
 block whose rows past the end are masked in the backward kernels (what
 they would add to the sums is garbage) and thrown away by the forward.
-On the TPU a channel count, a norm group or a head that is no multiple
-of 128 lanes is refused with a message; off the TPU the same kernels run in
-Pallas's interpreter at any width (the CPU tests), chosen from the
-backend alone.
+On the TPU a channel count, a norm group or ``kda_qkg``'s head that is
+no multiple of 128 lanes is refused with a message; ``kda_ogate`` takes
+a head of any width whose channel blocks can be whole heads AND whole
+lane tiles (``H·D`` a multiple of ``lcm(D, 128)``: 192 in twos; a head
+inside such a block is a lane slice that starts between tiles, which
+Mosaic shifts) and refuses the rest with a message. Off the TPU the same
+kernels run in Pallas's interpreter at any width (the CPU tests), chosen
+from the backend alone.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -409,15 +416,23 @@ def _conv_bwd_kernel(*refs, chunk: int, seq_len: int, halo: bool,
         work()
 
 
+def _whole_tiles(whole: int) -> int:
+    """The fewest lanes that are whole runs of ``whole`` channels AND
+    whole 128-lane tiles: ``whole`` itself where it is a multiple of
+    128, 384 (two heads, three tiles) for a head of 192."""
+    return math.lcm(whole, _LANES)
+
+
 def _lane_block(width: int, whole: int = _LANES) -> int:
-    """Lanes a channel block: the widest multiple of ``whole`` (itself a
-    multiple of 128) up to ``_LANE_BLOCK`` that divides ``width``, at
-    least one ``whole``; the whole of a width that is no multiple of
-    ``whole`` (off the TPU only)."""
-    if width % whole or whole % _LANES:
+    """Lanes a channel block: the widest multiple of ``whole`` channels
+    and of 128 lanes (:func:`_whole_tiles`) up to ``_LANE_BLOCK`` that
+    divides ``width``, at least one such unit; the whole of a width that
+    is no multiple of it (off the TPU only)."""
+    unit = _whole_tiles(whole)
+    if width % unit:
         return width
-    return max([whole] + [bc for bc in range(whole, _LANE_BLOCK + 1, whole)
-                          if width % bc == 0])
+    return max([unit] + [bc for bc in range(unit, _LANE_BLOCK + 1, unit)
+                         if width % bc == 0])
 
 
 def _row_block(seq_len: int, lanes: int) -> int:
@@ -1103,13 +1118,15 @@ _ogate.defvjp(_ogate_fwd_rule, _ogate_bwd_rule)
 
 
 def kda_ogate(o, gate, scale, eps: float, gated):
-    """What stands between the delta-rule mixer's scan and its output
+    """What stands between a delta-rule mixer's scan and its output
     projection (the module's docstring): ``o, gate [B, S, H·D]``, ``scale
-    [D]`` (the one weight every head shares) and ``gated`` the caller's
-    ``(o, scale, gate, eps) -> y`` of a head (jnp code over the last
-    axis of ``[rows, D]`` f32 blocks, ``scale`` as ``[1, D]``) -> ``y
-    [B, S, H·D]`` in ``o``'s dtype, differentiable in ``o``, ``gate``
-    and ``scale``."""
+    [D]`` (the one weight every head shares: ITS width is a head's) and
+    ``gated`` the caller's ``(o, scale, gate, eps) -> y`` of a head (jnp
+    code over the last axis of ``[rows, D]`` f32 blocks, ``scale`` as
+    ``[1, D]``: the norm and the gate's activation are the caller's) ->
+    ``y [B, S, H·D]`` in ``o``'s dtype, differentiable in ``o``,
+    ``gate`` and ``scale``. On the TPU ``H·D`` must be whole blocks of
+    ``lcm(D, 128)`` lanes."""
     width, head = o.shape[-1], scale.shape[-1]
     if (o.ndim != 3 or o.shape != gate.shape or scale.ndim != 1
             or width % head):
@@ -1117,7 +1134,11 @@ def kda_ogate(o, gate, scale, eps: float, gated):
             f"kda_ogate: o{tuple(o.shape)} gate{tuple(gate.shape)} "
             f"scale{tuple(scale.shape)} do not fit")
     interpret = _interpret()
-    _refuse_lanes("kda_ogate: a head's", head, interpret)
+    if not interpret and width % _whole_tiles(head):
+        raise ValueError(
+            f"kda_ogate: {width} channels in heads of {head} are no whole "
+            f"blocks of {_whole_tiles(head)} lanes (whole heads and whole "
+            f"{_LANES}-lane tiles)")
     bc = _lane_block(width, head)
     return _ogate(o, gate, scale, head, float(eps), gated,
                   (_row_block(o.shape[1], bc), bc), interpret)
