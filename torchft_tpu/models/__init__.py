@@ -9,10 +9,14 @@ own, each with ``<Family>Config``, ``init_params``, ``forward_hidden``,
 ``phi4flash`` (a stack that is not a chain: two layers hand their scan
 output and their keys and values on to later layers), ``smallthinker``
 (attention windowed or full and rotated or not by two lists of the
-config, a router that reads the stream before attention, ReGLU experts)
-and ``olmo_hybrid`` (a dense stack of scalar-gated delta-rule mixers 3 : 1
+config, a router that reads the stream before attention, ReGLU experts),
+``olmo_hybrid`` (a dense stack of scalar-gated delta-rule mixers 3 : 1
 with unrotated full attention, the OLMo family's norms on each sublayer's
-output); what more than one of them computes is in ``common``."""
+output) and ``laguna`` (attention whose query head count, mask and rotation
+follow the kind of layer, a YaRN rotation over half a head beside a plain
+one, a gate a head, a dense first layer and then sigmoid-routed experts
+beside a shared one); what more than one of them computes is in
+``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

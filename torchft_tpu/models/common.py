@@ -1,13 +1,16 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
 ``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``,
-``smallthinker``), the token table's lookup (``olmoe`` and the five
-below), the repeat of grouped key/value heads (what the attention of
+``smallthinker``, ``laguna``), the token table's lookup (``olmoe`` and
+the six below), the rotary embedding over the whole head or its first
+lanes at given frequencies (``llama._rope``, so ``olmoe``, ``lfm2``,
+``smallthinker`` and ``olmo_hybrid``; ``laguna``'s two rotations), the
+repeat of grouped key/value heads (what the attention of
 ``ops/`` does by index since PR 55: the tests' and the references'
 yardstick), the SwiGLU MLP and its dense sublayer (``joyai``,
-``lfm2``, ``kimi_linear``), and what the five models that hold ONE CHIP'S
-SHARE of an expert-parallel layer (``joyai``, ``nemotron_h``, ``lfm2``,
-``kimi_linear``, ``smallthinker``) have in common: the router's
+``lfm2``, ``kimi_linear``, ``laguna``), and what the six models that hold
+ONE CHIP'S SHARE of an expert-parallel layer (``joyai``, ``nemotron_h``,
+``lfm2``, ``kimi_linear``, ``smallthinker``, ``laguna``) have in common: the router's
 balance bias — its key in the parameter tree, the predicate
 ``optim.with_balance_bias`` partitions the leaves by, and the way a
 step's loads reach that rule in the gradient tree at the bias's place —
@@ -30,7 +33,8 @@ import jax.numpy as jnp
 
 from torchft_tpu.ops import moe
 
-__all__ = ["rms_norm", "embed", "repeat_kv", "swiglu", "dense_sublayer",
+__all__ = ["rms_norm", "embed", "rotary", "repeat_kv", "swiglu",
+           "dense_sublayer",
            "BALANCE_BIAS", "is_balance_bias", "loads_as_gradient",
            "routed_sublayer", "routing_record", "share_loss_terms"]
 
@@ -46,6 +50,33 @@ def rms_norm(x, scale, eps: float):
 @jax.named_scope("embed")
 def embed(cfg, params: Dict, tokens):
     return params["wte"]["embedding"].astype(cfg.dtype)[tokens]
+
+
+def rotary(x, freqs, factor: float = 1.0):
+    """Rotary embedding of ``x [B, S, H, D]`` in the ``rotate_half`` form
+    over the head's FIRST ``2·len(freqs)`` lanes: with ``r`` =
+    ``len(freqs)``, position ``t`` turns lanes ``i`` and ``i + r`` by
+    ``t · freqs[i]``; the lanes beyond ``2r`` pass as they are (a partial
+    rotation). ``cos`` and ``sin`` are both multiplied by ``factor``
+    (YaRN's ``attention_factor``; 1.0 leaves the table alone). The table
+    and the arithmetic are float32. ``models/llama.py::_rope`` is this
+    over the whole head at ``theta^(-i / r)``, and traces to the program
+    it always traced to (``tests/test_laguna.py`` pins it)."""
+    s, d = x.shape[1], x.shape[-1]
+    half = freqs.shape[0]
+    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]   # [1, S, 1, r]
+    sin = jnp.sin(angles)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
+    turned = jnp.concatenate(
+        [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1
+    ).astype(x.dtype)
+    if 2 * half == d:
+        return turned
+    return jnp.concatenate([turned, x[..., 2 * half:]], axis=-1)
 
 
 def repeat_kv(kv, n_heads: int):

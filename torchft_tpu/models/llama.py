@@ -24,7 +24,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.common import rms_norm
+from torchft_tpu.models.common import rms_norm, rotary
 
 __all__ = [
     "LlamaConfig",
@@ -125,20 +125,13 @@ def llama_init_params(cfg: LlamaConfig, key) -> Dict:
 
 
 def _rope(x, theta: float):
-    """Rotary embedding over [B, S, H, D] (D even)."""
-    b, s, h, d = x.shape
-    half = d // 2
+    """Rotary embedding over [B, S, H, D] (D even): ``common.rotary`` over
+    the whole head at the frequencies ``theta^(-i / (D/2))``."""
+    half = x.shape[-1] // 2
     freqs = theta ** (
         -jnp.arange(0, half, dtype=jnp.float32) / half
     )
-    angles = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]   # [1, S, 1, D/2]
-    sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1
-    ).astype(x.dtype)
+    return rotary(x, freqs)
 
 
 def _default_attention(q, k, v):
