@@ -5,6 +5,7 @@ head the call takes and the rectangle of blocks would (``_grid_steps``).
 
     python scripts/flash_micro.py
     python scripts/flash_micro.py --parent _scratch/parent/torchft_tpu/ops/flash.py
+    python scripts/flash_micro.py --parent ... --cells streamed --chunks 1 2 4 8
 
 ``--parent`` names a second ``ops/flash.py`` that is read in the same
 process: its kernels run on the same operands, turn about with this tree's,
@@ -12,7 +13,14 @@ and ``out``, ``lse``, ``dq``, ``dk`` and ``dv`` are compared bit for bit. A
 timed call is the jitted wrapper: the kernel and whatever XLA lays out
 around it. ``--cells`` picks the shapes (``_CELLS``: a cell's call as ``[BH,
 S, Dqk / Dv]``, bf16, the blocks the kernels choose from the shape; a name
-ending in ``-swa`` is under a window, in ``-unmasked`` without the mask).
+ending in ``-swa`` is under a window, in ``-unmasked`` without the mask;
+``streamed`` stands for the nine calls of the seven cells whose K and V
+stream, ``_STREAMED``). ``chunk`` is the k tiles a grid step of the streamed
+forward sweeps (``_choose_chunk``) and ``grid_steps_a_head.forward`` what its
+grid takes; ``--chunks`` times the forward again at each given chunk and
+holds its ``out`` and ``lse`` to the other side's bit for bit
+(``flash_fwd_by_chunk``: the readings beside ``flash._CHUNK_LADDER``, PR 60;
+a chunk Mosaic's VMEM does not hold is ``refused``).
 A cell of ``_GROUPED`` is another cell's call with the key/value heads the
 model has (``name: (cell, query heads a key/value head, sequences a
 step)``): there the two sides are THIS file's kernels on k and v as they
@@ -31,6 +39,7 @@ only.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -73,7 +82,18 @@ _CELLS = {
                   (2, 512, 16, 32, True, None, 0)),
     "lfm2": ((128, 8192, 64, 64, True, None),
              (2, 512, 16, 16, True, None, None)),
+    # the other streamed cells' calls (PR 60; kimi's is joyai's)
+    "olmohybrid": ((30, 8192, 128, 128, True, None),
+                   (2, 512, 32, 32, True, None, 0)),
+    "laguna": ((192, 8192, 128, 128, True, None),
+               (2, 512, 32, 32, True, None, 0)),
+    "laguna-swa": ((256, 8192, 128, 128, True, 512),
+                   (2, 512, 32, 32, True, 128, 0)),
 }
+
+# the calls of the seven cells whose K and V stream (PR 60)
+_STREAMED = ("joyai", "nemo3", "olmohybrid", "phi4flash", "phi4flash-swa",
+             "smallthinker", "smallthinker-swa", "laguna", "laguna-swa")
 
 # the four cells whose key/value heads serve several query heads (PR 55)
 _GROUPED = {
@@ -90,7 +110,12 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--parent", default=None,
                     help="a second ops/flash.py, compared in this process")
-    ap.add_argument("--cells", nargs="*", default=["joyai", "nemo3"])
+    ap.add_argument("--cells", nargs="*", default=["joyai", "nemo3"],
+                    help="names of _CELLS / _GROUPED; 'streamed' is the "
+                    "seven streamed cells' calls")
+    ap.add_argument("--chunks", nargs="*", type=int, default=[],
+                    help="time the forward again at these k tiles a grid "
+                    "step (the rule's is what every other figure is at)")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args()
@@ -124,7 +149,9 @@ def main() -> int:
             seen.append((time.perf_counter() - t) / args.calls)
         return 1e3 * sorted(seen)[1]
 
-    for cell in args.cells:
+    cells = [c for name in args.cells
+             for c in (_STREAMED if name == "streamed" else (name,))]
+    for cell in cells:
         base, group, batch = _GROUPED.get(cell, (cell, 1, 1))
         chip, cpu = _CELLS[base]
         bh, seq, dqk, dv, causal, window, threshold = (
@@ -149,12 +176,19 @@ def main() -> int:
         scale = 1.0 / dqk ** 0.5
         blocks = (flash._choose_blocks(seq, dqk, 2, v_dim=dv, window=window)
                   if on_chip else (64, 128))
-        live, rectangular = flash._grid_steps(seq, *blocks, window)
+        chunk = flash._choose_chunk(seq, dqk, 2, *blocks, dv, window, causal,
+                                    threshold)
+
+        def steps_a_head(chunk):
+            live, rectangular, chunked = flash._grid_steps(
+                seq, *blocks, window, chunk)
+            return {"live": live if causal else rectangular,
+                    "rectangular": rectangular,
+                    "forward": chunked if causal else rectangular // chunk}
+
         entry = {"q": [bh, seq, dqk], "v": [bh // group, seq, dv],
                  "blocks": blocks, "causal": causal, "window": window,
-                 "grid_steps_a_head": {
-                     "live": live if causal else rectangular,
-                     "rectangular": rectangular}}
+                 "chunk": chunk, "grid_steps_a_head": steps_a_head(chunk)}
 
         # the operands are arguments (a closed-over array is a constant of
         # the program); dq and dkv are two results of one builder, and the
@@ -227,6 +261,34 @@ def main() -> int:
             entry["bit_for_bit"] = {
                 name: bool(jnp.array_equal(a, results["parent"][name]))
                 for name, a in results["this"].items()}
+        # the forward again at other chunks: out and lse against the last
+        # side's (the parent's, where one is given), then ms a call
+        by_chunk = {}
+        for n in args.chunks:
+            if (seq // blocks[1]) % n:
+                continue
+            fn = jax.jit(functools.partial(
+                flash._flash_forward, causal=causal, scale=scale,
+                block_q=blocks[0], block_k=blocks[1], interpret=not on_chip,
+                resident_kv_bytes=threshold, window=window, chunk=n))
+            _, k_, v_ = sides[other]
+            try:
+                o, lse = fn(q, k_, v_)
+            except jax.errors.JaxRuntimeError as e:    # Mosaic's VMEM limit
+                text = str(e)
+                by_chunk[n] = {"refused": text[max(text.find(
+                    "Scoped allocation"), 0):][:160]}
+                continue
+            by_chunk[n] = {
+                "grid_steps_a_head": steps_a_head(n)["forward"],
+                "bit_for_bit": bool(
+                    jnp.array_equal(o, results[other]["out"])
+                    and jnp.array_equal(lse, results[other]["lse"]))}
+            del o, lse
+            if on_chip:
+                by_chunk[n]["ms_a_call"] = time_ms(fn, q, k_, v_)
+        if by_chunk:
+            entry["flash_fwd_by_chunk"] = by_chunk
         if on_chip:
             # both sides' backward kernels on ONE side's statistics: the
             # times do not depend on them, and two sets of results do not

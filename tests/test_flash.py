@@ -450,14 +450,19 @@ def test_tile_rule_takes_both_widths() -> None:
 # PR 51: lse leaves the forward, and lse and delta reach dq, as [BH, 1, S]
 # rows, and the kernels turn them to and from the tile's [BQ, 1] columns
 # in VMEM (f8b02ef3..., 0af33485... and 049a6f74... before; every output
-# stayed bit for bit: scripts/flash_micro.py --parent on the chip).
+# stayed bit for bit: scripts/flash_micro.py --parent on the chip); the two
+# streamed calls regenerated in PR 60 (7fb6fbf7... and 373a1e81... before):
+# their three builders stand under a jit of their own (a row of this
+# call's tiles is one tile long, so the forward's body is the one-tile
+# body; test_the_chunked_body_is_one_tile_long holds the chunked one). The
+# resident call did not move.
 _EQUAL_WIDTH_JAXPR = {
     ("resident", True):
         "4f629dc8e60f4c0d602d36f6df83c86843238c17e5c908829e96ccf433fd2874",
     ("streamed", True):
-        "7fb6fbf792643a53493b633772df327f2ced650b91e9990a5c9d938da7865c05",
+        "648e7a25fc67359fa19cdc9616554bb5dff4e9707c103665457f3ade36a822c1",
     ("streamed", False):
-        "373a1e8194f3af15891f78a9d74deaa154e538f28f422136f052048570f90078",
+        "1c16c841e7ba411fde1793b5cb3f2604e4348137bea450f0b2a4f355a042473b",
 }
 
 
@@ -568,52 +573,102 @@ def _dkv_in_table_order(q, k, v, do, lse, delta, scale, block_q, block_k):
     return jnp.asarray(dk).astype(k.dtype), jnp.asarray(dv).astype(v.dtype)
 
 
-@pytest.mark.parametrize("regime", sorted(_REGIMES))
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
-                         ids=["bf16", "f32"])
+# every (seq_len, block_q, block_k, (Dqk, Dv)) the three kernels are held
+# to across the regimes, at the rule's chunk
+_REGIME_SHAPES = [
+    (1024, 128, 256, (32, 32)), (1024, 256, 256, (32, 32)),
+    (1024, 256, 128, (32, 32)),
+    # latent attention's 192 / 128 a quarter the size, at the cell's ratio
+    (1024, 128, 256, (48, 32)), (768, 128, 128, (48, 32)),
+    # neither edge divides the other
+    (768, 384, 256, (32, 32)), (768, 256, 384, (32, 32)),
+    # shorter than a lane tile (PR 51): the statistics' [1, BQ] rows are
+    # then blocks as long as the whole dimension, one tile and several
+    (64, 64, 64, (32, 32)), (96, 32, 32, (48, 32)),
+]
+_DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32}
+# PR 60: the forward at an EXPLICIT chunk of k tiles a grid step — 1, 2 and
+# the whole row of tiles — under the causal mask, under a window shorter
+# than a tile, as long as a chunk of two tiles and no multiple of one, and
+# without the mask; latent attention's widths on heads of their own, equal
+# widths in groups of four
+_REGIME_CASES = [
+    pytest.param(*shape, _DTYPES[dtype], regime, "causal", 1, None,
+                 id="-".join(map(str, (*shape[:3], *shape[3], dtype, regime))))
+    for regime, dtype, shape in itertools.product(
+        sorted(_REGIMES), _DTYPES, _REGIME_SHAPES)
+] + [
+    pytest.param(1024, block_q, block_k, widths, jnp.bfloat16, "streamed",
+                 mask, group, chunk,
+                 id=f"{block_q}-{block_k}-{mask}-g{group}-chunk-{chunk}")
+    for (block_q, block_k, widths, group), mask, chunk in itertools.product(
+        [(128, 256, (48, 32), 1), (256, 128, (32, 32), 4)],
+        ["causal", 96, 512, 450, "unmasked"], [1, 2, "row"])
+]
+
+
 @pytest.mark.parametrize(
-    "seq_len,block_q,block_k,widths",
-    [(1024, 128, 256, (32, 32)), (1024, 256, 256, (32, 32)),
-     (1024, 256, 128, (32, 32)),
-     # latent attention's 192 / 128 a quarter the size, at the cell's ratio
-     (1024, 128, 256, (48, 32)), (768, 128, 128, (48, 32)),
-     # neither edge divides the other
-     (768, 384, 256, (32, 32)), (768, 256, 384, (32, 32)),
-     # shorter than a lane tile (PR 51): the statistics' [1, BQ] rows are
-     # then blocks as long as the whole dimension, one tile and several
-     (64, 64, 64, (32, 32)), (96, 32, 32, (48, 32))],
-)
+    "seq_len,block_q,block_k,widths,dtype,regime,mask,group,chunk",
+    _REGIME_CASES)
 def test_causal_kernels_agree_across_regimes_bit_for_bit(
-        seq_len, block_q, block_k, widths, dtype, regime) -> None:
+        seq_len, block_q, block_k, widths, dtype, regime, mask, group,
+        chunk) -> None:
     # A streamed causal grid is a table of live tiles, and an accumulator
     # meets them in the order the resident kernels' loops do, so out, lse
     # and dq are the RESIDENT kernels' bit for bit. dk and dv are not: the
     # resident column sweep adds its full tiles before its diagonal ones
     # and the streamed one runs a column top to bottom. Both regimes' dk
     # and dv are therefore held, bit for bit where the order is theirs, to
-    # the kernel's own tile body summed in the table's order.
-    from torchft_tpu.ops.flash import _flash_backward_core, _flash_forward
+    # the kernel's own tile body summed in the table's order. A grid step
+    # of the streamed forward sweeps a chunk of k tiles (PR 60), the live
+    # ones in ascending k through the tile body they always had: out and
+    # lse are the resident kernel's at EVERY chunk, mask and group (the
+    # backward kernels take no chunk: those cases end at the forward).
+    from torchft_tpu.ops.flash import (
+        _flash_backward_core, _flash_forward, _grid_steps,
+    )
 
     dqk, dv = widths
-    q, k = (_rand((2, seq_len, dqk), i + 50, dtype) for i in range(2))
-    v, do = (_rand((2, seq_len, dv), i + 52, dtype) for i in range(2))
+    heads = 2 * group
+    q = _rand((heads, seq_len, dqk), 50, dtype)
+    k = _rand((heads // group, seq_len, dqk), 51, dtype)
+    v = _rand((heads // group, seq_len, dv), 52, dtype)
+    do = _rand((heads, seq_len, dv), 53, dtype)
     scale = 1.0 / dqk ** 0.5
-    common = (True, scale, block_q, block_k, True)
+    causal = mask != "unmasked"
+    window = mask if isinstance(mask, int) else None
+    if chunk == "row":
+        chunk = seq_len // block_k
+    common = (causal, scale, block_q, block_k, True)
 
-    def kernels(threshold):
-        out, lse = _flash_forward(q, k, v, *common, threshold)
-        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)
-        return (out, lse, delta), _flash_backward_core(
+    def forward(threshold, chunk=None):
+        out, lse = _flash_forward(q, k, v, *common, threshold,
+                                  window=window, chunk=chunk)
+        return out, lse, jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+
+    r_out, r_lse, r_delta = forward(_REGIMES["resident"])
+    out, lse, delta = ((r_out, r_lse, r_delta) if regime == "resident"
+                       else forward(_REGIMES[regime], chunk))
+    assert out.dtype == r_out.dtype and jnp.array_equal(out, r_out)
+    assert jnp.array_equal(lse, r_lse)
+    if chunk is not None:
+        if causal:
+            # a chunk is a grid step where any of its tiles is live
+            live, _, chunked = _grid_steps(seq_len, block_q, block_k, window,
+                                           chunk)
+            assert -(-live // chunk) <= chunked <= live
+            assert (chunked < live) == (chunk > 1)
+        return
+
+    def backward(threshold, lse, delta):
+        return _flash_backward_core(
             q, k, v, do, lse, delta, *common, threshold)
 
-    resident = kernels(_REGIMES["resident"])
-    (out, lse, delta), (dq, dk, dv_) = (
-        resident if regime == "resident" else kernels(_REGIMES[regime]))
-    (r_out, r_lse, _), (r_dq, _, _) = resident
-    for name, a, b in (("out", out, r_out), ("lse", lse, r_lse),
-                       ("dq", dq, r_dq)):
-        assert a.dtype == b.dtype and jnp.array_equal(a, b), name
+    resident = backward(_REGIMES["resident"], r_lse, r_delta)
+    dq, dk, dv_ = (resident if regime == "resident"
+                   else backward(_REGIMES[regime], lse, delta))
+    assert dq.dtype == resident[0].dtype and jnp.array_equal(dq, resident[0])
     want_dk, want_dv = _dkv_in_table_order(
         q, k, v, do, lse, delta, scale, block_q, block_k)
     if regime == "streamed":
@@ -890,14 +945,13 @@ def _flash_calls(jaxpr):
     return found
 
 
-def _traced_flash_calls():
+def _traced_flash_calls(names=("flash_calls", "flash_calls_grouped")):
     """``(flash_calls, flash_calls_grouped)`` so far in this process
     (``utils/metrics.py::TRACED``): read it on both sides of a trace."""
     from torchft_tpu.utils.metrics import TRACED
 
     seen = TRACED.snapshot()
-    return np.array([seen.get("flash_calls", 0),
-                     seen.get("flash_calls_grouped", 0)], dtype=int)
+    return np.array([seen.get(name, 0) for name in names], dtype=int)
 
 
 _MASKS = {"causal": {}, "window": {"window": 200},
@@ -946,7 +1000,11 @@ def test_the_row_statistics_cross_hbm_lane_dense(regime, mask,
     (8, 2048, (64, 64), None),        # c111m's call: resident
     (4, 8192, (192, 128), None),      # joyai's: streamed, two widths
     (4, 16384, (128, 128), 4096),     # smallthinker's windowed call
-], ids=["c111m", "joyai", "smallthinker-swa"])
+    # PR 60: the forward's fullest chunk (eight tiles of K and V twice
+    # over, 8.4 MB of Mosaic's 16) and the band's shortest
+    (4, 8192, (128, 128), None),      # nemo3's, olmohybrid's, laguna's
+    (4, 8192, (128, 128), 512),       # laguna's band
+], ids=["c111m", "joyai", "smallthinker-swa", "nemo3", "laguna-swa"])
 def test_the_calls_compile_for_the_v5e_with_no_padded_statistic(
         one_chip, bh, seq_len, widths, window) -> None:
     """Mosaic takes the two turns of a statistic through the transpose
@@ -987,3 +1045,138 @@ def test_the_calls_compile_for_the_v5e_with_no_padded_statistic(
         assert f"{kernel}/pallas_call" in text, kernel
     assert f"f32[{bh},1,{seq_len}]" in text
     assert f"f32[{bh},{seq_len},1]" not in text
+
+
+# ------------------------------------------------------------ PR 60 contracts
+# A grid step of the streamed forward sweeps a chunk of k tiles: (a) the
+# chunk as a pure function of the shape, (b) the steps a head at the cells'
+# calls, (c) a body that does not grow with the chunk, (d) the counter. (The
+# kernels at an explicit chunk are cases of
+# test_causal_kernels_agree_across_regimes_bit_for_bit.)
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128),
+                                    (64, 128)],
+                         ids=["64", "128", "192-128", "64-128"])
+@pytest.mark.parametrize("window", [None, 512, 4096])
+@pytest.mark.parametrize("seq_len", [512, 2048, 4096, 8192, 8192 + 512,
+                                     16384, 32768])
+def test_chunk_rule_is_a_pure_function_of_the_shape(seq_len, window, widths,
+                                                    itemsize) -> None:
+    from torchft_tpu.ops.flash import (
+        _CHUNK_LADDER, _VMEM_BUDGET, _choose_blocks, _choose_chunk,
+        _forward_vmem_estimate, _grid_steps, _resident,
+    )
+
+    dqk, dv = widths
+    if window is not None and window >= seq_len:
+        window = None           # the wrapper's: the causal call itself
+    blocks = _choose_blocks(seq_len, dqk, itemsize, v_dim=dv, window=window)
+    shape = (seq_len, dqk, itemsize, *blocks, dv, window)
+    chunk = _choose_chunk(*shape)
+    assert chunk == _choose_chunk(*shape) and chunk in _CHUNK_LADDER
+    if _resident(seq_len, dqk + dv, itemsize):
+        assert chunk == 1
+        # and a call that streams by its own threshold has a chunk
+        assert _choose_chunk(*shape, True, 0) >= chunk
+        return
+    num_k = seq_len // blocks[1]
+    assert num_k % chunk == 0
+    assert chunk == 1 or _forward_vmem_estimate(
+        dqk, dv, itemsize, *blocks, chunk) <= _VMEM_BUDGET
+    # some row of tiles fills the chunk, and the steps only fall
+    live, rectangular, chunked = _grid_steps(seq_len, *blocks, window, chunk)
+    assert chunked <= live <= rectangular
+    rows = seq_len // blocks[0]
+    assert chunk <= num_k and chunked >= rows
+    # without the mask the row is the whole row of tiles
+    assert _choose_chunk(*shape[:6], None, False) >= chunk
+
+
+@pytest.mark.parametrize("seq_len,dqk,dv,window,blocks,chunk,steps", [
+    # the seven cells whose K and V stream: joyai's and kimi's latent
+    # attention, then nemo3's, olmohybrid's and laguna's full calls
+    (8192, 192, 128, None, (512, 1024), 4, (72, 128, 24)),
+    (8192, 128, 128, None, (512, 1024), 8, (72, 128, 16)),
+    # phi4flash's full call and its band, laguna's band
+    (8192, 64, 128, None, (512, 1024), 8, (72, 128, 16)),
+    (8192, 64, 128, 512, (512, 512), 2, (31, 256, 23)),
+    (8192, 128, 128, 512, (512, 512), 2, (31, 256, 23)),
+    # smallthinker's full call and its band of 4096 keys
+    (16384, 128, 128, None, (512, 1024), 8, (272, 512, 48)),
+    (16384, 128, 128, 4096, (512, 1024), 4, (140, 512, 56)),
+    # the resident cells (c111m, c1p3b, olmoe, lfm2): nothing to chunk
+    (2048, 64, 64, None, (512, 512), 1, (10, 16, 10)),
+    (2048, 128, 128, None, (512, 512), 1, (10, 16, 10)),
+    (4096, 128, 128, None, (512, 512), 1, (36, 64, 36)),
+    (8192, 64, 64, None, (512, 512), 1, (136, 256, 136)),
+])
+def test_the_chunk_rule_at_the_cells_calls(seq_len, dqk, dv, window, blocks,
+                                           chunk, steps) -> None:
+    """``_choose_chunk`` at every cell's call, and the grid steps a head
+    its forward takes there (``_grid_steps``' third): the ladder's readings
+    stand beside ``_CHUNK_LADDER`` (PERF.md, PR 60)."""
+    from torchft_tpu.ops.flash import (
+        _choose_blocks, _choose_chunk, _grid_steps,
+    )
+
+    assert _choose_blocks(seq_len, dqk, 2, v_dim=dv, window=window) == blocks
+    got = _choose_chunk(seq_len, dqk, 2, *blocks, dv, window)
+    assert got == chunk == _choose_chunk(seq_len, dqk, 2, *blocks, dv, window)
+    assert _grid_steps(seq_len, *blocks, window, chunk) == steps
+    assert _grid_steps(seq_len, *blocks, window) == steps[:2]
+
+
+def _forward_kernel_dots(chunk, mask):
+    from torchft_tpu.ops.flash import _flash_forward
+
+    q = jnp.zeros((2, 2048, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _flash_forward(
+        q, k, v, mask != "unmasked", 0.125, 128, 128, True, 0,
+        window=_MASKS[mask].get("window"), chunk=chunk))(q, q, q)
+    (eqn,) = _flash_calls(jaxpr.jaxpr)["flash_fwd"]
+    return (len(_kernel_dots(jaxpr.jaxpr)["flash_fwd"]),
+            eqn.params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+def test_the_chunked_body_is_one_tile_long(mask) -> None:
+    # A chunk's tiles are met in straight-line groups of ``_STRAIGHT``
+    # tiles, and a group is TRACED as a loop of one tile (Mosaic's lowering
+    # lays it out whole): the traced body — what every run pays to trace —
+    # holds a tile's two matmuls once a group size (twice under a mask: the
+    # body with it and the body without), whatever the chunk, and no group
+    # longer than the chunk. At one tile a step the two bodies stand under
+    # ``pl.when`` and there is no loop.
+    from torchft_tpu.ops.flash import _STRAIGHT
+
+    bodies = 1 if mask == "unmasked" else 2
+    dots = {n: _forward_kernel_dots(n, mask) for n in (1, 2, 4, 8, 16)}
+    for n, (count, _) in dots.items():
+        groups = sum(size <= n for size in _STRAIGHT) if n > 1 else 1
+        assert count == 2 * bodies * groups, (n, count)
+    assert dots[2][0] <= dots[4][0] == dots[8][0] == dots[16][0]
+    # and the grid shrinks with the chunk
+    steps = [int(np.prod(grid[1:])) for _, grid in dots.values()]
+    assert steps == sorted(steps, reverse=True) and steps[-1] < steps[0]
+
+
+def test_the_wrapper_counts_the_chunked_calls() -> None:
+    names = ("flash_calls", "flash_calls_chunked")
+
+    def trace(seq_len, **kw):
+        q = jnp.zeros((1, seq_len, 2, 64), jnp.bfloat16)
+        before = _traced_flash_calls(names)
+        jaxpr = jax.make_jaxpr(lambda q: flash_attention(
+            q, q, q, interpret=True, **kw))(q)
+        (eqn,) = _flash_calls(jaxpr.jaxpr)["flash_fwd"]
+        return (tuple(_traced_flash_calls(names) - before),
+                eqn.params["grid_mapping"].grid)
+
+    # resident: one tile's worth of nothing to chunk
+    assert trace(1024) == ((1, 0), (2, 2))
+    # streamed, two tiles a row: a chunk of two, a grid step a q block
+    assert trace(1024, _resident_kv_bytes=0) == ((1, 1), (2, 2))
+    # streamed, a tile a row (explicit blocks): the one-tile body
+    assert trace(1024, _resident_kv_bytes=0, block_q=1024,
+                 block_k=1024) == ((1, 0), (2, 1))

@@ -110,10 +110,42 @@ accumulators, no overlap of MXU and vector work across trips), so at
 S 2048 512 x 512 tiles run the three kernels 2.3-3.9x faster than
 128 x 128 at both head widths, although the diagonal wastes more (10 of
 16 tiles computed, against 136 of 256). In the streamed regime a grid
-step is one tile, and at 512 x 512 the step's own cost is of the order
-of the tile's arithmetic: there a 512 x 1024 tile is tried first
-(``_STREAMED_TILES``). Explicit arguments win
+step of dq and dkv is one tile, and at 512 x 512 the step's own cost is
+of the order of the tile's arithmetic: there a 512 x 1024 tile is tried
+first (``_STREAMED_TILES``). Explicit arguments win
 (parallel/ring.py and the tests pass them).
+
+Chunks. A grid step of the streamed FORWARD fetches ``n`` consecutive k
+tiles of K and V (one ``(1, n * block_k, D)`` block each; chunks start at
+multiples of ``n`` tiles) and sweeps the live ones inside the kernel, in
+ascending k. The inner grid axis enumerates the (q block, chunk) pairs
+that hold a live tile (``_live_tiles(chunk=n)``; a dead tile of a fetched
+chunk is fetched and not computed): 24 grid steps a head for 72 at S 8192
+and ``n`` 4, 48 for 272 at S 16384 and 8. What a step saves by itself is
+little (measured on the v5e, PERF.md, PR 60: the same tiles one after the
+other inside a step, each through the scratch as before, 22.3 -> 21.5 ms
+at [128, 8192, 128]); what pays is that the chunk's tiles are laid out in
+STRAIGHT-LINE GROUPS of ``_STRAIGHT`` tiles — as many groups of four as
+the live run holds, then a pair, then a single —, ``(acc, m, l)`` carried
+as values inside a group and through the VMEM scratch between groups:
+within a group Mosaic schedules one tile's matmuls beside its neighbour's
+softmax, which neither a grid step nor a loop trip lets it do (a loop
+over the tiles with the carry as values read SLOWER than one tile a
+step, 22.3 -> 22.7 - 26.3 ms; values through ``lax.cond`` 30 ms). A group
+is traced as a ``fori_loop`` of ONE tile with ``unroll`` its length, which
+Mosaic's lowering lays out whole, so the traced body holds a tile's body
+once a group size and mask, whatever ``n``. A group runs the masked body
+for all its tiles where any of them is cut by an edge of the mask (the
+mask leaves a full tile's scores as they are), the unmasked body
+otherwise. Every live tile is computed by ``_fwd_tile`` in the order it
+was always met, so ``out`` and ``lse`` are bit for bit what one tile a
+step gave. ``n`` is ``_choose_chunk``'s, a pure function of the shape
+(``_CHUNK_LADDER``); at ``n == 1`` the body is the one-tile body. dq and
+dkv keep one tile a step: their readings moved 1 - 3 % when they swept
+chunks (PERF.md, PR 58), under what a second and third longer body cost
+every run to trace. The three streamed builders stand under a
+``jax.jit`` of their own, so a body is traced and lowered once a shape
+and process, not at each call site (``_flash_forward_streamed``).
 
 Mosaic layout note: per-row statistics (lse, delta) are [BH, S] f32 in
 HBM and reach EVERY kernel as the one view of that array whose minor
@@ -267,31 +299,62 @@ def _sweep_ends(idx, block_q: int, block_k: int, seq_len: int, rows: bool,
             else (diagonal[0], full[1] - 1))
 
 
-def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool,
+def _row_ranges(qi, block_q: int, block_k: int, seq_len: int, causal: bool,
                 window: Optional[int] = None):
+    """``(live, full)`` of q block ``qi``'s row of tiles, each ``(lo, hi)``
+    over k blocks: the tiles that are computed and, among them, the run
+    that no edge of the mask cuts — :func:`_causal_sweep`'s ranges
+    (:func:`_band_sweep`'s under a window) joined; without the mask the
+    whole row both times."""
+    if not causal:
+        whole = (0, seq_len // block_k)
+        return whole, whole
+    if window is None:
+        full, diagonal = _causal_sweep(qi, block_q, block_k, seq_len, True)
+        return (full[0], diagonal[1]), full
+    full, diagonal, trailing = _band_sweep(
+        qi, block_q, block_k, seq_len, True, window)
+    return (trailing[0], diagonal[1]), full
+
+
+def _live_tiles(seq_len: int, block_q: int, block_k: int, rows: bool,
+                window: Optional[int] = None, chunk: int = 1):
     """``(q_of, k_of)``: the q block and the k block of the t-th live tile
     under the causal mask (the band's, under a window), as two ``int32``
     tables — row-major (a q block's
     k blocks ascending: forward and dq) or, ``rows`` false, column-major (a
     k block's q blocks ascending: dkv). What a streamed causal grid
-    enumerates; :func:`_tile_live` alone decides which tiles are in it."""
+    enumerates; :func:`_tile_live` alone decides which tiles are in it.
+    With a ``chunk`` of k tiles a grid step (the forward: ``rows``),
+    ``k_of`` is the chunk — tiles ``k_of * chunk`` and on — and a chunk is
+    listed where any of its tiles is live."""
     q_of, k_of = np.indices(
         (seq_len // block_q, seq_len // block_k), dtype=np.int32
     )
     if not rows:
         q_of, k_of = q_of.T, k_of.T
     live = _tile_live(q_of, k_of, block_q, block_k, window)
+    if chunk > 1:
+        live = live.reshape(len(live), -1, chunk).any(axis=-1)
+        q_of, k_of = np.indices(live.shape, dtype=np.int32)
     return q_of[live], k_of[live]
 
 
 def _grid_steps(seq_len: int, block_q: int, block_k: int,
-                window: Optional[int] = None) -> Tuple[int, int]:
+                window: Optional[int] = None,
+                chunk: Optional[int] = None) -> Tuple[int, ...]:
     """``(live, rectangular)`` grid steps a head of one streamed sweep at
     these tiles: what a causal call takes and what a call without the mask
     does (72 and 128 at S 8192 with 512 x 1024 tiles; under a window of
-    512 keys 31 of the 256 tiles of 512 x 512)."""
-    return (len(_live_tiles(seq_len, block_q, block_k, True, window)[0]),
-            (seq_len // block_q) * (seq_len // block_k))
+    512 keys 31 of the 256 tiles of 512 x 512). With a ``chunk``, a third:
+    the steps of the causal forward at that many k tiles a step (24 of the
+    72 at 4)."""
+    steps = (len(_live_tiles(seq_len, block_q, block_k, True, window)[0]),
+             (seq_len // block_q) * (seq_len // block_k))
+    if chunk is None:
+        return steps
+    return steps + (len(_live_tiles(
+        seq_len, block_q, block_k, True, window, chunk)[0]),)
 
 
 def _sweep(idx, block_q: int, block_k: int, seq_len: int, causal: bool,
@@ -437,7 +500,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
 
 
 def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
-                   causal: bool, rows: bool, window: Optional[int] = None):
+                   causal: bool, rows: bool, window: Optional[int] = None,
+                   chunk: int = 1):
     """Streamed regime: which tile this grid step computes. Returns ``(qi,
     ki, first, last, refs)``: the tile, the first and the last value the
     swept index (``ki`` of a row sweep, ``qi`` of a column sweep) takes in
@@ -446,11 +510,13 @@ def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
     axes are the blocks themselves. Under it the one inner axis runs over
     the live tiles only and ``refs`` starts with the two tables of
     :func:`_live_tiles`, prefetched as scalars; the sweep's ends are
-    :func:`_causal_sweep`'s."""
+    :func:`_causal_sweep`'s. Where a step holds a ``chunk`` of k tiles
+    (the forward), ``ki``, ``first`` and ``last`` count chunks."""
     if not causal:
         own, swept = pl.program_id(1), pl.program_id(2)
         qi, ki = (own, swept) if rows else (swept, own)
-        return qi, ki, 0, seq_len // (block_k if rows else block_q) - 1, refs
+        return (qi, ki, 0,
+                seq_len // (block_k * chunk if rows else block_q) - 1, refs)
     q_of, k_of, *refs = refs
     step = pl.program_id(1)
     qi, ki = q_of[step], k_of[step]
@@ -458,6 +524,8 @@ def _streamed_tile(refs, block_q: int, block_k: int, seq_len: int,
         qi if rows else ki, block_q, block_k, seq_len, rows,
         window=window
     )
+    if chunk > 1:
+        first, last = first // chunk, last // chunk
     return qi, ki, first, last, refs
 
 
@@ -499,7 +567,7 @@ def _whole_of(head):
 
 def _streamed_grid(heads: int, seq_len: int, block_q: int, block_k: int,
                    causal: bool, rows: bool, window: Optional[int] = None,
-                   group: int = 1):
+                   group: int = 1, chunk: int = 1):
     """A streamed call's ``(grid, tables, by_q, by_k, q_lanes)``: the grid,
     the scalar-prefetch operands and the index maps of a block of q rows
     ([.., BQ, D]), of k rows and of q positions along the lanes ([.., 1,
@@ -511,11 +579,13 @@ def _streamed_grid(heads: int, seq_len: int, block_q: int, block_k: int,
     Where a key/value head serves a ``group`` of query heads, a row sweep's
     ``by_k`` reads head ``b // group``, and the column sweep takes one more
     grid axis, innermost, over the group's heads: its ``by_q`` and
-    ``q_lanes`` read head ``b * group + g`` (:func:`_heads_of`)."""
+    ``q_lanes`` read head ``b * group + g`` (:func:`_heads_of`). With a
+    ``chunk`` (the forward's row sweep) ``by_k`` counts blocks of
+    ``chunk`` k tiles and the grid the chunks that hold a live tile."""
     q_head, kv_head, inner = _heads_of(group, rows)
     if causal:
         tables = _live_tiles(seq_len, block_q, block_k, rows,
-                             window=window)
+                             window=window, chunk=chunk)
 
         def at(head, of, lanes=False):
             def index_map(*ids_and_tables):
@@ -530,7 +600,7 @@ def _streamed_grid(heads: int, seq_len: int, block_q: int, block_k: int,
             tuple(jnp.asarray(t) for t in tables),
             at(q_head, "q"), at(kv_head, "k"), at(q_head, "q", lanes=True),
         )
-    num_q, num_k = seq_len // block_q, seq_len // block_k
+    num_q, num_k = seq_len // block_q, seq_len // (block_k * chunk)
     q_at, k_at = (1, 2) if rows else (2, 1)   # swept axis innermost
     return (
         ((heads, num_q, num_k) if rows else (heads, num_k, num_q)) + inner,
@@ -543,12 +613,20 @@ def _streamed_grid(heads: int, seq_len: int, block_q: int, block_k: int,
 
 def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
                            causal: bool, scale: float,
-                           window: Optional[int] = None):
-    """K-blocks ride the innermost grid dimension: only (block_k, d) K/V
-    tiles are VMEM-resident at a time, so sequence length is bounded by
-    HBM, not VMEM. acc/m/l live in VMEM scratch across the k sweep."""
+                           window: Optional[int] = None, chunk: int = 1):
+    """K-blocks ride the innermost grid dimension: only ``chunk`` (block_k,
+    d) K/V tiles are VMEM-resident at a time, so sequence length is bounded
+    by HBM, not VMEM. acc/m/l live in VMEM scratch across the k sweep. A
+    chunk's live tiles (:func:`_row_ranges`; a dead tile of a fetched chunk
+    is not computed) are met in ascending k in straight-line groups of
+    ``_STRAIGHT`` tiles, each traced as a loop of ONE tile that is laid
+    out whole when it is lowered: inside a group Mosaic schedules a
+    tile's matmuls beside its neighbour's softmax, which neither a grid
+    step nor a loop trip lets it do. At one tile a step there is no
+    loop."""
     qi, ki, first, last, refs = _streamed_tile(
-        refs, block_q, block_k, seq_len, causal, True, window=window
+        refs, block_q, block_k, seq_len, causal, True, window=window,
+        chunk=chunk,
     )
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
 
@@ -558,18 +636,71 @@ def _flash_streamed_kernel(*refs, block_q: int, block_k: int, seq_len: int,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _accumulate(masked):
-        acc, m, l = _fwd_tile(
-            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
-            acc_ref[...], m_ref[:, :1], l_ref[:, :1], qi, ki, masked,
-            window=window,
-        )
+    def _carried():
+        return acc_ref[...], m_ref[:, :1], l_ref[:, :1]
+
+    def _keep(acc, m, l):
         acc_ref[...] = acc
         m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
 
-    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
-                    window=window)
+    def _accumulate(masked):
+        _keep(*_fwd_tile(
+            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
+            *_carried(), qi, ki, masked, window=window,
+        ))
+
+    def _accumulate_chunk():
+        q = _f32(q_ref[0]) * scale
+        lo = ki * chunk          # ki counts chunks; the tiles are lo and on
+        (live_lo, live_hi), (full_lo, full_hi) = (
+            [jnp.clip(bound, lo, lo + chunk) for bound in bounds]
+            for bounds in _row_ranges(qi, block_q, block_k, seq_len, causal,
+                                      window))
+
+        def group(size, start):
+            # ``size`` consecutive tiles in one straight line, (acc, m, l)
+            # carried as values between them and through the scratch
+            # between groups; the masked body for all of them where any
+            # of them is cut by an edge (it leaves a full tile as it is)
+            def body(i, _):
+                first = start + i * size
+
+                def run(masked):
+                    def tile(j, carry):
+                        kt = first + j
+                        at = pl.ds((kt - lo) * block_k, block_k)
+                        return _fwd_tile(
+                            q, _f32(k_ref[0, at, :]), _f32(v_ref[0, at, :]),
+                            *carry, qi, kt, masked, window)
+
+                    _keep(*jax.lax.fori_loop(0, size, tile, _carried(),
+                                             unroll=size))
+
+                if not causal:
+                    run(False)
+                    return 0
+                full = (first >= full_lo) & (first + size <= full_hi)
+                pl.when(full)(functools.partial(run, False))
+                pl.when(jnp.logical_not(full))(functools.partial(run, True))
+                return 0
+            return body
+
+        # the chunk's live tiles in ascending k: as many groups of the
+        # longest size as they hold, then of the next
+        done = live_lo
+        for size in _STRAIGHT:
+            if size > chunk:
+                continue
+            count = (live_hi - done) // size
+            jax.lax.fori_loop(0, count, group(size, done), 0)
+            done = done + count * size
+
+    if chunk > 1:
+        _accumulate_chunk()
+    else:
+        _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
+                        window=window)
 
     @pl.when(ki == last)
     def _finalize():
@@ -587,22 +718,16 @@ _RESIDENT_KV_BYTES = 2 * 1024 * 1024
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool,
                    resident_kv_bytes: Optional[int] = None,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None,
+                   chunk: Optional[int] = None):
     """q [BH, S, Dqk], k [BKV, S, Dqk], v [BKV, S, Dv] -> (out [BH, S,
     Dv], lse [BH, S] f32); row ``b`` of q reads row ``b // (BH // BKV)``
-    of k and v."""
+    of k and v. ``chunk``: the k tiles a streamed grid step sweeps,
+    :func:`_choose_chunk`'s where the caller gave none."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
     group = bh // k.shape[0]
-    threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
-                 else resident_kv_bytes)
-    kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
-    # lse leaves as [BH, 1, S] rows (module docstring: lane-dense)
-    out_shapes = (
-        jax.ShapeDtypeStruct((bh, seq_len, dv), q.dtype),
-        jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
-    )
-    if kv_bytes <= threshold:
+    if _resident(seq_len, d + dv, q.dtype.itemsize, resident_kv_bytes):
         grid = (bh, seq_len // block_q)
         whole_kv = _whole_of(_heads_of(group, True)[1])
         kernel = functools.partial(
@@ -626,16 +751,55 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                 pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                 pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             ],
-            out_shape=out_shapes,
+            out_shape=_forward_out_shapes(q, dv),
             interpret=interpret,
             name="flash_fwd",
         )(q, k, v)
         return out, lse[:, 0, :]
 
     # Long context: stream K/V tiles via the grid.
+    if chunk is None:
+        chunk = _choose_chunk(seq_len, d, q.dtype.itemsize, block_q, block_k,
+                              dv, window, causal, resident_kv_bytes)
+    elif (seq_len // block_k) % chunk:
+        raise ValueError(
+            f"flash attention: a chunk of {chunk} k tiles does not divide "
+            f"the row of {seq_len // block_k}")
+    return _flash_forward_streamed(q, k, v, causal, scale, block_q, block_k,
+                                   interpret, window, chunk)
+
+
+def _forward_out_shapes(q, dv: int):
+    """``out`` and ``lse`` as the forward's kernels write them: lse leaves
+    as [BH, 1, S] rows (module docstring: lane-dense)."""
+    bh, seq_len, _ = q.shape
+    return (
+        jax.ShapeDtypeStruct((bh, seq_len, dv), q.dtype),
+        jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
+    )
+
+
+# The streamed builders stand under a jit of their own: a body is traced
+# and lowered once a shape and process, not at each call site of each
+# program a run traces (the models' layers are Python loops under a
+# ``jax.checkpoint`` a layer: two forwards, a dq and a dkv a layer of the
+# step's program, and the reference check's and the micro's programs
+# again; ``ops/kda.py::_forward`` is the precedent). The resident branches
+# stay outside it: the program of every call with resident K and V is
+# what it was, to the instruction (``tests/test_flash.py`` pins it).
+# ``_STRAIGHT`` is read inside the trace: whoever sets another clears
+# jax's caches (``_CHUNK_LADDER`` is read outside, by ``_choose_chunk``).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_forward_streamed(q, k, v, causal: bool, scale: float,
+                            block_q: int, block_k: int, interpret: bool,
+                            window: Optional[int], chunk: int):
+    """The streamed ``flash_fwd`` call: a grid step fetches ``chunk``
+    consecutive k tiles of K and V."""
+    bh, seq_len, d = q.shape
+    dv = v.shape[-1]
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
         bh, seq_len, block_q, block_k, causal, True, window=window,
-        group=group,
+        group=bh // k.shape[0], chunk=chunk,
     )
     kernel = functools.partial(
         _flash_streamed_kernel,
@@ -645,6 +809,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         causal=causal,
         scale=scale,
         window=window,
+        chunk=chunk,
     )
     scratch = [
         pltpu.VMEM((block_q, dv), jnp.float32),
@@ -658,8 +823,8 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), by_q),
-                pl.BlockSpec((1, block_k, d), by_k),
-                pl.BlockSpec((1, block_k, dv), by_k),
+                pl.BlockSpec((1, chunk * block_k, d), by_k),
+                pl.BlockSpec((1, chunk * block_k, dv), by_k),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, dv), by_q),
@@ -667,7 +832,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             ],
             scratch_shapes=scratch,
         ),
-        out_shape=out_shapes,
+        out_shape=_forward_out_shapes(q, dv),
         interpret=interpret,
         name="flash_fwd",
     )(*tables, q, k, v)
@@ -887,6 +1052,7 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
                              scale: float, block_q: int, block_k: int,
                              interpret: bool, window: Optional[int] = None):
@@ -996,16 +1162,13 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
     group = bh // k.shape[0]
-    threshold = (_RESIDENT_KV_BYTES if resident_kv_bytes is None
-                 else resident_kv_bytes)
-    kv_bytes = seq_len * (d + dv) * q.dtype.itemsize
     # the one view of the [BH, S] statistics that dq and dkv both take
     # (module docstring): [BH, 1, S] rows, q positions along the lanes
     lse_row, delta_row = lse[:, None, :], delta[:, None, :]
-    if kv_bytes > threshold:
+    if not _resident(seq_len, d + dv, q.dtype.itemsize, resident_kv_bytes):
         return _flash_backward_streamed(
             q, k, v, g, lse_row, delta_row, causal, scale, block_q,
-            block_k, interpret, window=window,
+            block_k, interpret, window,
         )
 
     whole_kv = _whole_of(_heads_of(group, True)[1])
@@ -1119,10 +1282,11 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # alike, for the row sweeps and for the column sweep; 1024 gains nothing
 # more where K and V are resident.
 _TILES = ((512, 512), (256, 256), (128, 128))
-# The streamed kernels run ONE tile a grid step, and a step costs about as
-# much again as a 512 x 512 tile's arithmetic, so they try a k edge of
-# 1024 first. Measured on the v5e at [2, 8192, 32, 192 / 128] (PERF.md,
-# PR 31): forward 27.8 -> 19.5 ms; at 128 / 128 it changes nothing.
+# A streamed grid step of dq and dkv is ONE tile (of the forward a chunk of
+# them, ``_CHUNK_LADDER``), and a step costs about as much again as a
+# 512 x 512 tile's arithmetic, so they try a k edge of 1024 first.
+# Measured on the v5e at [2, 8192, 32, 192 / 128] (PERF.md, PR 31):
+# forward 27.8 -> 19.5 ms; at 128 / 128 it changes nothing.
 _STREAMED_TILES = ((512, 1024),) + _TILES
 # Under a window a k edge of 1024 is live for every q block the band
 # touches it in, so it computes more above the band than the square tile
@@ -1140,10 +1304,13 @@ _STREAMED_WINDOW_EDGES = 2
 _VMEM_BUDGET = 16 * 1024 * 1024
 
 
-def _resident(seq_len: int, pair: int, itemsize: int) -> bool:
+def _resident(seq_len: int, pair: int, itemsize: int,
+              threshold: Optional[int] = None) -> bool:
     """Whether K and V of one head (``pair`` = their widths' sum) stay
-    whole in VMEM: the regime the kernels' wrappers pick by default."""
-    return seq_len * pair * itemsize <= _RESIDENT_KV_BYTES
+    whole in VMEM: the regime the kernels' wrappers pick by default
+    (``threshold``: a call's own ``_resident_kv_bytes``)."""
+    return seq_len * pair * itemsize <= (
+        _RESIDENT_KV_BYTES if threshold is None else threshold)
 
 
 def _vmem_estimate(seq_len: int, head_dim: int, itemsize: int,
@@ -1195,6 +1362,70 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
             break
     return (q_edge if block_q is None else min(block_q, seq_len),
             k_edge if block_k is None else min(block_k, seq_len))
+
+
+# k tiles a grid step of the streamed forward fetches, longest first, and
+# the tiles of a chunk laid out in one straight line, longest group first
+# (module docstring). Measured on the v5e (PERF.md, PR 60;
+# ``scripts/flash_micro.py --cells streamed --chunks 1 2 4 8``), the
+# forward alone in ms a call at one tile a step -> 2 / 4 / 8 tiles:
+#   [128, 8192, 192 | 128] (joyai, kimi)   31.5 -> 28.8 / 28.4 / refused
+#   [128, 8192, 128] (nemo3)               22.3 -> 19.3 / 18.8 / 18.4
+#   [56, 16384, 128] (smallthinker)        36.0 -> 30.7 / 29.5 / 29.5
+#   the same under W 4096                  19.1 -> 17.1 / 17.3 / 17.6
+#   [256, 8192, 128] under W 512 (laguna)  19.8 -> 19.1 / 18.5 / 18.5
+#   [80, 8192, 64 | 128] under W 512       6.96 -> 6.78 / 6.62 / 6.62
+# (at 192 | 128 eight tiles of K and V twice over are 10.5 MB and Mosaic
+# refuses the kernel by 20 KB of its 16 MiB: the estimate below reads
+# 17.25). Groups of (2, 1) read 0 - 2.5 % slower than (4, 2, 1) at every
+# full call (18.8, 30.3 at eight tiles) and the same under the windows;
+# a group of eight does not fit beside eight tiles. Under a window the
+# longest row of live tiles bounds the chunk: five tiles at W 4096, where
+# the shorter chunks fetch fewer dead tiles, two at W 512, where four and
+# eight read 3 % faster than the two the rule takes (more rows' two tiles
+# fall in one chunk) for 2.5 and 4.4 times the tiles fetched.
+_CHUNK_LADDER = (8, 4, 2, 1)
+_STRAIGHT = (4, 2, 1)
+
+
+def _forward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
+                           block_q: int, block_k: int, chunk: int) -> int:
+    """Bytes the streamed forward keeps in VMEM at ``chunk`` k tiles a
+    grid step: its pipelined operands twice (K and V of the chunk, q, out
+    and the lse row), the three scratch accumulators, the f32 copies of q
+    and of ONE tile of K and V, and a tile's temporaries (S, P and the
+    carried acc)."""
+    pair = head_dim + v_dim
+    operands = (chunk * block_k + block_q) * pair * itemsize + block_q * 4
+    scratch = block_q * (v_dim + 2 * _LANES) * 4
+    upcast = (block_q * head_dim + block_k * pair) * 4
+    return (2 * operands + scratch + upcast
+            + (2 * block_k + v_dim) * block_q * 4)
+
+
+def _choose_chunk(seq_len: int, head_dim: int, itemsize: int, block_q: int,
+                  block_k: int, v_dim: Optional[int] = None,
+                  window: Optional[int] = None, causal: bool = True,
+                  resident_kv_bytes: Optional[int] = None) -> int:
+    """k tiles a grid step of the forward sweeps, as a pure function of
+    the shape: 1 where K and V are resident, else the longest rung of
+    ``_CHUNK_LADDER`` that the longest row of live tiles fills, that
+    divides the row of tiles (chunks start at multiples of the rung) and
+    whose :func:`_forward_vmem_estimate` fits ``_VMEM_BUDGET``."""
+    v_dim = head_dim if v_dim is None else v_dim
+    if _resident(seq_len, head_dim + v_dim, itemsize, resident_kv_bytes):
+        return 1
+    num_k = seq_len // block_k
+    row = num_k
+    if causal:
+        row = int(np.bincount(_live_tiles(
+            seq_len, block_q, block_k, True, window)[0]).max())
+    return next(
+        n for n in _CHUNK_LADDER
+        if n == 1 or (n <= row and num_k % n == 0
+                      and _forward_vmem_estimate(
+                          head_dim, v_dim, itemsize, block_q, block_k, n)
+                      <= _VMEM_BUDGET))
 
 
 def _bshd_prologue(q, k, v, scale, block_q, block_k, window=None):
@@ -1334,6 +1565,10 @@ def flash_attention(q, k, v, causal: bool = True,
     TRACED.incr("flash_calls")
     if k.shape[2] != q.shape[2]:
         TRACED.incr("flash_calls_grouped")
+    if _choose_chunk(q.shape[1], q.shape[3], q.dtype.itemsize, block_q,
+                     block_k, v.shape[3], window, causal,
+                     _resident_kv_bytes) > 1:
+        TRACED.incr("flash_calls_chunked")
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
                  block_q, block_k, interpret, _resident_kv_bytes, window)
     return unmerge(out)
