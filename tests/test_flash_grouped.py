@@ -33,7 +33,14 @@ def test_a_key_value_head_is_read_where_it_lies(group, regime, mask,
     VMEM and rounded once, where the repeated path rounds a head's
     gradient and XLA sums the copies — stand at the file's bf16 tolerance
     from the float32 reference's gradients summed over the copies in
-    float32, and no further from them than the repeated path does."""
+    float32, and no further from them than the repeated path does.
+
+    Crosses, at the blocks passed (64 x 128): two k blocks and four q
+    blocks (S 256 is the least that holds two k blocks), the causal
+    diagonal inside a tile, the window's edge (200 keys: a band that
+    starts inside the first k block for the last q blocks) and ``KV < H``
+    with two key/value heads wherever the group leaves room (a query head
+    must be able to read the WRONG one)."""
     dqk, dv = widths
     b, s, kv = 1, 256, 2 if group < 7 else 1
     h = kv * group
@@ -50,28 +57,35 @@ def test_a_key_value_head_is_read_where_it_lies(group, regime, mask,
     def repeated(q, k, v):
         return flash(q, repeat_kv(k, h), repeat_kv(v, h))
 
-    def out_and_grads(fn):
+    def out_and_grads(fn, q, k, v, cot):
         out, vjp = jax.vjp(fn, q, k, v)
         return (out, *vjp(cot))
 
-    got = out_and_grads(flash)
-    # at group 1 the copies are the operands and the two calls one program
-    want = out_and_grads(repeated) if group > 1 else got
-    for name, a, b_ in zip(("out", "dq", "dk", "dv"), got, want):
-        assert a.dtype == jnp.bfloat16 and a.shape == b_.shape, name
-    assert jnp.array_equal(got[0], want[0])
-    assert jnp.array_equal(got[1], want[1])
-
-    # the float32 reference on the copies; its gradients summed over them
-    @jax.jit
     def reference(q, k, v, cot):
+        """The float32 reference on the copies; its gradients summed over
+        them."""
         grads = jax.grad(lambda k_, v_: jnp.sum(reference_attention(
             q, k_, v_, **_MASKS[mask]) * cot), argnums=(0, 1))(
                 repeat_kv(k, h), repeat_kv(v, h))
         return [x.reshape(b, s, kv, group, -1).sum(axis=3) for x in grads]
 
-    ref_dk, ref_dv = reference(
-        *(x.astype(jnp.float32) for x in (q, k, v, cot)))
+    # ONE program a case — the grouped call, the repeated call and the
+    # reference, forward and backward: at S 256 compiling is what a case
+    # costs, and three programs' fixed parts are paid once. At group 1 the
+    # copies are the operands and the two calls one program.
+    @jax.jit
+    def everything(q, k, v, cot):
+        got = out_and_grads(flash, q, k, v, cot)
+        want = out_and_grads(repeated, q, k, v, cot) if group > 1 else got
+        return got, want, reference(
+            *(x.astype(jnp.float32) for x in (q, k, v, cot)))
+
+    got, want, (ref_dk, ref_dv) = everything(q, k, v, cot)
+    for name, a, b_ in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16 and a.shape == b_.shape, name
+    assert jnp.array_equal(got[0], want[0])
+    assert jnp.array_equal(got[1], want[1])
+
     for name, a, r, ref in (("dk", got[2], want[2], ref_dk),
                             ("dv", got[3], want[3], ref_dv)):
         err, err_repeated = (

@@ -13,14 +13,14 @@ import hashlib
 import json
 import os
 import re
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+
+import family_kit as kit
 
 from benchmark.reference import joyai_f32
 from torchft_tpu.models import (
@@ -35,6 +35,8 @@ from torchft_tpu.optim import balance_bias_rule, with_balance_bias
 
 CFG = joyai.JOYAI_CONFIGS["joyai_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
+_params = functools.partial(kit.seeded_params, joyai)
+_batch = kit.batch
 
 
 def _ref_kw(cfg):
@@ -45,31 +47,6 @@ def _ref_kw(cfg):
         first_expert=cfg.first_expert, routed_scale=cfg.routed_scale,
         mtp_coef=cfg.mtp_coef, eps=cfg.rms_eps, rope_theta=cfg.rope_theta,
     )
-
-
-def _params(cfg, seed, bias_std=0.1):
-    """Seeded weights with the balance biases away from zero, so that a
-    system that ignored them would route differently."""
-    params = joyai.init_params(cfg, jax.random.key(seed))
-    key = jax.random.key(1000 + seed)
-
-    def leaf(path, x):
-        if path[-1].key != joyai.BALANCE_BIAS:
-            return x
-        return bias_std * jax.random.normal(
-            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, 64), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _bias_leaves(tree):
-    return [x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
-            if getattr(path[-1], "key", None) == joyai.BALANCE_BIAS]
 
 
 def _chosen(experts, n_routed):
@@ -115,7 +92,7 @@ def test_f32_gradients_equal_the_reference() -> None:
     layer, mtp = got["layers_1"]["moe"], got["mtp"]["block"]["moe"]
     assert np.array_equal(layer[joyai.BALANCE_BIAS], terms["loads"][0])
     assert np.array_equal(mtp[joyai.BALANCE_BIAS], terms["loads"][1])
-    assert not np.any(_bias_leaves(want))
+    assert not np.any(kit.bias_leaves(want))
     flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
     seen = set()
     for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
@@ -167,7 +144,9 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault) -> None:
 
     cfg, params, attn_fn = CFG32, _params(CFG32, 5), None
     tokens, targets = _batch(5)
-    want = joyai_f32.terms(params, tokens, targets, **_ref_kw(CFG32))
+    # the sound side: the plain reference, which no patch reaches, once
+    want = kit.sound(("joyai", "reference", 5), lambda: joyai_f32.terms(
+        params, tokens, targets, **_ref_kw(CFG32)))
     if fault == "no_renormalise":
         real = moe.top_k_routing
         monkeypatch.setattr(moe, "top_k_routing", lambda s, k, **kw: real(
@@ -396,25 +375,12 @@ def test_microbatched_grad_step_carries_the_mean_loads() -> None:
     _, whole = make_grad_step(CFG, loss=joyai.loss_fn)(params, tokens, targets)
     _, halves = make_grad_step(CFG, microbatches=2, loss=joyai.loss_fn)(
         params, tokens, targets)
-    for a, b in zip(_bias_leaves(whole), _bias_leaves(halves)):
+    for a, b in zip(kit.bias_leaves(whole), kit.bias_leaves(halves)):
         assert float(jnp.sum(a)) == 4 * 64 * CFG.top_k
         assert float(jnp.sum(b)) == 2 * 64 * CFG.top_k    # a slice's mean
 
 
 # -- the family through the step maker and the fault-tolerant loop -----------
-
-
-def _tiny_model(rows=2):
-    from benchmark.families import joyai as family
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "tests", "tiny-joyai.json")
-    with open(path) as f:
-        config = json.load(f)
-    config["job"]["rows"] = rows
-    # a rate that moves the bias visibly within a few steps
-    config["optimizer"]["balance_bias_rate"] = 0.01
-    return family, family.build(config)
 
 
 def test_the_family_builds_the_configuration_and_refuses_what_it_cannot():
@@ -452,41 +418,8 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size, and the bias
     rule on the fused path: behind the commit gate the bias moves exactly
     as in the plain step."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    family, model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    assert all(np.any(b) for b in _bias_leaves(params))
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
+    with kit.ft_steps(kit.tiny("joyai")) as run:
+        assert all(np.any(b) for b in kit.bias_leaves(run.params))
 
 
 def test_two_groups_on_other_batches_hold_one_bias_and_a_healed_one_gets_it():
@@ -496,69 +429,13 @@ def test_two_groups_on_other_batches_hold_one_bias_and_a_healed_one_gets_it():
     starts from other weights, behind, and gets the first's bias (moved
     by then) only by the heal. At rest on one step the sha256 of
     parameters and optimizer state are equal, and so is every bias."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    family, model = _tiny_model()
-    devices = jax.devices()
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                            heartbeat_timeout_ms=5000)
-    stop_at = [None]
-
-    def keep_going(group):
-        return stop_at[0] is None or group.manager.current_step() < stop_at[0]
-
-    groups, threads = [], []
-
-    def start(gid, seed):
-        source = BatchSource(11, gid, 0, model.rows, model.seq_len,
-                             model.vocab_draw)
-        group = ReplicaGroup(gid, 0, model, family, devices[gid], gid,
-                             lighthouse.address(), seed, source)
-        thread = threading.Thread(target=group.run, args=(keep_going,),
-                                  daemon=True)
-        groups.append(group)
-        threads.append(thread)
-        thread.start()
-        return group
-
-    def wait_for(cond, what):
-        deadline = time.monotonic() + 120
-        while not cond():
-            assert all(g.error is None for g in groups), [
-                repr(g.error) for g in groups]
-            assert time.monotonic() < deadline, what
-            time.sleep(0.02)
-
-    try:
-        first = start(0, 1)
-        wait_for(lambda: first.manager.current_step() >= 2, "solo steps")
-        second = start(1, 2)          # other weights, a zero bias, behind
-        wait_for(lambda: any(r["committed"] for r in list(second.records)),
-                 "the joiner's first commit")
-        stop_at[0] = max(g.manager.current_step() for g in groups) + 3
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-        assert all(g.error is None for g in groups), [g.error for g in groups]
-        jax.block_until_ready([g.state for g in groups])
-        assert any(r["healed"] for r in second.records)
-        both = [r for r in first.records
-                if r["committed"] and r["participants"] == 2]
-        assert len(both) >= 2 and all(r["path"] == "classic" for r in both)
-        assert first.manager.current_step() == second.manager.current_step()
-        assert first.digest() == second.digest()
-        biases = [_bias_leaves(jax.device_get(g.state["params"]))
-                  for g in groups]
+    with kit.two_groups_one_healed(kit.tiny("joyai")) as run:
+        biases = [kit.bias_leaves(jax.device_get(g.state["params"]))
+                  for g in run.groups]
         for a, b in zip(*biases):
             assert np.any(a) and np.array_equal(a, b)
             # whole multiples of the rate: only the sign rule touched it
             assert np.allclose(a / 0.01, np.round(a / 0.01), atol=1e-4)
-    finally:
-        for g in groups:
-            g.teardown()
-        lighthouse.shutdown()
 
 
 def test_the_cells_own_comparison_at_the_small_size() -> None:
@@ -585,7 +462,11 @@ def test_the_cells_own_comparison_at_the_small_size() -> None:
     # the check's own seeding of the bias: other leaves untouched
     seeded = family.seed_balance_bias(params, 3)
     assert seeded["wte"]["embedding"] is params["wte"]["embedding"]
-    assert all(np.any(b) for b in _bias_leaves(seeded))
+    assert all(np.any(b) for b in kit.bias_leaves(seeded))
     again = family.seed_balance_bias(params, 3)
-    for a, b in zip(_bias_leaves(seeded), _bias_leaves(again)):
+    for a, b in zip(kit.bias_leaves(seeded), kit.bias_leaves(again)):
         assert np.array_equal(a, b)
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("joyai")

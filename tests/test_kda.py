@@ -13,11 +13,23 @@ import pytest
 
 from benchmark.reference.kimi_linear_f32 import kda_recurrence
 from benchmark.reference.olmo_hybrid_f32 import gdn_recurrence
+from one_program import value_and_pullback
 from torchft_tpu.ops import kda
 from torchft_tpu.ops.kda import _choose_chunk, gdn_scan, kda_scan
 
 
 LEAVES = ("dq", "dk", "dv", "dg", "dbeta")
+# The file runs ONE head a grid step (``conftest.one_head_a_step``): the
+# interpreter runs the heads of a step side by side, so a body of four (or,
+# for the scalar decay, six) heads is that many times the program to trace
+# and compile, and a head's mathematics — what most of these tests are about
+# — does not know what else its step holds. That it does not is held bit for
+# bit by ``test_heads_that_share_a_step_change_no_bit`` and
+# ``test_scalar_decay_heads_that_share_a_step_change_no_bit``, which set
+# their own rungs; the tests about the rule itself, the pinned jaxpr and the
+# two compiles for the v5e run on the module's own rungs, kept here.
+pytestmark = pytest.mark.usefixtures("one_head_a_step")
+LADDER, GDN_LADDER = kda._LADDER, kda._GDN_LADDER
 
 
 def scan(q, k, v, g, beta, chunk=None):
@@ -61,9 +73,9 @@ def rel(a, b):
 def test_the_scan_is_the_recurrence_whatever_the_chunk(s, chunk, decay):
     args, do = inputs(s, 2, s, 2, 16, 16, decay)
     with jax.default_matmul_precision("highest"):
-        want, pull_ref = jax.vjp(kda_recurrence, *args)
-        got, pull = jax.vjp(lambda *a: scan(*a, chunk=chunk), *args)
-        grads, grads_ref = pull(do), pull_ref(do)
+        want, grads_ref = value_and_pullback(kda_recurrence, args, do)
+        got, grads = value_and_pullback(
+            lambda *a: scan(*a, chunk=chunk), args, do)
     assert rel(got, want) < 2e-6
     for name, a, b in zip(LEAVES, grads, grads_ref):
         assert rel(a, b) < 5e-6, name
@@ -72,10 +84,11 @@ def test_the_scan_is_the_recurrence_whatever_the_chunk(s, chunk, decay):
 def test_key_and_value_widths_may_differ() -> None:
     args, do = inputs(3, 1, 48, 3, 16, 32)
     with jax.default_matmul_precision("highest"):
-        want, pull_ref = jax.vjp(kda_recurrence, *args)
-        got, pull = jax.vjp(lambda *a: scan(*a, chunk=16), *args)
+        want, grads_ref = value_and_pullback(kda_recurrence, args, do)
+        got, grads = value_and_pullback(
+            lambda *a: scan(*a, chunk=16), args, do)
     assert got.shape == (1, 48, 3, 32) and rel(got, want) < 2e-6
-    for name, a, b in zip(LEAVES, pull(do), pull_ref(do)):
+    for name, a, b in zip(LEAVES, grads, grads_ref):
         assert rel(a, b) < 5e-6, name
 
 
@@ -92,9 +105,9 @@ def test_a_decay_that_underflows_gives_zero_never_inf_or_nan(chunk) -> None:
     g = g.at[:, 20:24].set(-300.0)        # and four positions wipe the state
     args = (q, k, v, g, beta)
     with jax.default_matmul_precision("highest"):
-        want, pull_ref = jax.vjp(kda_recurrence, *args)
-        got, pull = jax.vjp(lambda *a: scan(*a, chunk=chunk), *args)
-        grads, grads_ref = pull(do), pull_ref(do)
+        want, grads_ref = value_and_pullback(kda_recurrence, args, do)
+        got, grads = value_and_pullback(
+            lambda *a: scan(*a, chunk=chunk), args, do)
     assert bool(jnp.all(jnp.isfinite(got)))
     # an exponent is a difference of two cumulative sums: its absolute
     # error is 2^-24 of the chunk's total log-decay (here 1.4e4)
@@ -150,9 +163,9 @@ def test_the_chunk_and_what_is_refused() -> None:
 
 def both_ways(args, do, chunk):
     """``(o, dq, dk, dv, dg, dβ)`` of ``kda._kda`` at ``chunk``."""
-    o, pull = jax.vjp(
-        lambda *a: kda._kda(*a, chunk, kda._interpret()), *args)
-    return (o,) + pull(do)
+    o, grads = value_and_pullback(
+        lambda *a: kda._kda(*a, chunk, kda._interpret()), args, do)
+    return (o,) + grads
 
 
 @pytest.fixture
@@ -164,6 +177,9 @@ def ladder(monkeypatch):
         jax.clear_caches()
     yield to
     jax.clear_caches()
+
+
+_ONE_HEAD = {}       # the one-head-a-step results a test below compares with
 
 
 @pytest.mark.parametrize("heads,b,s,h,chunk", [
@@ -179,11 +195,18 @@ def ladder(monkeypatch):
 def test_heads_that_share_a_step_change_no_bit(ladder, heads, b, s, h, chunk):
     """A head's mathematics does not know what else its grid step holds:
     ``o`` and the five gradients are the one-head-a-step kernels' bit for
-    bit."""
+    bit. Crosses, a case: a rung of ``_LADDER`` (2 or 4 heads a step) and
+    two groups of heads where ``h`` is twice the rung; at a chunk of 16
+    two chunk boundaries and a ragged end (40 of 48) or, with two batch
+    rows, three whole chunks; at the cell's chunk of 128 one boundary (256:
+    the least without padding) or a ragged end over two rows (200 of 256).
+    Each shape is compiled at one head a step once, for both of its
+    rungs."""
     args, do = inputs(s + h, b, s, h, 16, 32, 0.3)
-    ladder(1)
-    assert kda._heads_a_step(h, chunk, 16, 32) == 1
-    want = both_ways(args, do, chunk)
+    assert kda._heads_a_step(h, chunk, 16, 32) == 1     # the file's rung
+    if (b, s, h, chunk) not in _ONE_HEAD:       # a shape's two rungs: once
+        _ONE_HEAD[b, s, h, chunk] = both_ways(args, do, chunk)
+    want = _ONE_HEAD[b, s, h, chunk]
     ladder(heads)
     assert kda._heads_a_step(h, chunk, 16, 32) == heads
     got = both_ways(args, do, chunk)
@@ -192,15 +215,17 @@ def test_heads_that_share_a_step_change_no_bit(ladder, heads, b, s, h, chunk):
 
 
 @pytest.mark.parametrize("h", [3, 5, 6])
-def test_a_head_count_takes_the_largest_rung_that_divides_it(h) -> None:
+def test_a_head_count_takes_the_largest_rung_that_divides_it(ladder, h):
     """3 and 5 heads: no rung but 1; 6: two a step, not four. Each is
-    the recurrence."""
+    the recurrence. Crosses: the module's own rungs, and two chunk
+    boundaries (48 positions at a chunk of 16)."""
+    ladder(*LADDER)
     args, do = inputs(h, 1, 48, h, 16, 16)
     assert kda._heads_a_step(h, 16, 16, 16) == {3: 1, 5: 1, 6: 2}[h]
     with jax.default_matmul_precision("highest"):
-        want, pull_ref = jax.vjp(kda_recurrence, *args)
-        got, pull = jax.vjp(lambda *a: scan(*a, chunk=16), *args)
-        grads, grads_ref = pull(do), pull_ref(do)
+        want, grads_ref = value_and_pullback(kda_recurrence, args, do)
+        got, grads = value_and_pullback(
+            lambda *a: scan(*a, chunk=16), args, do)
     assert rel(got, want) < 2e-6
     for name, a, b in zip(LEAVES, grads, grads_ref):
         assert rel(a, b) < 5e-6, name
@@ -223,13 +248,14 @@ def _grids(fn, *args):
     return seen
 
 
-def test_the_rule_and_the_grid_at_the_cells_shape() -> None:
+def test_the_rule_and_the_grid_at_the_cells_shape(ladder) -> None:
     """``kimi-ep32-solo-steady``'s call, ``[4, 8192]`` of 32 heads of 128:
     four heads a step (PERF.md section 6, PR 48), a grid of ``(b, h / 4,
     nc)`` = 2 048 steps a call both ways; the cell's own check (one row of
     2048) takes its heads from the head axis too. Wider heads take fewer
     a step: VMEM."""
-    assert kda._LADDER == (4, 2, 1)
+    assert LADDER == (4, 2, 1)
+    ladder(*LADDER)
     assert kda._heads_a_step(32, 128, 128, 128) == 4
     assert kda._heads_a_step(32, 128, 256, 256) == 4
     assert kda._heads_a_step(32, 128, 512, 512) == 2
@@ -305,7 +331,7 @@ def broadcast(q, k, v, g, beta, chunk):
 
 
 @pytest.mark.parametrize("b,s,h,kd,vd,chunk,decay", [
-    (2, 40, 6, 16, 32, 8, 1.0),       # a ragged end, six heads a step
+    (2, 40, 6, 16, 32, 8, 1.0),       # a ragged end, two batch rows
     (1, 100, 6, 24, 48, 32, 0.1),     # slow decays, a ragged end
     (1, 256, 6, 96, 192, 128, 0.3),   # the cell's widths and chunk
     (1, 192, 6, 64, 128, 64, 0.3),    # 64 / 128
@@ -314,14 +340,19 @@ def broadcast(q, k, v, g, beta, chunk):
 ])
 def test_the_scalar_decay_scan_is_the_recurrence_and_the_broadcast(
         b, s, h, kd, vd, chunk, decay):
+    """Each case crosses at least one chunk boundary at its chunk (40 / 8,
+    100 / 32, 256 / 128, 192 / 64, 48 / 16, 128 at the rule's own choice),
+    two of them with a ragged end; the widths are the cases' subject (12 /
+    24 up to the cell's 96 / 192, key and value unequal), and so is the
+    cell's head count of 30. One head a grid step (the file's rung): the
+    steps that heads share are the next test but two's."""
     args, do = gdn_inputs(s, b, s, h, kd, vd, decay)
     with jax.default_matmul_precision("highest"):
-        want, pull_ref = jax.vjp(gdn_recurrence, *args)
-        got, pull = jax.vjp(lambda *a: gdn(*a, chunk=chunk), *args)
-        grads, grads_ref = pull(do), pull_ref(do)
-        other, pull_other = jax.vjp(
-            lambda *a: broadcast(*a, chunk or _choose_chunk(s)), *args)
-        grads_other = pull_other(do)
+        want, grads_ref = value_and_pullback(gdn_recurrence, args, do)
+        got, grads = value_and_pullback(
+            lambda *a: gdn(*a, chunk=chunk), args, do)
+        other, grads_other = value_and_pullback(
+            lambda *a: broadcast(*a, chunk or _choose_chunk(s)), args, do)
     assert got.shape == (b, s, h, vd) and rel(got, want) < 2e-6
     assert rel(got, other) < 3e-6
     assert float(jnp.max(args[4])) > 1.0     # the negative-eigenvalue branch
@@ -400,14 +431,19 @@ def test_the_scalar_decay_scan_in_bf16_and_a_decay_that_underflows() -> None:
     (30, 64, 128, 6), (9, 64, 128, 2),    # two heads of 64 are a tile
     (30, 128, 1024, 1),   # a step too large for the limit: a smaller group
 ])
-def test_the_scalar_decay_grid_takes_any_head_count(h, kd, vd, want) -> None:
+def test_the_scalar_decay_grid_takes_any_head_count(monkeypatch, h, kd, vd,
+                                                     want) -> None:
     """A group is whole lane tiles on the TPU (of 96 / 192 only four heads
     are) and need not divide the heads: the rung that leaves the fewest
-    heads of the last group outside the arrays, the largest of those."""
+    heads of the last group outside the arrays, the largest of those.
+    (The module's own rungs; nothing is traced, so no cache is cleared.)"""
+    monkeypatch.setattr(kda, "_GDN_LADDER", GDN_LADDER)
     assert kda._gdn_heads_a_step(h, 128, kd, vd, False) == want
 
 
-def test_the_scalar_decay_grid_refuses_widths_no_group_tiles() -> None:
+def test_the_scalar_decay_grid_refuses_widths_no_group_tiles(
+        monkeypatch) -> None:
+    monkeypatch.setattr(kda, "_GDN_LADDER", GDN_LADDER)
     with pytest.raises(ValueError, match="gdn_scan: no group.*100 key"):
         kda._gdn_heads_a_step(30, 128, 100, 192, False)
     # the interpreter takes any width, and the group that wastes least
@@ -433,11 +469,13 @@ def test_scalar_decay_heads_that_share_a_step_change_no_bit(
     args, do = gdn_inputs(21, 2, 40, 6, 16, 32)
 
     def all_six(chunk=16):
-        o, pull = jax.vjp(lambda *a: gdn(*a, chunk=chunk), *args)
-        return (o,) + pull(do)
+        o, grads = value_and_pullback(
+            lambda *a: gdn(*a, chunk=chunk), args, do)
+        return (o,) + grads
 
-    gdn_ladder(1)
-    want = all_six()
+    if "gdn" not in _ONE_HEAD:         # the file's one head a step, once
+        _ONE_HEAD["gdn"] = all_six()
+    want = _ONE_HEAD["gdn"]
     gdn_ladder(heads)
     for name, a, b in zip(("o",) + LEAVES, all_six(), want):
         np.testing.assert_array_equal(a, b, err_msg=name)
@@ -453,9 +491,11 @@ _KIMI_CALL_JAXPR = \
     "0ccd965ce1f92ce6410b312c1761048ab2d2269b6d5745972acd5e4fb89fdec7"
 
 
-def test_kimis_call_traces_to_the_kernels_it_traced_to() -> None:
+def test_kimis_call_traces_to_the_kernels_it_traced_to(ladder) -> None:
     import hashlib
     import re
+
+    ladder(*LADDER)
 
     q = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16)
     g = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.float32)
@@ -475,7 +515,8 @@ def test_kimis_call_traces_to_the_kernels_it_traced_to() -> None:
 # -- the kernels at the cell's widths, for a described v5e --------------------
 
 
-def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
+def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip,
+                                                              ladder):
     """[1, 1024] of 32 heads of 128 at the cell's chunk and the cell's
     four heads a grid step: Mosaic takes the rolls, the tile reshapes,
     the transposes, the HIGHEST-precision cumulative sums and the VMEM
@@ -483,6 +524,7 @@ def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
     S, H, K, V]`` is planned (the states are ``S / C`` of them)."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    ladder(*LADDER)
     b, s, h, d = 1, 1024, 32, 128
 
     def sd(shape, dtype):
@@ -514,7 +556,7 @@ def test_both_kernels_compile_for_the_v5e_at_the_cells_widths(one_chip):
 
 
 def test_the_scalar_decay_kernels_compile_for_the_v5e_at_the_cells_widths(
-        one_chip):
+        one_chip, gdn_ladder):
     """[1, 1024] of 30 heads of 96 key and 192 value channels at the
     cell's chunk and four heads a grid step: Mosaic takes the lane slices
     that start between tiles, the matmuls 96 and 192 wide, the edge block
@@ -525,6 +567,7 @@ def test_the_scalar_decay_kernels_compile_for_the_v5e_at_the_cells_widths(
     planned."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    gdn_ladder(*GDN_LADDER)
     b, s, h, kd, vd = 1, 1024, 30, 96, 192
 
     def sd(shape, dtype):

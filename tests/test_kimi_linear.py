@@ -15,8 +15,6 @@ import dataclasses
 import functools
 import json
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +22,7 @@ import numpy as np
 import optax
 import pytest
 
+import family_kit as kit
 from benchmark import kda_flops
 from benchmark.families import kimi_linear as family
 from benchmark.reference import kimi_linear_f32
@@ -32,37 +31,16 @@ from benchmark.tests.kimi_faults import FAULTS
 from torchft_tpu import optim
 from torchft_tpu.models import joyai, kimi_linear
 
+# the model's tests are not about how many heads share a grid step
+pytestmark = pytest.mark.usefixtures("one_head_a_step")
 CFG = kimi_linear.KIMI_LINEAR_CONFIGS["kimi_linear_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
 # K (dense MLP), M (experts): the faults' size
 TWO32 = dataclasses.replace(CFG32, kda_layers=(1,), full_attn_layers=(2,))
 BIAS = kimi_linear.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _params(cfg, seed, bias_std=0.1):
-    """Seeded weights with the balance biases away from zero, so that a
-    system that ignored them would route differently."""
-    params = kimi_linear.init_params(cfg, jax.random.key(seed))
-    key = jax.random.key(1000 + seed)
-
-    def leaf(path, x):
-        if getattr(path[-1], "key", None) != BIAS:
-            return x
-        return bias_std * jax.random.normal(
-            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, 32), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _bias_leaves(tree):
-    return [x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
-            if getattr(path[-1], "key", None) == BIAS]
+_params = functools.partial(kit.seeded_params, kimi_linear)
+_batch = functools.partial(kit.batch, seq=32)
 
 
 def _reference(cfg):
@@ -84,16 +62,6 @@ def _reference_at(seed):
     """The sound reference of the two-layer model on seed ``seed``'s
     weights and batch, once."""
     return _reference(TWO32)(_params(TWO32, seed), *_batch(seed))
-
-
-def _tiny_model(rows=2):
-    with open(os.path.join(ROOT, "benchmark", "tests",
-                           "tiny-kimi.json")) as f:
-        config = json.load(f)
-    config["job"]["rows"] = rows
-    # a rate that moves the bias visibly within a few steps
-    config["optimizer"]["balance_bias_rate"] = 0.01
-    return family.build(config)
 
 
 # -- against the reference ---------------------------------------------------
@@ -351,8 +319,8 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault) -> None:
         # above the one bf16 rounding of each result, ten times this
         monkeypatch.setattr(family, "KDA_REL_L2_MAX",
                             {n: 1e-3 for n in family.KDA_LEAVES})
-        sound = jax.jit(family.kda_comparison())(
-            *family.kda_inputs(CFG32, 5, 32))
+        sound = kit.sound(("kimi_linear", "kda", 5), lambda: jax.jit(
+            family.kda_comparison())(*family.kda_inputs(CFG32, 5, 32)))
         assert family.judge_kda(sound)["ok"], sound
         alone = jax.jit(family.kda_comparison(scan_fn))(
             *family.kda_inputs(CFG32, 5, 32))
@@ -398,7 +366,7 @@ def test_check_reference_is_both_comparisons(monkeypatch) -> None:
     monkeypatch.setattr(family, "HIDDEN_REL_L2_MAX", 0.15)
     monkeypatch.setattr(family, "TOP_K_DISAGREEMENT_MAX", 0.1)
     monkeypatch.setattr(family, "REFERENCE_LOSS_ATOL", 2e-2)
-    model, device = _tiny_model(), jax.devices()[0]
+    model, device = kit.tiny("kimi_linear"), jax.devices()[0]
     params = family.init_state(model, 5, device)["params"]
     seen = family.check_reference(model, params, 5, device)
     assert seen["ok"], seen
@@ -456,7 +424,7 @@ def test_the_cells_own_comparison_at_the_small_size() -> None:
         CFG32, params, params, tokens, targets, system_cfg=turned))["ok"]
     seeded = family.seed_balance_bias(params, 3)
     assert seeded["wte"]["embedding"] is params["wte"]["embedding"]
-    assert all(np.any(b) for b in _bias_leaves(seeded))
+    assert all(np.any(b) for b in kit.bias_leaves(seeded))
 
 
 # -- the family, the optimizer and the fault-tolerant loop --------------------
@@ -540,7 +508,7 @@ def test_the_warm_up_is_a_schedule_and_the_rule_keeps_the_loads() -> None:
     decay, norms, ``A_log`` and ``dt_bias`` none; the bias rule's state
     is the loads it last saw — no moments — and ``routing_gauges`` reads
     the held share and the skew from it."""
-    model = _tiny_model()
+    model = kit.tiny("kimi_linear")
     params = kimi_linear.init_params(model.cfg, jax.random.key(0))
     opt = model.tx.init(params)
     counts = [x for x in jax.tree_util.tree_leaves(opt)
@@ -582,50 +550,12 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size; and the
     optimizer wrapper's three routing gauges arrive on its sink without a
     wait (read at a later commit than the one that asked)."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    assert all(np.any(b) for b in _bias_leaves(params))
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-        for i in range(3, 12):
-            if "moe_held_share" in group.opt.metrics.snapshot():
-                break
-            jax.block_until_ready(group.state)
-            group.step(*source.device_batch(i, device))
-        seen = group.opt.metrics.snapshot()
+    with kit.ft_steps(kit.tiny("kimi_linear")) as run:
+        assert all(np.any(b) for b in kit.bias_leaves(run.params))
+        seen = kit.routing_gauges(run)
         assert 0.0 < seen["moe_held_share"] < 1.0
         assert seen["moe_load_max_over_mean"] >= 1.0
         assert seen["moe_row_buffer_share"] == 1.0
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
 
 
 def test_two_groups_hold_one_state_and_a_healed_one_gets_it() -> None:
@@ -634,70 +564,18 @@ def test_two_groups_hold_one_state_and_a_healed_one_gets_it() -> None:
     and gets the first's parameters — ``A_log``, ``dt_bias`` and the taps
     among them —, bias, loads and count only by the heal. At rest on one
     step the sha256 of parameters and optimizer state are equal."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    devices = jax.devices()
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                            heartbeat_timeout_ms=5000)
-    stop_at = [None]
-
-    def keep_going(group):
-        return stop_at[0] is None or group.manager.current_step() < stop_at[0]
-
-    groups, threads = [], []
-
-    def start(gid, seed):
-        source = BatchSource(11, gid, 0, model.rows, model.seq_len,
-                             model.vocab_draw)
-        group = ReplicaGroup(gid, 0, model, family, devices[gid], gid,
-                             lighthouse.address(), seed, source)
-        thread = threading.Thread(target=group.run, args=(keep_going,),
-                                  daemon=True)
-        groups.append(group)
-        threads.append(thread)
-        thread.start()
-        return group
-
-    def wait_for(cond, what):
-        deadline = time.monotonic() + 120
-        while not cond():
-            assert all(g.error is None for g in groups), [
-                repr(g.error) for g in groups]
-            assert time.monotonic() < deadline, what
-            time.sleep(0.02)
-
-    try:
-        first = start(0, 1)
-        wait_for(lambda: first.manager.current_step() >= 2, "solo steps")
-        second = start(1, 2)          # other weights, a zero bias, behind
-        wait_for(lambda: any(r["committed"] for r in list(second.records)),
-                 "the joiner's first commit")
-        stop_at[0] = max(g.manager.current_step() for g in groups) + 3
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-        assert all(g.error is None for g in groups), [g.error for g in groups]
-        jax.block_until_ready([g.state for g in groups])
-        assert any(r["healed"] for r in second.records)
-        both = [r for r in first.records
-                if r["committed"] and r["participants"] == 2]
-        assert len(both) >= 2 and all(r["path"] == "classic" for r in both)
-        assert first.manager.current_step() == second.manager.current_step()
-        assert first.digest() == second.digest()
+    with kit.two_groups_one_healed(kit.tiny("kimi_linear")) as run:
         for leaf in ("A_log", "dt_bias"):
             a, b = (np.asarray(g.state["params"]["layers_0"]["kda"][leaf])
-                    for g in groups)
+                    for g in run.groups)
             assert np.array_equal(a, b)
-        biases = [_bias_leaves(jax.device_get(g.state["params"]))
-                  for g in groups]
+        biases = [kit.bias_leaves(jax.device_get(g.state["params"]))
+                  for g in run.groups]
         for a, b in zip(*biases):
             assert np.any(a) and np.array_equal(a, b)
         # the classic path reports the gauges too
-        assert "moe_load_max_over_mean" in first.opt.metrics.snapshot()
-    finally:
-        for g in groups:
-            g.teardown()
-        lighthouse.shutdown()
+        assert "moe_load_max_over_mean" in run.first.opt.metrics.snapshot()
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("kimi_linear")

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_kit as kit
+
 from benchmark.families import laguna as family
 from benchmark.reference import laguna_f32
 from torchft_tpu.models import (
@@ -45,26 +47,8 @@ CFG3 = dataclasses.replace(CFG32, windowed=(0, 1, 0), heads=(4, 6, 4),
                            sparse=(0, 1, 1))
 BIAS = laguna.BALANCE_BIAS
 D, S, E = CFG.d_model, 64, CFG.n_routed_experts
-
-
-def _params(cfg, seed, bias_std=0.1):
-    """Seeded weights with the balance biases away from zero, so that a
-    system that ignored them would route differently."""
-    params = laguna.init_params(cfg, jax.random.key(seed))
-    key = jax.random.key(1000 + seed)
-
-    def leaf(path, x):
-        if path[-1].key != BIAS:
-            return x
-        return bias_std * jax.random.normal(
-            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, S), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
+_params = functools.partial(kit.seeded_params, laguna)
+_batch = kit.batch
 
 
 def _reference(cfg):

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_kit as kit
+
 from benchmark import laguna_flops
 from benchmark.families import laguna as family
 from benchmark.tests import laguna_faults
@@ -29,6 +31,7 @@ CFG32 = dataclasses.replace(CFG, dtype=jnp.float32, windowed=(0, 1, 0),
 BIAS = laguna.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 S = 64
+_batch = kit.batch
 
 
 def _params(cfg, seed):
@@ -36,26 +39,6 @@ def _params(cfg, seed):
     seeds them."""
     return family.seed_balance_bias(
         laguna.init_params(cfg, jax.random.key(seed)), seed)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, S), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _tiny_model(layers=2):
-    """``tiny-laguna.json`` at its first ``layers`` layers (full and
-    dense, then sliding and sparse: the loop's tests compile the step)."""
-    with open(os.path.join(ROOT, "benchmark", "tests",
-                           "tiny-laguna.json")) as f:
-        config = json.load(f)
-    config["num_hidden_layers"] = layers
-    for name in ("layer_types", "mlp_layer_types",
-                 "num_attention_heads_per_layer"):
-        config[name] = config[name][:layers]
-    # a rate that moves the bias visibly within a few steps
-    config["optimizer"]["balance_bias_rate"] = 0.01
-    return family.build(config)
 
 
 @pytest.mark.parametrize("call", family.FLASH_CALLS)
@@ -103,7 +86,7 @@ def test_check_reference_is_all_three_comparisons(monkeypatch) -> None:
     monkeypatch.setattr(family, "HIDDEN_REL_L2_MAX", 0.08)
     monkeypatch.setattr(family, "TOP_K_DISAGREEMENT_MAX", 0.1)
     monkeypatch.setattr(family, "REFERENCE_LOSS_ATOL", 2e-2)
-    model, device = _tiny_model(layers=3), jax.devices()[0]
+    model, device = kit.tiny("laguna", layers=3), jax.devices()[0]
     params = family.init_state(model, 5, device)["params"]
     # the second verdict judges the first's readings again: nothing is
     # compiled twice
@@ -294,49 +277,14 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size; and the
     optimizer wrapper's routing gauges arrive on its sink without a wait
     (read at a later commit than the one that asked)."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    biases = [x for p, x in jax.tree_util.tree_flatten_with_path(params)[0]
-              if p[-1].key == BIAS]
-    assert len(biases) == 1 and all(np.any(b) for b in biases)
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-        for i in range(3, 12):
-            if "moe_held_share" in group.opt.metrics.snapshot():
-                break
-            jax.block_until_ready(group.state)
-            group.step(*source.device_batch(i, device))
-        seen = group.opt.metrics.snapshot()
+    with kit.ft_steps(kit.tiny("laguna")) as run:
+        biases = kit.bias_leaves(run.params)
+        assert len(biases) == 1 and all(np.any(b) for b in biases)
+        seen = kit.routing_gauges(run)
         assert 0.0 < seen["moe_held_share"] < 1.0
         assert seen["moe_load_max_over_mean"] >= 1.0
         assert seen["moe_row_buffer_share"] == 1.0
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("laguna")

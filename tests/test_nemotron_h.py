@@ -14,7 +14,6 @@ one optimizer and the fault-tolerant loop: ``test_nemotron_h_family.py``
 import dataclasses
 import functools
 import hashlib
-import json
 import os
 import re
 
@@ -22,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+import family_kit as kit
 
 from benchmark.families import nemotron_h as family
 from benchmark.reference import nemotron_h_f32
@@ -34,31 +35,8 @@ CFG = nemotron_h.NEMOTRON_H_CONFIGS["nemotron_h_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
 BIAS = nemotron_h.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _params(cfg, seed, bias_std=0.1):
-    """Seeded weights with the balance biases away from zero, so that a
-    system that ignored them would route differently."""
-    params = nemotron_h.init_params(cfg, jax.random.key(seed))
-    key = jax.random.key(1000 + seed)
-
-    def leaf(path, x):
-        if path[-1].key != BIAS:
-            return x
-        return bias_std * jax.random.normal(
-            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, 64), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _bias_leaves(tree):
-    return [x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
-            if getattr(path[-1], "key", None) == BIAS]
+_params = functools.partial(kit.seeded_params, nemotron_h)
+_batch = kit.batch
 
 
 def _chosen(experts, n_routed):
@@ -68,16 +46,6 @@ def _chosen(experts, n_routed):
 def _reference(cfg):
     return functools.partial(nemotron_h_f32.terms,
                              **family.reference_dims(cfg))
-
-
-def _tiny_model(rows=2):
-    with open(os.path.join(ROOT, "benchmark", "tests",
-                           "tiny-nemotron.json")) as f:
-        config = json.load(f)
-    config["job"]["rows"] = rows
-    # a rate that moves the bias visibly within a few steps
-    config["optimizer"]["balance_bias_rate"] = 0.01
-    return family.build(config)
 
 
 # -- against the reference ---------------------------------------------------
@@ -119,7 +87,7 @@ def test_f32_gradients_equal_the_reference() -> None:
     terms = nemotron_h.loss_terms(CFG32, params, tokens, targets)
     assert np.array_equal(got["layers_1"]["moe"][BIAS], terms["loads"][0])
     assert np.array_equal(got["layers_3"]["moe"][BIAS], terms["loads"][1])
-    assert not np.any(_bias_leaves(want))
+    assert not np.any(kit.bias_leaves(want))
     flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
     seen = set()
     for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:

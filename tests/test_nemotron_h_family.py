@@ -13,13 +13,13 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+import family_kit as kit
 
 from benchmark.families import nemotron_h as family
 from benchmark.tests import nemotron_faults
@@ -30,10 +30,8 @@ from test_nemotron_h import (
     CFG32,
     ROOT,
     _batch,
-    _bias_leaves,
     _params,
     _reference,
-    _tiny_model,
 )
 from torchft_tpu.models import nemotron_h
 
@@ -45,7 +43,9 @@ def test_a_fault_fails_the_comparison(monkeypatch, fault) -> None:
     (The sound system agrees to 5e-5: the first test of this file.)"""
     params = _params(CFG32, 5)
     tokens, targets = _batch(5)
-    want = _reference(CFG32)(params, tokens, targets)
+    # the sound side: the plain reference, which no patch reaches, once
+    want = kit.sound(("nemotron_h", "reference", 5), lambda: _reference(
+        CFG32)(params, tokens, targets))
     patches, weights, cfg, attn_fn = nemotron_faults.fault(
         fault, CFG32, params)
     for patch in patches:
@@ -108,7 +108,7 @@ def test_check_reference_is_both_comparisons(monkeypatch) -> None:
     carries the whole model's verdict and the scan's, and is ``ok`` only
     where both are (the tiny configuration, bf16 compute)."""
     monkeypatch.setattr(family, "SCAN_SEQ", 128)
-    model, device = _tiny_model(), jax.devices()[0]
+    model, device = kit.tiny("nemotron_h"), jax.devices()[0]
     params = family.init_state(model, 5, device)["params"]
     seen = family.check_reference(model, params, 5, device)
     assert seen["ok"], seen
@@ -187,7 +187,7 @@ def test_the_warm_up_is_a_schedule_in_the_optimizer_state() -> None:
     """Step ``c`` runs at ``peak·(c + 1)/warm``; the count is a leaf of
     the optimizer state (checkpointed, healed, hashed); vectors and the
     balance bias take no weight decay."""
-    model = _tiny_model()
+    model = kit.tiny("nemotron_h")
     params = nemotron_h.init_params(model.cfg, jax.random.key(0))
     opt = model.tx.init(params)
     counts = [x for x in jax.tree_util.tree_leaves(opt)
@@ -223,41 +223,8 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size, and the bias
     rule on the fused path: behind the commit gate the bias moves exactly
     as in the plain step."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    assert all(np.any(b) for b in _bias_leaves(params))
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
+    with kit.ft_steps(kit.tiny("nemotron_h")) as run:
+        assert all(np.any(b) for b in kit.bias_leaves(run.params))
 
 
 def test_two_groups_on_other_batches_hold_one_state_and_a_healed_one_gets_it():
@@ -268,81 +235,23 @@ def test_two_groups_on_other_batches_hold_one_state_and_a_healed_one_gets_it():
     weights, behind (its count at 0), and gets the first's parameters,
     bias and count only by the heal. At rest on one step the sha256 of
     parameters and optimizer state are equal."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
+    with kit.two_groups_one_healed(kit.tiny("nemotron_h")) as run:
+        def counts(group):
+            return sorted({int(x) for x in jax.tree_util.tree_leaves(
+                jax.device_get(group.state["opt"]))
+                if x.shape == () and np.issubdtype(x.dtype, np.integer)})
 
-    model = _tiny_model()
-    devices = jax.devices()
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                            heartbeat_timeout_ms=5000)
-    stop_at = [None]
-
-    def keep_going(group):
-        return stop_at[0] is None or group.manager.current_step() < stop_at[0]
-
-    groups, threads = [], []
-
-    def start(gid, seed):
-        source = BatchSource(11, gid, 0, model.rows, model.seq_len,
-                             model.vocab_draw)
-        group = ReplicaGroup(gid, 0, model, family, devices[gid], gid,
-                             lighthouse.address(), seed, source)
-        thread = threading.Thread(target=group.run, args=(keep_going,),
-                                  daemon=True)
-        groups.append(group)
-        threads.append(thread)
-        thread.start()
-        return group
-
-    def wait_for(cond, what):
-        deadline = time.monotonic() + 120
-        while not cond():
-            assert all(g.error is None for g in groups), [
-                repr(g.error) for g in groups]
-            assert time.monotonic() < deadline, what
-            time.sleep(0.02)
-
-    def counts(group):
-        return sorted({int(x) for x in jax.tree_util.tree_leaves(
-            jax.device_get(group.state["opt"]))
-            if x.shape == () and np.issubdtype(x.dtype, np.integer)})
-
-    try:
-        first = start(0, 1)
-        wait_for(lambda: first.manager.current_step() >= 2, "solo steps")
-        solo = [r for r in list(first.records) if r["committed"]]
-        assert solo and all(r["path"] == "fused" for r in solo)
-        second = start(1, 2)          # other weights, a zero bias, behind
-        wait_for(lambda: any(r["committed"] for r in list(second.records)),
-                 "the joiner's first commit")
-        stop_at[0] = max(g.manager.current_step() for g in groups) + 3
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-        assert all(g.error is None for g in groups), [g.error for g in groups]
-        jax.block_until_ready([g.state for g in groups])
-        assert any(r["healed"] for r in second.records)
-        both = [r for r in first.records
-                if r["committed"] and r["participants"] == 2]
-        assert len(both) >= 2 and all(r["path"] == "classic" for r in both)
-        assert first.manager.current_step() == second.manager.current_step()
-        assert first.digest() == second.digest()
         # the schedule's count came over with the heal: the joiner made
         # fewer steps than it counts
-        assert counts(first) == counts(second)
-        assert max(counts(second)) > sum(
-            1 for r in second.records if r["committed"])
-        biases = [_bias_leaves(jax.device_get(g.state["params"]))
-                  for g in groups]
+        assert counts(run.first) == counts(run.second)
+        assert max(counts(run.second)) > sum(
+            1 for r in run.second.records if r["committed"])
+        biases = [kit.bias_leaves(jax.device_get(g.state["params"]))
+                  for g in run.groups]
         for a, b in zip(*biases):
             assert np.any(a) and np.array_equal(a, b)
             # whole multiples of the rate: only the sign rule touched it
             assert np.allclose(a / 0.01, np.round(a / 0.01), atol=1e-4)
-    finally:
-        for g in groups:
-            g.teardown()
-        lighthouse.shutdown()
 
 
 def test_the_cells_own_comparison_at_the_small_size() -> None:
@@ -370,7 +279,11 @@ def test_the_cells_own_comparison_at_the_small_size() -> None:
     # the check's own seeding of the bias: other leaves untouched
     seeded = family.seed_balance_bias(params, 3)
     assert seeded["wte"]["embedding"] is params["wte"]["embedding"]
-    assert all(np.any(b) for b in _bias_leaves(seeded))
+    assert all(np.any(b) for b in kit.bias_leaves(seeded))
     again = family.seed_balance_bias(params, 3)
-    for a, b in zip(_bias_leaves(seeded), _bias_leaves(again)):
+    for a, b in zip(kit.bias_leaves(seeded), kit.bias_leaves(again)):
         assert np.array_equal(a, b)
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("nemotron_h")

@@ -27,6 +27,8 @@ from torchft_tpu.models.olmo_hybrid import (
     loss_fn, loss_terms,
 )
 
+# the model's tests are not about how many heads share a grid step
+pytestmark = pytest.mark.usefixtures("one_head_a_step")
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BF16 = OLMO_HYBRID_CONFIGS["olmo_hybrid_tiny"]
 # float32 compute: the comparison is of the mathematics, not of bf16

@@ -14,23 +14,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_kit as kit
+
 from benchmark import olmo_hybrid_flops
 from benchmark.families import olmo_hybrid as family
 from torchft_tpu.models import olmo_hybrid
 from torchft_tpu.models.olmo_hybrid import FULL, LINEAR
 
+# the model's tests are not about how many heads share a grid step
+pytestmark = pytest.mark.usefixtures("one_head_a_step")
 CFG = olmo_hybrid.OLMO_HYBRID_CONFIGS["olmo_hybrid_tiny"]
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _tiny_config():
-    with open(os.path.join(ROOT, "benchmark", "tests",
-                           "tiny-olmo-hybrid.json")) as f:
-        return json.load(f)
-
-
-def _tiny_model():
-    return family.build(_tiny_config())
 
 
 def test_the_family_builds_the_configuration_and_refuses_what_it_cannot():
@@ -59,13 +53,13 @@ def test_the_family_builds_the_configuration_and_refuses_what_it_cannot():
         with pytest.raises(ValueError, match=key):
             family.build(dict(config, **{key: value}))
     # the tiny configuration is the same family at other numbers
-    tiny = _tiny_model()
+    tiny = kit.tiny("olmo_hybrid")
     assert tiny.cfg == dataclasses.replace(CFG, remat=True, xent_chunks=2)
     assert (tiny.rows, tiny.seq_len, tiny.vocab_draw) == (2, 32, 256)
 
 
 def test_the_optimizer_decays_matrices_alone_behind_a_warm_up() -> None:
-    model = _tiny_model()
+    model = kit.tiny("olmo_hybrid")
     params = olmo_hybrid.init_params(model.cfg, jax.random.key(0))
     zero = jax.tree_util.tree_map(jnp.zeros_like, params)
     state = model.tx.init(params)
@@ -125,7 +119,7 @@ def test_check_reference_is_both_comparisons(monkeypatch) -> None:
     monkeypatch.setattr(family, "REFERENCE_LOSS_ATOL", 5e-2)
     monkeypatch.setattr(family, "GDN_REL_L2_MAX",
                         {n: 0.03 for n in family.GDN_LEAVES})
-    model, device = _tiny_model(), jax.devices()[0]
+    model, device = kit.tiny("olmo_hybrid"), jax.devices()[0]
     params = family.init_state(model, 5, device)["params"]
     seen = family.check_reference(model, params, 5, device)
     assert seen["ok"], seen
@@ -146,47 +140,14 @@ def test_check_reference_is_both_comparisons(monkeypatch) -> None:
 
 def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    assert all(np.isfinite(plain)) and len(set(plain)) == 3
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
+    with kit.ft_steps(kit.tiny("olmo_hybrid")) as run:
+        assert all(np.isfinite(run.losses)) and len(set(run.losses)) == 3
 
 
 def test_the_grad_step_is_the_fused_steps_gradient() -> None:
     """The classic path's program (``make_grad_step``) and the fused
     step's see one loss on one batch."""
-    model = _tiny_model()
+    model = kit.tiny("olmo_hybrid")
     device = jax.devices()[0]
     from benchmark.traffic_gen import BatchSource
 
@@ -201,3 +162,7 @@ def test_the_grad_step_is_the_fused_steps_gradient() -> None:
         jax.tree_util.tree_structure(state["params"])
     assert all(g.dtype == jnp.float32 and bool(jnp.all(jnp.isfinite(g)))
                for g in jax.tree_util.tree_leaves(grads))
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("olmo_hybrid")

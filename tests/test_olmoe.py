@@ -7,14 +7,14 @@ fault-tolerant loop."""
 
 import dataclasses
 import functools
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+
+import family_kit as kit
 
 from benchmark.reference import olmoe_f32
 from torchft_tpu.models import CONFIGS, make_grad_step, make_train_step
@@ -382,58 +382,12 @@ def test_microbatched_grad_step_equals_the_whole_batch(family) -> None:
 # -- through the fault-tolerant loop -----------------------------------------
 
 
-def _tiny_model(rows=2):
-    import json
-    import os
-
-    from benchmark.families import olmoe as family
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "tests", "tiny-olmoe.json")
-    with open(path) as f:
-        config = json.load(f)
-    config["job"]["rows"] = rows
-    return family, family.build(config)
-
-
 def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size: Manager +
     OptimizerWrapper.fused_step dispatch the very program the plain
     worker runs."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    family, model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
+    with kit.ft_steps(kit.tiny("olmoe")) as run:
+        assert all(np.isfinite(run.losses))
 
 
 def test_two_groups_on_the_classic_path_one_healed_from_the_other() -> None:
@@ -443,63 +397,11 @@ def test_two_groups_on_the_classic_path_one_healed_from_the_other() -> None:
     and optimizer state are equal: expert-shaped leaves ([8, 64, 32])
     through ddp.py and checkpointing.py, which this family did not
     touch."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    family, model = _tiny_model()
-    devices = jax.devices()
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                            heartbeat_timeout_ms=5000)
-    stop_at = [None]
-
-    def keep_going(group):
-        return stop_at[0] is None or group.manager.current_step() < stop_at[0]
-
-    groups, threads = [], []
-
-    def start(gid, seed):
-        source = BatchSource(11, gid, 0, model.rows, model.seq_len,
-                             model.vocab_draw)
-        group = ReplicaGroup(gid, 0, model, family, devices[gid], gid,
-                             lighthouse.address(), seed, source)
-        thread = threading.Thread(target=group.run, args=(keep_going,),
-                                  daemon=True)
-        groups.append(group)
-        threads.append(thread)
-        thread.start()
-        return group
-
-    def wait_for(cond, what):
-        deadline = time.monotonic() + 120
-        while not cond():
-            assert all(g.error is None for g in groups), [
-                repr(g.error) for g in groups]
-            assert time.monotonic() < deadline, what
-            time.sleep(0.02)
-
-    try:
-        first = start(0, 1)
-        wait_for(lambda: first.manager.current_step() >= 2, "solo steps")
-        second = start(1, 2)          # other weights, two steps behind
-        wait_for(lambda: any(r["committed"] for r in list(second.records)),
-                 "the joiner's first commit")
-        stop_at[0] = max(g.manager.current_step() for g in groups) + 2
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-        assert all(g.error is None for g in groups), [g.error for g in groups]
-        jax.block_until_ready([g.state for g in groups])
-        assert any(r["healed"] for r in second.records)
-        both = [r for r in first.records
-                if r["committed"] and r["participants"] == 2]
-        assert both and all(r["path"] == "classic" for r in both)
-        assert first.manager.current_step() == second.manager.current_step()
-        assert first.digest() == second.digest()
-        losses = jax.device_get([r["loss"] for g in groups
+    with kit.two_groups_one_healed(kit.tiny("olmoe"), tail=2) as run:
+        losses = jax.device_get([r["loss"] for g in run.groups
                                  for r in g.records if r["committed"]])
         assert all(np.isfinite(float(x)) for x in losses)
-    finally:
-        for g in groups:
-            g.teardown()
-        lighthouse.shutdown()
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("olmoe")

@@ -156,6 +156,48 @@ def test_fast_quorum_includes_new_joiner() -> None:
     assert [m["replica_id"] for m in q] == ["a", "c"]
 
 
+@pytest.mark.parametrize(
+    "asked, expect, why",
+    [
+        # (joined_ms of a, b, c; None = heartbeats and has not asked)
+        pytest.param((9990, None, 5000), ["a", "c"], "Valid quorum",
+                     id="left_out_member_and_first_asker_two_wide_at_once"),
+        pytest.param((9990, 9980, 5000), ["a", "b", "c"], "Fast quorum",
+                     id="both_previous_members_asked_fast_three_wide"),
+        pytest.param((9990, None, 9950), None, "stragglers",
+                     id="left_out_member_waited_less_than_the_timeout"),
+        pytest.param((9950, 5000, 9990), ["a", "b", "c"], "Fast quorum",
+                     id="all_three_asked_in_another_order_three_wide"),
+    ],
+)
+def test_the_wait_for_a_straggler_counts_from_the_longest_waiting_member(
+    asked, expect, why
+) -> None:
+    """The rule a kill-and-heal test ran into (tests/test_sharded_e2e.py,
+    PR 61): the previous quorum is {a, b}, and ``c`` was left out of it.
+    ``first_joined`` is the minimum over ALL healthy participants
+    (``native/quorum.cc``), so once ``c`` has waited ``join_timeout_ms``
+    it forms a quorum with the FIRST previous member that asks, with no
+    grace for the other; three groups whose members ask further apart
+    than the timeout therefore rotate through two-wide quorums
+    (docs/operations.md, "join_timeout_ms"; ROADMAP.md S5a). The other
+    three cases fence that one."""
+    opts = dict(OPTS, join_timeout_ms=200)
+    prev = {
+        "quorum_id": 1,
+        "participants": [member("a"), member("b")],
+        "created_ms": 0,
+    }
+    participants = [
+        (joined, member(r)) for r, joined in zip("abc", asked)
+        if joined is not None
+    ]
+    heartbeats = {"a": 9990, "b": 9990, "c": 9990}
+    q, reason = quorum_compute(10000, participants, heartbeats, prev, opts)
+    assert why in reason, reason
+    assert (q and sorted(m["replica_id"] for m in q)) == expect
+
+
 def test_shrink_only_restricts_to_prev_members() -> None:
     # shrink_only drops non-prev-members from candidates
     # (ref lighthouse.rs:825-910).

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference.phi4flash_f32 import selective_scan
+from one_program import value_and_pullback
 from torchft_tpu.ops import s6
 from torchft_tpu.ops.s6 import _choose_chunk, _lane_block, s6_scan
 
@@ -67,16 +68,21 @@ def _run(case):
     if case[0] not in _RUNS:
         _, rows, s, c, n, chunk, dt_scale, a_scale = case
         args, dy = inputs(len(case[0]), rows, s, c, n, dt_scale, a_scale)
-        want, pull = jax.vjp(recurrence, *args)
-        got, pull_got = jax.vjp(lambda *a: scan(*a, chunk=chunk), *args)
+        want, grads_want = value_and_pullback(recurrence, args, dy)
+        got, grads = value_and_pullback(
+            lambda *a: scan(*a, chunk=chunk), args, dy)
         _RUNS[case[0]] = {"y": (got, want),
-                          **dict(zip(LEAVES, zip(pull_got(dy), pull(dy))))}
+                          **dict(zip(LEAVES, zip(grads, grads_want)))}
     return _RUNS[case[0]]
 
 
 @pytest.mark.parametrize("leaf", ["y"] + LEAVES)
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
 def test_scan_equals_the_recurrence(case, leaf):
+    """A case's name is the boundary it crosses (chunks, a padded end,
+    lane blocks, an underflowing decay, a state carried over three
+    boundaries) at S 32 - 64; a case runs once for its seven leaves, each
+    side one program (``tests/one_program.py``)."""
     got, want = _run(case)[leaf]
     assert got.shape == want.shape
     assert np.all(np.isfinite(np.asarray(got)))
@@ -99,9 +105,10 @@ def test_last_position_needs_the_carried_state():
 
 def test_result_does_not_depend_on_the_chunk():
     args, dy = inputs(11, 1, 64, 128, 16)
-    runs = [jax.vjp(lambda *a: scan(*a, chunk=q), *args) for q in (16, 64)]
+    runs = [value_and_pullback(lambda *a: scan(*a, chunk=q), args, dy)
+            for q in (16, 64)]
     np.testing.assert_allclose(runs[0][0], runs[1][0], atol=1e-4, rtol=1e-4)
-    for a, b in zip(runs[0][1](dy), runs[1][1](dy)):
+    for a, b in zip(runs[0][1], runs[1][1]):
         np.testing.assert_allclose(
             a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))), rtol=1e-4)
 

@@ -42,20 +42,42 @@ def _fetch(url: str, timeout: float = 10.0) -> dict:
 
 
 class _Harness:
-    """Ends the run on the EVENT the tests are about, not on a count of
-    steps the restart has to beat: the killed replica's next incarnation
-    has committed a step in which all ``num_replicas`` took part (and
-    everyone is past ``total_steps``). The survivors keep stepping until
-    then, however long the restart takes on a loaded machine; the only
-    clock is :func:`_run_to_the_end`'s deadline for a hung run."""
+    """Moves the run along on the EVENTS the tests are about, not on
+    counts of steps or on times a loaded machine has to beat. The kill
+    comes once the victim has itself committed at full width
+    (:meth:`at_full_width`); the restart comes once a survivor has
+    committed a step one narrower AFTER the kill (``shrunk``: the shrink
+    has happened, whatever the lighthouse's timeouts are); the run ends
+    when the next incarnation has committed a step in which all
+    ``num_replicas`` took part (and everyone is past ``total_steps``).
+    The survivors keep stepping until then; the only clock is
+    :func:`_run_to_the_end`'s deadline for a hung run."""
 
     def __init__(self, num_replicas: int, total_steps: int) -> None:
         self.num_replicas = num_replicas
         self.total_steps = total_steps
         self.stop = threading.Event()
+        self.shrunk = threading.Event()
         self.progress: Dict[int, int] = {}
         self.rejoined_at_full_width = False
+        self._full_width: set = set()
+        self._killed = False
         self._lock = threading.Lock()
+
+    def at_full_width(self, replica_id: int) -> bool:
+        """Has this replica committed a step all ``num_replicas`` took
+        part in?"""
+        with self._lock:
+            return replica_id in self._full_width
+
+    def killed(self) -> None:
+        with self._lock:
+            self._killed = True
+
+    def end(self) -> None:
+        """Releases every loop and every waiter."""
+        self.stop.set()
+        self.shrunk.set()
 
     def report(self, replica_id: int, step: int, restarted: bool,
                participants: int) -> None:
@@ -63,6 +85,11 @@ class _Harness:
             self.progress[replica_id] = max(
                 self.progress.get(replica_id, 0), step
             )
+            if participants == self.num_replicas:
+                self._full_width.add(replica_id)
+            if (self._killed and not restarted
+                    and participants == self.num_replicas - 1):
+                self.shrunk.set()
             if restarted and participants == self.num_replicas:
                 self.rejoined_at_full_width = True
             if (
@@ -71,7 +98,25 @@ class _Harness:
                 and all(s >= self.total_steps
                         for s in self.progress.values())
             ):
-                self.stop.set()
+                self.end()
+
+
+def _lighthouse() -> Lighthouse:
+    """The lighthouse of both tests. ``join_timeout_ms`` is how long a
+    quorum waits for a healthy member that has not asked yet. Three
+    groups whose members ask further apart than that rotate through
+    two-wide quorums for ever (``native/quorum.cc``: the wait is counted
+    from the LONGEST-waiting member, so the one left out last time forms
+    a quorum with the first other that asks, and the third heals into
+    the next: docs/operations.md, "join_timeout_ms"); a healer on a CPU
+    under six test workers asks hundreds of milliseconds after its
+    donors. 4 s is above any heal seen here and below the managers' 5 s
+    quorum time-out. It no longer decides whether the shrink happens:
+    the dead incarnation leaves by its heartbeat (1 s) or the
+    lighthouse's knock, and the restart waits for the shrink."""
+    return Lighthouse(
+        min_replicas=1, join_timeout_ms=4000, heartbeat_timeout_ms=1000
+    )
 
 
 def _run_to_the_end(replicas: List["_Replica"], harness: _Harness,
@@ -88,7 +133,7 @@ def _run_to_the_end(replicas: List["_Replica"], harness: _Harness,
                 for f in futs:
                     f.result(timeout=max(1.0, deadline - time.monotonic()))
             finally:
-                harness.stop.set()
+                harness.end()
     finally:
         lighthouse.shutdown()
 
@@ -119,14 +164,13 @@ class _Replica:
             except InjectedFailure:
                 logger.warning("replica %s restarting after injected kill",
                                self.replica_id)
-                # A killed host is not back within the survivors' join
-                # window (200 ms here). The teardown in _main usually
-                # takes 0.5 s (the checkpoint server's poll interval) and
-                # that alone kept the restart out of it; when it happens
-                # to take 10 ms the new incarnation lands in the SAME
-                # quorum as the survivors' next step and the lifecycle
-                # under test (shrink, then rejoin) never occurs.
-                self.harness.stop.wait(0.5)
+                # A killed host is not back before the survivors have
+                # gone on without it: the next incarnation starts when
+                # one of them has committed a step two wide. Started
+                # earlier it can land in the SAME quorum as the
+                # survivors' next step, and the lifecycle under test
+                # (shrink, then rejoin) never occurs.
+                self.harness.shrunk.wait()
                 continue
             except RuntimeError as e:
                 # the failure-after-vote window: restart + heal
@@ -201,8 +245,10 @@ class _Replica:
                     self.fail_at_step is not None
                     and self.failures == 0
                     and manager.current_step() >= self.fail_at_step
+                    and self.harness.at_full_width(self.replica_id)
                 ):
                     self.failures += 1
+                    self.harness.killed()
                     raise InjectedFailure(
                         f"injected kill of replica {self.replica_id}"
                     )
@@ -242,9 +288,7 @@ def _events_of(dump: dict) -> List[dict]:
 
 
 def test_sharded_kill_shrink_rejoin_lifecycle() -> None:
-    lighthouse = Lighthouse(
-        min_replicas=1, join_timeout_ms=200, heartbeat_timeout_ms=1000
-    )
+    lighthouse = _lighthouse()
     harness = _Harness(num_replicas=3, total_steps=8)
     replicas = [
         _Replica(0, lighthouse.address(), harness, fail_at_step=3),
@@ -353,9 +397,7 @@ def test_sharded_2d_kill_shrink_rejoin_lower_bound() -> None:
     from torchft_tpu.ddp import shard_ranges
 
     M = 2
-    lighthouse = Lighthouse(
-        min_replicas=1, join_timeout_ms=200, heartbeat_timeout_ms=1000
-    )
+    lighthouse = _lighthouse()
     harness = _Harness(num_replicas=3, total_steps=8)
     replicas = [
         _Replica(0, lighthouse.address(), harness, fail_at_step=3,

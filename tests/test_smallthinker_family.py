@@ -10,13 +10,13 @@ import dataclasses
 import functools
 import json
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+import family_kit as kit
 
 from benchmark import smallthinker_flops
 from benchmark.families import smallthinker as family
@@ -30,52 +30,13 @@ CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
 BIAS = smallthinker.BALANCE_BIAS
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 D, S, E = CFG.d_model, 64, CFG.n_routed_experts
-
-
-def _params(cfg, seed, bias_std=0.1):
-    """Seeded weights with the balance biases away from zero, so that a
-    system that ignored them would route differently."""
-    params = smallthinker.init_params(cfg, jax.random.key(seed))
-    key = jax.random.key(1000 + seed)
-
-    def leaf(path, x):
-        if path[-1].key != BIAS:
-            return x
-        return bias_std * jax.random.normal(
-            jax.random.fold_in(key, len(jax.tree_util.keystr(path))), x.shape)
-
-    return jax.tree_util.tree_map_with_path(leaf, params)
-
-
-def _batch(seed, rows=2):
-    tokens = jax.random.randint(jax.random.key(100 + seed), (rows, S), 0, 512)
-    return tokens, jnp.roll(tokens, -1, axis=1)
-
-
-def _bias_leaves(tree):
-    return [x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
-            if getattr(path[-1], "key", None) == BIAS]
+_params = functools.partial(kit.seeded_params, smallthinker)
+_batch = kit.batch
 
 
 def _reference(cfg):
     return functools.partial(smallthinker_f32.terms,
                              **family.reference_dims(cfg))
-
-
-def _tiny_model(rows=2, layers=4):
-    """``tiny-smallthinker.json`` at its first ``layers`` layers (one
-    period: the loop's tests compile the step, and a period is every
-    kind of layer)."""
-    with open(os.path.join(ROOT, "benchmark", "tests",
-                           "tiny-smallthinker.json")) as f:
-        config = json.load(f)
-    config["job"]["rows"] = rows
-    config["num_hidden_layers"] = layers
-    for name in ("sliding_window_layout", "rope_layout"):
-        config[name] = config[name][:layers]
-    # a rate that moves the bias visibly within a few steps
-    config["optimizer"]["balance_bias_rate"] = 0.01
-    return family.build(config)
 
 
 def test_the_cells_own_check_of_the_windowed_call() -> None:
@@ -112,7 +73,7 @@ def test_check_reference_is_both_comparisons(monkeypatch) -> None:
     monkeypatch.setattr(family, "HIDDEN_REL_L2_MAX", 0.08)
     monkeypatch.setattr(family, "TOP_K_DISAGREEMENT_MAX", 0.1)
     monkeypatch.setattr(family, "REFERENCE_LOSS_ATOL", 2e-2)
-    model, device = _tiny_model(), jax.devices()[0]
+    model, device = kit.tiny("smallthinker"), jax.devices()[0]
     params = family.init_state(model, 5, device)["params"]
     seen = family.check_reference(model, params, 5, device)
     assert seen["ok"], seen
@@ -146,7 +107,7 @@ def test_the_cells_own_comparison_at_the_small_size() -> None:
         CFG32, params, params, tokens, targets, system_cfg=other))["ok"]
     seeded = family.seed_balance_bias(params, 3)
     assert seeded["wte"]["embedding"] is params["wte"]["embedding"]
-    assert all(np.any(b) for b in _bias_leaves(seeded))
+    assert all(np.any(b) for b in kit.bias_leaves(seeded))
 
 
 # -- the family, the optimizer and the fault-tolerant loop --------------------
@@ -251,7 +212,7 @@ def test_the_warm_up_is_a_schedule_and_the_rule_keeps_the_loads() -> None:
     of the optimizer state; matrices take weight decay, norms none; the
     bias rule's state is the loads it last saw — no moments — and
     ``routing_gauges`` reads the held share and the skew from it."""
-    model = _tiny_model()
+    model = kit.tiny("smallthinker")
     params = smallthinker.init_params(model.cfg, jax.random.key(0))
     opt = model.tx.init(params)
     counts = [x for x in jax.tree_util.tree_leaves(opt)
@@ -289,50 +250,12 @@ def test_three_ft_steps_equal_three_plain_steps_bit_for_bit() -> None:
     """The cell's ``plain_worker`` check at the small size; and the
     optimizer wrapper's routing gauges arrive on its sink without a wait
     (read at a later commit than the one that asked)."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    device = jax.devices()[0]
-    source = BatchSource(7, 0, 0, model.rows, model.seq_len, model.vocab_draw)
-    train_step = family.make_train_step(model)
-    state = family.init_state(model, 7, device)
-    params, opt = state["params"], state["opt"]
-    plain = []
-    for i in range(3):
-        params, opt, loss = train_step(params, opt,
-                                       *source.device_batch(i, device))
-        plain.append(float(loss))
-    assert all(np.any(b) for b in _bias_leaves(params))
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=100)
-    group = None
-    try:
-        group = ReplicaGroup(0, 0, model, family, device, 0,
-                             lighthouse.address(), 7, source,
-                             train_step=train_step)
-        records = [group.step(*source.device_batch(i, device))
-                   for i in range(3)]
-        assert all(r["committed"] and r["path"] == "fused" for r in records)
-        assert [float(r["loss"]) for r in records] == plain
-        for a, b in zip(jax.tree_util.tree_leaves(group.state),
-                        jax.tree_util.tree_leaves({"params": params,
-                                                   "opt": opt})):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
-        assert train_step._cache_size() == 1
-        for i in range(3, 12):
-            if "moe_held_share" in group.opt.metrics.snapshot():
-                break
-            jax.block_until_ready(group.state)
-            group.step(*source.device_batch(i, device))
-        seen = group.opt.metrics.snapshot()
+    with kit.ft_steps(kit.tiny("smallthinker")) as run:
+        assert all(np.any(b) for b in kit.bias_leaves(run.params))
+        seen = kit.routing_gauges(run)
         assert 0.0 < seen["moe_held_share"] < 1.0
         assert seen["moe_load_max_over_mean"] >= 1.0
         assert seen["moe_row_buffer_share"] == 1.0
-    finally:
-        if group is not None:
-            group.teardown()
-        lighthouse.shutdown()
 
 
 def test_a_healed_groups_digest_equals_its_donors() -> None:
@@ -341,66 +264,14 @@ def test_a_healed_groups_digest_equals_its_donors() -> None:
     and gets the first's parameters, bias, loads and count only by the
     heal. At rest on one step the sha256 of parameters and optimizer
     state are equal."""
-    from benchmark.group import ReplicaGroup
-    from benchmark.traffic_gen import BatchSource
-    from torchft_tpu.control import Lighthouse
-
-    model = _tiny_model()
-    devices = jax.devices()
-    lighthouse = Lighthouse(min_replicas=1, join_timeout_ms=200,
-                            heartbeat_timeout_ms=5000)
-    stop_at = [None]
-
-    def keep_going(group):
-        return stop_at[0] is None or group.manager.current_step() < stop_at[0]
-
-    groups, threads = [], []
-
-    def start(gid, seed):
-        source = BatchSource(11, gid, 0, model.rows, model.seq_len,
-                             model.vocab_draw)
-        group = ReplicaGroup(gid, 0, model, family, devices[gid], gid,
-                             lighthouse.address(), seed, source)
-        thread = threading.Thread(target=group.run, args=(keep_going,),
-                                  daemon=True)
-        groups.append(group)
-        threads.append(thread)
-        thread.start()
-        return group
-
-    def wait_for(cond, what):
-        deadline = time.monotonic() + 120
-        while not cond():
-            assert all(g.error is None for g in groups), [
-                repr(g.error) for g in groups]
-            assert time.monotonic() < deadline, what
-            time.sleep(0.02)
-
-    try:
-        first = start(0, 1)
-        wait_for(lambda: first.manager.current_step() >= 2, "solo steps")
-        second = start(1, 2)          # other weights, a zero bias, behind
-        wait_for(lambda: any(r["committed"] for r in list(second.records)),
-                 "the joiner's first commit")
-        stop_at[0] = max(g.manager.current_step() for g in groups) + 3
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-        assert all(g.error is None for g in groups), [g.error for g in groups]
-        jax.block_until_ready([g.state for g in groups])
-        assert any(r["healed"] for r in second.records)
-        both = [r for r in first.records
-                if r["committed"] and r["participants"] == 2]
-        assert len(both) >= 2 and all(r["path"] == "classic" for r in both)
-        assert first.manager.current_step() == second.manager.current_step()
-        assert first.digest() == second.digest()
-        biases = [_bias_leaves(jax.device_get(g.state["params"]))
-                  for g in groups]
+    with kit.two_groups_one_healed(kit.tiny("smallthinker")) as run:
+        biases = [kit.bias_leaves(jax.device_get(g.state["params"]))
+                  for g in run.groups]
         for a, b in zip(*biases):
             assert np.any(a) and np.array_equal(a, b)
         # the classic path reports the gauges too
-        assert "moe_load_max_over_mean" in first.opt.metrics.snapshot()
-    finally:
-        for g in groups:
-            g.teardown()
-        lighthouse.shutdown()
+        assert "moe_load_max_over_mean" in run.first.opt.metrics.snapshot()
+
+
+def test_the_loop_scenarios_built_one_step_program() -> None:
+    kit.assert_built_once("smallthinker")

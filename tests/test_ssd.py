@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from one_program import value_and_pullback
 from torchft_tpu.ops import ssd
 from torchft_tpu.ops.ssd import _choose_chunk, _heads_per_block, ssd_scan
 
@@ -75,16 +76,24 @@ CASES = [
 
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
 def test_scan_equals_the_recurrence(case):
+    """A case's name is the boundary it crosses, at the least size that
+    does: one chunk, four chunks (three boundaries), a padded end (40 of
+    48), a chunk chosen from the shape, one / eight heads a group, two lane
+    blocks a group (P 64), a decay that underflows within a chunk, a state
+    that three boundaries must carry. S is 24 - 64 and what a case costs is
+    compiling its kernels and the recurrence's scan: each side is one
+    program (``tests/one_program.py``)."""
     _, s, h, g, p, n, chunk, dt_scale, a_scale = case
     # two rows in one case: the interpreter's time goes with the grid
     rows = 2 if case[0] == "four-chunks" else 1
     args, dy = inputs(len(case[0]), rows, s, h, g, p, n, dt_scale, a_scale)
-    want, pull = jax.vjp(recurrence, *args)
-    got, pull_got = jax.vjp(lambda *a: scan(*a, chunk=chunk), *args)
+    want, grads_want = value_and_pullback(recurrence, args, dy)
+    got, grads = value_and_pullback(
+        lambda *a: scan(*a, chunk=chunk), args, dy)
     assert np.all(np.isfinite(np.asarray(got)))
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(got, want, atol=2e-5 * scale, rtol=2e-5)
-    for name, a, b in zip("x dt A B C D".split(), pull_got(dy), pull(dy)):
+    for name, a, b in zip("x dt A B C D".split(), grads, grads_want):
         assert np.all(np.isfinite(np.asarray(a))), name
         # dA is the sum over a chunk's positions of differences of O(1)
         # terms (dcum); where every decay underflows the true value is
@@ -111,9 +120,10 @@ def test_last_position_needs_the_carried_state():
 @pytest.mark.parametrize("chunks", [(8, 64), (16, 32)])
 def test_result_does_not_depend_on_the_chunk(chunks):
     args, dy = inputs(11, 1, 64, 4, 2, 8, 16)
-    runs = [jax.vjp(lambda *a: scan(*a, chunk=q), *args) for q in chunks]
+    runs = [value_and_pullback(lambda *a: scan(*a, chunk=q), args, dy)
+            for q in chunks]
     np.testing.assert_allclose(runs[0][0], runs[1][0], atol=1e-4, rtol=1e-4)
-    for a, b in zip(runs[0][1](dy), runs[1][1](dy)):
+    for a, b in zip(runs[0][1], runs[1][1]):
         np.testing.assert_allclose(
             a, b, atol=1e-4 * float(jnp.max(jnp.abs(b))), rtol=1e-4)
 

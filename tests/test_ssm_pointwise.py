@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from one_program import value_and_pullback
 from torchft_tpu.ops import ssm_pointwise as sp
 
 
@@ -209,10 +210,12 @@ CONV_CASES = [
 def test_conv_silu_equals_the_formula(case):
     name, b, s, c, k, blocks = case
     x, taps, bias, dy = conv_inputs(len(name), b, s, c, k)
-    want, pull = jax.vjp(conv_silu_formula, x, taps, bias)
-    got, pull_got = jax.vjp(lambda *a: conv(*a, blocks=blocks), x, taps, bias)
+    want, grads_want = value_and_pullback(
+        conv_silu_formula, (x, taps, bias), dy)
+    got, grads = value_and_pullback(
+        lambda *a: conv(*a, blocks=blocks), (x, taps, bias), dy)
     assert_close(got, want, 2e-6, "value")
-    for leaf, g, w in zip(("x", "taps", "bias"), pull_got(dy), pull(dy)):
+    for leaf, g, w in zip(("x", "taps", "bias"), grads, grads_want):
         assert g.dtype == w.dtype and g.shape == w.shape, leaf
         assert_close(g, w, 1e-5, leaf)
 
@@ -236,11 +239,12 @@ GATED_CASES = [
 def test_gated_conv_equals_the_formula(case):
     name, b, s, c, k, blocks = case
     bcx, taps, dy = gated_inputs(len(name), b, s, c, k)
-    want, pull = jax.vjp(gated_conv_formula, bcx, taps)
-    got, pull_got = jax.vjp(lambda *a: gconv(*a, blocks=blocks), bcx, taps)
+    want, (w_bcx, w_taps) = value_and_pullback(
+        gated_conv_formula, (bcx, taps), dy)
+    got, (d_bcx, d_taps) = value_and_pullback(
+        lambda *a: gconv(*a, blocks=blocks), (bcx, taps), dy)
     assert got.shape == (b, s, c)
     assert_close(got, want, 2e-6, "value")
-    (d_bcx, d_taps), (w_bcx, w_taps) = pull_got(dy), pull(dy)
     assert d_bcx.dtype == w_bcx.dtype and d_bcx.shape == w_bcx.shape
     # the one cotangent array, a third at a time: dB, dC, dX
     for i, leaf in enumerate(("dB", "dC", "dX")):
@@ -316,12 +320,12 @@ GATE_CASES = [
 def test_gated_norm_equals_the_formula(case):
     name, b, s, width, groups, blocks = case
     y, z, scale, dout = gate_inputs(len(name), b, s, width)
-    want, pull = jax.vjp(
-        lambda *a: gated_norm_formula(*a, groups, 1e-5), y, z, scale)
-    got, pull_got = jax.vjp(
-        lambda *a: gate(*a, groups, blocks=blocks), y, z, scale)
+    want, grads_want = value_and_pullback(
+        lambda *a: gated_norm_formula(*a, groups, 1e-5), (y, z, scale), dout)
+    got, grads = value_and_pullback(
+        lambda *a: gate(*a, groups, blocks=blocks), (y, z, scale), dout)
     assert_close(got, want, 2e-6, "value")
-    for leaf, g, w in zip(("y", "z", "scale"), pull_got(dout), pull(dout)):
+    for leaf, g, w in zip(("y", "z", "scale"), grads, grads_want):
         assert g.dtype == w.dtype and g.shape == w.shape, leaf
         assert_close(g, w, 1e-5, leaf)
 
@@ -404,15 +408,15 @@ def test_kda_qkg_equals_the_formula(case):
     name, b, s, h, d, blocks = case
     (qkv, f, dt_bias, a_log), cots = qkg_inputs(len(name), b, s, h, d)
     qkv = qkv.at[0, 3, :d].set(0.0)
-    want, pull = jax.vjp(kda_qkg_formula, qkv, f, dt_bias, a_log)
-    got, pull_got = jax.vjp(
-        lambda *a: qkg(*a, blocks=blocks), qkv, f, dt_bias, a_log)
+    want, (w_qkv, *wants) = value_and_pullback(
+        kda_qkg_formula, (qkv, f, dt_bias, a_log), cots)
+    got, (d_qkv, *grads) = value_and_pullback(
+        lambda *a: qkg(*a, blocks=blocks), (qkv, f, dt_bias, a_log), cots)
     for leaf, g, w in zip(("q", "k", "v", "g"), got, want):
         assert g.dtype == w.dtype and g.shape == w.shape == f.shape, leaf
         assert_close(g, w, 2e-6, leaf)
     assert not np.any(np.asarray(got[0][0, 3, :d]))
     # the one cotangent array, a third at a time: dq̃, dk̃, dv
-    (d_qkv, *grads), (w_qkv, *wants) = pull_got(cots), pull(cots)
     assert d_qkv.dtype == w_qkv.dtype and d_qkv.shape == w_qkv.shape
     w = h * d
     for i, leaf in enumerate(("dq~", "dk~", "dv")):
@@ -429,12 +433,12 @@ def test_kda_ogate_equals_the_formula(case):
     name, b, s, h, d, blocks = case
     o, gate, _, dy = gate_inputs(len(name), b, s, h * d)
     scale = jnp.linspace(0.5, 1.5, d)
-    want, pull = jax.vjp(
-        lambda *a: kda_ogate_formula(*a, 1e-5), o, gate, scale)
-    got, pull_got = jax.vjp(
-        lambda *a: ogate(*a, blocks=blocks), o, gate, scale)
+    want, grads_want = value_and_pullback(
+        lambda *a: kda_ogate_formula(*a, 1e-5), (o, gate, scale), dy)
+    got, grads = value_and_pullback(
+        lambda *a: ogate(*a, blocks=blocks), (o, gate, scale), dy)
     assert_close(got, want, 2e-6, "value")
-    for leaf, g, w in zip(("o", "gate", "scale"), pull_got(dy), pull(dy)):
+    for leaf, g, w in zip(("o", "gate", "scale"), grads, grads_want):
         assert g.dtype == w.dtype and g.shape == w.shape, leaf
         assert_close(g, w, 1e-5, leaf)
 
