@@ -8,7 +8,9 @@ heads on 2 key/value heads of 32 (groups of 2 and 3), a window of 20 keys
 in S 64, a YaRN rotation over half a head beside a plain one, 8 routed
 experts of width 24 of which 4 are held, top 2, seeded random weights.
 Also the rotation helper of models/common.py against the formulas written
-out, and the pins of the rotated programs that were there before it. The
+out, the pins of the rotated programs that were there before it, and the
+mixer's kernels (ops/ssm_pointwise.py: the rotation and the gate a head on
+either side of the attention call) against the jnp mixer the model had. The
 family (benchmark/families/laguna.py), the optimizer and the
 fault-tolerant loop are tests/test_laguna_family.py's."""
 
@@ -37,6 +39,7 @@ from torchft_tpu.models import (
     smallthinker,
 )
 from torchft_tpu.ops.attention import causal_attention
+from torchft_tpu.utils.metrics import TRACED
 
 CFG = laguna.LAGUNA_CONFIGS["laguna_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
@@ -294,7 +297,10 @@ def _layer_and_stream(seed, index=1, held=None):
 
 def _attend(layer, x, windowed, attn_fn=causal_attention):
     with jax.default_matmul_precision("highest"):
-        return laguna._attn_mixer(CFG32, windowed, layer, x, attn_fn=attn_fn)
+        return laguna._attn_mixer(
+            CFG32, windowed, layer, x,
+            laguna.rotation_tables(CFG32, x.shape[1])[windowed],
+            attn_fn=attn_fn)
 
 
 def test_a_sliding_position_sees_its_last_window_keys_and_no_more() -> None:
@@ -424,10 +430,113 @@ def test_the_shares_add_up_to_the_uncut_layer(split) -> None:
             for name in ("gate_proj", "up_proj", "down_proj"):
                 share["moe"][name] = {"kernel": full["moe"][name]["kernel"][
                     first:first + held]}
-            out, rec = laguna._layer(cfg, True, True, share, x,
+            out, rec = laguna._layer(
+                cfg, True, True, share, x,
+                laguna.rotation_tables(cfg, x.shape[1])[True],
                                      attn_fn=causal_attention)
             total = total + (out - alike)       # this share's routed part
             first += held
         assert first == E
     np.testing.assert_allclose(total + alike, want, atol=3e-5)
     assert float(jnp.max(jnp.abs(want - alike))) > 0.05
+
+
+# -- the kernels on either side of the attention call --------------------------
+
+# full and dense, then sliding and sparse, at the cell's precision: both
+# rotations and both head counts
+CFG2 = dataclasses.replace(CFG, windowed=(0, 1), heads=(4, 6), sparse=(0, 1))
+
+
+def _jnp_mixer(cfg, windowed, layer, x, table, *, attn_fn):
+    """``laguna._attn_mixer`` as it was until PR 62, kept as the form the
+    kernels are held to: ``common.rotary`` on ``[B, S, H, D]`` with its own
+    table a call, and the gate as an f32 multiply with a cast each way."""
+    a, dt = layer["attn"], cfg.dtype
+    B, S, _ = x.shape
+    KV, D = cfg.n_kv_heads, cfg.head_dim
+    H = a["gate"]["kernel"].shape[-1]
+    rot = cfg.rope_swa if windowed else cfg.rope_full
+    n32 = common.rms_norm(x.astype(jnp.float32), layer["norm_1"]["scale"],
+                          cfg.rms_eps)
+    n = n32.astype(dt)
+    q = (n @ a["q_proj"]["kernel"].astype(dt)).reshape(B, S, H, D)
+    k = (n @ a["k_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
+    v = (n @ a["v_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
+    freqs = jnp.asarray(laguna.rotation_freqs(rot, D))
+    q = common.rotary(q, freqs, rot.attention_factor)
+    k = common.rotary(k, freqs, rot.attention_factor)
+    o = attn_fn(q, k, v, window=cfg.window if windowed else None)
+    gate = laguna.head_gate(n32, a["gate"]["kernel"])
+    o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+    return x + o.reshape(B, S, H * D) @ a["o_proj"]["kernel"].astype(dt)
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_and_gradients(mixer=None):
+    """``(loss, gradients)`` of ``CFG2`` on seed 7 as one program, with
+    ``mixer`` in ``_attn_mixer``'s place while it is traced."""
+    params, (tokens, targets) = _params(CFG2, 7), _batch(7)
+    patch = pytest.MonkeyPatch()
+    if mixer is not None:
+        patch.setattr(laguna, "_attn_mixer", mixer)
+    try:
+        return jax.jit(jax.value_and_grad(
+            lambda p: laguna.loss_fn(CFG2, p, tokens, targets)))(params)
+    finally:
+        patch.undo()
+
+
+def test_the_mixers_kernels_give_the_jnp_mixers_loss_and_gradients() -> None:
+    """The rotation's and the gate's kernels compute what the jnp passes
+    computed, in bf16 with f32 inside: the loss and every gradient leaf of
+    two layers. Not to the bit in one program — where the CPU's compiler
+    fuses a product into the rotation's sum on one side, an element in some
+    thousands of q, k or their cotangents rounds the other way
+    (tests/test_ssm_pointwise.py), and a leaf downstream of it moves by a
+    place of bf16 (read: the loss equal, the leaves within 2^-8 of their
+    largest element; on the chip the kernels' results are the jnp form's
+    bits) — a wrong rotation or gate moves them by tenths."""
+    loss, grads = _loss_and_gradients()
+    want, wants = _loss_and_gradients(_jnp_mixer)
+    assert float(loss) == pytest.approx(float(want), rel=2e-4)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == len(jax.tree_util.tree_leaves(wants)) == (
+        2 * 7 + 3 + 8 + 3)
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wants)):
+        name = jax.tree_util.keystr(path)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        if path[-1].key == BIAS:
+            np.testing.assert_array_equal(g, w, err_msg=name)   # the loads
+            continue
+        scale = float(jnp.max(jnp.abs(w)))
+        assert scale > 0, name
+        np.testing.assert_allclose(g, w, atol=1e-2 * scale, err_msg=name)
+
+
+def test_the_mixers_kernels_are_counted_and_the_gate_stays_a_seam(
+        monkeypatch) -> None:
+    """A forward trace of the five layers engages the gate's kernel five
+    times and the rotation's ten (q and k a layer), whatever stands in
+    the attention's place; and what the gate IS remains
+    ``laguna.head_gate``, which ``benchmark/tests/laguna_faults.py``
+    patches by name: ones in its place change the loss."""
+    names = ("head_gate_kernel_calls", "rotary_kernel_calls")
+
+    def seen():
+        snap = TRACED.snapshot()
+        return np.array([snap.get(n, 0) for n in names])
+
+    shapes = jax.eval_shape(
+        lambda: laguna.init_params(CFG, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((2, S), jnp.int32)
+    before = seen()
+    jax.eval_shape(lambda p, t: laguna.forward_hidden(CFG, p, t)[0],
+                   shapes, tokens)
+    assert tuple(seen() - before) == (5, 10)
+    sound, _ = _loss_and_gradients()
+    monkeypatch.setattr(laguna, "head_gate", lambda n, w: jnp.ones(
+        (*n.shape[:-1], w.shape[-1]), jnp.float32))
+    params, (tok, tgt) = _params(CFG2, 7), _batch(7)
+    dropped = jax.jit(lambda p: laguna.loss_fn(CFG2, p, tok, tgt))(params)
+    assert abs(float(dropped) - float(sound)) > 1e-3

@@ -2,7 +2,9 @@
 scan, LFM2's gated short convolution (the convolution's kernel body
 with two multiplicands in the bias's and silu's place), and the two
 around the delta rule (l2 norms and decay before it; head norm, then a
-sigmoid gate, behind it), against plain
+sigmoid gate, behind it), and the two around Laguna's attention call (the
+rotation before it, the gate a head behind it: the jnp forms the model
+had, to the bit but for a contracted multiply-add), against plain
 f32 formulas — value and every gradient, whatever the blocks. Interpreter-mode Pallas on the
 CPU, so the shapes are small. The formulas here are the oracle (and
 ``scripts/ssm_pointwise_micro.py``'s jnp side);
@@ -10,12 +12,15 @@ CPU, so the shapes are small. The formulas here are the oracle (and
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from one_program import value_and_pullback
+from torchft_tpu.models import common
 from torchft_tpu.ops import ssm_pointwise as sp
 
 
@@ -702,3 +707,227 @@ def test_the_head_norm_compiles_for_the_v5e_at_heads_of_192(one_chip):
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
     assert "kda_ogate_fwd" in text and "kda_ogate_bwd" in text
+
+
+# -- around the attention call: the rotation, and the gate a head -------------
+
+
+def rotary_formula(x, freqs, factor, head):
+    """What ``models/laguna.py`` did until PR 62: ``common.rotary`` on
+    ``[B, S, H, D]``, then the flash call's turn to the heads first."""
+    b, s, w = x.shape
+    return common.rotary(x.reshape(b, s, w // head, head), freqs,
+                         factor).transpose(0, 2, 1, 3)
+
+
+def gate_heads_formula(o, gate):
+    """Likewise: the flash call's result turned back to ``[B, S, H, D]``,
+    an f32 multiply by the head's gate, one rounding."""
+    b, h, s, d = o.shape
+    return (o.transpose(0, 2, 1, 3).astype(F32) * gate[..., None]).astype(
+        o.dtype).reshape(b, s, h * d)
+
+
+def rotary_inputs(seed, b, s, heads, head, half, dtype=jnp.bfloat16):
+    """``x``, the frequencies and a cotangent, heads first."""
+    key = jax.random.split(jax.random.key(seed), 2)
+    return (jax.random.normal(key[0], (b, s, heads * head), F32).astype(dtype),
+            jnp.asarray(1e4 ** (-np.arange(half) / half), F32),
+            jax.random.normal(key[1], (b, heads, s, head), F32).astype(dtype))
+
+
+def head_gate_inputs(seed, b, s, heads, head, dtype=jnp.bfloat16):
+    """``o`` heads first, a gate in (0, 1) and a cotangent."""
+    key = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(key[0], (b, heads, s, head), F32).astype(dtype),
+            jax.nn.sigmoid(jax.random.normal(key[1], (b, s, heads), F32)),
+            jax.random.normal(key[2], (b, s, heads * head), F32).astype(dtype))
+
+
+def ulps_apart_traced(got, want):
+    """``(share of elements that differ, the most they differ by in units
+    of the last place)`` of two bf16 arrays of one shape, as arrays (the
+    micro script jits it: its arrays are half a gigabyte)."""
+    def ordered(a):
+        bits = jax.lax.bitcast_convert_type(a, jnp.int16).astype(jnp.int32)
+        return jnp.where(bits < 0, -(bits & 0x7FFF), bits)
+    return (jnp.mean((got != want).astype(F32)),
+            jnp.max(jnp.abs(ordered(got) - ordered(want))))
+
+
+def ulps_apart(got, want):
+    share, most = ulps_apart_traced(got, want)
+    return float(share), int(most)
+
+
+def assert_rounds_alike(got, want, name):
+    """Bit for bit, but for what a contracted multiply-add moves: two
+    products and a sum in f32 are the jnp form's, and where XLA's CPU
+    compiler fuses one product into the sum on one side the f32 value
+    moves by half a place of the PRODUCT's last digit, which shows in
+    bf16 on a rounding boundary or where the two products cancel — a few
+    elements in ten thousand, by one place of bf16 or by a few of f32's
+    of the operands' size."""
+    assert got.dtype == want.dtype and got.shape == want.shape, name
+    share, _ = ulps_apart(got, want)
+    assert share < 2e-3, (name, share)
+    g, w = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.abs(g - w) <= 2.0 ** -7 * np.abs(w)
+                  + 1e-6 * np.abs(w).max()), name
+
+
+# (case, B, S, heads, a head's lanes, half the turned lanes, the factor,
+#  (rows, lanes) a block or None)
+ROTARY_CASES = [
+    ("the-whole-head", 1, 32, 2, 128, 64, 1.0, None),
+    ("half-a-head-and-a-factor", 1, 32, 2, 128, 32, 1.25, None),
+    ("64-heads-in-blocks-of-four", 1, 16, 64, 128, 64, 1.0, (16, 512)),
+    ("48-heads-half-a-head", 1, 16, 48, 128, 32, 1.25, (16, 512)),
+    ("8-heads-two-batch-rows-two-row-blocks", 2, 32, 8, 128, 64, 1.0,
+     (16, 512)),
+    ("a-head-of-32-lanes-three-heads-a-block", 2, 24, 3, 32, 8, 1.25, None),
+]
+
+
+@pytest.mark.parametrize("case", ROTARY_CASES,
+                         ids=[c[0] for c in ROTARY_CASES])
+def test_rotary_equals_the_jnp_form(case):
+    """bf16 in and out, f32 inside: the rotated values and ``dx`` are
+    ``common.rotary``'s and its autodiff's, heads first."""
+    name, b, s, heads, head, half, factor, blocks = case
+    x, freqs, dy = rotary_inputs(len(name), b, s, heads, head, half)
+
+    def kernel(x):
+        cos, sin = sp.rotary_tables(freqs, s, head, factor)
+        if blocks is None:
+            return sp.rotary(x, cos, sin, half)
+        return sp._rotary(x, cos, sin, half, blocks, sp._interpret())
+
+    want, (dx_want,) = value_and_pullback(
+        lambda x: rotary_formula(x, freqs, factor, head), (x,), dy)
+    got, (dx,) = value_and_pullback(kernel, (x,), dy)
+    assert got.shape == (b, heads, s, head)
+    assert_rounds_alike(got, want, "value")
+    assert_rounds_alike(dx, dx_want, "dx")
+    lanes = x.reshape(b, s, heads, head).transpose(0, 2, 1, 3)
+    # the lanes beyond pass; the turned ones turn (but at position 0)
+    np.testing.assert_array_equal(got[..., 2 * half:], lanes[..., 2 * half:])
+    assert float(jnp.max(jnp.abs(
+        (got - lanes)[:, :, 1:, :2 * half].astype(F32)))) > 0.1
+
+
+# (case, B, S, heads, a head's lanes, rows a block or None)
+HEAD_GATE_CASES = [
+    ("two-heads-one-block", 1, 32, 2, 128, None),
+    ("64-heads", 1, 16, 64, 128, None),
+    ("48-heads-two-row-blocks", 1, 32, 48, 128, 16),
+    ("two-batch-rows-three-heads-of-32", 2, 48, 3, 32, 16),
+]
+
+
+@pytest.mark.parametrize("case", HEAD_GATE_CASES,
+                         ids=[c[0] for c in HEAD_GATE_CASES])
+def test_gate_heads_equals_the_jnp_form(case):
+    """One f32 multiply and one rounding forward and in ``do``: bit for
+    bit; ``dgate`` is an f32 sum over a head's lanes in another order."""
+    name, b, s, heads, head, rows = case
+    o, gate, dy = head_gate_inputs(len(name), b, s, heads, head)
+    want, (do_want, dg_want) = value_and_pullback(
+        gate_heads_formula, (o, gate), dy)
+    got, (do, dg) = value_and_pullback(
+        sp.gate_heads if rows is None else
+        lambda o, g: sp._hgate(o, g, rows, sp._interpret()), (o, gate), dy)
+    assert got.dtype == o.dtype and got.shape == (b, s, heads * head)
+    np.testing.assert_array_equal(got, want)
+    assert do.dtype == o.dtype and do.shape == o.shape
+    np.testing.assert_array_equal(do, do_want)
+    assert dg.dtype == F32 and dg.shape == gate.shape
+    np.testing.assert_allclose(
+        dg, dg_want, rtol=1e-6, atol=1e-6 * float(jnp.max(jnp.abs(dg_want))))
+
+
+def test_what_the_attention_kernels_refuse(monkeypatch):
+    """Shapes that do not fit, a gate that is not f32, and a sequence
+    that is no whole row blocks, each by name and before a kernel is
+    built; on the chip a head that is no whole lane tiles."""
+    x, freqs, _ = rotary_inputs(1, 1, 16, 2, 128, 64)
+    cos, sin = sp.rotary_tables(freqs, 16, 128)
+    with pytest.raises(ValueError, match="rotary: .* do not fit"):
+        sp.rotary(x[:, :8], cos, sin, 64)
+    with pytest.raises(ValueError, match="rotary: .* do not fit"):
+        sp.rotary(x[..., :192], cos, sin, 64)
+    with pytest.raises(ValueError, match="rotary: .* over 256 lanes"):
+        sp.rotary(x, cos, sin, 128)
+    o, gate, _ = head_gate_inputs(1, 1, 16, 2, 128)
+    with pytest.raises(ValueError, match="gate_heads: .* do not fit"):
+        sp.gate_heads(o, gate[..., :1])
+    with pytest.raises(ValueError, match="gate_heads: .*bfloat16 gate"):
+        sp.gate_heads(o, gate.astype(jnp.bfloat16))
+    # two heads of 128: blocks of 2048 rows, or of the 16 in 1040 .. 2048
+    # that divide the sequence: 2072 = 8 x 7 x 37 has none
+    assert sp._row_block(2072, 256, sp._ATTN_BLOCK_ELEMS) == 2048
+    long = jnp.zeros((1, 2072, 256), jnp.bfloat16)
+    table = jnp.zeros((2072, 128), F32)
+    with pytest.raises(ValueError, match="rotary: a sequence of 2072 is no "
+                                         "whole blocks of 2048 rows"):
+        sp.rotary(long, table, table, 64)
+    with pytest.raises(ValueError, match="gate_heads: a sequence of 2072 is "
+                                         "no whole blocks of 2048 rows"):
+        sp.gate_heads(jnp.zeros((1, 2, 2072, 128), jnp.bfloat16),
+                      jnp.zeros((1, 2072, 2), F32))
+    monkeypatch.setattr(sp, "_interpret", lambda: False)
+    narrow, f, _ = rotary_inputs(1, 1, 16, 4, 32, 16)
+    with pytest.raises(ValueError, match="rotary: a head's: 32 channels "
+                                         "are no multiple of 128 lanes"):
+        sp.rotary(narrow, *sp.rotary_tables(f, 16, 32), 16)
+    with pytest.raises(ValueError, match="gate_heads: a head's: 32 channels "
+                                         "are no multiple of 128 lanes"):
+        sp.gate_heads(jnp.zeros((1, 4, 16, 32), jnp.bfloat16),
+                      jnp.zeros((1, 16, 4), F32))
+
+
+def test_the_attention_kernels_compile_for_the_v5e_at_the_cells_widths(
+        one_chip):
+    """[4, 8192] of 64 heads of 128 turned whole and of 48 turned over
+    half a head, and the gate at 64 heads, at the blocks the shapes
+    choose: Mosaic takes the rolls inside a lane tile, the heads' loops
+    with their lane offsets of whole tiles, the gate's lanes rolled by a
+    loop's index and a head's column of it broadcast along the lanes (the
+    chip's compiler alone: nothing runs)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    b, s, d, bf16 = 4, 8192, 128, jnp.bfloat16
+    for heads in (64, 48, 8):       # eight heads a block, 512 rows
+        assert sp._lane_block(heads * d, d, sp._ROTARY_LANES) == 1024
+    # the gate's heads eight a trip of its loop; what divides the tiny ones
+    assert [sp._head_groups(h) for h in (64, 48, 6, 4, 3)] == [8, 8, 6, 4, 3]
+    rows = functools.partial(sp._row_block, s, elems=sp._ATTN_BLOCK_ELEMS)
+    assert rows(1024) == 512 and rows(64 * d) == rows(48 * d) == 64
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(q, q48, cos, sin, dq, dq48, o, gate, dy):
+        whole, pull = jax.vjp(lambda x: sp.rotary(x, cos, sin, 64), q)
+        part, pull48 = jax.vjp(lambda x: sp.rotary(x, cos, sin, 32), q48)
+        y, pull_y = jax.vjp(sp.gate_heads, o, gate)
+        return whole, pull(dq), part, pull48(dq48), y, pull_y(dy)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    monkey = pytest.MonkeyPatch()
+    monkey.setattr(sp, "_interpret", lambda: False)
+    try:
+        text = jax.jit(both).lower(
+            sd((b, s, 64 * d), bf16), sd((b, s, 48 * d), bf16),
+            sd((s, d), F32), sd((s, d), F32), sd((b, 64, s, d), bf16),
+            sd((b, 48, s, d), bf16), sd((b, 64, s, d), bf16),
+            sd((b, s, 64), F32), sd((b, s, 64 * d), bf16)
+        ).compile().as_text()
+    finally:
+        monkey.undo()
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    for kernel in ("rotary_fwd", "rotary_bwd", "head_gate_fwd",
+                   "head_gate_bwd"):
+        assert kernel in text, kernel

@@ -46,6 +46,13 @@ parameter tree with stable paths ``layers_<i>/{norm_1,norm_2}``,
 step programs of ``transformer.make_train_step`` / ``make_grad_step``
 (``loss=laguna.loss_fn``).
 
+The rotation and the gate's multiply are Pallas kernels
+(``ops/ssm_pointwise.py::rotary`` from tables built once a step,
+``gate_heads``): bf16 read once and written once, f32 inside, the jnp
+forms' values; the flash call's side of both is heads first, so no layout
+copy stands between them and the call. ``attn_fn`` still takes and gives
+``[B, S, H, D]``, and ``head_gate`` still makes the gate.
+
 Device-trace scopes: ``embed``; ``attn`` with ``gqa_proj`` (the norm, q /
 k / v, and inside it ``rope`` or ``rope_yarn`` around the rotation and
 ``attn_gate`` around the gate's matmul, sigmoid and multiply; ``W_o``)
@@ -72,7 +79,6 @@ from torchft_tpu.models.common import (
     embed,
     is_balance_bias,
     rms_norm,
-    rotary,
     routed_sublayer,
     routing_record,
     share_loss_terms,
@@ -80,9 +86,11 @@ from torchft_tpu.models.common import (
 )
 from torchft_tpu.models.transformer import ce_from_hidden
 from torchft_tpu.ops.attention import causal_attention
+from torchft_tpu.ops.ssm_pointwise import gate_heads, rotary, rotary_tables
 
 __all__ = ["LagunaConfig", "Rotation", "LAGUNA_CONFIGS", "BALANCE_BIAS",
-           "is_balance_bias", "yarn_ramp", "rotation_freqs", "init_params",
+           "is_balance_bias", "yarn_ramp", "rotation_freqs",
+           "rotation_tables", "init_params",
            "forward_hidden", "loss_terms", "loss_fn"]
 
 
@@ -254,13 +262,41 @@ def init_params(cfg: LagunaConfig, key) -> Dict:
     return params
 
 
-def _rotate(cfg: LagunaConfig, windowed: bool, q, k):
-    """q and k of one layer through its kind's rotation."""
-    rot = cfg.rope_swa if windowed else cfg.rope_full
-    with jax.named_scope("rope" if rot.yarn_factor is None else "rope_yarn"):
-        freqs = jnp.asarray(rotation_freqs(rot, cfg.head_dim))
-        return (rotary(q, freqs, rot.attention_factor),
-                rotary(k, freqs, rot.attention_factor))
+def _rotation(cfg: LagunaConfig, windowed: bool) -> Rotation:
+    return cfg.rope_swa if windowed else cfg.rope_full
+
+
+def _rope_scope(rot: Rotation):
+    return jax.named_scope("rope" if rot.yarn_factor is None else "rope_yarn")
+
+
+def rotation_tables(cfg: LagunaConfig, seq_len: int) -> Dict[bool, Tuple]:
+    """``{windowed: (cos, sin)}``, the ``[seq_len, head_dim]`` float32
+    tables of the kinds of layer the model has
+    (``ops/ssm_pointwise.py::rotary_tables``: ``common.rotary``'s
+    expressions, a lane of the head): built once a step, handed to every
+    layer of the kind."""
+    tables = {}
+    for windowed in sorted({bool(w) for w in cfg.windowed}):
+        rot = _rotation(cfg, windowed)
+        with jax.named_scope("attn"), jax.named_scope("gqa_proj"), \
+                _rope_scope(rot):
+            tables[windowed] = rotary_tables(
+                jnp.asarray(rotation_freqs(rot, cfg.head_dim)), seq_len,
+                cfg.head_dim, rot.attention_factor)
+    return tables
+
+
+def _rotate(cfg: LagunaConfig, windowed: bool, q, k, table):
+    """``q [B, S, H·D]`` and ``k [B, S, KV·D]`` of one layer through its
+    kind's rotation -> ``[B, S, H, D]`` and ``[B, S, KV, D]``: the
+    transposed views of the kernel's heads-first results, which the flash
+    call's own transposition folds away."""
+    rot = _rotation(cfg, windowed)
+    half = int(cfg.head_dim * rot.partial) // 2
+    with _rope_scope(rot):
+        return tuple(rotary(x, *table, half).transpose(0, 2, 1, 3)
+                     for x in (q, k))
 
 
 def head_gate(n32, kernel):
@@ -271,35 +307,36 @@ def head_gate(n32, kernel):
 
 
 @jax.named_scope("attn")
-def _attn_mixer(cfg: LagunaConfig, windowed: bool, layer: Dict, x, *,
-                attn_fn):
-    """``x + (γ ⊙ attention(n1))·W_o`` at the layer's own head count."""
+def _attn_mixer(cfg: LagunaConfig, windowed: bool, layer: Dict, x, table,
+                *, attn_fn):
+    """``x + (γ ⊙ attention(n1))·W_o`` at the layer's own head count;
+    ``table`` its kind's of :func:`rotation_tables`. The rotation and the
+    gate's multiply are ``ops/ssm_pointwise.py``'s kernels on either side
+    of ``attn_fn``, which takes and gives ``[B, S, H, D]``."""
     a, dt = layer["attn"], cfg.dtype
     B, S, _ = x.shape
     KV, D = cfg.n_kv_heads, cfg.head_dim
-    H = a["gate"]["kernel"].shape[-1]
     with jax.named_scope("gqa_proj"):
         n32 = rms_norm(x.astype(jnp.float32), layer["norm_1"]["scale"],
                        cfg.rms_eps)
         n = n32.astype(dt)
-        q = (n @ a["q_proj"]["kernel"].astype(dt)).reshape(B, S, H, D)
-        k = (n @ a["k_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
+        q, k = _rotate(cfg, windowed, n @ a["q_proj"]["kernel"].astype(dt),
+                       n @ a["k_proj"]["kernel"].astype(dt), table)
         v = (n @ a["v_proj"]["kernel"].astype(dt)).reshape(B, S, KV, D)
-        q, k = _rotate(cfg, windowed, q, k)
     with jax.named_scope("gqa_core"):
         with jax.named_scope("swa_core" if windowed else "full_core"):
             o = attn_fn(q, k, v, window=cfg.window if windowed else None)
     with jax.named_scope("gqa_proj"):
         with jax.named_scope("attn_gate"):
-            gate = head_gate(n32, a["gate"]["kernel"])
-            o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
-        return x + o.reshape(B, S, H * D) @ a["o_proj"]["kernel"].astype(dt)
+            o = gate_heads(o.transpose(0, 2, 1, 3),
+                           head_gate(n32, a["gate"]["kernel"]))
+        return x + o @ a["o_proj"]["kernel"].astype(dt)
 
 
 def _layer(cfg: LagunaConfig, windowed: bool, is_sparse: bool, layer: Dict,
-           x, *, attn_fn) -> Tuple[Any, Optional[Dict]]:
+           x, table, *, attn_fn) -> Tuple[Any, Optional[Dict]]:
     """One layer: ``(x, record)``, the record ``None`` of a dense one."""
-    h = _attn_mixer(cfg, windowed, layer, x, attn_fn=attn_fn)
+    h = _attn_mixer(cfg, windowed, layer, x, table, attn_fn=attn_fn)
     if not is_sparse:
         return dense_sublayer(cfg, h, layer["norm_2"]["scale"],
                               layer["mlp"]), None
@@ -321,13 +358,14 @@ def forward_hidden(cfg: LagunaConfig, params: Dict, tokens,
     if attn_fn is None:
         attn_fn = causal_attention
     x = embed(cfg, params, tokens)
+    tables = rotation_tables(cfg, tokens.shape[1])
     records = []
     for i, (windowed, is_sparse) in enumerate(zip(cfg.windowed, cfg.sparse)):
         run = functools.partial(_layer, cfg, bool(windowed), bool(is_sparse),
                                 attn_fn=attn_fn)
         if cfg.remat:
             run = jax.checkpoint(run)
-        x, rec = run(params[f"layers_{i}"], x)
+        x, rec = run(params[f"layers_{i}"], x, tables[bool(windowed)])
         if rec is not None:
             records.append(rec)
     return (rms_norm(x, params["ln_f"]["scale"], cfg.rms_eps),

@@ -4,10 +4,12 @@ convolution with its silu, and the gate with its grouped RMSNorm —,
 LFM2's gated short convolution, which is the first's kernel body with
 two multiplicands where that has a bias and a silu, and the two around
 the delta rule (``ops/kda.py``) — the heads' normalisation of ``q`` and
-``k`` with the decay, and the head norm with the gate after it. Each is
+``k`` with the decay, and the head norm with the gate after it —, and the
+two around Laguna's attention call (``ops/flash.py``) — the rotation of
+``q`` and ``k`` from a table, and the gate a head on its output. Each is
 ONE kernel forward and ONE backward under its own ``jax.custom_vjp``;
 each reads its operands once, in the dtype they arrive in, and writes
-its results once. The residuals of all five are their inputs alone: the
+its results once. The residuals of all seven are their inputs alone: the
 backward kernels recompute what they need.
 
 ``conv_silu(x [B, S, C], taps [K, C], bias [C])``:
@@ -99,6 +101,35 @@ outside. Handing the head's function in keeps what it IS in the model
 place, and a fault there must change what the kernels compute); the
 kernels own the blocks, the dtypes and the sums.
 
+``rotary(x [B, S, H·D], cos, sin [S, D], half)`` (``models/laguna.py``):
+the ``rotate_half`` rotation of ``models/common.py::rotary`` over the
+first ``2·half`` lanes of every head, from :func:`rotary_tables`' table
+(built once a step: a lane of the head, the sign of ``sin`` and the
+lanes that pass in it). With ``p(x)`` the partner lanes (``i ± half``,
+a ``pltpu.roll`` inside the head's lane tile):
+
+    y = x ⊙ cos + p(x) ⊙ sin            dx = dy ⊙ cos − p(dy) ⊙ sin
+
+``y`` leaves as ``[B, H, S, D]``, THE HEADS FIRST — the order the flash
+call merges its ``[B, S, H, D]`` argument to, so the caller hands it
+``y``'s transposed view and XLA folds the two turns away (a kernel that
+wrote ``[B, S, H·D]`` would pay a copy of ``q`` a call where XLA's own
+fusion wrote the flash call's layout directly) — and ``dy`` arrives so.
+
+``gate_heads(o [B, H, S, D], gate [B, S, H])``: the flash call's output
+as it leaves the kernel (heads first, likewise) times a float32 gate a
+head a position, to ``[B, S, H·D]`` as the output projection reads it:
+
+    y = o · γ               do = dy · γ               dγ = Σ_D dy ⊙ o
+
+one f32 multiply and one rounding each (the jnp form's bits), ``dγ`` in
+f32. A block is ALL the heads of a few rows (the gate's block is then
+the whole of its last axis, padded with zeros to whole lane tiles on the
+TPU); what the gate is stays the caller's. Both kernels' bodies are
+traced a head (the rotation) or a group of eight heads (the gate) long,
+whatever a block holds: the rotation's loop over heads is unrolled by the
+lowering, the gate's over groups stays a loop.
+
 What is which dtype: ``x``, ``y``, ``z`` and the cotangents arrive, and
 the results and ``dx``, ``dy``, ``dz`` leave, in the input dtype (bf16
 in the models); every operand is upcast to f32 as it is loaded and all
@@ -145,7 +176,10 @@ no multiple of 128 lanes is refused with a message; ``kda_ogate`` takes
 a head of any width whose channel blocks can be whole heads AND whole
 lane tiles (``H·D`` a multiple of ``lcm(D, 128)``: 192 in twos; a head
 inside such a block is a lane slice that starts between tiles, which
-Mosaic shifts) and refuses the rest with a message. Off the TPU the same
+Mosaic shifts) and refuses the rest with a message. The two around the
+attention call refuse a sequence that is no whole row blocks (nothing
+there is masked) and, on the TPU, a head that is no whole lane tiles.
+Off the TPU the same
 kernels run in Pallas's interpreter at any width (the CPU tests), chosen
 from the backend alone.
 """
@@ -161,7 +195,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["conv_silu", "gated_conv", "gated_norm", "kda_qkg", "kda_ogate"]
+from torchft_tpu.utils.metrics import TRACED
+
+__all__ = ["conv_silu", "gated_conv", "gated_norm", "kda_qkg", "kda_ogate",
+           "rotary_tables", "rotary", "gate_heads"]
 
 _LANES = 128
 _HALO = 8                    # rows kept beside a block: the f32 sublane tile
@@ -172,6 +209,16 @@ _BLOCK_ELEMS = 256 * 1024    # elements a block: 1 MiB as f32
 # few live rows, the norm's chain is short and wants few loop trips)
 _CONV_CHUNK = 32
 _GATE_CHUNK = 128
+# around the attention call (measured on the v5e at the cell's shapes,
+# PERF.md PR 62): blocks of twice the elements — the rotation eight heads
+# wide (512 x 1024: 79 % of its bytes' floor against 63 % at 512 x 512),
+# the gate a head all its heads wide, so few rows (64 x 8192) — the
+# rotation 64 rows a pass, the table's two rows held across a block's
+# heads, and the gate's heads in groups of eight a loop's trip
+_ATTN_BLOCK_ELEMS = 2 * _BLOCK_ELEMS
+_ROTARY_LANES = 1024
+_ROTARY_CHUNK = 64
+_GATE_GROUP = 8
 
 
 def _interpret() -> bool:
@@ -423,23 +470,24 @@ def _whole_tiles(whole: int) -> int:
     return math.lcm(whole, _LANES)
 
 
-def _lane_block(width: int, whole: int = _LANES) -> int:
+def _lane_block(width: int, whole: int = _LANES,
+                widest: int = _LANE_BLOCK) -> int:
     """Lanes a channel block: the widest multiple of ``whole`` channels
-    and of 128 lanes (:func:`_whole_tiles`) up to ``_LANE_BLOCK`` that
+    and of 128 lanes (:func:`_whole_tiles`) up to ``widest`` that
     divides ``width``, at least one such unit; the whole of a width that
     is no multiple of it (off the TPU only)."""
     unit = _whole_tiles(whole)
     if width % unit:
         return width
-    return max([unit] + [bc for bc in range(unit, _LANE_BLOCK + 1, unit)
+    return max([unit] + [bc for bc in range(unit, widest + 1, unit)
                          if width % bc == 0])
 
 
-def _row_block(seq_len: int, lanes: int) -> int:
-    """Rows a block: about ``_BLOCK_ELEMS / lanes``, in sixteens (a
+def _row_block(seq_len: int, lanes: int, elems: int = _BLOCK_ELEMS) -> int:
+    """Rows a block: about ``elems / lanes``, in sixteens (a
     packed bf16 tile); the whole of a shorter sequence; a divisor of the
     sequence where one lies within a factor of two below."""
-    rows = max(16, _BLOCK_ELEMS // lanes // 16 * 16)
+    rows = max(16, elems // lanes // 16 * 16)
     if seq_len <= rows:
         return seq_len
     return next((bs for bs in range(rows, rows // 2, -16)
@@ -1142,3 +1190,332 @@ def kda_ogate(o, gate, scale, eps: float, gated):
     bc = _lane_block(width, head)
     return _ogate(o, gate, scale, head, float(eps), gated,
                   (_row_block(o.shape[1], bc), bc), interpret)
+
+
+# ------------------------------- around the attention call: the rotation
+def rotary_tables(freqs, seq_len: int, head_dim: int, factor: float = 1.0):
+    """``(cos, sin)``, each ``[seq_len, head_dim]`` f32, for
+    :func:`rotary`: the table of ``models/common.py::rotary`` — the same
+    expressions, so the same values — laid out a LANE of the head. With
+    ``r = len(freqs)``: lanes ``i`` and ``r + i`` hold ``cos(t·f_i)``,
+    lane ``i`` holds ``−sin(t·f_i)`` and lane ``r + i`` ``+sin(t·f_i)``
+    (both times ``factor``), the lanes from ``2r`` on hold 1 and 0 (they
+    pass). Built once a step, outside the layers."""
+    half = freqs.shape[0]
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    rest = (seq_len, head_dim - 2 * half)
+    return (jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], axis=-1),
+            jnp.concatenate([-sin, sin, jnp.zeros(rest, jnp.float32)],
+                            axis=-1))
+
+
+def _partner(x, half: int):
+    """Lane ``i + half`` at lane ``i < half`` and lane ``i − half`` at
+    ``half <= i < 2·half`` of a head ``[rows, D]``: one roll where the
+    two halves are the head, two and a select where they are its first
+    lanes (what stands beyond ``2·half`` meets a zero of the table)."""
+    head = x.shape[-1]
+    down = pltpu.roll(x, shift=half, axis=1)
+    if 2 * half == head:
+        return down
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane < half,
+                     pltpu.roll(x, shift=head - half, axis=1), down)
+
+
+def _head_lanes(h, head: int):
+    """The lanes of head ``h`` of a block of whole heads; ``h`` may be a
+    loop's index (Mosaic takes a dynamic lane offset that is whole
+    tiles)."""
+    return pl.ds(h * head if isinstance(h, int)
+                 else pl.multiple_of(h * head, head), head)
+
+
+def _rotary_kernel(x_ref, cos_ref, sin_ref, o_ref, *, chunk: int, half: int,
+                   back: bool):
+    """One (sequence block, batch row, channel block of whole heads); the
+    table's block stays while the grid runs over batch rows and channel
+    blocks. Forward: ``x_ref [1, rows, heads·D]`` -> ``o_ref [1, heads,
+    rows, D]``, ``x ⊙ cos + partner(x) ⊙ sin``. ``back``: the transposed
+    rotation ``dy ⊙ cos − partner(dy) ⊙ sin`` from ``[1, heads, rows, D]``
+    to ``[1, rows, heads·D]``. The heads are a loop that the LOWERING
+    unrolls: the body is traced a head long whatever the block holds
+    (PERF.md, PR 62: on the chip's host a traced equation costs 1.4 ms,
+    once a shape and a process, in ``setup_s``; eight heads written out
+    were 0.3 s a body), and Mosaic still sees them in a straight line
+    with static offsets (left as a loop they read 3.2 ms a call for
+    1.7)."""
+    heads = (x_ref if back else o_ref).shape[1]
+    bs, head = cos_ref.shape
+
+    def rows(i, carry):
+        at = pl.ds(_row0(i, chunk), chunk)
+        cos, sin = cos_ref[at, :], sin_ref[at, :]
+
+        def a_head(h, carry):
+            lanes = _head_lanes(h, head)
+            if back:
+                dy = _f32(x_ref[0, h, at, :])
+                o_ref[0, at, lanes] = (
+                    dy * cos - _partner(dy, half) * sin).astype(o_ref.dtype)
+            else:
+                x = _f32(x_ref[0, at, lanes])
+                o_ref[0, h, at, :] = (
+                    x * cos + _partner(x, half) * sin).astype(o_ref.dtype)
+            return carry
+
+        return jax.lax.fori_loop(0, heads, a_head, carry, unroll=True)
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _whole_row_blocks(what: str, seq_len: int, bs: int) -> None:
+    if seq_len % bs:
+        raise ValueError(
+            f"{what}: a sequence of {seq_len} is no whole blocks of {bs} "
+            f"rows")
+
+
+# Jitted as the builders above are: five layers call each of these three
+# times a step, and a body is traced once a shape.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _rotary_call(x, cos, sin, half: int, blocks: Tuple[int, int],
+                 interpret: bool, back: bool):
+    """Forward ``x [B, S, H·D]`` -> ``[B, H, S, D]``; ``back`` the other
+    way with the transposed rotation."""
+    head = cos.shape[-1]
+    bs, bc = blocks
+    if back:
+        b, heads, s, _ = x.shape
+    else:
+        b, s, width = x.shape
+        heads = width // head
+    _whole_row_blocks("rotary", s, bs)
+    wide = pl.BlockSpec((1, bs, bc), lambda s, b, c: (b, s, c))
+    tall = pl.BlockSpec((1, bc // head, bs, head),
+                        lambda s, b, c: (b, c, s, 0))
+    table = pl.BlockSpec((bs, head), lambda s, b, c: (s, 0))
+    return pl.pallas_call(
+        functools.partial(_rotary_kernel, chunk=_row_chunk(bs, _ROTARY_CHUNK),
+                          half=half, back=back),
+        grid=(s // bs, b, heads * head // bc),
+        in_specs=[tall if back else wide, table, table],
+        out_specs=wide if back else tall,
+        out_shape=jax.ShapeDtypeStruct(
+            (b, s, heads * head) if back else (b, heads, s, head), x.dtype),
+        interpret=interpret, name="rotary_bwd" if back else "rotary_fwd",
+    )(x, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rotary(x, cos, sin, half, blocks, interpret):
+    return _rotary_call(x, cos, sin, half, blocks, interpret, False)
+
+
+def _rotary_fwd_rule(x, cos, sin, half, blocks, interpret):
+    return _rotary_call(x, cos, sin, half, blocks, interpret, False), (
+        cos, sin)
+
+
+def _rotary_bwd_rule(half, blocks, interpret, tables, dy):
+    # the table is no function of anything that learns
+    return _rotary_call(dy, *tables, half, blocks, interpret, True), None, None
+
+
+_rotary.defvjp(_rotary_fwd_rule, _rotary_bwd_rule)
+
+
+def rotary(x, cos, sin, half: int):
+    """The ``rotate_half`` rotation of ``models/common.py::rotary`` over
+    the first ``2·half`` lanes of every head: ``x [B, S, H·D]`` (as a
+    projection writes it) and :func:`rotary_tables`' ``cos, sin [S, D]``
+    -> ``[B, H, S, D]`` in ``x``'s dtype, THE HEADS FIRST: the order the
+    flash call merges to, so that its ``[B, S, H, D]`` argument is this
+    array's transposed view and nothing is copied. Differentiable in
+    ``x`` (the transposed rotation, from the same table). Two products
+    and a sum a lane in f32, one rounding: the values of the jnp form."""
+    if (x.ndim != 3 or cos.ndim != 2 or cos.shape != sin.shape
+            or cos.shape[0] != x.shape[1] or x.shape[2] % cos.shape[1]
+            or not 0 < 2 * half <= cos.shape[1]):
+        raise ValueError(
+            f"rotary: x{tuple(x.shape)} cos{tuple(cos.shape)} "
+            f"sin{tuple(sin.shape)} turned over {2 * half} lanes do not fit")
+    interpret = _interpret()
+    head = cos.shape[1]
+    _refuse_lanes("rotary: a head's", head, interpret)
+    bc = _lane_block(x.shape[2], head, _ROTARY_LANES)
+    TRACED.incr("rotary_kernel_calls")
+    return _rotary(x, cos, sin, half,
+                   (_row_block(x.shape[1], bc, _ATTN_BLOCK_ELEMS), bc),
+                   interpret)
+
+
+# ------------------------------ behind the attention call: a gate a head
+def _head_groups(heads: int) -> int:
+    """Heads a trip of the gate's loop: up to ``_GATE_GROUP`` that divide
+    the heads."""
+    return max(n for n in range(1, _GATE_GROUP + 1) if heads % n == 0)
+
+
+def _gate_group(g, j, group: int, head: int):
+    """Trip ``j`` of the gate's loop over groups of ``group`` heads: the
+    gate's lanes rolled so that the group's columns are lanes ``0 ..
+    group − 1`` (a dynamic lane offset into a VALUE is a rotate by a
+    dynamic amount: one a group), the group's first head, and the lanes
+    of its ``k``-th head in a ``[rows, H·D]`` block (a dynamic offset
+    that is whole tiles, then a static one)."""
+    lanes = g.shape[1]
+    first = j * group
+
+    def at(k):
+        return pl.ds(pl.multiple_of(first * head, group * head) + k * head,
+                     head)
+
+    return pltpu.roll(g, shift=(lanes - first) % lanes, axis=1), first, at
+
+
+def _hgate_fwd_kernel(o_ref, g_ref, y_ref, *, chunk: int):
+    """One (batch row, sequence block) of ALL the heads: ``o_ref [1, H,
+    rows, D]`` as the flash call leaves it, ``g_ref [1, rows, lanes >=
+    H]`` f32 -> ``y_ref [1, rows, H·D]``. The heads go by in a loop of
+    groups, a group written out: the body is traced a group long (64
+    heads written out were 0.3 – 0.5 s a body forward and 1.0 – 1.2
+    backward on the chip's host, once a shape, in ``setup_s``; a loop a
+    HEAD long read 5.8 ms a call for 1.7: PERF.md, PR 62)."""
+    _, heads, bs, head = o_ref.shape
+    group = _head_groups(heads)
+
+    def rows(i, carry):
+        rows_at = pl.ds(_row0(i, chunk), chunk)
+        g = g_ref[0, rows_at, :]
+
+        def a_group(j, carry):
+            gj, first, at = _gate_group(g, j, group, head)
+            for k in range(group):
+                y_ref[0, rows_at, at(k)] = (
+                    _f32(o_ref[0, first + k, rows_at, :]) * gj[:, k:k + 1]
+                ).astype(y_ref.dtype)
+            return carry
+
+        return jax.lax.fori_loop(0, heads // group, a_group, carry)
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _hgate_bwd_kernel(o_ref, g_ref, dy_ref, do_ref, dg_ref, *, chunk: int):
+    """``do = dy · γ`` (heads first, as the flash call's backward takes
+    it) and ``dγ = Σ_D dy ⊙ o`` in f32, from one read of ``dy`` and
+    ``o``; a head's sum lands in its lane of a block-wide value that is
+    stored once."""
+    _, heads, bs, head = o_ref.shape
+    group = _head_groups(heads)
+
+    def rows(i, carry):
+        rows_at = pl.ds(_row0(i, chunk), chunk)
+        g = g_ref[0, rows_at, :]
+        lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
+
+        def a_group(j, dg):
+            gj, first, at = _gate_group(g, j, group, head)
+            for k in range(group):
+                dy = _f32(dy_ref[0, rows_at, at(k)])
+                do_ref[0, first + k, rows_at, :] = (
+                    dy * gj[:, k:k + 1]).astype(do_ref.dtype)
+                dg = jnp.where(lane == first + k, jnp.sum(
+                    dy * _f32(o_ref[0, first + k, rows_at, :]), axis=-1,
+                    keepdims=True), dg)
+            return dg
+
+        dg_ref[0, rows_at, :] = jax.lax.fori_loop(
+            0, heads // group, a_group, jnp.zeros(g.shape, jnp.float32))
+        return carry
+
+    _for_chunks(bs // chunk, rows, 0)
+
+
+def _gate_lanes(gate):
+    """``gate [B, S, H]`` with zeros up to whole lane tiles on the TPU (a
+    value's lanes are rolled: :func:`_gate_group`); 16 MB at the cell's."""
+    short = -gate.shape[2] % _LANES
+    return jnp.pad(gate, ((0, 0), (0, 0), (0, short))) if short else gate
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _hgate_forward(o, gate, bs: int, interpret: bool):
+    b, heads, s, head = o.shape
+    _whole_row_blocks("gate_heads", s, bs)
+    if not interpret:
+        gate = _gate_lanes(gate)
+    return pl.pallas_call(
+        functools.partial(_hgate_fwd_kernel,
+                          chunk=_row_chunk(bs, _GATE_CHUNK)),
+        grid=(b, s // bs),
+        in_specs=[pl.BlockSpec((1, heads, bs, head),
+                               lambda b, s: (b, 0, s, 0)),
+                  pl.BlockSpec((1, bs, gate.shape[2]),
+                               lambda b, s: (b, s, 0))],
+        out_specs=pl.BlockSpec((1, bs, heads * head), lambda b, s: (b, s, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, heads * head), o.dtype),
+        interpret=interpret, name="head_gate_fwd",
+    )(o, gate)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _hgate_backward(o, gate, dy, bs: int, interpret: bool):
+    b, heads, s, head = o.shape
+    if not interpret:
+        gate = _gate_lanes(gate)
+    tall = pl.BlockSpec((1, heads, bs, head), lambda b, s: (b, 0, s, 0))
+    a_head = pl.BlockSpec((1, bs, gate.shape[2]), lambda b, s: (b, s, 0))
+    do, dgate = pl.pallas_call(
+        functools.partial(_hgate_bwd_kernel,
+                          chunk=_row_chunk(bs, _GATE_CHUNK)),
+        grid=(b, s // bs),
+        in_specs=[tall, a_head,
+                  pl.BlockSpec((1, bs, heads * head), lambda b, s: (b, s, 0))],
+        out_specs=[tall, a_head],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype),
+                   jax.ShapeDtypeStruct(gate.shape, gate.dtype)],
+        interpret=interpret, name="head_gate_bwd",
+    )(o, gate, dy)
+    return do, dgate[..., :heads]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _hgate(o, gate, bs, interpret):
+    return _hgate_forward(o, gate, bs, interpret)
+
+
+def _hgate_fwd_rule(o, gate, bs, interpret):
+    return _hgate_forward(o, gate, bs, interpret), (o, gate)
+
+
+def _hgate_bwd_rule(bs, interpret, residuals, dy):
+    return _hgate_backward(*residuals, dy, bs, interpret)
+
+
+_hgate.defvjp(_hgate_fwd_rule, _hgate_bwd_rule)
+
+
+def gate_heads(o, gate):
+    """A gate a head a position on the attention's output: ``o [B, H, S,
+    D]`` — THE HEADS FIRST, the flash call's own order: its ``[B, S, H,
+    D]`` result's transposed view, so nothing is copied — and ``gate [B,
+    S, H]`` f32 -> ``[B, S, H·D]`` in ``o``'s dtype, as the output
+    projection reads it: ``(o · gate)`` with one f32 multiply and one
+    rounding, the values of the jnp form. Differentiable in both: ``do =
+    dy · gate`` likewise and ``dgate = Σ_D dy ⊙ o`` in f32. What the
+    gate IS stays the caller's (``models/laguna.py::head_gate``)."""
+    if (o.ndim != 4 or gate.shape != (o.shape[0], o.shape[2], o.shape[1])
+            or gate.dtype != jnp.float32):
+        raise ValueError(
+            f"gate_heads: o{tuple(o.shape)} and a {gate.dtype} "
+            f"gate{tuple(gate.shape)} do not fit")
+    interpret = _interpret()
+    _refuse_lanes("gate_heads: a head's", o.shape[3], interpret)
+    TRACED.incr("head_gate_kernel_calls")
+    return _hgate(o, gate, _row_block(o.shape[2], o.shape[1] * o.shape[3],
+                                      _ATTN_BLOCK_ELEMS), interpret)
