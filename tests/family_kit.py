@@ -74,6 +74,8 @@ _TINY = {
         file="tiny-laguna.json", bias_rate=0.01, layers=2,
         per_layer=("layer_types", "mlp_layer_types",
                    "num_attention_heads_per_layer")),
+    # one period: three delta-rule layers and a full one, every one sparse
+    "qwen3_next": dict(file="tiny-qwen3next.json", bias_rate=0.01),
 }
 # the families with a loop scenario in tier-1 (``gpt``'s are
 # tests/test_step_programs.py's; ``phi4flash`` has none: ROADMAP.md)

@@ -15,8 +15,11 @@ with unrotated full attention, the OLMo family's norms on each sublayer's
 output) and ``laguna`` (attention whose query head count, mask and rotation
 follow the kind of layer, a YaRN rotation over half a head beside a plain
 one, a gate a head, a dense first layer and then sigmoid-routed experts
-beside a shared one); what more than one of them computes is in
-``common``."""
+beside a shared one) and ``qwen3_next`` (a pre-norm stack with zero-centred
+norm weights: a delta rule whose value heads share key heads 3 : 1 with
+attention on 256-wide heads under an element-wise gate and a quarter-head
+rotation, every layer a softmax top-10-of-512 router beside a gated shared
+expert); what more than one of them computes is in ``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

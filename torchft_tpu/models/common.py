@@ -1,16 +1,17 @@
 """What more than one model file computes, in one place: a change here is
 a change to every model that imports it, and says so. RMSNorm (``llama``,
 ``olmoe``, ``joyai``, ``nemotron_h``, ``lfm2``, ``kimi_linear``,
-``smallthinker``, ``laguna``), the token table's lookup (``olmoe`` and
+``smallthinker``, ``laguna``, ``qwen3_next``), the token table's lookup (``olmoe`` and
 the six below), the rotary embedding over the whole head or its first
 lanes at given frequencies (``llama._rope``, so ``olmoe``, ``lfm2``,
 ``smallthinker`` and ``olmo_hybrid``; ``laguna``'s two rotations), the
 repeat of grouped key/value heads (what the attention of
 ``ops/`` does by index since PR 55: the tests' and the references'
 yardstick), the SwiGLU MLP and its dense sublayer (``joyai``,
-``lfm2``, ``kimi_linear``, ``laguna``), and what the six models that hold
+``lfm2``, ``kimi_linear``, ``laguna``), and what the seven models that hold
 ONE CHIP'S SHARE of an expert-parallel layer (``joyai``, ``nemotron_h``,
-``lfm2``, ``kimi_linear``, ``smallthinker``, ``laguna``) have in common: the router's
+``lfm2``, ``kimi_linear``, ``smallthinker``, ``laguna``, ``qwen3_next``)
+have in common: the router's
 balance bias — its key in the parameter tree, the predicate
 ``optim.with_balance_bias`` partitions the leaves by, and the way a
 step's loads reach that rule in the gradient tree at the bias's place —

@@ -36,7 +36,9 @@ SiLU (:func:`_gated_head_norm`; Kimi Linear's is a sigmoid on a low-rank
 gate) — ONE kernel, ``ops/ssm_pointwise.py::kda_ogate`` with this
 file's seam: two heads of 192 are three lane tiles. The l2 norms are
 XLA's: ``q̃``'s and ``k̃``'s 2 880 channels are 22.5 lane tiles, so no
-block of whole heads and whole tiles divides them.
+block of whole heads and whole tiles divides them. (A 128-wide head is
+ONE lane tile: ``models/qwen3_next.py`` runs this seam and these norms so,
+32 value heads of 128 over 16 key heads.)
 
 Full-attention mixer: ``H`` heads of ``d / H``, as many key/value heads;
 ``q = RMSNorm(n·W_q; w_q)``, ``k = RMSNorm(n·W_k; w_k)`` over the WHOLE

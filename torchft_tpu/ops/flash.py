@@ -1376,8 +1376,7 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
 #   [256, 8192, 128] under W 512 (laguna)  19.8 -> 19.1 / 18.5 / 18.5
 #   [80, 8192, 64 | 128] under W 512       6.96 -> 6.78 / 6.62 / 6.62
 # (at 192 | 128 eight tiles of K and V twice over are 10.5 MB and Mosaic
-# refuses the kernel by 20 KB of its 16 MiB: the estimate below reads
-# 17.25). Groups of (2, 1) read 0 - 2.5 % slower than (4, 2, 1) at every
+# refuses the kernel: the estimate below reads 20.75, the compiler 21.73). Groups of (2, 1) read 0 - 2.5 % slower than (4, 2, 1) at every
 # full call (18.8, 30.3 at eight tiles) and the same under the windows;
 # a group of eight does not fit beside eight tiles. Under a window the
 # longest row of live tiles bounds the chunk: five tiles at W 4096, where
@@ -1386,6 +1385,23 @@ def _choose_blocks(seq_len: int, head_dim: int, itemsize: int,
 # fall in one chunk) for 2.5 and 4.4 times the tiles fetched.
 _CHUNK_LADDER = (8, 4, 2, 1)
 _STRAIGHT = (4, 2, 1)
+# What a straight-line group of FOUR tiles holds live beyond one tile's
+# temporaries where a head is wider than one lane tile. Read from the
+# compiler for a described v5e (PR 63: the least ``vmem_limit_bytes`` at
+# which ``flash_fwd`` lowers, bf16, 512-row q blocks; MiB, estimate without
+# this term -> allocation):
+#   128 | 128, k edge 1024:  8 tiles 14.75 -> 15.02   4 tiles 10.75 -> 11.02
+#   192 | 128, k edge 1024:  8 tiles 17.25 -> 21.73   4 tiles 12.25 -> 15.73
+#   256 | 256, k edge  512:  8 tiles 14.00 -> 17.48   4 tiles 10.00 -> 13.48
+# (two tiles a step read UNDER the estimate at every width: 5.2 for 9.75 at
+# 192 | 128, 8.6 for 8.0 at 256 | 256.) At one lane tile the estimate is
+# 0.27 short, at the wider heads 3.48 at either k edge and either chunk.
+# Eight tiles at 256 | 256 were taken by the estimate (14.0 of 16) and
+# refused on the chip inside a program whose neighbours left the call
+# 16 MiB ("scoped allocation with size 17.48M", the cell's own check of
+# the call at [64, 8192, 256]); four fit everywhere. 192 | 128 keeps its
+# four tiles: 15.75 of 16, its reading to the hundredth.
+_WIDE_GROUP_BYTES = 7 * 512 * 1024
 
 
 def _forward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
@@ -1393,14 +1409,17 @@ def _forward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
     """Bytes the streamed forward keeps in VMEM at ``chunk`` k tiles a
     grid step: its pipelined operands twice (K and V of the chunk, q, out
     and the lse row), the three scratch accumulators, the f32 copies of q
-    and of ONE tile of K and V, and a tile's temporaries (S, P and the
-    carried acc)."""
+    and of ONE tile of K and V, a tile's temporaries (S, P and the
+    carried acc) and, in a straight-line group of ``_STRAIGHT[0]`` tiles
+    of heads wider than a lane tile, ``_WIDE_GROUP_BYTES`` more."""
     pair = head_dim + v_dim
     operands = (chunk * block_k + block_q) * pair * itemsize + block_q * 4
     scratch = block_q * (v_dim + 2 * _LANES) * 4
     upcast = (block_q * head_dim + block_k * pair) * 4
+    wide = chunk >= _STRAIGHT[0] and max(head_dim, v_dim) > _LANES
     return (2 * operands + scratch + upcast
-            + (2 * block_k + v_dim) * block_q * 4)
+            + (2 * block_k + v_dim) * block_q * 4
+            + (_WIDE_GROUP_BYTES if wide else 0))
 
 
 def _choose_chunk(seq_len: int, head_dim: int, itemsize: int, block_q: int,

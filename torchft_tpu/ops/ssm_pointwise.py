@@ -1174,7 +1174,8 @@ def kda_ogate(o, gate, scale, eps: float, gated):
     ``[1, D]``: the norm and the gate's activation are the caller's) ->
     ``y [B, S, H·D]`` in ``o``'s dtype, differentiable in ``o``,
     ``gate`` and ``scale``. On the TPU ``H·D`` must be whole blocks of
-    ``lcm(D, 128)`` lanes."""
+    ``lcm(D, 128)`` lanes (a 128-wide head is one lane tile:
+    ``models/qwen3_next.py``'s, with ``models/olmo_hybrid.py``'s body)."""
     width, head = o.shape[-1], scale.shape[-1]
     if (o.ndim != 3 or o.shape != gate.shape or scale.ndim != 1
             or width % head):
