@@ -367,6 +367,26 @@ def _jaxpr_hash(fn, *args):
 #   its transpose); the other 40 and 28 are those of 7be2396, and on the
 #   device the flash call under gqa_core carries the names it carried.
 #   olmoe, joyai and kimi (equal head counts) did not move
+# - joyai, nemotron_h, lfm2 and kimi again at PR 64: the router of
+#   models/common.py::routed_sublayer stands under a custom_vjp (_route:
+#   the scores' matmul, moe.top_k_routing, the loads' count) whose outputs
+#   and chosen scores pass through four ``name`` equations
+#   (router_choice), and top_k_routing takes the chosen scores by compare
+#   and select (moe.take_chosen) where it gathered: the gather, its
+#   scatter-add in the transpose and the [N, E]-wide backward of the
+#   sigmoid and the top-k are out of the four programs. The backward
+#   rule's equations (the routing again on the [N, k] chosen columns, the
+#   select's transpose, the two products) stand under five new paths a
+#   model (ten in joyai, whose MTP block routes too):
+#   "transpose(jvp(mlp))/moe_router/moe_router" and, under it, "/jvp()",
+#   "/transpose()", "/transpose(jvp())" and
+#   "/transpose(transpose(jvp(mlp)))/moe_router/moe_router" — each holds
+#   moe_router, the token benchmark/readers/moe_scopes.py classifies by —,
+#   and no path of the earlier sets went. The tiny configurations have
+#   remat off, so these programs hold no checkpoint equation and no
+#   policy: tests/test_router_once.py holds the remat=True programs, seven
+#   models. olmoe (its own _moe_sublayer; top_k_routing without a bias is
+#   lax.top_k) did not move
 PROGRAMS_THAT_WERE = {
     "olmoe": (
         olmoe, olmoe.OLMOE_CONFIGS["olmoe_tiny"],
@@ -375,24 +395,24 @@ PROGRAMS_THAT_WERE = {
         24),
     "joyai": (
         joyai, joyai.JOYAI_CONFIGS["joyai_tiny"],
-        "1c74cd8031aed88edcf9c962c458677fcccb217cedef892342f8357051ff2834",
-        "b6eb31ce88265df1a34020e32d232f563094c86d492d8e125f707dcdee94bd0f",
-        68),
+        "83bed6ba00118309b8e4e3bd482aeeaf6d2c400b196f24c95a49a86e24915486",
+        "a986964025d2e54fa276c6dd2847394ccefa4fbf71ff454a674f40b3b53bd09f",
+        78),
     "nemotron_h": (
         nemotron_h, CFG,
-        "bb1157a9290c54acf5755cec187e0a777e34376b06df84b0edefe6a9cce7f099",
-        "7bf2e754cf45add0c8e060e7a6342a6c2b54e8a9d13baadbbed1d9048d77719c",
-        44),
+        "6bff4ba2124701f2a6d732455677265dde5ba25acb676e3af648fe7d094a39e7",
+        "f4bd4e403a7ce0556c35bc44b42638afb2bcb7a77f43084432d2541d92ea5bf3",
+        49),
     "lfm2": (
         lfm2, lfm2.LFM2_CONFIGS["lfm2_tiny"],
-        "dbfed6dd1f7e645635f6442311b5fbdde684ffe03f86660cf8d2e592f92c4560",
-        "5c68d83b6aad7a6b41fd31c37684ab6b17e395bc1222f50b7299eecb9af6851b",
-        32),
+        "17705a7f94c4d118162cf201d27d7bd33b029aaeed363f876adce845c24d3e6e",
+        "fc9fc0fcd379279e7fadc81e6bce88257a18c4fe8c76ad2fde05f48983d2b517",
+        37),
     "kimi": (
         kimi_linear, kimi_linear.KIMI_LINEAR_CONFIGS["kimi_linear_tiny"],
-        "3f4d40d876f593fbc640dc37514f359cdf766ae0387aac0e1a21f0fd51d6cb77",
-        "04e92318800edc9b5a6588653c5d38b4e8999cd866d2e694e92aeecaf1d2ebbd",
-        52),
+        "0a8eec0715756b2b70ae0e76b03eeba3c91312a19bbc9a447db4ab33e82b86c9",
+        "fb66e4791c929a6ba0c6a89bab11d59f9493a4b380b84c9bdb297ddfed172d29",
+        57),
 }
 
 

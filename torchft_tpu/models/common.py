@@ -15,8 +15,9 @@ have in common: the router's
 balance bias — its key in the parameter tree, the predicate
 ``optim.with_balance_bias`` partitions the leaves by, and the way a
 step's loads reach that rule in the gradient tree at the bias's place —
-the routed-expert sublayer, the record of a forward pass's expert layers
-and the terms of the loss.
+the routed-expert sublayer, whose router runs once a step (its decision
+crosses the layer's checkpoint by name: ``checkpoint_layer``), the record
+of a forward pass's expert layers and the terms of the loss.
 
 Those models' whole gradient programs are pinned instruction by
 instruction and scope by scope (``tests/test_nemotron_h.py::
@@ -27,16 +28,19 @@ what nobody reads, so a line added here is a line in every pin.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+import functools
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.ops import moe
 
 __all__ = ["rms_norm", "embed", "rotary", "repeat_kv", "swiglu",
            "dense_sublayer",
            "BALANCE_BIAS", "is_balance_bias", "loads_as_gradient",
+           "ROUTER_CHOICE", "checkpoint_layer",
            "routed_sublayer", "routing_record", "share_loss_terms"]
 
 
@@ -139,6 +143,100 @@ _loads_as_gradient.defvjp(_loads_fwd, _loads_bwd)
 loads_as_gradient = _loads_as_gradient
 
 
+# the one name under which a router's decision crosses a layer's
+# ``jax.checkpoint`` (:func:`checkpoint_layer`)
+ROUTER_CHOICE = "router_choice"
+
+
+def checkpoint_layer(run: Callable) -> Callable:
+    """``jax.checkpoint`` of a layer that keeps what its router decided:
+    the backward pass runs the layer's forward again but for the values
+    tagged :data:`ROUTER_CHOICE` (``_route_fwd``: the experts chosen,
+    their weights, the chosen scores, the loads — three ``[N, k]`` arrays
+    and an ``[E]``), which the forward pass saves. Nothing else is
+    saveable; a layer without a router is checkpointed as by plain
+    ``jax.checkpoint``."""
+    return jax.checkpoint(
+        run, policy=jax.checkpoint_policies.save_only_these_names(
+            ROUTER_CHOICE))
+
+
+class _How(NamedTuple):
+    """A sublayer's routing: static, and hashable for ``_route``."""
+    top_k: int
+    score: str          # "sigmoid" | "softmax"
+    scale: float
+    eps: float
+    n_routed: int
+
+
+def _weigh(how: _How, inputs, bias):
+    """``moe.top_k_routing`` on what the router hands it — ``sigmoid`` of
+    the logits, or the logits themselves for ``score="softmax"`` — with
+    the sublayer's arguments, looked up on ``moe`` at call time: over all
+    the experts in the forward pass, over the chosen columns in the
+    backward (``_route_bwd``)."""
+    if how.score == "sigmoid":
+        return moe.top_k_routing(inputs, how.top_k, bias=bias,
+                                 renormalise=True, scale=how.scale,
+                                 eps=how.eps)
+    return moe.top_k_routing(inputs, how.top_k, bias=bias, softmax=True,
+                             scale=how.scale)
+
+
+def _route_fwd(how: _How, r32, kernel, bias):
+    scores = jnp.dot(r32, kernel, precision=jax.lax.Precision.HIGHEST)
+    inputs = jax.nn.sigmoid(scores) if how.score == "sigmoid" else scores
+    weights, experts = _weigh(how, inputs, bias)
+    loads = jnp.zeros((how.n_routed,), jnp.float32).at[
+        experts.reshape(-1)].add(1.0)
+    # the routing's own gather again: one instruction once compiled
+    chosen = moe.take_chosen(inputs, experts)
+    weights, experts, loads, chosen = (
+        checkpoint_name(a, ROUTER_CHOICE)
+        for a in (weights, experts, loads, chosen))
+    return (weights, experts, loads), (r32, kernel, bias, experts, chosen)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _route(how: _How, r32, kernel, bias):
+    """``(weights [N, k], experts [N, k], loads [E])`` of the float32
+    router input ``r32 [N, d]``: the scores' matmul, the routing, the
+    count. Its backward is ``k`` wide (``_route_bwd``)."""
+    return _route_fwd(how, r32, kernel, bias)[0]
+
+
+def _route_bwd(how: _How, res, cotangents):
+    """The weights' cotangent through the weighting of the CHOSEN columns
+    alone — ``moe.top_k_routing`` again, on the ``[N, k]`` slice of what
+    it was handed, the chosen experts' bias beside it: ``k`` of ``k``
+    columns that stand in the order it chose them in come back in that
+    order —, through the sigmoid where there is one, put back at its
+    columns of a zero ``[N, E]`` (``moe.take_chosen`` transposed), then
+    the matmul's two products. The choice differentiated is the one the
+    forward pass made; nothing reaches the bias, and the loads are a
+    count."""
+    r32, kernel, bias, experts, chosen = res
+    with jax.named_scope("moe_router"):
+        _, pull = jax.vjp(
+            lambda c: _weigh(how, c, moe.take_chosen(bias[None], experts))[0],
+            chosen)
+        g_chosen, = pull(cotangents[0])
+        if how.score == "sigmoid":
+            g_chosen = g_chosen * chosen * (1 - chosen)
+        all_columns = jax.ShapeDtypeStruct(
+            (experts.shape[0], how.n_routed), chosen.dtype)
+        g_scores, = jax.linear_transpose(
+            lambda s: moe.take_chosen(s, experts), all_columns)(g_chosen)
+        highest = jax.lax.Precision.HIGHEST
+        return (jnp.dot(g_scores, kernel.T, precision=highest),
+                jnp.dot(r32.T, g_scores, precision=highest),
+                jnp.zeros_like(bias))
+
+
+_route.defvjp(_route_fwd, _route_bwd)
+
+
 @jax.named_scope("mlp")
 def routed_sublayer(cfg, x, scale, m: Dict, *,
                     shared: Optional[Callable] = None,
@@ -156,6 +254,17 @@ def routed_sublayer(cfg, x, scale, m: Dict, *,
     expert. The record: ``experts`` [N, top_k], ``loads`` [routed]
     (float32 counts), and ``carrier``, the zero that hands the loads to
     the bias's place in the gradient tree.
+
+    The router runs once a step. From the scores' matmul to ``(weights,
+    experts, loads)`` it is one ``custom_vjp`` (``_route``) whose backward
+    reads the chosen experts and their scores and nothing else ``[N, E]``
+    wide, and those, with the weights and the loads, are tagged
+    :data:`ROUTER_CHOICE`: a layer under :func:`checkpoint_layer` keeps
+    them, so its backward pass recomputes the norm (the experts read
+    ``h`` again) and not the matmul, the top-k or the count. The WEIGHTS
+    are among what is kept because the experts' combine reads them in its
+    own backward: left out, the recomputation that rebuilds them is the
+    whole router again.
 
     What SmallThinker changes, each a default that leaves the other
     models' traced programs what they are: ``route_on`` [B, S, d], the
@@ -177,19 +286,10 @@ def routed_sublayer(cfg, x, scale, m: Dict, *,
             r32 = route_on.astype(jnp.float32).reshape(B * S, -1)
         # as models/olmoe.py: the router reads the normed stream before
         # it is rounded to the compute dtype, in true float32
-        scores = jnp.dot(
-            r32, m["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
-        if score == "sigmoid":
-            weights, experts = moe.top_k_routing(
-                jax.nn.sigmoid(scores), cfg.top_k, bias=m[BALANCE_BIAS],
-                renormalise=True, scale=cfg.routed_scale, eps=renorm_eps)
-        else:
-            weights, experts = moe.top_k_routing(
-                scores, cfg.top_k, bias=m[BALANCE_BIAS], softmax=True,
-                scale=cfg.routed_scale)
-        loads = jnp.zeros((cfg.n_routed_experts,), jnp.float32).at[
-            experts.reshape(-1)].add(1.0)
+        weights, experts, loads = _route(
+            _How(cfg.top_k, score, cfg.routed_scale, renorm_eps,
+                 cfg.n_routed_experts),
+            r32, m["router"]["kernel"].astype(jnp.float32), m[BALANCE_BIAS])
         carrier = loads_as_gradient(
             m[BALANCE_BIAS], loads.astype(m[BALANCE_BIAS].dtype))
     if route_on is None:

@@ -46,9 +46,14 @@ repo's batches ``Emb(t_{i+1})`` is ``Emb(targets)`` and ``t_{i+2}`` is
 
 Conventions of ``models/olmoe.py``: float32 parameters, bf16 compute,
 float32 norms / router / softmax, an explicit parameter tree with stable
-paths, per-layer ``jax.checkpoint`` behind ``remat``, and the step
+paths, per-layer ``checkpoint_layer`` behind ``remat``, and the step
 programs of ``transformer.make_train_step`` / ``make_grad_step``
 (``loss=joyai.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; ``attn`` with inner ``mla_q`` (down,
 norm, up, RoPE), ``mla_kv`` (down, norm, up, RoPE, laying ``k`` out),
@@ -69,6 +74,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     dense_sublayer,
     embed,
     is_balance_bias,
@@ -319,7 +325,7 @@ def forward_hidden(cfg: JoyaiConfig, params: Dict, tokens, next_tokens=None,
     dense = functools.partial(_dense_block, cfg, attn_fn=attn_fn)
     expert = functools.partial(_expert_block, cfg, attn_fn=attn_fn)
     if cfg.remat:
-        dense, expert = jax.checkpoint(dense), jax.checkpoint(expert)
+        dense, expert = checkpoint_layer(dense), checkpoint_layer(expert)
     x = embed(cfg, params, tokens)
     records = []
     for i in range(cfg.n_layers):

@@ -61,9 +61,14 @@ projections stay bf16 up to its kernels, which upcast on load, compute
 in f32 and round ``q``, ``k`` and ``y`` once; of the stream's size ``[B,
 S, H·D]`` only ``g`` and its cotangent are f32 in HBM), an explicit parameter tree
 with stable paths ``layers_<i>/{ln_1, ln_2}``, ``layers_<i>/{kda|attn}``,
-``layers_<i>/{mlp|moe}``, per-layer ``jax.checkpoint`` behind ``remat``,
+``layers_<i>/{mlp|moe}``, per-layer ``checkpoint_layer`` behind ``remat``,
 and the step programs of ``transformer.make_train_step`` /
 ``make_grad_step`` (``loss=kimi_linear.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; both mixers under ``attn``, told apart
 inside — ``kda_in`` (norm, the five projections), ``kda_conv`` (the
@@ -90,6 +95,7 @@ import jax.numpy as jnp
 from torchft_tpu.models import joyai
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     dense_sublayer,
     embed,
     is_balance_bias,
@@ -342,7 +348,7 @@ def forward_hidden(cfg: KimiLinearConfig, params: Dict, tokens,
         run = functools.partial(_layer, cfg, cfg.is_kda(i),
                                 i < cfg.n_dense_layers, attn_fn=attn_fn)
         if cfg.remat:
-            run = jax.checkpoint(run)
+            run = checkpoint_layer(run)
         x, rec = run(params[f"layers_{i}"], x)
         if rec is not None:
             records.append(rec)

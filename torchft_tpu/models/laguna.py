@@ -41,10 +41,15 @@ Conventions of ``models/lfm2.py``: float32 parameters, bf16 compute,
 float32 norms, router, gate logits and rotation tables, an explicit
 parameter tree with stable paths ``layers_<i>/{norm_1,norm_2}``,
 ``layers_<i>/attn/...`` and ``layers_<i>/mlp/...`` (dense) or
-``layers_<i>/moe/...`` (sparse), per-layer ``jax.checkpoint`` behind
+``layers_<i>/moe/...`` (sparse), per-layer ``checkpoint_layer`` behind
 ``remat``, a plain Python loop over layers whose shapes differ, and the
 step programs of ``transformer.make_train_step`` / ``make_grad_step``
 (``loss=laguna.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 The rotation and the gate's multiply are Pallas kernels
 (``ops/ssm_pointwise.py::rotary`` from tables built once a step,
@@ -75,6 +80,7 @@ import numpy as np
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     dense_sublayer,
     embed,
     is_balance_bias,
@@ -364,7 +370,7 @@ def forward_hidden(cfg: LagunaConfig, params: Dict, tokens,
         run = functools.partial(_layer, cfg, bool(windowed), bool(is_sparse),
                                 attn_fn=attn_fn)
         if cfg.remat:
-            run = jax.checkpoint(run)
+            run = checkpoint_layer(run)
         x, rec = run(params[f"layers_{i}"], x, tables[bool(windowed)])
         if rec is not None:
             records.append(rec)

@@ -46,9 +46,14 @@ Conventions of ``models/joyai.py`` and ``models/nemotron_h.py``: float32
 parameters, bf16 compute, float32 norms / router / taps, an explicit
 parameter tree with stable paths ``layers_<i>/{norm_1,norm_2}`` and
 ``layers_<i>/{conv|attn}/...``, ``layers_<i>/{mlp|moe}/...``, per-layer
-``jax.checkpoint`` behind ``remat``, and the step programs of
+``checkpoint_layer`` behind ``remat``, and the step programs of
 ``transformer.make_train_step`` / ``make_grad_step``
 (``loss=lfm2.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; both mixers under ``attn``, told apart
 inside — ``sconv_in`` (norm, ``W_in``), ``sconv_core`` (the kernels),
@@ -71,6 +76,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     dense_sublayer,
     embed,
     is_balance_bias,
@@ -289,7 +295,7 @@ def forward_hidden(cfg: Lfm2Config, params: Dict, tokens,
         run = functools.partial(_layer, cfg, kind, i < cfg.n_dense_layers,
                                 attn_fn=attn_fn)
         if cfg.remat:
-            run = jax.checkpoint(run)
+            run = checkpoint_layer(run)
         x, rec = run(params[f"layers_{i}"], x)
         if rec is not None:
             records.append(rec)

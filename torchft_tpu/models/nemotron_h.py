@@ -46,9 +46,14 @@ the gradient tree are ``models/common.py``'s (JoyAI's too).
 Conventions of ``models/joyai.py``: float32 parameters, bf16 compute,
 float32 norms / router / softplus / decays / convolution taps, an
 explicit parameter tree with stable paths ``layers_<i>/norm`` and
-``layers_<i>/{mamba|attn|moe}/...``, per-layer ``jax.checkpoint`` behind
+``layers_<i>/{mamba|attn|moe}/...``, per-layer ``checkpoint_layer`` behind
 ``remat``, and the step programs of ``transformer.make_train_step`` /
 ``make_grad_step`` (``loss=nemotron_h.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 ``_conv_silu`` and ``_gated_norm`` are seams over ``ops/ssm_pointwise.py``
 (one fused kernel forward and one backward each, reading ``xBC`` / ``y``,
@@ -79,6 +84,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     embed,
     is_balance_bias,
     rms_norm,
@@ -335,7 +341,7 @@ def forward_hidden(cfg: NemotronHConfig, params: Dict, tokens,
         "E": functools.partial(_moe_mixer, cfg),
     }
     if cfg.remat:
-        mixers = {k: jax.checkpoint(f) for k, f in mixers.items()}
+        mixers = {k: checkpoint_layer(f) for k, f in mixers.items()}
     x = embed(cfg, params, tokens)
     records = []
     for i, letter in enumerate(cfg.pattern):

@@ -60,9 +60,14 @@ compute, float32 norms / softmax statistics / l2 norms / ``g`` / ``β`` /
 router / both gates' logits / rotation tables / the delta rule's state;
 an explicit parameter tree with stable paths ``layers_<i>/{norm_1,
 norm_2}``, ``layers_<i>/{gdn|attn}``, ``layers_<i>/moe``; per-layer
-``jax.checkpoint`` behind ``remat``; the step programs of
+``checkpoint_layer`` behind ``remat``; the step programs of
 ``transformer.make_train_step`` / ``make_grad_step``
 (``loss=qwen3_next.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; both mixers under ``attn`` — ``gdn_in``
 (the input norm, the projections, ``g`` and ``β``), ``gdn_conv`` (the
@@ -91,6 +96,7 @@ import numpy as np
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     embed,
     is_balance_bias,
     rms_norm,
@@ -467,7 +473,7 @@ def forward_hidden(cfg: Qwen3NextConfig, params: Dict, tokens,
     for i, kind in enumerate(cfg.layer_types):
         run = functools.partial(_layer, cfg, kind, attn_fn=attn_fn)
         if cfg.remat:
-            run = jax.checkpoint(run)
+            run = checkpoint_layer(run)
         x, rec = run(params[f"layers_{i}"], x, table)
         records.append(rec)
     return (rms_norm(x, unit_plus(params["ln_f"]["scale"]), cfg.rms_eps),

@@ -40,9 +40,14 @@ The balance bias ``b`` and the way its loads reach
 Conventions of ``models/lfm2.py``: float32 parameters, bf16 compute,
 float32 norms / router, an explicit parameter tree with stable paths
 ``layers_<i>/{norm_1,norm_2}``, ``layers_<i>/attn/...``,
-``layers_<i>/moe/...``, per-layer ``jax.checkpoint`` behind ``remat``,
+``layers_<i>/moe/...``, per-layer ``checkpoint_layer`` behind ``remat``,
 and the step programs of ``transformer.make_train_step`` /
 ``make_grad_step`` (``loss=smallthinker.loss_fn``).
+
+``checkpoint_layer`` (``models/common.py``) is ``jax.checkpoint`` that
+keeps what a layer's router decided — the experts, their weights, the
+chosen scores, the loads —, so the backward pass does not run the router
+again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; ``attn`` with ``gqa_proj`` (the norm, q /
 k / v, RoPE where the list says, the repeat, ``W_o``) and ``gqa_core``
@@ -64,6 +69,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
+    checkpoint_layer,
     embed,
     is_balance_bias,
     rms_norm,
@@ -255,7 +261,7 @@ def forward_hidden(cfg: SmallThinkerConfig, params: Dict, tokens,
         run = functools.partial(_layer, cfg, bool(windowed), bool(rotated),
                                 attn_fn=attn_fn)
         if cfg.remat:
-            run = jax.checkpoint(run)
+            run = checkpoint_layer(run)
         x, rec = run(params[f"layers_{i}"], x)
         records.append(rec)
     out = routing_record(records)

@@ -48,9 +48,10 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Dispatch", "top_k_routing", "sort_by_expert", "gather_tokens",
-           "grouped_matmul", "swiglu_experts", "reglu_experts",
-           "relu2_experts", "combine", "held_capacity", "moe_mlp"]
+__all__ = ["Dispatch", "take_chosen", "top_k_routing", "sort_by_expert",
+           "gather_tokens", "grouped_matmul", "swiglu_experts",
+           "reglu_experts", "relu2_experts", "combine", "held_capacity",
+           "moe_mlp"]
 
 
 class Dispatch(NamedTuple):
@@ -60,6 +61,21 @@ class Dispatch(NamedTuple):
     order: Any         # [N*k] int32
     inverse: Any       # [N*k] int32
     group_sizes: Any   # [E] int32, rows per expert, sums to N*k
+
+
+def take_chosen(values: Any, experts: Any) -> Any:
+    """``values[n, experts[n, j]]`` as ``[N, k]``, of ``values`` ``[N, E]``
+    (or ``[1, E]``: the same row for every token), by compare and select:
+    a sum over ``E`` with one term that is not zero, so the bits are a
+    gather's (``jnp.take_along_axis``; a chosen ``-0.0`` alone reads
+    ``+0.0``), and its transpose — the ``[N, k]`` cotangents put back at
+    their columns of ``[N, E]`` — is a sum over ``k``, not a scatter.
+    XLA's TPU gather and scatter move an element at a time (3.3 – 3.5 ms
+    for ``32768 x 10`` of 512 columns or of 10); the fused select is a
+    pass over ``N·k·E`` (0.5 and 0.25 ms there: PERF.md, PR 64)."""
+    columns = jnp.arange(values.shape[-1], dtype=experts.dtype)
+    return jnp.sum(jnp.where(experts[..., None] == columns,
+                             values[:, None, :], 0), axis=-1)
 
 
 def top_k_routing(scores: Any, k: int, bias: Any = None,
@@ -82,7 +98,7 @@ def top_k_routing(scores: Any, k: int, bias: Any = None,
     else:
         _, experts = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias).astype(scores.dtype), k)
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        weights = take_chosen(scores, experts)
     if softmax:
         weights = jax.nn.softmax(weights, axis=-1)
     if renormalise:
