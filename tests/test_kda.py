@@ -13,9 +13,10 @@ import pytest
 
 from benchmark.reference.kimi_linear_f32 import kda_recurrence
 from benchmark.reference.olmo_hybrid_f32 import gdn_recurrence
-from one_program import value_and_pullback
+from one_program import pallas_calls, value_and_pullback
 from torchft_tpu.ops import kda
 from torchft_tpu.ops.kda import _choose_chunk, gdn_scan, kda_scan
+from torchft_tpu.utils.metrics import TRACED
 
 
 LEAVES = ("dq", "dk", "dv", "dg", "dbeta")
@@ -234,18 +235,8 @@ def test_a_head_count_takes_the_largest_rung_that_divides_it(ladder, h):
 def _grids(fn, *args):
     """``{kernel's name: its grid}`` over the ``pallas_call``s of ``fn``'s
     jaxpr, nested ones too."""
-    seen = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                seen[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return seen
+    return {name: tuple(eqn.params["grid_mapping"].grid)
+            for name, eqn in pallas_calls(fn, *args).items()}
 
 
 def test_the_rule_and_the_grid_at_the_cells_shape(ladder) -> None:
@@ -370,9 +361,14 @@ def test_each_scan_refuses_the_others_decay() -> None:
     q, k, v, g, beta = args
     with pytest.raises(ValueError, match="kda_scan"):
         kda_scan(*args)
+    three = [jnp.concatenate([z, z[:, :, :1]], axis=2) for z in (v, g, beta)]
     for bad in ((q, k, v, g[:, :8], beta), (q, k, v, g, beta[..., :1]),
                 (q, k[..., :4], v, g, beta), (q, k, v[:, :8], g, beta),
-                (q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)):
+                (q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta),
+                # two key heads do not divide three value heads; the decay
+                # and the step stand at the VALUE heads; k at q's
+                (q, k, *three), (q[:, :, :1], k, v, g, beta),
+                (q[:, :, :1], k[:, :, :1], v, g[..., :1], beta)):
         with pytest.raises(ValueError, match="gdn_scan.*do not fit"):
             gdn_scan(*bad)
 
@@ -481,6 +477,144 @@ def test_scalar_decay_heads_that_share_a_step_change_no_bit(
         np.testing.assert_array_equal(a, b, err_msg=name)
 
 
+# -- more value heads than key heads: q and k read where they lie -------------
+
+
+def _copied(z, r):
+    return jnp.repeat(z, r, axis=2)
+
+
+def _key_head_sum(z, r):
+    """``[B, S, H_k·r, K]`` -> the f32 sum over a key head's ``r`` value
+    heads."""
+    b, s, h, kd = z.shape
+    return z.reshape(b, s, h // r, r, kd).sum(axis=3)
+
+
+@pytest.mark.parametrize("hk,hv,rung", [
+    (2, 4, 4),       # two key heads a step, each read by two value heads
+    (1, 4, 4),       # one key head, four value heads: r = 4
+    (3, 6, 4),       # two steps; the second holds a key head outside q
+    (2, 2, 2),       # r = 1: the program of equal heads, two a step
+], ids=lambda n: str(n))
+def test_value_head_h_reads_key_head_h_over_r_where_it_lies(
+        gdn_ladder, hk, hv, rung) -> None:
+    """``gdn_scan`` handed q and k at the KEY heads against itself on
+    ``jnp.repeat``-ed copies, at the same rung: ``o``, ``dv``, ``dg``,
+    ``dβ`` bit for bit (a value head's chain is the same instructions on
+    the same numbers); ``dq`` and ``dk`` are the sum of the copied call's
+    over a key head's value heads, added in f32 inside the kernel; and all
+    six leaves are the recurrence's. Crosses: key and value widths that
+    differ (16 / 32), 40 positions at a chunk of 16 (two boundaries and a
+    ragged end), two batch rows, and with six value heads at four a step a
+    last group whose second key head lies outside the arrays."""
+    gdn_ladder(rung)
+    r = hv // hk
+    (q, k, v, g, beta), do = gdn_inputs(31, 2, 40, hv, 16, 32)
+    q, k = q[:, :, ::r], k[:, :, ::r]
+    assert kda._gdn_heads_a_step(hv, 16, 16, 32, True, r) == rung
+    before = TRACED.snapshot().get("gdn_value_group_copies", 0)
+    with jax.default_matmul_precision("highest"):
+        got, grads = value_and_pullback(
+            lambda *a: gdn(*a, chunk=16), (q, k, v, g, beta), do)
+        copied = (_copied(q, r), _copied(k, r), v, g, beta)
+        same, grads_same = value_and_pullback(
+            lambda *a: gdn(*a, chunk=16), copied, do)
+        want, grads_ref = value_and_pullback(gdn_recurrence, copied, do)
+    assert TRACED.snapshot().get("gdn_value_group_copies", 0) == before
+    np.testing.assert_array_equal(got, same, err_msg="o")
+    assert rel(got, want) < 2e-6
+    for name, a, b, ref in zip(LEAVES, grads, grads_same, grads_ref):
+        if name in ("dq", "dk"):
+            assert a.shape == q.shape, name
+            b, ref = _key_head_sum(b, r), _key_head_sum(ref, r)
+            assert rel(a, b) < 1e-6, name
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        assert rel(a, ref) < 5e-6, name
+
+
+def _gdn_calls(fn, *args):
+    """``{kernel's name: (its grid, the lanes of its q block, the lanes
+    of q)}`` over the ``pallas_call``s of ``fn``'s jaxpr."""
+    def of(eqn):
+        mapping = eqn.params["grid_mapping"]
+        return (tuple(mapping.grid),
+                mapping.block_mappings[0].block_shape[-1].block_size,
+                eqn.invars[0].aval.shape[-1])
+    return {name: of(eqn) for name, eqn in pallas_calls(fn, *args).items()}
+
+
+def _gdn_shapes(b, s, hk, hv, kd, vd):
+    keys = jax.ShapeDtypeStruct((b, s, hk, kd), jnp.bfloat16)
+    values = jax.ShapeDtypeStruct((b, s, hv, vd), jnp.bfloat16)
+    scalar = jax.ShapeDtypeStruct((b, s, hv), jnp.float32)
+    return keys, keys, values, scalar, scalar, values
+
+
+def _both_gdn_kernels(*a):
+    return jax.vjp(gdn_scan, *a[:-1])[1](a[-1])
+
+
+def test_the_head_group_rule_with_value_heads_a_key_head(
+        monkeypatch, gdn_ladder) -> None:
+    """``qwen3next-ep16-solo-steady``'s call, ``[4, 8192]`` of 16 key and
+    32 value heads of 128, as the TPU takes it: four VALUE heads a step
+    are two key heads — 256 lanes of q and of k beside 512 of v —, the
+    grid ``(4, 8, 64)`` it had on copied operands, and q, k (and ``dq``,
+    ``dk``) ``H_k·K`` = 2 048 wide: nothing ``H_v·K`` wide goes in. A rung
+    is whole key heads whose lanes are whole tiles; where none is, the op
+    copies q and k itself, counts it and runs at equal heads."""
+    gdn_ladder(*GDN_LADDER)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    assert kda._gdn_heads_a_step(32, 128, 128, 128, False, 2) == 4
+    assert kda._gdn_rungs(128, 128, 128, False, 2) == [6, 4, 2]
+    assert kda._gdn_rungs(128, 128, 128, False, 4) == [4]
+    assert kda._gdn_rungs(128, 64, 128, False, 2) == [4]    # 2 x 64 a tile
+    assert kda._gdn_rungs(128, 64, 128, False, 3) == [6]
+    assert kda._gdn_rungs(128, 128, 128, False, 1) == list(GDN_LADDER)
+
+    def copies():
+        return TRACED.snapshot().get("gdn_value_group_copies", 0)
+
+    before = copies()
+    calls = _gdn_calls(_both_gdn_kernels, *_gdn_shapes(4, 8192, 16, 32, 128,
+                                                       128))
+    assert calls == {"gdn_fwd": ((4, 8, 64), 256, 2048),
+                     "gdn_bwd": ((4, 8, 64), 256, 2048)}
+    assert copies() == before
+    # no rung is a multiple of 7; of 96-wide key heads read by two value
+    # heads each only 4 key heads = 8 value heads are whole tiles
+    for hk, hv, kd, vd, at in ((1, 7, 128, 128, 1), (15, 30, 96, 192, 4)):
+        assert kda._gdn_rungs(128, kd, vd, False, hv // hk) == []
+        before = copies()
+        calls = _gdn_calls(_both_gdn_kernels,
+                           *_gdn_shapes(1, 256, hk, hv, kd, vd))
+        assert copies() == before + 1
+        steps = (1, -(-hv // at), 2)
+        assert calls == {"gdn_fwd": (steps, at * kd, hv * kd),
+                         "gdn_bwd": (steps, at * kd, hv * kd)}
+
+
+def test_a_group_no_rung_fits_is_copied_and_counted(gdn_ladder) -> None:
+    """Seven value heads on one key head: no rung is a multiple of seven,
+    in the interpreter either. The op copies q and k, says so, and is
+    the recurrence; ``dq`` and ``dk`` come back at the key head."""
+    gdn_ladder(*GDN_LADDER)
+    (q, k, v, g, beta), do = gdn_inputs(33, 1, 24, 7, 8, 16)
+    q, k = q[:, :, :1], k[:, :, :1]
+    before = TRACED.snapshot().get("gdn_value_group_copies", 0)
+    with jax.default_matmul_precision("highest"):
+        got, grads = value_and_pullback(gdn_scan, (q, k, v, g, beta), do)
+        want, grads_ref = value_and_pullback(
+            lambda q, k, *rest: gdn_recurrence(
+                _copied(q, 7), _copied(k, 7), *rest), (q, k, v, g, beta), do)
+    assert TRACED.snapshot().get("gdn_value_group_copies", 0) == before + 1
+    assert rel(got, want) < 2e-6
+    for name, a, ref in zip(LEAVES, grads, grads_ref):
+        assert a.shape == ref.shape and rel(a, ref) < 5e-6, name
+
+
 # sha256 of the jaxpr (source positions cut out) of the gradient of
 # ``kda_scan`` at Kimi's call ([4, 8192] of 32 heads of 128, bf16 q / k /
 # v, f32 g a channel and β): both kernels, their grids and every
@@ -510,6 +644,43 @@ def test_kimis_call_traces_to_the_kernels_it_traced_to(ladder) -> None:
     assert "name=kda_fwd" in text and "name=kda_bwd" in text
     assert "name=gdn_" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == _KIMI_CALL_JAXPR
+
+
+# The same of ``gdn_scan`` at Olmo Hybrid's call ([1, 8192] of 30 heads of
+# 96 key and 192 value channels, as many key as value heads): as the CPU
+# traces it (the interpreter: f32 matmuls, six heads a step) and as the TPU
+# does (three-pass matmuls, four heads a step). Taken at the commit before
+# a value head could read another head's q and k (65e11e1, PR 64): with as
+# many key as value heads the kernels are the program they were — what
+# ``olmohybrid-vp8-solo-steady`` and both cells' scan comparisons run.
+_OLMO_HYBRID_CALL_JAXPR = {
+    True: "22c61242f72f5fc8782098bb154d63a93eda46c0637fc1be24bfb65669518bb4",
+    False: "dc93918ace149788e4c57696eb15617da609909def44acad9964bc5468d2159b",
+}
+
+
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["as_the_cpu_traces_it", "as_the_tpu_does"])
+def test_olmo_hybrids_call_traces_to_the_kernels_it_traced_to(
+        monkeypatch, gdn_ladder, interpret) -> None:
+    import hashlib
+    import re
+
+    gdn_ladder(*GDN_LADDER)
+    monkeypatch.setattr(kda, "_interpret", lambda: interpret)
+    keys = jax.ShapeDtypeStruct((1, 8192, 30, 96), jnp.bfloat16)
+    values = jax.ShapeDtypeStruct((1, 8192, 30, 192), jnp.bfloat16)
+    scalar = jax.ShapeDtypeStruct((1, 8192, 30), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(gdn_scan(q, k, v, g, beta).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        keys, keys, values, scalar, scalar))
+    text = re.sub(r"/[^ ]*?\.py:\d+", "", text)
+    assert "name=gdn_fwd" in text and "name=gdn_bwd" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _OLMO_HYBRID_CALL_JAXPR[interpret]
 
 
 # -- the kernels at the cell's widths, for a described v5e --------------------
@@ -600,3 +771,44 @@ def test_the_scalar_decay_kernels_compile_for_the_v5e_at_the_cells_widths(
     per_position_states = b * s * h * kd * vd * 4
     assert compiled.memory_analysis().temp_size_in_bytes < \
         per_position_states / 8
+
+
+def test_the_grouped_kernels_compile_for_the_v5e_at_qwen3_nexts_widths(
+        one_chip, gdn_ladder):
+    """[1, 1024] of 16 key and 32 value heads of 128 at the cell's chunk:
+    Mosaic takes a step of four value heads on two key heads (256 lanes
+    of q and k beside 512 of v), and ``dq`` and ``dk`` come back at the
+    key heads."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    gdn_ladder(*GDN_LADDER)
+    b, s, hk, h, d = 1, 1024, 16, 32, 128
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    keys, values = (sd((b, s, n, d), jnp.bfloat16) for n in (hk, h))
+    scalar = sd((b, s, h), jnp.float32)
+
+    def both(q, k, v, g, beta, do):
+        o, pull = jax.vjp(
+            lambda *a: kda._gdn(*a, kda._CHUNK, False), q, k, v, g, beta)
+        return o, pull(do)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        lowered = jax.jit(both).lower(
+            keys, keys, values, scalar, scalar, values)
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert kda._gdn_heads_a_step(h, kda._CHUNK, d, d, False, h // hk) == 4
+    for kernel in ("gdn_fwd", "gdn_bwd"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    dq, dk = compiled.out_info[1][:2]
+    assert dq.shape == dk.shape == (b, s, hk, d)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        b * s * h * d * d * 4 / 8

@@ -2,7 +2,9 @@
 the system against ``benchmark/reference/qwen3_next_f32.py`` — loss, final
 hidden state and every gradient leaf, in f32 and in the cell's precision
 —, the delta-rule mixer against the recurrence with two value heads a key
-head and with one, the rotation over a quarter of the head, both gates,
+head, with one and with four (q and k handed to the scan at the key heads,
+copied only for a stand-in at one of the model's two seams), the rotation
+over a quarter of the head, both gates,
 the faults of ``benchmark/tests/qwen3next_faults.py`` that the CPU can
 show under the cell's own comparison, and the expert share tied to the
 model."""
@@ -21,17 +23,33 @@ from benchmark.families import qwen3_next as family
 from benchmark.reference import qwen3_next_f32
 from benchmark.tests import qwen3next_faults as faults
 from benchmark.tests.lfm2_faults import patched
+from one_program import pallas_calls
 from torchft_tpu.models import common, qwen3_next
 from torchft_tpu.models.qwen3_next import (
     FULL, LINEAR, QWEN3_NEXT_CONFIGS, Qwen3NextConfig, init_params,
     loss_terms,
 )
-from torchft_tpu.ops import ssm_pointwise
+from torchft_tpu.ops import kda, ssm_pointwise
 from torchft_tpu.ops.attention import causal_attention
 from torchft_tpu.utils.metrics import TRACED
 
-# the model's tests are not about how many heads share a grid step
-pytestmark = pytest.mark.usefixtures("one_head_a_step")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def one_key_head_a_step(one_head_a_step):
+    """The model's tests are not about how many heads share a grid step
+    (``conftest.one_head_a_step``), but the delta rule's grid step holds
+    whole KEY heads: the tiny model's two value heads a key head take the
+    rung of two, the smallest on which q and k are read where they lie
+    (at one head a step the op would copy them, and say so)."""
+    patch = pytest.MonkeyPatch()
+    patch.setattr(kda, "_GDN_LADDER", (2,))
+    jax.clear_caches()
+    yield
+    patch.undo()
+    jax.clear_caches()
+
+
 BF16 = QWEN3_NEXT_CONFIGS["qwen3_next_tiny"]
 # float32 compute: the comparison is of the mathematics, not of bf16
 TINY = dataclasses.replace(BF16, dtype=jnp.float32)
@@ -192,29 +210,171 @@ def test_remat_and_chunked_cross_entropy_change_nothing():
             rtol=1e-5)
 
 
-@pytest.mark.parametrize("heads", [(2, 4), (2, 2), (1, 4)],
-                         ids=lambda h: f"{h[0]}k{h[1]}v")
-def test_the_mixer_equals_the_recurrence_whatever_the_value_groups(heads):
-    """The delta-rule mixer alone against the reference's (the recurrence
-    position by position): two value heads a key head, one, and four; the
-    counter moves where the heads differ and there alone."""
+def _mixer_alone(heads):
+    """A one-layer cut of the tiny model with ``heads`` = (key, value)
+    heads, its seeded layer and an input."""
     cfg = dataclasses.replace(TINY, n_key_heads=heads[0],
                               n_value_heads=heads[1],
                               layer_types=(LINEAR,))
-    layer = seeded(cfg, 3)["layers_0"]
-    x = jax.random.normal(jax.random.key(4), (2, SEQ, cfg.d_model))
-    before = TRACED.snapshot().get("gdn_value_group_calls", 0)
-    got = jax.jit(lambda l, x: qwen3_next._gdn_mixer(cfg, l, x))(layer, x)
-    moved = TRACED.snapshot().get("gdn_value_group_calls", 0) - before
-    assert moved == (heads[0] != heads[1])
+    return (cfg, seeded(cfg, 3)["layers_0"],
+            jax.random.normal(jax.random.key(4), (2, SEQ, cfg.d_model)))
+
+
+def _reference_mixer(cfg, layer, x):
     d = dims(cfg)
     with jax.default_matmul_precision("highest"):
-        want = x + qwen3_next_f32._linear(
+        return x + qwen3_next_f32._linear(
             qwen3_next_f32.norm(x, layer["norm_1"]["scale"], cfg.rms_eps),
             layer["gdn"], n_key=d["n_key"], n_value=d["n_value"],
             key_dim=d["key_dim"], value_dim=d["value_dim"], eps=cfg.rms_eps)
+
+
+def _counted(fn, *args):
+    """``(fn(*args), how far the two value-group counters moved)``."""
+    names = ("gdn_value_group_calls", "gdn_value_group_copies")
+    before = TRACED.snapshot()
+    out = fn(*args)
+    after = TRACED.snapshot()
+    return out, tuple(after.get(n, 0) - before.get(n, 0) for n in names)
+
+
+@pytest.fixture
+def a_key_head_and_its_value_heads_a_step(monkeypatch):
+    """Another rung than the file's, where a key head has more than two
+    value heads; whoever moves the ladder clears jax's caches
+    (``conftest.one_head_a_step``)."""
+    moved = []
+
+    def to(rung):
+        if kda._GDN_LADDER != (rung,):
+            monkeypatch.setattr(kda, "_GDN_LADDER", (rung,))
+            jax.clear_caches()
+            moved.append(rung)
+    yield to
+    if moved:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("heads", [(2, 4), (2, 2), (1, 4)],
+                         ids=lambda h: f"{h[0]}k{h[1]}v")
+def test_the_mixer_equals_the_recurrence_whatever_the_value_groups(
+        heads, a_key_head_and_its_value_heads_a_step):
+    """The delta-rule mixer alone against the reference's (the recurrence
+    position by position): two value heads a key head, one, and four; the
+    counter moves where the heads differ and there alone. Where they
+    differ q and k reach ``gdn_fwd`` at the KEY heads — its q operand is
+    ``H_k·K`` wide, no array ``[B, S, H_v, K]`` stands anywhere in the
+    mixer's program, and nothing was copied (a grid step of one key head
+    and its value heads: the file's rung, four at ``1k4v``)."""
+    Hk, Hv = heads
+    a_key_head_and_its_value_heads_a_step(max(2, Hv // Hk))
+    cfg, layer, x = _mixer_alone(heads)
+    got, moved = _counted(jax.jit(
+        lambda l, x: qwen3_next._gdn_mixer(cfg, l, x)), layer, x)
+    assert moved == (Hk != Hv, 0)
+    want = _reference_mixer(cfg, layer, x)
     np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
     assert float(jnp.max(jnp.abs(want - x))) > 1e-2
+    def mixer(l, x):
+        return qwen3_next._gdn_mixer(cfg, l, x)
+
+    q_operand = pallas_calls(mixer, layer, x)["gdn_fwd"].invars[0].aval
+    assert q_operand.shape[-1] == Hk * cfg.key_dim
+    if Hk != Hv:
+        assert f"[2,{SEQ},{Hv},{cfg.key_dim}]" not in str(
+            jax.make_jaxpr(mixer)(layer, x))
+
+
+def _value_heads_swapped(gdn, cfg):
+    """The mixer's weights with value heads 1 and 2 of four exchanged,
+    wherever a value head has columns, rows or entries of its own: the
+    published map ``h // 2`` on them is the modulo map ``h % 2`` on the
+    weights as they were, and the mixer's output is the same sum."""
+    Hk, Hv, K, V = (cfg.n_key_heads, cfg.n_value_heads, cfg.key_dim,
+                    cfg.value_dim)
+    assert (Hk, Hv) == (2, 4)
+    heads = np.array([0, 2, 1, 3])
+
+    def by_head(z, axis, start, width):
+        """The ``Hv·width`` entries from ``start`` on along ``axis``."""
+        z = np.moveaxis(np.array(z), axis, -1)
+        part = z[..., start:start + Hv * width]
+        z[..., start:start + Hv * width] = part.reshape(
+            part.shape[:-1] + (Hv, width))[..., heads, :].reshape(part.shape)
+        return jnp.asarray(np.moveaxis(z, -1, axis))
+
+    v0 = 2 * Hk * K
+    qkvz = by_head(gdn["qkvz_proj"]["kernel"], 1, v0, V)            # v
+    qkvz = by_head(qkvz, 1, v0 + Hv * V, V)                         # z
+    ba = by_head(by_head(gdn["ba_proj"]["kernel"], 1, 0, 1), 1, Hv, 1)
+    return dict(
+        gdn, qkvz_proj={"kernel": qkvz}, ba_proj={"kernel": ba},
+        conv={"kernel": by_head(gdn["conv"]["kernel"], 1, v0, V)},
+        A_log=by_head(gdn["A_log"], 0, 0, 1),
+        dt_bias=by_head(gdn["dt_bias"], 0, 0, 1),
+        o_proj={"kernel": by_head(gdn["o_proj"]["kernel"], 0, 0, V)})
+
+
+@pytest.mark.parametrize("seam", ["_value_groups", "_gdn_scan"])
+def test_a_stand_in_at_either_seam_is_handed_the_copy(seam):
+    """The two seams the faults file patches, two value heads a key head.
+    ``value_heads_modulo`` (``jnp.tile`` in ``_value_groups``' place): the
+    map is no longer the kernels' own, so the mixer copies through it,
+    counts the copy, and its result is the modulo map's — the reference's
+    on weights whose value heads are exchanged to match —, not the
+    published one's. A stand-in in ``_gdn_scan``'s place (the fault
+    ``state_bf16``'s recurrence takes equal head counts) does not say it
+    takes key heads: it is handed q and k at the VALUE heads, as it always
+    was, and the result is the normal path's."""
+    cfg, layer, x = _mixer_alone((2, 4))
+    handed = []
+
+    def stand_in(q, k, v, g, beta):
+        handed.append((q.shape, k.shape, v.shape))
+        return kda.gdn_scan(q, k, v, g, beta)
+
+    patches = {
+        "_value_groups": faults.fault("value_heads_modulo", cfg)[0],
+        "_gdn_scan": ((qwen3_next, "_gdn_scan", stand_in),),
+    }[seam]
+    with patched(patches):
+        got, moved = _counted(jax.jit(
+            lambda l, x: qwen3_next._gdn_mixer(cfg, l, x)), layer, x)
+    assert moved == (1, 1)
+    published = _reference_mixer(cfg, layer, x)
+    if seam == "_gdn_scan":
+        wide = (2, SEQ, 4, cfg.key_dim)
+        assert handed == [(wide, wide, (2, SEQ, 4, cfg.value_dim))]
+        np.testing.assert_allclose(got, published, atol=5e-5, rtol=5e-5)
+        return
+    want = _reference_mixer(
+        cfg, dict(layer, gdn=_value_heads_swapped(layer["gdn"], cfg)), x)
+    np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5)
+    assert float(jnp.max(jnp.abs(got - published))) > 1e-2
+
+
+@pytest.mark.parametrize("seq", [SEQ, 13], ids=["tiles_of_8", "ragged"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_heads_l2_norm_is_the_plain_one_in_another_view(seq, dtype):
+    """``_heads_normed`` does its arithmetic on the view the flat array
+    has in memory (tiles of 8 positions); the numbers are
+    ``_l2_normed``'s on ``[B, S, heads, K]``, forward and backward, bit
+    for bit, with the scale and without; a length that is no multiple of
+    8 takes the plain form."""
+    z = jax.nn.silu(jax.random.normal(
+        jax.random.key(5), (2, seq, 3 * 12))).astype(dtype)
+    do = jax.random.normal(jax.random.key(6), (2, seq, 3, 12)).astype(dtype)
+    for scale in (12 ** -0.5, None):
+        def plain(z):
+            x = qwen3_next._l2_normed(z.reshape(2, seq, 3, 12))
+            return (x if scale is None else x * scale).astype(dtype)
+
+        want, pull = jax.vjp(jax.jit(plain), z)
+        got, pull_got = jax.vjp(jax.jit(
+            lambda z: qwen3_next._heads_normed(z, 3, scale, dtype)), z)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(pull_got(do)[0], pull(do)[0])
 
 
 def test_value_head_h_reads_key_head_h_over_r():

@@ -20,12 +20,15 @@ no slice of the activations is copied), ``[b ; a] = n·W_ba`` (``d -> 2
 H_v``, f32 from the accumulator on); ``[q̂ ; k̂ ; v] = silu(conv([q̃ ; k̃ ;
 ṽ]))``, one causal depthwise convolution of ``conv_kernel`` taps, no bias
 (``ops/ssm_pointwise.py::conv_silu``, a zero bias passed); a key head ``q
-= q̂ / ‖q̂‖₂ · K^{-1/2}``, ``k = k̂ / ‖k̂‖₂`` (:func:`_l2_normed`); a value
-head ``β = σ(b_h)`` — NOT doubled: the config has no
-``allow_neg_eigval`` —, ``g = −exp(A_log_h) · softplus(a_h + dt_bias_h)``;
-value head ``h`` reads key head ``h // r`` (:func:`_value_groups`: the
-published ``repeat_interleave``, a copy — ``ops/kda.py::gdn_scan`` takes
-as many key heads as value heads; scope ``gdn_repeat``);
+= q̂ / ‖q̂‖₂ · K^{-1/2}``, ``k = k̂ / ‖k̂‖₂`` (:func:`_l2_normed`, taken in
+the layout the convolution leaves and the scan reads:
+:func:`_heads_normed`); a value head ``β = σ(b_h)`` — NOT doubled: the
+config has no ``allow_neg_eigval`` —, ``g = −exp(A_log_h) · softplus(a_h
++ dt_bias_h)``;
+value head ``h`` reads key head ``h // r`` (:func:`_value_groups`, the
+published ``repeat_interleave``, says which; ``ops/kda.py::gdn_scan``
+takes q and k at the KEY heads and reads them there, so the normal path
+copies nothing: :func:`_gdn_mixer`);
 
     S_t = exp(g_t) (I − β_t k_t k_tᵀ) S_{t-1} + β_t k_t v_tᵀ,
     o_t = S_tᵀ q_t                       (``ops/kda.py::gdn_scan``)
@@ -71,16 +74,20 @@ again (``common.routed_sublayer`` says why the weights are among them).
 
 Device-trace scopes: ``embed``; both mixers under ``attn`` — ``gdn_in``
 (the input norm, the projections, ``g`` and ``β``), ``gdn_conv`` (the
-convolution, the l2 norms, and inside it ``gdn_repeat`` around the
-broadcast alone), ``gdn_core``, ``gdn_gate``, ``gdn_out``
+convolution, the l2 norms, and inside it ``gdn_repeat`` around the copy
+of q and k to the value heads, which the normal path does not make: the
+scope then holds nothing), ``gdn_core``, ``gdn_gate``, ``gdn_out``
 (``models/olmo_hybrid.py``'s names); ``gqa_proj`` (the input norm, the
 projections, the head norms, ``W_o``, and inside it ``rope`` around the
 rotation and ``attn_gate`` around the gate) and ``gqa_core`` with
 ``full_core`` around the flash call (``models/laguna.py``'s names);
 ``mlp`` with ``moe_router``, ``moe_dispatch``, ``moe_experts``,
 ``moe_combine``, ``moe_shared`` (its gate inside it); ``lm_head_xent``.
-Counter: ``TRACED["gdn_value_group_calls"]``, a traced mixer whose value
-heads outnumber its key heads.
+Counters: ``TRACED["gdn_value_group_calls"]``, a traced mixer whose value
+heads outnumber its key heads, and ``TRACED["gdn_value_group_copies"]``,
+those of them whose q and k were copied to the value heads after all —
+here (a stand-in at one of the two seams) or in ``ops/kda.py::gdn_scan``
+(heads and widths no grid step of its kernels fits).
 """
 
 from __future__ import annotations
@@ -298,8 +305,13 @@ def init_params(cfg: Qwen3NextConfig, key) -> Dict:
 def _gdn_scan(q, k, v, g, beta):
     """The delta rule: a seam over ``ops/kda.py``'s kernels, kept under
     this name because ``benchmark/tests/qwen3next_faults.py`` puts its
-    stand-ins in its place."""
+    stand-ins in its place. It takes q and k at the key heads or at the
+    value heads and says so (``takes_key_heads``); a stand-in, which does
+    not, is handed them at the value heads (:func:`_gdn_mixer`)."""
     return gdn_scan(q, k, v, g, beta)
+
+
+_gdn_scan.takes_key_heads = True
 
 
 def _gated_head_norm(o, scale, gate, eps: float):
@@ -319,11 +331,62 @@ def _l2_normed(x):
         jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
 
 
+_ROWS = 8     # positions a tile of a flat f32 ``[B, S, width]`` array holds
+
+
+def _heads_normed(z, heads: int, scale: Optional[float], dtype):
+    """``[B, S, heads·K]`` -> ``[B, S, heads, K]`` in ``dtype``: every head
+    :func:`_l2_normed` and, where given, scaled — the numbers of the plain
+    form on ``z.reshape(B, S, heads, K)``, bit for bit, forward and
+    backward (``tests/test_qwen3_next.py``; on the chip at ``[4, 8192, 16,
+    128]``, PERF.md section 6, PR 65). The arithmetic is done on the view
+    ``[B, S/8, heads, 8, K]``, which is how the flat array lies in the
+    chip's memory (tiles of 8 positions by 128 lanes: a head of 128
+    channels is a lane tile), and its flat result is held behind an
+    ``optimization_barrier``, so that the scan's two kernels read ONE
+    array in the layout they take. On ``[B, S, heads, K]`` XLA puts the
+    heads on the sublanes for the reduction: the f32 array is moved there
+    by a ``copy`` of 268 MB that carries no scope, q and k, in each of the
+    three passes, and what comes out is moved back to the flat layout for
+    the kernels by ``reshape`` instructions as large — 60 ms of the cell's
+    867 ms step."""
+    B, S, width = z.shape
+    K = width // heads
+
+    def normed(x):
+        x = _l2_normed(x)
+        return (x if scale is None else x * scale).astype(dtype)
+
+    if S % _ROWS:
+        return normed(z.reshape(B, S, heads, K))
+    tiles = z.reshape(B, S // _ROWS, _ROWS, heads, K).transpose(0, 1, 3, 2, 4)
+    flat = jax.lax.optimization_barrier(
+        normed(tiles).transpose(0, 1, 3, 2, 4).reshape(B, S, width))
+    return flat.reshape(B, S, heads, K)
+
+
 def _value_groups(x, n_value_heads: int):
     """``[B, S, H_k, K]`` -> ``[B, S, H_v, K]``: value head ``h`` reads
     key head ``h // (H_v / H_k)``, by a copy (the published
-    ``repeat_interleave``; a seam: see :func:`_gdn_scan`)."""
+    ``repeat_interleave``; a seam: see :func:`_gdn_scan`). The ONE place
+    that says which key head a value head reads: :func:`_gdn_mixer` asks
+    it for the map and copies only where the map is another than the
+    kernels' own."""
     return jnp.repeat(x, n_value_heads // x.shape[2], axis=2)
+
+
+def _reads_key_head_h_over_r(n_key_heads: int, n_value_heads: int) -> bool:
+    """Whether what stands in :func:`_value_groups`' place maps value
+    head ``h`` to key head ``h // r``, the map ``ops/kda.py::gdn_scan``
+    reads q and k by: the function applied to the key heads' indices, at
+    trace time."""
+    with jax.ensure_compile_time_eval():
+        heads = _value_groups(
+            jnp.arange(n_key_heads).reshape(1, 1, n_key_heads, 1),
+            n_value_heads)
+    return np.array_equal(
+        np.asarray(heads).reshape(-1),
+        np.arange(n_value_heads) // (n_value_heads // n_key_heads))
 
 
 def decay_and_step(cfg: Qwen3NextConfig, m: Dict, n):
@@ -340,6 +403,12 @@ def decay_and_step(cfg: Qwen3NextConfig, m: Dict, n):
 
 
 def _gdn_mixer(cfg: Qwen3NextConfig, layer: Dict, x):
+    """``x + mixer(N(x; w1))``, the delta-rule mixer. With more value
+    than key heads, q and k go to the scan at the KEY heads where
+    :func:`_value_groups` is the map the kernels read by and what stands
+    in :func:`_gdn_scan`'s place takes them so; in every other case they
+    are copied through :func:`_value_groups`, whatever stands there
+    (scope ``gdn_repeat``, counted)."""
     m, dt = layer["gdn"], cfg.dtype
     B, S, _ = x.shape
     Hk, Hv, K, V = (cfg.n_key_heads, cfg.n_value_heads, cfg.key_dim,
@@ -354,15 +423,16 @@ def _gdn_mixer(cfg: Qwen3NextConfig, layer: Dict, x):
     with jax.named_scope("gdn_conv"):
         taps = m["conv"]["kernel"]
         qkv = conv_silu(qkv, taps, jnp.zeros(taps.shape[1:], taps.dtype))
-        q = (_l2_normed(qkv[..., :Hk * K].reshape(B, S, Hk, K))
-             * K ** -0.5).astype(dt)
-        k = _l2_normed(
-            qkv[..., Hk * K:2 * Hk * K].reshape(B, S, Hk, K)).astype(dt)
+        q = _heads_normed(qkv[..., :Hk * K], Hk, K ** -0.5, dt)
+        k = _heads_normed(qkv[..., Hk * K:2 * Hk * K], Hk, None, dt)
         v = qkv[..., 2 * Hk * K:].reshape(B, S, Hv, V)
         if Hv != Hk:
             TRACED.incr("gdn_value_group_calls")
-            with jax.named_scope("gdn_repeat"):
-                q, k = _value_groups(q, Hv), _value_groups(k, Hv)
+            if not (getattr(_gdn_scan, "takes_key_heads", False)
+                    and _reads_key_head_h_over_r(Hk, Hv)):
+                TRACED.incr("gdn_value_group_copies")
+                with jax.named_scope("gdn_repeat"):
+                    q, k = _value_groups(q, Hv), _value_groups(k, Hv)
     with jax.named_scope("gdn_core"):
         o = _gdn_scan(q, k, v, g, beta)                  # [B, S, H_v, V]
     with jax.named_scope("gdn_gate"):

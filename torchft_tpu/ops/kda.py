@@ -110,7 +110,11 @@ of 128 lanes and refuse others with a message (a head there is an
 aligned lane slice of the step's block); the scalar-decay kernels take
 any ``K`` and ``V`` of which some group of heads fills whole lane tiles
 (four heads of 96 are three tiles, of 192 six), refuse others with a
-message, and take any head count (:func:`_gdn_heads_a_step`).
+message, and take any head count (:func:`_gdn_heads_a_step`) — and q and
+k at FEWER heads than v where the one divides the other: value head ``h``
+reads key head ``h // r`` where it lies (KEY HEADS, at the end of the
+file; ``TRACED["gdn_value_group_copies"]`` counts the calls, as traced,
+that had to copy q and k to the value heads after all).
 """
 
 from __future__ import annotations
@@ -121,6 +125,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from torchft_tpu.utils.metrics import TRACED
 
 __all__ = ["kda_scan", "gdn_scan"]
 
@@ -637,6 +643,27 @@ def kda_scan(q, k, v, g, beta):
 # (PERF.md section 6, PR 56). ``β`` is whatever the caller hands over: the
 # rule contracts for ``β`` in (0, 2) (``I − β k kᵀ`` has the eigenvalue
 # ``1 − β``), and nothing here bounds it.
+#
+# KEY HEADS. ``q, k [B, S, H_k, K]`` may have fewer heads than ``v [B, S,
+# H, V]``, ``r = H / H_k`` value heads reading one key head (Qwen3-Next:
+# 16 and 32): value head ``h`` reads key head ``h // r``, ``r`` read off
+# the shapes. q and k stay ``[B, S, H_k·K]``; a grid step of ``n`` VALUE
+# heads (``n % r == 0``) takes the block of its ``n / r`` key heads at the
+# same head-block index. Inside the step a key head's f32 ``q``, ``k`` and
+# ``[k ; q]·kᵀ`` are formed once, by the first of its value heads in the
+# body's order, and read by all ``r`` chains; everything else of a value
+# head's chain (``D``, ``A``, ``B``, ``T``, ``R``, ``U``, ``o``, the state)
+# is what it is at equal heads, so ``o``, ``dv``, ``dg`` and ``dβ`` are the
+# bits of the same kernels on copied q and k. ``gdn_bwd`` adds a key
+# head's ``r`` shares of ``dq`` and of ``dk`` in f32, in the heads' order,
+# and rounds the sum once into ``[B, S, H_k·K]`` (on copies each share is
+# rounded and XLA's sum of them rounded again); the vjp's residuals hold
+# q and k at ``H_k``. At ``r = 1`` the traced program is the one it was
+# (``tests/test_kda.py`` pins Olmo Hybrid's call). Measured at the cell's
+# ``[4, 8192, 16 | 32, 128]`` on the v5e against the same kernels on
+# ``jnp.repeat``-ed q and k with XLA's copy and pair-sum around them:
+# 14.4 ms forward and 34.2 forward + backward against 17.9 and 42.0
+# (PERF.md section 6, PR 65).
 _GDN_LADDER = (6, 5, 4, 3, 2, 1)
 
 
@@ -672,18 +699,33 @@ def _up(n: int, to: int = _LANES) -> int:
     return -(-n // to) * to
 
 
-def _gdn_heads_a_step(h: int, chunk: int, kd: int, vd: int,
-                      interpret: bool) -> int:
-    """Heads a grid step holds: of the rungs of ``_GDN_LADDER`` whose
-    blocks are whole lane tiles (``n·K`` and ``n·V`` multiples of 128;
-    the interpreter takes any) and whose step fits ``_VMEM_LIMIT`` — a
-    head of ``gdn_bwd`` reckoned as 48 f32 tiles of ``[C, max(C, K, V)]``,
-    the lanes rounded up —, the one that leaves the fewest heads of the
-    last group outside the arrays, the largest of those."""
+def _gdn_rungs(chunk: int, kd: int, vd: int, interpret: bool,
+               r: int = 1) -> list:
+    """The rungs of ``_GDN_LADDER`` a grid step may hold, in VALUE heads
+    of which ``r`` read one key head: whole key heads (``n % r == 0``),
+    blocks that are whole lane tiles (``(n / r)·K`` and ``n·V`` multiples
+    of 128; the interpreter takes any) and a step that fits
+    ``_VMEM_LIMIT`` — a head of ``gdn_bwd`` reckoned as 48 f32 tiles of
+    ``[C, max(C, K, V)]``, the lanes rounded up."""
     tile = 4 * chunk * _up(max(chunk, kd, vd))
-    rungs = [n for n in _GDN_LADDER
-             if (interpret or (n * kd % _LANES == 0 and n * vd % _LANES == 0))
-             and (n == 1 or n * 48 * tile <= _VMEM_LIMIT)]
+    return [n for n in _GDN_LADDER
+            if n % r == 0
+            and (interpret
+                 or (n // r * kd % _LANES == 0 and n * vd % _LANES == 0))
+            and (n == 1 or n * 48 * tile <= _VMEM_LIMIT)]
+
+
+def _gdn_heads_a_step(h: int, chunk: int, kd: int, vd: int,
+                      interpret: bool, r: int = 1) -> int:
+    """Value heads a grid step holds: of :func:`_gdn_rungs` the one that
+    leaves the fewest heads of the last group outside the arrays, the
+    largest of those. (With ``r`` value heads a key head a rung is whole
+    key heads, so the same head-block index finds a step's ``n / r`` key
+    heads: at the cell's ``[4, 8192, 16 | 32, 128]`` four value heads are
+    two key heads, 256 lanes of q and k beside 512 of v. Where no rung
+    fits an ``r > 1``, :func:`gdn_scan` copies q and k and calls at
+    equal heads.)"""
+    rungs = _gdn_rungs(chunk, kd, vd, interpret, r)
     if not rungs:
         raise ValueError(
             f"gdn_scan: no group of {_GDN_LADDER} heads of {kd} key and "
@@ -705,16 +747,21 @@ def _as_row(column):
                              column, 0.0), axis=0, keepdims=True)
 
 
-def _gdn_chunk(dot, q, k, v, gcol, grow, beta, st):
-    """What both scalar-decay kernels compute of one chunk of one head
-    (f32 operands; ``gcol [C, 1]`` and ``grow [1, C]`` the chunk's
-    cumulative log-decay, ``beta [C, 1]``, ``st [V, K]`` the transposed
-    state that enters; ``dot`` the matmul, :func:`_gdot`; a generator:
-    :func:`_side_by_side`)."""
+def _gdn_chunk(dot, key, v, gcol, grow, beta, st):
+    """What both scalar-decay kernels compute of one chunk of one value
+    head (f32 operands; ``key`` its key head's ``q``, ``k`` and, formed
+    by the first of the value heads that read it, their product ``[k ;
+    q]·kᵀ`` (:func:`_gdn_head`); ``gcol [C, 1]`` and ``grow [1, C]`` the
+    chunk's cumulative log-decay, ``beta [C, 1]``, ``st [V, K]`` the
+    transposed state that enters; ``dot`` the matmul, :func:`_gdot`; a
+    generator: :func:`_side_by_side`)."""
+    q, k = key["q"], key["k"]
     C = q.shape[0]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     D = jnp.exp(jnp.where(row >= col, gcol - grow, _NEG))
-    qk = dot(jnp.concatenate([k, q], axis=0), k, _NT)     # [2C, C]
+    if "qk" not in key:
+        key["qk"] = dot(jnp.concatenate([k, q], axis=0), k, _NT)  # [2C, C]
+    qk = key["qk"]
     yield
     A = jnp.where(row > col, qk[:C] * D, 0.0)
     B = qk[C:] * D
@@ -732,15 +779,23 @@ def _gdn_chunk(dot, q, k, v, gcol, grow, beta, st):
 
 
 def _gdn_head(j: int, heads: int, kd: int, vd: int, q_ref, k_ref, v_ref,
-              gc_ref, gr_ref, beta_ref):
-    """Head ``j`` of a step's ``heads``: its lanes and its operands in
-    f32 (:func:`_head`), the decay's column and row."""
-    keys, values = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
+              gc_ref, gr_ref, beta_ref, key_heads: dict):
+    """Value head ``j`` of a step's ``heads``: its lanes and its operands
+    in f32 (:func:`_head`), the decay's column and row. The step's q and
+    k blocks hold ``heads / r`` KEY heads, ``r`` read off the blocks'
+    widths; value head ``j`` reads key head ``j // r``, whose f32 ``q``
+    and ``k`` the first of its ``r`` value heads forms in ``key_heads``
+    (one dict a grid step) for all of them."""
+    at = j // (heads * kd // q_ref.shape[2])
+    keys, values = slice(at * kd, (at + 1) * kd), slice(j * vd, (j + 1) * vd)
     mine = pl.program_id(1) * heads + j
+    if at not in key_heads:
+        key_heads[at] = dict(q=_f32(q_ref[0, :, keys]),
+                             k=_f32(k_ref[0, :, keys]))
     return keys, values, (
-        _f32(q_ref[0, :, keys]), _f32(k_ref[0, :, keys]),
-        _f32(v_ref[0, :, values]), _column(gc_ref[0, 0], mine),
-        gr_ref[0, j, 0], _column(beta_ref[0, 0], mine))
+        key_heads[at], _f32(v_ref[0, :, values]),
+        _column(gc_ref[0, 0], mine), gr_ref[0, j, 0],
+        _column(beta_ref[0, 0], mine))
 
 
 def _gdn_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, o_ref,
@@ -755,13 +810,14 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, o_ref,
         state[...] = jnp.zeros_like(state)
 
     _, vd, kd = state.shape
+    key_heads = {}
 
     def head(j):
         st = state[j]
         if save_states:
             rest[0][0, j, 0] = st
         _, values, ins = _gdn_head(j, heads, kd, vd, q_ref, k_ref, v_ref,
-                                   gc_ref, gr_ref, beta_ref)
+                                   gc_ref, gr_ref, beta_ref, key_heads)
         c = yield from _gdn_chunk(dot, *ins, st)
         o = dot(c["qbar"], st, _NT) + dot(c["B"], c["U"])
         o_ref[0, :, values] = o.astype(o_ref.dtype)
@@ -776,20 +832,25 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, st_ref,
                     dstate, *, heads: int, exact: bool):
     """One (batch, group of ``heads`` heads, chunk), chunks last to
     first, as :func:`_kda_bwd_kernel`; ``dg_ref`` takes the cotangent of
-    the CUMULATIVE log-decay, a row a head."""
+    the CUMULATIVE log-decay, a row a head. ``dq`` and ``dk`` leave at
+    the KEY heads: what a key head's ``r`` value heads give is added in
+    f32, in the heads' order, and rounded once by the last of them."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dstate[...] = jnp.zeros_like(dstate)
 
     _, vd, kd = dstate.shape
     C = q_ref.shape[1]
+    r = heads * kd // q_ref.shape[2]
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     dot = functools.partial(_gdot, exact)
+    key_heads = {}
 
     def head(j):
         keys, values, ins = _gdn_head(j, heads, kd, vd, q_ref, k_ref, v_ref,
-                                      gc_ref, gr_ref, beta_ref)
-        q, k, _, _, _, beta = ins
+                                      gc_ref, gr_ref, beta_ref, key_heads)
+        key, beta = ins[0], ins[-1]
+        q, k = key["q"], key["k"]
         st, dst, do = st_ref[0, j, 0], dstate[j], _f32(do_ref[0, :, values])
         c = yield from _gdn_chunk(dot, *ins, st)
         U, R, T, D = c["U"], c["R"], c["T"], c["D"]
@@ -815,8 +876,13 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, st_ref,
         dq = dot(dqk, k) + dqbar * c["gam"]
         dk = (dot(dkk + dkk.T, k) + dot(dqk.T, q) + dkbar * c["gam"]
               + dktil * c["to_end"])
-        dq_ref[0, :, keys] = dq.astype(dq_ref.dtype)
-        dk_ref[0, :, keys] = dk.astype(dk_ref.dtype)
+        if j % r:
+            dq, dk = key["dq"] + dq, key["dk"] + dk
+        if (j + 1) % r:
+            key["dq"], key["dk"] = dq, dk
+        else:
+            dq_ref[0, :, keys] = dq.astype(dq_ref.dtype)
+            dk_ref[0, :, keys] = dk.astype(dk_ref.dtype)
         dv_ref[0, :, values] = dR.astype(dv_ref.dtype)
         yield
         pairs = dA * c["A"] + dB * c["B"]
@@ -839,10 +905,11 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, beta_ref, st_ref,
 
 def _gdn_layouts(q, k, v, g, beta, chunk: int):
     """The scalar-decay kernels' operands from the public ones, padded
-    to whole chunks (``g = 0, β = 0``): ``q, k [B, S, H·K]``, ``v [B, S,
-    H·V]``, the chunk's cumulative log-decay as columns ``[B, S/C, C,
-    H]`` and as rows ``[B, H, S/C, 1, C]``, ``β [B, S/C, C, H]``."""
-    b, s, h, _ = q.shape
+    to whole chunks (``g = 0, β = 0``): ``q, k [B, S, H_k·K]`` at the
+    heads they came with, ``v [B, S, H·V]``, the chunk's cumulative
+    log-decay as columns ``[B, S/C, C, H]`` and as rows ``[B, H, S/C, 1,
+    C]``, ``β [B, S/C, C, H]`` (``H`` the value heads)."""
+    b, s, h, _ = v.shape
     sp = _up(s, chunk)
 
     def wide(z):
@@ -856,25 +923,27 @@ def _gdn_layouts(q, k, v, g, beta, chunk: int):
             G.transpose(0, 3, 1, 2)[:, :, :, None, :], beta)
 
 
-def _gdn_specs(chunk: int, heads: int, h: int, kd: int, vd: int, at):
+def _gdn_specs(chunk: int, heads: int, key_heads: int, h: int, kd: int,
+               vd: int, at):
     """Block specs of the six operands both scalar-decay kernels read
-    (:func:`_specs`)."""
-    def wide(width):
-        return pl.BlockSpec((1, chunk, heads * width),
+    (:func:`_specs`): a step of ``heads`` value heads takes its
+    ``key_heads`` key heads of q and k at the same head-block index."""
+    def wide(n, width):
+        return pl.BlockSpec((1, chunk, n * width),
                             lambda b, h, c: (b, at(c), h))
 
+    keys = wide(key_heads, kd)
     column = pl.BlockSpec((1, 1, chunk, h), lambda b, h, c: (b, at(c), 0, 0))
     rows = pl.BlockSpec((1, heads, 1, 1, chunk),
                         lambda b, h, c: (b, h, at(c), 0, 0))
-    return [wide(kd), wide(kd), wide(vd), column, rows, column]
+    return [keys, keys, wide(heads, vd), column, rows, column]
 
 
 @functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _gdn_forward(q, k, v, g, beta, chunk: int, interpret: bool,
                  save_states: bool):
-    b, s, h, kd = q.shape
-    vd = v.shape[3]
-    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret)
+    (b, s, h, vd), (hk, kd) = v.shape, q.shape[2:]
+    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret, h // hk)
     ops = _gdn_layouts(q, k, v, g, beta, chunk)
     sp = ops[0].shape[1]
     nc = sp // chunk
@@ -890,7 +959,8 @@ def _gdn_forward(q, k, v, g, beta, chunk: int, interpret: bool,
         functools.partial(_gdn_fwd_kernel, heads=heads,
                           save_states=save_states, exact=interpret),
         grid=(b, pl.cdiv(h, heads), nc),
-        in_specs=_gdn_specs(chunk, heads, h, kd, vd, lambda c: c),
+        in_specs=_gdn_specs(chunk, heads, heads * hk // h, h, kd, vd,
+                            lambda c: c),
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, vd, kd), jnp.float32)],
         interpret=interpret, name="gdn_fwd", compiler_params=_PARAMS,
@@ -901,9 +971,8 @@ def _gdn_forward(q, k, v, g, beta, chunk: int, interpret: bool,
 
 @functools.partial(jax.jit, static_argnums=(7, 8))
 def _gdn_backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
-    b, s, h, kd = q.shape
-    vd = v.shape[3]
-    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret)
+    (b, s, h, vd), (hk, kd) = v.shape, q.shape[2:]
+    heads = _gdn_heads_a_step(h, chunk, kd, vd, interpret, h // hk)
     ops = _gdn_layouts(q, k, v, g, beta, chunk)
     sp = ops[0].shape[1]
     nc = sp // chunk
@@ -913,7 +982,7 @@ def _gdn_backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
     def at(c):
         return nc - 1 - c
 
-    specs = _gdn_specs(chunk, heads, h, kd, vd, at)
+    specs = _gdn_specs(chunk, heads, heads * hk // h, h, kd, vd, at)
     keys, _, values, _, rows, _ = specs
     dq, dk, dv, dG, dbeta = pl.pallas_call(
         functools.partial(_gdn_bwd_kernel, heads=heads, exact=interpret),
@@ -925,8 +994,8 @@ def _gdn_backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
         ],
         out_specs=[keys, keys, values, rows, rows],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sp, h * kd), q.dtype),
-            jax.ShapeDtypeStruct((b, sp, h * kd), k.dtype),
+            jax.ShapeDtypeStruct((b, sp, hk * kd), q.dtype),
+            jax.ShapeDtypeStruct((b, sp, hk * kd), k.dtype),
             jax.ShapeDtypeStruct((b, sp, h * vd), v.dtype),
             jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
             jax.ShapeDtypeStruct((b, h, nc, 1, chunk), jnp.float32),
@@ -940,7 +1009,7 @@ def _gdn_backward(q, k, v, g, beta, states, do, chunk: int, interpret: bool):
                  for z in (dg, dbeta))
 
     def narrow(z, like):
-        return z.reshape(b, sp, h, -1)[:, :s].astype(like.dtype)
+        return z.reshape(b, sp, like.shape[2], -1)[:, :s].astype(like.dtype)
 
     return (narrow(dq, q), narrow(dk, k), narrow(dv, v),
             dg.astype(g.dtype), dbeta.astype(beta.dtype))
@@ -969,9 +1038,12 @@ def gdn_scan(q, k, v, g, beta):
         S_t = exp(g_t) (I − β_t k_t k_tᵀ) S_{t-1} + β_t k_t v_tᵀ,
         o_t = S_tᵀ q_t,       S_0 = 0.
 
-    ``q, k [B, S, H, K]`` (the caller's normalisation and scale already
-    in them), ``v [B, S, H, V]`` — ``K`` and ``V`` equal or not; on the
-    TPU widths of which some group of heads fills whole lane tiles
+    ``q, k [B, S, H_k, K]`` (the caller's normalisation and scale already
+    in them), ``v [B, S, H, V]`` with ``H_k`` dividing ``H``: value head
+    ``h`` reads key head ``h // (H / H_k)`` where it lies, nothing is
+    copied, and ``dq``, ``dk`` come back at the key heads, their value
+    heads' shares added in f32 — ``K`` and ``V`` equal or not; on the TPU
+    widths of which some group of heads fills whole lane tiles
     (:func:`_gdn_heads_a_step`), others are refused —, ``g [B, S, H]``
     (log-decays, <= 0, f32), ``beta [B, S, H]`` (f32; the rule contracts
     for ``β`` in (0, 2), the op bounds nothing) -> ``o [B, S, H, V]`` in
@@ -979,13 +1051,20 @@ def gdn_scan(q, k, v, g, beta):
     fed ``g`` broadcast over the key channels (``tests/test_kda.py``),
     in kernels of its own (``gdn_fwd``, ``gdn_bwd``). The chunk is
     chosen from the sequence length; a sequence that is no multiple is
-    padded at its end."""
+    padded at its end. Where no rung of the ladder is whole key heads
+    that fill whole lane tiles (:func:`_gdn_rungs`), q and k are copied
+    to the value heads here and the kernels run at equal heads:
+    ``TRACED["gdn_value_group_copies"]`` counts those calls as traced."""
     if (q.ndim != 4 or k.shape != q.shape or v.ndim != 4
-            or v.shape[:3] != q.shape[:3] or g.shape != q.shape[:3]
-            or beta.shape != q.shape[:3]):
+            or v.shape[:2] != q.shape[:2] or v.shape[2] % q.shape[2]
+            or g.shape != v.shape[:3] or beta.shape != v.shape[:3]):
         raise ValueError(
             f"gdn_scan: q{tuple(q.shape)} k{tuple(k.shape)} "
             f"v{tuple(v.shape)} g{tuple(g.shape)} beta{tuple(beta.shape)} "
             "do not fit")
-    return _gdn(q, k, v, _f32(g), _f32(beta), _choose_chunk(q.shape[1]),
-                _interpret())
+    chunk, interpret = _choose_chunk(q.shape[1]), _interpret()
+    r = v.shape[2] // q.shape[2]
+    if r > 1 and not _gdn_rungs(chunk, q.shape[3], v.shape[3], interpret, r):
+        TRACED.incr("gdn_value_group_copies")
+        q, k = (jnp.repeat(z, r, axis=2) for z in (q, k))
+    return _gdn(q, k, v, _f32(g), _f32(beta), chunk, interpret)
