@@ -76,6 +76,8 @@ _TINY = {
                    "num_attention_heads_per_layer")),
     # one period: three delta-rule layers and a full one, every one sparse
     "qwen3_next": dict(file="tiny-qwen3next.json", bias_rate=0.01),
+    # two layers, each choosing 12 of up to 64 keys
+    "keye": dict(file="tiny-keye.json", bias_rate=0.01),
 }
 # the families with a loop scenario in tier-1 (``gpt``'s are
 # tests/test_step_programs.py's; ``phi4flash`` has none: ROADMAP.md)

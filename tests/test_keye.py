@@ -1,0 +1,386 @@
+"""``torchft_tpu/models/keye.py`` and ``torchft_tpu/ops/dsa.py`` at the
+small size: the model against its plain float32 reference
+(``benchmark/reference/keye_f32.py``) — hidden state, both terms of the
+loss, every gradient leaf, the sets of keys —, which leaves each term
+reaches, the three-stream rotation, what crosses a layer's checkpoint,
+and the new kernels in the interpreter against the ``jnp`` form. The
+family's side (the configuration, the share, the cell's comparisons and
+their faults, the loop) is ``tests/test_keye_family.py``'s: two files so
+that the two run on two of tier-1's workers."""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import family_kit as kit
+
+from benchmark.families import keye as family
+from benchmark.reference import keye_f32
+from benchmark.tests import keye_faults
+from torchft_tpu.models import common, keye
+from torchft_tpu.ops import dsa
+from torchft_tpu.ops.attention import reference_attention
+from torchft_tpu.ops.ssm_pointwise import rotary_tables
+
+CFG = keye.KEYE_CONFIGS["keye_tiny"]
+CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
+S = 64
+_batch = kit.batch
+TEXT, IMAGE = None, "image"
+
+
+def _positions(which):
+    return None if which is TEXT else keye_faults.image_positions(S)
+
+
+@functools.lru_cache(maxsize=None)
+def _params(seed=3):
+    """Seeded weights with the balance biases AND the indexer's LayerNorm
+    bias away from zero."""
+    return family.seed_check_params(
+        kit.seeded_params(keye, CFG32, seed), seed)
+
+
+@functools.lru_cache(maxsize=None)
+def _both(which):
+    """``(system terms, reference terms)`` on one batch, f32."""
+    tokens, targets = _batch(3)
+    positions = _positions(which)
+    got = jax.jit(lambda p: keye.loss_terms(
+        CFG32, p, tokens, targets, None, positions))(_params())
+    want = jax.jit(lambda p: keye_f32.terms(
+        p, tokens, targets, positions=positions,
+        **family.reference_dims(CFG32)))(_params())
+    return jax.device_get(got), jax.device_get(want)
+
+
+@functools.lru_cache(maxsize=None)
+def _grads(term, side, cfg=CFG32):
+    """The gradient tree of ``term`` (``loss`` | ``ce`` | ``index_kl``) of
+    the system or of the reference."""
+    tokens, targets = _batch(3)
+    if side == "system":
+        fn = lambda p: keye.loss_terms(cfg, p, tokens, targets)[term]  # noqa: E731
+    else:
+        fn = lambda p: keye_f32.terms(  # noqa: E731
+            p, tokens, targets, **family.reference_dims(cfg))[term]
+    return jax.device_get(jax.jit(jax.grad(fn))(_params()))
+
+
+def _leaves(tree):
+    return [(jax.tree_util.keystr(path), np.asarray(x)) for path, x in
+            jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _is_indexer(name):
+    return "['indexer']" in name
+
+
+@pytest.mark.parametrize("which", [TEXT, IMAGE])
+def test_the_model_is_its_reference(which) -> None:
+    """Hidden state, cross entropy, each layer's KL, the experts and THE
+    SETS OF KEYS, on text's streams and on three streams that differ."""
+    got, want = _both(which)
+    np.testing.assert_allclose(got["hidden"], want["hidden"], atol=2e-5)
+    assert got["ce"] == pytest.approx(want["ce"], abs=2e-6)
+    np.testing.assert_allclose(got["kl"], want["kl"], rtol=1e-4)
+    assert got["loss"] == pytest.approx(
+        want["ce"] + np.sum(want["kl"]), abs=5e-6)
+    assert np.array_equal(got["sel"], want["own_keys"])
+    taken = np.zeros(want["chosen"].shape, bool)
+    np.put_along_axis(taken, got["experts"], True, axis=-1)
+    assert np.array_equal(taken, want["chosen"])
+    size = np.sum(np.asarray(dsa.unpack(got["sel"])), axis=-1)
+    assert np.array_equal(size, np.broadcast_to(
+        np.minimum(np.arange(S) + 1, CFG.index_topk), size.shape))
+    # most queries choose: 12 of up to 64 keys
+    np.testing.assert_allclose(got["selected_share"], 702 / 2080, rtol=1e-6)
+
+
+def test_every_gradient_leaf_is_the_references() -> None:
+    got, want = _grads("loss", "system"), _grads("loss", "reference")
+    for (name, a), (_, b) in zip(_leaves(got), _leaves(want)):
+        if name.endswith("['balance_bias']"):
+            continue        # carries the loads (common.loads_as_gradient)
+        assert np.linalg.norm(a - b) <= 2e-5 * np.linalg.norm(b) + 1e-9, name
+        assert np.any(b), name
+
+
+@pytest.mark.parametrize("side", ["system", "reference"])
+def test_each_term_reaches_its_own_leaves_alone(side) -> None:
+    """The cross entropy's gradient is EXACTLY zero on every leaf of the
+    indexer (the set is not differentiable and the indexer's input is
+    detached); the KL term's is exactly zero on every other leaf and not
+    on the indexer's. In the system and in the reference."""
+    for name, g in _leaves(_grads("ce", side)):
+        assert np.any(g) != _is_indexer(name) or name.endswith(
+            "['balance_bias']"), name
+    for name, g in _leaves(_grads("index_kl", side)):
+        assert np.any(g) == _is_indexer(name), name
+
+
+def test_a_fault_fails_the_indexers_input_not_detached(monkeypatch) -> None:
+    monkeypatch.setattr(keye, "_detach", lambda x: x)
+    tokens, targets = _batch(3)
+    grads = jax.jit(jax.grad(lambda p: keye.loss_terms(
+        CFG32, p, tokens, targets)["index_kl"]))(_params())
+    assert any(np.any(g) for name, g in _leaves(grads)
+               if not _is_indexer(name))
+
+
+def test_a_fault_fails_the_kl_term_left_out() -> None:
+    off = dataclasses.replace(CFG32, index_kl_weight=0.0)
+    want = _grads("loss", "reference")
+    got = _grads("loss", "system", off)
+    for (name, a), (_, b) in zip(_leaves(got), _leaves(want)):
+        if _is_indexer(name):
+            assert not np.any(a) and np.any(b), name
+
+
+def test_the_table_of_three_streams() -> None:
+    """Pair ``i`` turns by ITS stream's position: 2 pairs temporal, 3
+    height, 3 width at the small size. On text's streams the table is the
+    one-stream table of ``rotary_tables`` bit for bit; on streams that
+    differ it is the direct formula, and swapping height and width
+    changes exactly the pairs of those two sections."""
+    half = CFG.head_dim // 2
+    freqs = CFG.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    text = jnp.broadcast_to(jnp.arange(S), (3, S))
+    for a, b in zip(keye.mrope_tables(CFG, text),
+                    rotary_tables(freqs, S, CFG.head_dim)):
+        assert np.array_equal(a, b)
+    pos = keye_faults.image_positions(S)
+    assert len({tuple(np.asarray(p)) for p in pos}) == 3
+    cos, sin = (np.asarray(t) for t in keye.mrope_tables(CFG, pos))
+    stream = [0, 0, 1, 1, 1, 2, 2, 2]
+    for i in range(half):
+        angle = np.asarray(pos[stream[i]], np.float32) * np.float32(freqs[i])
+        np.testing.assert_allclose(cos[:, i], np.cos(angle), atol=1e-6)
+        np.testing.assert_allclose(cos[:, half + i], np.cos(angle), atol=1e-6)
+        np.testing.assert_allclose(sin[:, i], -np.sin(angle), atol=1e-6)
+        np.testing.assert_allclose(sin[:, half + i], np.sin(angle), atol=1e-6)
+    swapped = np.asarray(keye.mrope_tables(CFG, pos[jnp.array([0, 2, 1])])[0])
+    moved = [i for i in range(half) if not np.array_equal(
+        swapped[:, i], cos[:, i])]
+    # (the slowest pairs' angles are too small to tell apart in f32)
+    assert set(moved) <= {2, 3, 4, 5, 6, 7} and {2, 3, 4, 5} <= set(moved)
+    np.testing.assert_allclose(
+        keye_f32.head_angles(pos, CFG.rope_theta, CFG.head_dim,
+                             CFG.mrope_section),
+        np.asarray(pos, np.float32)[stream].T * np.asarray(freqs)[None],
+        rtol=1e-6)
+
+
+def _program(remat):
+    cfg = dataclasses.replace(CFG32, remat=remat)
+    tokens, targets = _batch(3)
+    program = jax.jit(jax.value_and_grad(
+        lambda p: keye.loss_fn(cfg, p, tokens, targets))).lower(
+            _params()).compile()
+    loss, grads = program(_params())
+    return float(loss), grads, program.as_text()
+
+
+def test_forward_and_backward_read_one_set_under_remat() -> None:
+    """The selection runs ONCE a layer and step: in the compiled gradient
+    program with ``remat`` on, every ``sort`` under ``dsa_select`` stands
+    in the forward pass — none under the checkpoint's
+    ``rematted_computation``, none in the backward —, as many as without
+    ``remat``; the sets cross the checkpoint by name
+    (``common.KEY_CHOICE``). Loss and gradients equal the ``remat=False``
+    program's."""
+    loss, grads, text = _program(True)
+    plain_loss, plain_grads, plain_text = _program(False)
+
+    def sorts(text):
+        found = {}
+        for line in text.splitlines():
+            path = re.search(r'op_name="([^"]*)"', line)
+            if (path is None or "dsa_select" not in path.group(1)
+                    or not re.search(r" sort\(", line)):
+                continue
+            tokens = re.split(r"[/()]", path.group(1))
+            which = ("recomputed" if "rematted_computation" in tokens else
+                     "backward" if "transpose" in tokens else "forward")
+            found[which] = found.get(which, 0) + 1
+        return found
+
+    assert set(sorts(text)) == {"forward"}
+    assert sorts(text) == sorts(plain_text)
+    assert loss == plain_loss
+    for (name, a), (_, b) in zip(_leaves(grads), _leaves(plain_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7, err_msg=name)
+    assert common.KEY_CHOICE != common.ROUTER_CHOICE
+
+
+def test_below_topk_positions_it_is_plain_causal_attention() -> None:
+    """A query with ``t < topk`` keeps every key at or before it: its rows
+    of the packed set ARE the causal rows, and its output is, BIT FOR
+    BIT, the output under the all-causal set; that set's output is plain
+    causal attention's."""
+    x = family.kernel_inputs(CFG32, 5, 2, S)
+    sel, _ = dsa.select(x["qi"], x["ki"], x["w"], CFG.index_topk)
+    causal = jnp.broadcast_to(family.causal_words(S), sel.shape)
+    k = CFG.index_topk
+    assert np.array_equal(sel[:, :k], causal[:, :k])
+    assert not np.array_equal(sel[:, k:], causal[:, k:])
+    assert np.array_equal(dsa.pack(jnp.tril(jnp.ones((S, S), bool))),
+                          family.causal_words(S))
+    o, _ = dsa.attend(x["q"], x["k"], x["v"], sel)
+    o_all, _ = dsa.attend(x["q"], x["k"], x["v"], causal)
+    assert np.array_equal(o[:, :, :k], o_all[:, :, :k])
+    assert not np.allclose(o[:, :, k:], o_all[:, :, k:], atol=1e-3)
+    plain = reference_attention(*(x[n].transpose(0, 2, 1, 3)
+                                  for n in ("q", "k", "v")))
+    np.testing.assert_allclose(o_all.transpose(0, 2, 1, 3), plain,
+                               atol=2e-6)
+
+
+def test_the_packed_set() -> None:
+    """Bit ``b`` of word ``c`` of a row is key ``b · (S / 32) + c``."""
+    keep = jax.random.bernoulli(jax.random.key(0), 0.3, (2, S, S))
+    words = dsa.pack(keep)
+    assert words.shape == (2, S, S // 32) and words.dtype == jnp.int32
+    assert np.array_equal(dsa.unpack(words), keep)
+    assert np.array_equal(keye_f32.unpack_keys(words), keep)
+    assert np.array_equal(keye_f32.pack_keys(keep), words)
+    one = np.zeros((1, S), bool)
+    one[0, 31 * 2 + 1] = True
+    assert np.array_equal(np.asarray(dsa.pack(one)),
+                          [[0, np.int32(-2 ** 31)]])
+    with pytest.raises(ValueError, match="no multiple of the 32 keys"):
+        dsa.select(jnp.zeros((1, 1, 48, 8)), jnp.zeros((1, 48, 8)),
+                   jnp.zeros((1, 48, 1)), 4)
+
+
+def _coarse_inputs():
+    """The kernels' inputs with index operands on a coarse grid, so that
+    scores TIE at the rank-``topk`` boundary."""
+    x = family.kernel_inputs(CFG, 0, 2, S)
+    coarse = lambda a: (jnp.round(a.astype(jnp.float32) * 2) / 2  # noqa: E731
+                        ).astype(a.dtype)
+    return dict(x, qi=coarse(x["qi"]), ki=coarse(x["ki"]),
+                w=coarse(x["w"] * 8))
+
+
+@functools.lru_cache(maxsize=None)
+def _kernels_and_forms():
+    """The three calls in the interpreter (several query blocks a
+    sequence) and in their ``jnp`` form, values and gradients, ONE jitted
+    program each."""
+    x = _coarse_inputs()
+
+    def run(x, interpret):
+        kw = dict(block_q=16, interpret=interpret)
+        sel, lse_i = dsa.select(x["qi"], x["ki"], x["w"], CFG.index_topk,
+                                **kw)
+        (o, lse), pull = jax.vjp(
+            lambda q, k, v: dsa.attend(q, k, v, sel, **kw),
+            x["q"], x["k"], x["v"])
+        dq, dk, dv = pull((x["do"], jnp.zeros_like(lse)))
+        kl, (dqi, dki, dw) = jax.value_and_grad(
+            lambda qi, ki, w: dsa.index_kl(
+                x["q"], x["k"], lse, qi, ki, w, sel, lse_i, **kw),
+            argnums=(0, 1, 2))(x["qi"], x["ki"], x["w"])
+        return dict(sel=sel, lse_i=lse_i, o=o, lse=lse, dq=dq, dk=dk, dv=dv,
+                    kl=kl, dqi=dqi, dki=dki, dw=dw)
+
+    return (jax.device_get(jax.jit(lambda x: run(x, True))(x)),
+            jax.device_get(jax.jit(lambda x: run(x, None))(x)), x)
+
+
+def test_the_kernels_in_the_interpreter_are_the_jnp_forms() -> None:
+    got, want, _ = _kernels_and_forms()
+    assert np.array_equal(got["sel"], want["sel"])
+    for name, tol in (("lse_i", 1e-5), ("lse", 1e-5), ("kl", 1e-5),
+                      ("dw", 1e-4), ("o", 1e-2), ("dq", 1e-2), ("dk", 1e-2),
+                      ("dv", 1e-2), ("dqi", 1e-2), ("dki", 1e-2)):
+        a, b = (np.asarray(z[name], np.float32) for z in (got, want))
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), name
+
+
+def test_ties_go_to_the_lower_key_and_every_set_is_exact() -> None:
+    """On coarse operands rows tie at the boundary: the set still has
+    exactly ``min(t + 1, topk)`` members, no key after its query, and of
+    the keys that tie with the last kept one the LOWER positions are
+    kept."""
+    got, _, x = _kernels_and_forms()
+    keep = np.asarray(dsa.unpack(got["sel"]))
+    scores = np.asarray(dsa.index_scores(x["qi"], x["ki"], x["w"]))
+    t = np.arange(S)
+    assert np.array_equal(keep.sum(-1), np.broadcast_to(
+        np.minimum(t + 1, CFG.index_topk), keep.shape[:2]))
+    assert not np.any(keep & (t[None, :] > t[:, None]))
+    tied_rows = 0
+    for b in range(keep.shape[0]):
+        for row in range(CFG.index_topk, S):
+            kept = np.flatnonzero(keep[b, row])
+            low = scores[b, row, kept].min()
+            out = np.flatnonzero(~keep[b, row, :row + 1])
+            assert np.all(scores[b, row, out] <= low)
+            ties_out = out[scores[b, row, out] == low]
+            ties_in = kept[scores[b, row, kept] == low]
+            if len(ties_out):
+                tied_rows += 1
+                assert ties_in.max() < ties_out.min()
+    assert tied_rows > 0
+
+
+# -- the kernels compile for the chip ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_kernels_compile_for_a_described_v5e(one_chip) -> None:
+    """The five kernels at the cell's shapes (2 rows of 16 384, 32 | 4
+    heads of 128, an indexer of 16 x 64) pass the chip's compiler: tile
+    alignment and VMEM are what the interpreter cannot see."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    B, H, KV, L, D, HI, DI = 2, 32, 4, 16384, 128, 16, 64
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def on(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, k = on((B, H, L, D), bf), on((B, KV, L, D), bf)
+    qi, ki, w = on((B, HI, L, DI), bf), on((B, L, DI), bf), on((B, L, HI), f32)
+    sel, lse = on((B, L, L // 32), jnp.int32), on((B, H, L), f32)
+    lse_i = on((B, L), f32)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        names = set()
+        for fn, args in (
+            (lambda a, b, c: dsa._index_call(a, b, c, 2048, 128, False),
+             (qi, ki, w)),
+            (lambda a, b, c, d: jax.vjp(
+                lambda a, b, c: dsa._attend(a, b, c, d, D ** -0.5, 512,
+                                            False)[0], a, b, c)[1](a),
+             (q, k, k, sel)),
+            (lambda *a: dsa._kl_call(*a, D ** -0.5, 512, True, False),
+             (q, k, lse, qi, ki, w, sel, lse_i)),
+        ):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            names |= set(re.findall(r"dsa_[a-z]+", text))
+        assert {"dsa_select", "dsa_fwd", "dsa_dq", "dsa_dkv",
+                "dsa_kl"} <= names
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

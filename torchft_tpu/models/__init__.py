@@ -19,7 +19,12 @@ beside a shared one) and ``qwen3_next`` (a pre-norm stack with zero-centred
 norm weights: a delta rule whose value heads share key heads 3 : 1 with
 attention on 256-wide heads under an element-wise gate and a quarter-head
 rotation, every layer a softmax top-10-of-512 router beside a gated shared
-expert); what more than one of them computes is in ``common``."""
+expert) and ``keye`` (attention whose keys are chosen by the data: an
+indexer scores every causal pair, each query keeps its 2 048 best keys and
+attends to those alone — ``ops/dsa.py`` —, the indexer's own KL term beside
+the cross entropy, a rotation from three position streams, a softmax
+top-8-of-128 router); what more than one of them computes is in
+``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

@@ -36,11 +36,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.ops import moe
+from torchft_tpu.ops.dsa import KEY_CHOICE
 
 __all__ = ["rms_norm", "embed", "rotary", "repeat_kv", "swiglu",
            "dense_sublayer",
            "BALANCE_BIAS", "is_balance_bias", "loads_as_gradient",
-           "ROUTER_CHOICE", "checkpoint_layer",
+           "ROUTER_CHOICE", "KEY_CHOICE", "checkpoint_layer",
            "routed_sublayer", "routing_record", "share_loss_terms"]
 
 
@@ -146,6 +147,11 @@ loads_as_gradient = _loads_as_gradient
 # the one name under which a router's decision crosses a layer's
 # ``jax.checkpoint`` (:func:`checkpoint_layer`)
 ROUTER_CHOICE = "router_choice"
+# and ``ops/dsa.py``'s ``KEY_CHOICE``, under which an attention's choice
+# of KEYS does (``models/keye.py``: the packed sets of ``dsa.select`` and
+# their log-sum-exp, tagged where they are made): the backward pass and the
+# forward run again read the sets the forward pass chose; a set chosen
+# again from a stream rounded again would differentiate another function
 
 
 def checkpoint_layer(run: Callable) -> Callable:
@@ -153,12 +159,12 @@ def checkpoint_layer(run: Callable) -> Callable:
     the backward pass runs the layer's forward again but for the values
     tagged :data:`ROUTER_CHOICE` (``_route_fwd``: the experts chosen,
     their weights, the chosen scores, the loads — three ``[N, k]`` arrays
-    and an ``[E]``), which the forward pass saves. Nothing else is
-    saveable; a layer without a router is checkpointed as by plain
-    ``jax.checkpoint``."""
+    and an ``[E]``) or :data:`KEY_CHOICE`, which the forward pass saves.
+    Nothing else is saveable; a layer without a router or a choice of
+    keys is checkpointed as by plain ``jax.checkpoint``."""
     return jax.checkpoint(
         run, policy=jax.checkpoint_policies.save_only_these_names(
-            ROUTER_CHOICE))
+            ROUTER_CHOICE, KEY_CHOICE))
 
 
 class _How(NamedTuple):
