@@ -11,6 +11,7 @@ that the two run on two of tier-1's workers."""
 import dataclasses
 import functools
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ from torchft_tpu.models import common, keye
 from torchft_tpu.ops import dsa
 from torchft_tpu.ops.attention import reference_attention
 from torchft_tpu.ops.ssm_pointwise import rotary_tables
+from torchft_tpu.utils.metrics import TRACED
 
 CFG = keye.KEYE_CONFIGS["keye_tiny"]
 CFG32 = dataclasses.replace(CFG, dtype=jnp.float32)
@@ -177,14 +179,50 @@ def test_the_table_of_three_streams() -> None:
         rtol=1e-6)
 
 
-def _program(remat):
-    cfg = dataclasses.replace(CFG32, remat=remat)
+# ``ops/dsa.py``'s three calls as kernels in the interpreter, several query
+# blocks a sequence: what ``_attn_mixer`` takes as ``ops``
+KERNELS = types.SimpleNamespace(**{
+    name: functools.partial(getattr(dsa, name), block_q=16, interpret=True)
+    for name in ("select", "attend", "index_kl")})
+
+
+def _kl_grad_calls():
+    return TRACED.snapshot().get("dsa_kl_grad_calls", 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _program(remat, kernels=False, kl_weight=1.0):
+    """``(loss, gradients, compiled text, index_kl calls traced with their
+    gradients)`` of the gradient program, on :data:`KERNELS` or on the
+    ``jnp`` forms (the CPU's)."""
+    cfg = dataclasses.replace(CFG32, remat=remat, index_kl_weight=kl_weight)
     tokens, targets = _batch(3)
+    ops = KERNELS if kernels else None
+    before = _kl_grad_calls()
     program = jax.jit(jax.value_and_grad(
-        lambda p: keye.loss_fn(cfg, p, tokens, targets))).lower(
+        lambda p: keye.loss_fn(cfg, p, tokens, targets, ops))).lower(
             _params()).compile()
+    traced = _kl_grad_calls() - before
     loss, grads = program(_params())
-    return float(loss), grads, program.as_text()
+    return float(loss), jax.device_get(grads), program.as_text(), traced
+
+
+def _passes(text, scope, is_counted):
+    """How many of the compiled program's operations under ``scope`` that
+    ``is_counted(path, line)`` takes stand in the forward pass, under the
+    checkpoint's ``rematted_computation`` and in the backward."""
+    found = {}
+    for line in text.splitlines():
+        path = re.search(r'op_name="([^"]*)"', line)
+        if (path is None or scope not in path.group(1)
+                or not is_counted(path.group(1), line)):
+            continue
+        # (``transpose(`` is the backward pass; a path can END in the
+        # primitive ``transpose``)
+        which = ("recomputed" if "rematted_computation" in path.group(1) else
+                 "backward" if "transpose(" in path.group(1) else "forward")
+        found[which] = found.get(which, 0) + 1
+    return found
 
 
 def test_forward_and_backward_read_one_set_under_remat() -> None:
@@ -195,21 +233,12 @@ def test_forward_and_backward_read_one_set_under_remat() -> None:
     ``remat``; the sets cross the checkpoint by name
     (``common.KEY_CHOICE``). Loss and gradients equal the ``remat=False``
     program's."""
-    loss, grads, text = _program(True)
-    plain_loss, plain_grads, plain_text = _program(False)
+    loss, grads, text, _ = _program(True)
+    plain_loss, plain_grads, plain_text, _ = _program(False)
 
     def sorts(text):
-        found = {}
-        for line in text.splitlines():
-            path = re.search(r'op_name="([^"]*)"', line)
-            if (path is None or "dsa_select" not in path.group(1)
-                    or not re.search(r" sort\(", line)):
-                continue
-            tokens = re.split(r"[/()]", path.group(1))
-            which = ("recomputed" if "rematted_computation" in tokens else
-                     "backward" if "transpose" in tokens else "forward")
-            found[which] = found.get(which, 0) + 1
-        return found
+        return _passes(text, "dsa_select",
+                       lambda path, line: re.search(r" sort\(", line))
 
     assert set(sorts(text)) == {"forward"}
     assert sorts(text) == sorts(plain_text)
@@ -217,6 +246,54 @@ def test_forward_and_backward_read_one_set_under_remat() -> None:
     for (name, a), (_, b) in zip(_leaves(grads), _leaves(plain_grads)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7, err_msg=name)
     assert common.KEY_CHOICE != common.ROUTER_CHOICE
+
+
+def test_the_kl_term_is_differentiated_where_it_is_evaluated(capsys) -> None:
+    """``dsa_kl`` runs ONCE a layer and step: in the compiled gradient
+    program with ``remat`` on and the kernels in the interpreter,
+    every loop and matmul the kernel's call lowers to stands in the forward
+    pass — none under ``rematted_computation``, none in the backward —, as
+    much of it as with ``remat`` off; ``index_kl`` is traced with its
+    gradients once a layer. Loss and gradients equal the ``remat=False``
+    program's and the ``jnp`` form's. What the term keeps across the
+    checkpoint is shaped like the indexer's PARAMETERS (five leaves a
+    layer), not like the kernel's ``dqi``, ``dki``, ``dw``."""
+    loss, grads, text, traced = _program(True, True)
+    plain_loss, plain_grads, plain_text, plain_traced = _program(False,
+                                                                 True)
+    form_loss, form_grads, _, form_traced = _program(True)
+
+    def kernel(text):
+        # the kernel's loops and matmuls (XLA's copies between them vary)
+        return _passes(text, "dsa_kl", lambda path, line: (
+            "jit(_kl_call)" in path and re.search(r" (while|dot)\(", line)))
+
+    assert set(kernel(text)) == {"forward"} and kernel(text)["forward"] > 0
+    assert kernel(text) == kernel(plain_text)
+    # nothing of the scope is left for the backward pass: it scales the
+    # parameters' cotangents, which crossed the checkpoint (``KEY_CHOICE``)
+    assert set(_passes(text, "dsa_kl", lambda path, line: True)) == {
+        "forward"}
+    assert traced == plain_traced == CFG.n_layers and form_traced == 0
+    cfg = dataclasses.replace(CFG32, remat=True)
+    tokens, targets = _batch(3)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p: keye.loss_fn(cfg, p, tokens, targets, KERNELS), _params())
+    named = [line for line in capsys.readouterr().out.splitlines()
+             if f"named '{common.KEY_CHOICE}'" in line]
+    assert not [line for line in named if "_kl_fwd" in line]
+    kept = [re.match(r"\w+\[([\d,]*)\]", line).group(1)
+            for line in named if "keye.py" in line]
+    ix = jax.tree_util.tree_leaves(_params()["layers_0"]["indexer"])
+    assert sorted(kept) == sorted(
+        [",".join(map(str, leaf.shape)) for leaf in ix] * CFG.n_layers)
+    assert loss == plain_loss
+    assert loss == pytest.approx(form_loss, rel=1e-6)
+    for (name, a), (_, b), (_, c) in zip(_leaves(grads), _leaves(plain_grads),
+                                         _leaves(form_grads)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-7, err_msg=name)
+        np.testing.assert_allclose(a, c, rtol=2e-4, atol=1e-7, err_msg=name)
 
 
 def test_below_topk_positions_it_is_plain_causal_attention() -> None:
@@ -302,6 +379,77 @@ def test_the_kernels_in_the_interpreter_are_the_jnp_forms() -> None:
                       ("dw", 1e-4), ("o", 1e-2), ("dq", 1e-2), ("dk", 1e-2),
                       ("dv", 1e-2), ("dqi", 1e-2), ("dki", 1e-2)):
         a, b = (np.asarray(z[name], np.float32) for z in (got, want))
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), name
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("case", ["cotangent_3", "kl_weight_0.25",
+                                  "evaluated", "under_a_checkpoint"])
+def test_index_kl_scaled_and_not_differentiated(case) -> None:
+    """The backward rule is the cotangent's product and nothing else: ``3
+    · index_kl`` through the interpreter gives three times the forward
+    rule's gradients, rounded once, and the ``jnp`` form's scaled so;
+    ``index_kl_weight`` 0.25 through ``loss_fn`` a quarter of the term's
+    own gradient on every leaf of the indexer. NOT differentiated the
+    call lowers the kernel that computes no gradient (one result, the
+    rows' KL) and its value is the differentiated call's bit for bit. And
+    the call ALONE under ``common.checkpoint_layer`` (no model's unit
+    around it) is not made again either: its three gradients carry the
+    name."""
+    if case == "kl_weight_0.25":
+        _, grads, _, traced = _program(True, True, 0.25)
+        assert traced == CFG.n_layers
+        checked = 0
+        for (name, a), (_, b) in zip(_leaves(grads),
+                                     _leaves(_grads("index_kl", "system"))):
+            if _is_indexer(name):
+                np.testing.assert_allclose(a, 0.25 * b, rtol=2e-4, atol=1e-7,
+                                           err_msg=name)
+                checked += 1
+        assert checked == 5 * CFG.n_layers
+        return
+    got, want, x = _kernels_and_forms()
+
+    def kl(qi, ki, w):
+        return dsa.index_kl(x["q"], x["k"], got["lse"], qi, ki, w,
+                            got["sel"], got["lse_i"], block_q=16,
+                            interpret=True)
+
+    operands = (x["qi"], x["ki"], x["w"])
+    if case == "evaluated":
+        before = _kl_grad_calls()
+        calls = list(_pallas_calls(jax.make_jaxpr(kl)(*operands).jaxpr))
+        both = list(_pallas_calls(jax.make_jaxpr(jax.value_and_grad(
+            kl, argnums=(0, 1, 2)))(*operands).jaxpr))
+        assert _kl_grad_calls() == before + 1
+        assert [len(c.outvars) for c in calls] == [1]
+        assert [len(c.outvars) for c in both] == [4]
+        assert calls[0].outvars[0].aval.shape == x["qi"].shape[:1] + (1, S)
+        assert np.array_equal(jax.jit(kl)(*operands), got["kl"])
+        return
+    if case == "under_a_checkpoint":
+        text = jax.jit(jax.grad(common.checkpoint_layer(kl), argnums=(
+            0, 1, 2))).lower(*operands).compile().as_text()
+        loops = _passes(text, "jit(_kl_call)", lambda path, line: re.search(
+            r" (while|dot)\(", line))
+        assert set(loops) == {"forward"} and loops["forward"] > 0
+        return
+    value, grads = jax.jit(jax.value_and_grad(
+        lambda *a: 3.0 * kl(*a), argnums=(0, 1, 2)))(*operands)
+    assert np.array_equal(value, 3.0 * got["kl"])
+    for name, tol, g in zip(("dqi", "dki", "dw"), (1e-2, 1e-2, 1e-4), grads):
+        once = jnp.asarray(got[name])
+        assert np.array_equal(
+            g, (3.0 * once.astype(jnp.float32)).astype(once.dtype)), name
+        a, b = np.asarray(g, np.float32), 3.0 * np.asarray(want[name],
+                                                           np.float32)
         assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), name
 
 

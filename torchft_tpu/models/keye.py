@@ -47,7 +47,18 @@ step. So are the attention's output and its rows' log-sum-exp over those
 sets (``[B, H, S, D]`` in the compute type and ``[B, H, S]`` f32: 268 + 4
 MB a layer at 2 x 16 384): the kernel computes every causal tile to
 attend to a quarter of the pairs, and at that price a second forward a
-step costs more than keeping its result.
+step costs more than keeping its result. And the gradient of the layer's
+``L_I`` term. ``index_kl``'s forward rule computes ``dqi [B, HI, S, DI]``
+(compute type), ``dki [B, S, DI]`` and ``dw [B, S, HI]`` (f32) in the call
+that computes its value (``ops/dsa.py::_kl_fwd``: ``dsa_kl`` runs once a
+layer and step, in the forward pass), but those are 67.1 + 4.2 + 2.1 = 73
+MB a layer there and kept they raise the peak by 0.24 GiB at depth 4
+(PERF.md, PR 67). So :func:`_index_term` pulls them back through the
+indexer's projections in the forward pass as well, where the stream still
+is, and what crosses is shaped like the indexer's parameters (``q_proj``
+8.4 MB, ``k_proj`` 0.5, ``weights_proj`` 0.13, the LayerNorm's two: 9 MB
+a layer, f32); the backward pass scales them by the term's cotangent, and
+the layer run again makes neither the indexer's inputs nor the term.
 
 The three position streams (temporal, height, width) are ``[3, S]``;
 text has all three equal to ``t``, which is what ``forward_hidden``
@@ -67,8 +78,9 @@ k / v, the head norms, ``W_o``, and inside it ``rope``), ``dsa_index``
 (the indexer's projections, LayerNorm and rotation), ``dsa_select`` (the
 scores, the threshold and the packing: one kernel, the scores never
 leave it), ``dsa_core`` (attention over the chosen keys), ``dsa_kl``
-(``p̄``, ``L_I`` and its backward); ``mlp`` with ``moe_router``,
-``moe_dispatch``, ``moe_experts``, ``moe_combine``; ``lm_head_xent``.
+(``p̄``, ``L_I`` and its gradients: one call, in the forward pass); ``mlp``
+with ``moe_router``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``;
+``lm_head_xent``.
 """
 
 from __future__ import annotations
@@ -80,6 +92,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from torchft_tpu.models.common import (
     BALANCE_BIAS,
@@ -290,6 +303,51 @@ def indexer_inputs(cfg: KeyeConfig, ix: Dict, n, index_table):
             _turn(ki, *index_table), w)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _index_term(cfg: KeyeConfig, ops, ix: Dict, n, index_table, q, k, lse,
+                sel, lse_i):
+    """A layer's ``L_I`` (the sum over its rows) of the indexer's
+    parameters ``ix`` and the normed stream ``n``: :func:`indexer_inputs`
+    (the values ``select`` was given: one computation to the compiler),
+    then ``ops.index_kl``. ONE differentiable unit, so that what its
+    backward reads is shaped like the parameters (module docstring)."""
+    with jax.named_scope("dsa_index"):
+        qi, ki, w = indexer_inputs(cfg, ix, n, index_table)
+    with jax.named_scope("dsa_kl"):
+        return ops.index_kl(q, k, lse, qi, ki, w, sel, lse_i)
+
+
+def _index_term_fwd(cfg, ops, ix, n, index_table, q, k, lse, sel, lse_i):
+    # asked for only where the term is differentiated: ``index_kl``'s
+    # gradients come with its value (``ops/dsa.py::_kl_fwd``) and are
+    # pulled back through the projections HERE, where the stream still is;
+    # the backward rule scales. Every cotangent ``jax.vjp`` gives goes
+    # back, the stream's too: ``_detach`` decides what it holds. The
+    # parameters' are named (under ``checkpoint_layer`` the forward pass
+    # keeps them); the stream's is not: detached it is zeros, which depend
+    # on nothing, and a layer run again makes them again
+    with jax.named_scope("dsa_index"):
+        (qi, ki, w), pull = jax.vjp(
+            lambda ix, n: indexer_inputs(cfg, ix, n, index_table), ix, n)
+    with jax.named_scope("dsa_kl"):
+        kl, grads = jax.value_and_grad(
+            lambda *index: ops.index_kl(q, k, lse, *index, sel, lse_i),
+            argnums=(0, 1, 2))(qi, ki, w)
+    with jax.named_scope("dsa_index"):
+        d_ix, d_n = pull(grads)
+    return kl, (jax.tree_util.tree_map(
+        lambda a: checkpoint_name(a, dsa.KEY_CHOICE), d_ix), d_n)
+
+
+def _index_term_bwd(cfg, ops, grads, g):
+    d_ix, d_n = jax.tree_util.tree_map(lambda a: (g * a).astype(a.dtype),
+                                       grads)
+    return d_ix, d_n, None, None, None, None, None, None
+
+
+_index_term.defvjp(_index_term_fwd, _index_term_bwd)
+
+
 @jax.named_scope("attn")
 def _attn_mixer(cfg: KeyeConfig, layer: Dict, x, tables,
                 ops) -> Tuple[Any, Dict]:
@@ -321,8 +379,8 @@ def _attn_mixer(cfg: KeyeConfig, layer: Dict, x, tables,
         sel, lse_i = ops.select(qi, ki, w, cfg.index_topk)
     with jax.named_scope("dsa_core"):
         o, lse = ops.attend(q, k, v, sel)
-    with jax.named_scope("dsa_kl"):
-        kl = ops.index_kl(q, k, lse, qi, ki, w, sel, lse_i) / (B * S)
+    kl = _index_term(cfg, ops, layer["indexer"], n, index_table, q, k, lse,
+                     sel, lse_i) / (B * S)
     with jax.named_scope("gqa_proj"):
         y = jnp.einsum("bhsd,hdm->bsm", o,
                        a["o_proj"]["kernel"].astype(dt).reshape(H, D, -1))

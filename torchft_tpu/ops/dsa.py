@@ -45,9 +45,14 @@ that compute and fetch nothing.
 ``index_kl``: ``sum_t KL(pbar[t] || softmax_{S_t}(I[t]))`` with ``pbar[t,
 s] = mean_h P[t, h, s]`` (from ``q``, ``k``, ``lse``, all detached).
 ``dsa_kl`` recomputes every head's ``P`` tile and the indexer's scores
-tile by tile; differentiated it runs once more, with ``dI = softmax -
-pbar`` carried on to ``dqi``, ``dw`` (accumulated over a row's tiles) and
-``dki`` (one partial a query block, summed outside).
+tile by tile. DIFFERENTIATED it still runs once: the forward rule's call
+carries ``dI = softmax - pbar`` on to ``dqi``, ``dw`` (accumulated over a
+row's tiles) and ``dki`` (one partial a query block, summed outside) in
+the sweep that makes the value — every operand exists where the loss is
+evaluated, the backward pass brings one scalar — and the three cross to
+the backward rule, which scales them (``TRACED["dsa_kl_grad_calls"]``
+counts those calls as traced). An evaluation asks for no rule and runs
+the kernel without them.
 
 Off the TPU each call is its plain ``jnp`` form over whole ``[S, S]``
 arrays (the CPU tests' sizes), differentiated by ``jax``; ``interpret=
@@ -85,7 +90,11 @@ WORD = 32                       # keys a word of the packed set holds
 # a layer run again does not run ``dsa_fwd`` again — it computes every
 # causal tile to attend to a quarter of the pairs, and at that price a
 # second forward a step (83 ms a layer at 2 x 16 384 on the v5e, PERF.md,
-# PR 66) costs more than 272 MB a layer
+# PR 66) costs more than 272 MB a layer; and ``index_kl``'s gradients
+# (``_kl_fwd``: ``dqi``, ``dki``, ``dw``, 73 MB a layer there), so that
+# neither the layer run again nor the backward pass runs ``dsa_kl`` — kept
+# only where the backward pass reads THEM: ``models/keye.py`` pulls them
+# back to the indexer's parameters in the forward pass and keeps those
 KEY_CHOICE = "key_choice"
 _INDEX_ROWS = 128               # query rows a step of ``dsa_select``
 _ATTEND_ROWS = 512              # of the attention kernels and ``dsa_kl``
@@ -797,18 +806,22 @@ def _kl(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, interpret):
 
 
 def _kl_fwd(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, interpret):
-    # the gradient is computed where it is asked for (``_kl_bwd``), from
-    # the arguments alone: a layer run again under ``jax.checkpoint``
-    # does not run the loss's pass a second time
-    args = (q, k, lse, qi, ki, w, sel, lse_i)
-    return _kl(*args, scale, block_q, interpret), args
+    # asked for by ``jax`` only where ``_kl`` is differentiated: the
+    # gradients of the rows' SUM come out of the call that makes the value
+    # (every operand is here; the backward pass brings the cotangent, one
+    # scalar) and are all the backward rule reads. Named HERE: a name on
+    # the op's result does not reach a rule's own residuals, and under
+    # ``common.checkpoint_layer`` these are what the forward pass keeps of
+    # a call whose caller keeps nothing smaller
+    TRACED.incr("dsa_kl_grad_calls")
+    kl, *grads = _kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q,
+                          True, interpret)
+    return jnp.sum(kl), tuple(checkpoint_name(a, KEY_CHOICE) for a in grads)
 
 
-def _kl_bwd(scale, block_q, interpret, args, g):
-    _, dqi, dki, dw = _kl_call(*args, scale, block_q, True, interpret)
-    qi, ki, w = args[3:6]
-    return (None, None, None, (g * dqi).astype(qi.dtype),
-            (g * dki).astype(ki.dtype), (g * dw).astype(w.dtype), None, None)
+def _kl_bwd(scale, block_q, interpret, grads, g):
+    dqi, dki, dw = ((g * a).astype(a.dtype) for a in grads)
+    return None, None, None, dqi, dki, dw, None, None
 
 
 _kl.defvjp(_kl_fwd, _kl_bwd)
