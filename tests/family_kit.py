@@ -78,6 +78,9 @@ _TINY = {
     "qwen3_next": dict(file="tiny-qwen3next.json", bias_rate=0.01),
     # two layers, each choosing 12 of up to 64 keys
     "keye": dict(file="tiny-keye.json", bias_rate=0.01),
+    # one layer of each kind (M A of M A M M), sixteen heads on one B and C
+    "granite_hybrid": dict(file="tiny-granite.json", layers=2,
+                           per_layer=("layer_types",)),
 }
 # the families with a loop scenario in tier-1 (``gpt``'s are
 # tests/test_step_programs.py's; ``phi4flash`` has none: ROADMAP.md)

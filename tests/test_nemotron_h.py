@@ -350,6 +350,12 @@ def _jaxpr_hash(fn, *args):
 #   of PR 46, before models/common.py took the routed sublayer and the
 #   share's loss_terms. The scope readers of benchmark/readers classify
 #   device time by these paths.
+# - nemotron_h again at PR 68: ``ops/ssd.py``'s grid took a fourth axis of
+#   head blocks (one step at this model's groups), so the two
+#   ``pallas_call``s' grids, index maps and scratch changed and the program
+#   with them, on purpose; the scope paths did not. What the kernels COMPUTE
+#   at Nemotron's widths is held by ``tests/test_ssd.py`` against the
+#   parent's recorded outputs. Was 6bff4ba2…94a39e7 (program at 2d59480)
 # - kimi again at PR 48: ops/kda.py's builders went under an inner
 #   jax.jit (four heads a grid step), so the two kernels' equations stand
 #   inside jit(_forward) / jit(_backward) and carry "kda_fwd" / "kda_bwd"
@@ -400,7 +406,7 @@ PROGRAMS_THAT_WERE = {
         78),
     "nemotron_h": (
         nemotron_h, CFG,
-        "6bff4ba2124701f2a6d732455677265dde5ba25acb676e3af648fe7d094a39e7",
+        "cff06fccab8e161728678cf742f31d52b64c8c2c7cf6050947ad85e632f5f4e0",
         "f4bd4e403a7ce0556c35bc44b42638afb2bcb7a77f43084432d2541d92ea5bf3",
         49),
     "lfm2": (

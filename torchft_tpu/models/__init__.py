@@ -23,8 +23,12 @@ expert) and ``keye`` (attention whose keys are chosen by the data: an
 indexer scores every causal pair, each query keeps its 2 048 best keys and
 attends to those alone — ``ops/dsa.py`` —, the indexer's own KL term beside
 the cross entropy, a rotation from three position streams, a softmax
-top-8-of-128 router); what more than one of them computes is in
-``common``."""
+top-8-of-128 router) and ``granite_hybrid`` (a dense stack whose layer is
+a mixer THEN a SwiGLU under two norms with a multiplier on each branch:
+Mamba-2 mixers whose 64 heads share one ``B`` and ``C`` 9 : 1 with
+position-free attention at the config's own softmax scale, the
+embedding's and the logits' scalings around one tied table); what more
+than one of them computes is in ``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

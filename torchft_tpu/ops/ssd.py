@@ -18,23 +18,52 @@ which is the recurrence whatever ``Q`` is. Every exponent is a
 difference of cumulative sums taken the right way round, so it is <= 0:
 a chunk whose total decay underflows gives 0, never inf or nan.
 
-The kernels. One grid step is one (batch, group, chunk), the chunks
-innermost and in order: a TPU core runs its grid sequentially anyway, so
-the dependence between chunks costs nothing, and the state of the
-group's heads is carried from step to step in a VMEM scratch ``[N,
-(H/G)·P]`` f32 — it never goes to HBM in the forward pass proper. Per
-step ``C·Bᵀ`` [Q, Q] is computed ONCE for the group; per head the decay
-mask ``L`` [Q, Q] is built in f32 from the cumulative sums (a column and
-a row of them), multiplied in, and ``(C Bᵀ ∘ L)·u`` is one matmul.
-Heads are taken in lane blocks of 128 (two heads of 64 channels): a
-64-wide result uses the MXU's 128 columns no better than a 128-wide
-one, so each head's matmul runs over the block and a select keeps its
-own lanes — no 64-lane slice is ever cut. Neither ``L`` nor a
-per-position state exists outside VMEM; nothing ``[B, S, H, P, N]`` is
-formed anywhere.
+The kernels. One grid step is one (batch, group, chunk, HEAD BLOCK): a
+group's ``H/G`` heads are taken ``hb`` at a time (``_head_block``: the
+most whole 128-lane blocks within ``_STEP_LANES`` = 512 lanes that divide
+the group — 8 heads of 64 channels), the head blocks innermost, the chunks
+next and in order: a TPU core runs its grid sequentially anyway, so the
+dependence between chunks costs nothing, and the state of the group's
+heads is carried from chunk to chunk in a VMEM scratch ``[H/G/hb, N,
+hb·P]`` f32, a slot a head block — it never goes to HBM in the forward
+pass proper. ``B`` and ``C`` keep their block index over a chunk's head
+blocks, so they are fetched once a (batch, group, chunk), and ``C·Bᵀ``
+[Q, Q] is computed ONCE for the group, at its first head block, into a
+scratch the others read; per head the decay mask ``L`` [Q, Q] is built in
+f32 from the cumulative sums (a column and a row of them), multiplied in,
+and ``(C Bᵀ ∘ L)·u`` is one matmul. Inside a step heads are taken in lane
+blocks of 128 (two heads of 64 channels): a 64-wide result uses the MXU's
+128 columns no better than a 128-wide one, so each head's matmul runs
+over the block and a select keeps its own lanes — no 64-lane slice is
+ever cut. Neither ``L`` nor a per-position state exists outside VMEM;
+nothing ``[B, S, H, P, N]`` is formed anywhere.
+
+What a step holds in VMEM at Q 256, P 64, N 128 (PR 68; until then a step
+held the GROUP whole, blocks ``[Q, (H/G)·P]`` and a body of ``H/G / 2``
+unrolled head pairs: at Nemotron-H's ``H/G`` = 8 that is this step, at
+Granite 4.0-H's ONE group of 64 heads the v5e's compiler refused it — a
+scoped allocation of 21.95 MiB against the default limit of 16 MiB, and
+the body eight times the program): the step's blocks are ``[256, 512]``
+whatever ``H/G`` is — forward ``x``, ``y`` (bf16, 256 KiB, two buffers
+each), the saved-state block ``[128, 512]`` f32 (256 KiB, two buffers),
+the per-head columns ``[256, 8]`` f32 (a lane tile wide in VMEM: 128 KiB
+each), ``C·Bᵀ`` (256 KiB) and temporaries of ``[256, 512]`` f32 (512 KiB
+each); backward ``x``, ``dy``, ``dx``, five column outputs, ``B·Cᵀ`` and
+``Σ_h W_hᵀ`` (256 KiB each) and the two ``[256, 128]`` f32 sums — and only
+the state scratch grows with the group: 256 KiB at ``H/G`` 8 (one slot),
+2 MiB at 64 (eight). Both kernels compile inside the default limit at both
+(``tests/test_ssd.py`` holds Nemotron-H's call to what the kernels gave
+before the head-block axis: on the chip every leaf to the bit and 5.07 /
+13.22 ms a call at [4, 8192, 64, 64], 8 groups, against 5.17 / 13.28 —
+my chip run, PR 68).
 
 ``ssd_bwd`` walks the chunks LAST TO FIRST with the state's cotangent
-``dS`` in the same scratch. It recomputes the transposed tile
+``dS`` in the same scratch. ``dB`` and ``dC`` are the GROUP's: their
+block is revisited over a chunk's consecutive head blocks, the state's
+parts and ``Σ_h W_hᵀ`` add up in f32 scratch, and the two matmuls with
+the summed tile and the write happen at the last block (head blocks
+outside the chunks would need partial sums in HBM and a sum in XLA). It
+recomputes the transposed tile
 (``B·Cᵀ ∘ Lᵀ``: as ``flash_dkv``, so ``Mᵀ·dy`` is a plain matmul) and
 reads the state that entered the chunk. **What the backward keeps**: the
 scan's inputs and the chunk-boundary states ``[B, G, S/Q, N, (H/G)·P]``
@@ -78,8 +107,9 @@ pass at Mosaic's default precision.
 
 Layout: ``x`` is read as ``[B, S, H·P]`` and ``B, C`` as ``[B, S, G·N]``
 — the shapes the model's split of ``xBC`` already has, no transpose of
-anything large. The per-head scalars reach the kernel as ``[B, G, S,
-H/G]`` columns and ``[B, G, H/G, S]`` rows, both tile-legal.
+anything large. The per-head scalars reach the kernel a head block at a
+time, as ``[B, H/hb, S, hb]`` columns and ``[B, H/hb, hb, S]`` rows, both
+tile-legal (a block's last dimension is the array's).
 
 The chunk is ``_CHUNK`` (the whole of a shorter sequence, rounded up to
 the sublane tile); a sequence that is no multiple is padded with ``Δ = 0`` positions at its end (decay 1, input
@@ -89,7 +119,8 @@ work grows with ``Q`` and the grid steps' own cost with ``1 / Q``.
 
 Off the TPU the same kernels run in Pallas's interpreter (the CPU
 tests), chosen from the backend alone. On the TPU ``(H/G)·P`` must be a
-multiple of 128 lanes or the whole width.
+multiple of 128 lanes or the whole width. A test that wants other head
+blocks sets ``_STEP_LANES`` (nothing here is jitted on it).
 """
 
 from __future__ import annotations
@@ -106,6 +137,8 @@ _NEG = -1e30     # exp(_NEG) == 0: the mask above the diagonal
 _LANES = 128
 # positions a chunk; see the module docstring
 _CHUNK = 256
+# lanes of a group's heads one grid step takes: 8 heads of 64 channels
+_STEP_LANES = 512
 
 
 def _interpret() -> bool:
@@ -175,31 +208,37 @@ def _gather_cols(cols, like):
 def _ssd_fwd_kernel(x_ref, b_ref, c_ref, dtc_ref, cumc_ref, cumr_ref, d_ref,
                     y_ref, *rest, head_dim: int, heads_per_block: int,
                     save_states: bool):
-    """One (batch, group, chunk): ``y`` of the chunk and the state it
-    leaves, the state it entered with written out for the backward
-    where asked."""
-    state = rest[-1]
-    ci = pl.program_id(2)
+    """One (batch, group, chunk, head block): ``y`` of the block's heads
+    over the chunk and the state they leave, the state they entered with
+    written out for the backward where asked. ``C·Bᵀ`` is the group's:
+    computed at the chunk's first head block, read by the others."""
+    cb_ref, state = rest[-2:]
+    ci, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ci == 0)
     def _init():
-        state[...] = jnp.zeros_like(state)
+        state[j] = jnp.zeros(state.shape[1:], state.dtype)
 
     P, hpb = head_dim, heads_per_block
-    Q, hg = dtc_ref.shape[2], dtc_ref.shape[3]
+    Q, hb = dtc_ref.shape[2], dtc_ref.shape[3]
     W = hpb * P
     bm, cm = _f32(b_ref[0]), _f32(c_ref[0])            # [Q, N]
     dtc, cumc, cumr = dtc_ref[0, 0], cumc_ref[0, 0], cumr_ref[0, 0]
-    sprev = state[...]                                  # [N, hg·P]
+    sprev = state[j]                                    # [N, hb·P]
     if save_states:
         rest[0][0, 0, 0] = sprev
-    cb = _dot_nt(cm, bm)                                # [Q, Q]: C_i·B_j
+
+    @pl.when(j == 0)
+    def _group():
+        cb_ref[...] = _dot_nt(cm, bm)                   # [Q, Q]: C_i·B_j
+
+    cb = cb_ref[...]
     tri = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
-    carried = _dot(cm, sprev)                           # [Q, hg·P]
+    carried = _dot(cm, sprev)                           # [Q, hb·P]
     bt = bm.T                                           # [N, Q]
-    last = cumc[Q - 1:Q, :]                             # [1, hg]
-    for p in range(hg // hpb):
+    last = cumc[Q - 1:Q, :]                             # [1, hb]
+    for p in range(hb // hpb):
         heads = range(p * hpb, (p + 1) * hpb)
         lanes = slice(p * W, (p + 1) * W)
         xp = _f32(x_ref[0, :, lanes])                   # [Q, W]
@@ -214,42 +253,53 @@ def _ssd_fwd_kernel(x_ref, b_ref, c_ref, dtc_ref, cumc_ref, cumr_ref, d_ref,
              + d_ref[0, :, lanes] * xp)
         y_ref[0, :, lanes] = y.astype(y_ref.dtype)
         last_w = _spread([_col(last, h) for h in heads], W, P)   # [1, W]
-        state[:, lanes] = (sprev[:, lanes] * jnp.exp(last_w)
-                           + _dot(bt, u * jnp.exp(last_w - cum_w)))
+        state[j, :, lanes] = (sprev[:, lanes] * jnp.exp(last_w)
+                              + _dot(bt, u * jnp.exp(last_w - cum_w)))
 
 
 def _ssd_bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, cumc_ref, cumr_ref, d_ref,
                     st_ref, dy_ref, dx_ref, db_ref, dc_ref, ddt_ref,
-                    dcumc_ref, dcumr_ref, on_ref, dd_ref, edge_ref, ds, *,
+                    dcumc_ref, dcumr_ref, on_ref, dd_ref, edge_ref,
+                    bct_ref, wt_ref, db_acc, dc_acc, ds, *,
                     head_dim: int, heads_per_block: int):
-    """One (batch, group, chunk), chunks last to first; ``ds`` carries
-    the cotangent of the state the chunk leaves."""
-    ci = pl.program_id(2)
+    """One (batch, group, chunk, head block), chunks last to first;
+    ``ds`` carries the cotangent of the state the block's heads leave.
+    ``B·Cᵀ`` is computed at the chunk's first head block; ``Σ_h W_hᵀ``
+    and the state's parts of ``dB`` / ``dC`` add up over the group's head
+    blocks in scratch and leave at the last."""
+    ci, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ci == 0)
     def _init():
-        ds[...] = jnp.zeros_like(ds)
+        ds[j] = jnp.zeros(ds.shape[1:], ds.dtype)
 
     P, hpb = head_dim, heads_per_block
-    Q, hg = dtc_ref.shape[2], dtc_ref.shape[3]
+    Q, hb = dtc_ref.shape[2], dtc_ref.shape[3]
     W = hpb * P
     bm, cm = _f32(b_ref[0]), _f32(c_ref[0])            # [Q, N]
     dtc, cumc, cumr = dtc_ref[0, 0], cumc_ref[0, 0], cumr_ref[0, 0]
-    sprev = st_ref[0, 0, 0]                             # [N, hg·P]
-    dsn = ds[...]
-    bct = _dot_nt(bm, cm)                               # [Q, Q]: B_j·C_i
+    sprev = st_ref[0, 0, 0]                             # [N, hb·P]
+    dsn = ds[j]
+
+    @pl.when(j == 0)
+    def _group():
+        bct_ref[...] = _dot_nt(bm, cm)                  # [Q, Q]: B_j·C_i
+        wt_ref[...] = jnp.zeros_like(wt_ref)
+        db_acc[...] = jnp.zeros_like(db_acc)
+        dc_acc[...] = jnp.zeros_like(dc_acc)
+
+    bct = bct_ref[...]
     # the transposed tile: rows are j, columns i, live where i >= j
     tri_t = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
              >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0))
-    carried = _dot(cm, sprev)                           # [Q, hg·P]
-    b_ds = _dot(bm, dsn)                                # [Q, hg·P]
+    carried = _dot(cm, sprev)                           # [Q, hb·P]
+    b_ds = _dot(bm, dsn)                                # [Q, hb·P]
     ct = cm.T                                           # [N, Q]
     last = cumc[Q - 1:Q, :]
-    wt = jnp.zeros((Q, Q), jnp.float32)                 # Σ_h W_hᵀ
-    db = jnp.zeros(bm.shape, jnp.float32)
-    dc = jnp.zeros(cm.shape, jnp.float32)
+    wt = wt_ref[...]                                    # Σ_h W_hᵀ so far
+    db, dc = db_acc[...], dc_acc[...]
     ddt_cols, dcum_cols, on_cols, dd_cols = [], [], [], []
-    for p in range(hg // hpb):
+    for p in range(hb // hpb):
         heads = range(p * hpb, (p + 1) * hpb)
         lanes = slice(p * W, (p + 1) * W)
         xp, dyp = _f32(x_ref[0, :, lanes]), _f32(dy_ref[0, :, lanes])
@@ -288,10 +338,17 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, dtc_ref, cumc_ref, cumr_ref, d_ref,
             dsn[:, lanes] * sprev[:, lanes], axis=0, keepdims=True)
         dc = dc + _dot_nt(dy_in, sprev[:, lanes])
         db = db + _dot_nt(u * to_end, dsn[:, lanes])
-        ds[:, lanes] = dsn[:, lanes] * jnp.exp(last_w) + _dot(ct, dy_in)
-    db_ref[0] = (db + _dot(wt, cm)).astype(db_ref.dtype)
-    dc_ref[0] = (dc + _dot(wt.T, bm)).astype(dc_ref.dtype)
-    like = (Q, hg)
+        ds[j, :, lanes] = dsn[:, lanes] * jnp.exp(last_w) + _dot(ct, dy_in)
+    wt_ref[...] = wt
+    db_acc[...] = db
+    dc_acc[...] = dc
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _leave():
+        db_ref[0] = (db + _dot(wt, cm)).astype(db_ref.dtype)
+        dc_ref[0] = (dc + _dot(wt.T, bm)).astype(dc_ref.dtype)
+
+    like = (Q, hb)
     ddt_ref[0, 0] = _gather_cols(ddt_cols, like)
     dcumc_ref[0, 0] = _gather_cols(dcum_cols, like)
     on_ref[0, 0] = _gather_cols(on_cols, like)
@@ -312,14 +369,27 @@ def _heads_per_block(hg: int, head_dim: int, interpret: bool) -> int:
     return hpb
 
 
-def _layouts(x, dt, a_head, bm, cm, d_head, chunk: int):
+def _head_block(hg: int, head_dim: int, interpret: bool) -> tuple:
+    """``(heads a lane block, heads a grid step)``: a grid step takes the
+    most whole lane blocks of a group's heads that divide it and lie
+    within ``_STEP_LANES`` (whole 128-lane tiles, or the group whole)."""
+    hpb = _heads_per_block(hg, head_dim, interpret)
+    fits = [hb for hb in range(hpb, hg + 1, hpb)
+            if hg % hb == 0 and hb * head_dim <= _STEP_LANES
+            and (hb == hg or hb * head_dim % _LANES == 0)]
+    # no such block: the group whole where one lane block is (a narrow
+    # group off the TPU), else a lane block a step
+    return hpb, max(fits, default=hg if hg * head_dim <= _LANES else hpb)
+
+
+def _layouts(x, dt, a_head, bm, cm, d_head, chunk: int, hb: int):
     """The kernels' operands from the public ones (padded to whole
-    chunks): ``x [B, S, H·P]``, ``B, C [B, S, G·N]``, the columns ``Δ``
-    and ``cum`` ``[B, G, S, H/G]``, the row ``cum`` ``[B, G, H/G, S]``
-    and ``D`` by lane ``[G, 1, (H/G)·P]``."""
+    chunks), the heads in blocks of ``hb`` (``H/hb`` blocks, a group's
+    side by side): ``x [B, S, H·P]``, ``B, C [B, S, G·N]``, the columns
+    ``Δ`` and ``cum`` ``[B, H/hb, S, hb]``, the row ``cum`` ``[B, H/hb,
+    hb, S]`` and ``D`` by lane ``[H/hb, 1, hb·P]``."""
     b, s, h, p = x.shape
     g, n = bm.shape[2:]
-    hg = h // g
     pad = (-s) % chunk
     if pad:
         x, dt, bm, cm = (jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))
@@ -327,25 +397,33 @@ def _layouts(x, dt, a_head, bm, cm, d_head, chunk: int):
     sp = s + pad
     dt = _f32(dt)
     cum = jnp.cumsum((dt * _f32(a_head)).reshape(b, sp // chunk, chunk, h),
-                     axis=2).reshape(b, sp, g, hg)
+                     axis=2).reshape(b, sp, h // hb, hb)
     return (x.reshape(b, sp, h * p), bm.reshape(b, sp, g * n),
             cm.reshape(b, sp, g * n),
-            dt.reshape(b, sp, g, hg).transpose(0, 2, 1, 3),
+            dt.reshape(b, sp, h // hb, hb).transpose(0, 2, 1, 3),
             cum.transpose(0, 2, 1, 3), cum.transpose(0, 2, 3, 1),
-            jnp.repeat(_f32(d_head), p).reshape(g, 1, hg * p))
+            jnp.repeat(_f32(d_head), p).reshape(h // hb, 1, hb * p))
 
 
-def _specs(chunk: int, hg: int, p: int, n: int, at):
-    """Block specs of the seven operands both kernels read; ``at`` maps
-    the grid's chunk index to the chunk (the backward's runs down)."""
+def _specs(chunk: int, nb: int, hb: int, p: int, n: int, at):
+    """Block specs of the seven operands both kernels read, on the grid
+    (batch, group, chunk, head block): ``at`` maps the grid's chunk index
+    to the chunk (the backward's runs down); a group's ``nb`` blocks of
+    ``hb`` heads lie side by side, so block ``j`` of group ``g`` is block
+    ``g·nb + j`` of the heads. ``B`` and ``C`` keep their index over a
+    group's head blocks and are fetched once a chunk."""
     return [
-        pl.BlockSpec((1, chunk, hg * p), lambda b, g, c: (b, at(c), g)),
-        pl.BlockSpec((1, chunk, n), lambda b, g, c: (b, at(c), g)),
-        pl.BlockSpec((1, chunk, n), lambda b, g, c: (b, at(c), g)),
-        pl.BlockSpec((1, 1, chunk, hg), lambda b, g, c: (b, g, at(c), 0)),
-        pl.BlockSpec((1, 1, chunk, hg), lambda b, g, c: (b, g, at(c), 0)),
-        pl.BlockSpec((1, 1, hg, chunk), lambda b, g, c: (b, g, 0, at(c))),
-        pl.BlockSpec((1, 1, hg * p), lambda b, g, c: (g, 0, 0)),
+        pl.BlockSpec((1, chunk, hb * p),
+                     lambda b, g, c, j: (b, at(c), g * nb + j)),
+        pl.BlockSpec((1, chunk, n), lambda b, g, c, j: (b, at(c), g)),
+        pl.BlockSpec((1, chunk, n), lambda b, g, c, j: (b, at(c), g)),
+        pl.BlockSpec((1, 1, chunk, hb),
+                     lambda b, g, c, j: (b, g * nb + j, at(c), 0)),
+        pl.BlockSpec((1, 1, chunk, hb),
+                     lambda b, g, c, j: (b, g * nb + j, at(c), 0)),
+        pl.BlockSpec((1, 1, hb, chunk),
+                     lambda b, g, c, j: (b, g * nb + j, 0, at(c))),
+        pl.BlockSpec((1, 1, hb * p), lambda b, g, c, j: (g * nb + j, 0, 0)),
     ]
 
 
@@ -354,24 +432,27 @@ def _forward(x, dt, a_head, bm, cm, d_head, chunk: int, interpret: bool,
     b, s, h, p = x.shape
     g, n = bm.shape[2:]
     hg = h // g
-    ops = _layouts(x, dt, a_head, bm, cm, d_head, chunk)
+    hpb, hb = _head_block(hg, p, interpret)
+    nb = hg // hb
+    ops = _layouts(x, dt, a_head, bm, cm, d_head, chunk, hb)
     sp = ops[0].shape[1]
     nc = sp // chunk
+    f32 = jnp.float32
     out_shape = [jax.ShapeDtypeStruct((b, sp, h * p), x.dtype)]
-    out_specs = [pl.BlockSpec((1, chunk, hg * p), lambda b, g, c: (b, c, g))]
+    out_specs = [pl.BlockSpec((1, chunk, hb * p),
+                              lambda b, g, c, j: (b, c, g * nb + j))]
     if save_states:
-        out_shape.append(jax.ShapeDtypeStruct((b, g, nc, n, hg * p),
-                                              jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, 1, n, hg * p),
-                                      lambda b, g, c: (b, g, c, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, g, nc, n, hg * p), f32))
+        out_specs.append(pl.BlockSpec((1, 1, 1, n, hb * p),
+                                      lambda b, g, c, j: (b, g, c, 0, j)))
     out = pl.pallas_call(
-        functools.partial(
-            _ssd_fwd_kernel, head_dim=p, save_states=save_states,
-            heads_per_block=_heads_per_block(hg, p, interpret)),
-        grid=(b, g, nc),
-        in_specs=_specs(chunk, hg, p, n, lambda c: c),
+        functools.partial(_ssd_fwd_kernel, head_dim=p,
+                          save_states=save_states, heads_per_block=hpb),
+        grid=(b, g, nc, nb),
+        in_specs=_specs(chunk, nb, hb, p, n, lambda c: c),
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((n, hg * p), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), f32),
+                        pltpu.VMEM((nb, n, hb * p), f32)],
         interpret=interpret, name="ssd_fwd",
     )(*ops)
     y = out[0][:, :s].reshape(b, s, h, p)
@@ -383,7 +464,9 @@ def _backward(x, dt, a_head, bm, cm, d_head, states, dy, chunk: int,
     b, s, h, p = x.shape
     g, n = bm.shape[2:]
     hg = h // g
-    ops = _layouts(x, dt, a_head, bm, cm, d_head, chunk)
+    hpb, hb = _head_block(hg, p, interpret)
+    nb = hg // hb
+    ops = _layouts(x, dt, a_head, bm, cm, d_head, chunk, hb)
     sp = ops[0].shape[1]
     nc = sp // chunk
     dy = jnp.pad(dy, ((0, 0), (0, sp - s), (0, 0), (0, 0))).reshape(
@@ -392,40 +475,42 @@ def _backward(x, dt, a_head, bm, cm, d_head, states, dy, chunk: int,
     def at(c):
         return nc - 1 - c
 
-    specs = _specs(chunk, hg, p, n, at)
+    specs = _specs(chunk, nb, hb, p, n, at)
     wide, group, _, cols, _, rows, _ = specs
     f32 = jnp.float32
     dx, db, dc, ddt, dcum_c, dcum_r, on, dd, edge = pl.pallas_call(
-        functools.partial(
-            _ssd_bwd_kernel, head_dim=p,
-            heads_per_block=_heads_per_block(hg, p, interpret)),
-        grid=(b, g, nc),
+        functools.partial(_ssd_bwd_kernel, head_dim=p, heads_per_block=hpb),
+        grid=(b, g, nc, nb),
         in_specs=specs + [
-            pl.BlockSpec((1, 1, 1, n, hg * p),
-                         lambda b, g, c: (b, g, at(c), 0, 0)),
+            pl.BlockSpec((1, 1, 1, n, hb * p),
+                         lambda b, g, c, j: (b, g, at(c), 0, j)),
             wide,
         ],
         out_specs=[
             wide, group, group, cols, cols, rows, cols, cols,
-            pl.BlockSpec((1, 1, 1, 1, hg * p),
-                         lambda b, g, c: (b, g, at(c), 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, hb * p),
+                         lambda b, g, c, j: (b, g, at(c), 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, sp, h * p), x.dtype),
             jax.ShapeDtypeStruct((b, sp, g * n), bm.dtype),
             jax.ShapeDtypeStruct((b, sp, g * n), cm.dtype),
-            jax.ShapeDtypeStruct((b, g, sp, hg), f32),
-            jax.ShapeDtypeStruct((b, g, sp, hg), f32),
-            jax.ShapeDtypeStruct((b, g, hg, sp), f32),
-            jax.ShapeDtypeStruct((b, g, sp, hg), f32),
-            jax.ShapeDtypeStruct((b, g, sp, hg), f32),
+            jax.ShapeDtypeStruct((b, h // hb, sp, hb), f32),
+            jax.ShapeDtypeStruct((b, h // hb, sp, hb), f32),
+            jax.ShapeDtypeStruct((b, h // hb, hb, sp), f32),
+            jax.ShapeDtypeStruct((b, h // hb, sp, hb), f32),
+            jax.ShapeDtypeStruct((b, h // hb, sp, hb), f32),
             jax.ShapeDtypeStruct((b, g, nc, 1, hg * p), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((n, hg * p), f32)],
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), f32),
+                        pltpu.VMEM((chunk, chunk), f32),
+                        pltpu.VMEM((chunk, n), f32),
+                        pltpu.VMEM((chunk, n), f32),
+                        pltpu.VMEM((nb, n, hb * p), f32)],
         interpret=interpret, name="ssd_bwd",
     )(*ops, states, dy)
 
-    def heads(z):           # [B, G, S, H/G] -> [B, S, H]
+    def heads(z):           # [B, H/hb, S, hb] -> [B, S, H]
         return z.transpose(0, 2, 1, 3).reshape(b, sp, h)
 
     def chunks(z):          # [B, S, H] -> [B, S/Q, Q, H]
