@@ -17,9 +17,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 import family_kit as kit
+import one_program
 
 from benchmark.families import keye as family
 from benchmark.reference import keye_f32
@@ -478,6 +480,161 @@ def test_ties_go_to_the_lower_key_and_every_set_is_exact() -> None:
                 tied_rows += 1
                 assert ties_in.max() < ties_out.min()
     assert tied_rows > 0
+
+
+# -- ``dsa_fwd`` a chunk of k tiles a grid step ------------------------------
+
+CHUNK_S = 96        # tiles of 3 keys, 6 q blocks of 16 rows: block 0 ends in
+#                     tile 5, block 1 in tile 10 — at 2 and 4 tiles a step
+#                     both rows' last chunks are partly above the diagonal,
+#                     and a row's live tiles leave a lone one behind the pairs
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_inputs():
+    """Four query heads on two key heads (of the small model's sixteen on
+    two), ``select``'s sets with tile 0 taken out of rows 40 - 59: those
+    rows have no chosen key in their first tile."""
+    x = family.kernel_inputs(CFG, 7, 2, CHUNK_S)
+    sel, _ = dsa.select(x["qi"], x["ki"], x["w"], CFG.index_topk)
+    rows = (jnp.arange(CHUNK_S) >= 40) & (jnp.arange(CHUNK_S) < 60)
+    sel = jnp.where(rows[None, :, None], sel & ~1, sel)
+    kept = np.asarray(dsa.unpack(sel))[:, 40:60]
+    assert not np.any(kept[..., :3]) and np.all(np.sum(kept, axis=-1) >= 9)
+    return x["q"][:, :4], x["k"], x["v"], x["do"][:, :4], sel
+
+
+@functools.lru_cache(maxsize=None)
+def _chunked(n):
+    """``attend`` at ``n`` k tiles a grid step in the interpreter (``None``:
+    the ``jnp`` form): ``o``, ``lse``, ``dq``, ``dk``, ``dv`` as ONE jitted
+    program, ``dsa_fwd``'s grid and the gauge its trace left."""
+    q, k, v, do, sel = _chunk_inputs()
+    kw = {} if n is None else dict(block_q=16, interpret=True)
+
+    def run(q, k, v, do):
+        (o, lse), pull = jax.vjp(
+            lambda q, k, v: dsa.attend(q, k, v, sel, **kw), q, k, v)
+        return (o, lse) + pull((do, jnp.zeros_like(lse)))
+
+    rule = dsa._choose_chunk
+    dsa._choose_chunk = lambda *shape: n
+    try:
+        calls = one_program.pallas_calls(run, q, k, v, do)
+        traced = TRACED.snapshot().get("dsa_fwd_chunk_tiles")
+        out = jax.device_get(jax.jit(run)(q, k, v, do))
+    finally:
+        dsa._choose_chunk = rule
+    grid = calls["dsa_fwd"].params["grid_mapping"].grid if calls else None
+    return dict(zip(("o", "lse", "dq", "dk", "dv"), out)), grid, traced
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_the_forward_sweeps_a_chunk_of_k_tiles_a_grid_step(n) -> None:
+    """At ``n`` tiles a step the k axis is ``32 / n`` steps and a chunk
+    that crosses the diagonal computes its live tiles only: ``o`` and
+    ``lse`` are the ``jnp`` form's at this file's tolerances, rows with no
+    chosen key in their first tile among them, on four query heads of two
+    key heads; against the one-tile kernel ``lse`` agrees to f32's rounding
+    and ``o`` to bf16's (a PAIR of tiles is one update of the statistics:
+    the same f32 sums met in another order); and the gradient through
+    ``attend`` — ``dsa_dq`` and ``dsa_dkv`` read the chunked forward's
+    ``lse`` — is the one-tile forward's."""
+    got, grid, traced = _chunked(n)
+    want, _, _ = _chunked(None)
+    one, one_grid, _ = _chunked(1)
+    assert traced == n and grid == (2 * 4, CHUNK_S // 16, dsa.WORD // n)
+    assert one_grid[2] == dsa.WORD
+
+    def off(a, b):
+        a, b = (np.asarray(z, np.float32) for z in (a, b))
+        return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+    for name, tol in (("lse", 1e-5), ("o", 1e-2), ("dq", 1e-2), ("dk", 1e-2),
+                      ("dv", 1e-2)):
+        assert off(got[name], want[name]) <= tol, name
+    quiet = slice(40, 60)
+    assert off(got["o"][:, :, quiet], want["o"][:, :, quiet]) <= 1e-2
+    assert off(got["lse"][:, :, quiet], want["lse"][:, :, quiet]) <= 1e-5
+    assert off(got["lse"], one["lse"]) <= 2e-7
+    for name in ("o", "dq", "dk", "dv"):
+        assert off(got[name], one[name]) <= 4e-3, name
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((16384, 128, 128, 2, 512), 32),     # the cell's call: the whole row
+    ((32768, 128, 128, 2, 512), 32),
+    ((65536, 128, 128, 2, 512), 8),      # 16 tiles of 2 048 keys do not fit
+    ((4096, 128, 128, 2, 512), 32),
+    ((2048, 128, 128, 2, 512), 1),       # tiles narrower than a lane tile
+    ((64, 16, 16, 2, 16), 1),            # the CPU tests' sizes
+    ((CHUNK_S, 16, 16, 2, 16), 1),
+])
+def test_the_chunk_is_a_pure_function_of_the_shape(shape, want,
+                                                   monkeypatch) -> None:
+    """The longest rung of the ladder (descending, divisors of the 32
+    tiles, 1 last) whose estimate fits ``_PARAMS``' VMEM limit; 1 where a
+    tile is narrower than a lane tile. The estimate grows with the chunk,
+    and under a smaller limit the rule takes a shorter rung."""
+    ladder = dsa._CHUNK_LADDER
+    assert list(ladder) == sorted(ladder, reverse=True) and ladder[-1] == 1
+    assert all(dsa.WORD % n == 0 for n in ladder)
+    assert max(dsa._STRAIGHT) <= 4 and dsa._STRAIGHT[-1] == 1
+    n = dsa._choose_chunk(*shape)
+    assert n == want and n in ladder
+    seq_len, d, dv, itemsize, block_q = shape
+    width = seq_len // dsa.WORD
+
+    def estimate(n):
+        return dsa._forward_vmem_estimate(d, dv, itemsize, block_q, width, n)
+
+    limit = dsa._PARAMS.vmem_limit_bytes
+    if n > 1:
+        assert estimate(n) <= limit
+        longer = [m for m in ladder if m > n]
+        assert all(estimate(m) > limit for m in longer)
+        monkeypatch.setattr(dsa, "_PARAMS", pltpu.CompilerParams(
+            vmem_limit_bytes=estimate(n) - 1))
+        assert dsa._choose_chunk(*shape) == ladder[ladder.index(n) + 1]
+    assert [estimate(m) for m in ladder] == sorted(
+        (estimate(m) for m in ladder), reverse=True)
+
+
+def test_the_gauge_says_what_the_cells_call_took() -> None:
+    """``TRACED["dsa_fwd_chunk_tiles"]`` after a trace of ``attend`` at
+    the cell's shape (2 rows of 16 384, 32 | 4 heads of 128; nothing
+    runs): more than one tile a step, the rule's count, and the grid's k
+    axis that many times shorter; at this file's sizes, which ask the
+    rule, 1."""
+    B, H, KV, L, D = 2, 32, 4, 16384, 128
+    q = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B, KV, L, D), jnp.bfloat16)
+    sel = jax.ShapeDtypeStruct((B, L, L // 32), jnp.int32)
+    calls = one_program.pallas_calls(
+        lambda q, k, v, sel: dsa._attend(q, k, v, sel, D ** -0.5, 512,
+                                         False), q, k, k, sel)
+    n = TRACED.snapshot()["dsa_fwd_chunk_tiles"]
+    assert n == dsa._choose_chunk(L, D, D, 2, 512) >= 2
+    assert calls["dsa_fwd"].params["grid_mapping"].grid == (
+        B * H, L // 512, dsa.WORD // n)
+    assert [v.aval.shape for v in calls["dsa_fwd"].outvars] == [
+        (B * H, L, D), (B * H, 1, L)]
+
+    def dots(jaxpr):
+        return sum((eqn.primitive.name == "dot_general") + sum(
+            dots(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    # a straight-line group is traced as a loop of ONE update (two
+    # matmuls) that lowering lays out: the body does not grow with the
+    # chunk (what a call site costs to compile is ``setup_s``)
+    assert dots(calls["dsa_fwd"].params["jaxpr"]) == 2 * len(dsa._STRAIGHT)
+    x = family.kernel_inputs(CFG, 1, 1, S)
+    small = jax.ShapeDtypeStruct((1, S, S // 32), jnp.int32)
+    jax.make_jaxpr(lambda q, k, v, sel: dsa.attend(
+        q, k, v, sel, block_q=8, interpret=True))(
+            x["q"], x["k"], x["v"], small)
+    assert TRACED.snapshot()["dsa_fwd_chunk_tiles"] == 1
 
 
 # -- the kernels compile for the chip ----------------------------------------

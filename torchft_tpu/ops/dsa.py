@@ -39,8 +39,16 @@ columns, its transposed recompute in dkv, its group-wide dk / dv
 accumulators) whose mask is the packed set and nothing else — a key after
 the query is in no set, so no iota: every causal TILE is computed (the
 work of full causal attention) and a key outside ``S_t`` gives nothing to
-``o``, ``dq``, ``dk``, ``dv``. Tiles above the diagonal are grid steps
-that compute and fetch nothing.
+``o``, ``dq``, ``dk``, ``dv``. In ``dsa_dq`` and ``dsa_dkv`` a grid step
+is one tile, and tiles above the diagonal are grid steps that compute and
+fetch nothing. ``dsa_fwd`` takes a CHUNK of consecutive k tiles a grid
+step (``_choose_chunk``: a pure function of the shape, the longest rung of
+``_CHUNK_LADDER`` that fits VMEM — all 32, the whole row of keys, at the
+cell's 16 384 —; ``TRACED["dsa_fwd_chunk_tiles"]`` says what a trace took)
+and sweeps the chunk's live tiles in ascending k in straight-line groups,
+as ``flash._flash_streamed_kernel`` does, the softmax statistics updated
+once a PAIR of tiles (f32, as every sum of the kernel; ``lse`` is the
+value the three other kernels read): 83.6 -> 35.4 ms a call there (PR 69).
 
 ``index_kl``: ``sum_t KL(pbar[t] || softmax_{S_t}(I[t]))`` with ``pbar[t,
 s] = mean_h P[t, h, s]`` (from ``q``, ``k``, ``lse``, all detached).
@@ -90,7 +98,8 @@ WORD = 32                       # keys a word of the packed set holds
 # a layer run again does not run ``dsa_fwd`` again — it computes every
 # causal tile to attend to a quarter of the pairs, and at that price a
 # second forward a step (83 ms a layer at 2 x 16 384 on the v5e, PERF.md,
-# PR 66) costs more than 272 MB a layer; and ``index_kl``'s gradients
+# PR 66; 35 since PR 69) costs more than 272 MB a layer; and
+# ``index_kl``'s gradients
 # (``_kl_fwd``: ``dqi``, ``dki``, ``dw``, 73 MB a layer there), so that
 # neither the layer run again nor the backward pass runs ``dsa_kl`` — kept
 # only where the backward pass reads THEM: ``models/keye.py`` pulls them
@@ -381,10 +390,79 @@ def _first_q(ki, block_q: int, width: int):
     return (ki * width) // block_q
 
 
+# K tiles a grid step of ``dsa_fwd`` (a tile is a bit of the packed word:
+# ``S / 32`` keys), the longest first, every rung a divisor of the 32; the
+# straight-line groups a chunk's live tiles are met in (``ops/flash.py``'s,
+# PR 60: inside a group Mosaic schedules an update's matmuls beside its
+# neighbour's softmax, which neither a grid step nor a loop trip lets it
+# do); and the tiles ONE update of the softmax statistics takes (one
+# max-reduce, one alpha, one rescale of acc: 1 024 keys at 16k, that
+# file's streamed tile; a lone tile updates alone). On the v5e (PR 69,
+# ``scripts/dsa_micro.py``; ``[64 | 8, 16384, 128]``, 2 048 keys a query, ms
+# a call at 1 / 2 / 4 / 8 / 16 / 32 tiles a step):
+#   an update a tile       83.6  75.2  71.2  69.9  65.9  62.7
+#   an update a pair       83.6  49.9  43.9  42.7  39.2  35.4
+# (one tile a step is the body the kernel had, bit for bit). At 32 the k
+# axis is one step: no dead steps, and K and V of a key head are fetched
+# once for all its query heads' rows. Four tiles an update read 34.7,
+# groups of (8, 4, 2, 1) 33.7 - 34.2 for twice the body; groups of (2, 1)
+# 39.0.
+_CHUNK_LADDER = (32, 16, 8, 4, 2, 1)
+_STRAIGHT = (4, 2, 1)
+_SPAN = 2
+
+
+def _forward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
+                           block_q: int, width: int, chunk: int) -> int:
+    """Bytes ``dsa_fwd`` keeps in VMEM at ``chunk`` k tiles a grid step:
+    its pipelined operands twice (K and V of the chunk, q, the block of
+    packed words, o and the lse row), the three scratch accumulators, the
+    f32 copy of q and, for every tile of the longest straight-line group,
+    S and P. Held against the compiler for a described v5e (PR 69: the
+    least ``vmem_limit_bytes`` at which the kernel lowers at the cell's
+    ``[64 | 8, 16384, 128]`` bf16, 512 rows; MiB, estimate -> allocation):
+    1 tile 6.0 -> 4.69, 2 tiles 8.5 -> 6.91, 4 tiles 13.5 -> 8.88, 8 tiles
+    15.5 -> 10.84, 16 tiles 19.5 -> 14.78, 32 tiles 27.5 -> 22.90 (an
+    update a tile allocates 7.40 / 11.83 / 13.80 / 17.73 / 25.61 from 2
+    tiles on): over at every rung, by 1.3 - 4.7."""
+    pair = head_dim + v_dim
+    operands = ((chunk * width + block_q) * pair * itemsize
+                + block_q * width * 4 + block_q * 4)
+    scratch = block_q * (v_dim + 2 * _LANES) * 4
+    straight = min(chunk, _STRAIGHT[0])
+    return (2 * operands + scratch + block_q * head_dim * 4
+            + straight * 2 * block_q * width * 4)
+
+
+def _choose_chunk(seq_len: int, head_dim: int, v_dim: int, itemsize: int,
+                  block_q: int) -> int:
+    """K tiles a grid step of ``dsa_fwd`` sweeps, a pure function of the
+    shape: 1 where a tile is narrower than a lane tile (its slice of a
+    chunk's K and V and its scores' lanes would be no whole tiles of the
+    chip's layout: the sizes the interpreter runs), else the longest rung
+    of ``_CHUNK_LADDER`` whose :func:`_forward_vmem_estimate` fits
+    ``_PARAMS``' limit."""
+    width = _width(seq_len)
+    if width % _LANES:
+        return 1
+    limit = _PARAMS.vmem_limit_bytes
+    return next(n for n in _CHUNK_LADDER if n == 1 or _forward_vmem_estimate(
+        head_dim, v_dim, itemsize, block_q, width, n) <= limit)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, acc_ref,
-                m_ref, l_ref, *, scale: float, block_q: int, width: int):
+                m_ref, l_ref, *, scale: float, block_q: int, width: int,
+                chunk: int):
+    """Grid ``(B * H, q blocks, 32 / chunk)``: a step holds ``chunk``
+    consecutive k tiles of K and V and sweeps those at or before the q
+    block's last row (:func:`_last_k`) in ascending k, in straight-line
+    groups of ``_STRAIGHT`` tiles — each traced as a loop of ONE update
+    (of ``_SPAN`` tiles) that is laid out whole when it is lowered —,
+    ``(acc, m, l)`` carried as values inside a group and through the
+    scratch between groups. At one tile a step there is no loop."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     last = _last_k(qi, block_q, width)
+    lo = ki * chunk                 # ki counts chunks; the tiles are lo on
 
     @pl.when(ki == 0)
     def _init():
@@ -392,59 +470,107 @@ def _fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, acc_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki <= last)
-    def _tile():
-        # ``flash._fwd_tile`` under the set's mask: a row with no chosen
-        # key yet accumulates at m = _NEG_INF and its first chosen key
-        # clears that (alpha = 0), as under that file's window
-        s = jnp.where(_bit(sel_ref[0], ki),
-                      _dot(_f32(q_ref[0]) * scale, _f32(k_ref[0]), _NT),
-                      _NEG_INF)
-        m, l = m_ref[:, :1], l_ref[:, :1]
+    def _carried():
+        return acc_ref[...], m_ref[:, :1], l_ref[:, :1]
+
+    def _keep(acc, m, l):
+        acc_ref[...] = acc
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
+
+    def _update(q, kt, span, acc, m, l):
+        # ``flash._fwd_tile`` over tiles ``kt .. kt + span`` under the
+        # set's mask: a row with no chosen key yet accumulates at m =
+        # _NEG_INF and its first chosen key clears that (alpha = 0), as
+        # under that file's window
+        if chunk == 1:
+            k, v = k_ref[0], v_ref[0]
+        else:
+            at = pl.ds(pl.multiple_of((kt - lo) * width, width),
+                       span * width)
+            k, v = k_ref[0, at, :], v_ref[0, at, :]
+        words = sel_ref[0]
+        keep = [_bit(words, kt + j) for j in range(span)]
+        s = jnp.where(keep[0] if span == 1 else jnp.concatenate(keep, axis=1),
+                      _dot(q, _f32(k), _NT), _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        acc_ref[...] = acc_ref[...] * alpha + _dot(p, _f32(v_ref[0]), _NN)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        return (acc * alpha + _dot(p, _f32(v), _NN),
+                m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True))
 
-    @pl.when(ki == last)
+    @pl.when(lo <= last)
+    def _chunk():
+        q = _f32(q_ref[0]) * scale
+        if chunk == 1:
+            _keep(*_update(q, ki, 1, *_carried()))
+            return
+
+        def group(size, start):
+            span = min(size, _SPAN)
+
+            def body(i, _):
+                first = start + i * size
+                _keep(*jax.lax.fori_loop(
+                    0, size // span,
+                    lambda j, carry: _update(q, first + j * span, span,
+                                             *carry),
+                    _carried(), unroll=True))
+                return 0
+            return body
+
+        # the chunk's live tiles in ascending k: as many groups of the
+        # longest size as they hold, then of the next
+        done, live_hi = lo, jnp.minimum(last + 1, lo + chunk)
+        for size in _STRAIGHT:
+            if size > chunk:
+                continue
+            count = (live_hi - done) // size
+            jax.lax.fori_loop(0, count, group(size, done), 0)
+            done = done + count * size
+
+    @pl.when(ki == last // chunk)
     def _finalize():
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
         lse_ref[0] = _wide_to_row(m_ref[...] + jnp.log(l))
 
 
-def _row_maps(heads: int, group: int, block_q: int, width: int):
-    """Index maps of a row sweep's grid ``(B * H, q blocks, k tiles)``:
-    q rows, k rows (clamped to the last live tile: a dead step fetches
-    nothing), q positions along the lanes, the batch row's words."""
+def _row_maps(heads: int, group: int, block_q: int, width: int,
+              chunk: int = 1):
+    """Index maps of a row sweep's grid ``(B * H, q blocks, k steps)``:
+    q rows, k rows in blocks of ``chunk`` tiles (clamped to the last live
+    one: a dead step fetches nothing), q positions along the lanes, the
+    batch row's words."""
     def by_k(bh, i, j):
-        return (bh // group, jnp.minimum(j, _last_k(i, block_q, width)), 0)
+        return (bh // group,
+                jnp.minimum(j, _last_k(i, block_q, width) // chunk), 0)
 
     return (lambda bh, i, j: (bh, i, 0), by_k,
             lambda bh, i, j: (bh, 0, i),
             lambda bh, i, j: (bh // heads, i, 0))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _forward(q, k, v, sel, heads: int, scale: float, block_q: int,
-             interpret: bool):
+             chunk: int, interpret: bool):
     """``q [B * H, S, D]``, ``k``, ``v [B * KV, S, D]``, ``sel [B, S, S /
-    32]`` -> ``(o [B * H, S, D], lse [B * H, 1, S])``."""
+    32]`` -> ``(o [B * H, S, D], lse [B * H, 1, S])``, ``chunk`` k tiles a
+    grid step (:func:`_choose_chunk`'s: ``TRACED["dsa_fwd_chunk_tiles"]``
+    says what the last call traced took)."""
     bh, seq_len, d = q.shape
     width, dv = _width(seq_len), v.shape[-1]
+    TRACED.gauge("dsa_fwd_chunk_tiles", chunk)
     by_q, by_k, q_lanes, words = _row_maps(heads, bh // k.shape[0], block_q,
-                                           width)
+                                           width, chunk)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                          width=width),
-        grid=(bh, seq_len // block_q, WORD),
+                          width=width, chunk=chunk),
+        grid=(bh, seq_len // block_q, WORD // chunk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), by_q),
-            pl.BlockSpec((1, width, d), by_k),
-            pl.BlockSpec((1, width, dv), by_k),
+            pl.BlockSpec((1, chunk * width, d), by_k),
+            pl.BlockSpec((1, chunk * width, dv), by_k),
             pl.BlockSpec((1, block_q, width), words),
         ],
         out_specs=[
@@ -606,9 +732,11 @@ def _attend(q, k, v, sel, scale, block_q, interpret):
 
 
 def _attend_fwd(q, k, v, sel, scale, block_q, interpret):
+    chunk = _choose_chunk(q.shape[2], q.shape[3], v.shape[3],
+                          q.dtype.itemsize, block_q)
     o, lse = (checkpoint_name(a, KEY_CHOICE) for a in _forward(
         _merge(q), _merge(k), _merge(v), sel, q.shape[1], scale, block_q,
-        interpret))
+        chunk, interpret))
     return ((o.reshape(*q.shape[:3], -1), lse.reshape(q.shape[:3])),
             (q, k, v, sel, o, lse))
 
