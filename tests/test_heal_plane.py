@@ -391,7 +391,9 @@ def test_donor_death_mid_stream_retries_surviving_peer() -> None:
 
 def test_heal_metrics_surface() -> None:
     # The heal round must land heal_stage / heal_wire spans and the
-    # heal_wall_ms / heal_bytes_per_s gauges in the shared sink.
+    # heal_fetch_ms / heal_bytes_per_s gauges in the shared sink;
+    # heal_wall_ms is the Manager's (assignment → applied), and a
+    # transport on its own writes none.
     import jax.numpy as jnp
 
     state = {"w": jnp.arange(4096, dtype=jnp.float32)}
@@ -409,12 +411,77 @@ def test_heal_metrics_surface() -> None:
         h = healer_metrics.snapshot()
         assert d.get("heal_stage_avg_ms", -1) >= 0.0, sorted(d)
         assert h.get("heal_wire_avg_ms", -1) >= 0.0, sorted(h)
-        assert h.get("heal_wall_ms", -1) > 0.0, sorted(h)
+        assert h.get("heal_fetch_ms", -1) > 0.0, sorted(h)
+        assert "heal_wall_ms" not in h and "heal_wall_ms" not in d
         assert h.get("heal_bytes_per_s", -1) > 0.0, sorted(h)
-        for v in (h["heal_wall_ms"], h["heal_bytes_per_s"]):
+        for v in (h["heal_fetch_ms"], h["heal_bytes_per_s"]):
             assert np.isfinite(v)
+        # the fetch's inside: bytes and leaves as the manifest has them
+        # (one CRC frame a leaf rides the raw stream), the workers'
+        # seconds by phase, and the donor's side of the same wire
+        assert (h["heal_bytes"], h["heal_leaves"]) == (4096 * 4 + 4, 1)
+        for phase in ("wait", "read", "crc"):
+            assert h[f"heal_wire_{phase}_s"] > 0.0
+            assert h[f"heal_wire_{phase}_avg_ms"] > 0.0
+        assert 0.0 <= h["heal_donor_wait_share"] <= 1.0
+        assert d["heal_serve_crc_s"] > 0.0
+        assert {"heal_gate_avg_ms", "heal_serve_avg_ms"} <= set(d)
     finally:
         donor.shutdown()
+        healer.shutdown()
+
+
+@pytest.mark.parametrize("mode", ["sharded", "chunked"])
+def test_donor_wait_share_is_this_heals_and_rises_with_a_slow_stage(
+        mode: str) -> None:
+    # Heals into ONE sink: plain, staging delayed by the _stage_hook
+    # seam, plain. Each heal's share is the ratio of ITS deltas of the
+    # three counters (read here around the call), not the run's. On the
+    # sharded plane a delayed donor holds a worker before the response's
+    # headers; the raw stream sends its headers from metadata and stages
+    # behind the wire, so there a slow stage reads as a slow body.
+    import jax.numpy as jnp
+
+    # leaves large enough that reading and checking them outweighs a
+    # request's round trip, so the plain heals read low
+    n, side = 3, 1448
+    state = {f"l{i}": jnp.full((side, side), float(i), jnp.float32)
+             for i in range(n)}
+    sharded = mode == "sharded"
+    healer = CheckpointServer(
+        timeout=10.0, **({"template_fn": lambda: state} if sharded
+                         else {"num_chunks": 2}))
+    sink = Metrics()
+    healer.set_metrics(sink)
+    phases = ("heal_wire_wait_s", "heal_wire_read_s", "heal_wire_crc_s")
+    shares = []
+    try:
+        for step, delay in enumerate(
+                (0.0, 0.15, 0.0) if sharded else (0.0, 0.0)):
+            donor = CheckpointServer(timeout=10.0)
+            donor._stage_hook = lambda idx, path, d=delay: time.sleep(d)
+            try:
+                before = [sink.count(p) for p in phases]
+                donor.send_checkpoint([], step, state, 10.0)
+                got = healer.recv_checkpoint(0, donor.metadata(), step, 10.0)
+                _assert_bitwise(got, state)
+            finally:
+                donor.shutdown()
+            wait, read, crc = (sink.count(p) - b
+                               for p, b in zip(phases, before))
+            snap = sink.snapshot()
+            assert snap["heal_donor_wait_share"] == pytest.approx(
+                wait / (wait + read + crc), rel=1e-9)
+            assert 0.0 <= snap["heal_donor_wait_share"] <= 1.0
+            assert snap["heal_leaves"] == n
+            assert snap["heal_bytes"] == n * side * side * 4 + (
+                0 if sharded else n * 4)  # the raw stream's CRC frames
+            if delay:
+                assert wait >= delay  # a worker sat out one stage at least
+            shares.append(snap["heal_donor_wait_share"])
+        if sharded:
+            assert shares[1] > max(shares[0], shares[2]), shares
+    finally:
         healer.shutdown()
 
 

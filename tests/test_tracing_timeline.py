@@ -13,6 +13,7 @@ from typing import Any, Dict, List
 import numpy as np
 import pytest
 
+from torchft_tpu.checkpointing import CheckpointServer
 from torchft_tpu.comm.store import StoreServer
 from torchft_tpu.comm.transport import TcpCommContext
 from torchft_tpu.control import Lighthouse
@@ -96,9 +97,21 @@ class _Replica:
     "gradient", commit."""
 
     def __init__(self, name: str, value: float, lighthouse_addr: str,
-                 will_hang: bool = False) -> None:
+                 will_hang: bool = False, leaves: int = 0) -> None:
         self.store = StoreServer()
         self.state = {"w": np.full((4,), value, np.float32)}
+        transport = None
+        if leaves:
+            # a many-leaf device state beside the weights, healed over
+            # the sharding-aware plane as the kill cell's is
+            import jax.numpy as jnp
+
+            self.state["p"] = [jnp.full((64, 32), value + i, jnp.float32)
+                               for i in range(leaves)]
+            transport = CheckpointServer(timeout=5.0, template_fn=lambda: {
+                "user": dict(self.state),
+                "torchft": {"step": 0, "batches_committed": 0},
+            })
         # weights as committed, by the step they made: two free-running
         # loops are compared at a step both have, never mid-commit
         self.committed: Dict[int, np.ndarray] = {}
@@ -106,8 +119,9 @@ class _Replica:
         self.manager = Manager(
             comm=TcpCommContext(timeout=5.0),
             load_state_dict=lambda sd: self.state.update(
-                w=np.array(sd["w"], np.float32)),
-            state_dict=lambda: {"w": self.state["w"]},
+                sd, w=np.array(sd["w"], np.float32)),
+            state_dict=lambda: dict(self.state),
+            checkpoint_transport=transport,
             min_replica_size=1,
             timeout=5.0, quorum_timeout=20.0, connect_timeout=10.0,
             rank=0, world_size=1, store_addr=self.store.addr,
@@ -276,6 +290,71 @@ def test_episode_phases_are_timings_of_the_managers_sink(
     assert snap["replica_id"].startswith("tl_a_")
 
 
+def test_failed_wire_is_inside_wire_wait_in_every_episode(
+        kill_and_rejoin) -> None:
+    """As ``configure`` is inside ``quorum_wait``: no phase of the gap's
+    partition, never more than ``wire_wait``, nothing where no step was
+    discarded, and a timing of the sink under the episode's kind."""
+    for e in kill_and_rejoin["everything"]:
+        assert 0.0 <= e["failed_wire_ms"] <= e["wire_wait_ms"] + 1e-3, e
+        assert (e["failed_wire_ms"] > 0.0) <= (e["discards"] > 0), e
+    (shrink,) = kill_and_rejoin["after_kill"]
+    snap = kill_and_rejoin["snapshot"]
+    assert snap["episode_shrink_failed_wire_max_ms"] == pytest.approx(
+        shrink["failed_wire_ms"], abs=1e-3)
+    assert "failed_wire_ms" not in _PARTITION  # the gap's tiling is as it was
+
+
+def test_failed_wire_is_the_discarded_steps_wire_apart_from_the_next_steps(
+) -> None:
+    """A step waits on the wire and is discarded (a peer died under it);
+    the next one, narrower, waits again and commits. The shrink episode's
+    ``wire_wait`` holds both waits, its ``failed_wire`` the first alone."""
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=200)
+    store = StoreServer()
+    try:
+        manager = Manager(
+            comm=TcpCommContext(timeout=5.0),
+            load_state_dict=lambda sd: None, state_dict=dict,
+            min_replica_size=1, rank=0, world_size=1,
+            store_addr=store.addr, lighthouse_addr=lh.address(),
+            replica_id="tl_failed_wire_",
+        )
+        try:
+            for _ in range(2):      # past its first commit: no rejoin
+                manager.start_quorum()
+                manager.wait_quorum()
+                assert manager.should_commit()
+            manager.metrics.reset_timings()
+            manager._interval.members += ("gone",)  # it will have left
+            manager.start_quorum()
+            with manager.blocked_on_wire():
+                time.sleep(0.03)    # on the dead peer's sockets
+            manager.report_error(ConnectionError("peer died"))
+            assert not manager.should_commit()
+            manager.start_quorum()
+            with manager.blocked_on_wire():
+                time.sleep(0.01)    # the first narrow step's own wire
+            assert manager.should_commit()
+            (episode,) = [e for e in manager.events.since(0)[0]
+                          if e["kind"] == "recovery_episode"
+                          and e["episode"] == "shrink"]
+            snap = manager.metrics.snapshot()
+        finally:
+            manager.shutdown(wait=False)
+    finally:
+        store.shutdown()
+        lh.shutdown()
+    assert episode["discards"] == 1
+    assert 30.0 <= episode["failed_wire_ms"] <= episode["wire_wait_ms"] - 10.0
+    assert snap["episode_shrink_failed_wire_max_ms"] == pytest.approx(
+        episode["failed_wire_ms"], abs=1e-3)
+    assert snap["episode_shrink_failed_wire_max_ms"] <= (
+        snap["episode_shrink_wire_wait_max_ms"])
+    parts = [episode[k] for k in _PARTITION if k in episode]
+    assert sum(parts) == pytest.approx(episode["gap_ms"], rel=0.02)
+
+
 def test_three_survivors_commit_on_while_a_killed_groups_heartbeat_is_fresh(
 ) -> None:
     """Four groups under the kill cell's lighthouse settings but for a
@@ -359,7 +438,8 @@ def merged_quorum():
 
 
 _TIMED = ("gap", "quorum_wait", "wire_wait", "heal", "barrier", "other",
-          "configure")
+          "configure", "failed_wire")
+_INSIDE_ANOTHER = ("configure", "failed_wire")
 
 
 def test_one_quorum_that_drops_and_admits_is_one_episode_of_both_kinds(
@@ -401,7 +481,7 @@ def test_merged_episodes_phases_partition_its_gap(merged_quorum) -> None:
     snap = merged_quorum["snapshot"]
     for kind in ("shrink", "grow"):   # and so do each kind's timings
         timed = [snap[f"episode_{kind}_{phase}_max_ms"] for phase in _TIMED
-                 if phase not in ("gap", "configure")]
+                 if phase != "gap" and phase not in _INSIDE_ANOTHER]
         assert sum(timed) == pytest.approx(
             snap[f"episode_{kind}_gap_max_ms"], rel=0.02)
 
@@ -452,6 +532,148 @@ def test_an_episode_is_named_by_its_membership_edges(
     assert set(episode["episode"].split("+")) == kinds
     assert (episode["left"], episode["joined"]) == (left, joined)
     assert not manager._interval.dirty    # and the next interval is open
+
+
+# ------------------------------------------------- the heal on one clock
+
+_JOINER_SPANS = {"heal_meta", "heal_fetch", "heal_wire", "heal_wire_wait",
+                 "heal_wire_read", "heal_wire_crc", "heal_h2d", "heal_apply"}
+_DONOR_SPANS = {"heal_stage", "heal_gate", "heal_serve"}
+_SHUTDOWN_PARTS = ("shutdown_checkpoint", "shutdown_server",
+                   "shutdown_executor", "shutdown_comm")
+_LEAVES = 12
+
+
+@pytest.fixture(scope="module")
+def traced_heal(tmp_path_factory):
+    """A group steps alone; under ``jax.profiler`` a second one with
+    other weights joins, heals a many-leaf state from it and is then torn
+    down. Every ``tft.*`` event of the trace, and both sinks."""
+    import jax
+    from jax.profiler import ProfileData
+
+    lh = Lighthouse(min_replicas=1, join_timeout_ms=200)
+    live: List[_Replica] = []
+    trace_dir = str(tmp_path_factory.mktemp("heal_trace"))
+    try:
+        donor = _Replica("a", 1.0, lh.address(), leaves=_LEAVES)
+        live.append(donor)
+        donor.run_to(5)
+        jax.profiler.start_trace(trace_dir)
+        try:
+            joiner = _Replica("c", 99.0, lh.address(), leaves=_LEAVES)
+            live.append(joiner)
+            joiner.run_to(donor.manager.current_step() + 3)
+            sinks = {"joiner": joiner.manager.metrics.snapshot(),
+                     "donor": donor.manager.metrics.snapshot()}
+            joiner.kill()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        events = []
+        planes = ProfileData.from_file(path).planes
+        for i, plane in enumerate(planes):
+            for j, line in enumerate(plane.lines):  # a line a thread
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        events.append(dict(
+                            e.stats, name=e.name[len(SPAN_PREFIX):],
+                            t0=e.start_ns, t1=e.start_ns + e.duration_ns,
+                            line=(i, j)))
+        yield {
+            "events": events, **sinks,
+            "ids": {"joiner": joiner.manager.replica_id(),
+                    "donor": donor.manager.replica_id()},
+            "equal": all(np.array_equal(a, b) for a, b in zip(
+                joiner.state["p"], donor.state["p"])),
+        }
+    finally:
+        for r in live:
+            r.kill()
+        lh.shutdown()
+
+
+def _named(traced, *names):
+    return [e for e in traced["events"] if e["name"] in names]
+
+
+def test_every_heal_span_is_on_the_trace_under_its_sides_replica(
+        traced_heal) -> None:
+    assert traced_heal["equal"]
+    heal = [e for e in traced_heal["events"] if e["name"].startswith("heal_")]
+    assert {e["name"] for e in heal} == _JOINER_SPANS | _DONOR_SPANS
+    for e in heal:
+        side = "joiner" if e["name"] in _JOINER_SPANS else "donor"
+        assert e.get("replica") == traced_heal["ids"][side], e
+    # one heal: one address, one fetch, one apply, the state's step
+    (meta,), (fetch,), (apply_,) = (
+        _named(traced_heal, n) for n in ("heal_meta", "heal_fetch",
+                                         "heal_apply"))
+    assert meta["step"] == fetch["step"] == apply_["step"] >= 5
+    assert fetch["workers"] == 2 and "src" in meta
+    assert meta["t1"] <= fetch["t0"] <= fetch["t1"] <= apply_["t0"]
+
+
+def test_a_heals_leaf_spans_carry_leaf_and_bytes_that_sum_to_the_heal(
+        traced_heal) -> None:
+    joiner = traced_heal["joiner"]
+    wires = _named(traced_heal, "heal_wire")
+    for e in _named(traced_heal, "heal_wire", "heal_h2d", "heal_stage",
+                    "heal_serve"):
+        assert "leaf" in e and e["bytes"] >= 0, e
+    assert all("host" in e for e in wires)
+    assert sum(e["bytes"] for e in wires) == joiner["heal_bytes"] == (
+        _LEAVES * 64 * 32 * 4 + 4 * 4)
+    # a fetch a leaf (w, the p leaves, torchft's two counters); the device
+    # leaves go up a leaf at a time, and the donor staged and served them
+    assert joiner["heal_leaves"] == len(wires) == _LEAVES + 3
+    assert len(_named(traced_heal, "heal_h2d")) == _LEAVES
+    for name in ("heal_stage", "heal_serve"):
+        assert sum(e["bytes"] for e in _named(traced_heal, name)) == (
+            joiner["heal_bytes"])
+    # inside a worker's heal_wire: the wait for the response, the body,
+    # the checksum, in that order and nowhere else
+    fetch = _named(traced_heal, "heal_fetch")[0]
+    for wire in wires:
+        inside = sorted(
+            (e for e in _named(traced_heal, "heal_wire_wait",
+                               "heal_wire_read", "heal_wire_crc")
+             if e["line"] == wire["line"]
+             and wire["t0"] <= e["t0"] and e["t1"] <= wire["t1"]),
+            key=lambda e: e["t0"])
+        assert [e["name"] for e in inside] in (
+            ["heal_wire_wait"],  # an object: pickled, no tensor body
+            ["heal_wire_wait", "heal_wire_read", "heal_wire_crc"]), wire
+        assert fetch["t0"] <= wire["t0"] and wire["t1"] <= fetch["t1"]
+
+
+def test_the_heals_phases_tile_heal_wall_ms(traced_heal) -> None:
+    """``heal_wall_ms`` (assignment → applied, the Manager's alone) is
+    the address, the fetch, the wait for the step thread and the apply,
+    to within 1 % or 20 ms."""
+    s = traced_heal["joiner"]
+    tiles = (s["heal_meta_max_ms"] + s["heal_fetch_ms"]
+             + s["heal_apply_wait_ms"] + s["heal_apply_max_ms"])
+    assert s["heal_wall_ms"] >= tiles - 1e-6
+    assert s["heal_wall_ms"] - tiles <= max(0.01 * s["heal_wall_ms"], 20.0)
+    assert s["heal_fetch_ms"] <= s["heal_fetch_max_ms"]  # inside its span
+    assert 0.0 <= s["heal_donor_wait_share"] <= 1.0
+    assert not any(k.startswith("heal_wall") for k in traced_heal["donor"])
+    assert traced_heal["donor"]["heal_serve_crc_s"] > 0.0
+
+
+def test_a_shutdown_holds_its_four_parts_in_order(traced_heal) -> None:
+    mine = [e for e in traced_heal["events"]
+            if e.get("replica") == traced_heal["ids"]["joiner"]]
+    (whole,) = [e for e in mine if e["name"] == "shutdown"]
+    parts = sorted((e for e in mine if e["name"] in _SHUTDOWN_PARTS),
+                   key=lambda e: e["t0"])
+    assert tuple(e["name"] for e in parts) == _SHUTDOWN_PARTS
+    assert all(e["step"] == whole["step"] for e in parts)
+    edges = [whole["t0"]] + [t for e in parts for t in (e["t0"], e["t1"])] + [
+        whole["t1"]]
+    assert edges == sorted(edges)
 
 
 # ------------------------------------------- names on the device timeline

@@ -421,7 +421,7 @@ def _build_staged(step: int, state: Any,
             def _stage(x=leaf, p=path, pb=tuple(piece_bounds), idx=i):
                 if stage_hook is not None:
                     stage_hook(idx, p)
-                with span(metrics, "heal_stage"):
+                with span(metrics, "heal_stage", leaf=idx, bytes=x.nbytes):
                     staged = _ShardedLeaf(x)
                     staged.pieces = {
                         b: arr for b, arr in staged.pieces.items()
@@ -445,7 +445,7 @@ def _build_staged(step: int, state: Any,
                 }
             )
         elif isinstance(leaf, np.ndarray):
-            with span(metrics, "heal_stage"):
+            with span(metrics, "heal_stage", leaf=i, bytes=leaf.nbytes):
                 # detach from live training NOW (host arrays are
                 # mutable) — this memcpy is staging work like any D2H
                 snap = np.array(leaf, copy=True)
@@ -570,9 +570,10 @@ class _Handler(BaseHTTPRequestHandler):
         error response."""
         server: "CheckpointServer" = self.server.ckpt_server  # type: ignore[attr-defined]
         with server._cond:
-            opened = server._cond.wait_for(
-                lambda: not server._disallowed, timeout=server._timeout
-            )
+            with span(server._metrics, "heal_gate", step=step):
+                opened = server._cond.wait_for(
+                    lambda: not server._disallowed, timeout=server._timeout
+                )
             if not opened:
                 self.send_error(
                     503,
@@ -590,9 +591,28 @@ class _Handler(BaseHTTPRequestHandler):
                 return None
             return staged
 
+    def _write_body(self, view, crc: bool) -> float:
+        """Chunked writes of one tensor's wire bytes and, under ``crc``,
+        its CRC32C trailer, accumulated chunk by chunk on the way out.
+        Returns the seconds the checksum took: it runs inline on this
+        handler's thread, between two writes."""
+        c, crc_s = 0, 0.0
+        for off in range(0, view.nbytes, _SEND_CHUNK):
+            chunk = view[off: off + _SEND_CHUNK]
+            if crc:
+                t0 = time.perf_counter()
+                c = crc32c(chunk, c)
+                crc_s += time.perf_counter() - t0
+            if _WIRE_FAULT_HOOK is not None:
+                chunk = _WIRE_FAULT_HOOK(chunk)
+            self.wfile.write(chunk)
+        if crc:
+            self.wfile.write(struct.pack("<I", c))
+        return crc_s
+
     def _send_tensor(self, arr: np.ndarray, dtype: np.dtype,
                      wire_dtype: "Optional[np.dtype]",
-                     crc: bool = False) -> None:
+                     crc: bool = False, *, leaf: int) -> None:
         """Stream one tensor region: headers + chunked writes of a byte
         view over the (staged) array — no tobytes, no body
         materialization. ``dtype`` is the staged dtype; ``wire_dtype``
@@ -600,32 +620,27 @@ class _Handler(BaseHTTPRequestHandler):
         way out, which inherently allocates — it is the opt-in lossy
         lever, never the default. ``crc`` appends the 4-byte CRC32C
         trailer (requested via ``?crc=1``; Content-Length includes
-        it)."""
+        it). ``leaf`` names the response on the donor's timeline."""
+        metrics = self.server.ckpt_server._metrics  # type: ignore[attr-defined]
         view, wired = _wire_encode(arr, wire_dtype)
-        self.send_response(200)
-        self.send_header("X-Kind", "ndarray")
-        self.send_header("X-Dtype", str(dtype))
-        if wired is not None:
-            self.send_header("X-Wire-Dtype", str(wired))
-        self.send_header(
-            "X-Shape", ",".join(str(d) for d in arr.shape)
-        )
-        self.send_header(
-            "Content-Length", str(view.nbytes + (4 if crc else 0))
-        )
-        self.end_headers()
-        self._body_streaming = True
-        c = 0
-        for off in range(0, view.nbytes, _SEND_CHUNK):
-            chunk = view[off: off + _SEND_CHUNK]
-            if crc:
-                c = crc32c(chunk, c)
-            if _WIRE_FAULT_HOOK is not None:
-                chunk = _WIRE_FAULT_HOOK(chunk)
-            self.wfile.write(chunk)
-        if crc:
-            self.wfile.write(struct.pack("<I", c))
-        self._body_streaming = False
+        with span(metrics, "heal_serve", leaf=leaf, bytes=view.nbytes):
+            self.send_response(200)
+            self.send_header("X-Kind", "ndarray")
+            self.send_header("X-Dtype", str(dtype))
+            if wired is not None:
+                self.send_header("X-Wire-Dtype", str(wired))
+            self.send_header(
+                "X-Shape", ",".join(str(d) for d in arr.shape)
+            )
+            self.send_header(
+                "Content-Length", str(view.nbytes + (4 if crc else 0))
+            )
+            self.end_headers()
+            self._body_streaming = True
+            crc_s = self._write_body(view, crc)
+            self._body_streaming = False
+        if crc and metrics is not None:
+            metrics.incr("heal_serve_crc_s", crc_s)
 
     def _send_json(self, obj: dict) -> None:
         import json
@@ -803,6 +818,7 @@ class _Handler(BaseHTTPRequestHandler):
                 self.end_headers()
                 self._body_streaming = True
                 server_timeout = server._timeout
+                metrics, crc_s = server._metrics, 0.0
                 for i in range(lo, hi):
                     leaf = staged.leaf(i, server_timeout)  # JIT stage
                     arr = (
@@ -810,17 +826,12 @@ class _Handler(BaseHTTPRequestHandler):
                         if isinstance(leaf, _ShardedLeaf) else leaf
                     )
                     view, _ = _wire_encode(arr, wire_dtype)
-                    c = 0
-                    for off in range(0, view.nbytes, _SEND_CHUNK):
-                        chunk = view[off: off + _SEND_CHUNK]
-                        if crc:
-                            c = crc32c(chunk, c)
-                        if _WIRE_FAULT_HOOK is not None:
-                            chunk = _WIRE_FAULT_HOOK(chunk)
-                        self.wfile.write(chunk)
-                    if crc:
-                        self.wfile.write(struct.pack("<I", c))
+                    with span(metrics, "heal_serve", leaf=i,
+                              bytes=view.nbytes):
+                        crc_s += self._write_body(view, crc)
                 self._body_streaming = False
+                if crc and metrics is not None:
+                    metrics.incr("heal_serve_crc_s", crc_s)
                 return
 
             if parts[2] == "leaf" and len(parts) == 4:
@@ -872,7 +883,7 @@ class _Handler(BaseHTTPRequestHandler):
                     arr = leaf[_parse_slice_spec(spec, leaf.shape)]
                 else:
                     arr = leaf
-                self._send_tensor(arr, dtype, wire_dtype, crc=crc)
+                self._send_tensor(arr, dtype, wire_dtype, crc=crc, leaf=idx)
                 return
 
             self.send_error(404, "unknown path")
@@ -1051,6 +1062,13 @@ class CheckpointServer(CheckpointTransport[T]):
         if staged is not None:
             staged.finish_staging(self._timeout)
 
+    @property
+    def fetch_workers(self) -> int:
+        """Connections ``recv_checkpoint`` fetches over at once."""
+        if self._template_fn is not None:
+            return max(2, self._num_chunks)
+        return max(1, self._num_chunks)
+
     def recv_checkpoint(
         self, src_rank: int, metadata: str, step: int,
         timeout: "float | timedelta",
@@ -1062,7 +1080,7 @@ class CheckpointServer(CheckpointTransport[T]):
         if self._template_fn is not None:
             out = recv_checkpoint_sharded(
                 metadata, step, self._template_fn(), float(timeout),
-                parallel=max(2, self._num_chunks),
+                parallel=self.fetch_workers,
                 metrics=self._metrics,
                 wire_dtype=self._heal_wire_dtype,
                 stripe_bytes=self._stripe_bytes,
@@ -1079,8 +1097,10 @@ class CheckpointServer(CheckpointTransport[T]):
             with urllib.request.urlopen(url, timeout=timeout) as resp:
                 out = pytree_from_stream(resp)
         if self._metrics is not None:
+            # the fetch alone; ``heal_wall_ms`` is the Manager's, from
+            # the heal's assignment to the state applied
             self._metrics.gauge(
-                "heal_wall_ms", (time.perf_counter() - t0) * 1000.0
+                "heal_fetch_ms", (time.perf_counter() - t0) * 1000.0
             )
         return out
 
@@ -1235,10 +1255,39 @@ def fetch_manifest(metadata: str, step: int, timeout: float = 60.0,
         return pickle.load(resp)
 
 
+def _wire_seconds(metrics) -> "Tuple[float, float, float]":
+    """Seconds the sink's fetch workers have spent, summed over every
+    fetch so far: before a response's headers came (the donor's gate,
+    staging and handler), reading bodies, and checking them. Counters,
+    because a heal makes more fetches than a timing's window keeps."""
+    return (metrics.count("heal_wire_wait_s"),
+            metrics.count("heal_wire_read_s"),
+            metrics.count("heal_wire_crc_s"))
+
+
+def _gauge_fetch(metrics, t0: float, wire0: "Tuple[float, float, float]",
+                 nbytes: int, leaves: int) -> None:
+    """What a finished fetch that began at ``t0`` leaves in the sink:
+    its wire bytes, leaves and whole-fetch bandwidth, and of its
+    workers' in-flight seconds since ``wire0`` the share they waited on
+    the donor."""
+    wall = time.perf_counter() - t0
+    metrics.gauge("heal_bytes", nbytes)
+    metrics.gauge("heal_leaves", leaves)
+    if nbytes and wall > 0:
+        metrics.gauge("heal_bytes_per_s", nbytes / wall)
+    wait, read, crc = (
+        b - a for a, b in zip(wire0, _wire_seconds(metrics))
+    )
+    if wait + read + crc > 0:
+        metrics.gauge("heal_donor_wait_share", wait / (wait + read + crc))
+
+
 def _read_wire_tensor(resp, dtype: np.dtype, shape: tuple,
                       wire_np: np.dtype, what: str,
                       out: "Optional[np.ndarray]" = None,
-                      check_crc: bool = False) -> np.ndarray:
+                      check_crc: bool = False,
+                      metrics: "Optional[Any]" = None) -> np.ndarray:
     """Land one tensor body from ``resp``: readinto a preallocated (or
     fresh) array in the staged dtype, via a wire-dtype temporary + upcast
     when the opt-in lossy encoding is active. The single implementation
@@ -1249,23 +1298,29 @@ def _read_wire_tensor(resp, dtype: np.dtype, shape: tuple,
     bytes before they are trusted — a mismatch raises
     :class:`ChecksumError` (a ConnectionError: every failover site
     already retries it from a peer) and increments the receiver-side
-    frame counters."""
+    frame counters. ``metrics``: the heal's sink, for the spans
+    ``heal_wire_read`` and ``heal_wire_crc`` and their summed seconds."""
     if wire_np == dtype:
         wire_arr = out if out is not None else np.empty(shape, dtype)
-        readinto_exact(resp, as_bytes_view(wire_arr), what=what)
         result = wire_arr
     else:
         wire_arr = np.empty(shape, wire_np)
-        readinto_exact(resp, as_bytes_view(wire_arr), what=what)
         result = None  # upcast AFTER the frame check: corrupt bytes
         # must never be written into a caller's buffer
+    with span(metrics, "heal_wire_read") as reading:
+        readinto_exact(resp, as_bytes_view(wire_arr), what=what)
+    if metrics is not None:
+        metrics.incr("heal_wire_read_s", reading.elapsed)
     if check_crc:
         trailer = bytearray(4)
         readinto_exact(
             resp, memoryview(trailer), what=f"{what} crc frame"
         )
         want = struct.unpack("<I", trailer)[0]
-        got = crc32c(as_bytes_view(wire_arr))
+        with span(metrics, "heal_wire_crc") as checking:
+            got = crc32c(as_bytes_view(wire_arr))
+        if metrics is not None:
+            metrics.incr("heal_wire_crc_s", checking.elapsed)
         _count_crc(got == want)
         if got != want:
             raise ChecksumError(
@@ -1306,6 +1361,7 @@ def fetch_leaf(
     wire_dtype: "Optional[str]" = None,
     conn: "Optional[_DonorConn]" = None,
     crc: "Optional[bool]" = None,
+    metrics: "Optional[Any]" = None,
 ) -> Any:
     """Fetch one leaf (optionally a server-sliced shard of it) by index.
 
@@ -1319,16 +1375,23 @@ def fetch_leaf(
     keep-alive donor connection (callers doing many fetches).
     ``crc``: request + verify the CRC32C integrity frame (default: the
     process-wide ``TORCHFT_TPU_WIRE_CRC`` policy; objects are exempt —
-    the frame covers raw tensor bytes)."""
+    the frame covers raw tensor bytes). ``metrics``: the heal's sink —
+    the fetch then tiles into spans ``heal_wire_wait`` (request →
+    response headers: the donor's gate, staging and handler),
+    ``heal_wire_read`` and ``heal_wire_crc``, each also summed in
+    seconds under ``<span>_s``."""
     if crc is None:
         crc = _WIRE_CRC
     own_conn = conn is None
     if own_conn:
         conn = _DonorConn(metadata, timeout)
     try:
-        resp = conn.get(
-            _leaf_path(step, index, slices, wire_dtype, crc=crc)
-        )
+        with span(metrics, "heal_wire_wait") as waiting:
+            resp = conn.get(
+                _leaf_path(step, index, slices, wire_dtype, crc=crc)
+            )
+        if metrics is not None:
+            metrics.incr("heal_wire_wait_s", waiting.elapsed)
         kind = resp.headers.get("X-Kind", "ndarray")
         clen_hdr = resp.headers.get("Content-Length")
         if clen_hdr is None:
@@ -1373,7 +1436,7 @@ def fetch_leaf(
                 )
         return _read_wire_tensor(
             resp, dtype, shape, wire_dt, f"leaf {index} body", out=out,
-            check_crc=crc,
+            check_crc=crc, metrics=metrics,
         )
     finally:
         if own_conn:
@@ -1549,6 +1612,7 @@ def recv_checkpoint_sharded(
     import jax
 
     t0 = time.perf_counter()
+    wire0 = _wire_seconds(metrics) if metrics is not None else None
     manifest = fetch_manifest(metadata, step, timeout=timeout)
     entries = manifest["leaves"]
     t_flat, t_def = jax.tree_util.tree_flatten_with_path(template)
@@ -1672,9 +1736,24 @@ def recv_checkpoint_sharded(
         ConnectionError, socket.timeout, TimeoutError, OSError,
     )
 
+    wire_np = _WIRE_DTYPES[wire_dtype]() if wire_dtype is not None else None
+
+    def _planned_nbytes(i, fetch_bounds) -> int:
+        # the wire bytes the plan expects of this fetch (0: an object)
+        entry = entries[i]
+        if entry["kind"] != "ndarray":
+            return 0
+        whole = _entry_wire_nbytes(entry, wire_np)
+        if fetch_bounds is None:
+            return whole
+        count = int(np.prod(entry["shape"], dtype=np.int64))
+        part = int(np.prod([b - a for a, b in fetch_bounds], dtype=np.int64))
+        return whole // count * part if count else 0
+
     def _fetch_once(host, i, fetch_bounds, out):
         nb = [0]
-        with throughput_span(metrics, "heal_wire", nb):
+        with throughput_span(metrics, "heal_wire", nb, leaf=i, host=host,
+                             bytes=_planned_nbytes(i, fetch_bounds)):
             conn = conn_pool.acquire(host)
             try:
                 got = fetch_leaf(
@@ -1684,7 +1763,7 @@ def recv_checkpoint_sharded(
                         if fetch_bounds is not None else None
                     ),
                     timeout=timeout, out=out, wire_dtype=wire_dtype,
-                    conn=conn,
+                    conn=conn, metrics=metrics,
                 )
             except BaseException:
                 conn.close()  # possibly mid-body: stale, not reusable
@@ -1868,8 +1947,9 @@ def recv_checkpoint_sharded(
                         group.add(fetch_pool.submit(_piece_fetch))
 
             def _assemble(tleaf=tleaf, shape=shape,
-                          region_bufs=region_bufs):
-                with span(metrics, "heal_h2d"):
+                          region_bufs=region_bufs, i=i):
+                with span(metrics, "heal_h2d", leaf=i, bytes=sum(
+                        int(b.nbytes) for b in region_bufs.values())):
                     shards = {
                         b: np.asarray(a) for b, a in region_bufs.items()
                     }
@@ -1909,11 +1989,7 @@ def recv_checkpoint_sharded(
         conn_pool.close_all()
 
     if metrics is not None:
-        # heal_wall_ms is gauged by the callers that own the full span
-        # (CheckpointServer.recv_checkpoint / Manager at apply time)
-        wall = time.perf_counter() - t0
-        if total_bytes[0] and wall > 0:
-            metrics.gauge("heal_bytes_per_s", total_bytes[0] / wall)
+        _gauge_fetch(metrics, t0, wire0, total_bytes[0], len(leaves))
     return jax.tree_util.tree_unflatten(t_def, leaves)
 
 
@@ -2032,11 +2108,12 @@ def fetch_opt_shard(
         donor = donors[holder]
         by_slot = coverage[donor][leaf]
         nb = [0]
-        with throughput_span(metrics, "heal_wire", nb):
+        with throughput_span(metrics, "heal_wire", nb, leaf=leaf,
+                             host=donor, bytes=leaf_bytes[leaf]):
             arrays = _pool_fetch_leaves(
                 conn_pool, donor, step,
                 [by_slot[slot] for slot in range(state_slots)],
-                timeout, what=f"opt-shard leaf {leaf}",
+                timeout, what=f"opt-shard leaf {leaf}", metrics=metrics,
             )
             nb[0] = sum(int(a.nbytes) for a in arrays)
         return arrays
@@ -2081,6 +2158,7 @@ _REDIST_PATH_RE = r".*\['units'\]\['(\d+)'\]\[(\d+)\]$"
 def _pool_fetch_leaves(
     pool: _ConnPool, host: str, step: int, indices: "Sequence[int]",
     timeout: float, what: str = "unit",
+    metrics: "Optional[Any]" = None,
 ) -> "List[np.ndarray]":
     """THE keep-alive manifest-indexed fetch: acquire a pooled donor
     connection, fetch each leaf index in order, release only after the
@@ -2099,6 +2177,7 @@ def _pool_fetch_leaves(
             arrays = [
                 np.asarray(fetch_leaf(
                     host, step, int(mi), timeout=timeout, conn=conn,
+                    metrics=metrics,
                 ))
                 for mi in indices
             ]
@@ -2317,6 +2396,7 @@ def _recv_chunked(
     import jax
 
     t0 = time.perf_counter()
+    wire0 = _wire_seconds(metrics) if metrics is not None else None
     conn_pool = _ConnPool(timeout)
 
     first_conn = conn_pool.acquire(metadata)
@@ -2341,10 +2421,8 @@ def _recv_chunked(
         i for i, e in enumerate(entries) if e["kind"] != "ndarray"
     ]
     ranges: List[tuple] = []
+    wire_np = _WIRE_DTYPES[wire_dtype]() if wire_dtype is not None else None
     if tensor_idx:
-        wire_np = (
-            _WIRE_DTYPES[wire_dtype]() if wire_dtype is not None else None
-        )
         budget = sum(
             _entry_wire_nbytes(entries[i], wire_np) for i in tensor_idx
         ) / float(num_chunks)
@@ -2369,7 +2447,12 @@ def _recv_chunked(
     def _fetch_range(r: tuple) -> None:
         lo, hi = r
         nb = [0]
-        with throughput_span(metrics, "heal_wire", nb):
+        with throughput_span(
+            metrics, "heal_wire", nb, leaf=f"{lo}-{hi}", host=metadata,
+            bytes=sum(_entry_wire_nbytes(e, wire_np)
+                      for e in entries[lo:hi])
+            + (4 * (hi - lo) if _WIRE_CRC else 0),  # the frames ride it
+        ):
             _fetch_range_inner(lo, hi, nb)
 
     def _fetch_range_inner(lo: int, hi: int, nb: list) -> None:
@@ -2384,30 +2467,33 @@ def _recv_chunked(
             path += "?" + "&".join(params)
         conn = conn_pool.acquire(metadata)
         try:
-            resp = conn.get(path)
+            with span(metrics, "heal_wire_wait") as waiting:
+                resp = conn.get(path)
+            if metrics is not None:
+                metrics.incr("heal_wire_wait_s", waiting.elapsed)
             clen = int(resp.headers["Content-Length"])
             got = 0
             for i in range(lo, hi):
                 entry = entries[i]
                 dtype = _dtype_from_str(entry["dtype"])
                 shape = tuple(entry["shape"])
-                wire_np = (
-                    _WIRE_DTYPES[wire_dtype]()
-                    if wire_dtype is not None
+                leaf_np = (
+                    wire_np
+                    if wire_np is not None
                     and dtype in _WIRE_COMPRESSIBLE
                     else dtype
                 )
                 outs[i] = _read_wire_tensor(
-                    resp, dtype, shape, wire_np, f"leaf {i} body",
-                    check_crc=use_crc,
+                    resp, dtype, shape, leaf_np, f"leaf {i} body",
+                    check_crc=use_crc, metrics=metrics,
                 )
                 # count WIRE bytes (the downcast payload under the
                 # opt-in lossy encoding, not the upcast copy; the
                 # 4-byte CRC frame rides the body for length
                 # accounting but is not payload)
-                wire_nb = _entry_wire_nbytes(entry, (
-                    wire_np if wire_np != dtype else None
-                )) + (4 if use_crc else 0)
+                wire_nb = _entry_wire_nbytes(entry, wire_np) + (
+                    4 if use_crc else 0
+                )
                 got += wire_nb
                 with total_lock:
                     total[0] += wire_nb
@@ -2430,7 +2516,8 @@ def _recv_chunked(
         conn = conn_pool.acquire(metadata)
         try:
             outs[i] = fetch_leaf(
-                metadata, step, i, timeout=timeout, conn=conn
+                metadata, step, i, timeout=timeout, conn=conn,
+                metrics=metrics,
             )
         except BaseException:
             conn.close()
@@ -2448,7 +2535,5 @@ def _recv_chunked(
         # pins a blocked donor handler thread until the socket collects
         conn_pool.close_all()
     if metrics is not None:
-        wall = time.perf_counter() - t0
-        if total[0] and wall > 0:
-            metrics.gauge("heal_bytes_per_s", total[0] / wall)
+        _gauge_fetch(metrics, t0, wire0, total[0], n)
     return jax.tree_util.tree_unflatten(manifest["treedef"], outs)

@@ -117,12 +117,18 @@ class _Interval:
     every commit; an interval becomes an *episode* only if it turns
     ``dirty`` — a step was discarded, an error latched, the wire
     membership changed or this replica healed. A steady step pays the
-    reset (a handful of float stores) and one clock read."""
+    reset (a handful of float stores) and one clock read.
+
+    ``failed_wire`` is the part of ``wire_wait`` spent inside steps
+    that were then discarded (``wire_mark``: ``wire_wait`` as the
+    current step began) — after a peer's death, the wait on its sockets
+    apart from the first narrower step's wire."""
 
     __slots__ = (
         "t0", "first", "dirty", "members", "quorum_wait", "configure",
-        "wire_wait", "heal", "barrier", "discards", "errors", "init",
-        "heal_from", "healed_at", "stalled_at_heal",
+        "wire_wait", "wire_mark", "failed_wire", "heal", "barrier",
+        "discards", "errors", "init", "heal_from", "healed_at",
+        "stalled_at_heal",
     )
 
     def __init__(self, t0: float) -> None:
@@ -136,6 +142,7 @@ class _Interval:
         self.members = members
         self.dirty = False
         self.quorum_wait = self.configure = self.wire_wait = 0.0
+        self.wire_mark = self.failed_wire = 0.0
         self.heal = self.barrier = 0.0
         self.discards = self.errors = 0
         self.heal_from: Optional[float] = None
@@ -451,8 +458,9 @@ class Manager:
         if callable(set_metrics):
             set_metrics(self.metrics)
         # Same deal for the heal plane: its stage/wire/H2D spans
-        # (heal_stage / heal_wire / heal_h2d) and the heal_bytes_per_s /
-        # heal_wall_ms gauges land in this sink too.
+        # (heal_stage / heal_gate / heal_serve as a donor, heal_wire /
+        # heal_h2d as a joiner) and its heal_fetch_ms / heal_bytes_per_s
+        # gauges land in this sink too.
         ckpt_set_metrics = getattr(
             self._checkpoint_transport, "set_metrics", None
         )
@@ -497,6 +505,8 @@ class Manager:
         # wall-clock anchor for the CURRENT heal: set when the quorum
         # assigns us a heal, cleared when the healed state is applied
         self._heal_t0: Optional[float] = None
+        # ...and when its fetch returned, on the quorum thread
+        self._heal_fetched: Optional[float] = None
 
         # --- steady-state fast path (epoch lease + data-plane votes) ------
         # While a lease is live (granted by the last full quorum, renewed
@@ -554,12 +564,17 @@ class Manager:
             self._lease_live = False
         # a span: on a shared host a teardown that blocks delays whatever
         # is relaunched behind it, and nothing else on the timeline says so
-        with span(self.metrics, "shutdown", step=self._step):
-            self._checkpoint_transport.shutdown(wait=wait)
-            if self._manager is not None:
-                self._manager.shutdown()
-            self._executor.shutdown(wait=wait)
-            self._comm.shutdown()
+        step = self._step
+        with span(self.metrics, "shutdown", step=step):
+            with span(self.metrics, "shutdown_checkpoint", step=step):
+                self._checkpoint_transport.shutdown(wait=wait)
+            with span(self.metrics, "shutdown_server", step=step):
+                if self._manager is not None:
+                    self._manager.shutdown()
+            with span(self.metrics, "shutdown_executor", step=step):
+                self._executor.shutdown(wait=wait)
+            with span(self.metrics, "shutdown_comm", step=step):
+                self._comm.shutdown()
 
     # ------------------------------------------------------------ collectives
 
@@ -995,6 +1010,9 @@ class Manager:
         self._fastpath_active = False
         self._control_rpcs = 0
         self.metrics.gauge("control_rpcs_per_step", 0.0)
+        # what this step waits on the wire from here is ``failed_wire``
+        # if the step is discarded
+        self._interval.wire_mark = self._interval.wire_wait
         if self._lease_enabled and not shrink_only:
             latched = (
                 self.errored() is not None
@@ -1358,6 +1376,7 @@ class Manager:
                 try:
                     self._healing = True
                     self._heal_t0 = time.perf_counter()
+                    self._heal_fetched = None
                     self._interval.heal_from = self._heal_t0
                     if self.events:
                         self.events.emit(
@@ -1371,13 +1390,16 @@ class Manager:
                         f"from {quorum.recover_src_manager_address} "
                         f"max_step={quorum.max_step}"
                     )
-                    src_client = ManagerClient(
-                        quorum.recover_src_manager_address,
-                        connect_timeout=self._connect_timeout,
-                    )
-                    metadata = src_client.checkpoint_metadata(
-                        self._rank, timeout=self._timeout
-                    )
+                    with span(self.metrics, "heal_meta",
+                              step=quorum.max_step,
+                              src=quorum.recover_src_rank):
+                        src_client = ManagerClient(
+                            quorum.recover_src_manager_address,
+                            connect_timeout=self._connect_timeout,
+                        )
+                        metadata = src_client.checkpoint_metadata(
+                            self._rank, timeout=self._timeout
+                        )
                     assert quorum.recover_src_rank is not None, (
                         "must have a recover rank when healing"
                     )
@@ -1388,14 +1410,21 @@ class Manager:
                     # The user state dict is applied later from the main
                     # thread (should_commit) — only torchft state is loaded
                     # here (ref manager.py:512-526).
-                    self._pending_state_dict = (
-                        self._checkpoint_transport.recv_checkpoint(
-                            src_rank=quorum.recover_src_rank,
-                            metadata=metadata,
-                            step=quorum.max_step,
-                            timeout=self._timeout,
+                    with span(
+                        self.metrics, "heal_fetch", step=quorum.max_step,
+                        workers=getattr(
+                            self._checkpoint_transport, "fetch_workers", 1
+                        ),
+                    ):
+                        self._pending_state_dict = (
+                            self._checkpoint_transport.recv_checkpoint(
+                                src_rank=quorum.recover_src_rank,
+                                metadata=metadata,
+                                step=quorum.max_step,
+                                timeout=self._timeout,
+                            )
                         )
-                    )
+                    self._heal_fetched = time.perf_counter()
                     self.load_state_dict(self._pending_state_dict["torchft"])
                     self._step = quorum.max_step
                 except Exception as e:  # noqa: BLE001
@@ -1436,14 +1465,24 @@ class Manager:
         assert self._load_state_dict is not None, (
             "user load_state_dict is not initialized"
         )
-        self._load_state_dict(self._pending_state_dict["user"])
+        if self._heal_fetched is not None:
+            # the bytes were there; the state waited for this thread,
+            # which ran its own step (and, under DDP, a zero-contribution
+            # allreduce) before it came here
+            self.metrics.gauge(
+                "heal_apply_wait_ms",
+                (time.perf_counter() - self._heal_fetched) * 1000.0,
+            )
+        with span(self.metrics, "heal_apply", step=self._step):
+            self._load_state_dict(self._pending_state_dict["user"])
         self._pending_state_dict = None
         self._did_heal = True
         wall_ms = None
         if self._heal_t0 is not None:
-            # heal assignment → healed-state ready, end to end: quorum
-            # answer, donor fetch (stage/wire/H2D spans are inside), and
-            # the user load_state_dict that just ran
+            # heal assignment → healed-state ready, end to end. Tiled by
+            # heal_meta (donor's address), heal_fetch_ms (the transport's
+            # fetch; stage/wire/H2D spans are inside), heal_apply_wait_ms
+            # and heal_apply (the user load_state_dict that just ran)
             wall_ms = (time.perf_counter() - self._heal_t0) * 1000.0
             self.metrics.gauge("heal_wall_ms", wall_ms)
             self._heal_t0 = None
@@ -1621,8 +1660,10 @@ class Manager:
             if should_commit:
                 self._close_interval()
             else:
-                self._interval.discards += 1
-                self._interval.dirty = True
+                iv = self._interval
+                iv.discards += 1
+                iv.dirty = True
+                iv.failed_wire += iv.wire_wait - iv.wire_mark
 
             self._checkpoint_transport.disallow_checkpoint()
 
@@ -1678,8 +1719,10 @@ class Manager:
                     iv.stalled() - iv.stalled_at_heal
                 )
             phases["other"] = gap - sum(phases.values())
-            # configure is reported inside quorum_wait, not beside it
-            timed = {"gap": gap, "configure": iv.configure, **phases}
+            # configure is reported inside quorum_wait, failed_wire
+            # inside wire_wait, not beside them
+            timed = {"gap": gap, "configure": iv.configure,
+                     "failed_wire": iv.failed_wire, **phases}
             for kind in kinds:
                 for name, seconds in timed.items():
                     self.metrics.observe(f"episode_{kind}_{name}", seconds)
@@ -1689,6 +1732,7 @@ class Manager:
                     epoch=self._quorum_epoch, episode="+".join(kinds),
                     t_open=iv.t0, gap_ms=round(gap * 1e3, 3),
                     configure_ms=round(iv.configure * 1e3, 3),
+                    failed_wire_ms=round(iv.failed_wire * 1e3, 3),
                     discards=iv.discards, errors=iv.errors,
                     members_before=len(before), members_after=len(after),
                     left=len(before - after), joined=len(after - before),

@@ -54,6 +54,13 @@ class Metrics:
         with self._lock:
             self._counters[name] += value
 
+    def count(self, name: str) -> float:
+        """One counter's running total (0.0 before its first ``incr``):
+        what a caller reads before and after a piece of work to take
+        the work's own share of a cumulative counter."""
+        with self._lock:
+            return self._counters.get(name, 0.0)
+
     def gauge(self, name: str, value: float) -> None:
         """Set an absolute last-write-wins value (e.g. the most recent
         heal's ``heal_wall_ms`` / ``heal_bytes_per_s``). Gauges land in
