@@ -490,6 +490,11 @@ CHUNK_S = 96        # tiles of 3 keys, 6 q blocks of 16 rows: block 0 ends in
 #                     and a row's live tiles leave a lone one behind the pairs
 
 
+def _off(a, b):
+    a, b = (np.asarray(z, np.float32) for z in (a, b))
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
 @functools.lru_cache(maxsize=None)
 def _chunk_inputs():
     """Four query heads on two key heads (of the small model's sixteen on
@@ -546,19 +551,15 @@ def test_the_forward_sweeps_a_chunk_of_k_tiles_a_grid_step(n) -> None:
     assert traced == n and grid == (2 * 4, CHUNK_S // 16, dsa.WORD // n)
     assert one_grid[2] == dsa.WORD
 
-    def off(a, b):
-        a, b = (np.asarray(z, np.float32) for z in (a, b))
-        return np.linalg.norm(a - b) / np.linalg.norm(b)
-
     for name, tol in (("lse", 1e-5), ("o", 1e-2), ("dq", 1e-2), ("dk", 1e-2),
                       ("dv", 1e-2)):
-        assert off(got[name], want[name]) <= tol, name
+        assert _off(got[name], want[name]) <= tol, name
     quiet = slice(40, 60)
-    assert off(got["o"][:, :, quiet], want["o"][:, :, quiet]) <= 1e-2
-    assert off(got["lse"][:, :, quiet], want["lse"][:, :, quiet]) <= 1e-5
-    assert off(got["lse"], one["lse"]) <= 2e-7
+    assert _off(got["o"][:, :, quiet], want["o"][:, :, quiet]) <= 1e-2
+    assert _off(got["lse"][:, :, quiet], want["lse"][:, :, quiet]) <= 1e-5
+    assert _off(got["lse"], one["lse"]) <= 2e-7
     for name in ("o", "dq", "dk", "dv"):
-        assert off(got[name], one[name]) <= 4e-3, name
+        assert _off(got[name], one[name]) <= 4e-3, name
 
 
 @pytest.mark.parametrize("shape, want", [
@@ -637,6 +638,318 @@ def test_the_gauge_says_what_the_cells_call_took() -> None:
     assert TRACED.snapshot()["dsa_fwd_chunk_tiles"] == 1
 
 
+# -- ``dsa_dq``, ``dsa_dkv``, ``dsa_kl`` more than a tile a grid step ---------
+
+SWEPT_ROWS = 8      # 12 q blocks of 8 rows over ``CHUNK_S``'s tiles of 3 keys:
+#                     block 0 ends in tile 2, block 1 in tile 5, block 3 in
+#                     tile 10 — a row's last chunk of 2, 4 or 8 tiles is
+#                     partly above the diagonal, its live tiles leave a
+#                     lone one behind the groups of four and the pairs, and
+#                     a k tile's first chunk of 4 q blocks starts inside it
+
+
+def _a_rounding_apart(a, b):
+    """bf16 leaves equal or one rounding apart (the same f32 sums met in
+    another order round to neighbours at most; where terms cancel, to
+    within f32's rounding of the leaf's largest value)."""
+    a, b = (np.asarray(z, np.float32) for z in (a, b))
+    gap = np.abs(a - b)
+    ulp = 2.0 ** -7 * np.maximum(np.abs(a), np.abs(b))
+    return bool(np.all(gap <= np.maximum(ulp, 1e-6 * np.abs(b).max())))
+
+
+def _with_rule(name, answer, run, *args):
+    """``run`` traced and run with ``dsa.<name>`` answering ``answer`` —
+    the seam ``_chunked`` uses —: its ``pallas_call``s, the gauges its
+    trace left and its results."""
+    rule = getattr(dsa, name)
+    setattr(dsa, name, lambda *shape: answer)
+    try:
+        calls = one_program.pallas_calls(run, *args)
+        traced = TRACED.snapshot()
+        out = jax.device_get(jax.jit(run)(*args))
+    finally:
+        setattr(dsa, name, rule)
+    return calls, traced, out
+
+
+@functools.lru_cache(maxsize=None)
+def _swept_backward(tiles):
+    """The gradient through ``attend`` in the interpreter at ``tiles``
+    (``dsa_dq``'s k tiles, ``dsa_dkv``'s k tiles and q blocks a grid
+    step), ``SWEPT_ROWS`` rows a q block: ``dq``, ``dk``, ``dv`` as ONE
+    jitted program, the two kernels' grids and gauges."""
+    q, k, v, do, sel = _chunk_inputs()
+
+    def run(q, k, v, do):
+        (_, lse), pull = jax.vjp(lambda q, k, v: dsa.attend(
+            q, k, v, sel, block_q=SWEPT_ROWS, interpret=True), q, k, v)
+        return pull((do, jnp.zeros_like(lse)))
+
+    calls, traced, out = _with_rule("_choose_backward", tiles, run, q, k, v,
+                                    do)
+    return (dict(zip(("dq", "dk", "dv"), out)),
+            {name: calls[name].params["grid_mapping"].grid
+             for name in ("dsa_dq", "dsa_dkv")},
+            {name: traced[f"{name}_chunk_tiles"]
+             for name in ("dsa_dq", "dsa_dkv")})
+
+
+@functools.lru_cache(maxsize=None)
+def _swept_kl(straight):
+    """``index_kl``'s value and three gradients at ``straight`` heads a
+    straight-line group in the interpreter (``None``: the ``jnp`` form) on
+    ``_chunk_inputs``' four heads and sets — ``lse`` the ``jnp``
+    attention's, ``lse_i`` the sets' own —, and ``dsa_kl``'s gauge."""
+    q, k, _, _, sel = _chunk_inputs()
+    x = family.kernel_inputs(CFG, 7, 2, CHUNK_S)
+    lse = jnp.asarray(_chunked(None)[0]["lse"])
+    lse_i = jax.nn.logsumexp(jnp.where(dsa.unpack(sel), dsa.index_scores(
+        x["qi"], x["ki"], x["w"]), -jnp.inf), axis=-1)
+    kw = {} if straight is None else dict(block_q=16, interpret=True)
+
+    def run(qi, ki, w):
+        kl, grads = jax.value_and_grad(lambda qi, ki, w: dsa.index_kl(
+            q, k, lse, qi, ki, w, sel, lse_i, **kw), argnums=(0, 1, 2))(
+                qi, ki, w)
+        return (kl,) + grads
+
+    _, traced, out = _with_rule("_choose_kl", straight, run, x["qi"],
+                                x["ki"], x["w"])
+    return (dict(zip(("kl", "dqi", "dki", "dw"), out)),
+            traced.get("dsa_kl_group_heads"))
+
+
+SWEPT = [(2, 2, 1), (8, 1, 4), (4, 2, 4)]
+KL_HEADS = 16       # heads a straight-line group of ``dsa_kl`` at the cell
+
+
+@pytest.mark.parametrize("tiles", SWEPT)
+def test_dq_sweeps_a_chunk_of_k_tiles_a_grid_step(tiles) -> None:
+    """At ``n`` k tiles a step ``dsa_dq``'s k axis is ``32 / n`` steps, a
+    chunk that crosses the diagonal computes its live tiles only, and
+    ``dq`` is the one-tile body's (bf16: equal or one rounding apart — two
+    tiles a matmul are the same f32 sums met in another order) and the
+    ``jnp`` form's under this file's limit, the rows with no chosen key in
+    their first tile among them."""
+    n = tiles[0]
+    got, grids, traced = _swept_backward(tiles)
+    one, one_grids, one_traced = _swept_backward((1, 1, 1))
+    want, _, _ = _chunked(None)
+    blocks = CHUNK_S // SWEPT_ROWS
+    assert traced["dsa_dq"] == n and one_traced["dsa_dq"] == 1
+    assert grids["dsa_dq"] == (2 * 4, blocks, dsa.WORD // n)
+    assert one_grids["dsa_dq"] == (2 * 4, blocks, dsa.WORD)
+    assert _a_rounding_apart(got["dq"], one["dq"])
+    assert _off(got["dq"], one["dq"]) <= 1e-4
+    assert _off(got["dq"], want["dq"]) <= 1e-2
+    quiet = slice(40, 60)
+    assert _off(got["dq"][:, :, quiet], want["dq"][:, :, quiet]) <= 1e-2
+
+
+@pytest.mark.parametrize("tiles", SWEPT)
+def test_dkv_takes_k_tiles_against_a_chunk_of_q_blocks_a_grid_step(
+        tiles) -> None:
+    """At ``t`` k tiles against ``c`` q blocks a step ``dsa_dkv``'s grid is
+    ``(B * KV, 32 / t, blocks / c, group)``, a column's first chunk starts
+    inside it, and ``dk``, ``dv`` are the one-tile body's (equal or one
+    rounding apart) and the ``jnp`` form's under this file's limit."""
+    _, t, c = tiles
+    got, grids, traced = _swept_backward(tiles)
+    one, one_grids, one_traced = _swept_backward((1, 1, 1))
+    want, _, _ = _chunked(None)
+    blocks = CHUNK_S // SWEPT_ROWS
+    assert traced["dsa_dkv"] == t * c and one_traced["dsa_dkv"] == 1
+    assert grids["dsa_dkv"] == (2 * 2, dsa.WORD // t, blocks // c, 2)
+    assert one_grids["dsa_dkv"] == (2 * 2, dsa.WORD, blocks, 2)
+    for name in ("dk", "dv"):
+        assert _a_rounding_apart(got[name], one[name]), name
+        assert _off(got[name], one[name]) <= 1e-4, name
+        assert _off(got[name], want[name]) <= 1e-2, name
+
+
+@pytest.mark.parametrize("straight", [2, 3, 4])
+def test_kl_meets_its_heads_in_straight_line_groups(straight) -> None:
+    """At ``g`` heads a straight-line group (four query heads and the
+    indexer's three: at 2 two groups and a group with a head left over, at
+    3 a head left over and one group, at 4 one group and the leftovers
+    alone) the value and ``dw`` (f32) are the one-head loop's to 1e-6,
+    ``dqi`` and ``dki`` equal or one rounding apart, and all four the
+    ``jnp`` form's under this file's limits."""
+    got, traced = _swept_kl(straight)
+    one, one_traced = _swept_kl(1)
+    want, _ = _swept_kl(None)
+    assert traced == straight and one_traced == 1
+    for name in ("kl", "dw"):
+        assert _off(got[name], one[name]) <= 1e-6, name
+    for name in ("dqi", "dki"):
+        assert _a_rounding_apart(got[name], one[name]), name
+    for name, tol in (("kl", 1e-5), ("dw", 1e-4), ("dqi", 1e-2),
+                      ("dki", 1e-2)):
+        assert _off(got[name], want[name]) <= tol, name
+
+
+CELL = (16384, 128, 128, 2, 512)     # keye2's call: seq, d, dv, itemsize, rows
+
+
+@pytest.mark.parametrize("shape, want", [
+    (CELL, (32, 2, 8)),                  # the whole row; 2 tiles x 8 q blocks
+    ((32768, 128, 128, 2, 512), (32, 2, 2)),
+    ((65536, 128, 128, 2, 512), (2, 2, 1)),
+    ((4096, 128, 128, 2, 512), (32, 2, 8)),
+    ((2048, 128, 128, 2, 512), (1, 1, 1)),   # tiles narrower than a lane tile
+    ((64, 16, 16, 2, 16), (1, 1, 1)),        # the CPU tests' sizes
+    ((CHUNK_S, 16, 16, 2, SWEPT_ROWS), (1, 1, 1)),
+])
+def test_the_backward_counts_are_a_pure_function_of_the_shape(
+        shape, want, monkeypatch) -> None:
+    """``dsa_dq``'s k tiles a step: the longest rung of the forward's
+    ladder whose estimate fits ``_PARAMS``' VMEM limit; ``dsa_dkv``'s
+    ``_COLUMN_TILES`` k tiles against the longest rung of
+    ``_COLUMN_LADDER`` q blocks that fits; all 1 where a tile is narrower
+    than a lane tile. Each estimate grows with its count, and under a
+    smaller limit each rule falls a rung."""
+    got = dsa._choose_backward(*shape)
+    assert got == want == dsa._choose_backward(*shape)
+    seq_len, d, dv, itemsize, block_q = shape
+    width = seq_len // dsa.WORD
+    ladder, column = dsa._CHUNK_LADDER, dsa._COLUMN_LADDER
+    assert list(column) == sorted(column, reverse=True) and column[-1] == 1
+
+    def row(n):
+        return dsa._backward_vmem_estimate(d, dv, itemsize, block_q, width, n)
+
+    def col(t, n):
+        return dsa._column_vmem_estimate(d, dv, itemsize, block_q, width, t,
+                                         n)
+
+    assert [row(n) for n in ladder] == sorted(map(row, ladder), reverse=True)
+    assert [col(2, n) for n in column] == sorted(
+        (col(2, n) for n in column), reverse=True)
+    assert col(2, 1) > col(1, 1)
+    limit = dsa._PARAMS.vmem_limit_bytes
+    n, t, c = got
+    if n > 1:
+        assert row(n) <= limit
+        assert all(row(m) > limit for m in ladder if m > n)
+        monkeypatch.setattr(dsa, "_PARAMS", pltpu.CompilerParams(
+            vmem_limit_bytes=row(n) - 1))
+        assert dsa._choose_backward(*shape)[0] == ladder[ladder.index(n) + 1]
+    if (t, c) != (1, 1):
+        assert t == dsa._COLUMN_TILES and col(t, c) <= limit
+        assert all(col(t, m) > limit for m in column if m > c)
+        monkeypatch.setattr(dsa, "_PARAMS", pltpu.CompilerParams(
+            vmem_limit_bytes=col(t, c) - 1))
+        fell = dsa._choose_backward(*shape)[1:]
+        assert fell == ((t, column[column.index(c) + 1]) if c > 1
+                        else (1, 1))
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((16384, 32, 4, 128, 16, 64, 2, 512), KL_HEADS),   # keye2's call
+    ((32768, 32, 4, 128, 16, 64, 2, 512), KL_HEADS),
+    ((4096, 32, 4, 128, 16, 64, 2, 512), KL_HEADS),
+    ((2048, 32, 4, 128, 16, 64, 2, 512), 1),  # narrower than a lane tile
+    ((64, 16, 2, 16, 3, 8, 2, 16), 1),        # the CPU tests' sizes
+])
+def test_the_kl_group_is_a_pure_function_of_the_shape(shape, want,
+                                                      monkeypatch) -> None:
+    """``dsa_kl``'s heads a straight-line group: the longest rung of
+    ``_KL_LADDER`` whose estimate fits; 1 (the loop of one head) where a
+    tile is narrower than a lane tile; a rung less under a smaller
+    limit."""
+    ladder = dsa._KL_LADDER
+    assert list(ladder) == sorted(ladder, reverse=True) and ladder[-1] == 1
+    got = dsa._choose_kl(*shape)
+    assert got == want == dsa._choose_kl(*shape)
+    seq_len, *widths, block_q = shape
+
+    def estimate(n):
+        return dsa._kl_vmem_estimate(*widths, block_q, seq_len // dsa.WORD, n)
+
+    assert [estimate(n) for n in ladder] == sorted(map(estimate, ladder),
+                                                   reverse=True)
+    if got > 1:
+        assert estimate(got) <= dsa._PARAMS.vmem_limit_bytes
+        monkeypatch.setattr(dsa, "_PARAMS", pltpu.CompilerParams(
+            vmem_limit_bytes=estimate(got) - 1))
+        assert dsa._choose_kl(*shape) == ladder[ladder.index(got) + 1]
+
+
+def test_the_gauges_say_what_the_cells_backward_and_kl_took() -> None:
+    """``TRACED``'s three gauges after a trace of the cell's gradient
+    through ``attend`` and of ``index_kl`` with its gradients (2 rows of
+    16 384, 32 | 4 heads of 128, an indexer of 16 x 64; nothing runs): the
+    rules' counts, more than one tile a step, the grids that many times
+    shorter, and bodies that do not grow with the counts — a straight-line
+    group is traced as a loop of ONE update (what a call site costs to
+    compile is ``setup_s``). At this file's sizes, which ask the rules,
+    every gauge reads 1."""
+    B, H, KV, L, D, HI, DI = 2, 32, 4, 16384, 128, 16, 64
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q, k = (jax.ShapeDtypeStruct((B, n, L, D), bf) for n in (H, KV))
+    sel = jax.ShapeDtypeStruct((B, L, L // 32), jnp.int32)
+    qi = jax.ShapeDtypeStruct((B, HI, L, DI), bf)
+    ki = jax.ShapeDtypeStruct((B, L, DI), bf)
+    w = jax.ShapeDtypeStruct((B, L, HI), f32)
+    lse = jax.ShapeDtypeStruct((B, H, L), f32)
+    lse_i = jax.ShapeDtypeStruct((B, L), f32)
+
+    def run(q, k, v, sel, lse, qi, ki, w, lse_i):
+        o, pull = jax.vjp(lambda q, k, v: dsa._attend(
+            q, k, v, sel, D ** -0.5, 512, False)[0], q, k, v)
+        return pull(o), jax.grad(lambda qi, ki, w: dsa.index_kl(
+            q, k, lse, qi, ki, w, sel, lse_i, block_q=512, interpret=False),
+            argnums=(0, 1, 2))(qi, ki, w)
+
+    use = dsa._use_kernels
+    dsa._use_kernels = lambda interpret: (True, False)   # traced, never run
+    try:
+        calls = one_program.pallas_calls(run, q, k, k, sel, lse, qi, ki, w,
+                                         lse_i)
+    finally:
+        dsa._use_kernels = use
+    traced = TRACED.snapshot()
+    n, t, c = dsa._choose_backward(*CELL)
+    heads = dsa._choose_kl(L, H, KV, D, HI, DI, 2, 512)
+    assert (traced["dsa_dq_chunk_tiles"], traced["dsa_dkv_chunk_tiles"],
+            traced["dsa_kl_group_heads"]) == (n, t * c, heads)
+    assert min(n, t * c, heads) >= 2
+
+    def grid(name):
+        return calls[name].params["grid_mapping"].grid
+
+    assert grid("dsa_dq") == (B * H, L // 512, dsa.WORD // n)
+    assert grid("dsa_dkv") == (B * KV, dsa.WORD // t, L // 512 // c, H // KV)
+    assert grid("dsa_kl") == (B, L // 512, dsa.WORD)
+
+    def dots(jaxpr):
+        return sum((eqn.primitive.name == "dot_general") + sum(
+            dots(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    groups = len(dsa._STRAIGHT)
+    assert dots(calls["dsa_dq"].params["jaxpr"]) == 3 * groups
+    assert dots(calls["dsa_dkv"].params["jaxpr"]) == 4 * groups
+    assert dots(calls["dsa_kl"].params["jaxpr"]) == 1 + 1 + 3
+    x = family.kernel_inputs(CFG, 1, 1, S)
+    small = jax.ShapeDtypeStruct((1, S, S // 32), jnp.int32)
+
+    def tiny(q, k, v, sel, qi, ki, w):
+        (o, lse), pull = jax.vjp(lambda q, k, v: dsa.attend(
+            q, k, v, sel, block_q=8, interpret=True), q, k, v)
+        return pull((o, lse)), jax.grad(lambda qi: dsa.index_kl(
+            q, k, lse, qi, ki, w, sel, lse[:, 0], block_q=8,
+            interpret=True))(qi)
+
+    jax.make_jaxpr(tiny)(x["q"], x["k"], x["v"], small, x["qi"], x["ki"],
+                         x["w"])
+    traced = TRACED.snapshot()
+    assert [traced[name] for name in (
+        "dsa_dq_chunk_tiles", "dsa_dkv_chunk_tiles",
+        "dsa_kl_group_heads")] == [1, 1, 1]
+
+
 # -- the kernels compile for the chip ----------------------------------------
 
 
@@ -679,7 +992,8 @@ def test_the_kernels_compile_for_a_described_v5e(one_chip) -> None:
                 lambda a, b, c: dsa._attend(a, b, c, d, D ** -0.5, 512,
                                             False)[0], a, b, c)[1](a),
              (q, k, k, sel)),
-            (lambda *a: dsa._kl_call(*a, D ** -0.5, 512, True, False),
+            (lambda *a: dsa._kl_call(*a, D ** -0.5, 512, True, dsa._choose_kl(
+                L, H, KV, D, HI, DI, 2, 512), False),
              (q, k, lse, qi, ki, w, sel, lse_i)),
         ):
             text = jax.jit(fn).lower(*args).compile().as_text()

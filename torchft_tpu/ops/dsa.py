@@ -39,21 +39,37 @@ columns, its transposed recompute in dkv, its group-wide dk / dv
 accumulators) whose mask is the packed set and nothing else — a key after
 the query is in no set, so no iota: every causal TILE is computed (the
 work of full causal attention) and a key outside ``S_t`` gives nothing to
-``o``, ``dq``, ``dk``, ``dv``. In ``dsa_dq`` and ``dsa_dkv`` a grid step
-is one tile, and tiles above the diagonal are grid steps that compute and
-fetch nothing. ``dsa_fwd`` takes a CHUNK of consecutive k tiles a grid
-step (``_choose_chunk``: a pure function of the shape, the longest rung of
-``_CHUNK_LADDER`` that fits VMEM — all 32, the whole row of keys, at the
-cell's 16 384 —; ``TRACED["dsa_fwd_chunk_tiles"]`` says what a trace took)
-and sweeps the chunk's live tiles in ascending k in straight-line groups,
-as ``flash._flash_streamed_kernel`` does, the softmax statistics updated
-once a PAIR of tiles (f32, as every sum of the kernel; ``lse`` is the
-value the three other kernels read): 83.6 -> 35.4 ms a call there (PR 69).
+``o``, ``dq``, ``dk``, ``dv``. A grid step of each holds MORE THAN ONE
+tile, and meets what it holds in straight-line code (``_straight``: as
+``flash._flash_streamed_kernel`` does — inside a group Mosaic schedules a
+tile's matmuls beside its neighbour's pointwise work, which neither a grid
+step nor a loop trip lets it do), the count a pure function of the shape
+(the longest rung of a ladder whose VMEM estimate fits ``_PARAMS``' limit;
+1, the one-tile body, where a tile is narrower than a lane tile: the sizes
+the interpreter runs) that a ``TRACED`` gauge reports. ``dsa_fwd`` and
+``dsa_dq`` (row sweeps) take a CHUNK of consecutive k tiles
+(``_choose_chunk``, ``_choose_backward``: all 32, the whole row of keys, at
+the cell's 16 384; ``TRACED["dsa_fwd_chunk_tiles"]``,
+``["dsa_dq_chunk_tiles"]``) and sweep the chunk's live tiles in ascending
+k, a PAIR of tiles a matmul under one concatenated mask; the forward
+updates its softmax statistics once a pair (f32, as every sum of the
+kernels; ``lse`` is the value the three other kernels read): 83.6 -> 35.4
+ms a call (PR 69); ``dsa_dq`` has no statistics to update and gains by the
+chunk alone: 56.9 -> 37.5 (PR 72). ``dsa_dkv`` (a column sweep) takes two k
+tiles, one matmul wide, against a chunk of consecutive q blocks
+(``["dsa_dkv_chunk_tiles"]``: their product, 2 x 8 there), the group's
+heads innermost: 74.9 -> 56.5. A tile above the diagonal that a step holds
+beside a live one is computed under its all-false mask (3 % of the tiles at
+two k tiles a step); steps that hold no live tile compute and fetch
+nothing.
 
 ``index_kl``: ``sum_t KL(pbar[t] || softmax_{S_t}(I[t]))`` with ``pbar[t,
 s] = mean_h P[t, h, s]`` (from ``q``, ``k``, ``lse``, all detached).
 ``dsa_kl`` recomputes every head's ``P`` tile and the indexer's scores
-tile by tile. DIFFERENTIATED it still runs once: the forward rule's call
+tile by tile, one tile a grid step, its 32 + 16 + 16 heads in LOOPS of
+straight-line groups (``_choose_kl``: 16 heads a group at the cell;
+``TRACED["dsa_kl_group_heads"]``): 65.7 -> 42.1 ms a call (PR 72; two k
+tiles a step gained nothing there). DIFFERENTIATED it still runs once: the forward rule's call
 carries ``dI = softmax - pbar`` on to ``dqi``, ``dw`` (accumulated over a
 row's tiles) and ``dki`` (one partial a query block, summed outside) in
 the sweep that makes the value — every operand exists where the loss is
@@ -390,23 +406,28 @@ def _first_q(ki, block_q: int, width: int):
     return (ki * width) // block_q
 
 
-# K tiles a grid step of ``dsa_fwd`` (a tile is a bit of the packed word:
-# ``S / 32`` keys), the longest first, every rung a divisor of the 32; the
-# straight-line groups a chunk's live tiles are met in (``ops/flash.py``'s,
-# PR 60: inside a group Mosaic schedules an update's matmuls beside its
-# neighbour's softmax, which neither a grid step nor a loop trip lets it
-# do); and the tiles ONE update of the softmax statistics takes (one
-# max-reduce, one alpha, one rescale of acc: 1 024 keys at 16k, that
-# file's streamed tile; a lone tile updates alone). On the v5e (PR 69,
-# ``scripts/dsa_micro.py``; ``[64 | 8, 16384, 128]``, 2 048 keys a query, ms
-# a call at 1 / 2 / 4 / 8 / 16 / 32 tiles a step):
-#   an update a tile       83.6  75.2  71.2  69.9  65.9  62.7
-#   an update a pair       83.6  49.9  43.9  42.7  39.2  35.4
-# (one tile a step is the body the kernel had, bit for bit). At 32 the k
-# axis is one step: no dead steps, and K and V of a key head are fetched
-# once for all its query heads' rows. Four tiles an update read 34.7,
-# groups of (8, 4, 2, 1) 33.7 - 34.2 for twice the body; groups of (2, 1)
-# 39.0.
+# K tiles a grid step of ``dsa_fwd`` and ``dsa_dq`` (a tile is a bit of the
+# packed word: ``S / 32`` keys), the longest first, every rung a divisor of
+# the 32; the straight-line groups a step's tiles are met in
+# (``ops/flash.py``'s, PR 60: inside a group Mosaic schedules an update's
+# matmuls beside its neighbour's pointwise work, which neither a grid step
+# nor a loop trip lets it do); and the tiles ONE matmul takes side by side
+# (in the forward one update of the softmax statistics: one max-reduce,
+# one alpha, one rescale of acc: 1 024 keys at 16k, that file's streamed
+# tile; a lone tile updates alone). On the v5e (``scripts/dsa_micro.py``;
+# ``[64 | 8, 16384, 128]``, 2 048 keys a query, ms a call at 1 / 2 / 4 / 8 /
+# 16 / 32 tiles a step; one tile a step is the body the kernels had, bit
+# for bit):
+#   dsa_fwd, an update a tile  83.6  75.2  71.2  69.9  65.9  62.7  (PR 69)
+#   dsa_fwd, an update a pair  83.6  49.9  43.9  42.7  39.2  35.4
+#   dsa_dq, a tile a matmul    56.9  51.5  47.3  45.6  41.7  37.5  (PR 72)
+#   dsa_dq, a pair a matmul    56.9  51.5  47.3  45.7  41.7  37.5
+# At 32 the k axis is one step: no dead steps (31 744 of dq's 65 536 were),
+# and K and V of a key head are fetched once for all its query heads'
+# rows. The forward at four tiles an update read 34.7, groups of (8, 4, 2,
+# 1) 33.7 - 34.2 for twice the body; groups of (2, 1) 39.0. ``dsa_dq`` has
+# no statistics, and the pair's width is worth nothing to it: at 37.5 ms
+# it runs its three matmuls a tile at 92 % of the MXU's peak.
 _CHUNK_LADDER = (32, 16, 8, 4, 2, 1)
 _STRAIGHT = (4, 2, 1)
 _SPAN = 2
@@ -434,6 +455,10 @@ def _forward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
             + straight * 2 * block_q * width * 4)
 
 
+def _fits(estimate: int) -> bool:
+    return estimate <= _PARAMS.vmem_limit_bytes
+
+
 def _choose_chunk(seq_len: int, head_dim: int, v_dim: int, itemsize: int,
                   block_q: int) -> int:
     """K tiles a grid step of ``dsa_fwd`` sweeps, a pure function of the
@@ -445,9 +470,191 @@ def _choose_chunk(seq_len: int, head_dim: int, v_dim: int, itemsize: int,
     width = _width(seq_len)
     if width % _LANES:
         return 1
-    limit = _PARAMS.vmem_limit_bytes
-    return next(n for n in _CHUNK_LADDER if n == 1 or _forward_vmem_estimate(
-        head_dim, v_dim, itemsize, block_q, width, n) <= limit)
+    return next(n for n in _CHUNK_LADDER if n == 1 or _fits(
+        _forward_vmem_estimate(head_dim, v_dim, itemsize, block_q, width, n)))
+
+
+def _backward_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
+                            block_q: int, width: int, chunk: int) -> int:
+    """Bytes ``dsa_dq`` keeps in VMEM at ``chunk`` k tiles a grid step:
+    ``_forward_vmem_estimate``'s operands with dO beside q, dq for o, the
+    two statistics' rows for the one, a scratch accumulator and the
+    statistics' columns, the f32 copies of q and dO and, for every tile of
+    the longest straight-line group, S, P and dS. Held against the compiler
+    for a described v5e as that estimate is (PR 72, the cell's ``[64 | 8,
+    16384, 128]`` bf16, 512 rows; MiB, estimate -> allocation): 1 tile 7.3
+    -> 5.5, 2 tiles 10.8 -> 8.25, 4 tiles 17.8 -> 9.5, 8 tiles 19.8 -> 11.5,
+    16 tiles 23.8 -> 15.5, 32 tiles 31.8 -> 23.5: over at every rung, by 1.8
+    - 8.3."""
+    pair = head_dim + v_dim
+    operands = ((chunk * width + block_q) * pair * itemsize
+                + block_q * head_dim * itemsize
+                + block_q * width * 4 + 2 * block_q * 4)
+    scratch = block_q * (head_dim + _LANES) * 4
+    straight = min(chunk, _STRAIGHT[0])
+    return (2 * operands + scratch + block_q * pair * 4
+            + straight * 3 * block_q * width * 4)
+
+
+def _column_vmem_estimate(head_dim: int, v_dim: int, itemsize: int,
+                          block_q: int, width: int, tiles: int,
+                          chunk: int) -> int:
+    """Bytes ``dsa_dkv`` keeps in VMEM at ``tiles`` k tiles against a
+    ``chunk`` of q blocks a grid step: its pipelined operands twice (K, V,
+    dk and dv of the tiles; q, dO, the statistics' rows and the packed
+    words of the chunk), the two accumulators and the turned mask, the f32
+    copies of K and V and of a span's q and dO and, for every q block of
+    the longest straight-line group, two of S^T, P^T and dS^T (the compiler
+    reuses the third's room). Held against the compiler for a described
+    v5e (PR 72, the cell's shape; MiB, estimate -> allocation, k tiles x q
+    blocks): 1 x 1 8.0 -> 6.75, 2 x 1 13.0 -> 11.25, 2 x 2 22.0 -> 20.0, 2 x 4
+    39.0 -> 30.0, 2 x 8 57.1 -> 48.0 (1 x 8 39.1 -> 34.0, 4 x 4 67.0 -> 50.0):
+    over at every rung, by 1.3 - 9.1."""
+    pair = head_dim + v_dim
+    keys, rows = tiles * width, chunk * block_q
+    operands = ((2 * keys + rows) * pair * itemsize
+                + rows * width * 4 + 2 * rows * 4)
+    scratch = keys * pair * 4 + keys * rows * 4
+    straight = min(chunk, _STRAIGHT[0])
+    return (2 * operands + scratch
+            + (keys + min(chunk, _SPAN) * block_q) * pair * 4
+            + straight * 2 * keys * block_q * 4)
+
+
+def _kl_vmem_estimate(heads: int, kv_heads: int, head_dim: int,
+                      index_heads: int, index_dim: int, itemsize: int,
+                      block_q: int, width: int, straight: int) -> int:
+    """Bytes ``dsa_kl`` (with its gradients) keeps in VMEM at ``straight``
+    heads a group: its pipelined operands twice (every head's q and
+    statistics, the key heads' K and the indexer's of the tile, the words;
+    dqi, dw and the tile's dki), the scratch accumulators, and tiles of
+    ``[S / 32, rows]`` f32: pbar, the indexer's scores, dI, the mask, a
+    head's S and P and what the compiler keeps beside them — thirteen, and
+    one more for every four heads of a group. Held against the compiler for
+    a described v5e (PR 72, the cell's ``[2, 32 | 4, 16384, 128]`` and 16 x
+    64 bf16, 512 rows; MiB, estimate -> allocation): 1 head 30.7 -> 29.75, 4
+    heads 31.7 -> 30.0, 8 heads 32.7 -> 30.0, 16 heads 34.7 -> 31.25: over at
+    every rung, by 0.9 - 3.4."""
+    operands = (heads * block_q * (head_dim * itemsize + 4)
+                + kv_heads * width * head_dim * itemsize
+                + 2 * index_heads * block_q * (index_dim * itemsize + 4)
+                + width * index_dim * (itemsize + 4)
+                + block_q * width * 4 + 2 * block_q * 4)
+    scratch = index_heads * block_q * (index_dim + 1) * 4 + block_q * 4
+    return (2 * operands + scratch
+            + (13 + straight // 4) * width * block_q * 4)
+
+
+# ``dsa_dkv``: ``_COLUMN_TILES`` k tiles a grid step, one matmul wide
+# (``[2 * S / 32, rows]`` transposed tiles, the mask turned once a q step for
+# both bits), against a chunk of consecutive q blocks on ``_COLUMN_LADDER``,
+# swept in ``_STRAIGHT``'s groups. What bounds the chunk is VMEM: the packed
+# words are the large operand of a column sweep, 1 MiB a q block of 512
+# rows at 16k, twice for the pipeline, and the turned mask as much again a
+# k tile — 48 MiB of the limit's 64 at 2 x 8. ``dsa_kl``: heads a
+# straight-line group on ``_KL_LADDER``; a group of 32 is the 48 heads
+# written out that compiled for 15 - 25 s a call site. On the v5e (PR 72,
+# ``scripts/dsa_micro.py``, ms a call, the parent 74.9 and 65.7):
+#   dsa_dkv, k tiles x q blocks   2x1 62.4  4x1 59.1  8x1 61.7  1x2 68.2
+#       1x4 64.9  1x8 63.2  2x2 59.0  4x2 57.5  2x4 57.3  2x8 56.5  4x4 56.5
+#       (two q blocks a matmul or one: the same to 0.2)
+#   dsa_kl, heads a group         2 54.0  4 48.3  8 45.5  16 42.1
+#       (two k tiles a step 63.7 / 53.9 / 49.1 / 47.2 at 1 / 2 / 4 / 8
+#       heads, four 66.3: no gain, so a step stays one tile)
+_COLUMN_TILES = 2
+_COLUMN_LADDER = (8, 4, 2, 1)
+_KL_LADDER = (16, 8, 4, 2, 1)
+
+
+def _choose_backward(seq_len: int, head_dim: int, v_dim: int, itemsize: int,
+                     block_q: int) -> Tuple[int, int, int]:
+    """``(k tiles a grid step of dsa_dq, k tiles and q blocks a grid step
+    of dsa_dkv)``, a pure function of the shape: all 1 where a tile is
+    narrower than a lane tile (:func:`_choose_chunk`), else the longest
+    rungs whose estimates fit ``_PARAMS``' limit."""
+    width = _width(seq_len)
+    if width % _LANES:
+        return 1, 1, 1
+    blocks = seq_len // block_q
+    dq = next(n for n in _CHUNK_LADDER if n == 1 or _fits(
+        _backward_vmem_estimate(head_dim, v_dim, itemsize, block_q, width,
+                                n)))
+    tiles, chunk = next(
+        ((_COLUMN_TILES, n) for n in _COLUMN_LADDER
+         if blocks % n == 0 and _fits(_column_vmem_estimate(
+             head_dim, v_dim, itemsize, block_q, width, _COLUMN_TILES, n))),
+        (1, 1))
+    return dq, tiles, chunk
+
+
+def _choose_kl(seq_len: int, heads: int, kv_heads: int, head_dim: int,
+               index_heads: int, index_dim: int, itemsize: int,
+               block_q: int) -> int:
+    """Heads a straight-line group of ``dsa_kl``, a pure function of the
+    shape: 1 where a tile is narrower than a lane tile (the loop of one
+    head the kernel had), else the longest rung of ``_KL_LADDER`` whose
+    :func:`_kl_vmem_estimate` fits ``_PARAMS``' limit."""
+    width = _width(seq_len)
+    if width % _LANES:
+        return 1
+    return next(n for n in _KL_LADDER if n == 1 or _fits(_kl_vmem_estimate(
+        heads, kv_heads, head_dim, index_heads, index_dim, itemsize, block_q,
+        width, n)))
+
+
+def _tiles(ref, first, span: int, width: int, chunk: int):
+    """Tiles ``first .. first + span`` (of ``width`` rows each) of the
+    ``chunk`` a ref's block holds, f32 as loaded."""
+    if chunk == 1:
+        return _f32(ref[0])
+    return _f32(ref[0, pl.ds(pl.multiple_of(first * width, width),
+                             span * width), :])
+
+
+def _bits(words, tile, span: int, transposed: bool = False):
+    """The masks of k tiles ``tile .. tile + span`` of a block of words
+    side by side: ``[rows, span * S / 32]`` bool, or ``transposed`` the
+    bits themselves as ``[span * S / 32, rows]`` int32 (a kernel that
+    computes the tile transposed keeps them in scratch)."""
+    if transposed:
+        keep = [((words >> (tile + j)) & 1).T for j in range(span)]
+    else:
+        keep = [_bit(words, tile + j) for j in range(span)]
+    return keep[0] if span == 1 else jnp.concatenate(
+        keep, axis=0 if transposed else 1)
+
+
+def _straight(lo, hi, longest: int, update, carried, keep):
+    """Tiles ``lo .. hi`` (traced, at most ``longest``) in ascending order
+    in straight-line groups: as many groups of ``_STRAIGHT``'s longest
+    size as they hold, then of the next; a group is traced as a loop of
+    ONE ``update(first tile, span, *carry)`` (over ``_SPAN`` tiles side by
+    side) that is laid out whole when it is lowered, the carry a value
+    inside a group and through ``keep`` / ``carried`` (the scratch)
+    between groups. ``longest == 1``: the one update, no loop."""
+    if longest == 1:
+        keep(*update(lo, 1, *carried()))
+        return
+
+    def group(size, start):
+        span = min(size, _SPAN)
+
+        def body(i, _):
+            first = start + i * size
+            keep(*jax.lax.fori_loop(
+                0, size // span,
+                lambda j, carry: update(first + j * span, span, *carry),
+                carried(), unroll=True))
+            return 0
+        return body
+
+    done = lo
+    for size in _STRAIGHT:
+        if size > longest:
+            continue
+        count = (hi - done) // size
+        jax.lax.fori_loop(0, count, group(size, done), 0)
+        done = done + count * size
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, acc_ref,
@@ -483,51 +690,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, acc_ref,
         # set's mask: a row with no chosen key yet accumulates at m =
         # _NEG_INF and its first chosen key clears that (alpha = 0), as
         # under that file's window
-        if chunk == 1:
-            k, v = k_ref[0], v_ref[0]
-        else:
-            at = pl.ds(pl.multiple_of((kt - lo) * width, width),
-                       span * width)
-            k, v = k_ref[0, at, :], v_ref[0, at, :]
-        words = sel_ref[0]
-        keep = [_bit(words, kt + j) for j in range(span)]
-        s = jnp.where(keep[0] if span == 1 else jnp.concatenate(keep, axis=1),
-                      _dot(q, _f32(k), _NT), _NEG_INF)
+        k, v = (_tiles(ref, kt - lo, span, width, chunk)
+                for ref in (k_ref, v_ref))
+        s = jnp.where(_bits(sel_ref[0], kt, span), _dot(q, k, _NT), _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        return (acc * alpha + _dot(p, _f32(v), _NN),
+        return (acc * alpha + _dot(p, v, _NN),
                 m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True))
 
     @pl.when(lo <= last)
     def _chunk():
-        q = _f32(q_ref[0]) * scale
-        if chunk == 1:
-            _keep(*_update(q, ki, 1, *_carried()))
-            return
-
-        def group(size, start):
-            span = min(size, _SPAN)
-
-            def body(i, _):
-                first = start + i * size
-                _keep(*jax.lax.fori_loop(
-                    0, size // span,
-                    lambda j, carry: _update(q, first + j * span, span,
-                                             *carry),
-                    _carried(), unroll=True))
-                return 0
-            return body
-
-        # the chunk's live tiles in ascending k: as many groups of the
-        # longest size as they hold, then of the next
-        done, live_hi = lo, jnp.minimum(last + 1, lo + chunk)
-        for size in _STRAIGHT:
-            if size > chunk:
-                continue
-            count = (live_hi - done) // size
-            jax.lax.fori_loop(0, count, group(size, done), 0)
-            done = done + count * size
+        _straight(lo, jnp.minimum(last + 1, lo + chunk), chunk,
+                  functools.partial(_update, _f32(q_ref[0]) * scale),
+                  _carried, _keep)
 
     @pl.when(ki == last // chunk)
     def _finalize():
@@ -592,58 +768,92 @@ def _forward(q, k, v, sel, heads: int, scale: float, block_q: int,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
                dq_ref, dq_acc, cols, *, scale: float, block_q: int,
-               width: int):
+               width: int, chunk: int):
+    """``_fwd_kernel``'s grid and sweep: a step holds ``chunk`` k tiles
+    and meets the live ones in straight-line groups, each matmul over
+    ``_SPAN`` tiles side by side under one concatenated mask (``S``,
+    ``P``, ``dS`` ``[rows, span * S / 32]``; ``dS . K`` over the span's
+    keys); ``dq_acc`` a value inside a group, the scratch between."""
     qi, ki = pl.program_id(1), pl.program_id(2)
     last = _last_k(qi, block_q, width)
+    lo = ki * chunk
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         cols[...] = _rows_to_cols(lse_ref[0], delta_ref[0])
 
-    @pl.when(ki <= last)
-    def _tile():
-        k, v = _f32(k_ref[0]), _f32(v_ref[0])
-        s = jnp.where(_bit(sel_ref[0], ki),
-                      _dot(_f32(q_ref[0]) * scale, k, _NT), _NEG_INF)
-        p = jnp.exp(s - cols[:, :1])
-        ds = p * (_dot(_f32(do_ref[0]), v, _NT) - cols[:, 1:2])
-        dq_acc[...] = dq_acc[...] + _dot(ds, k, _NN)
+    def _keep(dq):
+        dq_acc[...] = dq
 
-    @pl.when(ki == last)
+    def _update(q, do, kt, span, dq):
+        k, v = (_tiles(ref, kt - lo, span, width, chunk)
+                for ref in (k_ref, v_ref))
+        s = jnp.where(_bits(sel_ref[0], kt, span), _dot(q, k, _NT), _NEG_INF)
+        p = jnp.exp(s - cols[:, :1])
+        ds = p * (_dot(do, v, _NT) - cols[:, 1:2])
+        return (dq + _dot(ds, k, _NN),)
+
+    @pl.when(lo <= last)
+    def _chunk():
+        _straight(lo, jnp.minimum(last + 1, lo + chunk), chunk,
+                  functools.partial(_update, _f32(q_ref[0]) * scale,
+                                    _f32(do_ref[0])),
+                  lambda: (dq_acc[...],), _keep)
+
+    @pl.when(ki == last // chunk)
     def _finalize():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, keep_t, *, scale: float,
-                block_q: int, width: int):
-    """Grid ``(B * KV, k tiles, q blocks, group)``: a k tile's column of q
-    blocks, the group's heads innermost, so the tile's mask is turned
-    once a q block (``keep_t``, ``[S / 32, rows]``: the tile is recomputed
-    TRANSPOSED as in ``flash._dkv_tile``) and dk, dv are summed over the
-    group in f32 and rounded once."""
+                block_q: int, width: int, tiles: int, chunk: int):
+    """Grid ``(B * KV, k steps, q steps, group)``: a step holds ``tiles``
+    consecutive k tiles (one matmul wide) against a ``chunk`` of
+    consecutive q blocks, the group's heads innermost, so the tiles' mask
+    is turned once a q step (``keep_t``, ``[tiles * S / 32, chunk *
+    rows]``: a tile is recomputed TRANSPOSED as in ``flash._dkv_tile``)
+    and dk, dv are summed over the group in f32 and rounded once. The
+    chunk's q blocks at or after the first tile's first key
+    (:func:`_first_q`) are met in ascending q in straight-line groups
+    (:func:`_straight`), ``_SPAN`` q blocks side by side a matmul (``dS^T
+    . Q`` and ``P^T . dO`` over the span's rows). Of the step's later
+    tiles the q blocks before their first key hold no chosen key."""
     ki, qi, g = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    first = _first_q(ki, block_q, width)
+    first = _first_q(ki * tiles, block_q, width)
+    lo = qi * chunk
 
-    @pl.when((qi == first) & (g == 0))
+    @pl.when((qi == first // chunk) & (g == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(qi >= first)
+    def _keep(dk, dv):
+        dk_acc[...] = dk
+        dv_acc[...] = dv
+
+    def _update(qb, span, dk, dv):
+        # the span's q blocks: rows of q and dO, lanes of the statistics'
+        # rows and of the turned mask
+        at = slice(None) if chunk == 1 else pl.ds(
+            pl.multiple_of((qb - lo) * block_q, block_q), span * block_q)
+        q, do = _f32(q_ref[0, at, :]) * scale, _f32(do_ref[0, at, :])
+        st = jnp.where(keep_t[:, at] != 0, _dot(_f32(k_ref[0]), q, _NT),
+                       _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, :, at])
+        dst = pt * (_dot(_f32(v_ref[0]), do, _NT) - delta_ref[0, :, at])
+        return dk + _dot(dst, q, _NN), dv + _dot(pt, do, _NN)
+
+    @pl.when(qi >= first // chunk)
     def _tile():
         @pl.when(g == 0)
         def _turn():
-            keep_t[...] = ((sel_ref[0] >> ki) & 1).T
+            keep_t[...] = _bits(sel_ref[0], ki * tiles, tiles,
+                                transposed=True)
 
-        q, do = _f32(q_ref[0]) * scale, _f32(do_ref[0])
-        st = jnp.where(keep_t[...] != 0, _dot(_f32(k_ref[0]), q, _NT),
-                       _NEG_INF)
-        pt = jnp.exp(st - lse_ref[0])
-        dst = pt * (_dot(_f32(v_ref[0]), do, _NT) - delta_ref[0])
-        dk_acc[...] = dk_acc[...] + _dot(dst, q, _NN)
-        dv_acc[...] = dv_acc[...] + _dot(pt, do, _NN)
+        _straight(jnp.maximum(lo, first), lo + chunk, chunk, _update,
+                  lambda: (dk_acc[...], dv_acc[...]), _keep)
 
     @pl.when((qi == pl.num_programs(2) - 1) & (g == pl.num_programs(3) - 1))
     def _finalize():
@@ -651,23 +861,29 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
 def _backward(q, k, v, do, lse, delta, sel, heads: int, scale: float,
-              block_q: int, interpret: bool):
-    """``lse``, ``delta``: ``[B * H, 1, S]`` f32."""
+              block_q: int, tiles: Tuple[int, int, int], interpret: bool):
+    """``lse``, ``delta``: ``[B * H, 1, S]`` f32. ``tiles``:
+    :func:`_choose_backward`'s (``TRACED["dsa_dq_chunk_tiles"]`` and
+    ``["dsa_dkv_chunk_tiles"]`` say what the last call traced took)."""
     bh, seq_len, d = q.shape
     bkv, dv = k.shape[0], v.shape[-1]
     group, kv_heads = bh // bkv, heads // (bh // bkv)
     width = _width(seq_len)
-    by_q, by_k, q_lanes, words = _row_maps(heads, group, block_q, width)
+    dq_chunk, dkv_tiles, dkv_chunk = tiles
+    TRACED.gauge("dsa_dq_chunk_tiles", dq_chunk)
+    TRACED.gauge("dsa_dkv_chunk_tiles", dkv_tiles * dkv_chunk)
+    by_q, by_k, q_lanes, words = _row_maps(heads, group, block_q, width,
+                                           dq_chunk)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, block_q=block_q,
-                          width=width),
-        grid=(bh, seq_len // block_q, WORD),
+                          width=width, chunk=dq_chunk),
+        grid=(bh, seq_len // block_q, WORD // dq_chunk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), by_q),
-            pl.BlockSpec((1, width, d), by_k),
-            pl.BlockSpec((1, width, dv), by_k),
+            pl.BlockSpec((1, dq_chunk * width, d), by_k),
+            pl.BlockSpec((1, dq_chunk * width, dv), by_k),
             pl.BlockSpec((1, block_q, dv), by_q),
             pl.BlockSpec((1, 1, block_q), q_lanes),
             pl.BlockSpec((1, 1, block_q), q_lanes),
@@ -682,39 +898,42 @@ def _backward(q, k, v, do, lse, delta, sel, heads: int, scale: float,
         name="dsa_dq",
     )(q, k, v, do, lse, delta, sel)
 
-    def q_block(j, i):          # a dead step stays on the first live block
-        return jnp.maximum(i, _first_q(j, block_q, width))
+    rows, keys = dkv_chunk * block_q, dkv_tiles * width
+
+    def q_step(j, i):           # a dead step stays on the first live one
+        return jnp.maximum(
+            i, _first_q(j * dkv_tiles, block_q, width) // dkv_chunk)
 
     def col_q(n, j, i, g):
-        return (n * group + g, q_block(j, i), 0)
+        return (n * group + g, q_step(j, i), 0)
 
     def col_lanes(n, j, i, g):
-        return (n * group + g, 0, q_block(j, i))
+        return (n * group + g, 0, q_step(j, i))
 
     def col_k(n, j, i, g):
         return (n, j, 0)
 
     dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
-                          width=width),
-        grid=(bkv, WORD, seq_len // block_q, group),
+                          width=width, tiles=dkv_tiles, chunk=dkv_chunk),
+        grid=(bkv, WORD // dkv_tiles, seq_len // rows, group),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), col_q),
-            pl.BlockSpec((1, width, d), col_k),
-            pl.BlockSpec((1, width, dv), col_k),
-            pl.BlockSpec((1, block_q, dv), col_q),
-            pl.BlockSpec((1, 1, block_q), col_lanes),
-            pl.BlockSpec((1, 1, block_q), col_lanes),
-            pl.BlockSpec((1, block_q, width),
-                         lambda n, j, i, g: (n // kv_heads, q_block(j, i), 0)),
+            pl.BlockSpec((1, rows, d), col_q),
+            pl.BlockSpec((1, keys, d), col_k),
+            pl.BlockSpec((1, keys, dv), col_k),
+            pl.BlockSpec((1, rows, dv), col_q),
+            pl.BlockSpec((1, 1, rows), col_lanes),
+            pl.BlockSpec((1, 1, rows), col_lanes),
+            pl.BlockSpec((1, rows, width),
+                         lambda n, j, i, g: (n // kv_heads, q_step(j, i), 0)),
         ],
-        out_specs=[pl.BlockSpec((1, width, d), col_k),
-                   pl.BlockSpec((1, width, dv), col_k)],
+        out_specs=[pl.BlockSpec((1, keys, d), col_k),
+                   pl.BlockSpec((1, keys, dv), col_k)],
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        scratch_shapes=[pltpu.VMEM((width, d), jnp.float32),
-                        pltpu.VMEM((width, dv), jnp.float32),
-                        pltpu.VMEM((width, block_q), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((keys, d), jnp.float32),
+                        pltpu.VMEM((keys, dv), jnp.float32),
+                        pltpu.VMEM((keys, rows), jnp.int32)],
         compiler_params=_PARAMS,
         interpret=interpret,
         name="dsa_dkv",
@@ -746,8 +965,10 @@ def _attend_bwd(scale, block_q, interpret, res, cotangents):
     do = _merge(cotangents[0])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]
+    tiles = _choose_backward(q.shape[2], q.shape[3], v.shape[3],
+                             q.dtype.itemsize, block_q)
     dq, dk, dv = _backward(_merge(q), _merge(k), _merge(v), do, lse, delta,
-                           sel, q.shape[1], scale, block_q, interpret)
+                           sel, q.shape[1], scale, block_q, tiles, interpret)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), None
 
 
@@ -778,17 +999,20 @@ def attend(q, k, v, sel, scale: Optional[float] = None, *,
 
 # ---------------------------------------------------------------- dsa_kl
 def _kl_kernel(*refs, scale: float, block_q: int, width: int, group: int,
-               grads: bool):
+               grads: bool, straight: int):
     """Grid ``(B, q blocks, k tiles)``; a step holds ALL the heads of a
     tile: ``pbar`` is their mean. The tile is computed TRANSPOSED (``[S /
     32, rows]``, as ``flash._dkv_tile``): the heads' and the rows'
     statistics and the indexer's weights are then rows ``[1, rows]`` read
-    where they lie, both sets of heads are LOOPS (a body is traced and
-    compiled once: 48 heads written out compiled for 15 - 25 s a call
-    site), and the mask is turned once a tile. ``grads``: besides the
-    rows' KL, ``dI = softmax_S(I) - pbar`` onto ``dqi`` and ``dw`` (over a
-    row's tiles, in scratch) and ``dki`` (this q block's partial of the
-    tile)."""
+    where they lie, both sets of heads are LOOPS of straight-line groups
+    of ``straight`` heads (a group is traced as a loop of ONE head that is
+    laid out when it is lowered: inside it Mosaic schedules a head's
+    matmul beside its neighbour's mask, subtraction and exponential, which
+    a loop trip does not let it do; 48 heads written out compiled for 15 -
+    25 s a call site), and the mask is turned once a tile. ``grads``:
+    besides the rows' KL, ``dI = softmax_S(I) - pbar`` onto ``dqi`` and
+    ``dw`` (over a row's tiles, in scratch) and ``dki`` (this q block's
+    partial of the tile)."""
     if grads:
         (q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, sel_ref, lsei_ref,
          kl_ref, dqi_ref, dw_ref, dki_ref, kl_acc, dqi_acc, dw_acc) = refs
@@ -810,6 +1034,22 @@ def _kl_kernel(*refs, scale: float, block_q: int, width: int, group: int,
     def row(ref, h):
         return ref[0, pl.ds(h, 1), :]
 
+    def over(count, head, init):
+        """``head(h, carry)`` over the heads in ascending ``h``: a loop of
+        groups of ``straight`` heads, then the heads left over; a group is
+        traced as a loop of ONE head that is laid out when it is lowered."""
+        if straight == 1:
+            return jax.lax.fori_loop(0, count, head, init)
+
+        def group(size):
+            return lambda i, carry: jax.lax.fori_loop(
+                0, size, lambda j, c: head(i * straight + j, c), carry,
+                unroll=True)
+
+        groups, rest = divmod(count, straight)
+        carry = jax.lax.fori_loop(0, groups, group(straight), init)
+        return group(rest)(groups, carry) if rest else carry
+
     @pl.when(ki <= last)
     def _tile():
         keep = ((sel_ref[0] >> ki) & 1).T != 0
@@ -820,17 +1060,16 @@ def _kl_kernel(*refs, scale: float, block_q: int, width: int, group: int,
             return pbar + jnp.exp(jnp.where(keep, s, _NEG_INF)
                                   - row(lse_ref, h))
 
-        pbar = jax.lax.fori_loop(
-            0, heads, head, jnp.zeros(tile, jnp.float32)) * (1.0 / heads)
+        pbar = over(heads, head, jnp.zeros(tile, jnp.float32)) * (
+            1.0 / heads)
         kt = ki_ref[0]
 
         def index_head(j, index):
             return index + row(w_ref, j) * _relu(
                 _dot(kt, qi_ref[0, j], _NT))
 
-        log_q = jax.lax.fori_loop(
-            0, index_heads, index_head, jnp.zeros(tile, jnp.float32)
-        ) - lsei_ref[0]
+        log_q = over(index_heads, index_head,
+                     jnp.zeros(tile, jnp.float32)) - lsei_ref[0]
         on = keep & (pbar > 0.0)
         kl = jnp.where(on, pbar * (jnp.log(jnp.where(on, pbar, 1.0))
                                    - log_q), 0.0)
@@ -848,9 +1087,8 @@ def _kl_kernel(*refs, scale: float, block_q: int, width: int, group: int,
                 dqi_acc[j] = dqi_acc[j] + _dot(g, ktf, _TN)
                 return dki + _dot(g, _f32(qj), _NN)
 
-            dki_ref[0, 0] = jax.lax.fori_loop(
-                0, index_heads, index_grads,
-                jnp.zeros(dki_ref.shape[2:], jnp.float32))
+            dki_ref[0, 0] = over(index_heads, index_grads,
+                                 jnp.zeros(dki_ref.shape[2:], jnp.float32))
 
     if grads:
         @pl.when(ki > last)
@@ -865,15 +1103,19 @@ def _kl_kernel(*refs, scale: float, block_q: int, width: int, group: int,
             dw_ref[0] = dw_acc[...]
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
 def _kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale: float, block_q: int,
-             grads: bool, interpret: bool):
+             grads: bool, straight: int, interpret: bool):
     """The rows' KL ``[B, S]`` and, with ``grads``, ``(dqi, dki, dw)`` of
     their SUM. ``w [B, S, HI]`` goes in, and ``dw`` comes out, through its
-    ``[B, HI, S]`` form (a head's weights along the lanes)."""
+    ``[B, HI, S]`` form (a head's weights along the lanes). ``straight``:
+    :func:`_choose_kl`'s heads a straight-line group
+    (``TRACED["dsa_kl_group_heads"]`` says what the last call traced
+    took)."""
     b, heads, seq_len, d = q.shape
     kv, index_heads, di = k.shape[1], qi.shape[1], qi.shape[-1]
     width, blocks = _width(seq_len), seq_len // block_q
+    TRACED.gauge("dsa_kl_group_heads", straight)
 
     def by_k(n, i, j):
         return jnp.minimum(j, _last_k(i, block_q, width))
@@ -912,7 +1154,8 @@ def _kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale: float, block_q: int,
                     pltpu.VMEM((index_heads, block_q), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_kl_kernel, scale=scale, block_q=block_q,
-                          width=width, group=heads // kv, grads=grads),
+                          width=width, group=heads // kv, grads=grads,
+                          straight=straight),
         grid=(b, blocks, WORD),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratch,
@@ -927,13 +1170,15 @@ def _kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale: float, block_q: int,
             dw.transpose(0, 2, 1))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _kl(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _kl(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, straight,
+        interpret):
     return jnp.sum(_kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q,
-                            False, interpret))
+                            False, straight, interpret))
 
 
-def _kl_fwd(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, interpret):
+def _kl_fwd(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, straight,
+            interpret):
     # asked for by ``jax`` only where ``_kl`` is differentiated: the
     # gradients of the rows' SUM come out of the call that makes the value
     # (every operand is here; the backward pass brings the cotangent, one
@@ -943,11 +1188,11 @@ def _kl_fwd(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q, interpret):
     # a call whose caller keeps nothing smaller
     TRACED.incr("dsa_kl_grad_calls")
     kl, *grads = _kl_call(q, k, lse, qi, ki, w, sel, lse_i, scale, block_q,
-                          True, interpret)
+                          True, straight, interpret)
     return jnp.sum(kl), tuple(checkpoint_name(a, KEY_CHOICE) for a in grads)
 
 
-def _kl_bwd(scale, block_q, interpret, grads, g):
+def _kl_bwd(scale, block_q, straight, interpret, grads, g):
     dqi, dki, dw = ((g * a).astype(a.dtype) for a in grads)
     return None, None, None, dqi, dki, dw, None, None
 
@@ -967,6 +1212,9 @@ def index_kl(q, k, lse, qi, ki, w, sel, lse_i, scale: Optional[float] = None,
     kernels, interpret = _use_kernels(interpret)
     if not kernels:
         return _dense_kl(q, k, lse, qi, ki, w, sel, scale)
+    block_q = _rows(seq_len, _ATTEND_ROWS, block_q)
+    straight = _choose_kl(seq_len, q.shape[1], k.shape[1], q.shape[3],
+                          qi.shape[1], qi.shape[3], q.dtype.itemsize, block_q)
     return _kl(q, k, lse.astype(jnp.float32), qi, ki, w.astype(jnp.float32),
-               sel, jax.lax.stop_gradient(lse_i), scale,
-               _rows(seq_len, _ATTEND_ROWS, block_q), interpret)
+               sel, jax.lax.stop_gradient(lse_i), scale, block_q, straight,
+               interpret)
