@@ -81,6 +81,8 @@ _TINY = {
     # one layer of each kind (M A of M A M M), sixteen heads on one B and C
     "granite_hybrid": dict(file="tiny-granite.json", layers=2,
                            per_layer=("layer_types",)),
+    # two layers run four times on one set of weights
+    "ouro": dict(file="tiny-ouro.json"),
 }
 # the families with a loop scenario in tier-1 (``gpt``'s are
 # tests/test_step_programs.py's; ``phi4flash`` has none: ROADMAP.md)
@@ -263,14 +265,15 @@ def ft_steps(model, seed=7, steps=3):
         lighthouse.shutdown()
 
 
-def routing_gauges(run, until=12):
-    """The optimizer wrapper's routing gauges arrive on its sink without a
-    wait: they are read at a later commit than the one that asked, so the
-    group of :func:`ft_steps` steps on (at most to ``until``) until the
-    first is there. Returns the sink's snapshot."""
+def routing_gauges(run, until=12, key="moe_held_share"):
+    """The optimizer wrapper's routing gauges (or, by ``key``, a model's
+    step statistics) arrive on its sink without a wait: they are read at a
+    later commit than the one that asked, so the group of :func:`ft_steps`
+    steps on (at most to ``until``) until the first is there. Returns the
+    sink's snapshot."""
     group = run.group
     for i in range(run.steps, until):
-        if "moe_held_share" in group.opt.metrics.snapshot():
+        if key in group.opt.metrics.snapshot():
             break
         jax.block_until_ready(group.state)
         group.step(*run.source.device_batch(i, run.device))

@@ -8,6 +8,7 @@ grouped matmuls run in Pallas's interpreter."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import types
 
 import jax
@@ -319,13 +320,16 @@ def test_the_gauge_is_absent_where_the_share_was_not_said() -> None:
     assert float(skew) == pytest.approx(525 / 256)
     assert np.isnan(float(share)) and np.isnan(float(fits))
     for held, emitted in ((None, False), ((2, 2), True)):
-        wrapper = types.SimpleNamespace(
-            _routing=jax.jit(lambda s, held=held: optim.routing_gauges(
-                s, held)),
-            _routing_pending=None, metrics=Metrics())
+        wrapper = types.SimpleNamespace(metrics=Metrics())
+        # a gauges' program, its publisher and its pending result (PR 73:
+        # one list for the routing's and a model's step statistics)
+        wrapper._late_gauges = [[
+            jax.jit(lambda s, held=held: optim.routing_gauges(s, held)),
+            functools.partial(optim.OptimizerWrapper._publish_routing,
+                              wrapper), None]]
         optim.OptimizerWrapper._observe_routing(wrapper, state)
         assert "moe_load_max_over_mean" not in wrapper.metrics.snapshot()
-        jax.block_until_ready(wrapper._routing_pending)
+        jax.block_until_ready(wrapper._late_gauges[0][2])
         optim.OptimizerWrapper._observe_routing(wrapper, state)
         seen = wrapper.metrics.snapshot()
         assert seen["moe_load_max_over_mean"] == pytest.approx(525 / 256)

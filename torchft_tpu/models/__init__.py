@@ -27,8 +27,14 @@ top-8-of-128 router) and ``granite_hybrid`` (a dense stack whose layer is
 a mixer THEN a SwiGLU under two norms with a multiplier on each branch:
 Mamba-2 mixers whose 64 heads share one ``B`` and ``C`` 9 : 1 with
 position-free attention at the config's own softmax scale, the
-embedding's and the logits' scalings around one tied table); what more
-than one of them computes is in ``common``."""
+embedding's and the logits' scalings around one tied table) and ``ouro``
+(a stack whose layers run ``total_ut_steps`` times on ONE set of weights —
+a scan over the passes, a layer's gradient the sum over its visits —, four
+norms a layer, a head and a cross entropy after every pass through one
+weighted sweep of ``ops/xent.py``, an exit gate whose distribution over the
+passes weighs the losses under an entropy term, its statistics carried to
+the optimizer's gauges in the gradient tree); what more than one of them
+computes is in ``common``."""
 
 from torchft_tpu.models.mlp import (  # noqa: F401
     init_linear,

@@ -64,7 +64,17 @@ shard's (lse, target-logit) pair locally, then the shards combine with a
 pmax-stabilized logaddexp psum - Megatron's vocab-parallel cross entropy,
 done the TPU way (shard_map + XLA collectives, no gathered logits anywhere).
 
-What is rounded where is the same on both paths: operands as the caller
+**A weight a row: ``weighted_cross_entropy``, the fused sweep again.** A
+loss that mixes rows unequally — ``models/ouro.py``: four heads a token,
+row ``i`` of pass ``t`` weighed by an exit probability that is itself
+differentiated — wants ``Σ_i w_i ℓ_i`` and every ``ℓ_i``. The same sweep
+over row tiles with ``dz = w_i (softmax − onehot)`` in the place of ``/ N``,
+the rows' own ``ℓ`` handed back beside the sum, and ``ℓ`` as the cotangent
+of ``w`` (``∂ Σ w ℓ / ∂ w_i = ℓ_i``): 6 T d V and one tile through HBM, as
+above. ``ℓ`` comes back for its VALUE: a cotangent on it is dropped (what
+would differentiate it is the recompute path below).
+
+What is rounded where is the same on every path: operands as the caller
 casts them (``hidden_cross_entropy``: f32), default matmul precision, f32
 logits, f32 ``lse``, ``dlogits`` formed in f32. Numerics match the dense
 log_softmax path up to fp reassociation of the sumexp (tests pin this to
@@ -83,6 +93,7 @@ import numpy as np
 
 __all__ = [
     "chunked_cross_entropy",
+    "weighted_cross_entropy",
     "chunked_lse_and_target",
     "hidden_cross_entropy",
     "make_vocab_parallel_cross_entropy",
@@ -281,6 +292,83 @@ def _ce_bwd(num_chunks: int, residuals, g):
 
 
 chunked_cross_entropy.defvjp(_ce_fwd, _ce_bwd)
+
+
+def _weighted_sweep(x, w, targets, weights, num_chunks: int,
+                    with_grads: bool):
+    """:func:`_sweep` with a weight a row: ``(Σ_i w_i ℓ_i, ℓ [N])`` and,
+    ``with_grads``, the sum's gradients ``dx`` [N, D] and ``dW`` [D, V]
+    (f32) for a cotangent of 1. Padding rows weigh nothing."""
+    n, d = x.shape
+    v = w.shape[1]
+    tiles, rows, pad = _row_tiles(n, num_chunks)
+    scanned = (x, jnp.clip(targets, 0, v - 1), weights.astype(jnp.float32))
+    if pad:
+        scanned = tuple(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                        for a in scanned)
+    cols = jnp.arange(v)
+
+    def tile(xr, tr, wr):
+        """A tile's rows' NLL and its dz."""
+        z = (xr @ w).astype(jnp.float32)                # [rows, V]
+        hit = cols[None, :] == tr[:, None]
+        m = jnp.max(z, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(z - m[:, None]), axis=-1))
+        nll = lse - jnp.sum(jnp.where(hit, z, 0.0), axis=-1)
+        if not with_grads:
+            return nll, None
+        return nll, (jnp.exp(z - lse[:, None]) - hit) * wr[:, None]
+
+    def loss_body(total, inputs):
+        nll, _ = tile(*inputs)
+        return total + jnp.sum(inputs[2] * nll), nll
+
+    def grad_body(carry, inputs):
+        total, dw = carry
+        nll, dz = tile(*inputs)
+        xr = inputs[0]
+        dxr = dz @ w.T.astype(jnp.float32)              # [rows, D]
+        dw = dw + xr.T.astype(jnp.float32) @ dz         # [D, V]
+        return (total + jnp.sum(inputs[2] * nll), dw), (nll, dxr)
+
+    scanned = tuple(a.reshape(tiles, rows, *a.shape[1:]) for a in scanned)
+    zero = jnp.zeros((), jnp.float32)
+    if not with_grads:
+        total, nll = jax.lax.scan(loss_body, zero, scanned)
+        return total, nll.reshape(-1)[:n]
+    (total, dw), (nll, dx) = jax.lax.scan(
+        grad_body, (zero, jnp.zeros((d, v), jnp.float32)), scanned)
+    return total, nll.reshape(-1)[:n], dx.reshape(tiles * rows, d)[:n], dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def weighted_cross_entropy(x, w, targets, weights, num_chunks: int = 8):
+    """``(Σ_i weights_i ℓ_i, ℓ [N])`` with ``ℓ_i =
+    -log_softmax(x @ w)[i, targets[i]]``: :func:`chunked_cross_entropy`'s
+    sweep with a weight a row, all three gradients of the SUM computed in
+    the sweep that computes it (``weights``' is ``ℓ``). x: [N, D], w: [D,
+    V], targets: [N] int (clamped), weights: [N] f32; any N, any V. ``ℓ``
+    is returned for its value: its cotangent is dropped (module
+    docstring)."""
+    return _weighted_sweep(x, w, targets, weights, num_chunks, False)
+
+
+def _wce_fwd(x, w, targets, weights, num_chunks: int):
+    total, nll, dx, dw = _weighted_sweep(x, w, targets, weights, num_chunks,
+                                         True)
+    return (total, nll), (dx.astype(x.dtype), dw.astype(w.dtype),
+                          nll.astype(weights.dtype))
+
+
+def _wce_bwd(num_chunks: int, residuals, cotangents):
+    dx, dw, nll = residuals
+    g, _dropped = cotangents
+    zeros_t = np.zeros(dx.shape[:1], dtype=jax.dtypes.float0)
+    return ((g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), zeros_t,
+            (g * nll).astype(nll.dtype))
+
+
+weighted_cross_entropy.defvjp(_wce_fwd, _wce_bwd)
 
 
 def hidden_cross_entropy(h, w, targets, num_chunks: int):

@@ -37,6 +37,8 @@ __all__ = [
     "balance_bias_rule",
     "with_balance_bias",
     "routing_gauges",
+    "StepStatsState",
+    "with_step_stats",
     "OptimizerWrapper",
     "PartitionedOuterOptimizer",
     "ShardedOptState",
@@ -119,6 +121,16 @@ def with_balance_bias(tx, rate: float, is_bias,
     return out
 
 
+def _states_of(opt_state, kind) -> List[Any]:
+    """Every state of type ``kind`` (a rule's NamedTuple) inside
+    ``opt_state``, in the tree's order."""
+    import jax
+
+    return [s for s in jax.tree_util.tree_leaves(
+        opt_state, is_leaf=lambda x: isinstance(x, kind))
+        if isinstance(s, kind)]
+
+
 def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
     """``[moe_load_max_over_mean, moe_held_share, moe_row_buffer_share]``
     (float32) from the loads every :class:`BalanceBiasState` inside
@@ -138,10 +150,8 @@ def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
 
     from torchft_tpu.ops.moe import held_capacity
 
-    states = [s for s in jax.tree_util.tree_leaves(
-        opt_state, is_leaf=lambda x: isinstance(x, BalanceBiasState))
-        if isinstance(s, BalanceBiasState)]
-    loads = [x.astype(jnp.float32) for s in states
+    loads = [x.astype(jnp.float32)
+             for s in _states_of(opt_state, BalanceBiasState)
              for x in jax.tree_util.tree_leaves(s.loads)]
     if not loads:
         return None
@@ -158,6 +168,73 @@ def routing_gauges(opt_state, held: Optional[Tuple[int, int]] = None):
                                 count, x.shape[0])
              for r, x in zip(rows, loads)]).astype(jnp.float32))
     return jnp.stack([skew, share, fits])
+
+
+class StepStatsState(NamedTuple):
+    """What :func:`with_step_stats`' rule keeps: the statistics of the
+    last step it saw, one vector a carrying leaf."""
+    stats: Any
+
+
+@functools.lru_cache(maxsize=None)
+def _stats_transformation():
+    import optax
+
+    class StatsTransformation(optax.GradientTransformation):
+        """:func:`with_step_stats`' result: an optax transformation that
+        also says how its statistics become gauges
+        (``publish_step_stats(metrics, values)``)."""
+        publish_step_stats: Optional[Any] = None
+
+    return StatsTransformation
+
+
+def with_step_stats(tx, is_stats, publish):
+    """``tx`` for every leaf but those whose path ``is_stats`` accepts (the
+    model file's predicate), whose "gradient" is a vector of statistics of
+    the step the model put there
+    (``models/common.py::loads_as_gradient``; averaged over replica groups
+    like any gradient): the leaf is never moved and the statistics are
+    kept in the optimizer state (:class:`StepStatsState`), so the fused
+    step, the classic update behind the commit gate and the heal carry
+    them like any other leaf and no second forward pass reads them.
+    :class:`OptimizerWrapper` hands them, joined into one vector of
+    floats, to ``publish(metrics, values)`` (the model file's: it names
+    the gauges; this module knows no model's) by the route of the routing
+    gauges: read a commit later, never waited for."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    def keep(stats, state, params=None):
+        del state, params
+        return (jax.tree_util.tree_map(jnp.zeros_like, stats),
+                StepStatsState(stats))
+
+    rule = optax.GradientTransformation(
+        lambda params: StepStatsState(
+            jax.tree_util.tree_map(jnp.zeros_like, params)), keep)
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _x: "stats" if is_stats(path) else "rest", params)
+
+    both = optax.multi_transform({"rest": tx, "stats": rule}, labels)
+    out = _stats_transformation()(both.init, both.update)
+    out.publish_step_stats = publish
+    return out
+
+
+def step_stats(opt_state):
+    """The statistics every :class:`StepStatsState` inside ``opt_state``
+    holds, joined into one float32 vector. Traceable."""
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.concatenate([
+        x.astype(jnp.float32).reshape(-1)
+        for s in _states_of(opt_state, StepStatsState)
+        for x in jax.tree_util.tree_leaves(s.stats)])
 
 
 class PartitionedOuterOptimizer:
@@ -1009,45 +1086,59 @@ class OptimizerWrapper:
         )[0]
         if reused:
             self.metrics.incr("update_program_reused")
-        # The routing gauges of a :func:`with_balance_bias` transformation
-        # (None for any other): a program of a few operations over the
-        # loads the optimizer state holds, and the one result of it whose
-        # host copy is under way.
-        self._routing = None
-        self._routing_pending = None
+        # What the optimizer state says of a step beside its update, as
+        # gauges: of a :func:`with_balance_bias` transformation the
+        # routing's, of a :func:`with_step_stats` one the statistics as its
+        # ``publish_step_stats`` names them (nothing for any other). Each a
+        # program of a few operations over the state, its ``publish`` and
+        # the one result of it whose host copy is under way.
+        self._late_gauges: List[List[Any]] = []
         if hasattr(tx, "held_experts"):
             held = tx.held_experts
 
             def tft_routing_gauges(opt_state):
                 return routing_gauges(opt_state, held)
 
-            self._routing = step_program(tft_routing_gauges, (tx,))[0]
+            self._late_gauges.append([
+                step_program(tft_routing_gauges, (tx,))[0],
+                self._publish_routing, None])
+        if hasattr(tx, "publish_step_stats"):
+            def tft_step_stats(opt_state):
+                return step_stats(opt_state)
+
+            self._late_gauges.append([
+                step_program(tft_step_stats, (tx,))[0],
+                functools.partial(tx.publish_step_stats, self.metrics),
+                None])
 
     def init(self, params) -> Any:
         return self.tx.init(params)
 
+    def _publish_routing(self, values: Sequence[float]) -> None:
+        skew, share, fits = values
+        self.metrics.gauge("moe_load_max_over_mean", skew)
+        if share == share:      # NaN: the share held was not said
+            self.metrics.gauge("moe_held_share", share)
+            self.metrics.gauge("moe_row_buffer_share", fits)
+
     def _observe_routing(self, opt_state: Any) -> None:
         """Gauges ``moe_load_max_over_mean`` and (where the
         transformation was told the share held) ``moe_held_share`` and
-        ``moe_row_buffer_share`` of a committed step, without a wait: the
+        ``moe_row_buffer_share`` of a committed step, and those a
+        :func:`with_step_stats` transformation publishes, without a wait: a
         gauges' program is
         dispatched behind the step's and its host copy started; what a
         LATER commit finds ready it reads, and starts the next. A result
         the device has not reached yet stays pending and this step's is
         not asked for (the loop's host runs steps ahead of the chip)."""
-        if self._routing is None:
-            return
-        pending = self._routing_pending
-        if pending is not None:
-            if not pending.is_ready():
-                return
-            skew, share, fits = (float(v) for v in np.asarray(pending))
-            self.metrics.gauge("moe_load_max_over_mean", skew)
-            if share == share:      # NaN: the share held was not said
-                self.metrics.gauge("moe_held_share", share)
-                self.metrics.gauge("moe_row_buffer_share", fits)
-        self._routing_pending = self._routing(opt_state)
-        self._routing_pending.copy_to_host_async()
+        for late in self._late_gauges:
+            program, publish, pending = late
+            if pending is not None:
+                if not pending.is_ready():
+                    continue
+                publish([float(v) for v in np.asarray(pending)])
+            late[2] = program(opt_state)
+            late[2].copy_to_host_async()
 
     def _span(self, name: str) -> span:
         """One phase of this step: a timing in ``self.metrics`` and a
