@@ -1,11 +1,16 @@
-"""The three flash kernels alone at a cell's call shape (run by hand on the
-chip; PERF.md section 6, PRs 45 and 51): ``flash_fwd``, ``flash_dq`` and
-``flash_dkv`` of ``ops/flash.py``, ms a call each, with the grid steps a
-head the call takes and the rectangle of blocks would (``_grid_steps``).
+"""The flash kernels alone at a cell's call shape (run by hand on the
+chip; PERF.md section 6, PRs 45, 51 and 74): ``flash_fwd`` and the backward
+of ``ops/flash.py`` — ``flash_bwd``, a side's WHOLE backward: the one kernel
+where the shape takes it (``fused_backward``: ``flash._fuses_backward``),
+else ``flash_dq`` + ``flash_dkv`` in one program, which are then timed alone
+as well (what ``--parent``'s side, a tree before PR 74, reads) —, ms a call
+each, with the grid steps a head the call takes and the rectangle of blocks
+would (``_grid_steps``).
 
     python scripts/flash_micro.py
     python scripts/flash_micro.py --parent _scratch/parent/torchft_tpu/ops/flash.py
     python scripts/flash_micro.py --parent ... --cells streamed --chunks 1 2 4 8
+    python scripts/flash_micro.py --parent ... --cells fused --plain-tile
 
 ``--parent`` names a second ``ops/flash.py`` that is read in the same
 process: its kernels run on the same operands, turn about with this tree's,
@@ -15,7 +20,12 @@ around it. ``--cells`` picks the shapes (``_CELLS``: a cell's call as ``[BH,
 S, Dqk / Dv]``, bf16, the blocks the kernels choose from the shape; a name
 ending in ``-swa`` is under a window, in ``-unmasked`` without the mask;
 ``streamed`` stands for the nine calls of the seven cells whose K and V
-stream, ``_STREAMED``). ``chunk`` is the k tiles a grid step of the streamed
+stream, ``_STREAMED``, ``fused`` for the eleven streamed calls whose
+backward is ``flash_bwd``, ``_FUSED``, ``resident`` for the five cells'
+calls whose K and V stay in VMEM, ``_RESIDENT``). ``--plain-tile`` times ``flash_bwd`` again with
+its tile in the other orientation (``_plain_tile``, swapped in for
+``flash._bwd_tile``: the script's, not an option of the library) and holds
+its results to the library's. ``chunk`` is the k tiles a grid step of the streamed
 forward sweeps (``_choose_chunk``) and ``grid_steps_a_head.forward`` what its
 grid takes; ``--chunks`` times the forward again at each given chunk and
 holds its ``out`` and ``lse`` to the other side's bit for bit
@@ -49,7 +59,7 @@ import time
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
-_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "flash_bwd")
 
 # cell -> ((heads x rows, sequence, Dqk, Dv, causal, window) on the chip,
 # the same on the CPU with the regime's threshold last): there the same
@@ -82,6 +92,13 @@ _CELLS = {
                   (2, 512, 16, 32, True, None, 0)),
     "lfm2": ((128, 8192, 64, 64, True, None),
              (2, 512, 16, 16, True, None, None)),
+    # the other resident cells' calls (PR 74)
+    "c1p3b": ((128, 2048, 128, 128, True, None),
+              (2, 512, 32, 32, True, None, None)),
+    "olmoe": ((96, 4096, 128, 128, True, None),
+              (2, 512, 32, 32, True, None, None)),
+    "granite4h": ((64, 8192, 64, 64, True, None),
+                  (2, 512, 16, 16, True, None, None)),
     # the other streamed cells' calls (PR 60; kimi's is joyai's)
     "olmohybrid": ((30, 8192, 128, 128, True, None),
                    (2, 512, 32, 32, True, None, 0)),
@@ -89,11 +106,27 @@ _CELLS = {
                (2, 512, 32, 32, True, None, 0)),
     "laguna-swa": ((256, 8192, 128, 128, True, 512),
                    (2, 512, 32, 32, True, 128, 0)),
+    # PR 74: the two streamed cells no entry stood for (256-wide heads on
+    # 512 x 512 tiles; one sequence of sixteen heads)
+    "qwen3next": ((64, 8192, 256, 256, True, None),
+                  (2, 512, 64, 64, True, None, 0)),
+    "ouro": ((16, 8192, 128, 128, True, None),
+             (2, 512, 32, 32, True, None, 0)),
+    # no cell's: the longest call ``flash._fuses_backward`` admits at
+    # 128-wide heads, and the shortest it sends to the pair at 256
+    "long32k": ((28, 32768, 128, 128, True, None),
+                (2, 512, 32, 32, True, None, 0)),
+    "long32k-256": ((8, 32768, 256, 256, True, None),
+                    (2, 512, 64, 64, True, None, 0)),
 }
 
 # the calls of the seven cells whose K and V stream (PR 60)
 _STREAMED = ("joyai", "nemo3", "olmohybrid", "phi4flash", "phi4flash-swa",
              "smallthinker", "smallthinker-swa", "laguna", "laguna-swa")
+# every streamed call whose backward is ``flash_bwd`` (PR 74): those and the
+# two above; and the five cells' calls whose K and V are resident
+_FUSED = _STREAMED + ("qwen3next", "ouro")
+_RESIDENT = ("c111m", "c1p3b", "olmoe", "lfm2", "granite4h")
 
 # the four cells whose key/value heads serve several query heads (PR 55)
 _GROUPED = {
@@ -103,7 +136,34 @@ _GROUPED = {
     "lfm2-gqa": ("lfm2", 4, 4),
     "phi4flash-gqa": ("phi4flash", 2, 4),
     "phi4flash-swa-gqa": ("phi4flash-swa", 2, 4),
+    "qwen3next-gqa": ("qwen3next", 8, 4),
+    "laguna-gqa": ("laguna", 6, 4),
+    "laguna-swa-gqa": ("laguna-swa", 8, 4),
+    "long32k-gqa": ("long32k", 7, 1),
+    "granite4h-gqa": ("granite4h", 4, 2),
 }
+
+
+def _plain_tile(flash):
+    """``flash._bwd_tile`` in the OTHER orientation (``--plain-tile``): P and
+    dS ``[BQ, BK]`` on the statistics' columns, dq the plain product, dk
+    and dv each contracted over the tile's rows — two turns of a score tile
+    through the transpose unit where the library's tile takes one."""
+    import jax
+    import jax.numpy as jnp
+
+    def rows_contracted(a, b):
+        return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def tile(q, k, v, do, lse, delta, qi, ki, masked, window=None):
+        cols = flash._rows_to_cols(lse, delta)
+        p, ds = flash._bwd_p_ds(q, k, v, do, cols[:, :1], cols[:, 1:2], qi,
+                                ki, masked, window=window)
+        dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return dq, rows_contracted(ds, q), rows_contracted(p, do)
+    return tile
 
 
 def main() -> int:
@@ -116,6 +176,9 @@ def main() -> int:
     ap.add_argument("--chunks", nargs="*", type=int, default=[],
                     help="time the forward again at these k tiles a grid "
                     "step (the rule's is what every other figure is at)")
+    ap.add_argument("--plain-tile", action="store_true",
+                    help="time flash_bwd again with its tile in the other "
+                    "orientation (_plain_tile), held to the library's")
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args()
@@ -138,6 +201,10 @@ def main() -> int:
 
     out = {"device": jax.devices()[0].device_kind, "calls": args.calls}
 
+    def max_abs(a, b):
+        return float(jnp.max(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32))))
+
     def time_ms(fn, *a):
         jax.block_until_ready(fn(*a))
         seen = []
@@ -150,7 +217,8 @@ def main() -> int:
         return 1e3 * sorted(seen)[1]
 
     cells = [c for name in args.cells
-             for c in (_STREAMED if name == "streamed" else (name,))]
+             for c in {"streamed": _STREAMED, "fused": _FUSED,
+                       "resident": _RESIDENT}.get(name, (name,))]
     for cell in cells:
         base, group, batch = _GROUPED.get(cell, (cell, 1, 1))
         chip, cpu = _CELLS[base]
@@ -186,9 +254,11 @@ def main() -> int:
                     "rectangular": rectangular,
                     "forward": chunked if causal else rectangular // chunk}
 
+        fused = flash._fuses_backward(seq, dqk, 2, *blocks, dv, threshold)
         entry = {"q": [bh, seq, dqk], "v": [bh // group, seq, dv],
                  "blocks": blocks, "causal": causal, "window": window,
-                 "chunk": chunk, "grid_steps_a_head": steps_a_head(chunk)}
+                 "chunk": chunk, "grid_steps_a_head": steps_a_head(chunk),
+                 "fused_backward": fused}
 
         # the operands are arguments (a closed-over array is a constant of
         # the program); dq and dkv are two results of one builder, and the
@@ -202,11 +272,12 @@ def main() -> int:
             def backward(q, k, v, g, lse, delta):
                 return mod._flash_backward_core(q, k, v, g, lse, delta,
                                                 *common, window=window)
-            return {
-                "flash_fwd": jax.jit(forward),
-                "flash_dq": jax.jit(lambda *a: backward(*a)[0]),
-                "flash_dkv": jax.jit(lambda *a: backward(*a)[1:]),
-            }
+            fns = {"flash_fwd": jax.jit(forward),
+                   "flash_bwd": jax.jit(backward)}
+            if not (fused and hasattr(mod, "_fuses_backward")):
+                fns["flash_dq"] = jax.jit(lambda *a: backward(*a)[0])
+                fns["flash_dkv"] = jax.jit(lambda *a: backward(*a)[1:])
+            return fns
 
         def layer_call(repeated):
             """``value_and_grad`` of the model's call on ``[B, S, H, D]``
@@ -241,10 +312,9 @@ def main() -> int:
             delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                             axis=-1)
             statistics[side] = lse, delta
-            dk, dvv = fns["flash_dkv"](q, k_, v_, g, lse, delta)
-            results[side] = {"out": o, "lse": lse,
-                             "dq": fns["flash_dq"](q, k_, v_, g, lse, delta),
-                             "dk": dk, "dv": dvv}
+            dq, dk, dvv = fns["flash_bwd"](q, k_, v_, g, lse, delta)
+            results[side] = {"out": o, "lse": lse, "dq": dq, "dk": dk,
+                             "dv": dvv}
         if group > 1:
             entry["bit_for_bit"] = {
                 name: bool(jnp.array_equal(
@@ -252,15 +322,45 @@ def main() -> int:
                 for name in ("out", "lse", "dq")}
             # the copies' gradients, each rounded to bf16, summed in f32
             entry["max_abs_from_the_copies_sum"] = {
-                name: float(jnp.max(jnp.abs(
-                    results["grouped"][name].astype(jnp.float32)
-                    - results["repeated"][name].astype(jnp.float32).reshape(
-                        bh // group, group, seq, -1).sum(axis=1))))
+                name: max_abs(
+                    results["grouped"][name],
+                    results["repeated"][name].astype(jnp.float32).reshape(
+                        bh // group, group, seq, -1).sum(axis=1))
                 for name in ("dk", "dv")}
         elif "parent" in results:
             entry["bit_for_bit"] = {
                 name: bool(jnp.array_equal(a, results["parent"][name]))
                 for name, a in results["this"].items()}
+            entry["max_abs_from_the_parent"] = {
+                name: max_abs(a, results["parent"][name])
+                for name, a in results["this"].items()
+                if not entry["bit_for_bit"][name]}
+        if args.plain_tile and fused:
+            # the other orientation of the tile, on this tree's kernel
+            # and the first side's operands and statistics
+            first = next(iter(sides))
+            operands = (q, *sides[first][1:], g, *statistics[first])
+            library, flash._bwd_tile = flash._bwd_tile, _plain_tile(flash)
+            jax.clear_caches()
+            plain = jax.jit(
+                lambda *a: flash._flash_backward_core(
+                    *a, causal, scale, *blocks, not on_chip, threshold,
+                    window=window))
+            try:
+                got = plain(*operands)
+                entry["plain_tile"] = {"max_abs_from_the_library": {
+                    name: max_abs(a, results[first][name])
+                    for name, a in zip(("dq", "dk", "dv"), got)}}
+                del got
+                if on_chip:
+                    entry["plain_tile"]["flash_bwd_ms_a_call"] = time_ms(
+                        plain, *operands)
+            except Exception as e:     # Mosaic refuses the other tile
+                entry["plain_tile"] = {"refused": str(e)[-300:]}
+            finally:
+                flash._bwd_tile = library
+                jax.clear_caches()
+            del plain, operands
         # the forward again at other chunks: out and lse against the last
         # side's (the parent's, where one is given), then ms a call
         by_chunk = {}

@@ -1,6 +1,7 @@
 """Flash-attention kernel tests (interpret mode on CPU; the same kernel
 compiles for TPU)."""
 
+import contextlib
 import itertools
 
 import numpy as np
@@ -135,10 +136,15 @@ def test_fused_backward_matches_reference(causal) -> None:
         )
 
 
-def test_streamed_backward_matches() -> None:
-    # Long-context (streamed) regime now runs the k/q-streamed fused
-    # backward kernels; gradients must match the reference exactly.
+@pytest.mark.parametrize("backward", ["flash_bwd", "pair"])
+def test_streamed_backward_matches(backward, request) -> None:
+    # Long-context (streamed) regime: the one backward kernel, and the
+    # streamed dq + dkv kernels it falls back to where its accumulators do
+    # not fit; gradients must match the reference exactly.
     import torchft_tpu.ops.flash as flash_mod
+
+    if backward == "pair":
+        request.getfixturevalue("pair")
 
     old = flash_mod._RESIDENT_KV_BYTES
     flash_mod._RESIDENT_KV_BYTES = 0
@@ -200,6 +206,39 @@ def test_flash_shape_error_names_the_shape() -> None:
 
 _KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 _REGIMES = {"resident": None, "streamed": 0}
+# PR 74: a call's backward is ONE kernel in either regime wherever dk's and
+# dv's whole-head accumulators fit VMEM (``_fuses_backward``: every shape
+# this file runs); the pair is the rule's fallback, which the ``pair``
+# fixture forces
+_FUSED_KERNELS = ("flash_fwd", "flash_bwd")
+_KERNELS_OF = {"resident": _FUSED_KERNELS, "resident-pair": _KERNELS,
+               "streamed": _FUSED_KERNELS, "streamed-pair": _KERNELS}
+_MATMULS_A_TILE = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4,
+                   "flash_bwd": 5}
+
+
+@pytest.fixture
+def pair():
+    """The backward as ``flash_dq`` + ``flash_dkv``: what
+    ``_fuses_backward`` falls back to where dk's and dv's whole-head
+    accumulators do not fit, forced here by a limit nothing fits."""
+    with _pair_forced():
+        yield
+
+
+@contextlib.contextmanager
+def _pair_forced():
+    """:func:`pair` for a part of a test (or of a trace: the rule is asked
+    while a program is traced)."""
+    import torchft_tpu.ops.flash as flash_mod
+    from jax.experimental.pallas import tpu as pltpu
+
+    fits = flash_mod._FUSED_PARAMS
+    flash_mod._FUSED_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=0)
+    try:
+        yield
+    finally:
+        flash_mod._FUSED_PARAMS = fits
 
 
 def _eqns(jaxpr, kernel=None):
@@ -231,22 +270,26 @@ def _kernel_dots(jaxpr):
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize("regime", sorted(_REGIMES))
-@pytest.mark.parametrize("kernel", _KERNELS)
-def test_kernel_matmuls_are_f32_whatever_arrives(kernel, regime,
-                                                 dtype) -> None:
+@pytest.mark.parametrize("regime,kernel", [
+    (regime, kernel) for regime, kernels in sorted(_KERNELS_OF.items())
+    for kernel in kernels])
+def test_kernel_matmuls_are_f32_whatever_arrives(kernel, regime, dtype,
+                                                 request) -> None:
     # The dtype contract of the module docstring: operands are upcast as
     # they are loaded and every dot_general of every kernel is f32 x f32 ->
     # f32, for bf16 and for f32 inputs (on the v5e casting P / dS down to
     # bf16 first was measured slower: PERF.md, PR 24); results leave in the
     # input dtype. Unequal blocks: the loop body and the straight-line
-    # diagonal tiles are both in the jaxpr.
+    # diagonal tiles are both in the jaxpr. ``flash_bwd`` builds a tile's
+    # P and dS once: five matmuls where dq and dkv together run seven.
     q = jnp.zeros((1, 512, 2, 64), dtype)
+    if regime.endswith("-pair"):
+        request.getfixturevalue("pair")
 
     def loss(q, k, v):
         out = flash_attention(
             q, k, v, causal=True, block_q=256, block_k=128, interpret=True,
-            _resident_kv_bytes=_REGIMES[regime],
+            _resident_kv_bytes=_REGIMES[regime.split("-")[0]],
         )
         assert out.dtype == dtype
         return jnp.sum(out.astype(jnp.float32))
@@ -255,8 +298,8 @@ def test_kernel_matmuls_are_f32_whatever_arrives(kernel, regime,
     jaxpr = jax.make_jaxpr(grad)(q, q, q)
     assert all(g.dtype == dtype for g in jax.eval_shape(grad, q, q, q))
     dots = _kernel_dots(jaxpr.jaxpr)
-    assert set(dots) == set(_KERNELS)
-    per_tile = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}[kernel]
+    assert set(dots) == set(_KERNELS_OF[regime])
+    per_tile = _MATMULS_A_TILE[kernel]
     assert len(dots[kernel]) >= per_tile
     assert len(dots[kernel]) % per_tile == 0
     f32 = jnp.dtype(jnp.float32)
@@ -467,14 +510,22 @@ def test_tile_rule_takes_both_widths() -> None:
 # their three builders stand under a jit of their own (a row of this
 # call's tiles is one tile long, so the forward's body is the one-tile
 # body; test_the_chunked_body_is_one_tile_long holds the chunked one). The
-# resident call did not move.
+# resident call did not move. The two streamed calls regenerated in PR 74
+# (648e7a25... and 1c16c841... before): their backward is the one kernel
+# ``flash_bwd`` (dq, dk and dv bit for bit the pair's on the chip at every
+# streamed cell's call: scripts/flash_micro.py --parent), and the resident
+# call with them (4f629dc8... before): ``flash_bwd`` with K and V whole in
+# VMEM (dq bit for bit the resident ``flash_dq``'s on the chip at the five
+# resident cells' calls, dk and dv a column's tiles summed top to bottom
+# where the resident ``flash_dkv`` added the full ones first; those two
+# kernels went with it: the streamed pair is the one fallback).
 _EQUAL_WIDTH_JAXPR = {
     ("resident", True):
-        "4f629dc8e60f4c0d602d36f6df83c86843238c17e5c908829e96ccf433fd2874",
+        "0bcd473cb6b20a2ec87e307b749132528aaffef92bfaad1346a246ffa6a2013b",
     ("streamed", True):
-        "648e7a25fc67359fa19cdc9616554bb5dff4e9707c103665457f3ade36a822c1",
+        "e71b01e257097148f2cc4ecdfc264b44b39362fa3f632cc6fa4675c68764d9aa",
     ("streamed", False):
-        "1c16c841e7ba411fde1793b5cb3f2604e4348137bea450f0b2a4f355a042473b",
+        "1a58a125b93fd9db713476dd43d1fa3ca46528934c1193b308d70a5d63e67b9a",
 }
 
 
@@ -637,7 +688,8 @@ def test_causal_kernels_agree_across_regimes_bit_for_bit(
     # shapes stay and each sweep is one program, the resident side once.
     # A streamed causal grid is a table of live tiles, and an accumulator
     # meets them in the order the resident kernels' loops do, so out, lse
-    # and dq are the RESIDENT kernels' bit for bit. dk and dv are not: the
+    # and dq are the RESIDENT kernels' bit for bit. dk and dv were not while
+    # the resident regime had a dkv kernel of its own (until PR 74): the
     # resident column sweep adds its full tiles before its diagonal ones
     # and the streamed one runs a column top to bottom. Both regimes' dk
     # and dv are therefore held, bit for bit where the order is theirs, to
@@ -705,10 +757,14 @@ def test_causal_kernels_agree_across_regimes_bit_for_bit(
     resident = _RESIDENT[key]["backward"]
     dq, dk, dv_ = (resident if regime == "resident"
                    else backward(_REGIMES[regime], lse, delta))
+    # the backward is ``flash_bwd`` in both regimes (PR 74): one tile body,
+    # dkv's, a row's k tiles and a column's q blocks met ascending either
+    # way, so dq is the resident kernel's and dk and dv the table's order
+    # in BOTH (until PR 74 the resident regime had a dkv kernel of its own
+    # that added a column's full tiles before its diagonal ones)
     assert dq.dtype == resident[0].dtype and jnp.array_equal(dq, resident[0])
     want_dk, want_dv = _RESIDENT[key]["table"]
-    if regime == "streamed":
-        assert jnp.array_equal(dk, want_dk) and jnp.array_equal(dv_, want_dv)
+    assert jnp.array_equal(dk, want_dk) and jnp.array_equal(dv_, want_dv)
     # the resident order against the table's: rounding of the last place
     # of an f32 sum, before the cast to the operands' dtype
     for name, a, b in (("dk", dk, want_dk), ("dv", dv_, want_dv)):
@@ -1013,7 +1069,8 @@ def test_the_row_statistics_cross_hbm_lane_dense(regime, mask,
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
     calls = _flash_calls(jaxpr.jaxpr)
-    assert {k: len(c) for k, c in calls.items()} == dict.fromkeys(_KERNELS, 1)
+    assert {k: len(c) for k, c in calls.items()} == dict.fromkeys(
+        _KERNELS_OF[regime], 1)
     f32 = jnp.dtype(jnp.float32)
     for name, (eqn,) in calls.items():
         for var in (*eqn.invars, *eqn.outvars):
@@ -1021,42 +1078,54 @@ def test_the_row_statistics_cross_hbm_lane_dense(regime, mask,
             assert not (aval.dtype == f32 and aval.ndim and aval.shape[-1]
                         == 1), (name, aval)
     row = jax.core.ShapedArray((b * h, 1, s), f32)
-    (fwd,), (dq,), (dkv,) = (calls[k] for k in _KERNELS)
+    (fwd,), (dq,), *dkv = (calls[k] for k in _KERNELS_OF[regime])
     assert fwd.outvars[1].aval == row
-    # the very same two operands, not two equal views
-    assert dq.invars[-2:] == dkv.invars[-2:]
+    # the very same two operands, not two equal views (one kernel takes
+    # them where the backward is ``flash_bwd``)
+    for (other,) in dkv:
+        assert dq.invars[-2:] == other.invars[-2:]
     assert [x.aval for x in dq.invars[-2:]] == [row, row]
 
 
-@pytest.mark.parametrize("bh,seq_len,widths,window", [
-    (8, 2048, (64, 64), None),        # c111m's call: resident
-    (4, 8192, (192, 128), None),      # joyai's: streamed, two widths
-    (4, 16384, (128, 128), 4096),     # smallthinker's windowed call
+@pytest.mark.parametrize("bh,group,seq_len,widths,window", [
+    (8, 1, 2048, (64, 64), None),     # c111m's call: resident
+    (4, 1, 8192, (192, 128), None),   # joyai's: streamed, two widths
+    (4, 1, 16384, (128, 128), 4096),  # smallthinker's windowed call
     # PR 60: the forward's fullest chunk (eight tiles of K and V twice
     # over, 8.4 MB of Mosaic's 16) and the band's shortest
-    (4, 8192, (128, 128), None),      # nemo3's, olmohybrid's, laguna's
-    (4, 8192, (128, 128), 512),       # laguna's band
-], ids=["c111m", "joyai", "smallthinker-swa", "nemo3", "laguna-swa"])
+    (4, 1, 8192, (128, 128), None),   # nemo3's, olmohybrid's, laguna's
+    (4, 1, 8192, (128, 128), 512),    # laguna's band
+    # PR 74: ``flash_bwd``'s fullest calls — dk's and dv's accumulators and
+    # output blocks 32 MiB of its 96 —, each at its cell's group
+    (14, 7, 16384, (128, 128), None),     # smallthinker's full call
+    (16, 8, 8192, (256, 256), None),      # qwen3next's, 512 x 512 tiles
+], ids=["c111m", "joyai", "smallthinker-swa", "nemo3", "laguna-swa",
+        "smallthinker", "qwen3next"])
 def test_the_calls_compile_for_the_v5e_with_no_padded_statistic(
-        one_chip, bh, seq_len, widths, window) -> None:
+        one_chip, bh, group, seq_len, widths, window) -> None:
     """Mosaic takes the two turns of a statistic through the transpose
     unit at the cells' tiles (the interpreter takes anything), and the
-    program around the three kernels holds no ``f32[BH, S, 1]`` array in
+    program around the kernels holds no ``f32[BH, S, 1]`` array in
     any layout (PERF.md, PR 51: it held lse out of the forward, its slice's
-    operand and both of dq's operands so)."""
+    operand and both of dq's operands so). The backward is
+    ``flash_bwd``: Mosaic takes the product contracted over its left
+    operand's rows and the whole-head accumulators under the limit the
+    call passes (``_FUSED_PARAMS``)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from torchft_tpu.ops.flash import (
         _choose_blocks, _flash_backward_core, _flash_forward,
+        _fuses_backward,
     )
 
     dqk, dv = widths
     blocks = _choose_blocks(seq_len, dqk, 2, v_dim=dv, window=window)
     common = (True, 1.0 / dqk ** 0.5, *blocks, False, None)
+    assert _fuses_backward(seq_len, dqk, 2, *blocks, dv)
 
-    def sd(width):
+    def sd(width, heads=bh):
         return jax.ShapeDtypeStruct(
-            (bh, seq_len, width), jnp.bfloat16, sharding=one_chip)
+            (heads, seq_len, width), jnp.bfloat16, sharding=one_chip)
 
     def both(q, k, v, g):
         out, lse = _flash_forward(q, k, v, *common, window=window)
@@ -1069,11 +1138,12 @@ def test_the_calls_compile_for_the_v5e_with_no_padded_statistic(
     compilation_cache.reset_cache()
     try:
         text = jax.jit(both).lower(
-            sd(dqk), sd(dqk), sd(dv), sd(dv)).compile().as_text()
+            sd(dqk), sd(dqk, bh // group), sd(dv, bh // group),
+            sd(dv)).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    for kernel in _KERNELS:
+    for kernel in _FUSED_KERNELS:
         assert f"{kernel}/pallas_call" in text, kernel
     assert f"f32[{bh},1,{seq_len}]" in text
     assert f"f32[{bh},{seq_len},1]" not in text
@@ -1212,3 +1282,232 @@ def test_the_wrapper_counts_the_chunked_calls() -> None:
     # streamed, a tile a row (explicit blocks): the one-tile body
     assert trace(1024, _resident_kv_bytes=0, block_q=1024,
                  block_k=1024) == ((1, 0), (2, 1))
+
+
+# ------------------------------------------------------------ PR 74 contracts
+# A streamed call's backward is ONE kernel, ``flash_bwd``: (a) against the
+# pair it replaces and the f32 reference, (b) dk's and dv's accumulators
+# cleared and written once a group, (c) which calls take it, as a pure
+# function of the shape, (d) the counter. (Its matmuls a tile, its operands
+# and its compile for the v5e are cases of the tests above.)
+
+_FUSED_CASES = [
+    # (block_q, block_k, (Dqk, Dv), group) x mask x regime: latent
+    # attention's 192 / 128 on heads of their own under every mask,
+    # phi4flash's 64 / 128 in groups of four, and smallthinker's seven
+    # heads a key/value head; a streamed case of each shape, resident ones
+    # where the masks differ most
+    pytest.param(*shape, mask, regime, id="-".join(map(str, (
+        *shape[:2], *shape[2], f"g{shape[3]}", mask, regime))))
+    for shape, cases in (
+        ((64, 128, (48, 32), 1), (
+            ("causal", "streamed"), (96, "streamed"), (300, "streamed"),
+            ("unmasked", "streamed"), ("causal", "resident"),
+            ("unmasked", "resident"))),
+        ((128, 64, (16, 32), 4), (
+            ("causal", "streamed"), (300, "streamed"),
+            ("unmasked", "streamed"), (300, "resident"))),
+        ((64, 128, (32, 32), 7), (
+            ("causal", "streamed"), (96, "streamed"), (96, "resident"))))
+    for mask, regime in cases
+]
+
+
+@pytest.mark.parametrize("block_q,block_k,widths,group,mask,regime",
+                         _FUSED_CASES)
+def test_flash_bwd_against_the_pair_and_the_f32_reference(
+        block_q, block_k, widths, group, mask, regime) -> None:
+    """``flash_bwd`` at a few tiles (S 512: 8 x 4 or 4 x 8), bf16, under
+    the causal mask, a window shorter than a k tile of 128 keys (96), one
+    several k edges of 64 long (300) and no mask, in both regimes (the
+    pair is the streamed one in either: it is the fallback of both). At
+    equal head counts dk and dv are ``flash_dq`` + ``flash_dkv``'s BIT FOR
+    BIT — the tile is dkv's and a row-major sweep meets a column's q
+    blocks ascending — and dq a rounding of one f32 sum met in another
+    order apart. A group's dk and dv are the float32 sum over its heads in one rounding
+    either way, head-major here and a tile's heads together there: both
+    stand inside ``tests/test_flash_grouped.py``'s limit from the float32
+    reference's gradients summed over the copies, the one kernel no
+    further than the pair."""
+    from torchft_tpu.ops.flash import _flash_backward_core, _flash_forward
+
+    dqk, dv = widths
+    seq_len, kv = 512, 2
+    heads = kv * group
+    q, k, v, do = (_rand(shape, i + 74, jnp.bfloat16) for i, shape in
+                   enumerate(((heads, seq_len, dqk), (kv, seq_len, dqk),
+                              (kv, seq_len, dv), (heads, seq_len, dv))))
+    causal = mask != "unmasked"
+    window = mask if isinstance(mask, int) else None
+    scale = dqk ** -0.5
+
+    def reference(q, k, v, do):
+        """dq, dk, dv of the float32 reference on the copies, the
+        key/value gradients summed over them; [BH, S, D] is a batch of
+        one-head sequences."""
+        def loss(q, k, v):
+            out = reference_attention(
+                q[:, :, None], jnp.repeat(k, group, axis=0)[:, :, None],
+                jnp.repeat(v, group, axis=0)[:, :, None], causal=causal,
+                scale=scale, window=window)
+            return jnp.sum(out[:, :, 0] * do)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    @jax.jit
+    def everything(q, k, v, do):
+        common = (causal, scale, block_q, block_k, True, _REGIMES[regime])
+        out, lse = _flash_forward(q, k, v, *common, window=window)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+
+        def backward():
+            return _flash_backward_core(q, k, v, do, lse, delta, *common,
+                                        window=window)
+
+        one = backward()
+        with _pair_forced():        # the rule is asked while this traces
+            pair_ = backward()
+        return one, pair_, reference(
+            *(x.astype(jnp.float32) for x in (q, k, v, do)))
+
+    one, pair_, ref = everything(q, k, v, do)
+
+    def off(a, b):
+        return np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32))
+
+    for name, a, b, r in zip(("dq", "dk", "dv"), one, pair_, ref):
+        top = float(jnp.abs(r).max())
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape == r.shape
+        assert off(a, r).max() <= 0.02 * top, name
+        if group == 1 and name != "dq":
+            assert jnp.array_equal(a, b), name
+            continue
+        # the same f32 sums met in another order: a bf16 place of the
+        # largest element at most, and no further from the reference
+        assert off(a, b).max() <= 2 ** -7 * top, name
+        assert np.sqrt(np.mean(off(a, r) ** 2)) <= 1.001 * np.sqrt(
+            np.mean(off(b, r) ** 2)), name
+
+
+@pytest.mark.parametrize("group", [1, 4, 7])
+@pytest.mark.parametrize("window", [None, 96, 128, 450, 512, "unmasked"])
+@pytest.mark.parametrize("seq_len,block_q,block_k", [
+    (1024, 128, 256), (1024, 256, 128), (768, 384, 256), (768, 128, 128)])
+def test_dk_and_dv_are_cleared_and_written_once_a_group(
+        seq_len, block_q, block_k, window, group) -> None:
+    """``flash_bwd``'s grid, step by step, with the kernel's own
+    predicates: the leading axis over the query heads (a group's
+    consecutive), under it the live tiles row-major. Every column of
+    every key/value head is cleared exactly once, before its first term
+    — at ``_sweep_ends``' top q block of the group's first head —, and
+    written exactly once, after its last — at the bottom q block of the
+    group's last head —, whatever the mask, the window and the ratio of
+    the tile's edges."""
+    from torchft_tpu.ops.flash import _live_tiles, _sweep_ends
+
+    causal = window != "unmasked"
+    if not causal:
+        window = None
+    nq, nk = seq_len // block_q, seq_len // block_k
+    tiles = (list(zip(*_live_tiles(seq_len, block_q, block_k, True, window)))
+             if causal else [(qi, ki) for qi in range(nq) for ki in range(nk)])
+    kv_heads = 2
+    events = {}
+    for head in range(kv_heads * group):
+        for qi, ki in tiles:
+            top, bottom = ((int(e) for e in _sweep_ends(
+                ki, block_q, block_k, seq_len, False, window))
+                if causal else (0, nq - 1))
+            seen = events.setdefault((head // group, int(ki)), [])
+            if qi == top and head % group == 0:
+                seen.append("clear")
+            seen.append("add")
+            if qi == bottom and head % group == group - 1:
+                seen.append("write")
+    assert len(events) == kv_heads * nk      # no column without a tile
+    for column, seen in events.items():
+        assert seen[0] == "clear" and seen[-1] == "write", column
+        assert seen.count("clear") == seen.count("write") == 1, column
+        assert set(seen[1:-1]) == {"add"}, column
+
+
+@pytest.mark.parametrize("cell,seq_len,dqk,dv,window,fused", [
+    # the fourteen configurations that call the kernels (keye2 calls none):
+    # K and V of a head resident in five, streamed in nine
+    ("c111m", 2048, 64, 64, None, True),
+    ("c1p3b", 2048, 128, 128, None, True),
+    ("olmoe", 4096, 128, 128, None, True),
+    ("lfm2", 8192, 64, 64, None, True),
+    ("granite4h", 8192, 64, 64, None, True),
+    ("joyai", 8192, 192, 128, None, True),
+    ("kimi", 8192, 192, 128, None, True),
+    ("nemo3", 8192, 128, 128, None, True),
+    ("olmohybrid", 8192, 128, 128, None, True),
+    ("ouro", 8192, 128, 128, None, True),
+    ("laguna", 8192, 128, 128, None, True),
+    ("laguna-swa", 8192, 128, 128, 512, True),
+    ("phi4flash", 8192, 64, 128, None, True),
+    ("phi4flash-swa", 8192, 64, 128, 512, True),
+    ("smallthinker", 16384, 128, 128, None, True),
+    ("smallthinker-swa", 16384, 128, 128, 4096, True),
+    ("qwen3next", 8192, 256, 256, None, True),
+    # no cell's: the longest call the limit admits at 128-wide heads, and
+    # the shapes that keep flash_dq + flash_dkv
+    ("32k-128", 32768, 128, 128, None, True),
+    ("32k-256", 32768, 256, 256, None, False),
+    ("64k-128", 65536, 128, 128, None, False),
+])
+def test_the_fused_backward_rule_at_the_cells_calls(
+        cell, seq_len, dqk, dv, window, fused, monkeypatch) -> None:
+    """``_fuses_backward`` as a pure function of the shape: every call
+    whose two whole-head accumulators and output blocks fit, beside its
+    operands (K and V whole where they are resident), the limit
+    ``flash_bwd`` passes — every resident call, and the streamed ones up
+    to 32k x 128; one byte under the estimate a call falls back to the
+    pair."""
+    import torchft_tpu.ops.flash as flash_mod
+    from jax.experimental.pallas import tpu as pltpu
+
+    blocks = flash_mod._choose_blocks(seq_len, dqk, 2, v_dim=dv,
+                                      window=window)
+    shape = (seq_len, dqk, 2, *blocks, dv)
+    assert flash_mod._fuses_backward(*shape) is fused
+    assert flash_mod._fuses_backward(*shape) is fused
+    resident = flash_mod._resident(seq_len, dqk + dv, 2)
+    assert resident == (cell in ("c111m", "c1p3b", "olmoe", "lfm2",
+                                 "granite4h"))
+    estimate = flash_mod._fused_vmem_estimate(seq_len, dqk, dv, 2, *blocks,
+                                              resident)
+    limit = flash_mod._FUSED_PARAMS.vmem_limit_bytes
+    assert fused == (estimate <= limit)
+    # the whole-head arrays are the estimate's bulk: 8 S (Dqk + Dv) at
+    # bf16, and a resident call's K and V twice more
+    assert estimate > (8 + 4 * resident) * seq_len * (dqk + dv)
+    streamed = flash_mod._fused_vmem_estimate(seq_len, dqk, dv, 2, *blocks)
+    assert flash_mod._fuses_backward(*shape, 0) == (streamed <= limit)
+    monkeypatch.setattr(flash_mod, "_FUSED_PARAMS", pltpu.CompilerParams(
+        vmem_limit_bytes=estimate - 1))
+    assert not flash_mod._fuses_backward(*shape)
+    monkeypatch.setattr(flash_mod, "_FUSED_PARAMS", pltpu.CompilerParams(
+        vmem_limit_bytes=estimate))
+    assert flash_mod._fuses_backward(*shape)
+
+
+def test_the_wrapper_counts_the_fused_backwards(request) -> None:
+    names = ("flash_calls", "flash_calls_fused_bwd")
+    q = jnp.zeros((1, 1024, 2, 64), jnp.bfloat16)
+
+    def trace(**kw):
+        before = _traced_flash_calls(names)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, q, q, interpret=True, **kw).astype(jnp.float32))))(q)
+        return (tuple(_traced_flash_calls(names) - before),
+                sorted(_flash_calls(jaxpr.jaxpr)))
+
+    # the one kernel in either regime, counted where the call is traced
+    assert trace() == ((1, 1), sorted(_FUSED_KERNELS))
+    assert trace(_resident_kv_bytes=0) == ((1, 1), sorted(_FUSED_KERNELS))
+    # the accumulators over the limit: the pair, not counted
+    request.getfixturevalue("pair")
+    assert trace() == ((1, 0), sorted(_KERNELS))
+    assert trace(_resident_kv_bytes=0) == ((1, 0), sorted(_KERNELS))

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from test_flash import (
-    _KERNELS, _MASKS, _REGIMES, _eqns, _flash_calls, _rand,
+    _KERNELS_OF, _MASKS, _REGIMES, _eqns, _flash_calls, _rand,
     _traced_flash_calls,
 )
 from torchft_tpu.models.common import repeat_kv
@@ -122,10 +122,15 @@ def test_equal_head_counts_trace_to_the_grids_they_had(regime, mask) -> None:
     above hold two of these six programs to the letter): grids of the rank
     they had, two tables under the mask and none without, index maps that
     pass the leading index through and read a table — no division, no
-    multiply-add —, no scratch accumulator in the resident dkv. A grouped
-    call differs in exactly those: the key/value maps of the row sweeps
-    divide, and dkv takes one more grid axis, innermost, whose length is
-    the group."""
+    multiply-add. A grouped call differs in exactly that: the key/value
+    maps divide. The backward is ``flash_bwd`` (PR 74), a ROW sweep in
+    either regime: its grid is dq's whatever the group, its accumulators
+    (dk's and dv's a whole head long; dq's a q block where K and V stream)
+    are scratch at every group, and a grouped call differs in its
+    key/value maps alone — K's and V's blocks and dk's and dv's whole-head
+    output blocks at ``b // group``."""
+    kernels = _KERNELS_OF[regime]
+
     def calls(heads, kv_heads):
         q = jnp.zeros((1, 512, heads, 64), jnp.bfloat16)
         k = jnp.zeros((1, 512, kv_heads, 64), jnp.bfloat16)
@@ -136,33 +141,35 @@ def test_equal_head_counts_trace_to_the_grids_they_had(regime, mask) -> None:
             ).astype(jnp.float32)), argnums=(0, 1, 2)))(q, k, k)
         found = _flash_calls(jaxpr.jaxpr)
         assert {n: len(c) for n, c in found.items()} == dict.fromkeys(
-            _KERNELS, 1)
+            kernels, 1)
         return {n: c[0].params["grid_mapping"] for n, c in found.items()
                 }, {n: _index_map_primitives(c[0]) for n, c in found.items()}
 
     streamed_tables = 2 * (regime == "streamed" and mask != "unmasked")
     rank = 3 if (regime, mask) == ("streamed", "unmasked") else 2
     grids, primitives = calls(4, 4)
-    for name in _KERNELS:
+    for name in kernels:
         assert len(grids[name].grid) == rank, name
         assert grids[name].grid[0] == 4
         assert grids[name].num_index_operands == streamed_tables, name
         assert primitives[name] <= {"get"}, (name, primitives[name])
         assert bool(primitives[name]) == bool(streamed_tables)
     scratch = {n: g.num_scratch_operands for n, g in grids.items()}
-    assert scratch["flash_dkv"] == (2 if regime == "streamed" else 0)
+    assert scratch["flash_bwd"] == (3 if regime == "streamed" else 2)
 
     grouped, primitives = calls(4, 2)
-    for name in ("flash_fwd", "flash_dq"):
+    for name in kernels:
         assert grouped[name].grid == grids[name].grid, name
         assert primitives[name] - {"get"}, name          # b // group
-    assert grouped["flash_dkv"].grid == (
-        2, *grids["flash_dkv"].grid[1:], 2)
-    assert grouped["flash_dkv"].num_index_operands == streamed_tables
-    assert grouped["flash_dkv"].num_scratch_operands == 2
+    # dk and dv leave KV heads wide, a whole head an output block
+    assert [(out.shape, tuple(edge.block_size for edge in m.block_shape))
+            for out, m in zip(
+                grouped["flash_bwd"].out_shapes,
+                grouped["flash_bwd"].block_mappings_output)] == [
+        ((4, 512, 64), (1, 128, 64)), ((2, 512, 64), (1, 512, 64)),
+        ((2, 512, 64), (1, 512, 64))]
     assert {n: g.num_scratch_operands for n, g in grouped.items()
-            if n != "flash_dkv"} == {n: c for n, c in scratch.items()
-                                     if n != "flash_dkv"}
+            } == scratch
 
 
 def test_the_wrapper_counts_the_calls_it_traces() -> None:

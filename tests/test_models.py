@@ -332,17 +332,23 @@ def _zoo(name):
     }[name]()
 
 
+@pytest.mark.parametrize("regime", ["resident", "streamed"])
 @pytest.mark.parametrize("model", [
     "smallthinker", "lfm2", "nemotron_h", "phi4flash", "llama",
     "transformer", "olmoe", "joyai"])
-def test_a_models_program_holds_no_copy_of_k_or_v(model) -> None:
+def test_a_models_program_holds_no_copy_of_k_or_v(model, regime) -> None:
     """The gradient program of every model whose key/value heads serve
     several query heads, traced as a TPU traces it (the flash kernels,
     here through the interpreter; nothing runs): every flash kernel is
     handed K and V ``B · KV`` rows tall — there is no ``[B, S, H, D]`` copy
     to hand it — and ``flash_calls_grouped`` counts every call; the models
-    of equal head counts engage none of it."""
-    from test_flash import _flash_calls, _traced_flash_calls
+    of equal head counts engage none of it. Where K and V stream (the
+    long-context cells' regime, here by the call's own threshold) the
+    backward is the one kernel ``flash_bwd`` (PR 74), handed the same K
+    and V."""
+    from test_flash import (
+        _KERNELS_OF, _REGIMES, _flash_calls, _traced_flash_calls,
+    )
     from torchft_tpu.ops.flash import flash_attention
 
     loss, init, cfg, kv_heads = _zoo(model)
@@ -351,7 +357,8 @@ def test_a_models_program_holds_no_copy_of_k_or_v(model) -> None:
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
 
     def attn_fn(q, k, v, window=None):
-        return flash_attention(q, k, v, window=window, interpret=True)
+        return flash_attention(q, k, v, window=window, interpret=True,
+                               _resident_kv_bytes=_REGIMES[regime])
 
     before = _traced_flash_calls()
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -360,7 +367,7 @@ def test_a_models_program_holds_no_copy_of_k_or_v(model) -> None:
     assert calls > 0
     assert grouped == (calls if kv_heads else 0)
     found = _flash_calls(jaxpr.jaxpr)
-    assert set(found) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    assert set(found) == set(_KERNELS_OF[regime])
     for name, eqns in found.items():
         for eqn in eqns:
             tables = eqn.params["grid_mapping"].num_index_operands

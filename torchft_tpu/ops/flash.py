@@ -1,14 +1,16 @@
-"""Pallas flash-attention kernels for TPU: forward, dQ and dK/dV.
+"""Pallas flash-attention kernels for TPU: forward and backward.
 
 Forward: K/V tiles go through VMEM with an online-softmax accumulator, so
 the [S, S] score matrix never materializes in HBM. Backward:
 FlashAttention-2 style — residuals are (q, k, v, out, lse); delta =
-rowsum(dO·O) is a cheap XLA reduce; ``flash_dq`` sweeps k-tiles per
-q-tile and ``flash_dkv`` sweeps q-tiles per k-tile, recomputing P =
-exp(S − lse) tile by tile. Two regimes of each: resident kernels hold K/V
-(resp. Q/dO) whole in VMEM up to ``_RESIDENT_KV_BYTES`` and loop over
-tiles inside the kernel; streamed kernels ride the tiles over the
-innermost grid dimension with VMEM scratch accumulators (long context).
+rowsum(dO·O) is a cheap XLA reduce; P = exp(S − lse) is recomputed tile
+by tile. Two regimes: resident kernels hold K/V whole in VMEM up to
+``_RESIDENT_KV_BYTES`` and loop over tiles inside the kernel; streamed
+kernels ride the tiles over the innermost grid dimension with VMEM
+scratch accumulators (long context). In both the backward is ONE kernel,
+``flash_bwd`` (below: "One backward kernel"); ``flash_dq``, which sweeps
+k-tiles per q-tile, and ``flash_dkv``, q-tiles per k-tile, are what a call
+too long for it falls back to.
 
 Two widths. q and k are ``Dqk`` wide, v (and out, dO, dv) ``Dv``: one
 for the GPT and OLMoE families, 192 and 128 for latent attention
@@ -26,31 +28,31 @@ Which head. k and v have ``KV`` heads where q has ``H = KV · group``, and
 query head ``i`` reads key/value head ``i // group`` — WHERE IT LIES: in
 the merged ``[B·H, S, D]`` / ``[B·KV, S, D]`` layout row ``b`` of q reads
 row ``b // group`` of k and v, and that division stands in the K and V
-index maps of ``flash_fwd`` and ``flash_dq`` (``_heads_of``), whose
-bodies, tiles and grids are what they are for one head a head. No copy of
-a key/value head a query head exists in HBM, before or after a kernel
-(until PR 55 the models repeated K and V ``group`` times, XLA laid the
-copies out twice, ``flash_dkv`` wrote dK and dV ``H`` heads wide and a
-``group``-way sum followed: ≈ 20 H-wide arrays a layer-step, 235 MB each
-at 28 heads of 16k). In the resident regime consecutive heads of a group
-keep the K / V block index and Pallas does not fetch the block again.
-``flash_dkv``'s grid leads over the ``B·KV`` key/value heads and takes one
-more axis, INNERMOST, over the group's query heads: a column's sweep (the
-resident kernel's loop, a streamed tile) runs for head ``b · group + g``
-at step ``g``, the K / V block stays where it is, and dk and dv
-accumulate in float32 scratch across the whole group, cleared at the
-column's first tile of head 0 and written ONCE, at its last tile of head
-``group − 1`` (``_at_group_head``): one rounding of the group's sum,
-where the copies' gradients were each rounded and then summed. Under the
-mask the enumeration stays key-block-major with the group inside a tile,
-so an accumulator is live for one column at a time and nothing
-``group``-wide is resident. ``group == 1`` — every MHA call, latent
-attention, the ring — traces to the program it always traced to: no ``//
-1`` in a map, no extra axis, no scratch in the resident dkv (the rule the
-``window=None`` path and the ``Dqk == Dv`` zeros follow; ``tests/
-test_flash.py`` pins the jaxprs). Measured on the v5e (PERF.md, PR 55):
-the three kernels take the same time grouped as on the copies, within
-0.5 %, at 7, 16, 4 and 2 heads a key/value head.
+index maps of ``flash_fwd``, ``flash_bwd`` and ``flash_dq``
+(``_heads_of``), whose bodies, tiles and grids are what they are for one
+head a head. No copy of a key/value head a query head exists in HBM,
+before or after a kernel (until PR 55 the models repeated K and V
+``group`` times, XLA laid the copies out twice, ``flash_dkv`` wrote dK and
+dV ``H`` heads wide and a ``group``-way sum followed: ≈ 20 H-wide arrays a
+layer-step, 235 MB each at 28 heads of 16k). In the resident regime
+consecutive heads of a group keep the K / V block index and Pallas does
+not fetch the block again. dk and dv are the float32 sum over the whole
+group, rounded ONCE, where the copies' gradients were each rounded and
+then summed (``_at_group_head``): ``flash_bwd`` meets a group's query
+heads one after the other on its leading axis and keeps the sums in its
+whole-head accumulators ("One backward kernel" below); the fallback's
+``flash_dkv`` leads over the ``B·KV`` key/value heads and takes one more
+grid axis, INNERMOST, over the group's query heads — a column's tile runs
+for head ``b · group + g`` at step ``g``, the K / V block stays where it
+is, and its two ``[BK, D]`` scratch accumulators are cleared at the
+column's first tile of head 0 and written at its last tile of head
+``group − 1``. ``group == 1`` — every MHA call, latent attention, the
+ring — traces to a program with no ``// 1`` in a map and no extra axis
+(the rule the ``window=None`` path and the ``Dqk == Dv`` zeros follow;
+``tests/test_flash.py`` pins the jaxprs). Measured on the v5e (PERF.md,
+PR 55): the kernels take the same time grouped as on the copies, within
+0.5 %, at 7, 16, 4 and 2 heads a key/value head (PR 74: ``flash_bwd`` 0.1
+- 1.3 ms a call faster on K and V where they lie).
 
 What is which dtype. q, k, v, dO arrive and out, dq, dk, dv leave in the
 input dtype (bf16 in the models). Inside a kernel every operand is upcast
@@ -102,6 +104,53 @@ dK/dV recomputes its tile TRANSPOSED (Sᵀ = K·Qᵀ, [BK, BQ]): Pᵀ·dO and
 dSᵀ·Q are then plain matmuls and no [BQ, BK] tile goes through the
 transpose unit (``flash_dkv`` -20 % at 64-wide, -26 % at 128-wide heads).
 
+One backward kernel. ``flash_dq`` runs S, dP and dS·K — three matmuls a
+live tile — and ``flash_dkv`` Sᵀ, dPᵀ, dSᵀ·Q and Pᵀ·dO: seven, of which
+the second score tile and the second dP are time and not work, and the
+exp, the mask and ``p * (dp - delta)`` over the f32 tile are made twice
+as well; both kernels are MXU-bound on what they execute (PERF.md §7).
+The backward is therefore ``flash_bwd``: the tile built once,
+as dkv builds it (``_bwd_tile``: transposed, the statistics as the [1, BQ]
+rows HBM holds, so no column form of them is made at all), dk and dv its
+two plain products and dq the ONE product whose left operand is
+contracted over its rows, ``(dSᵀ)ᵀ·K`` — five matmuls and one [BK, BQ]
+turn through the transpose unit. The other orientation (P and dS [BQ, BK],
+dq plain, dk and dv each contracted over rows: two turns) read 1.4 – 6.8 %
+slower at every streamed cell's call (PERF.md, PR 74). The grid is dq's
+ROW sweep in either regime (streamed: its live-tile tables as they are,
+dq in scratch across a row as before; resident: dq the carry of the q
+block's loop over its row, ``_sweep``), and dk and dv accumulate in two
+``[S, D]`` f32 accumulators that stay in VMEM for the whole sweep of one
+key/value head, a tile adding into rows ``ki * block_k`` and on. The query heads of a group are
+consecutive on the leading grid axis, so the accumulators see the whole
+group: a column of tiles has its rows cleared at its first tile of the
+group's first head and rounded once, into the head's whole-``[S, D]``
+output block (index ``b // group``: it leaves VMEM when the leading index
+moves on), at its last tile of the group's last (the resident body, whose
+grid steps are q blocks, clears all rows at the first and writes all at
+the last). A row-major sweep meets a
+column's q blocks ascending, as the column sweep did, and the tile's
+arithmetic is dkv's own: at equal head counts dq, dk and dv are the pair's
+BIT FOR BIT on the v5e at every streamed cell's call (the interpreter's
+dq differs in f32's last place: its turned product sums in another
+order); a group's dk and dv sum head-major where the pair's summed a tile's
+heads together — f32's last place, before the one rounding. The
+accumulators and the output blocks are ``8 · S · (Dqk + Dv)`` bytes at
+bf16 (20 MiB at 8 192 x (192 + 128), 32 MiB at 16 384 x 256), so the call
+passes ``_FUSED_PARAMS``' ``vmem_limit_bytes``, and ``_fuses_backward`` —
+a pure function of the shape — sends a call whose
+``_fused_vmem_estimate`` does not fit it (32k x 256 and beyond; no call
+with resident K and V) to ``flash_dq`` + ``flash_dkv``, whose VMEM does
+not grow with the sequence. Measured on the v5e (PERF.md, PR 74): the
+backward alone 84.2 -> 62.6 ms a call at [128, 8192, 192 | 128], 84.5 ->
+60.7 at [56, 16384, 128], 27.1 -> 19.4 under W 512 at [256, 8192, 128] —
+x 0.71 - 0.76, the count of matmuls —, and with K and V resident 7.39 ->
+5.69 at [192, 2048, 64], 4.14 -> 2.75 at [128, 2048, 128], 52.8 -> 36.6 at
+[128 | 32, 8192, 64]: x 0.67 - 0.77 (at 64-wide heads the tile's vector
+work, made once, is worth as much as the matmuls); dq bit for bit there
+too, dk and dv f32's last place from the resident ``flash_dkv``'s, which
+adds a column's full tiles before its diagonal ones.
+
 Tiles. ``block_q`` / ``block_k`` default to ``None``: ``_choose_blocks``
 picks them from (S, D, itemsize) alone — the largest of 512 / 256 / 128
 that divides S with ``_vmem_estimate`` <= ``_VMEM_BUDGET``. A tile's cost
@@ -140,12 +189,12 @@ mask leaves a full tile's scores as they are), the unmasked body
 otherwise. Every live tile is computed by ``_fwd_tile`` in the order it
 was always met, so ``out`` and ``lse`` are bit for bit what one tile a
 step gave. ``n`` is ``_choose_chunk``'s, a pure function of the shape
-(``_CHUNK_LADDER``); at ``n == 1`` the body is the one-tile body. dq and
-dkv keep one tile a step: their readings moved 1 - 3 % when they swept
-chunks (PERF.md, PR 58), under what a second and third longer body cost
-every run to trace. The three streamed builders stand under a
-``jax.jit`` of their own, so a body is traced and lowered once a shape
-and process, not at each call site (``_flash_forward_streamed``).
+(``_CHUNK_LADDER``); at ``n == 1`` the body is the one-tile body. The
+backward keeps one tile a step: dq's and dkv's readings moved 1 - 3 % when
+they swept chunks (PERF.md, PR 58), under what longer bodies cost every
+run to trace. The streamed builders stand under a ``jax.jit`` of their
+own, so a body is traced and lowered once a shape and process, not at
+each call site (``_flash_forward_streamed``).
 
 Mosaic layout note: per-row statistics (lse, delta) are [BH, S] f32 in
 HBM and reach EVERY kernel as the one view of that array whose minor
@@ -161,12 +210,11 @@ planned 15.04 GiB (PR 47); 335 MB a call at 80 heads of 8k, written by
 the forward, read back by XLA to slice it, written twice more for dq
 (PR 50); 1.86 GiB of ``c111m``'s peak and 2 % of its step (PR 51). So the
 [BQ, 1] column lives in VMEM only: the forward turns ``m + log(l)`` into
-its [1, BQ] row once a q block (``_wide_to_row``), dq turns both rows into
-columns once a q block (``_rows_to_cols``: at the top of the resident
-kernel, into scratch at the first step of a streamed sweep), and dkv,
-whose transposed tile has q positions along its columns, reads the rows
-as they are. Both turns go through the transpose unit as a 128-lane-wide
-copy: exact, where an identity product at the MXU's default precision
+its [1, BQ] row once a q block (``_wide_to_row``), the fallback's dq turns
+both rows into columns once a q block (``_rows_to_cols``: into scratch at
+the first step of its sweep), and ``flash_bwd`` and dkv, whose transposed
+tile has q positions along its columns, read the rows as they are. Both turns go through the transpose unit as a
+128-lane-wide copy: exact, where an identity product at the MXU's default precision
 would round lse to bf16. Every result is bit for bit what the column
 layout gave (``scripts/flash_micro.py --parent``).
 
@@ -234,9 +282,9 @@ def _causal_sweep(idx, block_q: int, block_k: int, seq_len: int,
     ``(lo, hi)``: the closed forms of :func:`_tile_full` and
     :func:`_tile_live` along a row of tiles (``rows``: ``idx`` is the q
     block, the loop runs over k blocks — forward and dq) or along a column
-    (``idx`` is the k block, the loop runs over q blocks — dkv). One
-    definition, so the three resident kernels cannot disagree on which
-    tiles carry the mask."""
+    (``idx`` is the k block, the loop runs over q blocks — dkv, and the
+    ends of a column in ``flash_bwd``). One definition, so no two kernels
+    can disagree on which tiles carry the mask."""
     if rows:
         full_end = (idx * block_q + 1) // block_k
         live_end = jnp.minimum(
@@ -782,7 +830,7 @@ def _forward_out_shapes(q, dv: int):
 # The streamed builders stand under a jit of their own: a body is traced
 # and lowered once a shape and process, not at each call site of each
 # program a run traces (the models' layers are Python loops under a
-# ``jax.checkpoint`` a layer: two forwards, a dq and a dkv a layer of the
+# ``jax.checkpoint`` a layer: two forwards and a backward a layer of the
 # step's program, and the reference check's and the micro's programs
 # again; ``ops/kda.py::_forward`` is the precedent). The resident branches
 # stay outside it: the program of every call with resident K and V is
@@ -841,10 +889,12 @@ def _flash_forward_streamed(q, k, v, causal: bool, scale: float,
 
 # ------------------------------------------------------------- backward pass
 # FlashAttention-2 style fused backward: residuals are (q, k, v, out, lse);
-# delta = rowsum(dO * O) is a cheap XLA elementwise+reduce; two kernels
-# recompute P = exp(S - lse) tile-by-tile — dQ sweeps k-blocks per q-block,
-# dK/dV sweeps q-blocks per k-block. Nothing [S, S]-shaped ever
-# materializes in HBM in either direction.
+# delta = rowsum(dO * O) is a cheap XLA elementwise+reduce; P = exp(S - lse)
+# is recomputed tile-by-tile — by ONE kernel (``flash_bwd``: dQ's sweep of
+# k-blocks per q-block, dK and dV summed in whole-head accumulators), or,
+# where those do not fit VMEM, by two (dQ's sweep, and dK/dV's of q-blocks
+# per k-block). Nothing [S, S]-shaped ever materializes in HBM in either
+# direction.
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, qi, ki, masked: bool,
@@ -876,13 +926,13 @@ def _dq_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
     )
 
 
-def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
-              window: Optional[int] = None):
-    """This tile's terms of (dK, dV): dSᵀ·Q (q carries the scale, so this
-    is dL/dK itself) and Pᵀ·dO, f32 [BK, D]. The tile is recomputed
-    TRANSPOSED (lse and delta arrive as [1, BQ] rows), so both products
-    are plain row-by-column matmuls and nothing [BQ, BK]-shaped goes
-    through the transpose unit."""
+def _turned_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
+                 window: Optional[int] = None):
+    """``(dSᵀ, dK term, dV term)`` of one tile recomputed TRANSPOSED (lse
+    and delta arrive as [1, BQ] rows): dSᵀ·Q (q carries the scale, so this
+    is dL/dK itself) and Pᵀ·dO, f32 [BK, D], are then plain row-by-column
+    matmuls and nothing [BQ, BK]-shaped goes through the transpose
+    unit."""
     pt, dst = _bwd_p_ds(
         q, k, v, do, lse, delta, qi, ki, masked, transposed=True,
         window=window
@@ -895,24 +945,90 @@ def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
         pt, do, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    return dk, dv
+    return dst, dk, dv
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_q: int, block_k: int,
-                         seq_len: int, causal: bool, scale: float,
-                         window: Optional[int] = None):
+def _dkv_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
+              window: Optional[int] = None):
+    """This tile's terms of (dK, dV), f32 [BK, D]: :func:`_turned_tile`'s
+    two products."""
+    return _turned_tile(q, k, v, do, lse, delta, qi, ki, masked, window)[1:]
+
+
+def _bwd_tile(q, k, v, do, lse, delta, qi, ki, masked: bool,
+              window: Optional[int] = None):
+    """This tile's terms of (dQ/scale, dK, dV) from ONE Pᵀ and dSᵀ: the
+    tile as dkv builds it (:func:`_turned_tile`), its two plain products,
+    and dS·K as the one product whose left operand is contracted over its
+    rows — five matmuls and one [BK, BQ] turn through the transpose unit,
+    where the pair of kernels runs seven and builds the tile twice."""
+    dst, dk, dv = _turned_tile(q, k, v, do, lse, delta, qi, ki, masked,
+                               window)
+    dq = jax.lax.dot_general(
+        dst, k, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dq, dk, dv
+
+
+def _at_group_head(edge, head: int, group: int, at):
+    """``edge`` — where ONE head's sweep of a column of tiles starts (ends)
+    — narrowed to where dk's and dv's accumulators are cleared (written
+    out). A key/value head serves a ``group`` of query heads and the step
+    is at head ``at()`` of them (the innermost grid axis of ``flash_dkv``;
+    the leading index modulo the group in ``flash_bwd``, whose leading
+    axis runs over the query heads): dk and dv are the float32 sum over
+    the whole group, cleared at head 0's first tile and rounded once, at
+    head ``group - 1``'s last."""
+    if group == 1:
+        return edge
+    return edge & (at() == head)
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                      block_q: int, block_k: int, seq_len: int, causal: bool,
+                      scale: float, window: Optional[int] = None,
+                      group: int = 1):
+    """The whole backward with K and V resident: dq's sweep of a q block's
+    row of tiles (:func:`_sweep`, dq the loop's carry), each tile built
+    ONCE (:func:`_bwd_tile`) and its dk and dv terms added into rows ``ki
+    * block_k`` and on of two ``[S, D]`` f32 accumulators — cleared at the
+    first q block of a group's first query head, rounded into the
+    key/value head's whole output blocks at the last q block of its last
+    (:func:`_at_group_head`)."""
     qi = pl.program_id(1)
     q = _f32(q_ref[0]) * scale                    # [BQ, Dqk]
     do = _f32(do_ref[0])                          # [BQ, Dv]
-    cols = _rows_to_cols(lse_ref[0], delta_ref[0])
-    lse, delta = cols[:, :1], cols[:, 1:2]        # [BQ, 1] each
+    lse, delta = lse_ref[0], delta_ref[0]         # [1, BQ] rows, as HBM's
+
+    def at():
+        return pl.program_id(0) % group
+
+    def every_k_block(fn):
+        def body(ki, _):
+            fn(pl.ds(pl.multiple_of(ki * block_k, block_k), block_k))
+            return 0
+        jax.lax.fori_loop(0, seq_len // block_k, body, 0)
+
+    @pl.when(_at_group_head(qi == 0, 0, group, at))
+    def _clear():
+        def clear(rows):
+            dk_acc[rows, :] = jnp.zeros((block_k, dk_acc.shape[1]),
+                                        jnp.float32)
+            dv_acc[rows, :] = jnp.zeros((block_k, dv_acc.shape[1]),
+                                        jnp.float32)
+        every_k_block(clear)
 
     def tile(ki, dq, masked):
-        k = _f32(k_ref[0, pl.ds(ki * block_k, block_k), :])
-        v = _f32(v_ref[0, pl.ds(ki * block_k, block_k), :])
-        return dq + _dq_tile(q, k, v, do, lse, delta, qi, ki, masked,
-                             window=window)
+        rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        dq_term, dk, dv = _bwd_tile(
+            q, _f32(k_ref[0, rows, :]), _f32(v_ref[0, rows, :]), do, lse,
+            delta, qi, ki, masked, window=window,
+        )
+        dk_acc[rows, :] = dk_acc[rows, :] + dk
+        dv_acc[rows, :] = dv_acc[rows, :] + dv
+        return dq + dq_term
 
     dq = _sweep(
         qi, block_q, block_k, seq_len, causal, True, tile,
@@ -920,65 +1036,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     )
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
-
-def _at_group_head(edge, head: int, axis: int, group: int):
-    """``edge`` — where ONE head's sweep of a dkv column starts (ends) —
-    narrowed to where the accumulators are cleared (written out). A
-    key/value head that serves a ``group`` of query heads meets them on
-    grid axis ``axis``, the innermost: dk and dv are the float32 sum over
-    the whole group, cleared at head 0's first tile and rounded once, at
-    head ``group - 1``'s last."""
-    if group == 1:
-        return edge
-    return edge & (pl.program_id(axis) == head)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *acc_refs, block_q: int,
-                          block_k: int, seq_len: int, causal: bool,
-                          scale: float, window: Optional[int] = None,
-                          group: int = 1):
-    ki = pl.program_id(1)
-    k = _f32(k_ref[0])                            # [BK, Dqk]
-    v = _f32(v_ref[0])                            # [BK, Dv]
-
-    def tile(qi, carry, masked):
-        rows = pl.ds(qi * block_q, block_q)
-        dk, dv = _dkv_tile(
-            _f32(q_ref[0, rows, :]) * scale, k, v, _f32(do_ref[0, rows, :]),
-            lse_ref[0, :, rows], delta_ref[0, :, rows],   # [1, BQ]
-            qi, ki, masked, window=window,
-        )
-        return carry[0] + dk, carry[1] + dv
-
-    zeros = jnp.zeros(k.shape, dtype=jnp.float32)
-    dk, dv = _sweep(
-        ki, block_q, block_k, seq_len, causal, False, tile,
-        # one zeros for both where the widths are one: the program of
-        # every call with Dqk == Dv stays what it was, to the instruction
-        (zeros, zeros if v.shape == k.shape
-         else jnp.zeros(v.shape, dtype=jnp.float32)),
-        window=window,
-    )
-    if group == 1:
-        dk_ref[0] = dk.astype(dk_ref.dtype)
-        dv_ref[0] = dv.astype(dv_ref.dtype)
-        return
-    # one head of the group a grid step, this column's sums in scratch
-    dk_acc, dv_acc = acc_refs
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    dk_acc[...] = dk_acc[...] + dk
-    dv_acc[...] = dv_acc[...] + dv
-
-    @pl.when(pl.program_id(2) == group - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    @pl.when(_at_group_head(qi == seq_len // block_q - 1, group - 1, group,
+                            at))
+    def _write():
+        def write(rows):
+            dk_ref[0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+        every_k_block(write)
 
 
 def _flash_bwd_dq_streamed_kernel(*refs, block_q: int, block_k: int,
@@ -1027,9 +1091,9 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
     )
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
      dv_acc) = refs
-    heads_axis = 2 if causal else 3
+    at = functools.partial(pl.program_id, 2 if causal else 3)
 
-    @pl.when(_at_group_head(qi == first, 0, heads_axis, group))
+    @pl.when(_at_group_head(qi == first, 0, group, at))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -1046,26 +1110,133 @@ def _flash_bwd_dkv_streamed_kernel(*refs, block_q: int, block_k: int,
     _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
                     window=window)
 
-    @pl.when(_at_group_head(qi == last, group - 1, heads_axis, group))
+    @pl.when(_at_group_head(qi == last, group - 1, group, at))
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _flash_bwd_streamed_kernel(*refs, block_q: int, block_k: int,
+                               seq_len: int, causal: bool, scale: float,
+                               window: Optional[int] = None, group: int = 1):
+    """The whole streamed backward in one kernel: dq's row sweep (K / V
+    tiles on the innermost grid dimension, dq in VMEM scratch across a
+    row), each live tile built ONCE (:func:`_bwd_tile`) and its dk and dv
+    terms added into rows ``ki * block_k`` and on of two ``[S, D]`` f32
+    accumulators that stay in VMEM for the whole sweep of a key/value
+    head — its ``group`` query heads are consecutive on the leading grid
+    axis. A column of tiles is met q blocks ascending, as ``flash_dkv``'s
+    column sweep met it: its rows are cleared at its first tile of the
+    group's first head and rounded ONCE, into the head's whole ``[S, D]``
+    output block, at its last tile of the group's last
+    (:func:`_at_group_head`); the block leaves VMEM when the leading
+    index moves to the next key/value head."""
+    qi, ki, first, last, refs = _streamed_tile(
+        refs, block_q, block_k, seq_len, causal, True, window=window
+    )
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+     dq_acc, dk_acc, dv_acc) = refs
+    # the column's ends: the q blocks whose tiles hold key block ``ki``
+    top, bottom = (
+        _sweep_ends(ki, block_q, block_k, seq_len, False, window=window)
+        if causal else (0, seq_len // block_q - 1))
+    cols = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+
+    def at():
+        return pl.program_id(0) % group
+
+    @pl.when(ki == first)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_at_group_head(qi == top, 0, group, at))
+    def _clear():
+        dk_acc[cols, :] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
+        dv_acc[cols, :] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
+
+    def _accumulate(masked):
+        dq, dk, dv = _bwd_tile(
+            _f32(q_ref[0]) * scale, _f32(k_ref[0]), _f32(v_ref[0]),
+            _f32(do_ref[0]), lse_ref[0], delta_ref[0], qi, ki, masked,
+            window=window,
+        )
+        dq_acc[...] = dq_acc[...] + dq
+        dk_acc[cols, :] = dk_acc[cols, :] + dk
+        dv_acc[cols, :] = dv_acc[cols, :] + dv
+
+    _full_or_masked(qi, ki, block_q, block_k, causal, _accumulate,
+                    window=window)
+
+    @pl.when(ki == last)
+    def _finalize():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(_at_group_head(qi == bottom, group - 1, group, at))
+    def _write():
+        dk_ref[0, cols, :] = dk_acc[cols, :].astype(dk_ref.dtype)
+        dv_ref[0, cols, :] = dv_acc[cols, :].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
                              scale: float, block_q: int, block_k: int,
-                             interpret: bool, window: Optional[int] = None):
-    """The streamed dq and dkv calls; ``lse_row`` and ``delta_row`` are the
-    ``[BH, 1, S]`` views both take."""
+                             interpret: bool, window: Optional[int] = None,
+                             fused: bool = True):
+    """The streamed backward: ``flash_bwd`` alone where ``fused``
+    (:func:`_fuses_backward`), else the dq and the dkv call; ``lse_row``
+    and ``delta_row`` are the ``[BH, 1, S]`` views every one takes."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
     group = bh // k.shape[0]
+
+    def operands(by_q, by_k, q_lanes):
+        # q, k, v, dO and the two statistics, as every kernel here takes them
+        return [
+            pl.BlockSpec((1, block_q, d), by_q),
+            pl.BlockSpec((1, block_k, d), by_k),
+            pl.BlockSpec((1, block_k, dv), by_k),
+            pl.BlockSpec((1, block_q, dv), by_q),
+            pl.BlockSpec((1, 1, block_q), q_lanes),
+            pl.BlockSpec((1, 1, block_q), q_lanes),
+        ]
 
     grid, tables, by_q, by_k, q_lanes = _streamed_grid(
         bh, seq_len, block_q, block_k, causal, True, window=window,
         group=group,
     )
+    if fused:
+        whole_kv = _whole_of(_heads_of(group, True)[1])
+        return pl.pallas_call(
+            functools.partial(
+                _flash_bwd_streamed_kernel, block_q=block_q,
+                block_k=block_k, seq_len=seq_len, causal=causal,
+                scale=scale, window=window, group=group,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables),
+                grid=grid,
+                in_specs=operands(by_q, by_k, q_lanes),
+                out_specs=[
+                    pl.BlockSpec((1, block_q, d), by_q),
+                    pl.BlockSpec((1, seq_len, d), whole_kv),
+                    pl.BlockSpec((1, seq_len, dv), whole_kv),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, d), jnp.float32),
+                    pltpu.VMEM((seq_len, d), jnp.float32),
+                    pltpu.VMEM((seq_len, dv), jnp.float32),
+                ],
+            ),
+            out_shape=(
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ),
+            compiler_params=_FUSED_PARAMS,
+            interpret=interpret,
+            name="flash_bwd",
+        )(*tables, q, k, v, g, lse_row, delta_row)
+
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_streamed_kernel, block_q=block_q,
@@ -1075,14 +1246,7 @@ def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), by_q),
-                pl.BlockSpec((1, block_k, d), by_k),
-                pl.BlockSpec((1, block_k, dv), by_k),
-                pl.BlockSpec((1, block_q, dv), by_q),
-                pl.BlockSpec((1, 1, block_q), q_lanes),
-                pl.BlockSpec((1, 1, block_q), q_lanes),
-            ],
+            in_specs=operands(by_q, by_k, q_lanes),
             out_specs=pl.BlockSpec((1, block_q, d), by_q),
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
@@ -1107,14 +1271,7 @@ def _flash_backward_streamed(q, k, v, g, lse_row, delta_row, causal: bool,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(tables),
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), by_q),
-                pl.BlockSpec((1, block_k, d), by_k),
-                pl.BlockSpec((1, block_k, dv), by_k),
-                pl.BlockSpec((1, block_q, dv), by_q),
-                pl.BlockSpec((1, 1, block_q), q_lanes),
-                pl.BlockSpec((1, 1, block_q), q_lanes),
-            ],
+            in_specs=operands(by_q, by_k, q_lanes),
             out_specs=[
                 pl.BlockSpec((1, block_k, d), by_k),
                 pl.BlockSpec((1, block_k, dv), by_k),
@@ -1153,31 +1310,36 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
                          interpret: bool,
                          resident_kv_bytes: Optional[int] = None,
                          window: Optional[int] = None):
-    """Kernel core with EXTERNAL lse/delta ([BH, S] f32): resident variant
-    (full K/V resp. Q/dO in VMEM) below the threshold, streamed tiles
-    above it. External statistics are what make the ring backward work —
+    """Kernel core with EXTERNAL lse/delta ([BH, S] f32): ``flash_bwd``
+    alone where :func:`_fuses_backward` says so — its resident body (full
+    K/V in VMEM) below the threshold, streamed tiles above it —, else the
+    streamed ``flash_dq`` and ``flash_dkv``, whose VMEM no sequence
+    outgrows. External statistics are what make the ring backward work —
     with the GLOBAL lse and delta, each (q-block, kv-block) pair's
     dq/dk/dv contributions are independent (FlashAttention-2), so pairs
     can be revisited in any order/placement and summed."""
     bh, seq_len, d = q.shape
     dv = v.shape[-1]
     group = bh // k.shape[0]
-    # the one view of the [BH, S] statistics that dq and dkv both take
+    # the one view of the [BH, S] statistics that every kernel takes
     # (module docstring): [BH, 1, S] rows, q positions along the lanes
     lse_row, delta_row = lse[:, None, :], delta[:, None, :]
-    if not _resident(seq_len, d + dv, q.dtype.itemsize, resident_kv_bytes):
+    fused = _fuses_backward(seq_len, d, q.dtype.itemsize, block_q, block_k,
+                            dv, resident_kv_bytes)
+    if not (fused and _resident(seq_len, d + dv, q.dtype.itemsize,
+                                resident_kv_bytes)):
         return _flash_backward_streamed(
             q, k, v, g, lse_row, delta_row, causal, scale, block_q,
-            block_k, interpret, window,
+            block_k, interpret, window, fused,
         )
 
     whole_kv = _whole_of(_heads_of(group, True)[1])
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-        seq_len=seq_len, causal=causal, scale=scale, window=window,
-    )
-    dq = pl.pallas_call(
-        dq_kernel,
+    return pl.pallas_call(
+        functools.partial(
+            _flash_bwd_kernel, block_q=block_q, block_k=block_k,
+            seq_len=seq_len, causal=causal, scale=scale, window=window,
+            group=group,
+        ),
         grid=(bh, seq_len // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -1187,51 +1349,24 @@ def _flash_backward_core(q, k, v, g, lse, delta, causal: bool,
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_dq",
-    )(q, k, v, g, lse_row, delta_row)
-
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-        seq_len=seq_len, causal=causal, scale=scale, window=window,
-        group=group,
-    )
-    # the column sweep: the key/value heads lead; a group's query heads
-    # ride one more grid axis, innermost, and their sums two scratch
-    # accumulators (none at group 1: the sweep's own carry is written out)
-    q_head, _, inner = _heads_of(group, False)
-    by_head = _whole_of(q_head)
-
-    def by_k(b, j, *head_in_group):
-        return b, j, 0
-
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(k.shape[0], seq_len // block_k) + inner,
-        in_specs=[
-            pl.BlockSpec((1, seq_len, d), by_head),
-            pl.BlockSpec((1, block_k, d), by_k),
-            pl.BlockSpec((1, block_k, dv), by_k),
-            pl.BlockSpec((1, seq_len, dv), by_head),
-            pl.BlockSpec((1, 1, seq_len), by_head),
-            pl.BlockSpec((1, 1, seq_len), by_head),
-        ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), by_k),
-            pl.BlockSpec((1, block_k, dv), by_k),
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, seq_len, d), whole_kv),
+            pl.BlockSpec((1, seq_len, dv), whole_kv),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, width), jnp.float32)
-                        for width in (d, dv) if group > 1],
+        scratch_shapes=[
+            pltpu.VMEM((seq_len, d), jnp.float32),
+            pltpu.VMEM((seq_len, dv), jnp.float32),
+        ],
         out_shape=(
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
+        compiler_params=_FUSED_PARAMS,
         interpret=interpret,
-        name="flash_dkv",
+        name="flash_bwd",
     )(q, k, v, g, lse_row, delta_row)
-    return dq, dk, dv
 
 
 def _reference(q, k, v, causal: bool, scale: float):
@@ -1282,8 +1417,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # alike, for the row sweeps and for the column sweep; 1024 gains nothing
 # more where K and V are resident.
 _TILES = ((512, 512), (256, 256), (128, 128))
-# A streamed grid step of dq and dkv is ONE tile (of the forward a chunk of
-# them, ``_CHUNK_LADDER``), and a step costs about as much again as a
+# A streamed grid step of the backward is ONE tile (of the forward a chunk
+# of them, ``_CHUNK_LADDER``), and a step costs about as much again as a
 # 512 x 512 tile's arithmetic, so they try a k edge of 1024 first.
 # Measured on the v5e at [2, 8192, 32, 192 / 128] (PERF.md, PR 31):
 # forward 27.8 -> 19.5 ms; at 128 / 128 it changes nothing.
@@ -1447,6 +1582,55 @@ def _choose_chunk(seq_len: int, head_dim: int, itemsize: int, block_q: int,
                       <= _VMEM_BUDGET))
 
 
+# What ``flash_bwd`` may plan to hold in VMEM (of the v5e's 128 MiB): dk's
+# and dv's accumulators and output blocks are whole sequences of one
+# key/value head.
+_FUSED_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=96 * 1024 * 1024)
+
+
+def _fused_vmem_estimate(seq_len: int, head_dim: int, v_dim: int,
+                         itemsize: int, block_q: int, block_k: int,
+                         resident: bool = False) -> int:
+    """Bytes ``flash_bwd`` keeps in VMEM: the two ``[S, D]`` f32
+    accumulators, dk's and dv's whole-head output blocks twice (Pallas
+    double-buffers an output block), and a tile's share — its pipelined
+    operands (``resident``: K and V whole sequences), statistics and dq's
+    block twice, their f32 copies, the five f32 score-tile temporaries
+    (Sᵀ, Pᵀ, dPᵀ, dSᵀ and dS turned) and dq's accumulator. Held against the compiler for a described v5e (PR 74: the
+    least ``vmem_limit_bytes`` at which ``flash_bwd`` lowers, bf16; MiB,
+    estimate -> allocation): 8 192 x 192 | 128 34.5 -> 22.4, 8 192 x 128
+    29.5 -> 22.6 (22.3 without the mask), 16 384 x 128 45.5 -> 38.6 (38.9
+    under W 4 096), 8 192 x 256 on 512 x 512 tiles 42.0 -> 38.7, 8 192 x
+    64 | 128 24.5 -> 16.8 (18.8 -> 14.6 under W 512), 32 768 x 128 77.5 ->
+    70.5: over at every call, by 3.3 - 12.1 (the compiler counts the
+    whole-head arrays and the pipelined blocks and little of a tile's
+    temporaries)."""
+    pair = head_dim + v_dim
+    whole = seq_len * pair * (4 + 2 * itemsize)
+    k_rows = seq_len if resident else block_k
+    operands = ((block_q + k_rows) * pair + block_q * head_dim) * itemsize
+    stats = 2 * block_q * 4
+    upcast = (block_q + block_k) * pair * 4
+    return (whole + 2 * (operands + stats) + upcast
+            + 5 * block_q * block_k * 4 + block_q * head_dim * 4)
+
+
+def _fuses_backward(seq_len: int, head_dim: int, itemsize: int,
+                    block_q: int, block_k: int, v_dim: Optional[int] = None,
+                    resident_kv_bytes: Optional[int] = None) -> bool:
+    """Whether a call's backward is the ONE kernel ``flash_bwd``, as a pure
+    function of the shape: every call whose :func:`_fused_vmem_estimate`
+    fits ``_FUSED_PARAMS``' limit — every call with K and V resident (its
+    accumulators are no longer than they), and the streamed ones up to 32k
+    x 128. A longer or wider call keeps ``flash_dq`` and ``flash_dkv``,
+    whose VMEM does not grow with the sequence."""
+    v_dim = head_dim if v_dim is None else v_dim
+    return _fused_vmem_estimate(
+        seq_len, head_dim, v_dim, itemsize, block_q, block_k,
+        _resident(seq_len, head_dim + v_dim, itemsize, resident_kv_bytes),
+    ) <= _FUSED_PARAMS.vmem_limit_bytes
+
+
 def _bshd_prologue(q, k, v, scale, block_q, block_k, window=None):
     """Shared [B,S,H,D]-surface plumbing: scale default (from q's
     width), block choice (from the shape where the caller gave none) and
@@ -1588,6 +1772,9 @@ def flash_attention(q, k, v, causal: bool = True,
                      block_k, v.shape[3], window, causal,
                      _resident_kv_bytes) > 1:
         TRACED.incr("flash_calls_chunked")
+    if _fuses_backward(q.shape[1], q.shape[3], q.dtype.itemsize, block_q,
+                       block_k, v.shape[3], _resident_kv_bytes):
+        TRACED.incr("flash_calls_fused_bwd")
     out = _flash(merge(q), merge(k), merge(v), causal, scale,
                  block_q, block_k, interpret, _resident_kv_bytes, window)
     return unmerge(out)

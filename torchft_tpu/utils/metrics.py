@@ -125,7 +125,8 @@ class Metrics:
 # counts here which of its paths a model's program engaged (once a trace,
 # not once a step; ``ops/flash.py``: ``flash_calls`` and, of those, the
 # ``flash_calls_grouped`` whose key/value heads each serve several query
-# heads; ``ops/dsa.py``: ``dsa_kl_grad_calls`` and, gauges, what the last
+# heads and the ``flash_calls_fused_bwd`` whose backward is one kernel;
+# ``ops/dsa.py``: ``dsa_kl_grad_calls`` and, gauges, what the last
 # trace of each kernel took a grid step: ``dsa_fwd_`` / ``dsa_dq_`` /
 # ``dsa_dkv_chunk_tiles`` and ``dsa_kl_group_heads``).
 # Tests read the difference across a ``jax.make_jaxpr``.
